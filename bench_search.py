@@ -36,8 +36,13 @@ def main():
 
     force_cpu(8)
 
+    import sys
+
     import jax
     import numpy as np
+
+    print(f"[bench_search] backend={jax.default_backend()} "
+          f"devices={len(jax.devices())}", file=sys.stderr, flush=True)
 
     from flexflow_tpu import SGDOptimizer, make_mesh
     from flexflow_tpu.models.transformer import build_transformer_classifier

@@ -86,6 +86,10 @@ def main():
         from flexflow_tpu.utils.platform import force_cpu
 
         force_cpu(args.cpu)
+    else:  # persistent compile cache (not for virtual-device collectives)
+        from flexflow_tpu.utils.platform import enable_compile_cache
+
+        enable_compile_cache()
     import jax
     import numpy as np
 

@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-from ..compat import shard_map
 from .op import OpContext
 from .pcg import Plan, Step
 from .sharding import TensorSharding
@@ -135,11 +134,12 @@ def build_forward(plan: Plan, mode: str = "spmd") -> Callable:
         def local_body(params_, inputs_):
             return body(params_, inputs_, rng, training)
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             local_body,
             mesh=mesh,
             in_specs=(pspecs, input_pspecs),
             out_specs=out_pspecs,
+            check_vma=False,
         )
         return mapped(params, inputs)
 

@@ -312,46 +312,78 @@ def decode_attention(
     return out.reshape(t, qh, d)
 
 
-# Prefill streams K+V blocks against a Bq*gq-row query tile; the block
-# budget is tighter than decode's because the scores tile and the q/o/acc
-# tiles also live in VMEM.  The grid carries a KV-HEAD-CHUNK axis: each
-# grid step works on ``kv_chunk <= KV`` heads, so the f32 score/softmax
-# scratch is [kv_chunk, Bq*gq, Bs] — chunking the heads (heads are
-# independent softmaxes) is what lets the Q tile WIDEN (Bq up to 128 at
-# the 7B shape, where the unchunked [32, 128, 512] score tile alone blows
-# VMEM) without shrinking the seq block below the DMA-efficient size.
+# Prefill streams K+V blocks against a Bq*gq-row query tile.  The grid
+# carries a KV-HEAD-CHUNK axis: each grid step works on ``kv_chunk <= KV``
+# heads, so the f32 score/softmax working set is [kv_chunk, Bq*gq, Bs] —
+# chunking the heads (heads are independent softmaxes) is what lets the Q
+# tile WIDEN (Bq up to 128 at the 7B shape, where the unchunked
+# [32, 128, 128] plan needs 17.41 MB) without shrinking the seq block below
+# the DMA-efficient size.  This budget bounds the K+V block pipeline alone;
+# the whole plan is then held to the compiler's limit below.
 _VMEM_BUDGET_PREFILL = 4 * 2**20
-# f32 working set per grid step (scores + acc + m/l scratch); 8 MB keeps
-# the shipped tile=64, KV=32, d=128 config admissible (measured compiling
-# on v5e at r5) and forces head-chunking beyond it.
-_VMEM_BUDGET_PREFILL_SCRATCH = 8 * 2**20
+# Scoped VMEM the TPU compiler grants one kernel (its default on v5e; it
+# refuses the kernel outright beyond it).  :func:`_prefill_vmem_bytes`
+# counts everything the compiler stacks against this number.
+_VMEM_SCOPED_LIMIT = 16 * 2**20
 
 
-def _prefill_plan(num_kv, d, itemsize, kv_quant, m_rows, block_s, s_len):
+def _prefill_vmem_bytes(kv_chunk, m_rows, block_s, d, q_itemsize,
+                        kv_itemsize, kv_quant):
+    """Scoped VMEM one prefill grid step needs, as the TPU compiler counts
+    it: the double-buffered q and o blocks, the double-buffered K+V (+ int8
+    scale) blocks, the m/l/acc scratch, and the f32 score and probability
+    tiles the body materializes.  Minor dims pad to the 128-lane tile.
+
+    Checked against the compiler's own totals for a described v5e (the
+    refusal message names them): 17.41 MB at (kv_chunk 32, block 128) and
+    11.54 MB at (16, 256) for m_rows 128, d 128, bf16 — this returns 18 and
+    13 MB; it errs high everywhere probed (the compiler keeps less than two
+    full score tiles live), never low.
+    """
+    lanes = -(-d // 128) * 128
+    qo = 2 * 2 * kv_chunk * m_rows * lanes * q_itemsize
+    kv = 2 * 2 * kv_chunk * block_s * (lanes * kv_itemsize
+                                       + (4 if kv_quant else 0))
+    scratch = 4 * kv_chunk * m_rows * (128 + 128 + lanes)
+    tiles = 2 * 4 * kv_chunk * m_rows * block_s
+    return qo + kv + scratch + tiles
+
+
+def _prefill_plan(num_kv, d, q_itemsize, kv_itemsize, kv_quant, m_rows,
+                  block_s, s_len, kv_chunk=None):
     """(kv_chunk, block_s) for the prefill grid.
 
-    Chooses the widest kv-head chunk whose f32 score/softmax scratch
-    (``4 * kv_chunk * m_rows * (block_s + d + 256)`` bytes: scores/p tile +
-    acc + the two 128-lane m/l buffers) fits the scratch budget, fitting
-    the seq block under the K+V double-buffer budget (int8 scales ride the
-    same pipeline — :func:`_fit_block_s`) at each candidate width.  Wider
-    Q tiles (m_rows) therefore trade head-parallelism per grid step for
-    query rows, keeping total VMEM bounded.
+    Chooses the widest kv-head chunk (a divisor of ``num_kv``; ``kv_chunk``
+    forces one) whose whole working set (:func:`_prefill_vmem_bytes`) fits
+    the compiler's scoped-VMEM limit, with the seq block fitted under the
+    K+V double-buffer budget at each candidate width
+    (:func:`_fit_block_s`).  Wider Q tiles (m_rows) therefore trade
+    head-parallelism per grid step for query rows.  At one head per step
+    the seq block halves too (down to the 128-lane tile); when even that
+    does not fit — ``m_rows`` = tile * gq is itself too large, the MQA
+    geometry — no plan exists and the caller gets a ValueError at trace
+    time instead of a kernel the compiler refuses.
     """
-    kv_chunk = num_kv
+    def need(kc, bs):
+        return _prefill_vmem_bytes(kc, m_rows, bs, d, q_itemsize,
+                                   kv_itemsize, kv_quant)
 
-    def fit(kc):
-        return _fit_block_s(block_s, s_len, kc, d, itemsize, kv_quant,
-                            _VMEM_BUDGET_PREFILL)
-
-    bs = fit(kv_chunk)
-    while (kv_chunk > 1
-           and 4 * kv_chunk * m_rows * (bs + d + 256)
-           > _VMEM_BUDGET_PREFILL_SCRATCH):
-        # largest proper divisor (power-of-two head counts halve)
-        kv_chunk = max(c for c in range(1, kv_chunk) if kv_chunk % c == 0)
-        bs = fit(kv_chunk)
-    return kv_chunk, bs
+    chunks = [kv_chunk] if kv_chunk else [
+        c for c in range(num_kv, 0, -1) if num_kv % c == 0]
+    for kc in chunks:
+        bs = _fit_block_s(block_s, s_len, kc, d, kv_itemsize, kv_quant,
+                          _VMEM_BUDGET_PREFILL)
+        if kc == chunks[-1]:  # no narrower chunk left: give up seq block
+            while need(kc, bs) > _VMEM_SCOPED_LIMIT and bs % 256 == 0:
+                bs //= 2
+        if need(kc, bs) <= _VMEM_SCOPED_LIMIT:
+            return kc, bs
+    raise ValueError(
+        f"prefill_attention: no VMEM-admissible plan for KV={num_kv} "
+        f"D={d} m_rows={m_rows} (query tile * q-heads per kv-head): "
+        f"{need(kc, bs) / 2**20:.1f} MiB at kv_chunk={kc}, block_s={bs} "
+        f"exceeds the {_VMEM_SCOPED_LIMIT >> 20} MiB scoped limit; this "
+        "geometry needs a narrower query tile")
 
 
 def _prefill_kernel(
@@ -481,18 +513,12 @@ def prefill_attention(
     m_rows = bq * gq
     kv_quant = k_scale is not None
     paged = page_table is not None
-    plan_kc, plan_bs = _prefill_plan(
-        num_kv, d, jnp.dtype(k_cache.dtype).itemsize, kv_quant, m_rows,
-        block_s, s_len)
-    if kv_chunk is None:
-        kv_chunk = plan_kc
-        block_s = plan_bs
-    else:  # forced chunk (tests): still fit the seq block at that width
-        if num_kv % kv_chunk:
-            raise ValueError(f"kv_chunk {kv_chunk} must divide KV {num_kv}")
-        block_s = _fit_block_s(block_s, s_len, kv_chunk, d,
-                               jnp.dtype(k_cache.dtype).itemsize, kv_quant,
-                               _VMEM_BUDGET_PREFILL)
+    if kv_chunk is not None and num_kv % kv_chunk:  # forced chunk (tests)
+        raise ValueError(f"kv_chunk {kv_chunk} must divide KV {num_kv}")
+    kv_chunk, block_s = _prefill_plan(
+        num_kv, d, jnp.dtype(q.dtype).itemsize,
+        jnp.dtype(k_cache.dtype).itemsize, kv_quant, m_rows, block_s, s_len,
+        kv_chunk)
     if paged:  # a seq-block must sit inside one page (see decode_attention)
         block_s = math.gcd(block_s, page_size)
     n_kc = num_kv // kv_chunk

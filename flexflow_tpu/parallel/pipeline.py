@@ -21,8 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..compat import shard_map
-
 
 def pipeline_apply(
     stage_fn: Callable,
@@ -138,11 +136,12 @@ def pipeline_train_step(
 
     def step(stacked_params, x, labels):
         p_specs = jax.tree.map(lambda _: P(axis_name), stacked_params)
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(p_specs, data_spec, data_spec),
             out_specs=(P(), p_specs),
+            check_vma=False,
         )(stacked_params, x, labels)
 
     return step
@@ -228,11 +227,12 @@ def graph_pipeline_train_step(
         core_specs = jax.tree.map(lambda _: P(axis_name), core_p)
         rep = jax.tree.map(lambda _: P(), pre_p), \
             jax.tree.map(lambda _: P(), suf_p)
-        return shard_map(
+        return jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(core_specs, rep[0], rep[1], data_spec, data_spec),
             out_specs=(P(), data_spec, (core_specs, rep[0], rep[1])),
+            check_vma=False,
         )(core_p, pre_p, suf_p, x, labels)
 
     return step

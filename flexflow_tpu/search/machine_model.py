@@ -92,6 +92,15 @@ TPU_SPECS: Dict[str, TPUSpec] = {
 }
 
 
+# ``device.device_kind`` as JAX reports it -> TPU_SPECS key.  A device that
+# is not here is an error, never priced as some other chip.
+DEVICE_KIND_SPECS: Dict[str, str] = {
+    "TPU v5 lite": "v5e",
+    "TPU v5": "v5p",
+    "cpu": "cpu",
+}
+
+
 @dataclasses.dataclass
 class MachineModel:
     """Cost oracle for one mesh: compute roofline + collective time."""
@@ -104,8 +113,13 @@ class MachineModel:
     def for_mesh(mesh, spec_name: Optional[str] = None,
                  dcn_axes=()) -> "MachineModel":
         if spec_name is None:
-            plat = mesh.devices.flat[0].platform if mesh.size else "cpu"
-            spec_name = {"tpu": "v5e", "cpu": "cpu"}.get(plat, "v5e")
+            kind = mesh.devices.flat[0].device_kind
+            if kind not in DEVICE_KIND_SPECS:
+                raise ValueError(
+                    f"no machine spec for device_kind {kind!r} (known: "
+                    f"{sorted(DEVICE_KIND_SPECS)}); add it to TPU_SPECS / "
+                    "DEVICE_KIND_SPECS or pass spec_name explicitly")
+            spec_name = DEVICE_KIND_SPECS[kind]
         return MachineModel(TPU_SPECS[spec_name], frozenset(dcn_axes))
 
     def with_calibration(self, path: str) -> "MachineModel":

@@ -524,29 +524,37 @@ class IncMultiHeadSelfAttention(Op):
             mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
         )
 
-    @staticmethod
-    def _head_shard_map(ctx, head_axes, in_specs, out_specs):
+    def _head_shard_map(self, ctx, head_axes, in_specs, out_specs, what):
         """shard_map wrapper for a Pallas attention call under GSPMD.
 
         Returns the identity when the mesh is trivial (plain single-device
-        call), a ``shard_map`` partial over the kv-head axis when every
+        call) and a ``shard_map`` partial over the kv-head axis when every
         non-trivial mesh axis is a head axis (Megatron serve TP: GQA groups
         stay intact per shard, so the kernel runs unchanged on local
-        shapes), and ``None`` when the sharding is unsupported — the caller
-        falls back to the gather path.
+        shapes).  A sharding the kernel cannot express is an ERROR on a TPU
+        backend — the user asked for the kernels and cannot see them give
+        way — naming ``what`` (the attention path) and the mesh axes; off
+        the chip it returns ``None`` and the caller takes the gather path
+        (the CPU tests' oracle).
         """
         mesh = ctx.mesh if ctx is not None else None
         if mesh is None or all(mesh.shape[a] == 1 for a in mesh.axis_names):
             return lambda f: f
         nontrivial = {a for a in mesh.axis_names if mesh.shape[a] > 1}
         if not head_axes or not nontrivial.issubset(set(head_axes)):
+            if jax.default_backend() == "tpu":
+                raise ValueError(
+                    f"{self.type_name} {what}: the Pallas kernel shards "
+                    f"over the kv-head axes {tuple(head_axes)} only "
+                    f"({self.num_kv_heads} kv heads), but mesh axes "
+                    f"{dict(mesh.shape)} are non-trivial; pass "
+                    "use_pallas=False to serve this plan on the gather path")
             return None
-        from ..compat import shard_map
 
         def wrap(f):
-            return shard_map(
+            return jax.shard_map(
                 f, mesh=mesh, in_specs=tuple(in_specs),
-                out_specs=out_specs,
+                out_specs=out_specs, check_vma=False,
             )
 
         return wrap
@@ -602,7 +610,7 @@ class IncMultiHeadSelfAttention(Op):
                 ctx, h,
                 [P(None, h), P(None, h), P(None, h), P(), P(), P(h)]
                 + [P(None, h)] * len(scales) + [P()] * len(pg),
-                P(None, h),
+                P(None, h), "decode attention",
             )
             if sm is not None:
                 out = sm(attend)(q, kc, vc, rows, pos, slopes, *scales, *pg)
@@ -689,9 +697,9 @@ class IncMultiHeadSelfAttention(Op):
             [P(None, h), P(None, h), P(None, h), P(), P()]
             + [P(None, h)] * (2 if kv_q else 0)
             + [P()] * (1 if pages is not None else 0),
-            P(None, h),
+            P(None, h), "prefill attention",
         )
-        if sm is None:  # unsupported sharding: flat gather fallback
+        if sm is None:  # unsupported sharding off the chip: gather oracle
             return self._inc_attend(q, k, v, state, base, ctx)
         # tile row: real slots sit at the tile head, pads map to the scratch
         # row nreq (the largest index), so min() recovers the tile's request
@@ -895,7 +903,7 @@ class IncMultiHeadSelfAttention(Op):
                 ctx, h,
                 [P(None, h)] * 5 + [P(), P(), P()]
                 + [P(None, h)] * len(scales) + [P()] * len(pg),
-                P(None, h),
+                P(None, h), "tree-verify attention",
             )
             if sm is not None:
                 out = sm(attend)(q, kc, vc, sk, sv, rows, clens, amask,

@@ -123,9 +123,10 @@ def main():
     import bench
 
     # program mode like bench.main(): arm the persistent compile cache so
-    # profiling re-runs skip the ~30-60s full-model recompile (bench no
-    # longer enables it at import — that side effect segfaulted pytest)
-    bench._enable_compile_cache()
+    # profiling re-runs skip the full-model recompile
+    from flexflow_tpu.utils.platform import enable_compile_cache
+
+    enable_compile_cache()
     im = bench.build_im(use_pallas=True, layers=LAYERS, hidden=E, heads=32,
                         kv=KV, inter=INTER, vocab=VOCAB, max_requests=R,
                         max_seq=S, max_tokens=T)

@@ -21,7 +21,12 @@ import pytest
 
 import jax
 
-from flexflow_tpu.obs import NULL_PROFILER, StepProfiler, Telemetry
+from flexflow_tpu.obs import (
+    NULL_PROFILER,
+    NULL_TELEMETRY,
+    StepProfiler,
+    Telemetry,
+)
 from flexflow_tpu.obs.profiler import plan_cost_card
 from flexflow_tpu.serve import GenerationConfig, RequestManager
 
@@ -561,7 +566,9 @@ def test_step_profile_instants_and_export(tmp_path):
     try:
         rm.generate([[3, 5, 7]])
     finally:
-        im.telemetry = None
+        # the cached im is shared with other test files in this worker
+        # (test_serve.make_im): hand it back with the no-op handle, not None
+        im.telemetry = NULL_TELEMETRY
         im.profiler = NULL_PROFILER
     assert tel.profiler is prof
     paths = tel.export(str(tmp_path))
