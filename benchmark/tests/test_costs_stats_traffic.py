@@ -1,0 +1,93 @@
+"""costs.py against hand-worked operations and bytes; the percentile; the
+traffic generator's promises."""
+
+import json
+import os
+import statistics
+
+import pytest
+
+from conftest import ROOT
+
+from benchmark import costs, stats, traffic_gen
+
+
+def test_decode_attention_cost_by_hand():
+    # 2 rows, contexts 3 and 5, 4 query heads on 1 kv head, head size 8,
+    # bf16: per position QK^T is 2*4*8 ops and PV another 2*4*8 -> 128
+    ops, nbytes = costs.decode_attention_cost([3, 5], 4, 1, 8)
+    assert ops == 128 * 8
+    # K and V: 2 * 1 head * 8 * 2 bytes per position = 32 B x 8 positions;
+    # q in and o out: 2 * 2 rows * 4 heads * 8 * 2 bytes = 256 B
+    assert nbytes == 32 * 8 + 256
+
+
+def test_prefill_attention_cost_by_hand():
+    # 4 queries starting at position 2: they attend 3, 4, 5, 6 positions
+    ops, nbytes = costs.prefill_attention_cost(4, 2, 4, 1, 8)
+    assert ops == 4 * 4 * 8 * (3 + 4 + 5 + 6)
+    # K and V of 6 positions (32 B each) + q and o of 4 queries (64 B each
+    # way)
+    assert nbytes == 32 * 6 + 2 * 4 * 4 * 8 * 2
+
+
+def test_roofline_picks_the_binding_roof():
+    peak = {"flops_bf16": 100.0, "hbm_bytes_per_s": 10.0}
+    assert costs.roofline_seconds(1000, 10, peak) == (10.0, "compute")
+    assert costs.roofline_seconds(10, 1000, peak) == (100.0, "memory")
+
+
+def test_percentile_is_nearest_rank():
+    xs = list(range(1, 21))
+    assert stats.percentile(xs, 0.95) == 20
+    assert stats.percentile(xs, 0.5) == 11
+    assert stats.percentile([], 0.95) is None
+    vals = [10, 11, 12, 13, 14, 15]
+    q1, _, q3 = statistics.quantiles(vals, n=4)
+    assert stats.iqr_share(vals) == (q3 - q1) / 12.5
+
+
+def _mix(name):
+    with open(os.path.join(ROOT, "benchmark", "traffic", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_every_seed_gets_the_same_schedule_and_other_token_ids():
+    mix = _mix("code-complete")
+    a = traffic_gen.make_requests(mix, 7, 49152, 40, 8192)
+    b = traffic_gen.make_requests(mix, 2 ** 31 + 12345, 49152, 40, 8192)
+    assert a == traffic_gen.make_requests(mix, 7, 49152, 40, 8192)
+    shape = lambda reqs: [(t, len(p), o) for t, p, o in reqs]
+    assert shape(a) == shape(b)
+    assert [p for _, p, _ in a] != [p for _, p, _ in b]  # token ids differ
+    assert len(a) == round(mix["arrivals"]["rate_per_s"] * 40)
+    lo, hi = mix["prompt_len"]["lo"], mix["prompt_len"]["hi"]
+    assert all(lo <= len(p) <= hi for _, p, _ in a)
+    assert all(4 <= t < 49152 for _, p, _ in a for t in p)
+    # stratified: every block of arrivals holds the whole grid of lengths
+    # and spans exactly block / rate seconds
+    n = mix["block"]
+    grid = sorted(traffic_gen.length_grid(mix["prompt_len"], n))
+    assert sorted(len(p) for _, p, _ in a[:n]) == grid
+    assert sorted(len(p) for _, p, _ in a[n:2 * n]) == grid
+    assert abs(a[2 * n - 1][0] - a[n - 1][0] - n / 3.6) < 1e-9
+    # the rehearsal's stream: the same schedule, other token ids
+    r = traffic_gen.make_requests(mix, 7, 49152, 40, 8192, stream=1)
+    assert shape(r) == shape(a) and r != a
+
+
+def test_closed_queue_is_deep_and_due_at_zero():
+    for name, max_seq in (("decode-heavy", 2048), ("long-prompt", 2048)):
+        mix = _mix(name)
+        reqs = traffic_gen.make_requests(mix, 3, 50272, 40, max_seq)
+        assert len(reqs) == mix["queue_depth"]
+        assert all(t == 0.0 for t, _, _ in reqs)
+        assert all(len(p) + o <= max_seq for _, p, o in reqs)
+
+
+def test_what_no_cell_brings_is_refused():
+    with pytest.raises(ValueError):
+        traffic_gen.gap_grid({"process": "gamma", "cv": 3.0,
+                              "rate_per_s": 2.0}, 16)
+    with pytest.raises(ValueError):
+        traffic_gen.length_grid({"dist": "fixed", "value": 5}, 4)
