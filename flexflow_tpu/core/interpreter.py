@@ -32,6 +32,15 @@ def _mesh_is_trivial(mesh: Mesh) -> bool:
     return mesh.size == 1
 
 
+def node_scope(node) -> str:
+    """The ``jax.named_scope`` a graph node is lowered under:
+    ``<OpClass>.<node name>`` (``Linear.layers_3_fc1``).  It becomes part of
+    every HLO operation's ``op_name`` — metadata only, so it costs nothing
+    at run time — and a device trace then says which node, and which kind
+    of operator, an XLA fusion or copy belongs to."""
+    return f"{type(node.op).__name__}.{node.name}"
+
+
 def build_forward(plan: Plan, mode: str = "spmd") -> Callable:
     """Return ``fn(params, inputs, rng=None, training=False) -> list[out]``.
 
@@ -81,7 +90,9 @@ def build_forward(plan: Plan, mode: str = "spmd") -> Callable:
             if state is not None and getattr(step.node.op, "stateful", False):
                 ctx.extras["state"] = state.get(step.node.name)
             args = [env[v] for v in step.in_vids]
-            outs = step.node.op.lower(ctx, args, params.get(step.node.name, {}))
+            with jax.named_scope(node_scope(step.node)):
+                outs = step.node.op.lower(ctx, args,
+                                          params.get(step.node.name, {}))
             if new_state is not None and "state_out" in ctx.extras:
                 new_state[step.node.name] = ctx.extras["state_out"]
             if mode == "spmd" and not trivial and not step.is_parallel:

@@ -17,7 +17,11 @@ decomposed half:
     preparation (``host_prepare``), jit dispatch (``dispatch``;
     per-stage ``stage{i}`` under pp), the inter-stage activation hop
     (``hop``), and the sample readback (``readback``) — the host-side
-    time-budget decomposition of a tick;
+    time-budget decomposition of a tick.  Each phase is the ``prof=``
+    consumer of the ONE span entered at that boundary
+    (``telemetry.span(name, prof=profiler)``, obs/trace.py ``Span``):
+    ``phase_s`` is filled at the span's exit, and leaving a launch span
+    (``dispatch`` / ``stage{i}``) counts one ``dispatches``;
   - **accumulates deterministic work counters** per tick and per request
     (:data:`WORK_COUNTERS`): flops executed, HBM bytes read/written, KV
     bytes touched, dispatch count, jit-recompile count, host-device
@@ -81,6 +85,8 @@ from __future__ import annotations
 import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
+
+from .trace import Span
 
 # the per-component time vocabulary: calibration-ledger field names are
 # f"{component}_ms" (TIME_COMPONENT_FIELDS).  pp_serve_cost EMITS this
@@ -203,25 +209,6 @@ def plan_cost_card(im) -> PlanCostCard:
     )
 
 
-class _Phase:
-    """Context manager accumulating one phase's wall time (entry/exit on
-    the profiler's injectable clock — mirrors trace._Span)."""
-
-    __slots__ = ("_prof", "_name", "_t0")
-
-    def __init__(self, prof, name):
-        self._prof = prof
-        self._name = name
-
-    def __enter__(self):
-        self._t0 = self._prof._clock()
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        self._prof._phase_done(self._name, self._prof._clock() - self._t0)
-        return False
-
-
 class StepProfiler:
     """See the module docstring.  One instance per serving session;
     shared by the RequestManager and its InferenceManager(s) like the
@@ -317,12 +304,19 @@ class StepProfiler:
         return card
 
     # ---- phase timing -------------------------------------------------
-    def phase(self, name: str) -> _Phase:
-        return _Phase(self, name)
+    def phase(self, name: str) -> Span:
+        """A span whose only consumer is this profiler; instrumented code
+        enters ``telemetry.span(name, prof=profiler)`` instead, which
+        feeds the ring and ``phase_s`` through one ``with``."""
+        return Span(name, prof=self)
 
     def _phase_done(self, name: str, dt: float) -> None:
         self.phase_s[name] = self.phase_s.get(name, 0.0) + dt
         self.phase_counts[name] = self.phase_counts.get(name, 0) + 1
+        # a launch span (``dispatch``; ``stage<i>`` under pp) is one host
+        # program launch: counted where it is timed
+        if name == "dispatch" or name.startswith("stage"):
+            self.work["dispatches"] += 1
 
     # ---- deterministic counters ---------------------------------------
     def count(self, name: str, n: float = 1) -> None:
@@ -479,8 +473,8 @@ class NullStepProfiler:
     def card_for(self, *a, **k):
         return None
 
-    def phase(self, *a, **k):
-        return _NULL_PHASE
+    def phase(self, name, *a, **k):
+        return Span(name)
 
     def count(self, *a, **k):
         return None
@@ -509,18 +503,6 @@ class NullStepProfiler:
     def report(self):
         return {}
 
-
-class _NullPhase:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NULL_PHASE = _NullPhase()
 
 NULL_PROFILER = NullStepProfiler()
 

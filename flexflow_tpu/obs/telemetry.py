@@ -39,7 +39,7 @@ from .calibration import CalibrationLedger
 from .drift import WorkloadProfile
 from .memory import KV_OCCUPANCY_HIST, MEMORY_GAUGE_KEYS, MemoryLedger
 from .metrics import MetricsRegistry
-from .trace import TraceRecorder
+from .trace import Span, TraceRecorder
 
 # the resilience counter vocabulary (emitted by the request_rejected/
 # cancelled/timed_out/preempted/failed + dispatch_retry/fault_observed
@@ -285,8 +285,11 @@ class Telemetry:
     def now(self) -> float:
         return self._clock()
 
-    def span(self, name, cat="serve", track="serve", **args):
-        return self.trace.span(name, cat, track, **args)
+    def span(self, name, cat="serve", track="serve", prof=None, phase=None,
+             **args):
+        """The one boundary primitive (:class:`~.trace.Span`): profiler
+        annotation + ring event + (``prof``) a StepProfiler phase."""
+        return self.trace.span(name, cat, track, prof, phase, **args)
 
     def instant(self, name, cat="serve", track="serve", **args):
         return self.trace.instant(name, cat, track, **args)
@@ -792,19 +795,6 @@ class Telemetry:
         return {"trace_json": trace_path, "jsonl": jsonl_path}
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, exc_type, exc, tb):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullTelemetry:
     """No-op stand-in: every hook returns a constant; ``enabled`` is False
     so instrumented code can skip argument computation entirely."""
@@ -814,8 +804,11 @@ class NullTelemetry:
     def now(self):
         return 0.0
 
-    def span(self, *a, **k):
-        return _NULL_SPAN
+    def span(self, name, cat="serve", track="serve", prof=None, phase=None,
+             **args):
+        # no ring, but still the profiler annotation (and the phase): an
+        # attached jax.profiler session sees the scheduler with no handle
+        return Span(name, args, prof=prof, phase=phase)
 
     def instant(self, *a, **k):
         return 0.0

@@ -9,8 +9,13 @@ typed spans/instants/counters on named tracks, exportable as
 stage, so a pp run shows the stage interleave visually) and as JSONL for
 ``scripts/trace_report.py``.
 
-Overhead contract (the reason this exists as its own layer instead of
-piggybacking on ``jax.profiler``):
+Every span is ALSO a ``jax.profiler.TraceAnnotation`` (:class:`Span`): a
+profiler session attached to the process — with or without a recorder —
+sees the same names and arguments on its host plane, on the clock of the
+device's operations.  The ring stays, for what a profiler session is not:
+always on, bounded, exportable per request, testable on a virtual clock.
+
+Overhead contract of the ring:
 
 * **host-side only** — events are Python dicts appended to a ring buffer;
   nothing is ever passed into (or read back from) a jitted program, so
@@ -35,33 +40,66 @@ import time
 from collections import deque
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
 
-class _Span:
-    """Context manager recording one complete ("X" phase) event.
 
-    The event is emitted at ``__exit__`` with the entry timestamp, so buffer
-    order is completion order; Perfetto sorts by ``ts`` and infers nesting
-    from containment on a track, which entry/exit pairing here guarantees
-    for same-track spans.
+class Span:
+    """One boundary of the serving stack, entered through ONE ``with``.
+
+    The span always enters a ``jax.profiler.TraceAnnotation(name, **args)``
+    — a level-1 ``TraceMe``: an atomic load and this object when no
+    profiler session is active; under a session (``jax.profiler.trace``,
+    ``start_server``, the benchmark's ``--trace 1``) the span lands on the
+    profiler's host plane, on the same time base as the device's ops, with
+    ``args`` as its stats.  No handle, flag or environment variable turns
+    this on.  Two optional consumers ride the same entry/exit:
+
+    * ``rec`` — a :class:`TraceRecorder`: one complete ("X" phase) event in
+      the ring, emitted at ``__exit__`` with the entry timestamp, so buffer
+      order is completion order; Perfetto sorts by ``ts`` and infers
+      nesting from containment on a track;
+    * ``prof`` — an enabled ``StepProfiler``: the body's wall time (the
+      profiler's own injectable clock) is added to ``phase_s[phase]``.
+
+    :meth:`set` appends arguments known only inside the body (the tokens a
+    commit loop appended) to the annotation and to the ring event.
     """
 
-    __slots__ = ("_rec", "_name", "_cat", "_track", "_args", "_t0")
+    __slots__ = ("_ann", "_rec", "_name", "_cat", "_track", "_args", "_t0",
+                 "_prof", "_phase", "_p0")
 
-    def __init__(self, rec, name, cat, track, args):
-        self._rec = rec
+    def __init__(self, name, args=None, rec=None, cat="serve",
+                 track="serve", prof=None, phase=None):
         self._name = name
+        self._args = args or {}
+        self._rec = rec
         self._cat = cat
         self._track = track
-        self._args = args
+        self._prof = prof if prof is not None and prof.enabled else None
+        self._phase = phase or name
+
+    def set(self, **args):
+        self._ann.set_metadata(**args)
+        if self._rec is not None:
+            self._args = {**self._args, **args}
 
     def __enter__(self):
-        self._t0 = self._rec._clock()
+        if self._rec is not None:
+            self._t0 = self._rec._clock()
+        if self._prof is not None:
+            self._p0 = self._prof._clock()
+        self._ann = TraceAnnotation(self._name, **self._args)
+        self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        rec = self._rec
-        rec._emit("X", self._name, self._cat, self._track, self._t0,
-                  rec._clock() - self._t0, self._args)
+        self._ann.__exit__(exc_type, exc, tb)
+        prof, rec = self._prof, self._rec
+        if prof is not None:
+            prof._phase_done(self._phase, prof._clock() - self._p0)
+        if rec is not None:
+            rec._emit("X", self._name, self._cat, self._track, self._t0,
+                      rec._clock() - self._t0, self._args)
         return False
 
 
@@ -106,10 +144,11 @@ class TraceRecorder:
 
     # ------------------------------------------------------------------
     def span(self, name: str, cat: str = "serve", track: str = "serve",
-             **args) -> _Span:
+             prof=None, phase: Optional[str] = None, **args) -> Span:
         """``with rec.span("decode_stretch", steps=8): ...`` — a complete
-        event covering the body's wall time on ``track``."""
-        return _Span(self, name, cat, track, args)
+        event covering the body's wall time on ``track`` (see
+        :class:`Span` for ``prof``/``phase``)."""
+        return Span(name, args, self, cat, track, prof, phase)
 
     def instant(self, name: str, cat: str = "serve", track: str = "serve",
                 **args) -> float:

@@ -21,6 +21,7 @@ from ..core.interpreter import build_forward
 from ..core.pcg import PCG
 from ..obs.profiler import NULL_PROFILER
 from ..obs.telemetry import NULL_TELEMETRY
+from ..utils.platform import with_stack_room
 from .batch_config import BatchConfig, InferenceResult
 from .kv_allocator import (  # noqa: F401 — re-exported for compat
     KVAllocator,
@@ -584,17 +585,18 @@ class InferenceManager:
                 "pages": pages,
             },
         )
-        logits = outs[0].astype(jnp.float32)  # [T, vocab]
-        if sample is not None:
-            token_ids = self._sample_tokens(logits, sample)
-        else:
-            token_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        logits_max = jnp.max(logits, axis=-1)
-        topk_ids = topk_lp = None
-        if self.topk:
-            lp = jax.nn.log_softmax(logits, axis=-1)
-            topk_lp, topk_ids = jax.lax.top_k(lp, self.topk)
-            topk_ids = topk_ids.astype(jnp.int32)
+        with jax.named_scope("sample"):
+            logits = outs[0].astype(jnp.float32)  # [T, vocab]
+            if sample is not None:
+                token_ids = self._sample_tokens(logits, sample)
+            else:
+                token_ids = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            logits_max = jnp.max(logits, axis=-1)
+            topk_ids = topk_lp = None
+            if self.topk:
+                lp = jax.nn.log_softmax(logits, axis=-1)
+                topk_lp, topk_ids = jax.lax.top_k(lp, self.topk)
+                topk_ids = topk_ids.astype(jnp.int32)
         return (
             InferenceResult(token_ids, logits_max, topk_ids, topk_lp),
             new_state,
@@ -606,10 +608,13 @@ class InferenceManager:
         calls may have remapped pages (allocation, COW) since last step."""
         return self.kv.page_view()
 
-    def step(self, bc, sample=None) -> InferenceResult:
+    def step(self, bc, sample=None, counts=None) -> InferenceResult:
         """Run one serving step; caches update in place (donated).
 
         ``sample``: optional ``(key, temperature, top_p)`` — argmax if None.
+        ``counts``: what the caller's host bookkeeping knows about this
+        launch (``rows``, ``prompt_tokens``, ``ctx_sum``, ...) — ints that
+        become arguments of the dispatch span, nothing else.
         """
         assert self.params is not None, "call init_operators_inference() first"
         if self.fault_injector is not None:
@@ -618,14 +623,13 @@ class InferenceManager:
         # device time shows up at the result readback, not here.  Dispatch
         # spans live on their own track: they nest inside the serve loop's
         # spans, and per-track totals assume non-overlapping spans per track
-        prof = self.profiler
-        if prof.enabled:
-            prof.count("dispatches")
         with self.telemetry.span("step_dispatch", cat="dispatch",
-                                 track="dispatch"), prof.phase("dispatch"):
-            result, self.state = self._step(self.params, self.state, bc,
-                                            sample, None, None,
-                                            self._page_view())
+                                 track="dispatch", prof=self.profiler,
+                                 phase="dispatch", kind="step", n_steps=1,
+                                 **(counts or {})):
+            result, self.state = with_stack_room(
+                self._step, self.params, self.state, bc, sample, None, None,
+                self._page_view())
         return result
 
     # ------------------------------------------------------------------
@@ -687,32 +691,35 @@ class InferenceManager:
                                             pages=pages)
             toks = result.token_ids
             live = alive  # emission validity for THIS step
-            if eos is not None:
-                hit = live & (toks == eos)
-                eos_hit = eos_hit | hit
-                alive = alive & ~hit
-            if allowed is not None:
-                alive = alive & (i + 1 < allowed)
-            nxt = bc.advance(toks)
-            if eos is not None or allowed is not None:
-                nxt = BatchConfig(
-                    tokens=nxt.tokens,
-                    request_index=jnp.where(alive, nxt.request_index, -1),
-                    token_position=nxt.token_position,
-                    num_tokens=nxt.num_tokens,
-                    seq_lens=nxt.seq_lens,
-                )
+            with jax.named_scope("advance"):
+                if eos is not None:
+                    hit = live & (toks == eos)
+                    eos_hit = eos_hit | hit
+                    alive = alive & ~hit
+                if allowed is not None:
+                    alive = alive & (i + 1 < allowed)
+                nxt = bc.advance(toks)
+                if eos is not None or allowed is not None:
+                    nxt = BatchConfig(
+                        tokens=nxt.tokens,
+                        request_index=jnp.where(alive, nxt.request_index,
+                                                -1),
+                        token_position=nxt.token_position,
+                        num_tokens=nxt.num_tokens,
+                        seq_lens=nxt.seq_lens,
+                    )
             return (state, nxt, alive, eos_hit), (toks, live)
 
         eos_hit0 = jnp.zeros_like(alive0)
         (state, bc, alive_end, eos_hit), (tokens, live) = jax.lax.scan(
             body, (state, bc, alive0, eos_hit0), jnp.arange(n_steps)
         )
-        ecode = jnp.where(
-            ~present, EXIT_NOT_IN_BATCH,
-            jnp.where(eos_hit, EXIT_EOS,
-                      jnp.where(alive_end, EXIT_RUNNING, EXIT_BUDGET)),
-        ).astype(jnp.int32)
+        with jax.named_scope("advance"):
+            ecode = jnp.where(
+                ~present, EXIT_NOT_IN_BATCH,
+                jnp.where(eos_hit, EXIT_EOS,
+                          jnp.where(alive_end, EXIT_RUNNING, EXIT_BUDGET)),
+            ).astype(jnp.int32)
         return tokens, live, ecode, state, bc
 
     def _decode_scan_guards(self, n_steps: int, max_position=None,
@@ -752,7 +759,7 @@ class InferenceManager:
             )
 
     def decode_scan(self, bc, n_steps: int, eos: Optional[int] = None,
-                    sample=None):
+                    sample=None, counts=None):
         """Run ``n_steps`` decode steps on device.
 
         Returns ``(tokens, live, bc)``: i32[n_steps, T] token ids,
@@ -763,23 +770,20 @@ class InferenceManager:
         self._decode_scan_guards(n_steps, bc=bc)
         if self.fault_injector is not None:
             self.fault_injector.maybe_fail("decode_scan")
-        prof = self.profiler
-        if prof.enabled:
-            prof.count("dispatches")
         with self.telemetry.span("decode_scan_dispatch", cat="dispatch",
-                                 track="dispatch",
-                                 n_steps=n_steps), prof.phase("dispatch"):
-            tokens, live, _, self.state, bc = self._scan(
-                self.params, self.state, bc, sample, self._page_view(),
-                None, n_steps=n_steps, eos=eos
-            )
+                                 track="dispatch", prof=self.profiler,
+                                 phase="dispatch", kind="decode_scan",
+                                 n_steps=n_steps, **(counts or {})):
+            tokens, live, _, self.state, bc = with_stack_room(
+                self._scan, self.params, self.state, bc, sample,
+                self._page_view(), None, n_steps=n_steps, eos=eos)
         if self.telemetry.enabled:
             self.telemetry.metrics.counter("decode_scan_steps").inc(n_steps)
         return tokens, live, bc
 
     def decode_scan_async(self, bc, n_steps: int, eos: Optional[int] = None,
                           sample=None, allowed=None,
-                          max_position: Optional[int] = None):
+                          max_position: Optional[int] = None, counts=None):
         """One chained-stretch segment: ``n_steps`` decode steps with NO
         readback and NO host-side read of ``bc``.
 
@@ -800,29 +804,27 @@ class InferenceManager:
         self._decode_scan_guards(n_steps, max_position=max_position)
         if self.fault_injector is not None:
             self.fault_injector.maybe_fail("decode_scan")
-        prof = self.profiler
-        if prof.enabled:
-            prof.count("dispatches")
         with self.telemetry.span("decode_scan_dispatch", cat="dispatch",
-                                 track="dispatch",
-                                 n_steps=n_steps), prof.phase("dispatch"):
-            tokens, live, ecode, self.state, bc = self._scan(
-                self.params, self.state, bc, sample, self._page_view(),
-                allowed, n_steps=n_steps, eos=eos
-            )
+                                 track="dispatch", prof=self.profiler,
+                                 phase="dispatch", kind="decode_scan",
+                                 n_steps=n_steps, **(counts or {})):
+            tokens, live, ecode, self.state, bc = with_stack_room(
+                self._scan, self.params, self.state, bc, sample,
+                self._page_view(), allowed, n_steps=n_steps, eos=eos)
         if self.telemetry.enabled:
             self.telemetry.metrics.counter("decode_scan_steps").inc(n_steps)
         return tokens, live, ecode, bc
 
     def _join_impl(self, bc, tok_src, src_idx, dst, slot, pos, seq_len,
                    num_tokens, eos: Optional[int]):
-        tok = tok_src[src_idx]
-        active = True if eos is None else tok != eos
-        return bc.join_row(dst, tok, slot, pos, seq_len, num_tokens,
-                           active=active)
+        with jax.named_scope("join"):
+            tok = tok_src[src_idx]
+            active = True if eos is None else tok != eos
+            return bc.join_row(dst, tok, slot, pos, seq_len, num_tokens,
+                               active=active)
 
     def join_slot(self, bc, tok_src, src_idx, dst, slot, pos, seq_len,
-                  num_tokens, eos: Optional[int] = None):
+                  num_tokens, eos: Optional[int] = None, counts=None):
         """Splice one staged arrival into a running stretch's batch.
 
         ``tok_src``: the arrival's final prefill-chunk result tokens (a
@@ -833,12 +835,12 @@ class InferenceManager:
         (fixed avals — compiles once, polled by the recompile guard); the
         dispatched chain stays fully async.
         """
-        prof = self.profiler
-        if prof.enabled:
-            prof.count("dispatches")
-        with prof.phase("dispatch"):
-            return self._join(
-                bc, tok_src, jnp.int32(src_idx), jnp.int32(dst),
+        with self.telemetry.span("join_dispatch", cat="dispatch",
+                                 track="dispatch", prof=self.profiler,
+                                 phase="dispatch", kind="join", n_steps=1,
+                                 **(counts or {})):
+            return with_stack_room(
+                self._join, bc, tok_src, jnp.int32(src_idx), jnp.int32(dst),
                 jnp.int32(slot), jnp.int32(pos), jnp.int32(seq_len),
                 jnp.int32(num_tokens), eos=eos)
 
@@ -856,7 +858,8 @@ class InferenceManager:
         which is the guard if a future op lower or interpreter convention
         change makes the two paths diverge.
         """
-        from ..core.interpreter import _constrain_spmd, _mesh_is_trivial
+        from ..core.interpreter import (_constrain_spmd, _mesh_is_trivial,
+                                        node_scope)
         from ..core.op import OpContext
 
         e_step, n_step, a_step = self._overlap_steps
@@ -879,8 +882,9 @@ class InferenceManager:
                     "pages": None,
                 },
             )
-            [x] = step.node.op.lower(ctx, [x],
-                                     params.get(step.node.name, {}))
+            with jax.named_scope(node_scope(step.node)):
+                [x] = step.node.op.lower(ctx, [x],
+                                         params.get(step.node.name, {}))
             if not trivial:
                 x = _constrain_spmd(x, step.out_shardings[0], mesh)
         return a_step.node.op.project_qkv(
@@ -960,7 +964,7 @@ class InferenceManager:
             else (bcs, bcs_next, idx))
         return tokens, state
 
-    def prefill_scan(self, bcs, sample=None):
+    def prefill_scan(self, bcs, sample=None, counts=None):
         """Run a stacked PrefillBatchConfig (leading chunk axis) on device.
 
         ``sample``: optional ``(key, temperature, top_p)`` so the chunks
@@ -969,18 +973,17 @@ class InferenceManager:
         assert self.params is not None, "call init_operators_inference() first"
         if self.fault_injector is not None:
             self.fault_injector.maybe_fail("prefill_scan")
-        prof = self.profiler
-        if prof.enabled:
-            prof.count("dispatches")
+        n_chunks = int(bcs.base.tokens.shape[0])
         with self.telemetry.span("prefill_scan_dispatch", cat="dispatch",
-                                 track="dispatch",
-                                 n_chunks=int(bcs.base.tokens.shape[0])), \
-                prof.phase("dispatch"):
-            tokens, self.state = self._pscan(
-                self.params, self.state, bcs, sample, self._page_view(),
+                                 track="dispatch", prof=self.profiler,
+                                 phase="dispatch", kind="prefill_scan",
+                                 n_steps=n_chunks, n_chunks=n_chunks,
+                                 **(counts or {})):
+            tokens, self.state = with_stack_room(
+                self._pscan, self.params, self.state, bcs, sample,
+                self._page_view(),
                 overlap=bool(self.prefill_overlap
-                             and self._overlap_steps is not None),
-            )
+                             and self._overlap_steps is not None))
         return tokens
 
     def reset(self):

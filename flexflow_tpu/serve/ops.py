@@ -312,34 +312,40 @@ class IncMultiHeadSelfAttention(Op):
         else:
             q, k, v = self.project_qkv(x, params, bc)
 
-        if isinstance(bc, TreeVerifyBatchConfig):
-            state = self._commit(state, bc,
-                                 ctx.extras.get("pages") if ctx else None)
-            out, state = self._tree_attend(q, k, v, state, bc, ctx)
-        elif isinstance(bc, TreeSearchBatchConfig):
-            out, state = self._tree_attend(q, k, v, state, bc, ctx)
-        elif isinstance(bc, PrefillBatchConfig):
-            out, state = self._prefill_attend(q, k, v, state, bc, ctx)
-        else:
-            out, state = self._inc_attend(q, k, v, state, bc, ctx)
+        # device-trace scopes: ``attend`` around the attention proper, and
+        # inside it ``kv_write`` around every cache write (_write_kv, the
+        # prefill path's block writes), so a trace tells the write's time
+        # — copies the compiler inserts for it included — from the kernel's
+        with jax.named_scope("attend"):
+            if isinstance(bc, TreeVerifyBatchConfig):
+                state = self._commit(state, bc,
+                                     ctx.extras.get("pages") if ctx else None)
+                out, state = self._tree_attend(q, k, v, state, bc, ctx)
+            elif isinstance(bc, TreeSearchBatchConfig):
+                out, state = self._tree_attend(q, k, v, state, bc, ctx)
+            elif isinstance(bc, PrefillBatchConfig):
+                out, state = self._prefill_attend(q, k, v, state, bc, ctx)
+            else:
+                out, state = self._inc_attend(q, k, v, state, bc, ctx)
 
         ctx.extras["state_out"] = state
         # [T, QH, D] -> [T, QH*D] -> o_proj (row-parallel under TP)
         t = out.shape[0]
-        o_w = params["o_proj"]
-        if o_w.dtype == jnp.int8:  # weight-only int8 (serve/quant.py)
-            from .quant import dequant
+        with jax.named_scope("o_proj"):
+            o_w = params["o_proj"]
+            if o_w.dtype == jnp.int8:  # weight-only int8 (serve/quant.py)
+                from .quant import dequant
 
-            o_w = dequant(o_w, params["o_proj_scale"], out.dtype)
-        y = jnp.dot(
-            out.reshape(t, self.num_q_heads * self.head_dim),
-            o_w,
-            preferred_element_type=jnp.float32,
-        )
-        if self.use_bias:
-            head = tuple(ctx.config.get("head", ())) if ctx.config else ()
-            y = y + bias_once(params["o_bias"], head, ctx)
-        return [y.astype(self.dtype)]
+                o_w = dequant(o_w, params["o_proj_scale"], out.dtype)
+            y = jnp.dot(
+                out.reshape(t, self.num_q_heads * self.head_dim),
+                o_w,
+                preferred_element_type=jnp.float32,
+            )
+            if self.use_bias:
+                head = tuple(ctx.config.get("head", ())) if ctx.config else ()
+                y = y + bias_once(params["o_bias"], head, ctx)
+            return [y.astype(self.dtype)]
 
     def project_qkv(self, x, params, bc):
         """QKV projection (+ dequant + RoPE) for a step's flat tokens.
@@ -349,12 +355,13 @@ class IncMultiHeadSelfAttention(Op):
         chunk's layer-0 projection inside the current scan step — one
         code path, so the pipelined and plain scans stay bit-identical.
         """
-        qkv_w = params["qkv"]
-        if qkv_w.dtype == jnp.int8:  # weight-only int8 (serve/quant.py)
-            from .quant import dequant
+        with jax.named_scope("qkv_proj"):
+            qkv_w = params["qkv"]
+            if qkv_w.dtype == jnp.int8:  # weight-only int8 (serve/quant.py)
+                from .quant import dequant
 
-            qkv_w = dequant(qkv_w, params["qkv_scale"], x.dtype)
-        return self._project(x, qkv_w, params.get("qkv_bias"), bc)
+                qkv_w = dequant(qkv_w, params["qkv_scale"], x.dtype)
+            return self._project(x, qkv_w, params.get("qkv_bias"), bc)
 
     def _project(self, x, qkv_w, qkv_b, bc):
         base = bc.base if not isinstance(bc, BatchConfig) else bc
@@ -471,6 +478,7 @@ class IncMultiHeadSelfAttention(Op):
             )
         return cache
 
+    @jax.named_scope("kv_write")
     def _write_kv(self, state, rows, pos, k, v, pages=None):
         """Write this step's K/V vectors into the committed caches,
         quantizing on write when the caches are int8.  Returns the updated
@@ -705,65 +713,66 @@ class IncMultiHeadSelfAttention(Op):
         # row nreq (the largest index), so min() recovers the tile's request
         tile_rows = jnp.min(rows.reshape(g, bq), axis=1)
         pstart = pos.reshape(g, bq)[:, 0]
-        if pages is not None:
-            # physical coordinates for the per-tile block DUS: a tile sits
-            # inside ONE page (tile-aligned start, tile divides page — the
-            # manager validates page % prefill_tile == 0), so translating
-            # the tile's start translates the whole block
-            w_rows, w_start = _page_rows_pos(pages, tile_rows, pstart)
-        else:
-            w_rows, w_start = tile_rows, pstart
-        # KV-cache write as G per-tile BLOCK dynamic-update-slices instead of
-        # a flat-token scatter: a prefill chunk carries max_tokens (>
-        # DUS_MAX_TOKENS) tokens, so _scatter_rows_pos would take the XLA
-        # scatter path — whose layout choice forces a full-cache relayout
-        # copy per prefill_scan step (the same hazard _scatter_rows_pos
-        # documents for the decode scan, ~2x the chunk's whole HBM traffic
-        # at the 7B bench shape).  PrefillBatchConfig's contract makes the
-        # block write exact for real tokens: tile g is one request, its
-        # positions contiguous from a TILE-ALIGNED pstart (RequestManager
-        # only advances prefill_offset by whole tiles until completion), so
-        # the DUS start is never clamp-shifted.  Tail-pad slots write ZEROS
-        # at the request's next positions (junk-free: fresh caches are
-        # zeros, so the tiled and flat paths stay bit-identical); even a
-        # non-zero value there would be benign, since every future step
-        # WRITES position p before any token's causal frontier reaches p
-        # (the scratch-row behavior of fully-pad tiles is unchanged: min()
-        # maps them to row nreq).
-        if kv_q:
-            # quantize-on-write: the int8 VALUES ride the same per-tile
-            # block DUS as the fp path; the per-(token, head) scales ride a
-            # matching [1, KV, bq] block DUS into the scale caches.  Tile
-            # pads write value 0 AND scale 0, so they dequantize to the
-            # zeros the fp path writes (the tiled/flat bit-identity note
-            # above carries over to the quantized representation).
-            k, ks = self._kv_quant(k)   # int8 [T, KV, D], f32 [T, KV]
-            v, vs = self._kv_quant(v)
-            ksc, vsc = state["k_scale"], state["v_scale"]  # [R+1, KV, S]
-            valid_s = (base.request_index >= 0).reshape(g, 1, bq)
-            ksb = jnp.where(
-                valid_s, ks.reshape(g, bq, self.num_kv_heads)
-                .transpose(0, 2, 1), 0.0)
-            vsb = jnp.where(
-                valid_s, vs.reshape(g, bq, self.num_kv_heads)
-                .transpose(0, 2, 1), 0.0)
-        valid = (base.request_index >= 0).reshape(g, 1, bq, 1)
-        kb = k.reshape(g, bq, self.num_kv_heads, self.head_dim) \
-             .transpose(0, 2, 1, 3).astype(kc.dtype)
-        vb = v.reshape(g, bq, self.num_kv_heads, self.head_dim) \
-             .transpose(0, 2, 1, 3).astype(vc.dtype)
-        kb = jnp.where(valid, kb, 0)
-        vb = jnp.where(valid, vb, 0)
-        zero = jnp.int32(0)
-        for i in range(g):
-            at = (w_rows[i], zero, w_start[i], zero)
-            kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
-            vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
+        with jax.named_scope("kv_write"):
+            if pages is not None:
+                # physical coordinates for the per-tile block DUS: a tile sits
+                # inside ONE page (tile-aligned start, tile divides page — the
+                # manager validates page % prefill_tile == 0), so translating
+                # the tile's start translates the whole block
+                w_rows, w_start = _page_rows_pos(pages, tile_rows, pstart)
+            else:
+                w_rows, w_start = tile_rows, pstart
+            # KV-cache write as G per-tile BLOCK dynamic-update-slices instead of
+            # a flat-token scatter: a prefill chunk carries max_tokens (>
+            # DUS_MAX_TOKENS) tokens, so _scatter_rows_pos would take the XLA
+            # scatter path — whose layout choice forces a full-cache relayout
+            # copy per prefill_scan step (the same hazard _scatter_rows_pos
+            # documents for the decode scan, ~2x the chunk's whole HBM traffic
+            # at the 7B bench shape).  PrefillBatchConfig's contract makes the
+            # block write exact for real tokens: tile g is one request, its
+            # positions contiguous from a TILE-ALIGNED pstart (RequestManager
+            # only advances prefill_offset by whole tiles until completion), so
+            # the DUS start is never clamp-shifted.  Tail-pad slots write ZEROS
+            # at the request's next positions (junk-free: fresh caches are
+            # zeros, so the tiled and flat paths stay bit-identical); even a
+            # non-zero value there would be benign, since every future step
+            # WRITES position p before any token's causal frontier reaches p
+            # (the scratch-row behavior of fully-pad tiles is unchanged: min()
+            # maps them to row nreq).
             if kv_q:
-                ksc = jax.lax.dynamic_update_slice(
-                    ksc, ksb[i][None], at[:3])
-                vsc = jax.lax.dynamic_update_slice(
-                    vsc, vsb[i][None], at[:3])
+                # quantize-on-write: the int8 VALUES ride the same per-tile
+                # block DUS as the fp path; the per-(token, head) scales ride a
+                # matching [1, KV, bq] block DUS into the scale caches.  Tile
+                # pads write value 0 AND scale 0, so they dequantize to the
+                # zeros the fp path writes (the tiled/flat bit-identity note
+                # above carries over to the quantized representation).
+                k, ks = self._kv_quant(k)   # int8 [T, KV, D], f32 [T, KV]
+                v, vs = self._kv_quant(v)
+                ksc, vsc = state["k_scale"], state["v_scale"]  # [R+1, KV, S]
+                valid_s = (base.request_index >= 0).reshape(g, 1, bq)
+                ksb = jnp.where(
+                    valid_s, ks.reshape(g, bq, self.num_kv_heads)
+                    .transpose(0, 2, 1), 0.0)
+                vsb = jnp.where(
+                    valid_s, vs.reshape(g, bq, self.num_kv_heads)
+                    .transpose(0, 2, 1), 0.0)
+            valid = (base.request_index >= 0).reshape(g, 1, bq, 1)
+            kb = k.reshape(g, bq, self.num_kv_heads, self.head_dim) \
+                 .transpose(0, 2, 1, 3).astype(kc.dtype)
+            vb = v.reshape(g, bq, self.num_kv_heads, self.head_dim) \
+                 .transpose(0, 2, 1, 3).astype(vc.dtype)
+            kb = jnp.where(valid, kb, 0)
+            vb = jnp.where(valid, vb, 0)
+            zero = jnp.int32(0)
+            for i in range(g):
+                at = (w_rows[i], zero, w_start[i], zero)
+                kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
+                vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
+                if kv_q:
+                    ksc = jax.lax.dynamic_update_slice(
+                        ksc, ksb[i][None], at[:3])
+                    vsc = jax.lax.dynamic_update_slice(
+                        vsc, vsb[i][None], at[:3])
         scales = (ksc, vsc) if kv_q else ()
         pg = (pages.table,) if pages is not None else ()
         pg_size = pages.page_size if pages is not None else 0

@@ -578,18 +578,21 @@ class PipelinedInferenceManager:
         res = None
         n = len(self.stages)
         for s, stage in enumerate(self.stages):
-            with tel.span("stage_dispatch", cat="pp", track=f"stage{s}",
-                          stage=s, mb=mb), prof.phase(f"stage{s}"):
-                if fi is not None:
-                    fi.maybe_fail(f"stage{s}_dispatch")
+            # injected faults fire BEFORE the launch span: a launch that
+            # never happened is neither timed nor counted as a dispatch
+            if fi is not None:
+                fi.maybe_fail(f"stage{s}_dispatch")
                 if s > 0:
-                    if fi is not None:
-                        fi.maybe_fail(f"stage{s}_hop")
+                    fi.maybe_fail(f"stage{s}_hop")
+            with tel.span("stage_dispatch", cat="pp", track=f"stage{s}",
+                          prof=prof, phase=f"stage{s}", stage=s, mb=mb):
+                if s > 0:
                     tel.instant("stage_hop", cat="pp", track=f"stage{s}",
                                 stage=s, mb=mb)
                     if tel.enabled:
                         tel.metrics.counter("pp_hops").inc()
-                    with prof.phase("hop"):
+                    with tel.span("hop", cat="pp", track="hop", prof=prof,
+                                  stage=s, mb=mb):
                         # the whole hop ships as ONE batched transfer —
                         # batch descriptor, page table and boundary
                         # activations in a single pytree device_put (one
@@ -600,8 +603,6 @@ class PipelinedInferenceManager:
                 else:
                     bc_s, pg_s = jax.device_put((bc, pages),
                                                 stage.replicated)
-                if prof.enabled:
-                    prof.count("dispatches")
                 if s < n - 1:
                     xs, stage.state = stage.step(stage.params, stage.state,
                                                  bc_s, xs, None, pg_s)
@@ -625,7 +626,7 @@ class PipelinedInferenceManager:
             cat([r.topk_logprobs for r in results]),
         )
 
-    def step(self, bc, sample=None) -> InferenceResult:
+    def step(self, bc, sample=None, counts=None) -> InferenceResult:
         """Run one serving macro-step: ``n_micro`` interleaved micro-batches
         through the stage chain (async dispatch; stage s runs micro-batch j
         while stage s-1 runs j+1).  Caches update in place per stage."""
@@ -640,8 +641,10 @@ class PipelinedInferenceManager:
             tel.metrics.gauge("pp_bubble_frac").set(
                 max(0, self.pp - len(mbs)) / self.pp)
         pv = self._page_view()
+        # ``counts``: the caller's launch bookkeeping (see
+        # InferenceManager.step) rides the macro-step span
         with tel.span("pp_macro_step", cat="pp", track="pp",
-                      n_micro=len(mbs)):
+                      n_micro=len(mbs), kind="step", **(counts or {})):
             results = []
             k = self.max_tokens // max(len(mbs), 1)
             for j, mbc in enumerate(mbs):
@@ -705,22 +708,22 @@ class PipelinedInferenceManager:
                            active=active)
 
     def join_slot(self, bc, tok_src, src_idx, dst, slot, pos, seq_len,
-                  num_tokens, eos=None):
+                  num_tokens, eos=None, counts=None):
         """Splice a mid-stretch arrival into the running (device-resident)
         batch — same contract as InferenceManager.join_slot; the join
         program runs on the last stage's mesh, where the chained scan's
         BatchConfig lives."""
-        prof = self.profiler
-        if prof.enabled:
-            prof.count("dispatches")
-        with prof.phase("dispatch"):
+        with self.telemetry.span("join_dispatch", cat="dispatch",
+                                 track="dispatch", prof=self.profiler,
+                                 phase="dispatch", kind="join", n_steps=1,
+                                 **(counts or {})):
             return self._join(
                 bc, tok_src, jnp.int32(src_idx), jnp.int32(dst),
                 jnp.int32(slot), jnp.int32(pos), jnp.int32(seq_len),
                 jnp.int32(num_tokens), eos=eos)
 
     def decode_scan(self, bc, n_steps: int, eos: Optional[int] = None,
-                    sample=None):
+                    sample=None, counts=None):
         """``n_steps`` pure-decode macro-steps, host-dispatched but never
         host-synced: each micro-batch's next BatchConfig derives on device
         (``_advance_impl``) and flows back to stage 0, so the host only
@@ -751,7 +754,8 @@ class PipelinedInferenceManager:
         pv = self._page_view()
         for i in range(n_steps):
             with tel.span("pp_decode_macro_step", cat="pp", track="pp",
-                          step=i, n_micro=m):
+                          step=i, n_micro=m, kind="decode_scan",
+                          **(counts or {})):
                 for j in range(m):
                     smp = None
                     if sample is not None:
@@ -780,7 +784,8 @@ class PipelinedInferenceManager:
         return tokens, live_np, bc_out
 
     def decode_scan_async(self, bc, n_steps: int, eos: Optional[int] = None,
-                          sample=None, allowed=None, max_position=None):
+                          sample=None, allowed=None, max_position=None,
+                          counts=None):
         """``n_steps`` pure-decode macro-steps with NOTHING materialized:
         returns LAZY device values — ``(tokens [n, max_tokens], live
         masks, per-row exit codes, advanced BatchConfig)`` — so a chained
@@ -842,7 +847,8 @@ class PipelinedInferenceManager:
         pv = self._page_view()
         for i in range(n_steps):
             with tel.span("pp_decode_macro_step", cat="pp", track="pp",
-                          step=i, n_micro=m):
+                          step=i, n_micro=m, kind="decode_scan",
+                          **(counts or {})):
                 for j in range(m):
                     smp = None
                     if sample is not None:

@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -193,6 +194,8 @@ class RequestManager:
         self._next_rid = 0
         self.steps = 0
         self.tokens_decoded = 0
+        # dispatch-span arguments of the batch _build_next_batch made last
+        self._step_counts: Optional[Dict[str, int]] = None
         self.scan_runs = 0      # decode stretches run as on-device scans
         # ONE Telemetry handle across the serving stack: syncing it onto the
         # InferenceManager (which forwards to pipeline stages) puts request
@@ -313,6 +316,30 @@ class RequestManager:
             if getattr(plan_health, "brownout", None) is None:
                 plan_health.brownout = self.brownout
 
+    def _span(self, name: str, phase: bool = False, **args):
+        """A scheduler span below the tick (obs/trace.py ``Span``): always
+        a profiler annotation, a ring event on the ``host`` track when
+        telemetry is on, and — ``phase`` — the StepProfiler phase of the
+        same name.  ``args`` are ints the scheduler already holds."""
+        return self.telemetry.span(
+            name, cat="host", track="host",
+            prof=self.profiler if phase else None, **args)
+
+    @staticmethod
+    def _launch_counts(spans, n_decode: int) -> Dict[str, int]:
+        """Dispatch-span arguments of one flat step from its cache-write
+        spans ``[(rid, lo, hi)]``, the ``n_decode`` decode rows first:
+        ``ctx_sum`` is the decode rows' KV lengths, ``prompt_ctx_sum`` the
+        prompt rows' (token at position p attends p + 1 entries)."""
+        dec, pre = spans[:n_decode], spans[n_decode:]
+        return {
+            "rows": n_decode,
+            "prompt_tokens": sum(hi - lo for _, lo, hi in pre),
+            "ctx_sum": sum(hi for _, _, hi in dec),
+            "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
+                                  for _, lo, hi in pre),
+        }
+
     @staticmethod
     def _fold_for(req: Request) -> Tuple[int, int]:
         """THE per-request sample-key fold: (rid, index of the token about
@@ -346,13 +373,14 @@ class RequestManager:
         import jax
         import jax.numpy as jnp
 
-        folds = np.zeros((n_rows, 2), np.int32)
-        for row, rid, *off in points:
-            rid_fold, idx = self._fold_for(self.requests[rid])
-            folds[row] = (rid_fold, idx + (off[0] if off else 0))
-        return (jax.random.PRNGKey(self.gen.seed),
-                jnp.float32(self.gen.temperature),
-                jnp.float32(self.gen.top_p), jnp.asarray(folds))
+        with self._span("sample_for"):
+            folds = np.zeros((n_rows, 2), np.int32)
+            for row, rid, *off in points:
+                rid_fold, idx = self._fold_for(self.requests[rid])
+                folds[row] = (rid_fold, idx + (off[0] if off else 0))
+            return (jax.random.PRNGKey(self.gen.seed),
+                    jnp.float32(self.gen.temperature),
+                    jnp.float32(self.gen.top_p), jnp.asarray(folds))
 
     # ------------------------------------------------------------------
     def _seq_len_needed(self, req: Request) -> int:
@@ -1055,9 +1083,9 @@ class RequestManager:
         accumulators, so the time budget shows scheduling cost apart from
         batch-build cost.
         """
-        with self.profiler.phase("host_admit"):
+        with self._span("host_admit", phase=True):
             self._admit()
-        with self.profiler.phase("host_prepare"):
+        with self._span("host_prepare", phase=True):
             return self._build_next_batch()
 
     def _build_next_batch(self) -> Tuple[BatchConfig, List[Tuple[int, int]]]:
@@ -1135,6 +1163,7 @@ class RequestManager:
             self._prof_account(
                 spans, logit_rows=len(sample_points) if gate else None)
             self._note_batch(0, sum(len(s[1]) for s in segments), seq_lens)
+            self._step_counts = self._launch_counts(spans, 0)
             return pbc, sample_points
 
         # then prefill chunks fill the remaining budget.  Mid-prompt cuts
@@ -1212,6 +1241,7 @@ class RequestManager:
         self._kv_prepare(spans)
         self._prof_account(spans)
         self._note_batch(n_decode, len(tokens) - n_decode, seq_lens)
+        self._step_counts = self._launch_counts(spans, n_decode)
         return bc, sample_points
 
     def _note_batch(self, n_decode: int, n_prefill: int, seq_lens) -> None:
@@ -1249,25 +1279,28 @@ class RequestManager:
             # mid-prefill step: nothing to read back — leave the result on
             # device so chunked prefill dispatches stay fully async
             return
-        prof = self.profiler
-        with prof.phase("readback"):
+        with self._span("readback", phase=True):
             token_ids = np.asarray(result.token_ids)
-        prof.host_sync()
-        for flat_idx, rid in sample_points:
-            req = self.requests[rid]
-            if req.status not in (RequestStatus.PREFILLING,
-                                  RequestStatus.DECODING):
-                # the request left its slot between batch build and result
-                # readback (page-pressure preemption in _kv_prepare runs
-                # AFTER the batch is built): its emission is dead — the
-                # readmission recomputes it, and appending here would
-                # double-count the token in the recompute feed
-                continue
-            tok = int(token_ids[flat_idx])
-            if req.status is RequestStatus.PREFILLING:
-                req.status = RequestStatus.DECODING
-            self._append_token(req, tok)
-            self._maybe_finish(req)
+        self.profiler.host_sync()
+        with self._span("commit") as sp:
+            before = self.tokens_decoded
+            for flat_idx, rid in sample_points:
+                req = self.requests[rid]
+                if req.status not in (RequestStatus.PREFILLING,
+                                      RequestStatus.DECODING):
+                    # the request left its slot between batch build and
+                    # result readback (page-pressure preemption in
+                    # _kv_prepare runs AFTER the batch is built): its
+                    # emission is dead — the readmission recomputes it, and
+                    # appending here would double-count the token in the
+                    # recompute feed
+                    continue
+                tok = int(token_ids[flat_idx])
+                if req.status is RequestStatus.PREFILLING:
+                    req.status = RequestStatus.DECODING
+                self._append_token(req, tok)
+                self._maybe_finish(req)
+            sp.set(step_tokens=self.tokens_decoded - before)
 
     def _maybe_finish(self, req: Request) -> None:
         eos = self.gen.eos_token_id
@@ -1397,7 +1430,7 @@ class RequestManager:
         ONE host sync at the end, vs a dispatch per chunk (+ a host sync
         per request boundary) on the per-step path.
         """
-        with self.profiler.phase("host_admit"):
+        with self._span("host_admit", phase=True):
             self._admit()
         active = self._active()
         tile = getattr(self.im, "prefill_tile", 1)
@@ -1411,24 +1444,14 @@ class RequestManager:
             and all(r.prefill_offset % tile == 0 for r in active)
         )
 
-    def _prefill_stretch(self) -> None:
-        """Prefill every active request's remaining feed via prefill_scan."""
-        import jax
-        import jax.numpy as jnp
-
+    def _prefill_chunks(self, gate: bool, sampling: bool):
+        """Cut every prefilling request's remaining feed into tile-aligned
+        chunks: per-chunk numpy fields, logit slots, sample folds, the
+        sample points ``(chunk_idx, result_idx, rid)`` and each chunk's
+        ``(start, take)``.  Advances ``prefill_offset``."""
         im = self.im
         tile = im.prefill_tile
         cap = im.max_tokens
-        # the whole stretch's write spans, prepared before the first
-        # dispatch (the scans run back-to-back with no host boundary to
-        # map pages at)
-        self._kv_prepare([
-            (r.rid, r.prefill_offset, len(r.prefill_tokens))
-            for r in self._active()
-            if r.status is RequestStatus.PREFILLING
-            and r.prefill_offset < len(r.prefill_tokens)])
-        gate = bool(getattr(im, "gate_lm_head", False))
-        sampling = self.gen.temperature > 0.0
         n_rows = im.max_requests if gate else cap
         chunks: List = []  # per-chunk numpy field tuples (BatchConfig order)
         ls_chunks: List = []  # per-chunk logit_slots (gated path)
@@ -1436,6 +1459,7 @@ class RequestManager:
         # (chunk_idx, result_idx, rid): result_idx is the SLOT when gated
         # (result arrays are [max_requests]), the flat token index otherwise
         points: List[Tuple[int, int, int]] = []
+        feeds: List[Tuple[int, int]] = []  # per-chunk (start, take)
         seq = np.zeros(im.max_requests, np.int32)
         for req in self._active():
             seq[req.slot] = req.seq_len
@@ -1472,33 +1496,67 @@ class RequestManager:
                 ls_chunks.append(PrefillBatchConfig.np_logit_slots(
                     [req.slot] if done else [], last_flat, im.max_requests))
                 chunks.append(fields)
+                feeds.append((start, take))
+        return chunks, ls_chunks, fold_chunks, points, feeds
+
+    def _prefill_stretch(self) -> None:
+        """Prefill every active request's remaining feed via prefill_scan."""
+        import jax
+        import jax.numpy as jnp
+
+        im = self.im
+        tile = im.prefill_tile
+        # the whole stretch's write spans, prepared before the first
+        # dispatch (the scans run back-to-back with no host boundary to
+        # map pages at)
+        self._kv_prepare([
+            (r.rid, r.prefill_offset, len(r.prefill_tokens))
+            for r in self._active()
+            if r.status is RequestStatus.PREFILLING
+            and r.prefill_offset < len(r.prefill_tokens)])
+        gate = bool(getattr(im, "gate_lm_head", False))
+        sampling = self.gen.temperature > 0.0
+        with self._span("host_prepare", phase=True):
+            chunks, ls_chunks, fold_chunks, points, feeds = \
+                self._prefill_chunks(gate, sampling)
         # stack chunk fields host-side (ONE device transfer per field per
         # segment, not five tiny transfers per chunk) and scan in power-of-
         # two segments so each distinct scan length compiles at most once
-        outs = []   # (start_chunk, token array [seg, cap]) — read after all
+        outs = []   # (start_chunk, token array [seg, max_tokens]) — read after
         at = 0
         while at < len(chunks):
             seg = 1 << (min(len(chunks) - at, 64).bit_length() - 1)
-            stacked = PrefillBatchConfig(
-                base=BatchConfig(*(
-                    jnp.asarray(np.stack([c[i] for c in chunks[at: at + seg]]))
-                    for i in range(5)
-                )),
-                tile_size=tile,
-                logit_slots=jnp.asarray(np.stack(ls_chunks[at: at + seg]))
-                if gate else None,
-            )
-            smp = None
-            if sampling:
-                # per-request key schedule: the chunk carrying request rid's
-                # completion samples its token n with fold (rid, n) — same
-                # key whatever chunking/segmentation/preemption produced it
-                smp = (jax.random.PRNGKey(self.gen.seed),
-                       jnp.float32(self.gen.temperature),
-                       jnp.float32(self.gen.top_p),
-                       jnp.asarray(np.stack(fold_chunks[at: at + seg])))
+            with self._span("host_prepare", phase=True):
+                stacked = PrefillBatchConfig(
+                    base=BatchConfig(*(
+                        jnp.asarray(np.stack(
+                            [c[i] for c in chunks[at: at + seg]]))
+                        for i in range(5)
+                    )),
+                    tile_size=tile,
+                    logit_slots=jnp.asarray(
+                        np.stack(ls_chunks[at: at + seg]))
+                    if gate else None,
+                )
+                smp = None
+                if sampling:
+                    # per-request key schedule: the chunk carrying request
+                    # rid's completion samples its token n with fold
+                    # (rid, n) — same key whatever chunking/segmentation/
+                    # preemption produced it
+                    smp = (jax.random.PRNGKey(self.gen.seed),
+                           jnp.float32(self.gen.temperature),
+                           jnp.float32(self.gen.top_p),
+                           jnp.asarray(np.stack(fold_chunks[at: at + seg])))
+            # each chunk's start offset and size: what the prefill
+            # kernel's least work is computed from
+            cnt = {"rows": 0,
+                   "prompt_tokens": sum(t for _, t in feeds[at: at + seg]),
+                   "ctx_sum": sum(st for st, _ in feeds[at: at + seg])}
             res = self._guarded(
-                "prefill_scan", lambda s=stacked, a=smp: im.prefill_scan(s, a))
+                "prefill_scan",
+                lambda s=stacked, a=smp, c=cnt: im.prefill_scan(
+                    s, a, counts=c))
             if res is None:
                 # dispatch failed past the retry budget: _fail_inflight
                 # already requeued/failed every prefilling request (their
@@ -1509,17 +1567,18 @@ class RequestManager:
                 return
             outs.append((at, res))
             at += seg
-        with self.profiler.phase("readback"):
+        with self._span("readback", phase=True):
             toks = {start: np.asarray(t) for start, t in outs}  # one sync
         self.profiler.host_sync(len(outs))
         starts = sorted(toks)
-        for chunk_idx, flat_idx, rid in points:
-            start = max(s for s in starts if s <= chunk_idx)
-            req = self.requests[rid]
-            req.status = RequestStatus.DECODING
-            self._append_token(req,
-                               int(toks[start][chunk_idx - start, flat_idx]))
-            self._maybe_finish(req)
+        with self._span("commit", prefill_tokens=len(points)):
+            for chunk_idx, flat_idx, rid in points:
+                start = max(s for s in starts if s <= chunk_idx)
+                req = self.requests[rid]
+                req.status = RequestStatus.DECODING
+                self._append_token(
+                    req, int(toks[start][chunk_idx - start, flat_idx]))
+                self._maybe_finish(req)
         self.steps += len(chunks)
         self.scan_runs += 1
 
@@ -1568,7 +1627,7 @@ class RequestManager:
         rows: List[Tuple[Request, int]] = []   # (req, flat row) in order
         sched: Dict[int, int] = {}    # rid -> tokens produced this stretch
         dev_seq: Dict[int, int] = {}  # rid -> device-side cache depth
-        with prof.phase("host_prepare"):
+        with self._span("host_prepare", phase=True):
             tokens, reqi, pos = [], [], []
             for req in active:
                 tokens.append(req.generated[-1])
@@ -1597,38 +1656,44 @@ class RequestManager:
         n_joins = 0
         seg = n
         while True:
-            ks: Dict[int, int] = {}
-            allowed = np.zeros(im.max_tokens, np.int32)
-            pts = []
-            for req, flat in rows:
-                k = max(min(seg, remaining(req)), 0)
-                ks[req.rid] = k
-                # the emission budget is the row's FULL remaining, not
-                # the segment cap: a row that outlives this segment must
-                # end it alive so its exit code reads RUNNING, not BUDGET
-                allowed[flat] = max(remaining(req), 0)
-                pts.append((flat, req.rid, sched[req.rid]))
-            if prof.enabled:
-                # k_i decode steps per row: each streams the weights and
-                # reads the growing causally-live prefix
-                prof.account(
-                    prof.card_for(im),
-                    [(req.rid, ks[req.rid],
-                      ks[req.rid] * dev_seq[req.rid]
-                      + ks[req.rid] * (ks[req.rid] - 1) // 2)
-                     for req, _ in rows if ks[req.rid] > 0],
-                    passes=seg)
+            with self._span("host_prepare", phase=True):
+                ks: Dict[int, int] = {}
+                allowed = np.zeros(im.max_tokens, np.int32)
+                pts = []
+                cnt = {"rows": 0, "prompt_tokens": 0, "ctx_sum": 0}
+                for req, flat in rows:
+                    k = max(min(seg, remaining(req)), 0)
+                    ks[req.rid] = k
+                    # the emission budget is the row's FULL remaining, not
+                    # the segment cap: a row that outlives this segment
+                    # must end it alive so its exit code reads RUNNING,
+                    # not BUDGET
+                    allowed[flat] = max(remaining(req), 0)
+                    pts.append((flat, req.rid, sched[req.rid]))
+                    if k > 0:   # a live row, and its KV length at launch
+                        cnt["rows"] += 1
+                        cnt["ctx_sum"] += dev_seq[req.rid]
+                if prof.enabled:
+                    # k_i decode steps per row: each streams the weights
+                    # and reads the growing causally-live prefix
+                    prof.account(
+                        prof.card_for(im),
+                        [(req.rid, ks[req.rid],
+                          ks[req.rid] * dev_seq[req.rid]
+                          + ks[req.rid] * (ks[req.rid] - 1) // 2)
+                         for req, _ in rows if ks[req.rid] > 0],
+                        passes=seg)
+                max_pos = max(dev_seq[req.rid] - 1 + ks[req.rid]
+                              for req, _ in rows) - seg
             # sample folds advance past the stretch's UNCOMMITTED tokens:
             # row i's next key is (rid_i, len(generated_i) + sched_i)
             smp = self._sample_for(pts, im.max_tokens)
-            max_pos = max(dev_seq[req.rid] - 1 + ks[req.rid]
-                          for req, _ in rows) - seg
             this_seg = seg
             out = self._guarded(
                 "decode_scan",
                 lambda: im.decode_scan_async(
                     bc, this_seg, eos=eos, sample=smp,
-                    allowed=allowed, max_position=max_pos))
+                    allowed=allowed, max_position=max_pos, counts=cnt))
             if out is None:
                 # the whole stretch's emissions were in flight and nothing
                 # was committed: the requeue recompute regenerates every
@@ -1653,7 +1718,7 @@ class RequestManager:
                    for r in reqs):
                 break   # a clock-callback preempted/terminated a row
             if self._arrival_pump is not None:
-                with prof.phase("host_admit"):
+                with self._span("host_admit", phase=True):
                     self._arrival_pump()   # register newly-due arrivals
             rem_cap = self.scan_chunk - total
             if rem_cap < 2:
@@ -1688,7 +1753,7 @@ class RequestManager:
                 break   # page pressure resolves on the per-tick path
 
         # ---- single readback + chronological commit -------------------
-        with prof.phase("readback"):
+        with self._span("readback", phase=True):
             ready = []
             for item in commits:
                 if item[0] == "scan":
@@ -1701,31 +1766,39 @@ class RequestManager:
                                   int(np.asarray(token_ids)[src])))
         prof.host_sync()
         codes: Dict[int, int] = {}
-        for item in ready:
-            if item[0] == "join":
-                _, req, tok = item
-                if req.status not in (RequestStatus.PREFILLING,
-                                      RequestStatus.DECODING):
-                    continue   # left its slot before commit: emission is
-                               # dead, the readmission recomputes it
-                if req.status is RequestStatus.PREFILLING:
-                    req.status = RequestStatus.DECODING
-                self._append_token(req, tok)
-                self._maybe_finish(req)
-                continue
-            _, sg, pts2, toks, live, ecode = item
-            for s in range(sg):
-                for flat, rid in pts2:
-                    req = self.requests[rid]
-                    if (req.status is not RequestStatus.DECODING
-                            or not live[s, flat]):
-                        continue
-                    self._append_token(req, int(toks[s, flat]))
+        # which program made each token the host now appends: the decode
+        # scan, or a joiner's flat prefill spliced in by the join
+        made = {"scan": 0, "join": 0}
+        with self._span("commit") as sp:
+            for item in ready:
+                before = self.tokens_decoded
+                if item[0] == "join":
+                    _, req, tok = item
+                    if req.status not in (RequestStatus.PREFILLING,
+                                          RequestStatus.DECODING):
+                        continue   # left its slot before commit: emission
+                                   # is dead, the readmission recomputes it
+                    if req.status is RequestStatus.PREFILLING:
+                        req.status = RequestStatus.DECODING
+                    self._append_token(req, tok)
                     self._maybe_finish(req)
-            for flat, rid in pts2:
-                c = int(ecode[flat])
-                if c != EXIT_NOT_IN_BATCH:
-                    codes[rid] = c   # the segment where the row ran last
+                    made["join"] += self.tokens_decoded - before
+                    continue
+                _, sg, pts2, toks, live, ecode = item
+                for s in range(sg):
+                    for flat, rid in pts2:
+                        req = self.requests[rid]
+                        if (req.status is not RequestStatus.DECODING
+                                or not live[s, flat]):
+                            continue
+                        self._append_token(req, int(toks[s, flat]))
+                        self._maybe_finish(req)
+                for flat, rid in pts2:
+                    c = int(ecode[flat])
+                    if c != EXIT_NOT_IN_BATCH:
+                        codes[rid] = c   # the segment where the row ran last
+                made["scan"] += self.tokens_decoded - before
+            sp.set(scan_tokens=made["scan"], join_tokens=made["join"])
         self.last_exit_codes = codes
         self.steps += total
         self.scan_runs += 1
@@ -1743,7 +1816,7 @@ class RequestManager:
         dispatch failure un-joins the request back to the queue; the
         per-tick path retries it with the full pressure machinery."""
         im = self.im
-        with self.profiler.phase("host_admit"):
+        with self._span("host_admit", phase=True):
             pre = {rid for rid in self.slots if rid is not None}
             self._fill_slots()
             newly = [rid for rid in self.slots
@@ -1755,33 +1828,43 @@ class RequestManager:
                 # no flat-row capacity left: the leftover stays slotted
                 # and prefills on the next tick's per-step path
                 continue
-            out = self._stretch_prefill(req, rows, dev_seq)
-            if out is None:
-                if (req.status is RequestStatus.PREFILLING
-                        and req.slot >= 0
-                        and self.slots[req.slot] == req.rid):
-                    self._unjoin(req)
-                continue
-            res, src = out
-            stamped.append(rid)
-            L = len(req.prefill_tokens)
-            commits.append(("join", req, res.token_ids, src))
-            if req.max_new_tokens - len(req.generated) <= 1:
-                # the held token is the whole remaining budget: nothing
-                # to decode — it completes at the stretch readback
-                continue
-            dst = len(rows)
-            bc = im.join_slot(bc, res.token_ids, src, dst, req.slot,
-                              L, L + 1, dst + 1, eos=eos)
-            rows.append((req, dst))
-            sched[req.rid] = 1
-            dev_seq[req.rid] = L + 1
+            with self._span("join", rid=rid):
+                bc = self._join_one(req, bc, rows, sched, dev_seq, commits,
+                                    eos, stamped)
         if stamped:
             if self._join_stamp is not None:
                 self._join_stamp(stamped)
             if self.telemetry.enabled:
                 self.telemetry.metrics.counter("stretch_joins").inc(
                     len(stamped))
+        return bc
+
+    def _join_one(self, req, bc, rows, sched, dev_seq, commits, eos,
+                  stamped):
+        """One joiner of :meth:`_stretch_join`: feed its prompt, then
+        splice it in.  Returns the batch the stretch goes on with."""
+        out = self._stretch_prefill(req, rows, dev_seq)
+        if out is None:
+            if (req.status is RequestStatus.PREFILLING
+                    and req.slot >= 0
+                    and self.slots[req.slot] == req.rid):
+                self._unjoin(req)
+            return bc
+        res, src = out
+        stamped.append(req.rid)
+        L = len(req.prefill_tokens)
+        commits.append(("join", req, res.token_ids, src))
+        if req.max_new_tokens - len(req.generated) <= 1:
+            # the held token is the whole remaining budget: nothing
+            # to decode — it completes at the stretch readback
+            return bc
+        dst = len(rows)
+        bc = self.im.join_slot(bc, res.token_ids, src, dst, req.slot,
+                               L, L + 1, dst + 1, eos=eos,
+                               counts={"rows": dst + 1, "rid": req.rid})
+        rows.append((req, dst))
+        sched[req.rid] = 1
+        dev_seq[req.rid] = L + 1
         return bc
 
     def _stretch_prefill(self, req, rows, dev_seq):
@@ -1803,7 +1886,7 @@ class RequestManager:
             start = req.prefill_offset
             take = min(im.max_tokens, L - start)
             done = start + take == L
-            with self.profiler.phase("host_prepare"):
+            with self._span("host_prepare", phase=True):
                 # running rows' cache depths are their DEVICE depths (the
                 # chain is ahead of the committed host view); only the
                 # joiner's own entry is read by its feed
@@ -1819,8 +1902,10 @@ class RequestManager:
             smp = (self._sample_for([(take - 1, req.rid)], im.max_tokens)
                    if done else None)
             self._prof_account([(req.rid, start, start + take)])
+            cnt = self._launch_counts([(req.rid, start, start + take)], 0)
             out = self._guarded(
-                "step", lambda b=bc2, s=smp: im.step(b, sample=s),
+                "step", lambda b=bc2, s=smp, c=cnt: im.step(
+                    b, sample=s, counts=c),
                 affected_fn=lambda: [req.rid])
             if out is None:
                 return None
@@ -1855,8 +1940,9 @@ class RequestManager:
             return True
         from .kv_paged import PagePoolExhausted
         try:
-            for rid, lo, hi in spans:
-                kv.prepare_write(rid, lo, hi)
+            with self._span("kv_prepare"):
+                for rid, lo, hi in spans:
+                    kv.prepare_write(rid, lo, hi)
         except PagePoolExhausted:
             return False
         return True
@@ -1877,7 +1963,7 @@ class RequestManager:
         if not active:
             return
         prof = self.profiler
-        with prof.phase("host_prepare"):
+        with self._span("host_prepare", phase=True):
             tokens, reqi, pos = [], [], []
             points = []
             for req in active:
@@ -1905,24 +1991,31 @@ class RequestManager:
         # per-request sample keys: row i starts at (rid_i, len(generated_i))
         # and the scan advances the token index per step on device
         smp = self._sample_for(list(enumerate(points)), self.im.max_tokens)
+        cnt = {"rows": len(active), "prompt_tokens": 0,
+               "ctx_sum": int(seq_lens.sum())}
         out = self._guarded(
             "decode_scan",
-            lambda: self.im.decode_scan(bc, n, eos=eos, sample=smp))
+            lambda: self.im.decode_scan(bc, n, eos=eos, sample=smp,
+                                        counts=cnt))
         if out is None:
             self.scan_runs += 1
             return
         toks, live, _ = out
-        with prof.phase("readback"):
+        with self._span("readback", phase=True):
             toks = np.asarray(toks)
             live = np.asarray(live)
         prof.host_sync()
-        for s in range(n):
-            for flat, rid in enumerate(points):
-                req = self.requests[rid]
-                if req.status is not RequestStatus.DECODING or not live[s, flat]:
-                    continue
-                self._append_token(req, int(toks[s, flat]))
-                self._maybe_finish(req)
+        with self._span("commit") as sp:
+            before = self.tokens_decoded
+            for s in range(n):
+                for flat, rid in enumerate(points):
+                    req = self.requests[rid]
+                    if (req.status is not RequestStatus.DECODING
+                            or not live[s, flat]):
+                        continue
+                    self._append_token(req, int(toks[s, flat]))
+                    self._maybe_finish(req)
+            sp.set(scan_tokens=self.tokens_decoded - before, join_tokens=0)
         self.steps += n
         self.scan_runs += 1
         if prof.enabled:
@@ -1936,21 +2029,30 @@ class RequestManager:
         to requeue/reject of the affected requests instead of killing the
         loop."""
         tel = self.telemetry
+        # ``pc_ns``: this clock at the tick's entry — the one subtraction
+        # that lays perf_counter stamps (the serving records, the ring)
+        # over a profiler session's time base
         if self._prefill_stretch_possible():
-            with tel.span("prefill_stretch", cat="serve"):
+            with tel.span("prefill_stretch", cat="serve",
+                          pc_ns=time.perf_counter_ns()):
                 self._prefill_stretch()
             return
         n = self._scan_steps_possible()
         if n > 1:
-            with tel.span("decode_stretch", cat="serve", steps=n):
+            with tel.span("decode_stretch", cat="serve", steps=n,
+                          pc_ns=time.perf_counter_ns()):
                 self._decode_stretch(n)
             return
-        with tel.span("serve_step", cat="serve"):
+        with tel.span("serve_step", cat="serve",
+                      pc_ns=time.perf_counter_ns()):
             # prepare_next_batch attributes its own host_admit /
             # host_prepare phases
             bc, sample_points = self.prepare_next_batch()
             base = bc if isinstance(bc, BatchConfig) else bc.base
-            if int(np.asarray(base.num_tokens)) == 0:
+            with self._span("batch_sync"):
+                # a device read: it waits for the batch's transfer
+                n_fed = int(np.asarray(base.num_tokens))
+            if n_fed == 0:
                 # nothing slotted fed a token (admission closed during a
                 # migration drain with only pending work): dispatching an
                 # empty batch would burn a device step for nothing
@@ -1961,7 +2063,8 @@ class RequestManager:
                 sample_points,
                 self.im.max_requests if gated else self.im.max_tokens)
             result = self._guarded(
-                "step", lambda: self.im.step(bc, sample=smp),
+                "step", lambda: self.im.step(bc, sample=smp,
+                                             counts=self._step_counts),
                 affected_fn=lambda: self._rids_in_batch(bc))
             if result is not None:
                 self.process_result(result, sample_points)
@@ -2135,16 +2238,17 @@ class RequestManager:
             return
         from .kv_paged import PagePoolExhausted
 
-        for rid, lo, hi in spans:
-            for _ in range(len(self.slots) + 1):
-                try:
-                    kv.prepare_write(rid, lo, hi)
-                    break
-                except PagePoolExhausted:
-                    victim = self._page_pressure_victim(rid)
-                    if victim is None:
-                        raise
-                    self.preempt(victim.rid)
+        with self._span("kv_prepare"):
+            for rid, lo, hi in spans:
+                for _ in range(len(self.slots) + 1):
+                    try:
+                        kv.prepare_write(rid, lo, hi)
+                        break
+                    except PagePoolExhausted:
+                        victim = self._page_pressure_victim(rid)
+                        if victim is None:
+                            raise
+                        self.preempt(victim.rid)
 
     def _page_pressure_victim(self, needer_rid: int):
         """Lowest-priority DECODING request (newest first among equals,
@@ -2489,10 +2593,16 @@ class RequestManager:
             self._arrival_pump = admit_due if chained else None
             self._join_stamp = stamp_joined if chained else None
             while pending or self.has_work():
-                now = admit_due()
-                self._check_lifecycle()
-                stamp(clock() - t0)
-                if not self.has_work():
+                # the loop's own work on either side of the tick (and the
+                # caller's, inside its clock): device-idle time here is
+                # the arrival loop's, not the scheduler's
+                with self._span("loop_arrivals"):
+                    now = admit_due()
+                    self._check_lifecycle()
+                    stamp(clock() - t0)
+                    work = self.has_work()
+                    starters = prefill_starters() if work else []
+                if not work:
                     new_rm = self._maybe_migrate(idle=True)
                     if new_rm is not None:
                         return continue_on(new_rm)
@@ -2501,32 +2611,34 @@ class RequestManager:
                     # clocks (which advance per call) lose at most ~1ms of
                     # wall time per idle poll
                     if pending:
-                        _time.sleep(min(1e-3, max(0.0,
-                                                  pending[0][0] - now)))
+                        with self._span("loop_idle"):
+                            _time.sleep(min(1e-3, max(0.0,
+                                                      pending[0][0] - now)))
                     continue
                 if not chained:
                     # legacy TTFT protection: cap the stretch while
                     # arrivals are outstanding (the chained path joins
                     # them mid-stretch instead)
                     self.scan_chunk = quantum if pending else saved_chunk
-                starters = prefill_starters()
                 self.profiler.tick_begin()
                 self._tick()
                 self.profiler.tick_end()
-                self._sync_kv()
-                self._maybe_check_health()
-                self._maybe_brownout()
-                for rid in starters:
-                    # a mid-stretch join already stamped (and telemetered)
-                    # its own prefill start — don't re-stamp it here
-                    if (self.requests[rid].prefill_offset > 0
-                            and "prefill_start_s" not in records[rid]):
-                        records[rid]["prefill_start_s"] = now
-                        if tel.enabled:
-                            tel.request_prefill_started(
-                                self.requests[rid].trace_id)
-                stamp(clock() - t0)
-                new_rm = self._maybe_migrate()
+                with self._span("loop_bookkeep"):
+                    self._sync_kv()
+                    self._maybe_check_health()
+                    self._maybe_brownout()
+                    for rid in starters:
+                        # a mid-stretch join already stamped (and
+                        # telemetered) its own prefill start — don't
+                        # re-stamp it here
+                        if (self.requests[rid].prefill_offset > 0
+                                and "prefill_start_s" not in records[rid]):
+                            records[rid]["prefill_start_s"] = now
+                            if tel.enabled:
+                                tel.request_prefill_started(
+                                    self.requests[rid].trace_id)
+                    stamp(clock() - t0)
+                    new_rm = self._maybe_migrate()
                 if new_rm is not None:
                     return continue_on(new_rm)
             self._maybe_check_health(force=True)

@@ -372,7 +372,7 @@ class SpecInferManager(RequestManager):
             if result is None:
                 return
             self.llm_steps += 1
-            with self.profiler.phase("readback"):
+            with self._span("readback", phase=True):
                 ids = np.asarray(result.token_ids)
             self.profiler.host_sync()
             for flat, rid in points:
@@ -504,7 +504,7 @@ class SpecInferManager(RequestManager):
                                    lambda b=bc: self.ssm.step(b))
             if result is None:
                 return []
-            with prof.phase("readback"):
+            with self._span("readback", phase=True):
                 topk_ids = np.asarray(result.topk_ids)
                 topk_lp = np.asarray(result.topk_logprobs)
             prof.host_sync()
@@ -646,7 +646,7 @@ class SpecInferManager(RequestManager):
         if result is None:
             return
         self.llm_steps += 1
-        with prof.phase("readback"):
+        with self._span("readback", phase=True):
             ids = np.asarray(result.token_ids)
         prof.host_sync()
 

@@ -37,6 +37,37 @@ def enable_compile_cache() -> str:
     return cache_dir
 
 
+def _make_roomy(slots: int):
+    """A function whose frame has ``slots`` unused locals (8 bytes each)."""
+    names = " = ".join(f"_{i}" for i in range(slots))
+    src = ("def with_stack_room(fn, *args, **kwargs):\n"
+           "    if fn is None:\n"
+           f"        {names} = None\n"
+           "    return fn(*args, **kwargs)\n")
+    scope: dict = {}
+    exec(compile(src, "<flexflow_tpu stack room>", "exec"), scope)
+    return scope["with_stack_room"]
+
+
+# ``with_stack_room(fn, *args, **kwargs)`` is ``fn(*args, **kwargs)``, called
+# from a frame of 128 KiB.  CPython 3.11+ keeps a thread's Python frames on a
+# data stack of 16 KiB chunks and frees a chunk the moment its first frame
+# returns; code that calls up and down across a chunk boundary then maps and
+# unmaps a chunk on EVERY call (~6 us instead of ~50 ns here).  JAX's
+# jaxpr -> MLIR lowering is a deep recursion that goes up and down a few
+# frames per equation, so whether a program lowers in 0.3 s or in 8 s
+# depends on how many bytes of frames happen to lie below it: on the v5e
+# host (PR 27) the prefill scan of OPT-6.7B-d12 lowered in 5.7 s, 7.7 s
+# with THREE more local slots in ``RequestManager._prefill_stretch``, and
+# 0.29 s when the same script was started through ``runpy``.  A frame
+# larger than a chunk gets a chunk of its own, twice its size: everything
+# called from it (tracing and lowering need ~40 KiB) then lives in that
+# one chunk and crosses no boundary, whatever lies below.  Costs ~70 us a
+# call, so it wraps the jitted launches (which may trace and lower), not
+# hot host loops.
+with_stack_room = _make_roomy(1 << 14)
+
+
 def cpu_child_env() -> dict:
     """Environment for a child process that must stay off the chip its
     parent holds: ``JAX_PLATFORMS=cpu``, whatever the child imports first

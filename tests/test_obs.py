@@ -351,3 +351,132 @@ def test_every_emitted_typed_event_is_in_event_schema():
     assert ("trace_recorded", "replay") in emitted
     assert ("replay_completed", "replay") in emitted
     assert ("replay_mismatch", "replay") in emitted
+
+
+# ---------------------------------------------------------------------------
+# one vocabulary on the profiler's clock (ISSUE 27): the scheduler's spans
+# reach a jax.profiler session with NO handle, and the device's operations
+# carry the graph node that made them
+# ---------------------------------------------------------------------------
+_ARRIVALS = [(0.0, [1, 2, 3, 4, 5], 9), (0.0, [3, 4, 5], 6),
+             (0.01, [7, 8, 9, 10], 5)]
+
+
+def _serve_toy(telemetry=None, profiler=None, trace_dir=None):
+    """The toy arrival loop once; under a ``jax.profiler`` session when
+    ``trace_dir`` is given (host tracer level 1, as benchmark/run.py)."""
+    from flexflow_tpu.obs import NULL_PROFILER
+
+    im = make_im(max_tokens=16, max_requests=2, max_seq=64)
+    rm = RequestManager(im, GenerationConfig(stop_on_eos=False),
+                        telemetry=telemetry, profiler=profiler)
+    try:
+        if trace_dir is not None:
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            opts.host_tracer_level = 1
+            jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+        try:
+            recs = rm.serve_with_arrivals(list(_ARRIVALS))
+        finally:
+            if trace_dir is not None:
+                jax.profiler.stop_trace()
+    finally:
+        im.telemetry, im.profiler = NULL_TELEMETRY, NULL_PROFILER
+    return [recs[rid]["tokens"] for rid in sorted(recs)]
+
+
+def test_profiler_session_sees_scheduler_spans_without_a_handle(tmp_path):
+    from benchmark import trace_reduce, xplane_spans
+
+    want = _serve_toy()
+    got = _serve_toy(trace_dir=tmp_path)
+    # a session attached or not: the same tokens
+    assert got == want and [len(t) for t in got] == [9, 6, 5]
+    trace = xplane_spans.load(trace_reduce.find_xplane(str(tmp_path)))
+    spans = xplane_spans.program_spans(trace)
+    by_name = {}
+    for h in spans:
+        by_name.setdefault(h.name, []).append(h)
+    assert {"decode_stretch", "serve_step", "host_admit", "host_prepare",
+            "readback", "commit", "loop_arrivals",
+            "loop_bookkeep"} <= set(by_name)
+    # every tick carries the perf_counter reading that links the clocks
+    for tick in by_name["decode_stretch"] + by_name["serve_step"]:
+        assert tick.args["pc_ns"] > 0
+    # counts at the launch, as ints the scheduler held
+    scans = by_name["decode_scan_dispatch"]
+    assert all(h.args["kind"] == "decode_scan" and h.args["rows"] >= 1
+               and h.args["n_steps"] >= 2 and h.args["ctx_sum"] > 0
+               for h in scans)
+    steps = by_name["step_dispatch"]
+    assert sum(h.args["prompt_tokens"] for h in steps) == 5 + 3 + 4
+    # prompts of 5 and 3 tokens fed from position 0: 15 + 6 contexts
+    assert steps[0].args["prompt_ctx_sum"] == 21
+    # the commit spans say which program made every token returned
+    made = {k: xplane_spans.committed_tokens(trace, [k])
+            for k in xplane_spans.TOKENS}
+    assert sum(made.values()) == sum(len(t) for t in got)
+    assert made["step_tokens"] == 3 and made["scan_tokens"] == 17
+    # spans nest: a launch lies inside its tick
+    tick = by_name["decode_stretch"][0]
+    assert any(tick.start_ns <= h.start_ns
+               and h.start_ns + h.dur_ns <= tick.start_ns + tick.dur_ns
+               for h in scans)
+
+
+def test_outputs_identical_with_session_telemetry_or_profiler(tmp_path):
+    from flexflow_tpu.obs import StepProfiler
+
+    want = _serve_toy()
+    tel, prof = Telemetry(), StepProfiler()
+    assert _serve_toy(telemetry=tel, profiler=prof,
+                      trace_dir=tmp_path) == want
+    # the ring holds the same spans the session saw, arguments included
+    ring = {e["name"]: e for e in tel.trace.trace_events() if e["ph"] == "X"}
+    assert {"decode_stretch", "decode_scan_dispatch", "commit",
+            "host_prepare"} <= set(ring)
+    assert ring["decode_scan_dispatch"]["args"]["kind"] == "decode_scan"
+    assert "scan_tokens" in ring["commit"]["args"]
+    # one ``with`` per boundary feeds the profiler too: the old phases,
+    # the same meaning; a launch span is one counted dispatch; the spans
+    # this issue added (commit, loop_*) are no phases
+    assert {"dispatch", "host_admit", "host_prepare", "readback"} \
+        == set(prof.phase_s)
+    assert prof.work["dispatches"] == prof.phase_counts["dispatch"] > 0
+    assert prof.work["host_syncs"] == prof.phase_counts["readback"]
+
+
+def test_lowered_programs_carry_node_and_stage_scopes():
+    import re
+
+    from flexflow_tpu.serve.batch_config import BatchConfig
+
+    im = make_im()
+    bc = BatchConfig.build([1, 2], [0, 1], [3, 4],
+                           np.array([4, 5], np.int32), max_tokens=16,
+                           max_requests=2)
+
+    def scopes(fn, *args, **kw):
+        """The ``op_name`` of every instruction of the compiled HLO: what
+        a device trace shows as an event's ``tf_op``."""
+        hlo = jax.jit(fn, static_argnames=tuple(kw)).lower(
+            *args, **kw).compile().as_text()
+        return set(re.findall(r'op_name="([^"]*)"', hlo))
+
+    step = scopes(im._step_impl, im.params, im.state, bc)
+    scan = scopes(im._decode_scan_impl, im.params, im.state, bc, None, None,
+                  None, n_steps=2, eos=None)
+    attn = "IncMultiHeadSelfAttention.model.layers.0.self_attn"
+    for names in (step, scan):
+        assert any(f"{attn}/attend/kv_write/" in n for n in names)
+        assert any(f"{attn}/qkv_proj/" in n for n in names)
+        assert any(f"{attn}/o_proj/" in n for n in names)
+        assert any("/Linear.lm_head/" in n for n in names)
+        assert any("/sample/" in n for n in names)
+        # nothing a node lowers escapes its scope: every dot_general sits
+        # under a node
+        assert all(re.search(r"/[A-Z]\w*\.[\w.]+/", n) for n in names
+                   if n.endswith("dot_general"))
+    assert any("/advance/" in n for n in scan)
+    assert not any("/advance/" in n for n in step)
