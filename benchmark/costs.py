@@ -39,6 +39,17 @@ def prefill_attention_cost(chunk_len, start, q_heads, kv_heads, head_dim,
     return ops, nbytes
 
 
+def dense_step_cost(matrix_params, rows, weight_bytes=2):
+    """The dense part (projections, FFN, LM head) of one step over ``rows``
+    token rows: every weight matrix is multiplied once per row and streamed
+    from memory once per step, however many rows share it.
+
+    ops: 2 * parameters * rows.  bytes: parameters * ``weight_bytes``
+    (activations are thousands of times smaller and left out).
+    """
+    return 2 * matrix_params * rows, matrix_params * weight_bytes
+
+
 def roofline_seconds(ops, nbytes, peak):
     """Least seconds the chip could take and which roof bounds it."""
     t_ops = ops / peak["flops_bf16"]

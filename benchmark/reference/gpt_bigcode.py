@@ -20,6 +20,11 @@ def num_layers(hf):
     return hf["n_layer"]
 
 
+def attention_shape(hf):
+    """``(query heads, key/value heads, head size)``."""
+    return hf["n_head"], _KV(hf), _D(hf)
+
+
 GLOBAL = [
     ("wte", lambda hf: (hf["vocab_size"], _E(hf)), "matrix"),
     ("wpe", lambda hf: (hf["n_positions"], _E(hf)), "matrix"),

@@ -17,6 +17,12 @@ def num_layers(hf):
     return hf["num_hidden_layers"]
 
 
+def attention_shape(hf):
+    """``(query heads, key/value heads, head size)``."""
+    h = hf["num_attention_heads"]
+    return h, h, _E(hf) // h
+
+
 GLOBAL = [
     ("embed_tokens", lambda hf: (hf["vocab_size"], _E(hf)), "matrix"),
     ("embed_positions",

@@ -244,6 +244,32 @@ def end_to_end(mix, records, clock, seconds):
     return out, counts
 
 
+def no_window(mix, requests, clock, seconds, t_end):
+    """Why a run has no window to report: it never opened, or (closed loop)
+    the loop served its whole queue and returned before the window closed.
+    Says the pace that emptied the queue and the depth that would have
+    outlasted the window at it, with headroom.py's margin."""
+    from benchmark.headroom import HEADROOM
+
+    loop_s = t_end - clock.t0
+    if clock.opened is None:
+        return (f"the window never opened: the loop returned after "
+                f"{loop_s:.1f}s and {len(requests)} requests, and at no step "
+                "boundary did every slot hold a request that had produced a "
+                "token")
+    tokens = sum(len(ids) + n for _, ids, n in requests)
+    until_open = clock.opened.t - clock.t0
+    per_round = int(mix.get("round", 1))
+    depth = HEADROOM * len(requests) * (until_open + seconds) / loop_s
+    depth = per_round * int(-(-depth // per_round))
+    return (f"the queue ran dry {t_end - clock.opened.t:.1f}s into a window "
+            f"of {seconds:g}s: all {len(requests)} requests ({tokens} tokens)"
+            f" were served in {loop_s:.1f}s, {tokens / loop_s:.0f} tokens/s; "
+            f"at that pace the traffic file needs queue_depth >= {depth} "
+            f"({HEADROOM} x what {until_open + seconds:.1f}s take), and "
+            "benchmark/headroom.py says what the chip's roofline asks for")
+
+
 def per_layer(bench, cell, ctx):
     """Every per-layer metric of this cell whose reader finds something."""
     out = {}
@@ -349,6 +375,15 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
     for (a, ta), (b, tb) in zip(marks, marks[1:]):
         log(f"setup: {b} {tb - ta:.2f}s")
     log(f"setup: JAX's own account {watch.seconds()}")
+    if len(clock.ticks) > 1:
+        # where a stall of the host or the device would show: the longest
+        # stretches between two step boundaries of the window
+        gaps = sorted(((b - a, a - clock.opened.t) for a, b in
+                       zip([clock.opened.t] + clock.ticks, clock.ticks)),
+                      reverse=True)
+        log(f"ticks: {len(gaps)} in the window, median "
+            f"{gaps[len(gaps) // 2][0]:.3f}s; longest (seconds, at) "
+            f"{[(round(g, 3), round(at, 1)) for g, at in gaps[:4]]}")
     compiled = watch.between(t_window, t_end)
     if compiled:
         correct = False
@@ -356,8 +391,9 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
             f"window: {compiled[:4]}")
     if clock.opened is None or (mix["loop"] == "closed"
                                 and not clock.cancelled):
-        die("the window never opened, or the queue ran dry before it closed:"
-            " the traffic file's queue_depth is too small for this window")
+        msg = no_window(mix, requests, clock, args.seconds, t_end)
+        log(f"benchmark: {msg}")
+        die(msg)
     t_lo, t_hi = clock.opened.t - clock.t0, clock.closed.t - clock.t0
     # attempted: due in the window (open loop); reached the device before
     # it closed (closed loop: the rest of the queue only waited)
@@ -388,8 +424,8 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
     if args.trace:
         from benchmark import trace_reduce
 
-        reduced = trace_reduce.reduce_trace(
-            trace_reduce.find_xplane(tracer.dir))
+        xplane = trace_reduce.find_xplane(tracer.dir)
+        reduced = trace_reduce.reduce_trace(xplane)
         if not reduced["chips"]:
             die("the traced span holds no operation on any device")
         # the records' own metrics: requests due before the span only (the
@@ -397,8 +433,9 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
         t_span = clock.trace_at[0].t - clock.t0
         before = {rid: r for rid, r in inside.items()
                   if r["arrival_s"] < t_span}
-        ctx = dict(records=before, reduced=reduced, clock=clock, peak=peak,
-                   hf=hf, dep=dep, mix=mix, llm=llm, log=log)
+        ctx = dict(records=before, reduced=reduced, xplane=xplane,
+                   clock=clock, peak=peak, hf=hf, dep=dep, mix=mix, llm=llm,
+                   log=log)
         metrics = per_layer(bench, cell, ctx)
         window_s = tracer.t_stop - tracer.t_start
         chips = reduced["chips"][:len(devices)]
@@ -423,6 +460,13 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
     }
     if breakdown:
         result["breakdown"] = breakdown
+    # each number compared beside its limit, as standard error's last lines
+    # too: of a run that is not correct the driver keeps the end of that
+    limits = dict(dep["correct"],
+                  served_gap_ulps=dep["correct"]["token_gap_ulps"])
+    for name, value in numbers.items():
+        sys.stderr.write(f"correct: {name} = {value:.4f} "
+                         f"(limit {limits[name]})\n")
     print(json.dumps(result), flush=True)
     return 0
 

@@ -12,8 +12,9 @@ Open loop: the window is the arrival schedule itself (requests due in
 ``drain_s`` later is cancelled and counts as failed.
 Closed loop: every request is queued at 0; the window opens at the first
 boundary at which every slot holds a request that has produced a token, and
-closes at the first boundary ``seconds`` later.  Tokens are counted as the
-host sees them at those two boundaries.
+closes at the first boundary of that kind ``seconds`` or more later (any
+boundary, once it is a tenth of the window overdue).  Tokens are counted as
+the host sees them at those two boundaries.
 """
 
 import collections
@@ -22,6 +23,9 @@ import time
 # what the host has seen at one step boundary: generated tokens, prompt
 # tokens of requests that have their first token, prompt tokens fed
 Stamp = collections.namedtuple("Stamp", "t generated prompt_done fed")
+# how much longer than asked a closed-loop window may run while it waits
+# for a boundary of the kind that opened it
+ALIGN_SHARE = 0.1
 
 
 class WindowClock:
@@ -35,12 +39,15 @@ class WindowClock:
         self.t0 = None            # the loop's own zero
         self.opened = None        # Stamp at the window's open
         self.closed = None        # Stamp at its close
+        self.ticks = []           # closed loop: boundary times in the window
         self.trace_at = None      # (Stamp, Stamp) around the traced span
         self.trace_lens = None    # per request (prompt, generated) at each
         self._trace_open = None
         self._steps_seen = rm.steps
         self.cancelled = False    # the clock ended the run itself
-        self._live = set()        # rids that are not terminal yet
+        self._waiting = set()     # rids that have never left the queue
+        self._started = set()     # rids that have
+        self._live = set()        # those of them that are not terminal yet
         self._seen = self.first_rid
         self._done = [0, 0, 0]
 
@@ -52,8 +59,20 @@ class WindowClock:
         from flexflow_tpu.serve.request_manager import TERMINAL_STATUSES
 
         reqs = self.rm.requests
-        self._live.update(range(self._seen, self.rm._next_rid))
+        # a request that has never left the manager's queue has no token
+        # and none fed: only those that have left it are walked (set
+        # arithmetic, so a backlog of thousands costs no loop here; with a
+        # manager that has no ``pending`` list of rids every request is
+        # walked, as before).  Nothing preempts in the benchmark's traffic:
+        # a request sent BACK to the queue before its first look would be
+        # taken for one that never left
+        self._waiting.update(range(self._seen, self.rm._next_rid))
         self._seen = self.rm._next_rid
+        queued = self._waiting.intersection(getattr(self.rm, "pending", ()))
+        left = self._waiting - queued
+        self._started |= left
+        self._live |= left
+        self._waiting = queued
         live = [0, 0, 0]
         for rid in list(self._live):
             r = reqs[rid]
@@ -69,11 +88,10 @@ class WindowClock:
 
     def _lengths(self):
         """``{rid: (prompt length, tokens generated)}`` of this run's
-        requests that have reached a slot."""
+        requests that have reached a slot, as of the last ``_totals``."""
         reqs = self.rm.requests
         return {rid: (len(reqs[rid].prompt), len(reqs[rid].generated))
-                for rid in range(self.first_rid, self.rm._next_rid)
-                if reqs[rid].prefill_offset}
+                for rid in self._started if reqs[rid].prefill_offset}
 
     def _all_slots_decoding(self):
         reqs = self.rm.requests
@@ -107,9 +125,20 @@ class WindowClock:
         elif self.opened is None:
             if self._all_slots_decoding():
                 self.opened = Stamp(now, *self._totals())
-        elif self.closed is None and now - self.opened.t >= self.seconds:
-            self.closed = Stamp(now, *self._totals())
-            self._cancel_all()
+        elif self.closed is None:
+            self.ticks.append(now)
+            due = now - self.opened.t - self.seconds
+            # close where the window opened: at a boundary at which every
+            # slot holds a request that has produced a token.  Work comes
+            # in phases (a wave's prompts in one tick, its answers in the
+            # next), and a window that ends between two phases reads high
+            # or low by half a wave; one that spans whole waves does not.
+            # A program that never shows such a boundary again is closed
+            # ``ALIGN_SHARE`` of the window late, wherever it is
+            if due >= 0 and (self._all_slots_decoding()
+                             or due >= ALIGN_SHARE * self.seconds):
+                self.closed = Stamp(now, *self._totals())
+                self._cancel_all()
         self._trace(now)
 
     def _trace(self, now):

@@ -83,6 +83,47 @@ def test_closed_queue_is_deep_and_due_at_zero():
         assert len(reqs) == mix["queue_depth"]
         assert all(t == 0.0 for t, _, _ in reqs)
         assert all(len(p) + o <= max_seq for _, p, o in reqs)
+        # drawn in rounds (the last may be cut short), each a whole number
+        # of blocks, and every block holds the whole grid of both lengths
+        per_round, block = mix["round"], mix["block"]
+        assert mix["queue_depth"] % block == 0 == per_round % block
+        assert mix["queue_depth"] >= 2 * per_round
+        prompts = sorted(traffic_gen.length_grid(mix["prompt_len"], block))
+        answers = sorted(traffic_gen.length_grid(mix["output_len"], block))
+        for at in range(0, len(reqs), block):
+            assert sorted(len(p) for _, p, _ in reqs[at:at + block]) == prompts
+            assert sorted(o for _, _, o in reqs[at:at + block]) == answers
+        # a queue of fewer rounds is the beginning of a deeper one, ids and
+        # all; the rounds differ from each other
+        for rounds in (1, 2):
+            fewer = dict(mix, queue_depth=rounds * per_round)
+            assert traffic_gen.make_requests(
+                fewer, 3, 50272, 40, max_seq) == reqs[:rounds * per_round]
+        shape = lambda rs: [(len(p), o) for _, p, o in rs]
+        assert shape(reqs[:per_round]) != shape(reqs[per_round:2 * per_round])
+
+
+def test_rounds_are_drawn_whole_and_one_round_is_the_old_draw():
+    mix = {"loop": "closed", "queue_depth": 20, "block": 4,
+           "prompt_len": {"dist": "uniform", "lo": 8, "hi": 120},
+           "output_len": {"dist": "uniform", "lo": 4, "hi": 40}}
+    shape = lambda rs: [(len(p), o) for _, p, o in rs]
+    whole = traffic_gen.make_requests(mix, 1, 512, 1, 256)
+    # no ``round``, or one as deep as the queue: the same draw
+    assert traffic_gen.make_requests(dict(mix, round=20), 1, 512, 1,
+                                     256) == whole
+    # rounds of 8: a queue cut inside a round is still the beginning of a
+    # deeper one (the round's draw does not depend on where the queue ends)
+    deep = traffic_gen.make_requests(dict(mix, round=8, queue_depth=40), 1,
+                                     512, 1, 256)
+    for depth in (3, 8, 13, 20):
+        cut = traffic_gen.make_requests(
+            dict(mix, round=8, queue_depth=depth), 1, 512, 1, 256)
+        assert cut == deep[:depth]
+    # without rounds a deeper queue shifts the answers' order: the defect
+    # the rounds are there to mend
+    assert shape(traffic_gen.make_requests(
+        dict(mix, queue_depth=40), 1, 512, 1, 256))[:20] != shape(whole)
 
 
 def test_what_no_cell_brings_is_refused():

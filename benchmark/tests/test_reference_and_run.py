@@ -3,6 +3,7 @@ fail, and run.py end to end on toy cells (CPU; Pallas interpreted)."""
 
 import importlib
 import json
+import os
 
 import jax
 import pytest
@@ -93,3 +94,37 @@ def test_run_py_end_to_end_on_a_toy_cell(pallas_on_cpu, toy_root, capsys,
     assert any(line.startswith("samples:") for line in out)
     assert any("0 lowerings or compiles in it" in line for line in out)
     assert not any(line.startswith("NOT CORRECT") for line in out)
+
+
+@pytest.mark.parametrize("depth,says", [
+    (2, "the window never opened"),
+    (8, "the queue ran dry"),
+])
+def test_a_closed_queue_too_shallow_ends_without_a_result(
+        pallas_on_cpu, toy_root, capsys, depth, says):
+    """Fewer requests than slots: the window never opens.  More, but not
+    enough for the window: the loop serves them all and returns; the run
+    says at what pace and how deep the queue would have had to be, on both
+    streams, and prints no result line."""
+    path = os.path.join(toy_root, "traffic", "toy-closed.json")
+    with open(path) as f:
+        mix = json.load(f)
+    mix.update(queue_depth=depth, round=4)
+    with open(path, "w") as f:
+        json.dump(mix, f)
+    with pytest.raises(SystemExit) as e:
+        run.main(["--workload", "toy-opt.toy-closed", "--seed", "11",
+                  "--seconds", "3600", "--trace", "0"],
+                 root=toy_root, data=toy_root, gate=_gate)
+    assert e.value.code not in (0, None)
+    streams = capsys.readouterr()
+    assert '"correct"' not in streams.out
+    for text in (streams.out, streams.err):
+        assert says in text
+    if depth > 2:
+        last = streams.err.strip().splitlines()[-1]
+        assert f"all {depth} requests" in last and "tokens/s" in last
+        # 1.5 x the requests x (the wait for the window + 3600 s) / the
+        # seconds the loop took, rounded up to whole rounds of 4
+        needed = int(last.split("queue_depth >= ")[1].split()[0])
+        assert needed % 4 == 0 and needed > depth * 100

@@ -15,9 +15,12 @@ reduction keeps, per chip:
              between op intervals, longest first
 
 A program's name is its jitted function's (``_step_impl``,
-``_decode_scan_impl``, ``_prefill_scan_impl``, ``_join_impl``): the serve
-path has no ``jax.named_scope`` and its Pallas calls carry no ``name=``, so
-the trace can name nothing finer than these and XLA's own op names.
+``_decode_scan_impl``, ``_prefill_scan_impl``, ``_join_impl``) and a Pallas
+kernel's event carries the kernel function's name (the calls have no
+``name=``).  This reduction goes by those names alone; what lies below
+them — each operation's ``jax.named_scope`` path by graph node and stage,
+and the scheduler's spans on the host plane, both there since PR 27 — is
+read by ``xplane_spans.py``.
 
     python -m benchmark.trace_reduce <file.xplane.pb>     # what a trace holds
 """

@@ -11,6 +11,15 @@ of the schedule is balanced, each block of arrivals spans exactly
 schedule with the distribution's shape, not a draw from it (PERF.md section
 7 keeps a drawn schedule, and the knee on it, for a later PR).
 
+A closed queue may be drawn in ROUNDS (``"round": n`` in the file): round
+after round draws its ``n`` prompts' shuffles, then its ``n`` answers', from
+the one fixed generator, and the per-seed generator hands out token ids
+request by request — so the first requests of a queue do not depend on how
+deep it is, and a queue can be deepened (headroom.py says how deep it has
+to be) without moving what a window that never gets past its
+first round sees.  A file without ``round`` is one round: all prompts'
+shuffles, then all answers', as ever.
+
 ``--seed`` moves the token ids (and the weights, and the check's sequences)
 and nothing of the schedule.  Why: the serving loop is deterministic, and at
 0.8 of the knee a tail over ~180 requests follows the order of arrivals — a
@@ -64,15 +73,25 @@ def _cycle(grid_fn, n, block, rng):
     return out
 
 
-def make_requests(mix, seed, vocab_size, seconds, max_seq_len, stream=0):
-    """Requests for one run: a list of ``(due_s, prompt_ids, max_new)``.
+def _schedule(grid_fns, n, block, per_round, rng):
+    """One column of ``n`` values per grid, drawn round by round: each
+    round draws ``per_round`` values of the first grid, then of the next.
+    Every round is drawn whole, so a longer schedule starts with the
+    shorter one."""
+    cols = [[] for _ in grid_fns]
+    for _ in range(0, n, per_round):
+        for col, fn in zip(cols, grid_fns):
+            col.extend(_cycle(fn, per_round, block, rng)[:per_round])
+    return [col[:n] for col in cols]
 
-    Open loop: the arrivals due inside ``seconds`` at the file's rate (the
-    count is the same for every seed).  Closed loop: ``queue_depth``
-    requests, all due at 0.  ``stream`` separates the rehearsal's requests
-    from the window's.
+
+def schedule(mix, seconds):
+    """The fixed schedule of one run, the same for every seed: a list of
+    ``(due_s, prompt length, answer length)``.
+
+    Open loop: the arrivals due inside ``seconds`` at the file's rate.
+    Closed loop: ``queue_depth`` requests, all due at 0.
     """
-    rng = np.random.default_rng([int(seed), int(stream)])
     fixed = np.random.default_rng(0)
     block = int(mix.get("block", 8))
     if mix["loop"] == "open":
@@ -81,23 +100,34 @@ def make_requests(mix, seed, vocab_size, seconds, max_seq_len, stream=0):
         n = int(mix["queue_depth"])
     else:
         raise ValueError(f"unknown loop kind {mix['loop']!r}")
-    prompts = _cycle(lambda b: length_grid(mix["prompt_len"], b),
-                     n, block, fixed)[:n]
-    outputs = _cycle(lambda b: length_grid(mix["output_len"], b),
-                     n, block, fixed)[:n]
+    grids = [lambda b: length_grid(mix["prompt_len"], b),
+             lambda b: length_grid(mix["output_len"], b)]
     if mix["loop"] == "open":
-        gaps = np.asarray(_cycle(lambda b: gap_grid(mix["arrivals"], b),
-                                 n, block, fixed)[:n])
+        grids.append(lambda b: gap_grid(mix["arrivals"], b))
+    prompts, outputs, *gaps = _schedule(grids, n, block,
+                                        int(mix.get("round", n)), fixed)
+    if gaps:
+        gaps = np.asarray(gaps[0])
         # the mean gap of any whole block is exactly 1/rate
         due = np.cumsum(gaps) - gaps[0]
     else:
         due = np.zeros(n)
+    return [(float(t), int(p), int(o))
+            for t, p, o in zip(due.tolist(), prompts, outputs)]
+
+
+def make_requests(mix, seed, vocab_size, seconds, max_seq_len, stream=0):
+    """Requests for one run: ``schedule`` with token ids from ``seed``, a
+    list of ``(due_s, prompt_ids, max_new)`` (the count is the same for
+    every seed).  ``stream`` separates the rehearsal's requests from the
+    window's.
+    """
+    rng = np.random.default_rng([int(seed), int(stream)])
     reqs = []
-    for t, p, o in zip(due.tolist(), prompts, outputs):
-        p, o = int(p), int(o)
+    for t, p, o in schedule(mix, seconds):
         if p + o > max_seq_len:
             raise ValueError(
                 f"prompt {p} + output {o} exceeds max_seq_len {max_seq_len}")
         ids = rng.integers(FIRST_TOKEN_ID, vocab_size, size=p).tolist()
-        reqs.append((float(t), ids, o))
+        reqs.append((t, ids, o))
     return reqs
