@@ -52,12 +52,16 @@ OPT_6_7B = {
     "word_embed_proj_dim": 4096,
     "do_layer_norm_before": True,
 }
-# Depth on ONE chip: 12 of 32.  AOT memory_analysis of the step programs
-# for a described v5e (PR 24 rehearsal; nothing ran): at 16 layers the
-# decode scan needs 17.3 GB of the 15.75 GB — at a 512-token step the KV
-# write is an XLA scatter whose layout makes the scan copy every cache
-# (ops.DUS_MAX_TOKENS); at 12 it is 5.7 GB weights + 3.6 GB KV + 4.8 GB
-# temporaries = 14.1 GB, 1.6 GB free.
+# Depth on ONE chip: 12 of 32, the depth the benchmark's opt-6.7b-d12 cells
+# run.  AOT memory_analysis of the step programs for a described v5e (PR 24
+# rehearsal; nothing ran) read 17.3 GB of the 15.75 GB for the decode scan
+# at 16 layers and 14.1 GB at 12 (5.7 GB weights + 3.6 GB KV + 4.8 GB
+# temporaries) — but those temporaries were the scan's own doing: its
+# 512-row KV write was an XLA scatter whose layout made the scan copy
+# every cache (ops.DUS_MAX_TOKENS).  The scan runs on one row per slot now
+# (InferenceManager._decode_scan_impl) and writes in place; the flat step
+# and the prefill scan were not sized again at 16 layers, so the depth
+# stands.
 ONE_CHIP_LAYERS = 12
 SEED = 0
 # 8 prompts of mixed lengths; chosen so the 512-token prefill chunks number

@@ -199,8 +199,11 @@ def sample_tokens(logits, sample):
     scalars, so one compiled step serves every GenerationConfig) and an
     explicit key threaded from the RequestManager.
 
-    ``sample`` is ``(key, temperature, top_p)`` — one key draws every row —
-    or the resilient-serving 4-tuple ``(key, temperature, top_p, folds)``
+    ``sample`` is ``(key, temperature, top_p)`` — one key draws every row,
+    so the bits depend on the ROW COUNT of ``logits``: the decode scan,
+    which runs on one row per slot, draws with it what a deployment of
+    ``max_tokens == max_requests`` draws — or the resilient-serving
+    4-tuple ``(key, temperature, top_p, folds)``
     with ``folds`` i32[rows, 2]: row ``i`` draws from
     ``fold_in(fold_in(key, folds[i, 0]), folds[i, 1])``, i.e. a PER-REQUEST
     (rid, token-index) key schedule that is invariant to batch composition
@@ -226,6 +229,16 @@ def sample_tokens(logits, sample):
         return jax.vmap(jax.random.categorical)(keys, lg).astype(jnp.int32)
 
     return jax.lax.cond(temperature <= 0.0, lambda _: greedy, draw, None)
+
+
+def decode_scan_width(bc) -> int:
+    """Rows the decode scan's body runs on for the batch ``bc``: one per
+    request slot.  A pure-decode batch holds at most one live row per
+    request, so the scan never needs the flat step's ``max_tokens``
+    capacity.  From shapes alone (no sync, usable at trace time), and the
+    ONE place the width is worked out: the program, its guard and its
+    dispatch span all ask here, about the batch they were handed."""
+    return min(bc.max_requests, bc.max_tokens)
 
 
 class InferenceManager:
@@ -644,6 +657,18 @@ class InferenceManager:
         per scan.  With dispatch latency L and device step time t, TPOT drops
         from ``max(L, t)`` to ``t + L/n_steps``.
 
+        The callers' contract is WIDE — ``bc``, ``allowed``, the sample
+        folds and every result are per flat row of a ``max_tokens`` batch,
+        the layout ``step`` and ``join_slot`` share — but the scan's body
+        runs on ``min(max_requests, max_tokens)`` rows, one per slot: the
+        rows with ``request_index >= 0`` (at most one per request, wherever
+        they sit) are compacted on device once per call, outside the scan,
+        and the results are expanded back to their flat rows at the end.
+        So the KV write (``ops.DUS_MAX_TOKENS``), the attention kernel's
+        grid, the GEMMs and the LM head see that many rows.  Rows the
+        scan did not run read token 0, ``live`` False and
+        ``EXIT_NOT_IN_BATCH``; the returned ``bc`` leaves them as they came.
+
         ``eos`` (static): slots that emit it are FROZEN for the rest of the
         scan — their request_index flips to -1, so later steps write their
         KV to the scratch row and their emissions are masked out of ``live``.
@@ -658,6 +683,25 @@ class InferenceManager:
         readback, so the host reaps lifecycle outcomes without re-deriving
         them from the token stream.
         """
+        wide = bc
+        width = decode_scan_width(bc)
+        narrowed = width < bc.max_tokens
+        if narrowed:
+            with jax.named_scope("advance"):
+                # stable: the present rows keep their flat order, absent
+                # rows fill what is left of the width — no host read of bc
+                taken = jnp.argsort(bc.request_index < 0, stable=True)[:width]
+                bc = BatchConfig(
+                    tokens=bc.tokens[taken],
+                    request_index=bc.request_index[taken],
+                    token_position=bc.token_position[taken],
+                    num_tokens=bc.num_tokens,
+                    seq_lens=bc.seq_lens,
+                )
+                if allowed is not None:
+                    allowed = allowed[taken]
+                if sample is not None and len(sample) > 3:
+                    sample = (*sample[:3], sample[3][taken])
         present = bc.request_index >= 0
         alive0 = present
         if allowed is not None:
@@ -720,36 +764,66 @@ class InferenceManager:
                 jnp.where(eos_hit, EXIT_EOS,
                           jnp.where(alive_end, EXIT_RUNNING, EXIT_BUDGET)),
             ).astype(jnp.int32)
+            if narrowed:
+                shape = (n_steps, wide.max_tokens)
+                tokens = jnp.zeros(shape, tokens.dtype).at[:, taken].set(
+                    jnp.where(present, tokens, 0))
+                live = jnp.zeros(shape, live.dtype).at[:, taken].set(live)
+                ecode = jnp.full(shape[1:], EXIT_NOT_IN_BATCH,
+                                 ecode.dtype).at[taken].set(ecode)
+                bc = BatchConfig(
+                    tokens=wide.tokens.at[taken].set(bc.tokens),
+                    request_index=wide.request_index.at[taken].set(
+                        bc.request_index),
+                    token_position=wide.token_position.at[taken].set(
+                        bc.token_position),
+                    num_tokens=bc.num_tokens,
+                    seq_lens=bc.seq_lens,
+                )
         return tokens, live, ecode, state, bc
 
-    def _decode_scan_guards(self, n_steps: int, max_position=None,
-                            bc=None) -> None:
-        """Shared pre-dispatch validation for the scan paths.
+    def _decode_scan_guards(self, bc, n_steps: int, max_position=None,
+                            rows=None) -> int:
+        """Shared pre-dispatch validation for the scan paths; returns the
+        width the scan of ``bc`` runs at (``decode_scan_width``), for the
+        dispatch span.
 
         ``max_position``: the highest ``token_position`` in the batch as
         HOST bookkeeping (the chained path always knows it — reading it
         off a device-resident ``bc`` would force the mid-stretch sync the
         whole design removes).  Falls back to reading ``bc`` when the
-        caller has no host-side count (external hand-built batches)."""
+        caller has no host-side count (external hand-built batches).
+
+        ``rows``: the caller's host count of rows that hold a request (the
+        dispatch span's ``rows``), where it has one.  More than the scan's
+        width would be cut by the compaction and come back
+        ``EXIT_NOT_IN_BATCH`` without a word, so they are refused here."""
         import numpy as np
 
         from .ops import DUS_MAX_TOKENS
 
-        if self.max_tokens > DUS_MAX_TOKENS:
-            # the scan's KV writes are padded to max_tokens; past the DUS
-            # threshold they become an XLA scatter whose layout choice
-            # forces a per-step full-cache relayout (see ops.DUS_MAX_TOKENS)
+        width = decode_scan_width(bc)
+        if width > DUS_MAX_TOKENS:
+            # the scan's KV writes are as wide as the scan (one row per
+            # slot, not max_tokens); past the DUS threshold they become an
+            # XLA scatter whose layout choice forces a per-step full-cache
+            # relayout (see ops.DUS_MAX_TOKENS)
             import warnings
 
             warnings.warn(
-                f"decode_scan with max_tokens_per_batch {self.max_tokens} > "
+                f"decode_scan runs {width} rows (one per request slot) > "
                 f"{DUS_MAX_TOKENS}: KV writes take the scatter path and "
-                "re-lay out the full cache every step; use a smaller "
-                "max_tokens_per_batch for scanned decoding",
+                "re-lay out the full cache every step",
                 stacklevel=2,
             )
         if max_position is None:
+            rows = int(np.count_nonzero(np.asarray(bc.request_index) >= 0))
             max_position = int(np.max(np.asarray(bc.token_position)))
+        if rows is not None and rows > width:
+            raise ValueError(
+                f"decode_scan got {rows} rows with a request; a "
+                "pure-decode batch holds one row per request, at most "
+                f"{width} (BatchConfig.advance)")
         last = int(max_position) + n_steps
         if last > self.max_seq_len:
             raise ValueError(
@@ -757,6 +831,7 @@ class InferenceManager:
                 f"{self.max_seq_len}; cache writes past the end clamp to the "
                 "last slot and silently corrupt it"
             )
+        return width
 
     def decode_scan(self, bc, n_steps: int, eos: Optional[int] = None,
                     sample=None, counts=None):
@@ -764,16 +839,20 @@ class InferenceManager:
 
         Returns ``(tokens, live, bc)``: i32[n_steps, T] token ids,
         bool[n_steps, T] emission validity (False once a slot passed its
-        ``eos``), and the advanced BatchConfig to resume from.
+        ``eos``), and the advanced BatchConfig to resume from — all per
+        flat row of the ``T = max_tokens`` batch that came in, though the
+        scan itself ran on one row per slot (``_decode_scan_impl``); a
+        token means something only where ``live``.
         """
         assert self.params is not None, "call init_operators_inference() first"
-        self._decode_scan_guards(n_steps, bc=bc)
+        width = self._decode_scan_guards(bc, n_steps)
         if self.fault_injector is not None:
             self.fault_injector.maybe_fail("decode_scan")
         with self.telemetry.span("decode_scan_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
                                  phase="dispatch", kind="decode_scan",
-                                 n_steps=n_steps, **(counts or {})):
+                                 n_steps=n_steps, width=width,
+                                 **(counts or {})):
             tokens, live, _, self.state, bc = with_stack_room(
                 self._scan, self.params, self.state, bc, sample,
                 self._page_view(), None, n_steps=n_steps, eos=eos)
@@ -793,21 +872,26 @@ class InferenceManager:
         everything in ONE sync at stretch end.  ``allowed`` is the
         per-flat-row remaining-token budget (i32[max_tokens]); rows freeze
         on device when it runs out, so heterogeneous budgets share one
-        scan.  ``max_position`` is the caller's host bookkeeping of the
-        batch's highest token position (required: this path must not sync
-        to validate).  Returns LAZY device values
+        scan.  It, ``bc``, the sample folds and the results are all in the
+        flat step's ``max_tokens`` layout, whatever width the scan runs at
+        (``decode_scan_width``).  ``max_position`` is the caller's host
+        bookkeeping of the batch's highest token position (required: this
+        path must not sync to validate).  Returns LAZY device values
         ``(tokens, live, exit_codes, bc)``.
         """
         assert self.params is not None, "call init_operators_inference() first"
         assert max_position is not None, \
             "decode_scan_async requires host-tracked max_position"
-        self._decode_scan_guards(n_steps, max_position=max_position)
+        width = self._decode_scan_guards(
+            bc, n_steps, max_position=max_position,
+            rows=(counts or {}).get("rows"))
         if self.fault_injector is not None:
             self.fault_injector.maybe_fail("decode_scan")
         with self.telemetry.span("decode_scan_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
                                  phase="dispatch", kind="decode_scan",
-                                 n_steps=n_steps, **(counts or {})):
+                                 n_steps=n_steps, width=width,
+                                 **(counts or {})):
             tokens, live, ecode, self.state, bc = with_stack_room(
                 self._scan, self.params, self.state, bc, sample,
                 self._page_view(), allowed, n_steps=n_steps, eos=eos)

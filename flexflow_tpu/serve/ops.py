@@ -91,12 +91,34 @@ def _gather_logical_rows(cache, pages, rows):
 
 # token-count cutoff between the per-token dynamic-update-slice chain and a
 # single XLA scatter for KV-cache writes (see _scatter_rows_pos).  The
-# switch is on the CAPACITY-PADDED batch length (max_tokens_per_batch),
-# not the live token count: any InferenceManager whose max_tokens exceeds
-# this silently takes the scatter path, whose layout choice forces a
-# per-step full-cache relayout inside the decode/spec scans —
-# SpecDecodeScan and InferenceManager.decode_scan check their capacities.
+# switch is on the row count the write is TRACED at, not the live token
+# count: max_tokens_per_batch in a flat step (a prefill chunk wants the
+# scatter), one row per request slot in the decode scan (which compacts its
+# batch: InferenceManager._decode_scan_impl), max_requests*(depth+1) in the
+# spec scan.  Inside a scan the scatter's layout choice forces a per-step
+# full-cache relayout, so SpecDecodeScan and InferenceManager.decode_scan
+# check those widths against it.
 DUS_MAX_TOKENS = 128
+
+
+@jax.jit
+def _update_rows(cache, rows, pos, upd):
+    """``cache[rows[i], :, pos[i]] = upd[i]`` as a chain of in-place
+    dynamic-update-slices, one per token (``cache`` [R, H, S, D] with
+    ``upd`` [T, H, D], or a scale plane [R, H, S] with ``upd`` [T, H]).
+
+    Jitted so that the chain is traced once per shape and lowered once per
+    program, however many layers call it: a 36-layer model with 16 slots
+    writes 1152 slices a decode-scan step, and unrolled into every
+    caller's trace they tripled the scan programs' trace + lowering time
+    (PERF.md section 6, PR 30).  XLA inlines the call, so the compiled
+    program is the unrolled one."""
+    zero = jnp.int32(0)
+    for i in range(upd.shape[0]):
+        start = (rows[i], zero, pos[i]) + (zero,) * (cache.ndim - 3)
+        cache = jax.lax.dynamic_update_slice(
+            cache, jnp.expand_dims(upd[i], (0, 2)), start)
+    return cache
 
 
 def alibi_slopes(num_heads: int) -> jax.Array:
@@ -406,7 +428,7 @@ class IncMultiHeadSelfAttention(Op):
         the DUS_MAX_TOKENS threshold keeps both on the DUS path.
         cache: [R, H, S, D], updates: [T, H, D].
         """
-        t, h, d = updates.shape
+        t = updates.shape[0]
         upd = updates.astype(cache.dtype)
         # Clip so both paths share the DUS path's clamped out-of-range
         # semantics: PROMISE_IN_BOUNDS on the scatter would otherwise be
@@ -424,12 +446,7 @@ class IncMultiHeadSelfAttention(Op):
                 cache, idx, upd, dnums,
                 mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
             )
-        for i in range(t):
-            cache = jax.lax.dynamic_update_slice(
-                cache, upd[i].reshape(1, h, 1, d),
-                (rows[i], jnp.int32(0), pos[i], jnp.int32(0)),
-            )
-        return cache
+        return _update_rows(cache, rows, pos, upd)
 
     # ---- int8 KV cache (kv_dtype="int8") -------------------------------
     @staticmethod
@@ -456,7 +473,7 @@ class IncMultiHeadSelfAttention(Op):
         :meth:`_scatter_rows_pos` (same DUS-vs-scatter reasoning and clamped
         out-of-range semantics).
         """
-        t, h = updates.shape
+        t = updates.shape[0]
         upd = updates.astype(cache.dtype)
         rows = jnp.clip(rows.astype(jnp.int32), 0, cache.shape[0] - 1)
         pos = jnp.clip(pos.astype(jnp.int32), 0, cache.shape[2] - 1)
@@ -471,12 +488,7 @@ class IncMultiHeadSelfAttention(Op):
                 cache, idx, upd, dnums,
                 mode=jax.lax.GatherScatterMode.PROMISE_IN_BOUNDS,
             )
-        for i in range(t):
-            cache = jax.lax.dynamic_update_slice(
-                cache, upd[i].reshape(1, h, 1),
-                (rows[i], jnp.int32(0), pos[i]),
-            )
-        return cache
+        return _update_rows(cache, rows, pos, upd)
 
     @jax.named_scope("kv_write")
     def _write_kv(self, state, rows, pos, k, v, pages=None):

@@ -729,6 +729,11 @@ class PipelinedInferenceManager:
         (``_advance_impl``) and flows back to stage 0, so the host only
         reads tokens once at the end.  Micro-batches interleave across
         stages step by step (i-major dispatch order).
+
+        A token means something only where ``live`` — the one contract of
+        both managers' scans.  This one runs all ``max_tokens`` rows and
+        returns the padding rows' argmax; InferenceManager's runs one row
+        per slot and returns 0 on the rows it did not run.
         """
         assert self.stages[0].params is not None, \
             "call init_operators_inference() first"
