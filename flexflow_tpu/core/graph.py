@@ -48,6 +48,9 @@ class ParamSpec:
     spec: TensorSpec
     initializer: Any = None  # Initializer instance or None -> op default
     trainable: bool = True
+    # hold the spec's dtype whatever dtype the model's params are cast to at
+    # initialization (float32 constants of a recurrence in a bf16 model)
+    pin_dtype: bool = False
 
 
 class Tensor:

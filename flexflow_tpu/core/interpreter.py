@@ -53,7 +53,9 @@ def build_forward(plan: Plan, mode: str = "spmd") -> Callable:
     ``{node_name: pytree}``) and optionally ``extras`` (shared values visible
     to every op, e.g. the ``BatchConfig``).  Ops marked ``stateful = True``
     receive their state at ``ctx.extras["state"]`` and publish the updated
-    state to ``ctx.extras["state_out"]``; the call then returns
+    state to ``ctx.extras["state_out"]`` (an op with a ``state_owner`` reads
+    the state that node published in this same step, and publishes none);
+    the call then returns
     ``(outputs, new_state)``.  This replaces the reference's mutable per-op
     ``OpMeta`` device state (e.g. ``IncMultiHeadSelfAttentionMeta``'s KV cache)
     with explicit functional threading so the whole step stays jittable and
@@ -88,7 +90,12 @@ def build_forward(plan: Plan, mode: str = "spmd") -> Callable:
             if extras:
                 ctx.extras.update(extras)
             if state is not None and getattr(step.node.op, "stateful", False):
-                ctx.extras["state"] = state.get(step.node.name)
+                # an op that names a ``state_owner`` reads that node's state
+                # as this step has already left it (the owner comes first in
+                # the graph) and keeps none of its own
+                owner = getattr(step.node.op, "state_owner", None)
+                ctx.extras["state"] = (new_state[owner] if owner
+                                       else state.get(step.node.name))
             args = [env[v] for v in step.in_vids]
             with jax.named_scope(node_scope(step.node)):
                 outs = step.node.op.lower(ctx, args,
@@ -196,7 +203,8 @@ def init_params(
             key = jax.random.fold_in(rng, i)
             i += 1
             init = p.initializer or default_initializer_for(node.op, p)
-            arr = init(key, p.spec.shape, dtype or p.spec.dtype)
+            arr = init(key, p.spec.shape,
+                       p.spec.dtype if p.pin_dtype else dtype or p.spec.dtype)
             sh = plan.param_shardings.get(node.name, {}).get(p.name)
             if sh is not None and not _mesh_is_trivial(mesh):
                 arr = jax.device_put(arr, sh.named_sharding(mesh))

@@ -328,6 +328,31 @@ class FFModel:
         op = PositionEmbedding(num_positions, x.shape[-1], offset, x.dtype)
         return self._add(op, [x], name or "position_embedding")[0]
 
+    # ops with per-slot state that is not a full-length K/V cache
+    # (serve/hybrid_ops.py): Mamba's conv and scan, differential attention
+    # over a window ring, a full cache, or another node's cache
+    def causal_conv1d(self, x, kernel=4, name=None):
+        from .serve.hybrid_ops import CausalConv1d
+
+        op = CausalConv1d(x.shape[-1], kernel, dtype=x.dtype)
+        return self._add(op, [x], name or "causal_conv1d")[0]
+
+    def selective_scan(self, xs, dt, b, c, d_state=16, name=None):
+        from .serve.hybrid_ops import SelectiveScan
+
+        op = SelectiveScan(xs.shape[-1], d_state, dtype=xs.dtype)
+        return self._add(op, [xs, dt, b, c], name or "selective_scan")[0]
+
+    def diff_attention(self, x, embed_dim, num_q_heads, num_kv_heads,
+                       head_dim, layer, mode="full", window=0,
+                       state_owner=None, name=None):
+        from .serve.hybrid_ops import DIFF_ATTENTION
+
+        op = DIFF_ATTENTION[mode](embed_dim, num_q_heads, num_kv_heads,
+                                  head_dim, layer, window, state_owner,
+                                  dtype=x.dtype)
+        return self._add(op, [x], name or "diff_attention")[0]
+
     def spec_inc_multihead_self_attention(self, x, embed_dim, num_q_heads,
                                           num_kv_heads=None, head_dim=None,
                                           rotary_embedding=True,

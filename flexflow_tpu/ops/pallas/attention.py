@@ -117,6 +117,29 @@ def _scale_plumbing(kv_map, num_kv, block_s, k_scale, v_scale):
     return specs, (k_scale.astype(jnp.float32), v_scale.astype(jnp.float32))
 
 
+def _ring_blocks(pos, block_s, s_len, window):
+    """Which seq-blocks of a RING cache a query at ``pos`` needs.
+
+    A window layer's cache is a ring of ``s_len`` slots, position ``p`` at
+    slot ``p % s_len``; a query at ``pos`` sees the ``min(pos + 1, window)``
+    newest positions, a cyclic run of slots that ends at ``pos % s_len``.
+    Returns ``(needed(j), first, newest)``: whether block ``j`` holds one of
+    them, and the blocks of the run's oldest and newest slot.  Scalar
+    arithmetic only: the index maps and the kernel body share it."""
+    pm = pos % s_len
+    nvalid = jnp.minimum(pos + 1, window)
+    newest = pm // block_s
+    first = ((pm - nvalid + 1) % s_len) // block_s
+
+    def needed(j):
+        # the youngest slot of a block that does not hold ``pm`` is its last
+        youngest = jnp.where(newest == j, 0,
+                             (pm - ((j + 1) * block_s - 1)) % s_len)
+        return youngest < nvalid
+
+    return needed, first, newest
+
+
 def _decode_kernel(
     rows_ref,       # scalar prefetch: i32[T] cache row per token
     pos_ref,        # scalar prefetch: i32[T] absolute position per token
@@ -129,6 +152,8 @@ def _decode_kernel(
     use_alibi: bool,
     kv_quant: bool,
     paged: bool = False,
+    window: int = 0,
+    s_len: int = 0,
 ):
     if paged:
         # the page-table prefetch ref is consumed by the index maps only
@@ -152,8 +177,14 @@ def _decode_kernel(
 
     pos = pos_ref[t]
     base = s * block_s
+    if window:
+        # a ring: the block is worth computing if it holds a slot of the
+        # window (the others' DMA was skipped by the index map)
+        run = _ring_blocks(pos, block_s, s_len, window)[0](s)
+    else:
+        run = base <= pos  # blocks past the frontier: DMA already clamped
 
-    @pl.when(base <= pos)  # blocks past the frontier: DMA already clamped
+    @pl.when(run)
     def _compute():
         q = q_ref[0].astype(jnp.float32)               # [KV, gq, D]
         k = k_ref[0].astype(jnp.float32)               # [KV, Bs, D]
@@ -171,7 +202,16 @@ def _decode_kernel(
         if use_alibi:
             slopes = slopes_ref[...][:, :, None].astype(jnp.float32)
             sc = sc + slopes * (key_pos - pos).astype(jnp.float32)
-        sc = jnp.where(key_pos <= pos, sc, NEG_INF)
+        if window:
+            # ``key_pos`` is a ring SLOT here: it holds the position ``age``
+            # back from ``pos``, in the window if that is one of the
+            # min(pos + 1, window) newest
+            age = pos % s_len - key_pos
+            age = jnp.where(age < 0, age + s_len, age)
+            seen = age < jnp.minimum(pos + 1, window)
+        else:
+            seen = key_pos <= pos
+        sc = jnp.where(seen, sc, NEG_INF)
 
         m_prev = m_ref[:, :, 0:1]                       # [KV, gq, 1]
         m_cur = jnp.max(sc, axis=-1, keepdims=True)
@@ -180,7 +220,7 @@ def _decode_kernel(
         p = jnp.exp(sc - m_new)                         # [KV, gq, Bs]
         # mask again post-exp: exp(NEG_INF - m) may not be exactly 0 when a
         # block is fully masked and m_new is NEG_INF (NEG_INF-NEG_INF = 0)
-        p = jnp.where(key_pos <= pos, p, 0.0)
+        p = jnp.where(seen, p, 0.0)
 
         l_new = alpha * l_ref[:, :, 0:1] + jnp.sum(p, -1, keepdims=True)
         v = v_ref[0].astype(jnp.float32)                # [KV, Bs, D]
@@ -204,7 +244,7 @@ def _decode_kernel(
 @functools.partial(
     jax.jit,
     static_argnames=("scale", "block_s", "use_alibi", "interpret",
-                     "page_size"),
+                     "page_size", "window"),
 )
 def decode_attention(
     q: jax.Array,        # [T, QH, D] (RoPE already applied)
@@ -221,12 +261,26 @@ def decode_attention(
     v_scale: Optional[jax.Array] = None,  # scales (None = fp cache)
     page_table: Optional[jax.Array] = None,  # i32[R+1, S//page_size] paged KV
     page_size: int = 0,                      # static; 0 = slot-contiguous
+    window: int = 0,     # static; > 0: the cache is a RING (see below)
 ) -> jax.Array:
+    """``window > 0``: a sliding-window layer.  The cache's seq dim is then a
+    ring — position ``p`` lives at slot ``p % S`` (``S`` at least the window
+    plus the widest step that writes before it attends) — and a query at
+    ``positions[i]`` sees the ``min(positions[i] + 1, window)`` newest
+    positions.  Blocks that hold none of them are neither fetched (the index
+    map sends them to a block that is) nor computed."""
     t, qh, d = q.shape
     _, num_kv, s_len, _ = k_cache.shape
     gq = qh // num_kv
     kv_quant = k_scale is not None
     paged = page_table is not None
+    if window:
+        if paged or use_alibi or kv_quant:
+            raise ValueError("a ring cache is slot-contiguous, fp, and has "
+                             "no positional bias")
+        # finer blocks than a full cache's: a window of 512 in a ring of
+        # 1024 touches 3 blocks of 256 but all of 2 blocks of 512
+        block_s = min(block_s, 256)
     # cap the block so K+V (+ scale) double-buffered blocks fit the budget
     block_s = _fit_block_s(block_s, s_len, num_kv, d,
                            jnp.dtype(k_cache.dtype).itemsize, kv_quant,
@@ -256,6 +310,17 @@ def decode_attention(
 
         prefetch = (rows.astype(jnp.int32), positions.astype(jnp.int32),
                     page_table.astype(jnp.int32))
+    elif window:
+        def kv_map(i, j, rows, pos):
+            # a block outside the window re-maps to one inside it whose
+            # copy Pallas then skips: the run's first block while the grid
+            # has not reached the run, its newest block once past it
+            needed, first, newest = _ring_blocks(pos[i], block_s, s_len,
+                                                 window)
+            skip_to = jnp.where(j > newest, newest, first)
+            return (rows[i], 0, jnp.where(needed(j), j, skip_to), 0)
+
+        prefetch = (rows.astype(jnp.int32), positions.astype(jnp.int32))
     else:
         def kv_map(i, j, rows, pos):
             # clamp to the causal frontier: future blocks re-map to the
@@ -301,7 +366,7 @@ def decode_attention(
         _decode_kernel,
         block_s=block_s, num_kv=num_kv, gq=gq,
         scale=float(scale), use_alibi=use_alibi, kv_quant=kv_quant,
-        paged=paged,
+        paged=paged, window=window, s_len=s_len,
     )
     out = pl.pallas_call(
         kernel,

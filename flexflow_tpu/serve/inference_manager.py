@@ -147,7 +147,8 @@ def searched_serve_strategy(model, budget: int = 300, seed: int = 0,
 
 
 def register_serve_capacities(graph, max_requests, max_seq_len,
-                              max_spec_tokens=0, kv_dtype=None):
+                              max_spec_tokens=0, kv_dtype=None,
+                              max_tokens=None):
     """Record the serving capacities + KV dtype on a serve graph's attention
     ops so planning (``plan_memory_bytes``), the serve search, and the cache
     allocator all see the deployment's real buffer shapes.  Shared by the
@@ -159,6 +160,51 @@ def register_serve_capacities(graph, max_requests, max_seq_len,
             node.op.cost_max_requests = max_requests
             node.op.cost_max_spec = max_spec_tokens
             node.op.kv_dtype = kv_dtype
+        elif getattr(node.op, "slot_state", False):
+            # window rings and recurrent state (serve/hybrid_ops.py): sized
+            # by the slots and, for a ring, by the widest step that writes it
+            node.op.cost_seq_len = max_seq_len
+            node.op.cost_max_requests = max_requests
+            node.op.cost_max_tokens = max_tokens or max_seq_len
+
+
+def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
+                                 max_spec_tokens=0, tp=1,
+                                 pipelined=False) -> None:
+    """A graph that keeps window rings or recurrent state per slot
+    (``slot_state`` ops: serve/hybrid_ops.py) runs slot-contiguous, in its
+    compute dtype, one token a step, on one chip.  Each other deployment
+    option needs something that is not written yet; it is refused here, at
+    compile, by what is missing — none silently takes another path."""
+    kinds = sorted({type(n.op).__name__ for n in graph.nodes
+                    if getattr(n.op, "slot_state", False)})
+    if not kinds:
+        return
+    missing = []
+    if kv_page_size:
+        missing.append("kv_page_size: a page table for a ring that wraps, "
+                       "and copy-on-write of recurrent state at a shared "
+                       "prefix's end")
+    if kv_dtype == "int8":
+        missing.append("kv_dtype='int8': quantise-on-write of the window "
+                       "ring and of the cache the cross-attention layers "
+                       "read")
+    if max_spec_tokens:
+        missing.append("speculation: a recurrent state cannot be rolled "
+                       "back over rejected tokens without a snapshot per "
+                       "tree node")
+    if tp > 1:
+        missing.append("tp > 1: a sharding rule for the conv, the scan and "
+                       "the differential attention's head pairs")
+    if pipelined:
+        missing.append("pp > 1 (the pipelined manager, at any number of "
+                       "stages): the exported scan output and the shared "
+                       "cache cross stage boundaries, and its per-stage "
+                       "state hand-over knows K/V planes only")
+    if missing:
+        raise ValueError(
+            f"this graph keeps per-slot state in {kinds}, which cannot be "
+            "combined with " + "; ".join(missing))
 
 
 def mark_gated_lm_head(graph, out_tids, max_requests) -> bool:
@@ -345,10 +391,22 @@ class InferenceManager:
         if tp_axes is None:
             tp_axes = ("tp",) if mesh is not None and "tp" in mesh.shape else ()
         self.tp_axes = tuple(tp_axes)
+        tp = 1
+        for a in self.tp_axes:
+            tp *= dict(mesh.shape)[a]
+        refuse_unsupported_slot_state(
+            model.graph, kv_dtype=kv_dtype, kv_page_size=kv_page_size,
+            max_spec_tokens=max_spec_tokens, tp=tp)
         # register serve capacities on the attention ops so the search's
         # cost/memory models see the KV + spec buffers (plan_memory_bytes)
         register_serve_capacities(model.graph, max_requests, max_seq_len,
-                                  max_spec_tokens, kv_dtype)
+                                  max_spec_tokens, kv_dtype,
+                                  max_tokens=max_tokens_per_batch)
+        # which path each kind of attention layer took in each program
+        # (Pallas kernel or XLA), written by the ops as they are traced:
+        # {(layer kind, batch type): path} — a fallback is never unseen
+        self.attention_paths: Dict[Tuple[str, str], str] = {}
+        self._paths_counted = 0
         if outputs is None:
             out_tids = [model.graph.nodes[-1].outputs[-1]]
         else:
@@ -562,9 +620,12 @@ class InferenceManager:
 
         w = params_nbytes(self.params)
         kv = self.kv.allocated_bytes(kv_only=False, per_device=True)
+        # the three kinds of per-slot state apart (bytes ONE slot holds)
+        by_kind = {f"slot_{kind}_bytes": b
+                   for kind, b in self.kv.bytes_per_slot().items()}
         telemetry.memory_plan_allocated(
             key, weights_gb=w / 1e9, kv_gb=kv / 1e9,
-            static_gb=(w + kv) / 1e9,
+            static_gb=(w + kv) / 1e9, **by_kind,
         )
 
     # ------------------------------------------------------------------
@@ -574,7 +635,7 @@ class InferenceManager:
         return sample_tokens(logits, sample)
 
     def _step_impl(self, params, state, bc, sample=None, tree_layout=None,
-                   qkv0=None, pages=None):
+                   qkv0=None, pages=None, one_row_per_request=False):
         # ``tree_layout`` is passed ONLY by SpecDecodeScan, whose verify
         # batches are guaranteed slot-major [R, P]; host-built tree batches
         # (SpecInferManager) have variable layouts and must not take the
@@ -583,6 +644,9 @@ class InferenceManager:
         # the marked qkv0_consumer attention op reads it.  ``pages`` is the
         # paged-KV block table (kv_paged.PageTable) every attention op
         # translates its cache coordinates through; None = slot-contiguous.
+        # ``one_row_per_request`` (static; the decode scan passes it): every
+        # live row is a request of its own, so an op with recurrent state
+        # updates all rows at once instead of scanning them in order.
         base = bc if isinstance(bc, BatchConfig) else bc.base
         outs, new_state = self._fwd(
             params,
@@ -596,6 +660,8 @@ class InferenceManager:
                 if not isinstance(bc, BatchConfig) else None,
                 "qkv0": qkv0,
                 "pages": pages,
+                "one_row_per_request": one_row_per_request,
+                "attention_paths": self.attention_paths,
             },
         )
         with jax.named_scope("sample"):
@@ -614,6 +680,20 @@ class InferenceManager:
             InferenceResult(token_ids, logits_max, topk_ids, topk_lp),
             new_state,
         )
+
+    def _count_attention_paths(self) -> None:
+        """One telemetry counter per (attention layer kind, path) the traced
+        programs took — ``attention_path.window_attention.xla`` on a chip is
+        a fallback someone should see.  Counts programs, not launches: an
+        entry is counted once, when the dispatch that traced it returns."""
+        if len(self.attention_paths) == self._paths_counted \
+                or not self.telemetry.enabled:
+            return
+        for (kind, batch), path in list(self.attention_paths.items())[
+                self._paths_counted:]:
+            self.telemetry.metrics.counter(
+                f"attention_path.{kind}.{path}").inc()
+        self._paths_counted = len(self.attention_paths)
 
     def _page_view(self):
         """Current device-side block table (None = slot-contiguous).  Read
@@ -643,6 +723,7 @@ class InferenceManager:
             result, self.state = with_stack_room(
                 self._step, self.params, self.state, bc, sample, None, None,
                 self._page_view())
+        self._count_attention_paths()
         return result
 
     # ------------------------------------------------------------------
@@ -732,7 +813,8 @@ class InferenceManager:
             # prepare_write pre-mapped (and COW-resolved) every page the
             # n_steps positions can reach before dispatch
             result, state = self._step_impl(params, state, bc, stp,
-                                            pages=pages)
+                                            pages=pages,
+                                            one_row_per_request=True)
             toks = result.token_ids
             live = alive  # emission validity for THIS step
             with jax.named_scope("advance"):
@@ -897,6 +979,7 @@ class InferenceManager:
                 self._page_view(), allowed, n_steps=n_steps, eos=eos)
         if self.telemetry.enabled:
             self.telemetry.metrics.counter("decode_scan_steps").inc(n_steps)
+        self._count_attention_paths()
         return tokens, live, ecode, bc
 
     def _join_impl(self, bc, tok_src, src_idx, dst, slot, pos, seq_len,
@@ -1068,6 +1151,7 @@ class InferenceManager:
                 self._page_view(),
                 overlap=bool(self.prefill_overlap
                              and self._overlap_steps is not None))
+        self._count_attention_paths()
         return tokens
 
     def reset(self):

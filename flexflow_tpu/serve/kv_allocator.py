@@ -43,6 +43,15 @@ import jax.numpy as jnp
 # scale planes) — THE byte-accounting vocabulary every consumer shares
 # (admission headroom, the serve search's KV-stream pricing, the ledger)
 KV_BUFFER_NAMES = frozenset({"k", "v", "k_scale", "v_scale"})
+# per-slot state that does NOT grow with the context: the ring a window
+# attention layer keeps of its last positions, and a state-space layer's
+# conv tail and scan state (serve/hybrid_ops.py).  A slot holds them whole
+# from admission on, so they are priced per slot, never per token.
+STATE_KINDS = {
+    "kv_full": KV_BUFFER_NAMES,
+    "kv_window": frozenset({"wk", "wv"}),
+    "recurrent": frozenset({"conv", "ssm"}),
+}
 
 
 def per_device_nbytes(arr) -> float:
@@ -91,12 +100,15 @@ def allocate_attention_state(nodes, strategy, mesh, max_requests,
     (the default only places on multi-device meshes, matching the
     single-plan manager's historical behavior).
     """
-    from .ops import IncMultiHeadSelfAttention
-
     state: Dict[str, Any] = {}
     for node in nodes:
         op = node.op
-        if not isinstance(op, IncMultiHeadSelfAttention):
+        # any op that keeps per-slot state says so through ``state_specs``
+        # (attention caches, window rings, recurrent state); one that reads
+        # another node's state (``state_owner``) allocates none
+        if (not getattr(op, "stateful", False)
+                or not hasattr(op, "state_specs")
+                or getattr(op, "state_owner", None)):
             continue
         head_axes = tuple(strategy.get(node.name, {}).get("head", ()))
         specs = op.state_specs(max_requests, max_seq_len, max_spec_tokens,
@@ -182,6 +194,20 @@ class StageKV:
                     rows = max(arr.shape[0] - 1, 1)  # minus the scratch row
                     total += arr.nbytes / (rows * arr.shape[2])
         return total or None
+
+    def bytes_per_slot(self) -> Dict[str, float]:
+        """Bytes one request slot holds of each kind of state
+        (``STATE_KINDS``), read off the allocated arrays: ``kv_full`` is the
+        slot's whole reserved span (``bytes_per_token`` x the padded seq
+        length), ``kv_window`` and ``recurrent`` do not depend on
+        ``max_seq_len`` at all.  Zeros before :meth:`allocate`."""
+        out = {kind: 0.0 for kind in STATE_KINDS}
+        for bufs in (self.state or {}).values():
+            for name, arr in bufs.items():
+                for kind, names in STATE_KINDS.items():
+                    if name in names:
+                        out[kind] += arr.nbytes / max(arr.shape[0] - 1, 1)
+        return out
 
 
 class KVAllocator:
@@ -281,6 +307,15 @@ class KVAllocator:
         :meth:`StageKV.allocated_bytes` for the ``per_device`` basis."""
         return sum(s.allocated_bytes(kv_only=kv_only, per_device=per_device)
                    for s in self.stages)
+
+    def bytes_per_slot(self) -> Dict[str, float]:
+        """Per-slot bytes by kind of state across all stages (see
+        :meth:`StageKV.bytes_per_slot`)."""
+        out = {kind: 0.0 for kind in STATE_KINDS}
+        for s in self.stages:
+            for kind, b in s.bytes_per_slot().items():
+                out[kind] += b
+        return out
 
     # ---- per-request attribution --------------------------------------
     def bind(self, rid: int, **_) -> Optional[Dict]:

@@ -48,6 +48,14 @@ class ServeModelConfig:
     bias: bool = False                # falcon-rw: linear biases
     use_alibi: bool = False           # mpt
     new_decoder_architecture: bool = False  # falcon >= 40b
+    # phi4flash (SambaY; ``models/phi4flash.py`` says what layer i is).  The
+    # Mamba sizes are the family's defaults; HF's config.json leaves them out.
+    sliding_window: Optional[int] = None
+    mb_per_layer: int = 2
+    mamba_d_state: int = 16
+    mamba_d_conv: int = 4
+    mamba_expand: int = 2
+    mamba_dt_rank: Optional[int] = None  # None = ceil(hidden_size / 16)
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

@@ -56,6 +56,7 @@ from .inference_manager import (
     EXIT_RUNNING,
     mark_gated_lm_head,
     pick_prefill_tile,
+    refuse_unsupported_slot_state,
     register_serve_capacities,
     sample_tokens,
     tensor_parallel_strategy,
@@ -301,6 +302,7 @@ class PipelinedInferenceManager:
                 f"n_micro={fixed}", stacklevel=2)
             self.n_micro = fixed
 
+        refuse_unsupported_slot_state(model.graph, pipelined=True)
         register_serve_capacities(model.graph, max_requests, max_seq_len,
                                   max_spec_tokens, kv_dtype)
         if outputs is None:

@@ -997,7 +997,14 @@ class RequestManager:
         self._pending_since.pop(self.pending[best], None)
         return self.pending.pop(best)
 
-    def _fill_slots(self):
+    def _fill_slots(self) -> int:
+        """Give free slots to pending requests; returns how many were taken.
+        A request that takes a slot starts it from ZERO state: its first
+        row sits at position 0, where every op with per-slot state (K/V
+        cache, window ring, conv tail, scan state) starts anew whatever the
+        slot held before — the ``state_reset`` argument of ``host_admit``
+        counts these."""
+        taken = 0
         for i, occupant in enumerate(self.slots):
             if occupant is None and self.pending:
                 rid = self._pop_pending()
@@ -1007,6 +1014,7 @@ class RequestManager:
                 req.slot = i
                 req.status = RequestStatus.PREFILLING
                 self.slots[i] = rid
+                taken += 1
                 self._kv_bind(rid)
                 tel = self.telemetry
                 if tel.enabled:
@@ -1020,6 +1028,7 @@ class RequestManager:
                             req.trace_id,
                             queue_wait_s=(tel.now() - ts["enqueue"]
                                           if "enqueue" in ts else None))
+        return taken
 
     def _try_preempt(self) -> bool:
         """Preempt the lowest-priority DECODING request (newest first among
@@ -1043,20 +1052,24 @@ class RequestManager:
         self.preempt(victim.rid)
         return True
 
-    def _admit(self):
+    def _admit(self, span=None) -> None:
+        """Admission; ``span`` (the ``host_admit`` span it runs under) is
+        told how many slots were taken (``state_reset``)."""
         if self.admission_closed:
             # a migration drain is in progress: nothing new takes a slot
             # (pending requests wait; they transplant to — or readmit
             # after a rollback on — whichever manager serves next)
             return
-        self._fill_slots()
+        taken = self._fill_slots()
         if self.res.preemption:
             # bounded: each iteration either admits into a freed slot or
             # stops (no admissible victim)
             for _ in range(len(self.slots)):
                 if not (self.pending and self._try_preempt()):
                     break
-                self._fill_slots()
+                taken += self._fill_slots()
+        if taken and span is not None:
+            span.set(state_reset=taken)
 
     def _active(self) -> List[Request]:
         return [
@@ -1083,8 +1096,8 @@ class RequestManager:
         accumulators, so the time budget shows scheduling cost apart from
         batch-build cost.
         """
-        with self._span("host_admit", phase=True):
-            self._admit()
+        with self._span("host_admit", phase=True) as admit:
+            self._admit(admit)
         with self._span("host_prepare", phase=True):
             return self._build_next_batch()
 
@@ -1430,8 +1443,8 @@ class RequestManager:
         ONE host sync at the end, vs a dispatch per chunk (+ a host sync
         per request boundary) on the per-step path.
         """
-        with self._span("host_admit", phase=True):
-            self._admit()
+        with self._span("host_admit", phase=True) as admit:
+            self._admit(admit)
         active = self._active()
         tile = getattr(self.im, "prefill_tile", 1)
         return (
@@ -1816,9 +1829,11 @@ class RequestManager:
         dispatch failure un-joins the request back to the queue; the
         per-tick path retries it with the full pressure machinery."""
         im = self.im
-        with self._span("host_admit", phase=True):
+        with self._span("host_admit", phase=True) as admit:
             pre = {rid for rid in self.slots if rid is not None}
-            self._fill_slots()
+            taken = self._fill_slots()
+            if taken:
+                admit.set(state_reset=taken)
             newly = [rid for rid in self.slots
                      if rid is not None and rid not in pre]
         stamped = []

@@ -72,7 +72,8 @@ def _no_compile_cache():
 
 def _lower(kernel, sh, kv, gq, d, s, variant):
     """Lowered (not yet compiled) ``kernel`` at the serve path's shapes,
-    for one cache variant: bf16, int8 (+ scale planes) or paged-512."""
+    for one cache variant: bf16, int8 (+ scale planes), paged-512, or a
+    ring read through a window."""
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=sh)
     k = sds((R + 1, kv, s, d),
             jnp.int8 if variant == "int8" else jnp.bfloat16)
@@ -82,6 +83,8 @@ def _lower(kernel, sh, kv, gq, d, s, variant):
     if variant == "paged":
         kw["page_table"] = sds((R + 1, s // PAGE), jnp.int32)
         static["page_size"] = PAGE
+    if variant.startswith("ring"):  # a sliding-window layer's ring cache
+        static["window"] = int(variant[len("ring"):])
     scale = d ** -0.5
     qh = kv * gq
     if kernel == "decode":
@@ -124,6 +127,12 @@ _CASES = [
     ("prefill", 8, 1, 128, 2048, "bf16"),
     ("decode", 1, 16, 128, 8192, "bf16"),
     ("prefill", 1, 16, 128, 8192, "bf16"),
+    # differential attention (phi4flash): 10 K/V pairs cached as heads of
+    # 128, four zero-padded query heads each; the one full-length cache,
+    # and a 512-window in a ring of 1024 slots
+    ("decode", 10, 4, 128, 8192, "bf16"),
+    ("prefill", 10, 4, 128, 8192, "bf16"),
+    ("decode", 10, 4, 128, 1024, "ring512"),
 ]
 
 
