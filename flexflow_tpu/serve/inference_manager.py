@@ -1069,6 +1069,10 @@ class InferenceManager:
         token ids — the host reads only the sample points it needs, once,
         after the whole scan.  With LM-head gating (``bcs.logit_slots``)
         the emitted ids are [n_chunks, max_requests], indexed by slot.
+        The LAST chunk's ids come back a second time in the flat
+        ``[max_tokens]`` layout ``join_slot`` takes its token source in
+        (gated ids zero-padded: index = slot), so a prompt fed here splices
+        into a running stretch with no program of its own in between.
 
         ``overlap`` (static): software-pipeline the scan — step i ALSO
         computes chunk i+1's embedding→norm→layer-0 QKV (``_project_chunk0``)
@@ -1108,7 +1112,8 @@ class InferenceManager:
             state, tokens = jax.lax.scan(
                 body, state,
                 (bcs, idx, folds_all) if per_row else (bcs, idx))
-            return tokens, state  # tokens: i32[n_chunks, T or R]
+            # tokens: i32[n_chunks, T or R]
+            return tokens, self._flat_last(tokens), state
 
         # chunk i+1's batch config rides step i's xs; the final step
         # re-projects its own chunk (uniform program; output unused)
@@ -1129,13 +1134,24 @@ class InferenceManager:
             body, (state, pre0),
             (bcs, bcs_next, idx, folds_all) if per_row
             else (bcs, bcs_next, idx))
-        return tokens, state
+        return tokens, self._flat_last(tokens), state
 
-    def prefill_scan(self, bcs, sample=None, counts=None):
+    def _flat_last(self, tokens):
+        """The last chunk's token ids as ``join_slot``'s ``tok_src``:
+        i32[max_tokens] (slot-indexed gated ids are zero-padded to it)."""
+        last = tokens[-1]
+        short = self.max_tokens - last.shape[0]
+        return jnp.pad(last, (0, short)) if short > 0 else last
+
+    def prefill_scan(self, bcs, sample=None, counts=None,
+                     flat_last: bool = False):
         """Run a stacked PrefillBatchConfig (leading chunk axis) on device.
 
         ``sample``: optional ``(key, temperature, top_p)`` so the chunks
         carrying a prompt's final position emit a SAMPLED first token.
+        ``flat_last``: return ``(tokens, last)`` — ``last`` is the final
+        chunk's ids in ``join_slot``'s flat layout (the same program either
+        way: the caller that splices a prompt in just keeps it).
         """
         assert self.params is not None, "call init_operators_inference() first"
         if self.fault_injector is not None:
@@ -1146,13 +1162,13 @@ class InferenceManager:
                                  phase="dispatch", kind="prefill_scan",
                                  n_steps=n_chunks, n_chunks=n_chunks,
                                  **(counts or {})):
-            tokens, self.state = with_stack_room(
+            tokens, last, self.state = with_stack_room(
                 self._pscan, self.params, self.state, bcs, sample,
                 self._page_view(),
                 overlap=bool(self.prefill_overlap
                              and self._overlap_steps is not None))
         self._count_attention_paths()
-        return tokens
+        return (tokens, last) if flat_last else tokens
 
     def reset(self):
         """Clear all cache contents (new serving session)."""

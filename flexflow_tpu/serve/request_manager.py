@@ -1091,6 +1091,15 @@ class RequestManager:
         the next token of that request (last prefill token, or the decode
         token).  Mirrors ``RequestManager::prepare_next_batch``.
 
+        What still reaches it from :meth:`_serve_tick`: a 1-step trailer
+        (some decoder has one token left, or one cache position), and the
+        MIXED branch — decode rows first, then prompt rows, flat — only
+        where :meth:`_tiled_feed` turns a prompt away: no Pallas kernels
+        (CPU, tile 1), a manager without ``prefill_scan`` (serve/pp.py),
+        an off-tile ``prefill_offset``, the un-chained loop, a closed
+        admission.  With the kernels on, a prompt admitted among live
+        decoders joins the decode stretch instead (:meth:`_stretch_join`).
+
         Phase attribution (StepProfiler): admission/slot-fill runs under
         ``host_admit``, batch assembly under ``host_prepare`` — separate
         accumulators, so the time budget shows scheduling cost apart from
@@ -1175,7 +1184,9 @@ class RequestManager:
             self._kv_prepare(spans)
             self._prof_account(
                 spans, logit_rows=len(sample_points) if gate else None)
-            self._note_batch(0, sum(len(s[1]) for s in segments), seq_lens)
+            n_prefill = sum(len(s[1]) for s in segments)
+            self._note_batch(0, n_prefill, seq_lens)
+            self._count_feed("tiled", n_prefill, chunks=1)
             self._step_counts = self._launch_counts(spans, 0)
             return pbc, sample_points
 
@@ -1254,6 +1265,7 @@ class RequestManager:
         self._kv_prepare(spans)
         self._prof_account(spans)
         self._note_batch(n_decode, len(tokens) - n_decode, seq_lens)
+        self._count_feed("flat", len(tokens) - n_decode)
         self._step_counts = self._launch_counts(spans, n_decode)
         return bc, sample_points
 
@@ -1343,14 +1355,20 @@ class RequestManager:
     def _scan_steps_possible(self) -> int:
         """How many pure-decode steps can run as ONE on-device scan now.
 
-        > 1 only when no admission/prefill work is pending and every active
-        request is decoding; bounded by the smallest remaining token budget
-        (so no slot overshoots max_new_tokens) and by cache headroom.
+        > 1 only when some request is decoding and every other active
+        request is a prompt the stretch can splice in itself
+        (:meth:`_tiled_feed`: it rides the tiled prefill scan and joins
+        before the first segment); bounded by the decoders' smallest
+        remaining token budget (so no slot overshoots max_new_tokens) and
+        by cache headroom.
         """
         active = self._active()
-        if (not active
-                or any(r.status is not RequestStatus.DECODING
-                       for r in active)):
+        decoding = [r for r in active
+                    if r.status is RequestStatus.DECODING]
+        joiners = [r for r in active
+                   if r.status is not RequestStatus.DECODING]
+        if not decoding or not all(
+                self._tiled_feed(r, joining=True) for r in joiners):
             return 0
         if self.pending:
             # pending work blocks a stretch ONLY when the per-tick path
@@ -1368,9 +1386,14 @@ class RequestManager:
                              or any(s is None for s in self.slots)
                              or self._preempt_would_fire()):
                 return 0
-        n = min(r.max_new_tokens - len(r.generated) for r in active)
+        n = min(r.max_new_tokens - len(r.generated) for r in decoding)
+        if joiners:
+            # a row with ONE token left must not send the joiners' prompts
+            # down the flat mixed step: it rides a 2-step segment and
+            # freezes on device after its token (the ``allowed`` mask)
+            n = max(n, 2)
         n = min(n, self.scan_chunk,
-                self.im.max_seq_len - max(r.seq_len for r in active) + 1)
+                self.im.max_seq_len - max(r.seq_len for r in decoding) + 1)
         # armed deadlines or pending cancels bound the stretch: lifecycle
         # reaping happens at host step boundaries, so an uncapped scan
         # would overshoot a deadline by up to scan_chunk device steps.
@@ -1433,11 +1456,45 @@ class RequestManager:
     health_check_every = 16
 
     # ------------------------------------------------------------------
+    def _tiled_feed(self, req: Request, joining: bool = False) -> bool:
+        """Does ``req``'s remaining prompt ride the tiled prefill scan
+        (``im.prefill_scan``: ``PrefillBatchConfig`` chunks, the Q-tiled
+        kernel, block KV writes, a gated LM head)?  THE predicate of the
+        prompt feed — a wave and a joiner ask the same one — read off what
+        the manager and the request show: the Pallas kernels are on, the
+        tile is a real one, the manager scans prefill chunks, and the
+        feed starts ON a tile (contract (d); a prefix-cache hit or a
+        starvation fallback can leave it off).  ``joining``: the prompt
+        would be spliced into a RUNNING batch, which only the chained
+        stretch does.  Everything else keeps the flat step."""
+        im = self.im
+        tile = getattr(im, "prefill_tile", 1)
+        return (tile > 1
+                and bool(getattr(im, "use_pallas", False))
+                and hasattr(im, "prefill_scan")
+                and req.prefill_offset % tile == 0
+                and (not joining
+                     or (self.chain_segments
+                         and hasattr(im, "decode_scan_async"))))
+
+    def _count_feed(self, path: str, tokens: int, chunks: int = 0) -> None:
+        """Prompt tokens fed, by path (``prompt_feed.tiled_tokens`` /
+        ``.flat_tokens``); the tiled feed also counts its chunks and the
+        rows of them that held no prompt token."""
+        tel = self.telemetry
+        if not tel.enabled or not tokens:
+            return
+        tel.metrics.counter(f"prompt_feed.{path}_tokens").inc(tokens)
+        if chunks:
+            tel.metrics.counter("prompt_feed.tiled_chunks").inc(chunks)
+            tel.metrics.counter("prompt_feed.tiled_padded_rows").inc(
+                chunks * self.im.max_tokens - tokens)
+
     def _prefill_stretch_possible(self) -> bool:
         """Can the whole current prefill wave run as on-device scans?
 
         True when every active request is PREFILLING (no decode latency to
-        protect) and the InferenceManager has the tiled-prefill path.  The
+        protect) and rides the tiled feed (:meth:`_tiled_feed`).  The
         stretch then feeds every request's remaining prompt through
         ``prefill_scan`` — one dispatch per power-of-two chunk segment and
         ONE host sync at the end, vs a dispatch per chunk (+ a host sync
@@ -1446,22 +1503,22 @@ class RequestManager:
         with self._span("host_admit", phase=True) as admit:
             self._admit(admit)
         active = self._active()
-        tile = getattr(self.im, "prefill_tile", 1)
         return (
-            tile > 1
-            and self.im.use_pallas
-            and hasattr(self.im, "prefill_scan")
-            and bool(active)
+            bool(active)
             and all(r.status is RequestStatus.PREFILLING for r in active)
             and any(r.prefill_offset < len(r.prefill_tokens) for r in active)
-            and all(r.prefill_offset % tile == 0 for r in active)
+            and all(self._tiled_feed(r) for r in active)
         )
 
-    def _prefill_chunks(self, gate: bool, sampling: bool):
-        """Cut every prefilling request's remaining feed into tile-aligned
-        chunks: per-chunk numpy fields, logit slots, sample folds, the
-        sample points ``(chunk_idx, result_idx, rid)`` and each chunk's
-        ``(start, take)``.  Advances ``prefill_offset``."""
+    def _prefill_chunks(self, gate: bool, sampling: bool, reqs=None,
+                        depths=None):
+        """Cut the remaining feed of ``reqs`` (default: every prefilling
+        request) into tile-aligned chunks: per-chunk numpy fields, logit
+        slots, sample folds, the sample points ``(chunk_idx, result_idx,
+        rid)`` and each chunk's ``(start, take)``.  Advances
+        ``prefill_offset``.  ``depths``: ``{slot: cache depth}`` of rows a
+        running chain is ahead of the committed host view on (their DEVICE
+        depths go into ``seq_lens``)."""
         im = self.im
         tile = im.prefill_tile
         cap = im.max_tokens
@@ -1476,7 +1533,9 @@ class RequestManager:
         seq = np.zeros(im.max_requests, np.int32)
         for req in self._active():
             seq[req.slot] = req.seq_len
-        for req in self._active():
+        for slot, depth in (depths or {}).items():
+            seq[slot] = depth
+        for req in (self._active() if reqs is None else reqs):
             if req.status is not RequestStatus.PREFILLING:
                 continue
             while req.prefill_offset < len(req.prefill_tokens):
@@ -1512,30 +1571,35 @@ class RequestManager:
                 feeds.append((start, take))
         return chunks, ls_chunks, fold_chunks, points, feeds
 
-    def _prefill_stretch(self) -> None:
-        """Prefill every active request's remaining feed via prefill_scan."""
+    def _prefill_feed(self, joiners=None, depths=None, rows: int = 0):
+        """THE tiled prompt feed, a wave's and a joiner's alike: cut the
+        remaining prompts (:meth:`_prefill_chunks`) — of every prefilling
+        request, or of ``joiners``, requests about to be spliced into a
+        running batch of ``rows`` live decode rows whose device depths are
+        ``depths`` — and dispatch the chunks through ``im.prefill_scan``,
+        asynchronously.  Returns ``(points, outs)`` — ``outs`` holds
+        ``(first chunk, tokens [segment, T or R], last)`` per dispatched
+        segment, all on the device (``last``: the segment's final chunk in
+        ``join_slot``'s flat layout) — or None when a dispatch failed past
+        the retry budget (:meth:`_fail_inflight` already requeued or
+        failed the requests fed: the joiners, or every active one).
+        """
         import jax
         import jax.numpy as jnp
 
         im = self.im
         tile = im.prefill_tile
-        # the whole stretch's write spans, prepared before the first
-        # dispatch (the scans run back-to-back with no host boundary to
-        # map pages at)
-        self._kv_prepare([
-            (r.rid, r.prefill_offset, len(r.prefill_tokens))
-            for r in self._active()
-            if r.status is RequestStatus.PREFILLING
-            and r.prefill_offset < len(r.prefill_tokens)])
         gate = bool(getattr(im, "gate_lm_head", False))
         sampling = self.gen.temperature > 0.0
         with self._span("host_prepare", phase=True):
             chunks, ls_chunks, fold_chunks, points, feeds = \
-                self._prefill_chunks(gate, sampling)
+                self._prefill_chunks(gate, sampling, joiners, depths)
+        affected = None if joiners is None else (
+            lambda: [r.rid for r in joiners])
         # stack chunk fields host-side (ONE device transfer per field per
         # segment, not five tiny transfers per chunk) and scan in power-of-
         # two segments so each distinct scan length compiles at most once
-        outs = []   # (start_chunk, token array [seg, max_tokens]) — read after
+        outs = []
         at = 0
         while at < len(chunks):
             seg = 1 << (min(len(chunks) - at, 64).bit_length() - 1)
@@ -1563,25 +1627,44 @@ class RequestManager:
                            jnp.asarray(np.stack(fold_chunks[at: at + seg])))
             # each chunk's start offset and size: what the prefill
             # kernel's least work is computed from
-            cnt = {"rows": 0,
-                   "prompt_tokens": sum(t for _, t in feeds[at: at + seg]),
+            fed = sum(t for _, t in feeds[at: at + seg])
+            cnt = {"rows": rows, "joiners": len(joiners or ()),
+                   "prompt_tokens": fed,
                    "ctx_sum": sum(st for st, _ in feeds[at: at + seg])}
             res = self._guarded(
                 "prefill_scan",
                 lambda s=stacked, a=smp, c=cnt: im.prefill_scan(
-                    s, a, counts=c))
+                    s, a, counts=c, flat_last=True),
+                affected_fn=affected)
             if res is None:
-                # dispatch failed past the retry budget: _fail_inflight
-                # already requeued/failed every prefilling request (their
-                # advanced offsets were reset by the recompute path) — the
-                # partial segments' KV is dead weight the next occupant of
-                # each slot overwrites
-                self.scan_runs += 1
-                return
-            outs.append((at, res))
+                return None
+            self._count_feed("tiled", fed, chunks=seg)
+            outs.append((at, *res))
             at += seg
+        return points, outs
+
+    def _prefill_stretch(self) -> None:
+        """Prefill every active request's remaining feed via prefill_scan."""
+        # the whole stretch's write spans, prepared before the first
+        # dispatch (the scans run back-to-back with no host boundary to
+        # map pages at)
+        self._kv_prepare([
+            (r.rid, r.prefill_offset, len(r.prefill_tokens))
+            for r in self._active()
+            if r.status is RequestStatus.PREFILLING
+            and r.prefill_offset < len(r.prefill_tokens)])
+        fed = self._prefill_feed()
+        self.scan_runs += 1
+        if fed is None:
+            # dispatch failed past the retry budget: _fail_inflight
+            # already requeued/failed every prefilling request (their
+            # advanced offsets were reset by the recompute path) — the
+            # partial segments' KV is dead weight the next occupant of
+            # each slot overwrites
+            return
+        points, outs = fed
         with self._span("readback", phase=True):
-            toks = {start: np.asarray(t) for start, t in outs}  # one sync
+            toks = {start: np.asarray(t) for start, t, _ in outs}  # one sync
         self.profiler.host_sync(len(outs))
         starts = sorted(toks)
         with self._span("commit", prefill_tokens=len(points)):
@@ -1592,8 +1675,7 @@ class RequestManager:
                 self._append_token(
                     req, int(toks[start][chunk_idx - start, flat_idx]))
                 self._maybe_finish(req)
-        self.steps += len(chunks)
-        self.scan_runs += 1
+        self.steps += sum(len(t) for t in toks.values())
 
     def _decode_stretch(self, n: int) -> None:
         """Run one decode stretch with ONE host sync.
@@ -1611,16 +1693,22 @@ class RequestManager:
         * armed deadlines/cancels bound SEGMENTS (the host clock-checks
           between dispatches, same ``lifecycle_quantum`` granularity)
           instead of terminating the stretch;
-        * arrivals landing mid-stretch JOIN the running batch at the next
-          segment boundary — async flat prefill of the prompt, then
-          ``join_slot`` splices the held first token into the batch — so
-          pending work no longer degenerates serving to one dispatch per
-          token.
+        * prompts JOIN the running batch (:meth:`_stretch_join`): the
+          requests that hold a slot at the tick's start but have not been
+          fed — admitted among live decoders — BEFORE the first segment,
+          arrivals landing mid-stretch at the next segment boundary.  The
+          prompt is fed asynchronously (tile-aligned chunks through the
+          prefill scan; flat chunks where :meth:`_tiled_feed` says no),
+          then ``join_slot`` splices the held first token into the batch
+          — so pending work neither degenerates serving to one dispatch
+          per token nor sends a prompt through the 512-row flat step.
 
         Everything materializes in ONE readback at stretch end (tokens,
         emission masks, exit codes), then commits in dispatch order —
         bit-identical to the per-tick loop by construction (same sample
-        folds, same masks).
+        folds, same masks).  A joiner's first token therefore becomes
+        visible when the stretch returns, up to ``scan_chunk`` steps
+        after its prompt was fed.
         """
         if not (self.chain_segments
                 and hasattr(self.im, "decode_scan_async")):
@@ -1628,11 +1716,16 @@ class RequestManager:
         im = self.im
         prof = self.profiler
         eos = self.gen.eos_token_id if self.gen.stop_on_eos else None
-        # whole first-segment write spans up front, BEFORE building the
-        # batch (page-pressure preemption inside the prepare can evict a
-        # victim, which must drop out of the batch)
-        self._kv_prepare([(r.rid, r.seq_len - 1, r.seq_len - 1 + n)
-                          for r in self._active()])
+        # whole first-segment write spans — and the whole prompt of every
+        # request that joins before it — up front, BEFORE building the
+        # batch: nothing is in flight yet, so page pressure may still
+        # preempt here (a victim must drop out of the batch)
+        self._kv_prepare([
+            (r.rid, r.seq_len - 1,
+             r.seq_len - 1 + min(n, r.max_new_tokens - len(r.generated)))
+            if r.status is RequestStatus.DECODING
+            else (r.rid, r.prefill_offset, len(r.prefill_tokens))
+            for r in self._active()])
         active = [r for r in self._active()
                   if r.status is RequestStatus.DECODING]
         if not active:
@@ -1659,6 +1752,11 @@ class RequestManager:
         def remaining(req):
             return req.max_new_tokens - len(req.generated) - sched[req.rid]
 
+        def next_writes(among, seg):
+            return [(req.rid, dev_seq[req.rid] - 1,
+                     dev_seq[req.rid] - 1 + min(seg, remaining(req)))
+                    for req, _ in among if remaining(req) > 0]
+
         # chronological commit log: ("scan", seg, [(flat, rid)], toks,
         # live, ecode) per dispatched segment, ("join", req, token_ids,
         # src_idx) per spliced arrival — all values LAZY until the single
@@ -1666,9 +1764,16 @@ class RequestManager:
         commits: List[Tuple] = []
         total = 0
         n_segments = 0
-        n_joins = 0
         seg = n
-        while True:
+        go = True
+        if len(active) < len(self._active()):
+            # requests admitted among these decoders (a slot, no prompt
+            # fed yet) join BEFORE the first segment; a joined row's first
+            # decode writes need their pages like a later segment's do
+            bc = self._stretch_join(bc, rows, sched, dev_seq, commits, eos)
+            go = self._kv_prepare_nopreempt(
+                next_writes(rows[len(active):], seg))
+        while go:
             with self._span("host_prepare", phase=True):
                 ks: Dict[int, int] = {}
                 allowed = np.zeros(im.max_tokens, np.int32)
@@ -1743,7 +1848,6 @@ class RequestManager:
                             for rid in self.pending)):
                 bc = self._stretch_join(bc, rows, sched, dev_seq,
                                         commits, eos)
-                n_joins = sum(1 for c in commits if c[0] == "join")
             armed = [r.deadline_s for r, _ in rows
                      if r.deadline_s is not None]
             if armed and self.clock() >= min(armed):
@@ -1751,7 +1855,7 @@ class RequestManager:
             rem = [remaining(req) for req, _ in rows]
             rem_max = max(rem) if rem else 0
             if rem_max < 2:
-                break   # a 1-step trailer rides the next tick's mixed
+                break   # a 1-step trailer rides the next tick's flat
                         # step (no single-step scan compile class)
             seg = min(rem_cap, rem_max)
             if armed:
@@ -1759,10 +1863,7 @@ class RequestManager:
             seg = 1 << (seg.bit_length() - 1)
             if seg < 2:
                 break
-            spans = [(req.rid, dev_seq[req.rid] - 1,
-                      dev_seq[req.rid] - 1 + min(seg, remaining(req)))
-                     for req, _ in rows if remaining(req) > 0]
-            if not self._kv_prepare_nopreempt(spans):
+            if not self._kv_prepare_nopreempt(next_writes(rows, seg)):
                 break   # page pressure resolves on the per-tick path
 
         # ---- single readback + chronological commit -------------------
@@ -1780,7 +1881,7 @@ class RequestManager:
         prof.host_sync()
         codes: Dict[int, int] = {}
         # which program made each token the host now appends: the decode
-        # scan, or a joiner's flat prefill spliced in by the join
+        # scan, or a joiner's prompt feed spliced in by the join
         made = {"scan": 0, "join": 0}
         with self._span("commit") as sp:
             for item in ready:
@@ -1818,32 +1919,34 @@ class RequestManager:
         if prof.enabled:
             prof.note(decode_quantum=n, stretch_steps=total,
                       stretch_segments=n_segments,
-                      stretch_joins=n_joins)
+                      stretch_joins=sum(c[0] == "join" for c in commits))
 
     def _stretch_join(self, bc, rows, sched, dev_seq, commits, eos):
-        """Admit pending arrivals INTO the running stretch (on-device
-        continuous batching): fill free slots, asynchronously prefill
-        each joiner's prompt (flat chunks, no readback), then splice its
-        held first token into the live batch via ``join_slot`` — the
+        """Admit prompts INTO the stretch's batch (on-device continuous
+        batching): fill free slots, then for every request that holds a
+        slot but is not a row yet — taken just now at a segment boundary,
+        or by the tick's admission before the first segment — feed its
+        prompt asynchronously (:meth:`_stretch_prefill`, no readback) and
+        splice its held first token into the batch via ``join_slot``: the
         device decodes it from the next segment on.  Page exhaustion or
         dispatch failure un-joins the request back to the queue; the
         per-tick path retries it with the full pressure machinery."""
         im = self.im
-        with self._span("host_admit", phase=True) as admit:
-            pre = {rid for rid in self.slots if rid is not None}
-            taken = self._fill_slots()
-            if taken:
-                admit.set(state_reset=taken)
-            newly = [rid for rid in self.slots
-                     if rid is not None and rid not in pre]
+        if not self.admission_closed:
+            with self._span("host_admit", phase=True) as admit:
+                taken = self._fill_slots()
+                if taken:
+                    admit.set(state_reset=taken)
+        seen = {req.rid for req, _ in rows}
+        seen.update(c[1].rid for c in commits if c[0] == "join")
         stamped = []
-        for rid in newly:
-            req = self.requests[rid]
-            if len(rows) >= im.max_tokens:
-                # no flat-row capacity left: the leftover stays slotted
-                # and prefills on the next tick's per-step path
+        for req in self._active():
+            if (req.status is not RequestStatus.PREFILLING
+                    or req.rid in seen or len(rows) >= im.max_tokens):
+                # (no flat-row capacity left: the leftover stays slotted
+                # for a later boundary or the next tick)
                 continue
-            with self._span("join", rid=rid):
+            with self._span("join", rid=req.rid):
                 bc = self._join_one(req, bc, rows, sched, dev_seq, commits,
                                     eos, stamped)
         if stamped:
@@ -1858,23 +1961,25 @@ class RequestManager:
                   stamped):
         """One joiner of :meth:`_stretch_join`: feed its prompt, then
         splice it in.  Returns the batch the stretch goes on with."""
-        out = self._stretch_prefill(req, rows, dev_seq)
+        live = sum(1 for r2, _ in rows
+                   if r2.max_new_tokens - len(r2.generated) > sched[r2.rid])
+        out = self._stretch_prefill(req, rows, dev_seq, live)
         if out is None:
             if (req.status is RequestStatus.PREFILLING
                     and req.slot >= 0
                     and self.slots[req.slot] == req.rid):
                 self._unjoin(req)
             return bc
-        res, src = out
+        tok_src, src = out
         stamped.append(req.rid)
         L = len(req.prefill_tokens)
-        commits.append(("join", req, res.token_ids, src))
+        commits.append(("join", req, tok_src, src))
         if req.max_new_tokens - len(req.generated) <= 1:
             # the held token is the whole remaining budget: nothing
             # to decode — it completes at the stretch readback
             return bc
         dst = len(rows)
-        bc = self.im.join_slot(bc, res.token_ids, src, dst, req.slot,
+        bc = self.im.join_slot(bc, tok_src, src, dst, req.slot,
                                L, L + 1, dst + 1, eos=eos,
                                counts={"rows": dst + 1, "rid": req.rid})
         rows.append((req, dst))
@@ -1882,32 +1987,44 @@ class RequestManager:
         dev_seq[req.rid] = L + 1
         return bc
 
-    def _stretch_prefill(self, req, rows, dev_seq):
-        """Asynchronously feed one joining request's whole prompt (flat
-        chunks, results left on device) and return ``(result, src_idx)``
-        of the final chunk — the joiner's first generated token, read
-        back only at the stretch's single readback.  None when the feed
-        could not run (page-pool exhaustion before dispatch, or a
-        dispatch failure after retries — the latter already requeued the
-        request via the retry guard)."""
+    def _stretch_prefill(self, req, rows, dev_seq, live: int = 0):
+        """Asynchronously feed one joining request's whole remaining
+        prompt, results left on device, and return ``(tok_src, src_idx)``:
+        a device array in ``join_slot``'s flat layout and where the
+        joiner's first generated token sits in it — read back only at the
+        stretch's single readback.  The prompt rides the tiled prefill
+        scan (:meth:`_prefill_feed`, the wave path's cutter and dispatch)
+        wherever :meth:`_tiled_feed` holds, flat chunks through ``im.step``
+        otherwise.  None when the feed could not run (page-pool exhaustion
+        before dispatch, or a dispatch failure after retries — the latter
+        already requeued the request via the retry guard).  ``live``: the
+        decode rows that wait behind this feed (a span argument)."""
         im = self.im
         feed = req.prefill_tokens
         L = len(feed)
         if not self._kv_prepare_nopreempt(
                 [(req.rid, req.prefill_offset, L)]):
             return None
+        # running rows' cache depths are their DEVICE depths (the chain is
+        # ahead of the committed host view); only the joiner's own entry
+        # is read by its feed
+        depths = {r2.slot: dev_seq[r2.rid] for r2, _ in rows}
+        if self._tiled_feed(req, joining=True):
+            fed = self._prefill_feed([req], depths, rows=live)
+            if not fed or not fed[1]:
+                return None   # failed, or nothing left to feed (cannot
+                              # happen: the prefix cache keeps the last token)
+            ((_, src, _),), outs = fed
+            return outs[-1][2], src
         res = src = None
         while req.prefill_offset < L:
             start = req.prefill_offset
             take = min(im.max_tokens, L - start)
             done = start + take == L
             with self._span("host_prepare", phase=True):
-                # running rows' cache depths are their DEVICE depths (the
-                # chain is ahead of the committed host view); only the
-                # joiner's own entry is read by its feed
                 seq_lens = np.zeros(im.max_requests, np.int32)
-                for r2, _ in rows:
-                    seq_lens[r2.slot] = dev_seq[r2.rid]
+                for slot, depth in depths.items():
+                    seq_lens[slot] = depth
                 seq_lens[req.slot] = start + take
                 bc2 = BatchConfig.build(
                     list(feed[start: start + take]), [req.slot] * take,
@@ -1924,11 +2041,11 @@ class RequestManager:
                 affected_fn=lambda: [req.rid])
             if out is None:
                 return None
+            self._count_feed("flat", take)
             req.prefill_offset = start + take
-            res, src = out, take - 1
+            res, src = out.token_ids, take - 1
         if res is None:
-            return None   # nothing left to feed (cannot happen: the
-                          # prefix cache keeps at least the last token)
+            return None   # nothing left to feed (see above)
         return res, src
 
     def _unjoin(self, req) -> None:
@@ -2038,11 +2155,20 @@ class RequestManager:
                       stretch_segments=1, stretch_joins=0)
 
     def _serve_tick(self) -> None:
-        """One scheduling decision + dispatch of the incremental loop:
-        prefill stretch, decode stretch, or a single mixed step — every
-        dispatch runs under the retry guard, so a transient fault degrades
-        to requeue/reject of the affected requests instead of killing the
-        loop."""
+        """One scheduling decision + dispatch of the incremental loop —
+        every dispatch runs under the retry guard, so a transient fault
+        degrades to requeue/reject of the affected requests instead of
+        killing the loop:
+
+        * every active request still has prompt to feed and rides the
+          tiled feed: a prefill stretch (the wave);
+        * someone decodes, and whoever else holds a slot can be spliced
+          in (:meth:`_scan_steps_possible`): a decode stretch, which
+          starts with the join of those prompts and admits later arrivals
+          at its segment boundaries;
+        * otherwise a single flat step: a 1-step trailer, or a mixed step
+          where the tiled feed does not apply (see
+          :meth:`prepare_next_batch`)."""
         tel = self.telemetry
         # ``pc_ns``: this clock at the tick's entry — the one subtraction
         # that lays perf_counter stamps (the serving records, the ring)
