@@ -2508,11 +2508,11 @@ def slo_overload_dryrun(out_dir=None):
         # The ladder walk here is calibrated against tick-paced decode:
         # chained stretches drain this mix without ever saturating to
         # SHED (the chained engine's throughput is the host_tick
-        # section's job), so pin the legacy per-tick path for a stable
-        # escalation walk.
-        for rep in fleet.replicas:
-            rep.rm.chain_segments = False
-        records = fleet.serve_with_arrivals(list(arrivals), clock=_Tick())
+        # section's job), so pace the replicas one token per tick (the
+        # fleet loop sets their ``scan_chunk`` from ``quantum``) for a
+        # stable escalation walk.
+        records = fleet.serve_with_arrivals(list(arrivals), clock=_Tick(),
+                                            quantum=1)
         return fleet, bo, records
 
     variants = {}
@@ -2617,19 +2617,19 @@ def host_tick_dryrun(out_dir=None):
     """Hermetic ``--dry-run`` host-tick elimination section
     (serve/request_manager.py chained decode stretches): the SAME seeded
     Poisson arrival stream served twice on the virtual clock — once on
-    the legacy per-tick loop pinned to ``quantum=1`` (one host round
-    trip per token), once on the chained engine (admission, slot joins
+    the flat per-step loop (``scan_chunk = 1``: one host round trip
+    per token), once on the chained engine (admission, slot joins
     and lifecycle exit ride the device dispatch chain; ONE host sync per
     stretch) — demonstrating the acceptance contract with no device
     work:
 
-    * **bit-identity**: every request's token stream matches the legacy
-      run exactly, greedy AND seeded (the ``(rid, token_index)`` sample
-      fold makes the stream a pure function of the request, not the
-      schedule);
+    * **bit-identity**: every request's token stream matches the
+      per-step run exactly, greedy AND seeded (the ``(rid, token_index)``
+      sample fold makes the stream a pure function of the request, not
+      the schedule);
     * **host-sync collapse**: the chained run does exactly one readback
       per decode stretch (``host_syncs_per_stretch == 1``) where the
-      quantum-1 loop pays one per token;
+      per-step loop pays one per token;
     * **dispatch amortization**: ``dispatches_per_token`` drops with the
       stretch length (``<= 1/stretch`` for pure decode);
     * **zero steady-state recompiles**: a second identical serve on the
@@ -2673,7 +2673,7 @@ def host_tick_dryrun(out_dir=None):
         prof = StepProfiler(clock=_Tick())
         rm = RequestManager(im, gen, telemetry=telemetry, profiler=prof)
         if not chained:
-            rm.chain_segments = False
+            rm.scan_chunk = 1   # one flat step (one host sync) per token
         # per-stretch counter sampling: exact host syncs / dispatches
         # attributable to each decode stretch
         stretch_syncs, stretch_disp = [], []
@@ -2686,9 +2686,7 @@ def host_tick_dryrun(out_dir=None):
             stretch_disp.append(prof.work["dispatches"] - d0)
 
         rm._decode_stretch = sampled
-        recs = rm.serve_with_arrivals(
-            list(arrivals), clock=_Tick(),
-            **({"quantum": 1} if not chained else {}))
+        recs = rm.serve_with_arrivals(list(arrivals), clock=_Tick())
         if rm_out is not None:
             rm_out.append(rm)
         toks = {rid: recs[rid]["tokens"] for rid in sorted(recs)}
@@ -2748,7 +2746,7 @@ def host_tick_dryrun(out_dir=None):
         "summary": summary,
         **variants["greedy"],
         "seeded": variants["seeded"],
-        "note": "same seeded Poisson stream, legacy quantum-1 loop vs "
+        "note": "same seeded Poisson stream, flat per-step loop vs "
                 "chained decode stretches on the virtual clock: token "
                 "streams bit-identical (greedy AND seeded), exactly one "
                 "host sync per decode stretch vs one per token, "

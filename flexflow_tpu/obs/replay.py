@@ -399,8 +399,7 @@ class ReplayHarness:
             target.im.fault_injector = inj
 
     def replay(self, target, clock: Optional[Callable[[], float]] = None,
-               quantum: int = 8, pin: bool = True,
-               record_trace=None) -> Dict[int, Dict]:
+               pin: bool = True, record_trace=None) -> Dict[int, Dict]:
         """Fidelity replay: drive ``target`` with the recorded arrival
         stream on a virtual clock (``pin=True`` installs the recorded
         gen/fault/kill provenance first).  Returns the replayed records;
@@ -414,7 +413,7 @@ class ReplayHarness:
                                arrivals=len(self.trace.arrivals))
         return target.serve_with_arrivals(
             self.arrivals(), clock=clock or VirtualClock(),
-            quantum=quantum, record_trace=record_trace)
+            record_trace=record_trace)
 
     def verify(self, records: Dict[int, Dict]) -> Dict:
         """Bit-identity check of a replayed run against the recording:

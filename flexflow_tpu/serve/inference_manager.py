@@ -864,7 +864,7 @@ class InferenceManager:
                 )
         return tokens, live, ecode, state, bc
 
-    def _decode_scan_guards(self, bc, n_steps: int, max_position=None,
+    def _decode_scan_guards(self, bc, n_steps: int, max_position: int,
                             rows=None) -> int:
         """Shared pre-dispatch validation for the scan paths; returns the
         width the scan of ``bc`` runs at (``decode_scan_width``), for the
@@ -873,15 +873,12 @@ class InferenceManager:
         ``max_position``: the highest ``token_position`` in the batch as
         HOST bookkeeping (the chained path always knows it — reading it
         off a device-resident ``bc`` would force the mid-stretch sync the
-        whole design removes).  Falls back to reading ``bc`` when the
-        caller has no host-side count (external hand-built batches).
+        whole design removes).
 
         ``rows``: the caller's host count of rows that hold a request (the
         dispatch span's ``rows``), where it has one.  More than the scan's
         width would be cut by the compaction and come back
         ``EXIT_NOT_IN_BATCH`` without a word, so they are refused here."""
-        import numpy as np
-
         from .ops import DUS_MAX_TOKENS
 
         width = decode_scan_width(bc)
@@ -898,9 +895,6 @@ class InferenceManager:
                 "re-lay out the full cache every step",
                 stacklevel=2,
             )
-        if max_position is None:
-            rows = int(np.count_nonzero(np.asarray(bc.request_index) >= 0))
-            max_position = int(np.max(np.asarray(bc.token_position)))
         if rows is not None and rows > width:
             raise ValueError(
                 f"decode_scan got {rows} rows with a request; a "
@@ -917,7 +911,10 @@ class InferenceManager:
 
     def decode_scan(self, bc, n_steps: int, eos: Optional[int] = None,
                     sample=None, counts=None):
-        """Run ``n_steps`` decode steps on device.
+        """Run ``n_steps`` decode steps on device: :meth:`decode_scan_async`
+        for a caller that has no host bookkeeping of ``bc`` — the top
+        position and the count of rows with a request are read off the
+        batch here — and wants no budgets or exit codes.
 
         Returns ``(tokens, live, bc)``: i32[n_steps, T] token ids,
         bool[n_steps, T] emission validity (False once a slot passed its
@@ -926,20 +923,13 @@ class InferenceManager:
         scan itself ran on one row per slot (``_decode_scan_impl``); a
         token means something only where ``live``.
         """
-        assert self.params is not None, "call init_operators_inference() first"
-        width = self._decode_scan_guards(bc, n_steps)
-        if self.fault_injector is not None:
-            self.fault_injector.maybe_fail("decode_scan")
-        with self.telemetry.span("decode_scan_dispatch", cat="dispatch",
-                                 track="dispatch", prof=self.profiler,
-                                 phase="dispatch", kind="decode_scan",
-                                 n_steps=n_steps, width=width,
-                                 **(counts or {})):
-            tokens, live, _, self.state, bc = with_stack_room(
-                self._scan, self.params, self.state, bc, sample,
-                self._page_view(), None, n_steps=n_steps, eos=eos)
-        if self.telemetry.enabled:
-            self.telemetry.metrics.counter("decode_scan_steps").inc(n_steps)
+        import numpy as np
+
+        rows = int(np.count_nonzero(np.asarray(bc.request_index) >= 0))
+        tokens, live, _, bc = self.decode_scan_async(
+            bc, n_steps, eos=eos, sample=sample,
+            max_position=int(np.max(np.asarray(bc.token_position))),
+            counts={**(counts or {}), "rows": rows})
         return tokens, live, bc
 
     def decode_scan_async(self, bc, n_steps: int, eos: Optional[int] = None,

@@ -500,10 +500,8 @@ class MigrationController:
         back with the incumbent's queue intact."""
         new_rm.admission_closed = True  # until commit reopens it
         new_rm.clock = rm.clock  # deadlines stay on one time base
-        # decode pacing crosses the switch: an operator who pinned
-        # tick-paced decode (chain_segments off) or a custom stretch
-        # bound must not silently revert to the defaults mid-session
-        new_rm.chain_segments = rm.chain_segments
+        # decode pacing crosses the switch: a pinned stretch bound must
+        # not silently revert to the default mid-session
         new_rm.scan_chunk = rm.scan_chunk
         new_rm.lifecycle_quantum = rm.lifecycle_quantum
         spec_on = (spec_shape(candidate.get("plan_key", "")) is not None

@@ -726,75 +726,30 @@ class PipelinedInferenceManager:
 
     def decode_scan(self, bc, n_steps: int, eos: Optional[int] = None,
                     sample=None, counts=None):
-        """``n_steps`` pure-decode macro-steps, host-dispatched but never
-        host-synced: each micro-batch's next BatchConfig derives on device
-        (``_advance_impl``) and flows back to stage 0, so the host only
-        reads tokens once at the end.  Micro-batches interleave across
-        stages step by step (i-major dispatch order).
+        """:meth:`decode_scan_async` read back, for a caller with no host
+        bookkeeping of ``bc`` (the top position is read off the batch
+        here) and no budgets: host ``(tokens, live)`` and the advanced
+        BatchConfig.
 
         A token means something only where ``live`` — the one contract of
         both managers' scans.  This one runs all ``max_tokens`` rows and
         returns the padding rows' argmax; InferenceManager's runs one row
         per slot and returns 0 on the rows it did not run.
         """
-        assert self.stages[0].params is not None, \
-            "call init_operators_inference() first"
-        last = int(np.max(np.asarray(bc.token_position))) + n_steps
-        if last > self.max_seq_len:
-            raise ValueError(
-                f"decode_scan would reach position {last} > max_seq_len "
-                f"{self.max_seq_len}")
-        mbs = self._microbatches(bc)
-        m = len(mbs)
-        rep = self.stages[-1].replicated
-        mbs = [jax.device_put(mb, rep) for mb in mbs]
-        alive = [mb.request_index >= 0 for mb in mbs]
-        eos_hit = [jnp.zeros_like(a) for a in alive]
-        toks = [[None] * m for _ in range(n_steps)]
-        lives = [[None] * m for _ in range(n_steps)]
-        tel = self.telemetry
-        if tel.enabled:
-            tel.metrics.gauge("pp_bubble_frac").set(
-                max(0, self.pp - m) / self.pp)
-        # one table fetch for the whole scan: the manager pre-mapped every
-        # page the n_steps positions can reach (no mid-scan mutation)
-        pv = self._page_view()
-        for i in range(n_steps):
-            with tel.span("pp_decode_macro_step", cat="pp", track="pp",
-                          step=i, n_micro=m, kind="decode_scan",
-                          **(counts or {})):
-                for j in range(m):
-                    smp = None
-                    if sample is not None:
-                        if len(sample) > 3:
-                            key, t, p, folds = sample
-                            k = folds.shape[0] // m
-                            f = folds[j * k: (j + 1) * k]
-                            smp = (key, t, p,
-                                   f + jnp.array([0, i], jnp.int32))
-                        else:
-                            key, t, p = sample
-                            smp = (jax.random.fold_in(key, i * m + j), t, p)
-                    res = self._dispatch(mbs[j], smp, mb=j, pages=pv)
-                    mbs[j], alive[j], eos_hit[j], live = self._advance(
-                        mbs[j], res.token_ids, alive[j], eos_hit[j],
-                        jnp.int32(i), None, eos=eos)
-                    toks[i][j] = res.token_ids
-                    lives[i][j] = live
-        tokens = np.stack([
-            np.concatenate([np.asarray(t) for t in row]) for row in toks
-        ])
-        live_np = np.stack([
-            np.concatenate([np.asarray(v) for v in row]) for row in lives
-        ])
-        bc_out = self._merge_bcs(mbs)
-        return tokens, live_np, bc_out
+        tokens, live, _, bc = self.decode_scan_async(
+            bc, n_steps, eos=eos, sample=sample,
+            max_position=int(np.max(np.asarray(bc.token_position))),
+            counts=counts)
+        return np.asarray(tokens), np.asarray(live), bc
 
     def decode_scan_async(self, bc, n_steps: int, eos: Optional[int] = None,
                           sample=None, allowed=None, max_position=None,
                           counts=None):
-        """``n_steps`` pure-decode macro-steps with NOTHING materialized:
-        returns LAZY device values — ``(tokens [n, max_tokens], live
+        """``n_steps`` pure-decode macro-steps, host-dispatched but never
+        host-synced: each micro-batch's next BatchConfig derives on device
+        (``_advance_impl``) and flows back to stage 0, and micro-batches
+        interleave across stages step by step (i-major dispatch order).
+        Returns LAZY device values — ``(tokens [n, max_tokens], live
         masks, per-row exit codes, advanced BatchConfig)`` — so a chained
         stretch dispatches segment after segment (pp hops included,
         device-to-device) and reads everything back once at stretch end.
@@ -805,9 +760,9 @@ class PipelinedInferenceManager:
         row (EXIT_NOT_IN_BATCH for pad/frozen-at-entry rows).
 
         ``max_position`` is REQUIRED: the host-known largest starting
-        token position across rows.  The legacy ``decode_scan`` reads it
-        from the batch with ``np.max`` — a host sync the chained path
-        cannot afford on a device-resident mid-stretch BatchConfig.
+        token position across rows.  ``decode_scan`` reads it from the
+        batch with ``np.max`` — a host sync the chained path cannot
+        afford on a device-resident mid-stretch BatchConfig.
         """
         assert self.stages[0].params is not None, \
             "call init_operators_inference() first"

@@ -228,10 +228,8 @@ class RequestManager:
         # deployment's predicted-vs-allocated HBM is recorded into the
         # handle's memory ledger once, here (host-side only — pinned
         # bit-identical with the layer on or off).
-        kv = getattr(im, "kv", None)
-        if kv is not None:
-            kv.reset_attribution()
-        if self.telemetry.enabled and hasattr(im, "publish_memory"):
+        im.kv.reset_attribution()
+        if self.telemetry.enabled:
             im.publish_memory(self.telemetry)
         # resilient serving (serve/resilience.py): admission/deadline/
         # preemption/retry policy + the seeded chaos hook.  The injector is
@@ -259,8 +257,8 @@ class RequestManager:
         # instead of dropping them, and readmission restores (checksum-
         # verified) instead of re-prefilling.  No-op for the
         # slot-contiguous allocator (attach_host_tier returns None there).
-        if kv is not None and self.res.host_tier_bytes:
-            kv.attach_host_tier(self.res.host_tier_bytes)
+        if self.res.host_tier_bytes:
+            im.kv.attach_host_tier(self.res.host_tier_bytes)
         # deadline/TTL clock — serve_with_arrivals swaps in its loop clock
         # for its duration so virtual-clock tests stay hermetic; _sleep is
         # the retry backoff's wait (injectable for the same reason)
@@ -280,7 +278,7 @@ class RequestManager:
         self.plan_health = plan_health
         if (plan_health is not None
                 and getattr(plan_health, "kv_allocator", None) is None):
-            plan_health.kv_allocator = kv
+            plan_health.kv_allocator = im.kv
         self._health_ticks = 0
         # live plan migration (serve/migration.py): an attached
         # MigrationController gets a tick-boundary slot via
@@ -453,8 +451,7 @@ class RequestManager:
             # page-granular under a paged allocator: a request can only
             # ever hold whole pages, so its worst-case need rounds up to
             # the page size (round_need is identity for slot-contiguous)
-            kv0 = getattr(self.im, "kv", None)
-            rnd = kv0.round_need if kv0 is not None else (lambda t: t)
+            rnd = self.im.kv.round_need
             committed = sum(rnd(self._seq_len_needed(r)) for r in live) \
                 + rnd(self._seq_len_needed(req))
             # the budget: an explicit byte cap when configured (this is
@@ -463,13 +460,10 @@ class RequestManager:
             # fraction of the allocator's own byte capacity — ONE
             # arithmetic, owned by the KVAllocator, shared with
             # preemption pricing and the memory ledger
-            kv = getattr(self.im, "kv", None)
             cap_bytes = (res.kv_budget_bytes
                          if res.kv_budget_bytes is not None
                          else res.kv_headroom_frac
-                         * (kv.capacity_tokens if kv is not None
-                            else self.im.max_requests * self.im.max_seq_len)
-                         * per_tok)
+                         * self.im.kv.capacity_tokens * per_tok)
             if committed * per_tok > cap_bytes:
                 return (f"KV headroom: {committed * per_tok / 2**20:.2f}"
                         f" MiB committed > {cap_bytes / 2**20:.2f} MiB "
@@ -696,10 +690,9 @@ class RequestManager:
             # here, so no terminal outcome can leak it (pinned by
             # tests/test_kv_allocator.py); the returned peak-bytes stamp
             # rides finish telemetry and serving records
-            kv = getattr(self.im, "kv", None)
-            if kv is not None:
-                req.kv_bytes = max(
-                    req.kv_bytes, kv.release(req.rid, tokens=req.seq_len))
+            req.kv_bytes = max(
+                req.kv_bytes,
+                self.im.kv.release(req.rid, tokens=req.seq_len))
 
     def _terminate(self, req: Request, status: RequestStatus,
                    site: str = "") -> None:
@@ -713,8 +706,8 @@ class RequestManager:
         self._pending_since.pop(req.rid, None)
         self._release_slot(req)
         req.prefill_src = None  # recompute feed is dead weight once terminal
-        kv = getattr(self.im, "kv", None)
-        if kv is not None and kv.host_tier is not None:
+        kv = self.im.kv
+        if kv.host_tier is not None:
             # a terminal request's host-tier pages are garbage too — drop
             # them now instead of waiting for the tier's LRU (the no-leak
             # contract extends to the host tier per terminal outcome)
@@ -812,7 +805,7 @@ class RequestManager:
                               RequestStatus.DECODING):
             raise ValueError(
                 f"cannot preempt request {rid} in status {req.status.name}")
-        self._kv_spill(req, getattr(self.im, "kv", None))
+        self._kv_spill(req, self.im.kv)
         self._release_slot(req)
         req.prefill_src = list(req.prompt) + list(req.generated)
         req.n_prefed = len(req.generated)
@@ -1096,9 +1089,9 @@ class RequestManager:
         MIXED branch — decode rows first, then prompt rows, flat — only
         where :meth:`_tiled_feed` turns a prompt away: no Pallas kernels
         (CPU, tile 1), a manager without ``prefill_scan`` (serve/pp.py),
-        an off-tile ``prefill_offset``, the un-chained loop, a closed
-        admission.  With the kernels on, a prompt admitted among live
-        decoders joins the decode stretch instead (:meth:`_stretch_join`).
+        an off-tile ``prefill_offset``, a closed admission.  With the
+        kernels on, a prompt admitted among live decoders joins the
+        decode stretch instead (:meth:`_stretch_join`).
 
         Phase attribution (StepProfiler): admission/slot-fill runs under
         ``host_admit``, batch assembly under ``host_prepare`` — separate
@@ -1136,7 +1129,7 @@ class RequestManager:
         # a pure-prefill step with Pallas enabled ships tile-aligned chunks
         # (PrefillBatchConfig -> the Q-tiled prefill kernel); mixed
         # decode+prefill steps keep the flat layout
-        tile = getattr(self.im, "prefill_tile", 1)
+        tile = self.im.prefill_tile
         if (not tokens and tile > 1 and self.im.use_pallas
                 and any(r.status is RequestStatus.PREFILLING
                         for r in self._active())
@@ -1169,7 +1162,7 @@ class RequestManager:
             # LM-head gating: completing segments' sample points ride the
             # chunk's logit_slots, the step computes logits ONLY there, and
             # the result arrays are indexed by SLOT (shape [max_requests])
-            gate = bool(getattr(self.im, "gate_lm_head", False))
+            gate = self.im.gate_lm_head
             pbc, last_flat = PrefillBatchConfig.build(
                 segments, seq_lens, tile,
                 max_tokens=self.im.max_tokens,
@@ -1368,21 +1361,18 @@ class RequestManager:
         joiners = [r for r in active
                    if r.status is not RequestStatus.DECODING]
         if not decoding or not all(
-                self._tiled_feed(r, joining=True) for r in joiners):
+                self._tiled_feed(r) for r in joiners):
             return 0
         if self.pending:
             # pending work blocks a stretch ONLY when the per-tick path
             # could actually act on it right now — a free slot to fill, or
             # a preemption that would fire.  Otherwise (all slots busy, no
-            # victim) the queue is waiting regardless, and the chained
-            # stretch path admits mid-stretch joiners itself the moment a
-            # slot frees, so the stretch proceeds
+            # victim) the queue is waiting regardless, and the stretch
+            # admits mid-stretch joiners itself the moment a slot frees,
+            # so the stretch proceeds
             eligible = [rid for rid in self.pending
                         if not self._held(self.requests[rid])]
-            chained = (self.chain_segments
-                       and hasattr(self.im, "decode_scan_async")
-                       and not self.admission_closed)
-            if eligible and (not chained
+            if eligible and (self.admission_closed
                              or any(s is None for s in self.slots)
                              or self._preempt_would_fire()):
                 return 0
@@ -1397,8 +1387,8 @@ class RequestManager:
         # armed deadlines or pending cancels bound the stretch: lifecycle
         # reaping happens at host step boundaries, so an uncapped scan
         # would overshoot a deadline by up to scan_chunk device steps.
-        # (Under the chained path this bounds SEGMENTS, not the stretch —
-        # the chain clock-checks between dispatches; see _decode_stretch.)
+        # (This bounds SEGMENTS, not the stretch — the chain clock-checks
+        # between dispatches; see _decode_stretch.)
         if any(r.deadline_s is not None or r.cancel_requested
                for r in active):
             n = min(n, self.lifecycle_quantum)
@@ -1428,13 +1418,7 @@ class RequestManager:
                    for r in self._active())
 
     scan_chunk = 32  # sync-amortization window for the decode scan
-    # chain decode-scan segments back-to-back (no readback in between) up
-    # to scan_chunk total steps, admitting arrivals into the RUNNING
-    # batch at segment boundaries (on-device continuous batching).  Off:
-    # the legacy one-dispatch-per-stretch path (the bit-identity
-    # comparator tests/test_host_tick.py pins against)
-    chain_segments = True
-    # serve_with_arrivals hooks for the chained path: pump registers
+    # serve_with_arrivals hooks for the decode stretch: pump registers
     # newly-due arrivals at segment boundaries; stamp records
     # prefill_start_s for mid-stretch joiners
     _arrival_pump = None
@@ -1456,26 +1440,21 @@ class RequestManager:
     health_check_every = 16
 
     # ------------------------------------------------------------------
-    def _tiled_feed(self, req: Request, joining: bool = False) -> bool:
+    def _tiled_feed(self, req: Request) -> bool:
         """Does ``req``'s remaining prompt ride the tiled prefill scan
         (``im.prefill_scan``: ``PrefillBatchConfig`` chunks, the Q-tiled
         kernel, block KV writes, a gated LM head)?  THE predicate of the
         prompt feed — a wave and a joiner ask the same one — read off what
         the manager and the request show: the Pallas kernels are on, the
-        tile is a real one, the manager scans prefill chunks, and the
-        feed starts ON a tile (contract (d); a prefix-cache hit or a
-        starvation fallback can leave it off).  ``joining``: the prompt
-        would be spliced into a RUNNING batch, which only the chained
-        stretch does.  Everything else keeps the flat step."""
+        tile is a real one, the manager scans prefill chunks
+        (``serve/pp.py``'s does not), and the feed starts ON a tile
+        (contract (d); a prefix-cache hit or a starvation fallback can
+        leave it off).  Everything else keeps the flat step."""
         im = self.im
-        tile = getattr(im, "prefill_tile", 1)
-        return (tile > 1
-                and bool(getattr(im, "use_pallas", False))
+        return (im.prefill_tile > 1
+                and im.use_pallas
                 and hasattr(im, "prefill_scan")
-                and req.prefill_offset % tile == 0
-                and (not joining
-                     or (self.chain_segments
-                         and hasattr(im, "decode_scan_async"))))
+                and req.prefill_offset % im.prefill_tile == 0)
 
     def _count_feed(self, path: str, tokens: int, chunks: int = 0) -> None:
         """Prompt tokens fed, by path (``prompt_feed.tiled_tokens`` /
@@ -1589,7 +1568,7 @@ class RequestManager:
 
         im = self.im
         tile = im.prefill_tile
-        gate = bool(getattr(im, "gate_lm_head", False))
+        gate = im.gate_lm_head
         sampling = self.gen.temperature > 0.0
         with self._span("host_prepare", phase=True):
             chunks, ls_chunks, fold_chunks, points, feeds = \
@@ -1680,11 +1659,10 @@ class RequestManager:
     def _decode_stretch(self, n: int) -> None:
         """Run one decode stretch with ONE host sync.
 
-        With :attr:`chain_segments` on (and an ``im`` exposing the async
-        scan path) the stretch is a CHAIN of back-to-back
-        ``decode_scan_async`` segments — dispatched with no readback
-        between them — that keeps running up to ``scan_chunk`` total
-        steps while any row has budget left:
+        The stretch is a CHAIN of back-to-back ``decode_scan_async``
+        segments — dispatched with no readback between them — that keeps
+        running up to ``scan_chunk`` total steps while any row has budget
+        left:
 
         * rows of UNEQUAL remaining budgets ride one stretch (the device
           freezes each row at ITS budget via the ``allowed`` mask and
@@ -1710,9 +1688,6 @@ class RequestManager:
         visible when the stretch returns, up to ``scan_chunk`` steps
         after its prompt was fed.
         """
-        if not (self.chain_segments
-                and hasattr(self.im, "decode_scan_async")):
-            return self._decode_stretch_single(n)
         im = self.im
         prof = self.profiler
         eos = self.gen.eos_token_id if self.gen.stop_on_eos else None
@@ -2009,7 +1984,7 @@ class RequestManager:
         # ahead of the committed host view); only the joiner's own entry
         # is read by its feed
         depths = {r2.slot: dev_seq[r2.rid] for r2, _ in rows}
-        if self._tiled_feed(req, joining=True):
+        if self._tiled_feed(req):
             fed = self._prefill_feed([req], depths, rows=live)
             if not fed or not fed[1]:
                 return None   # failed, or nothing left to feed (cannot
@@ -2067,8 +2042,8 @@ class RequestManager:
         cache).  Returns False on exhaustion — the caller stops extending
         the stretch (or skips the join) and the per-tick path resolves
         the pressure with the full victim machinery."""
-        kv = kv if kv is not None else getattr(self.im, "kv", None)
-        if kv is None or not getattr(kv, "paged", False) or not spans:
+        kv = kv if kv is not None else self.im.kv
+        if not kv.paged or not spans:
             return True
         from .kv_paged import PagePoolExhausted
         try:
@@ -2078,81 +2053,6 @@ class RequestManager:
         except PagePoolExhausted:
             return False
         return True
-
-    def _decode_stretch_single(self, n: int) -> None:
-        """The unchained stretch: n decode steps as ONE decode_scan
-        dispatch, one host sync (the ``chain_segments=False`` baseline
-        the continuous-batching bit-identity tests compare against)."""
-        # the scan writes n positions per request with no host boundary in
-        # between — map (and COW-resolve) the whole span up front, BEFORE
-        # building the batch: page-pressure preemption inside the prepare
-        # can evict a victim (slot -> -1), which must drop out of the
-        # batch instead of corrupting seq_lens via negative indexing
-        self._kv_prepare([(r.rid, r.seq_len - 1, r.seq_len - 1 + n)
-                          for r in self._active()])
-        active = [r for r in self._active()
-                  if r.status is RequestStatus.DECODING]
-        if not active:
-            return
-        prof = self.profiler
-        with self._span("host_prepare", phase=True):
-            tokens, reqi, pos = [], [], []
-            points = []
-            for req in active:
-                tokens.append(req.generated[-1])
-                reqi.append(req.slot)
-                pos.append(req.seq_len - 1)
-                points.append(req.rid)
-            seq_lens = np.zeros(self.im.max_requests, np.int32)
-            for req in active:
-                seq_lens[req.slot] = req.seq_len
-            bc = BatchConfig.build(
-                tokens, reqi, pos, seq_lens,
-                max_tokens=self.im.max_tokens,
-                max_requests=self.im.max_requests,
-            )
-        if prof.enabled:
-            # n decode steps: each streams the weights and reads the
-            # growing causally-live prefix (seq, seq+1, ... seq+n-1)
-            prof.account(
-                prof.card_for(self.im),
-                [(r.rid, n, n * r.seq_len + n * (n - 1) // 2)
-                 for r in active],
-                passes=n)
-        eos = self.gen.eos_token_id if self.gen.stop_on_eos else None
-        # per-request sample keys: row i starts at (rid_i, len(generated_i))
-        # and the scan advances the token index per step on device
-        smp = self._sample_for(list(enumerate(points)), self.im.max_tokens)
-        cnt = {"rows": len(active), "prompt_tokens": 0,
-               "ctx_sum": int(seq_lens.sum())}
-        out = self._guarded(
-            "decode_scan",
-            lambda: self.im.decode_scan(bc, n, eos=eos, sample=smp,
-                                        counts=cnt))
-        if out is None:
-            self.scan_runs += 1
-            return
-        toks, live, _ = out
-        with self._span("readback", phase=True):
-            toks = np.asarray(toks)
-            live = np.asarray(live)
-        prof.host_sync()
-        with self._span("commit") as sp:
-            before = self.tokens_decoded
-            for s in range(n):
-                for flat, rid in enumerate(points):
-                    req = self.requests[rid]
-                    if (req.status is not RequestStatus.DECODING
-                            or not live[s, flat]):
-                        continue
-                    self._append_token(req, int(toks[s, flat]))
-                    self._maybe_finish(req)
-            sp.set(scan_tokens=self.tokens_decoded - before, join_tokens=0)
-        self.steps += n
-        self.scan_runs += 1
-        if prof.enabled:
-            prof.note(decode_quantum=n, stretch_steps=n,
-                      stretch_segments=1, stretch_joins=0)
 
     def _serve_tick(self) -> None:
         """One scheduling decision + dispatch of the incremental loop —
@@ -2237,15 +2137,12 @@ class RequestManager:
         construction (``align=prefill_tile``), preserving the tiled
         prefill path's contract (d).
         """
-        kv = getattr(self.im, "kv", None)
-        if kv is None:
-            return
+        kv = self.im.kv
         req = self.requests[rid]
         # the tile alignment only matters when the tiled Pallas prefill
         # path will consume the resumed offset; the flat gather path
         # accepts any start, so it keeps every matched token
-        align = (getattr(self.im, "prefill_tile", 1)
-                 if getattr(self.im, "use_pallas", False) else 1)
+        align = self.im.prefill_tile if self.im.use_pallas else 1
         info = kv.bind(rid, slot=req.slot, tokens=req.prefill_tokens,
                        need=self._seq_len_needed(req), align=align)
         if info is None:
@@ -2278,7 +2175,7 @@ class RequestManager:
         spill — the r9 recompute feed still covers recovery
         bit-identically, so a failed spill can never corrupt, only cost.
         """
-        if kv is None or kv.host_tier is None or req.slot < 0:
+        if kv.host_tier is None or req.slot < 0:
             return
         site = f"kv_swap_out:{req.rid}"
         tokens = list(req.prompt) + list(req.generated)
@@ -2316,7 +2213,7 @@ class RequestManager:
         :class:`~.kv_paged.HostTierCorruption` (checksum mismatch) is NOT
         retried — the host copy itself is damaged, so the entry drops and
         recompute takes over."""
-        if kv is None or kv.host_tier is None or not kv.has_spill(req.rid):
+        if kv.host_tier is None or not kv.has_spill(req.rid):
             return 0
         from .kv_paged import HostTierCorruption
 
@@ -2374,8 +2271,8 @@ class RequestManager:
         retries; otherwise the exhaustion propagates (an admission gate
         sized with ``round_need`` prevents reaching it).
         """
-        kv = kv if kv is not None else getattr(self.im, "kv", None)
-        if kv is None or not getattr(kv, "paged", False) or not spans:
+        kv = kv if kv is not None else self.im.kv
+        if not kv.paged or not spans:
             return
         from .kv_paged import PagePoolExhausted
 
@@ -2411,23 +2308,18 @@ class RequestManager:
             return None
         return min(victims, key=lambda r: (r.priority, -r.rid))
 
-    def kv_snapshot(self) -> Optional[Dict]:
+    def kv_snapshot(self) -> Dict:
         """The deployment's live KV view (pure read — see
         :meth:`KVAllocator.snapshot`); overridden by managers holding
         more than one deployment's caches (the spec manager returns the
-        combined target+draft view its gauges publish).  None without an
-        allocator."""
-        kv = getattr(self.im, "kv", None)
-        return kv.snapshot() if kv is not None else None
+        combined target+draft view its gauges publish)."""
+        return self.im.kv.snapshot()
 
     def _sync_kv(self) -> None:
         """One per-tick snapshot of live cache depths into the allocator
         (per-request peaks, watermarks, occupancy/headroom/fragmentation
         gauges when telemetry is live) — host bookkeeping only."""
-        kv = getattr(self.im, "kv", None)
-        if kv is None:
-            return
-        kv.observe(
+        self.im.kv.observe(
             {r.rid: r.seq_len for r in self._active()
              if r.status in (RequestStatus.PREFILLING,
                              RequestStatus.DECODING)},
@@ -2475,9 +2367,9 @@ class RequestManager:
             return
         slo = self.slo
         tel = self.telemetry
-        kv = getattr(self.im, "kv", None)
+        kv = self.im.kv
         occ = (kv.live_tokens() / kv.capacity_tokens
-               if kv is not None and kv.capacity_tokens else 0.0)
+               if kv.capacity_tokens else 0.0)
         depths: Dict[str, int] = {c: 0 for c in slo.classes}
         lc_depth = 0
         for rid in self.pending:
@@ -2520,7 +2412,7 @@ class RequestManager:
         # pressure; the level walk/hysteresis pins stay as they are
         # because SPILL is an action DEFER_BATCH and above carry, not a
         # new enum member (fleet.py hardcodes level comparisons).
-        if (kv is not None and kv.host_tier is not None
+        if (kv.host_tier is not None
                 and occ >= bo.config.kv_pressure_frac):
             victims = [r for r in self._active()
                        if r.status is RequestStatus.DECODING
@@ -2584,8 +2476,7 @@ class RequestManager:
             meta["slo"] = self.slo.snapshot()
         return meta
 
-    def serve_with_arrivals(self, arrivals, clock=None, quantum: int = 8,
-                            record_trace=None,
+    def serve_with_arrivals(self, arrivals, clock=None, record_trace=None,
                             _t0=None, _records=None, _open=None):
         """Arrival-driven serving: requests join the running admit/retire
         loop at their offered times (open-loop load, the serving_under_load
@@ -2598,14 +2489,11 @@ class RequestManager:
         ``deadline_s``, ``spec`` — per-request speculation mode under a
         SpecInferManager).  ``clock``: 0-arg seconds callable (injectable for
         hermetic tests; default ``time.perf_counter``); it also drives the
-        deadline/TTL checks for the loop's duration.  ``quantum``: cap on
-        the on-device decode-scan stretch while arrivals are outstanding
-        — LEGACY-PATH ONLY (``chain_segments=False``): the chained
-        stretch admits arrivals into the RUNNING scan at segment
-        boundaries (on-device continuous batching, see
-        :meth:`_decode_stretch`), so pending arrivals no longer cap the
-        stretch at all; cancellations and deadlines still land at
-        segment-boundary granularity.
+        deadline/TTL checks for the loop's duration.  A decode stretch
+        admits arrivals into the RUNNING scan at segment boundaries
+        (on-device continuous batching, see :meth:`_decode_stretch`), so
+        outstanding arrivals do not cap it; cancellations and deadlines
+        land at segment-boundary granularity.
 
         Returns ``{rid: record}`` with ``arrival_s``, ``first_token_s``
         (host-visible TTFT stamp), ``finish_s``, ``prompt_len``,
@@ -2651,7 +2539,6 @@ class RequestManager:
             record_trace.begin_run(self.trace_run_meta())
         pending = sorted(arrivals, key=lambda a: a[0])
         records: Dict[int, Dict] = {} if _records is None else _records
-        saved_chunk = self.scan_chunk
         saved_clock = self._swap_clock(clock)  # rebases armed deadlines
         tel = self.telemetry
 
@@ -2709,8 +2596,7 @@ class RequestManager:
             # carries every request (rids preserved) — it re-enters this
             # loop with the remaining arrivals on the original time base
             return new_rm.serve_with_arrivals(
-                pending, clock=clock, quantum=quantum,
-                record_trace=record_trace,
+                pending, clock=clock, record_trace=record_trace,
                 _t0=t0, _records=records, _open=open_rids)
 
         def stamp_joined(rids):
@@ -2726,13 +2612,11 @@ class RequestManager:
                         tel.request_prefill_started(
                             self.requests[rid].trace_id)
 
-        chained = (self.chain_segments
-                   and hasattr(self.im, "decode_scan_async"))
         try:
-            # the chained stretch pulls newly-due arrivals in at segment
+            # the decode stretch pulls newly-due arrivals in at segment
             # boundaries itself (and stamps joiners' records)
-            self._arrival_pump = admit_due if chained else None
-            self._join_stamp = stamp_joined if chained else None
+            self._arrival_pump = admit_due
+            self._join_stamp = stamp_joined
             while pending or self.has_work():
                 # the loop's own work on either side of the tick (and the
                 # caller's, inside its clock): device-idle time here is
@@ -2756,11 +2640,6 @@ class RequestManager:
                             _time.sleep(min(1e-3, max(0.0,
                                                       pending[0][0] - now)))
                     continue
-                if not chained:
-                    # legacy TTFT protection: cap the stretch while
-                    # arrivals are outstanding (the chained path joins
-                    # them mid-stretch instead)
-                    self.scan_chunk = quantum if pending else saved_chunk
                 self.profiler.tick_begin()
                 self._tick()
                 self.profiler.tick_end()
@@ -2784,7 +2663,6 @@ class RequestManager:
                     return continue_on(new_rm)
             self._maybe_check_health(force=True)
         finally:
-            self.scan_chunk = saved_chunk
             self._arrival_pump = None
             self._join_stamp = None
             self._swap_clock(saved_clock)

@@ -378,7 +378,8 @@ def test_update_slice_chain_writes_what_indexing_writes(plane):
 def _serve(im, gen, arrivals, chained):
     im.reset()
     rm = RequestManager(im, gen)
-    rm.chain_segments = chained
+    if not chained:
+        rm.scan_chunk = 1   # the reference: one flat step per token
     joins = []
     inner = rm.im.join_slot
 
@@ -388,9 +389,7 @@ def _serve(im, gen, arrivals, chained):
 
     rm.im.join_slot = join_slot
     try:
-        recs = rm.serve_with_arrivals(
-            list(arrivals), clock=VirtualClock(),
-            **({} if chained else {"quantum": 1}))
+        recs = rm.serve_with_arrivals(list(arrivals), clock=VirtualClock())
     finally:
         del rm.im.join_slot
     return {rid: r["tokens"] for rid, r in recs.items()}, joins

@@ -1,20 +1,20 @@
 """Host-tick elimination: on-device continuous batching tests.
 
-The chained decode engine (``RequestManager._decode_stretch`` with
-``chain_segments`` on) fuses admission, slot joins, and lifecycle exit
-into the device dispatch chain: ``decode_scan_async`` segments run back
-to back with no readback between them, per-row ``allowed`` budgets
-freeze each slot ON DEVICE at its own max-new (per-slot exit codes
-report why), and arrivals landing mid-stretch splice into the running
-batch at a segment boundary via ``join_slot``.  The contract pinned
+The decode engine (``RequestManager._decode_stretch``) fuses admission,
+slot joins, and lifecycle exit into the device dispatch chain:
+``decode_scan_async`` segments run back to back with no readback between
+them, per-row ``allowed`` budgets freeze each slot ON DEVICE at its own
+max-new (per-slot exit codes report why), and arrivals landing
+mid-stretch splice into the running batch at a segment boundary via
+``join_slot``.  The contract pinned
 here: exactly ONE host sync per decode stretch, and token streams
-bit-identical to the legacy per-tick loop — greedy AND seeded — under
-the same Poisson arrival stream.
+bit-identical to the tick-paced flat per-step loop (``scan_chunk = 1``)
+— greedy AND seeded — under the same Poisson arrival stream.
 """
 
 import numpy as np
 
-from flexflow_tpu.obs import StepProfiler
+from flexflow_tpu.obs import NULL_TELEMETRY, StepProfiler, Telemetry
 from flexflow_tpu.serve import GenerationConfig, RequestManager
 from flexflow_tpu.serve.inference_manager import (
     EXIT_BUDGET,
@@ -43,17 +43,16 @@ def _sampled_stretches(rm, prof):
 
 
 def _serve_both(gen, arrivals):
-    """Same arrival stream through the legacy quantum-1 loop and the
-    chained engine; returns (legacy records, chained records, per-stretch
-    sync counts, per-stretch dispatch counts, legacy profiler, chained
-    profiler)."""
+    """Same arrival stream through the tick-paced reference (the flat
+    per-step loop, ``scan_chunk = 1``) and the chained engine; returns
+    (reference records, chained records, per-stretch sync counts,
+    per-stretch dispatch counts, reference profiler, chained profiler)."""
     im = make_im(max_seq=64, max_requests=2)
     im.reset()
     prof_a = StepProfiler()
     rm_a = RequestManager(im, gen, profiler=prof_a)
-    rm_a.chain_segments = False   # the legacy per-tick baseline
-    rec_a = rm_a.serve_with_arrivals(list(arrivals), clock=VirtualClock(),
-                                     quantum=1)
+    rm_a.scan_chunk = 1   # the tick-paced reference: one flat step a token
+    rec_a = rm_a.serve_with_arrivals(list(arrivals), clock=VirtualClock())
     im.reset()
     prof_b = StepProfiler()
     rm_b = RequestManager(im, gen, profiler=prof_b)
@@ -62,8 +61,42 @@ def _serve_both(gen, arrivals):
     return rec_a, rec_b, syncs, disp, prof_a, prof_b
 
 
+def test_the_tick_paced_reference_runs_no_scan():
+    # what every comparison against ``scan_chunk = 1`` compares with: the
+    # flat per-step loop — no decode scan is launched, each token after a
+    # request's first is one flat step of its own, and the tokens are the
+    # default engine's
+    prompt, n_new = [3, 11, 25, 40, 7], 9
+    gen = GenerationConfig(max_new_tokens=n_new)
+    im = make_im(max_seq=64, max_requests=2)
+    want = RequestManager(im, gen).serve_with_arrivals(
+        [(0.0, prompt, n_new)], clock=VirtualClock())
+    im.reset()
+    tel = Telemetry()
+    rm = RequestManager(im, gen, telemetry=tel)
+    rm.scan_chunk = 1
+    try:
+        got = rm.serve_with_arrivals([(0.0, prompt, n_new)],
+                                     clock=VirtualClock())
+    finally:
+        im.telemetry = NULL_TELEMETRY
+    assert [r["tokens"] for r in got.values()] \
+        == [r["tokens"] for r in want.values()]
+    assert rm.scan_runs == 0
+
+    def spans(name):
+        return [e["args"] for e in tel.trace.trace_events()
+                if e["ph"] == "X" and e["name"] == name]
+
+    assert spans("decode_scan_dispatch") == []
+    decode_steps = [a for a in spans("step_dispatch")
+                    if a["prompt_tokens"] == 0]
+    assert len(decode_steps) == n_new - 1
+    assert all(a["rows"] == 1 for a in decode_steps)
+
+
 def test_quantum1_vs_unbounded_bit_identical_greedy():
-    # THE acceptance pin: same Poisson stream, host-ticked quantum-1 loop
+    # THE acceptance pin: same Poisson stream, host-ticked per-step loop
     # vs unbounded chained stretches -> bit-identical per-request streams,
     # and every chained stretch costs exactly one host sync
     rng = np.random.RandomState(3)
@@ -74,7 +107,7 @@ def test_quantum1_vs_unbounded_bit_identical_greedy():
     assert sorted(rec_a) == sorted(rec_b)
     for rid in rec_a:
         assert rec_a[rid]["tokens"] == rec_b[rid]["tokens"], \
-            f"rid {rid} diverged between legacy and chained serving"
+            f"rid {rid} diverged between tick-paced and chained serving"
     assert syncs, "chained run never took the stretch path"
     assert all(s == 1 for s in syncs), \
         f"a stretch took more than one host sync: {syncs}"
@@ -94,7 +127,7 @@ def test_quantum1_vs_unbounded_bit_identical_seeded():
     rec_a, rec_b, syncs, _, _, _ = _serve_both(gen, arrivals)
     for rid in rec_a:
         assert rec_a[rid]["tokens"] == rec_b[rid]["tokens"], \
-            f"rid {rid} diverged (seeded) between legacy and chained"
+            f"rid {rid} diverged (seeded) between tick-paced and chained"
     assert syncs and all(s == 1 for s in syncs)
 
 

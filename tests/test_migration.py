@@ -104,6 +104,24 @@ def test_migrate_contiguous_to_paged_bit_identical(gen_fn):
     assert ctrl.rm.im.kv.pages_held() == 0
 
 
+def test_successor_inherits_the_decode_pacing():
+    """``scan_chunk`` and ``lifecycle_quantum`` cross the switch: a session
+    pinned to its own stretch bounds does not fall back to the class
+    defaults on the successor manager."""
+    im = make_im(max_seq=64)
+    rm = RequestManager(im, greedy())
+    ctrl = midflight_ctrl(
+        rm, lambda cand: make_im(max_seq=64, kv_page_size=16))
+    rm.lifecycle_quantum = 3   # beside midflight_ctrl's scan_chunk = 2
+    assert RequestManager.scan_chunk != 2
+    assert RequestManager.lifecycle_quantum != 3
+    ctrl.request_migration("tp1_pp1_m1_paged")
+    rm.generate(PROMPTS)
+    assert_clean_switch(ctrl, im)
+    assert ctrl.rm is not rm
+    assert (ctrl.rm.scan_chunk, ctrl.rm.lifecycle_quantum) == (2, 3)
+
+
 @pytest.mark.parametrize("gen_fn", [greedy, seeded],
                          ids=["greedy", "seeded"])
 def test_migrate_tp1_to_pp2_bit_identical(gen_fn):
@@ -346,12 +364,12 @@ def test_chaos_migration_plus_dispatch_faults_all_terminal():
         im, greedy(6), fault_injector=inj,
         resilience=ResilienceConfig(retry=RetryPolicy(max_retries=6,
                                                       backoff_s=0.0))))
+    ctrl = midflight_ctrl(
+        rm, lambda cand: make_im(max_seq=64, kv_page_size=16))
     # tick-paced decode: chained stretches consolidate dispatch sites, so
     # the seeded injector barely fires — this test wants MANY fault
     # opportunities interleaved with the migration phases
-    rm.chain_segments = False
-    ctrl = midflight_ctrl(
-        rm, lambda cand: make_im(max_seq=64, kv_page_size=16))
+    rm.scan_chunk = 1
     ctrl.request_migration("tp1_pp1_m1_paged")
     got = rm.generate(prompts)
     assert inj.injected >= 4, "seeded chaos barely fired"

@@ -163,6 +163,61 @@ def test_pp2_decode_scan_matches_single_stage_scan():
     assert_states_equal(im1.state, pim.state)
 
 
+def _scan_read_back(make, sample, facade):
+    """Two prompts fed flat on a fresh manager, then 5 scan steps on both
+    rows through ``decode_scan`` (``facade``) or through
+    ``decode_scan_async`` with what the facade would read off the batch;
+    everything read back to the host."""
+    im = make()
+    prompts = [[3, 11, 25, 40, 7], [2, 4, 6]]
+    flat = [t for p in prompts for t in p]
+    reqi = [i for i, p in enumerate(prompts) for _ in p]
+    pos = [j for p in prompts for j in range(len(p))]
+    lens = [len(p) for p in prompts]
+    res = im.step(BatchConfig.build(flat, reqi, pos, lens, max_tokens=16,
+                                    max_requests=2))
+    first = np.asarray(res.token_ids)[np.cumsum(lens) - 1]
+    bc = BatchConfig.build([int(t) for t in first], [0, 1], lens,
+                           [n + 1 for n in lens], max_tokens=16,
+                           max_requests=2)
+    if facade:
+        tokens, live, out = im.decode_scan(bc, 5, eos=int(first[0]),
+                                           sample=sample)
+    else:
+        tokens, live, _, out = im.decode_scan_async(
+            bc, 5, eos=int(first[0]), sample=sample, allowed=None,
+            max_position=max(lens))
+    return (np.asarray(tokens), np.asarray(live),
+            {f.name: np.asarray(getattr(out, f.name))
+             for f in dataclasses.fields(out)})
+
+
+@pytest.mark.parametrize("make", [
+    lambda: make_im(max_seq=64, use_pallas=True),
+    lambda: make_pp_im({"pp": 2}, max_seq=64),
+], ids=["InferenceManager", "PipelinedInferenceManager"])
+def test_decode_scan_is_the_async_scan_read_back(make):
+    """``decode_scan`` is ``decode_scan_async`` with no budgets, the top
+    position read off the batch, and the exit codes dropped: the ONE test
+    that holds each manager's facade to its engine — tokens, ``live`` and
+    the advanced batch, greedy and with a seeded sample."""
+    folds = np.zeros((16, 2), np.int32)
+    folds[:2] = [(0, 1), (1, 1)]
+    seeded = (jax.random.PRNGKey(11), np.float32(0.8), np.float32(0.9),
+              jax.numpy.asarray(folds))
+    drawn = []
+    for sample in (None, seeded):
+        got = _scan_read_back(make, sample, facade=True)
+        want = _scan_read_back(make, sample, facade=False)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+        assert got[1][:, :2].any()
+        for field, value in want[2].items():
+            np.testing.assert_array_equal(got[2][field], value, field)
+        drawn.append(got[0][got[1]])
+    assert not np.array_equal(*drawn), "the sample was not drawn from"
+
+
 def test_pp2_generate_matches_full_forward_reference():
     pim = make_pp_im({"pp": 2})
     rm = RequestManager(pim, GenerationConfig(max_new_tokens=8))

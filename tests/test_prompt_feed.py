@@ -6,8 +6,8 @@ LM head) wherever ``_tiled_feed`` holds, and splices it into the running
 batch with ``join_slot`` — at a segment boundary of a decode stretch, and
 before the first segment for requests the tick's admission slotted among
 decoders.  No prompt row goes through the flat step then.  Where the
-predicate says no (no Pallas, a manager without ``prefill_scan``, the
-un-chained loop, an off-tile offset) the flat feed runs as it always did.
+predicate says no (no Pallas, a manager without ``prefill_scan``, an
+off-tile offset) the flat feed runs as it always did.
 Pinned at toy size, kernels interpreted: the tokens of every request are
 those of the request served alone and those of the flat feed; the dispatch
 spans and the ``prompt_feed.*`` counters say which path fed what; and after
@@ -67,8 +67,8 @@ def alone(prompt):
 def serve_with_joiners(rm, prompts, where):
     """``FIRST`` decodes; ``prompts`` arrive among it — registered before
     the tick (``where`` = "tick": admitted at its start) or by the arrival
-    pump at the first segment boundary ("boundary"; the un-chained loop has
-    no pump).  Returns every request's tokens, ``FIRST`` first."""
+    pump at the first segment boundary ("boundary").  Returns every
+    request's tokens, ``FIRST`` first."""
     rids = [rm.register_new_request(FIRST)]
     while not rm.requests[rids[0]].generated:
         rm._serve_tick()
@@ -77,7 +77,7 @@ def serve_with_joiners(rm, prompts, where):
         if len(rids) == 1:
             rids.extend(rm.register_new_request(p) for p in prompts)
 
-    if where == "tick" or not rm.chain_segments:
+    if where == "tick":
         arrive()
     else:
         rm._arrival_pump = arrive
@@ -175,7 +175,6 @@ class NoPrefillScan:
 FALLBACKS = {
     "off_tile_offset": dict(pallas=True, prepare=off_tile),
     "no_prefill_scan": dict(pallas=True, wrap=NoPrefillScan),
-    "chain_segments_off": dict(pallas=True, attrs={"chain_segments": False}),
     "no_pallas": dict(pallas=False),
 }
 
@@ -196,8 +195,6 @@ def test_the_fallbacks_keep_the_flat_feed(case, where):
             for p in [FIRST] + prompts]
     tel = Telemetry()
     rm = RequestManager(fresh(), GEN, telemetry=tel)
-    for k, v in spec.get("attrs", {}).items():
-        setattr(rm, k, v)
     if "prepare" in spec:
         spec["prepare"](rm)
     assert serve_with_joiners(rm, prompts, where) == want

@@ -246,7 +246,9 @@ def chaos_arrivals():
         prompt = [int(x) for x in rng.randint(1, 63,
                                               size=rng.randint(3, 8))]
         cls = "latency_critical" if i % 3 == 0 else "batch"
-        arrivals.append((0.002 * i, prompt, 8, {"slo_class": cls}))
+        # gaps re-calibrated (0.002 -> 0.0005) against the chained decode
+        # engine, which drains the old stream before the ladder moves
+        arrivals.append((0.0005 * i, prompt, 8, {"slo_class": cls}))
     return arrivals
 
 
@@ -265,10 +267,6 @@ def build_chaos_fleet(gen, telemetry=None, injector=None):
         [fresh_im() for _ in range(3)], gen=gen, telemetry=telemetry,
         resilience=ResilienceConfig(kv_gate=True), fault_injector=injector,
         slo=policy, brownout=bo)
-    # tick-paced decode keeps the ladder walk stable (bench's
-    # slo_overload idiom) — identical on both sides by construction
-    for rep in fleet.replicas:
-        rep.rm.chain_segments = False
     return fleet, bo
 
 

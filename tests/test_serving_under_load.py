@@ -93,15 +93,6 @@ def test_arrival_records_are_complete_and_ordered():
     assert max(qw) > min(qw)
 
 
-def test_arrival_scan_quantum_restored():
-    im = make_im(max_seq=64)
-    rm = RequestManager(im, GenerationConfig(max_new_tokens=4))
-    saved = rm.scan_chunk
-    rm.serve_with_arrivals([(0.0, [3, 5, 7], 4)], clock=VirtualClock(),
-                           quantum=2)
-    assert rm.scan_chunk == saved
-
-
 def test_under_load_metrics_helper():
     # the bench's metric reduction, hermetically (shared with bench.py)
     import bench

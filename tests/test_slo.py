@@ -333,9 +333,9 @@ def test_degrade_flips_live_request_spec_via_set_spec_mode():
     rm = RequestManager(make_im(), GenerationConfig(max_new_tokens=8),
                         telemetry=tel, slo=pol, brownout=bo)
     # this test pins the MID-FLIGHT flip, so pace decode one token per
-    # tick — a chained stretch would finish the request before the
+    # tick — a decode stretch would finish the request before the
     # DEGRADE tick gets a boundary to act on
-    rm.chain_segments = False
+    rm.scan_chunk = 1
     rid = rm.register_new_request(PROMPTS[0], 8, spec=True)
     assert rm.requests[rid].spec is True
     # escalate mid-serve: run a few ticks, then pin DEGRADE and tick on
