@@ -198,26 +198,45 @@ def _pct(q):
 
 
 # the statistic a latency metric's name ends in: ``ttft_<statistic>`` of the
-# first-token times (from the due time), ``tpot_<statistic>`` of the pace
+# first-token times and ``latency_<statistic>`` of the whole requests' times
+# (both from the due time), ``tpot_<statistic>`` of the per-request pace.
+# ``tpot_pooled_ms`` is no statistic of the per-request paces: it is the sum
+# of the decode spans over the sum of their tokens (stats.pooled_pace)
 STATISTICS = {"mean_ms": lambda xs: sum(xs) / len(xs), "p50_ms": _pct(0.5),
               "p90_ms": _pct(0.9), "p95_ms": _pct(0.95)}
 
 
 def end_to_end(mix, records, clock, seconds):
-    """The window's end-to-end metrics, by the traffic file's ``reports``."""
+    """The window's end-to-end metrics, by the traffic file's ``reports``.
+    A request that failed counts as having waited until the loop ended.  A
+    metric with nothing under it (no request with a second token) is left
+    out, never reported as 0."""
+    from benchmark import stats
+
     out, counts = {}, {}
     worst = clock.closed.t - clock.t0 if clock.closed else seconds
-    ttft = [(r["first_token_s"] - r["arrival_s"])
-            if r["outcome"] == "ok" and "first_token_s" in r
-            else max(worst - r["arrival_s"], 0.0)
-            for r in records.values()]
-    tpot = [(r["finish_s"] - r["first_token_s"]) / (len(r["tokens"]) - 1)
-            for r in records.values()
-            if r["outcome"] == "ok" and len(r["tokens"]) >= 2]
-    tails = {"ttft": ttft, "tpot": tpot}
+
+    def since_due(stamp):
+        return [(r[stamp] - r["arrival_s"])
+                if r["outcome"] == "ok" and stamp in r
+                else max(worst - r["arrival_s"], 0.0)
+                for r in records.values()]
+
+    spans = stats.decode_spans(records.values())
+    tails = {"ttft": since_due("first_token_s"),
+             "latency": since_due("finish_s"),
+             "tpot": [s / n for s, n in spans]}
+    pooled = {"tpot": stats.pooled_pace(spans)}
+
+    def in_ms(xs):
+        return {k: round(1e3 * f(xs), 3) for k, f in STATISTICS.items()}
+
     if mix["loop"] == "open":
-        # not judged: where a first token's wait was spent (the due time to
-        # the loop picking the request up, and to its first prompt chunk)
+        # printed on every run, judged only where reported: where a first
+        # token's wait was spent (the due time to the loop picking the
+        # request up, and to its first prompt chunk); the whole request's
+        # time; the per-request pace, the share of requests on its point
+        # mass at 0 and the pooled pace that has none (PERF.md section 2)
         for label, later in (("admit late", "admitted_s"),
                              ("queue wait", "prefill_start_s")):
             xs = [r[later] - r["arrival_s"] for r in records.values()
@@ -225,14 +244,20 @@ def end_to_end(mix, records, clock, seconds):
             if xs:
                 counts[f"{label} p95 ms"] = round(
                     1e3 * STATISTICS["p95_ms"](xs), 1)
+        counts["latency ms"] = in_ms(tails["latency"])
+        if spans:
+            counts["tpot ms"] = in_ms(tails["tpot"])
+            counts["tpot pooled ms"] = round(1e3 * pooled["tpot"], 3)
+            counts["zero-pace share"] = round(stats.zero_pace_share(spans), 3)
     for name in mix["reports"]:
         what, _, stat = name.partition("_")
-        if what in tails:
-            out[name] = (1e3 * STATISTICS[stat](tails[what]), "ms")
-            counts[f"{what} samples"] = len(tails[what])
-            counts[f"{what} ms"] = {
-                k: round(1e3 * f(tails[what]), 1)
-                for k, f in STATISTICS.items()}
+        if what not in tails or not tails[what]:
+            continue
+        value = (pooled[what] if stat == "pooled_ms"
+                 else STATISTICS[stat](tails[what]))
+        out[name] = (1e3 * value, "ms")
+        counts[f"{what} samples"] = len(tails[what])
+        counts.setdefault(f"{what} ms", in_ms(tails[what]))
     if "total_tok_s" in mix["reports"]:
         a, b = clock.opened, clock.closed
         generated = b.generated - a.generated
@@ -360,8 +385,10 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
     if args.trace:
         tracer = Tracer(os.path.join(root, ".bench_trace"))
     # stopping the trace stalls the host for seconds: in the open loop the
-    # span is the window's end, so that no arrival waits behind the stall
-    trace_after_s = (max(args.seconds - span_s - 1.0, 1.0)
+    # span is the window's last ``trace_span_s`` seconds of arrivals and the
+    # drain after them, and is closed once the loop has returned
+    # (serve_loop), so that no arrival waits behind the stall
+    trace_after_s = (max(args.seconds - span_s, 1.0)
                      if mix["loop"] == "open" else 1.0)
     t_window = time.perf_counter()
     setup_s = t_window - T_START
@@ -456,14 +483,16 @@ def main(argv=None, root=ROOT, data=HERE, gate=require_device):
         "metrics": {k: {"value": v, "unit": u}
                     for k, (v, u) in metrics.items()},
         "device": device,
-        "check": numbers,
     }
     if breakdown:
         result["breakdown"] = breakdown
-    # each number compared beside its limit, as standard error's last lines
-    # too: of a run that is not correct the driver keeps the end of that
+    # each number compared beside its limit: the line's last key, and
+    # standard error's last lines too (of a run that is not correct the
+    # driver keeps the end of both)
     limits = dict(dep["correct"],
                   served_gap_ulps=dep["correct"]["token_gap_ulps"])
+    result["check"] = {name: {"value": value, "limit": limits[name]}
+                       for name, value in numbers.items()}
     for name, value in numbers.items():
         sys.stderr.write(f"correct: {name} = {value:.4f} "
                          f"(limit {limits[name]})\n")

@@ -5,7 +5,10 @@ is the host's ``perf_counter`` plus a hook: the loop calls it at every
 host-visible step boundary, so the hook sees what a user could see — each
 request's ``generated`` list as the host has it — without a thread of its
 own.  It opens and closes the window, starts and stops the device trace, and
-ends the run (by ``cancel``) when the window is over.
+ends the run (by ``cancel``) when the window is over.  The traced span of a
+closed loop is ``trace_span_s`` long from ``trace_after_s`` into the window;
+that of an open loop opens at ``trace_after_s`` and is closed once the loop
+has returned, so that the profiler's stop delays no request.
 
 Open loop: the window is the arrival schedule itself (requests due in
 ``[0, seconds)``); the loop then drains, and whatever is still unfinished
@@ -152,8 +155,13 @@ class WindowClock:
                 t = time.perf_counter()
                 self._trace_open = Stamp(t, *self._totals())
                 self._lens_open = self._lengths()
-        elif (now - self._trace_open.t >= self.trace_span_s
+        elif self.loop == "closed" and (
+                now - self._trace_open.t >= self.trace_span_s
                 or self.closed is not None):
+            # stopping the trace stalls the host for seconds (PERF.md
+            # section 7).  A closed queue only waits; open-loop arrivals
+            # would pile up behind the stall and outlast the drain, so
+            # there the trace runs on until the loop has returned: finish()
             self._stop_trace(now)
 
     def _stop_trace(self, now):

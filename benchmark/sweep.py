@@ -8,7 +8,10 @@ offers the cell's traffic at each rate in turn (the traffic file's lengths
 and arrival process, its ``rate_per_s`` overridden) and prints, per rate:
 offered requests per second and those completed per second over the window's
 second half (the first half fills the slots), the backlog (requests due and
-not finished) at the middle and at the end of the window, and the tails.
+not finished) at the middle and at the end of the window, the tails, and
+what an open cell is judged by with what stands beside it: the mean time
+from due to last token, the pooled decode pace (stats.pooled_pace) and the
+share of requests whose pace reads exactly 0.
 The knee is the highest rate at which completions/s >= 0.95 x offered and
 the backlog at the end is no larger than at the middle.  The cell's file
 then gets 0.8 of it as a number; run.py never searches.
@@ -25,7 +28,8 @@ sys.path.insert(0, os.path.dirname(HERE))
 
 from benchmark import run as harness  # noqa: E402
 from benchmark import serve_loop, traffic_gen, warmup  # noqa: E402
-from benchmark.stats import percentile  # noqa: E402
+from benchmark.stats import (decode_spans, percentile,  # noqa: E402
+                             pooled_pace, zero_pace_share)
 
 
 def backlog(records, t):
@@ -67,8 +71,8 @@ def main(argv=None):
         half = args.seconds / 2
         done_late = [r for r in ok if half < r["finish_s"] <= args.seconds]
         ttft = [r["first_token_s"] - r["arrival_s"] for r in ok]
-        tpot = [(r["finish_s"] - r["first_token_s"]) / (len(r["tokens"]) - 1)
-                for r in ok if len(r["tokens"]) >= 2]
+        spans = decode_spans(ok)
+        tpot = [s / n for s, n in spans]
         print(json.dumps({
             "rate": rate, "offered": len(reqs),
             "offered_per_s": len(reqs) / args.seconds,
@@ -78,7 +82,12 @@ def main(argv=None):
             "backlog_end": backlog(records, args.seconds),
             "ttft_p50_ms": 1e3 * (percentile(ttft, 0.5) or 0),
             "ttft_p95_ms": 1e3 * (percentile(ttft, 0.95) or 0),
+            "tpot_p50_ms": 1e3 * (percentile(tpot, 0.5) or 0),
             "tpot_p95_ms": 1e3 * (percentile(tpot, 0.95) or 0),
+            "tpot_pooled_ms": 1e3 * (pooled_pace(spans) or 0),
+            "zero_pace_share": zero_pace_share(spans),
+            "latency_mean_ms": 1e3 * sum(
+                r["finish_s"] - r["arrival_s"] for r in ok) / max(len(ok), 1),
             "loop_s": clock.closed.t - clock.t0}), flush=True)
     return 0
 

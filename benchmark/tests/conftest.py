@@ -94,7 +94,7 @@ def toy_root(tmp_path):
                            "lo": 8, "hi": 150},
             "output_len": {"dist": "lognormal", "median": 6, "sigma": 1.0,
                            "lo": 1, "hi": 40},
-            "reports": ["ttft_p90_ms", "tpot_p50_ms"], "drain_s": 20,
+            "reports": ["ttft_p90_ms", "tpot_pooled_ms"], "drain_s": 20,
             "rehearse_s": 0.5},
     }
     for name, mix in mixes.items():

@@ -47,6 +47,96 @@ def test_percentile_is_nearest_rank():
     assert stats.iqr_share(vals) == (q3 - q1) / 12.5
 
 
+def _records(spans, outcome="ok"):
+    """Serving records with the fields the pace reads: ``(first token's
+    stamp, last token's stamp, tokens)`` each."""
+    return [{"outcome": outcome, "first_token_s": a, "finish_s": b,
+             "tokens": [7] * n} for a, b, n in spans]
+
+
+# ten requests of 16 tokens, stamps at the ends of 0.3 s stretches: four end
+# in the stretch they joined (a pace of exactly 0), one needs one stretch
+# more, five need two
+TEN = [(1.0, 1.0, 16)] * 4 + [(1.0, 1.3, 16)] + [(1.0, 1.6, 16)] * 5
+
+
+def test_pooled_pace_moves_by_a_request_s_share_where_p50_jumps():
+    spans = stats.decode_spans(_records(TEN))
+    assert len(spans) == 10 and stats.zero_pace_share(spans) == 0.4
+    pooled = stats.pooled_pace(spans)
+    assert pooled == pytest.approx((0.3 + 5 * 0.6) / 150)
+    p50 = lambda sp: stats.percentile([s / n for s, n in sp], 0.5)
+    assert p50(spans) == pytest.approx(0.04)
+    # one request gets away with a stretch less: the median, which has two
+    # values to choose from, halves; the pooled pace moves by that
+    # request's share of all the waiting, a stretch over 150 tokens
+    flipped = stats.decode_spans(_records(TEN[:5] + [(1.0, 1.3, 16)]
+                                          + TEN[6:]))
+    assert p50(flipped) == pytest.approx(0.02)
+    assert stats.pooled_pace(flipped) == pytest.approx(pooled - 0.3 / 150)
+    # weighted by tokens: a long answer carries it
+    assert stats.pooled_pace([(0.0, 1), (1.0, 99)]) == 0.01
+
+
+def test_pooled_pace_leaves_out_what_has_no_pace_and_reports_no_zero():
+    # one token has no pace; a request that failed has no finish stamp
+    odd = _records([(1.0, 1.0, 1)]) + _records([(1.0, 9.0, 16)], "cancelled")
+    odd[-1].pop("finish_s")
+    assert stats.decode_spans(_records(TEN) + odd) == stats.decode_spans(
+        _records(TEN))
+    assert stats.decode_spans(odd) == []
+    assert stats.pooled_pace([]) is None
+    assert stats.zero_pace_share([]) is None
+
+
+def test_run_py_reports_the_pooled_pace_the_whole_time_or_nothing():
+    import types
+
+    from benchmark import run
+
+    clock = types.SimpleNamespace(closed=None)
+    mix = {"loop": "open", "reports": ["tpot_pooled_ms", "latency_mean_ms"]}
+    records = {i: dict(r, arrival_s=0.5)
+               for i, r in enumerate(_records(TEN))}
+    out, counts = run.end_to_end(mix, records, clock, 2.0)
+    assert out == {"tpot_pooled_ms": (pytest.approx(22.0), "ms"),
+                   "latency_mean_ms": (pytest.approx(830.0), "ms")}
+    assert counts["zero-pace share"] == 0.4 and counts["tpot samples"] == 10
+    assert counts["tpot ms"]["p50_ms"] == 40.0
+    assert counts["latency ms"]["mean_ms"] == pytest.approx(830.0)
+    # a request that failed waited until the loop ended (2.0 s here)
+    records[10] = {"outcome": "cancelled", "arrival_s": 0.9, "tokens": []}
+    out, _ = run.end_to_end(mix, records, clock, 2.0)
+    assert out["latency_mean_ms"][0] == pytest.approx(
+        (10 * 830.0 + 1100.0) / 11)
+    # an empty denominator: the metric is left out, not reported as 0
+    single = {0: dict(_records([(1.0, 1.0, 1)])[0], arrival_s=0.5)}
+    out, counts = run.end_to_end(mix, single, clock, 2.0)
+    assert set(out) == {"latency_mean_ms"}
+    assert "zero-pace share" not in counts
+
+
+def test_the_pooled_pace_reader_leaves_out_what_the_tracer_delayed():
+    import types
+
+    from benchmark import run
+
+    reader = run.load_module(os.path.join(
+        ROOT, "benchmark", "layer_metrics", "records_pooled_pace.py"))
+    Stamp = types.SimpleNamespace
+    clock = types.SimpleNamespace(t0=100.0,
+                                  trace_at=(Stamp(t=101.5), Stamp(t=103.5)))
+    # the span opens 1.5 s into the loop: the five requests that finish at
+    # 1.6 s are the tracer's, the other five count
+    records = dict(enumerate(_records(TEN)))
+    assert reader.read({"clock": clock, "records": records}) == (
+        pytest.approx(1e3 * 0.3 / 75))
+    clock.trace_at = None
+    assert reader.read({"clock": clock, "records": records}) is None
+    clock.trace_at = (Stamp(t=100.5), Stamp(t=102.5))
+    assert reader.read({"clock": clock, "records": records}) is None
+
+
 def _mix(name):
     with open(os.path.join(ROOT, "benchmark", "traffic", name + ".json")) as f:
         return json.load(f)
@@ -70,7 +160,8 @@ def test_every_seed_gets_the_same_schedule_and_other_token_ids():
     grid = sorted(traffic_gen.length_grid(mix["prompt_len"], n))
     assert sorted(len(p) for _, p, _ in a[:n]) == grid
     assert sorted(len(p) for _, p, _ in a[n:2 * n]) == grid
-    assert abs(a[2 * n - 1][0] - a[n - 1][0] - n / 3.6) < 1e-9
+    rate = mix["arrivals"]["rate_per_s"]
+    assert abs(a[2 * n - 1][0] - a[n - 1][0] - n / rate) < 1e-9
     # the rehearsal's stream: the same schedule, other token ids
     r = traffic_gen.make_requests(mix, 7, 49152, 40, 8192, stream=1)
     assert shape(r) == shape(a) and r != a

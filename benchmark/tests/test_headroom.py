@@ -54,13 +54,19 @@ PINNED_REHEARSAL = {
 }
 SIZES = {"decode-heavy": (50272, 2048), "long-prompt": (50272, 2048),
          "code-complete": (49152, 8192)}
+# PR 36 re-seated the open loop's rate; offered the old one, the generator
+# still makes the old schedule: its construction did not move
+OLD_RATE = {"code-complete": 3.6}
 
 
 @pytest.mark.parametrize("name,first,seed", sorted(PINNED))
 def test_the_first_requests_are_the_ones_the_old_files_made(name, first,
                                                             seed):
     vocab, max_seq = SIZES[name]
-    reqs = traffic_gen.make_requests(_mix(name), seed, vocab, 51, max_seq)
+    mix = _mix(name)
+    if name in OLD_RATE:
+        mix["arrivals"]["rate_per_s"] = OLD_RATE[name]
+    reqs = traffic_gen.make_requests(mix, seed, vocab, 51, max_seq)
     assert len(reqs) >= first
     assert _digest(reqs[:first]) == PINNED[name, first, seed]
 
@@ -73,11 +79,32 @@ def test_the_rehearsal_begins_as_it_did(name, first):
     assert _digest(reqs[:first]) == PINNED_REHEARSAL[name, first]
 
 
-def test_the_open_loop_file_is_untouched():
-    with open(os.path.join(ROOT, "benchmark", "traffic",
-                           "code-complete.json"), "rb") as f:
-        assert hashlib.sha256(f.read()).hexdigest() == (
-            "e67d98d91dfe40ead5f248b9cf4f592b7c39cb7cc05cbd2ba63dd2c07dde93b1")
+# what a re-seated rate may not move (PR 36 moved ``rate_per_s``, ``reports``
+# and ``trace_span_s`` of both open mixes and nothing else): the lengths,
+# the grid, the arrival process, the drain
+OPEN_MIXES = {
+    "code-complete": ({"dist": "lognormal", "median": 1024, "sigma": 0.85,
+                       "lo": 64, "hi": 6144},
+                      {"dist": "lognormal", "median": 16, "sigma": 1.0,
+                       "lo": 1, "hi": 128}),
+    "chat-open": ({"dist": "lognormal", "median": 512, "sigma": 0.8,
+                   "lo": 32, "hi": 1500},
+                  {"dist": "lognormal", "median": 128, "sigma": 0.8,
+                   "lo": 8, "hi": 400}),
+}
+
+
+@pytest.mark.parametrize("name", sorted(OPEN_MIXES))
+def test_an_open_loop_file_keeps_its_lengths_and_says_where_its_rate_is_from(
+        name):
+    mix = _mix(name)
+    assert (mix["prompt_len"], mix["output_len"]) == OPEN_MIXES[name]
+    assert (mix["loop"], mix["block"], mix["drain_s"], mix["rehearse_s"],
+            mix["arrivals"]["process"]) == ("open", 16, 20, 4, "poisson")
+    # the rate is 0.8 of a knee that the file names, to one decimal
+    knee = float(mix["rate_from"].split("knee of THIS schedule, ")[1]
+                 .split(" req/s")[0])
+    assert mix["arrivals"]["rate_per_s"] == round(0.8 * knee, 1)
 
 
 def test_dense_step_cost_by_hand():
@@ -285,3 +312,43 @@ def test_a_window_that_finds_no_such_boundary_closes_a_tenth_late(
     assert clock.closed is None
     tick(57.2)                   # more than 5.1 s overdue: closed as it is
     assert clock.closed.t == 57.2 and clock.cancelled
+
+
+class _Tracer:
+    def __init__(self):
+        self.calls = []
+
+    def start(self):
+        self.calls.append("start")
+
+    def stop(self):
+        self.calls.append("stop")
+
+
+def test_an_open_loop_s_trace_is_stopped_after_the_loop_a_closed_one_s_in_it(
+        monkeypatch):
+    """``stop_trace`` stalls the host for seconds: arrivals of an open loop
+    would wait behind it and outlast the drain (failed requests in traced
+    runs only), a closed queue just waits."""
+    now = [0.0]
+    monkeypatch.setattr(serve_loop, "time", types.SimpleNamespace(
+        perf_counter=lambda: now[0]))
+    for loop, stopped_inside in (("open", False), ("closed", True)):
+        rm = _fake_manager(depth=40, slots=4)
+        tracer = _Tracer()
+        clock = serve_loop.WindowClock(rm, loop, 51, tracer=tracer,
+                                       trace_after_s=2.0, trace_span_s=1.0)
+        now[0] = 0.0
+        clock()
+        for t in (1.0, 3.0, 3.5, 4.5, 5.0):
+            rm.steps += 1
+            now[0] = t
+            clock()
+        assert tracer.calls[0] == "start"
+        assert ("stop" in tracer.calls) is stopped_inside
+        now[0] = 6.0
+        clock.finish()
+        assert tracer.calls == ["start", "stop"]
+        opened, closed = clock.trace_at
+        assert opened.t == 3.0
+        assert closed.t == (4.5 if stopped_inside else 6.0)

@@ -77,7 +77,7 @@ def test_a_token_the_reference_would_not_pick_is_seen(pallas_on_cpu):
 
 @pytest.mark.parametrize("cell,metrics", [
     ("toy-opt.toy-closed", {"total_tok_s", "setup_s"}),
-    ("toy-bigcode.toy-open", {"ttft_p90_ms", "tpot_p50_ms", "setup_s"}),
+    ("toy-bigcode.toy-open", {"ttft_p90_ms", "tpot_pooled_ms", "setup_s"}),
 ])
 def test_run_py_end_to_end_on_a_toy_cell(pallas_on_cpu, toy_root, capsys,
                                          cell, metrics):
