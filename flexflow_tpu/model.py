@@ -177,9 +177,10 @@ class FFModel:
         op = LayerNorm(x.shape[-1], elementwise_affine, eps, use_bias, x.dtype)
         return self._add(op, [x], name or "layer_norm")[0]
 
-    def rms_norm(self, x, eps=1e-6, name=None):
-        return self._add(RMSNorm(x.shape[-1], eps, x.dtype), [x],
-                         name or "rms_norm")[0]
+    def rms_norm(self, x, eps=1e-6, name=None, unit_offset=False,
+                 out_dtype=None):
+        return self._add(RMSNorm(x.shape[-1], eps, x.dtype, unit_offset,
+                                 out_dtype), [x], name or "rms_norm")[0]
 
     def residual_layer_norm(self, x, r1, r2=None, elementwise_affine=True,
                             eps=1e-5, use_bias=True, name=None):
@@ -194,8 +195,10 @@ class FFModel:
                                       use_bias, x.dtype)
         return self._add(op, [x, residual], name or "add_bias_residual_layer_norm")
 
-    def residual_rms_norm(self, x, residual, eps=1e-6, name=None):
-        op = ResidualRMSNorm(x.shape[-1], eps, x.dtype)
+    def residual_rms_norm(self, x, residual, eps=1e-6, name=None,
+                          unit_offset=False, out_dtype=None):
+        op = ResidualRMSNorm(x.shape[-1], eps, x.dtype, unit_offset,
+                             out_dtype)
         return self._add(op, [x, residual], name or "residual_rms_norm")
 
     def sigmoid_silu_multi(self, x1, x2, name=None):
@@ -352,6 +355,14 @@ class FFModel:
                                   head_dim, layer, window, state_owner,
                                   dtype=x.dtype)
         return self._add(op, [x], name or "diff_attention")[0]
+
+    def eva_attention(self, x, embed_dim, num_heads, head_dim, window, chunk,
+                      rope_theta=10000.0, name=None):
+        from .serve.hybrid_ops import EvaAttention
+
+        op = EvaAttention(embed_dim, num_heads, head_dim, window, chunk,
+                          rope_theta, dtype=x.dtype)
+        return self._add(op, [x], name or "eva_attention")[0]
 
     def spec_inc_multihead_self_attention(self, x, embed_dim, num_q_heads,
                                           num_kv_heads=None, head_dim=None,

@@ -43,14 +43,17 @@ def _layer_norm(x, gamma, beta, eps):
     return y.astype(dtype)
 
 
-def _rms_norm(x, gamma, eps):
-    dtype = x.dtype
+def _rms_norm(x, gamma, eps, unit_offset=False, out_dtype=None):
+    """``unit_offset``: the gain is stored as its distance from one (scale
+    by ``1 + gamma``).  ``out_dtype``: what the normed rows come out as
+    where that is not the input's type (a float32 residual stream feeding
+    bf16 projections)."""
     x32 = x.astype(jnp.float32)
     ms = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     y = x32 * jax.lax.rsqrt(ms + eps)
     if gamma is not None:
-        y = y * gamma
-    return y.astype(dtype)
+        y = y * (1.0 + gamma.astype(jnp.float32) if unit_offset else gamma)
+    return y.astype(out_dtype or x.dtype)
 
 
 @register_op
@@ -93,19 +96,24 @@ class LayerNorm(Op):
 class RMSNorm(Op):
     type_name = "rms_norm"
 
-    def __init__(self, dim: int, eps: float = 1e-6, dtype=jnp.float32):
+    def __init__(self, dim: int, eps: float = 1e-6, dtype=jnp.float32,
+                 unit_offset: bool = False, out_dtype=None):
         self.dim = int(dim)
         self.eps = float(eps)
         self.dtype = jnp.dtype(dtype).name
+        self.unit_offset = bool(unit_offset)
+        self.out_dtype = jnp.dtype(out_dtype).name if out_dtype else None
 
     def infer_shapes(self, in_specs):
-        return [in_specs[0]]
+        x = in_specs[0]
+        return [TensorSpec(x.shape, jnp.dtype(self.out_dtype or x.dtype))]
 
     def params(self):
         return [ParamSpec("gamma", TensorSpec((self.dim,), jnp.dtype(self.dtype)))]
 
     def lower(self, ctx, inputs, params):
-        return [_rms_norm(inputs[0], params.get("gamma"), self.eps)]
+        return [_rms_norm(inputs[0], params.get("gamma"), self.eps,
+                          self.unit_offset, self.out_dtype)]
 
     def apply_config(self, config, in_specs, mesh, in_shardings=None):
         sh = _norm_sharding(in_specs[0], in_shardings[0] if in_shardings else None)
@@ -207,20 +215,29 @@ class ResidualRMSNorm(Op):
 
     type_name = "residual_rms_norm"
 
-    def __init__(self, dim: int, eps: float = 1e-6, dtype=jnp.float32):
+    def __init__(self, dim: int, eps: float = 1e-6, dtype=jnp.float32,
+                 unit_offset: bool = False, out_dtype=None):
         self.dim = int(dim)
         self.eps = float(eps)
         self.dtype = jnp.dtype(dtype).name
+        self.unit_offset = bool(unit_offset)
+        self.out_dtype = jnp.dtype(out_dtype).name if out_dtype else None
 
     def infer_shapes(self, in_specs):
-        return [in_specs[0], in_specs[0]]
+        # the sum takes the wider of the two types: a float32 residual
+        # stream stays float32 whatever the block's output came in
+        x = in_specs[0]
+        s = jnp.promote_types(x.dtype, in_specs[1].dtype)
+        return [TensorSpec(x.shape, s),
+                TensorSpec(x.shape, jnp.dtype(self.out_dtype or s))]
 
     def params(self):
         return [ParamSpec("gamma", TensorSpec((self.dim,), jnp.dtype(self.dtype)))]
 
     def lower(self, ctx, inputs, params):
         s = inputs[0] + inputs[1]
-        return [s, _rms_norm(s, params.get("gamma"), self.eps)]
+        return [s, _rms_norm(s, params.get("gamma"), self.eps,
+                             self.unit_offset, self.out_dtype)]
 
     def apply_config(self, config, in_specs, mesh, in_shardings=None):
         sh = _norm_sharding(in_specs[0], in_shardings[0] if in_shardings else None)

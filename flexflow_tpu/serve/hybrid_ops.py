@@ -13,6 +13,11 @@ beside ``IncMultiHeadSelfAttention``:
   kernels' tile; independent of ``max_seq_len``), ``full`` owns an ordinary
   full-length cache, and ``cross`` projects queries only and reads the cache
   a ``full`` node (its ``state_owner``) wrote earlier in the same step.
+* :class:`EvaAttention` — EVA attention (EvaByte): exact attention inside
+  the query's own window, one summary per chunk of every earlier window, one
+  softmax over both.  Its cache COMPACTS itself: when a window closes, its
+  raw keys and values are replaced by their summaries, so a row's live cache
+  is a contiguous prefix whose length is not the position, and falls.
 
 All of them run on the flat token batch every step program shares.  A flat
 batch mixes rows of several requests, so state is SEGMENTED by
@@ -37,7 +42,8 @@ from ..core.graph import ParamSpec, TensorSpec
 from ..core.op import Op, OpContext, register_op
 from ..core.sharding import TensorSharding
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .ops import DUS_MAX_TOKENS, NEG_INF, IncMultiHeadSelfAttention
+from .ops import (DUS_MAX_TOKENS, NEG_INF, IncMultiHeadSelfAttention,
+                  apply_rope)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
@@ -106,6 +112,18 @@ def _set_rows(buf, rows, upd):
     if upd.shape[0] > DUS_MAX_TOKENS:
         return buf.at[rows].set(upd)
     return _set_rows_chain(buf, rows, upd)
+
+
+def _put_blocks(kc, vc, kb, vb, rows, start):
+    """``kb[i]`` / ``vb[i]`` (``[heads, tile, D]`` each) into row ``rows[i]``
+    of the caches from seq index ``start[i]`` on: one in-place block write
+    per prefill tile (ops._prefill_attend says why not a scatter)."""
+    zero = jnp.int32(0)
+    for i in range(kb.shape[0]):
+        at = (rows[i], zero, start[i], zero)
+        kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
+        vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
+    return kc, vc
 
 
 def _init(fn):
@@ -443,13 +461,7 @@ class DiffAttention(_SlotStateOp):
         block = lambda a: jnp.where(
             valid, a.reshape(g, bq, self.kv_pairs, self.pair_dim)
             .transpose(0, 2, 1, 3), 0).astype(kc.dtype)
-        kb, vb = block(k), block(v)
-        zero = jnp.int32(0)
-        for i in range(g):
-            at = (rows[i], zero, start[i], zero)
-            kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
-            vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
-        return kc, vc
+        return _put_blocks(kc, vc, block(k), block(v), rows, start)
 
     def _attend_xla(self, q, kc, vc, rows, pos):
         """Plain attention of query groups against their slot's cache:
@@ -582,3 +594,264 @@ class CrossDiffAttention(DiffAttention):
 
 DIFF_ATTENTION = {c.mode: c for c in (FullDiffAttention, WindowDiffAttention,
                                       CrossDiffAttention)}
+
+
+def compact_cache_len(max_seq_len: int, window: int, chunk: int) -> int:
+    """Entries of a slot's compacting cache: the summaries of every window
+    but the last, then one window of raw entries, padded to the decode
+    kernel's seq block where the cache is longer than one (to the lane
+    otherwise) so that the kernels get a dividing block."""
+    per_window = window // chunk
+    n = per_window * (-(-max_seq_len // window) - 1) + window
+    block = 512 if n > 512 else LANE
+    return -(-n // block) * block
+
+
+def compact_len(position, window: int, chunk: int):
+    """``L(t) - 1``: where position ``t`` sits in its slot's compacting
+    cache — behind the ``window / chunk`` summaries of each closed window,
+    at its offset into the open one.  A query at ``t`` reads the entries
+    ``0 .. compact_len(t)``; works on ints and on arrays alike."""
+    return (window // chunk) * (position // window) + position % window
+
+
+def compact_geometry(graph):
+    """``(window, chunk, layers)`` of the graph's compacting caches, or
+    None for a graph that has none."""
+    ops = [n.op for n in graph.nodes if isinstance(n.op, EvaAttention)]
+    if not ops:
+        return None
+    return ops[0].window, ops[0].chunk, len(ops)
+
+
+@register_op
+class EvaAttention(_SlotStateOp):
+    """EVA attention over flat token batches (Zheng et al., "Efficient
+    Attention via Control Variates", as EvaByte runs it).
+
+    With window ``W``, chunk ``C`` and per-head learned ``phi, mu``: a chunk
+    of ``C`` positions has the summary ``kbar = sum_j a_j k_j + mu``,
+    ``vbar = sum_j a_j v_j`` with ``a = softmax_j(phi . k_j)`` over the
+    chunk's (rotated) keys; a query at position ``t`` in window
+    ``w = t // W`` attends the exact keys ``W w .. t`` and the summaries of
+    every chunk of windows ``0 .. w - 1``, in ONE softmax, same scale.
+
+    The cache ``ck`` / ``cv`` ``[rows, heads, compact_cache_len, head_dim]``
+    holds that set as a contiguous prefix: the ``W / C`` summaries of each
+    closed window, then the open window's raw entries — position ``t`` at
+    index :func:`compact_len` ``(t)``.  So the mask is plain causal
+    attention at compact indices and the kernels there are run it as it is:
+    ``decode_attention`` over ``L(t)`` entries, ``prefill_attention`` on a
+    tile whose queries and keys sit at compact indices (a tile never
+    straddles a window: the tile divides ``W``).  RoPE turns by the
+    position, the cache is indexed by the compact index; the two differ
+    from the first window's end on.
+
+    COMPACTION: after the step in which a row writes a window's last
+    position, that window's ``W`` raw entries are read once, summarised and
+    overwritten in place by the ``W / C`` summaries (the next window's raw
+    entries then follow them).  It runs under a loop whose trip count is the
+    number of rows that close a window in this step — none, nearly always
+    — so a step that closes nothing streams nothing.
+
+    A flat batch may hold one request's rows on BOTH sides of a window's end
+    (the rows after it must read summaries that need the rows before it, and
+    their raw entries land where the closing window's still lie): such a
+    step attends in two passes, the rows of each segment's first window,
+    the compaction, then the rest.  The decode scan (every row a request of
+    its own) needs one.
+    """
+
+    type_name = "eva_attention"
+
+    def __init__(self, embed_dim: int, num_heads: int, head_dim: int,
+                 window: int, chunk: int, rope_theta: float = 10000.0,
+                 dtype=jnp.float32):
+        if window % chunk:
+            raise ValueError("the window holds whole chunks")
+        self.embed_dim = int(embed_dim)
+        # plain MHA; both names, as the weight quantiser finds attention
+        # ops by ``num_kv_heads``
+        self.num_q_heads = self.num_kv_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.window = int(window)
+        self.chunk = int(chunk)
+        self.rope_theta = float(rope_theta)
+        self.scaling_factor = 1.0 / math.sqrt(self.head_dim)
+        self.dtype = jnp.dtype(dtype).name
+
+    @property
+    def per_window(self) -> int:
+        return self.window // self.chunk
+
+    # ---- shapes / params ----------------------------------------------
+    def infer_shapes(self, in_specs):
+        return [TensorSpec(in_specs[0].shape, jnp.dtype(self.dtype))]
+
+    def params(self) -> List[ParamSpec]:
+        dt = jnp.dtype(self.dtype)
+        e, h, hd = self.embed_dim, self.num_q_heads, self.head_dim
+        return [
+            ParamSpec("qkv", TensorSpec((e, h, 3, hd), dt)),
+            ParamSpec("o_proj", TensorSpec((h * hd, e), dt)),
+            ParamSpec("phi", TensorSpec((h, hd), dt)),
+            ParamSpec("mu", TensorSpec((h, hd), dt)),
+        ]
+
+    def flops(self, in_specs):
+        t = in_specs[0].shape[0]
+        return 2 * t * self.embed_dim * 4 * self.num_q_heads * self.head_dim
+
+    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
+                    head_axes=()):
+        if getattr(self, "cost_max_tokens", 0) > self.window:
+            raise ValueError(
+                f"max_tokens_per_batch {self.cost_max_tokens} > window_size "
+                f"{self.window}: a step may cross one window's end, not two")
+        shape = (max_requests + 1, self.num_kv_heads,
+                 compact_cache_len(max_seq_len, self.window, self.chunk),
+                 self.head_dim)
+        sh = TensorSharding.replicated(4)
+        return {"ck": (shape, self.dtype, sh), "cv": (shape, self.dtype, sh)}
+
+    # ---- compute -------------------------------------------------------
+    def _project(self, x, params, pos):
+        w = dequant(params["qkv"], params.get("qkv_scale"), x.dtype)
+        qkv = jnp.einsum("te,ehgd->thgd", x, w,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+        q = apply_rope(qkv[:, :, 0], pos, self.rope_theta)
+        k = apply_rope(qkv[:, :, 1], pos, self.rope_theta)
+        return q, k, qkv[:, :, 2]
+
+    @jax.named_scope("kv_write")
+    def _write(self, kc, vc, k, v, rows, at, bc, tiled):
+        """This pass's keys and values to ``(rows, at)``: ``at`` the compact
+        index, ``rows`` the scratch row for what the pass leaves out."""
+        if not tiled:
+            put = IncMultiHeadSelfAttention._scatter_rows_pos
+            return put(kc, rows, at, k), put(vc, rows, at, v)
+        # one block write per tile; a tile lies inside one window, so its
+        # entries are contiguous, and its tail pads land beyond the open
+        # window's newest entry (which a later chunk overwrites before any
+        # query reads it)
+        bq = bc.tile_size
+        g = k.shape[0] // bq
+        h, hd = self.num_kv_heads, self.head_dim
+        valid = (rows != kc.shape[0] - 1).reshape(g, 1, bq, 1)
+        block = lambda a: jnp.where(
+            valid, a.reshape(g, bq, h, hd).transpose(0, 2, 1, 3),
+            0).astype(kc.dtype)
+        return _put_blocks(kc, vc, block(k), block(v),
+                           rows.reshape(g, bq)[:, 0], at.reshape(g, bq)[:, 0])
+
+    def _attend(self, q, kc, vc, rows, at, bc, ctx, tiled):
+        """``[T, heads, D]``: causal attention of each row over its slot's
+        entries ``0 .. at`` — and the path taken."""
+        from ..ops.pallas.attention import decode_attention, prefill_attention
+
+        interp = bool(ctx.extras.get("pallas_interpret"))
+        if tiled:
+            bq = bc.tile_size
+            g = q.shape[0] // bq
+            out = prefill_attention(
+                q.reshape(g, bq, *q.shape[1:]), kc, vc,
+                rows.reshape(g, bq)[:, 0], at.reshape(g, bq)[:, 0],
+                scale=self.scaling_factor, interpret=interp)
+            return out.reshape(q.shape), "prefill_attention"
+        if ctx.extras.get("pallas_decode"):
+            out = decode_attention(q, kc, vc, rows, at,
+                                   scale=self.scaling_factor,
+                                   interpret=interp)
+            return out, "decode_attention"
+        # the CPU oracle of the kernels
+        sc = jnp.einsum("thd,thsd->ths", q, kc[rows],
+                        preferred_element_type=jnp.float32)
+        seen = jnp.arange(kc.shape[2], dtype=jnp.int32) <= at[:, None]
+        sc = jnp.where(seen[:, None], sc * self.scaling_factor, NEG_INF)
+        w = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("ths,thsd->thd", w, vc[rows].astype(w.dtype),
+                          preferred_element_type=jnp.float32), "xla"
+
+    def summarize(self, k, v, params):
+        """The summaries of whole chunks: ``k, v [heads, n C, D]`` to
+        ``[heads, n, D]`` each, in float32 (no matmul: a float32 one would
+        round the softmax weights to bf16 on the MXU)."""
+        h, s, hd = k.shape
+        up = lambda a: a.astype(jnp.float32)
+        k = up(k).reshape(h, s // self.chunk, self.chunk, hd)
+        v = up(v).reshape(k.shape)
+        a = jax.nn.softmax(
+            jnp.sum(up(params["phi"])[:, None, None] * k, axis=-1), axis=-1)
+        return (jnp.sum(a[..., None] * k, axis=2) + up(params["mu"])[:, None],
+                jnp.sum(a[..., None] * v, axis=2))
+
+    def _compact(self, kc, vc, params, rows, pos, closing):
+        """Replace the raw entries of the windows that ``closing`` rows end
+        by their summaries, one row per trip of a loop that runs as many
+        trips as rows close a window."""
+        w, n = self.window, self.per_window
+        order = jnp.argsort(~closing, stable=True)
+        zero = jnp.int32(0)
+
+        def one(i, caches):
+            kc, vc = caches
+            f = order[i]
+            at = (rows[f], zero, n * (pos[f] // w), zero)
+            size = (1, self.num_kv_heads, w, self.head_dim)
+            ks, vs = self.summarize(
+                jax.lax.dynamic_slice(kc, at, size)[0],
+                jax.lax.dynamic_slice(vc, at, size)[0], params)
+            return (jax.lax.dynamic_update_slice(
+                        kc, ks[None].astype(kc.dtype), at),
+                    jax.lax.dynamic_update_slice(
+                        vc, vs[None].astype(vc.dtype), at))
+
+        # its own operator class in a device trace, apart from the node's
+        with jax.named_scope("EvaCompaction.window_close"):
+            return jax.lax.fori_loop(
+                0, jnp.sum(closing.astype(jnp.int32)), one, (kc, vc))
+
+    def lower(self, ctx, inputs, params):
+        bc, state = _require(ctx, self.type_name)
+        x = inputs[0]
+        base = _flat(bc)
+        kc, vc = state["ck"], state["cv"]
+        nreq = kc.shape[0] - 1
+        seg = Segments(base, nreq)
+        pos = base.token_position
+        with jax.named_scope("qkv_proj"):
+            q, k, v = self._project(x, params, pos)
+        tiled = (isinstance(bc, PrefillBatchConfig)
+                 and bool(ctx.extras.get("pallas_decode")))
+        if tiled and self.window % bc.tile_size:
+            raise ValueError(f"the prefill tile {bc.tile_size} must divide "
+                             f"window_size {self.window}")
+        at = compact_len(pos, self.window, self.chunk)
+        # rows past the end of the window their segment began in wait for
+        # its compaction (none in the decode scan: a row is a segment)
+        late = seg.live & (pos // self.window
+                           > (pos - seg.offset) // self.window)
+        passes = [~late] if ctx.extras.get("one_row_per_request") \
+            else [~late, late]
+        with jax.named_scope("attend"):
+            for i, now in enumerate(passes):
+                rows = jnp.where(seg.live & now, seg.rows, nreq)
+                idx = jnp.where(rows == nreq, 0, at)
+                kc, vc = self._write(kc, vc, k, v, rows, idx, bc, tiled)
+                o, path = self._attend(q, kc, vc, rows, idx, bc, ctx, tiled)
+                if i == 0:
+                    out = o
+                    closing = (rows != nreq) & ((pos + 1) % self.window == 0)
+                    kc, vc = self._compact(kc, vc, params, rows, pos, closing)
+                else:
+                    out = jnp.where(now[:, None, None], o, out)
+            ctx.extras["state_out"] = {"ck": kc, "cv": vc}
+            paths = ctx.extras.get("attention_paths")
+            if paths is not None:
+                paths[("eva_attention", type(bc).__name__)] = path
+        with jax.named_scope("o_proj"):
+            o_w = dequant(params["o_proj"], params.get("o_proj_scale"),
+                          x.dtype)
+            y = jnp.dot(out.astype(x.dtype).reshape(x.shape[0], -1), o_w,
+                        preferred_element_type=jnp.float32)
+            return [y.astype(self.dtype)]

@@ -171,36 +171,39 @@ def register_serve_capacities(graph, max_requests, max_seq_len,
 def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                                  max_spec_tokens=0, tp=1,
                                  pipelined=False) -> None:
-    """A graph that keeps window rings or recurrent state per slot
-    (``slot_state`` ops: serve/hybrid_ops.py) runs slot-contiguous, in its
-    compute dtype, one token a step, on one chip.  Each other deployment
-    option needs something that is not written yet; it is refused here, at
-    compile, by what is missing — none silently takes another path."""
+    """A graph that keeps window rings, recurrent state or a compacting
+    cache per slot (``slot_state`` ops: serve/hybrid_ops.py) runs
+    slot-contiguous, in its compute dtype, one token a step, on one chip.
+    Each other deployment option needs something that is not written yet; it
+    is refused here, at compile, by what is missing — none silently takes
+    another path."""
     kinds = sorted({type(n.op).__name__ for n in graph.nodes
                     if getattr(n.op, "slot_state", False)})
     if not kinds:
         return
     missing = []
     if kv_page_size:
-        missing.append("kv_page_size: a page table for a ring that wraps, "
-                       "and copy-on-write of recurrent state at a shared "
-                       "prefix's end")
+        missing.append("kv_page_size: a page table for a ring that wraps "
+                       "or a cache that compacts (pages assume one entry a "
+                       "position), and copy-on-write of recurrent state or "
+                       "of an open window at a shared prefix's end")
     if kv_dtype == "int8":
         missing.append("kv_dtype='int8': quantise-on-write of the window "
-                       "ring and of the cache the cross-attention layers "
-                       "read")
+                       "ring, of the cache the cross-attention layers read "
+                       "and of a compacting cache's summaries")
     if max_spec_tokens:
-        missing.append("speculation: a recurrent state cannot be rolled "
-                       "back over rejected tokens without a snapshot per "
-                       "tree node")
+        missing.append("speculation: a recurrent state or a closed window "
+                       "cannot be rolled back over rejected tokens without "
+                       "a snapshot per tree node")
     if tp > 1:
-        missing.append("tp > 1: a sharding rule for the conv, the scan and "
-                       "the differential attention's head pairs")
+        missing.append("tp > 1: a sharding rule for the conv, the scan, the "
+                       "differential attention's head pairs and the "
+                       "per-head summaries")
     if pipelined:
         missing.append("pp > 1 (the pipelined manager, at any number of "
                        "stages): the exported scan output and the shared "
                        "cache cross stage boundaries, and its per-stage "
-                       "state hand-over knows K/V planes only")
+                       "state hand-over knows full-length K/V planes only")
     if missing:
         raise ValueError(
             f"this graph keeps per-slot state in {kinds}, which cannot be "

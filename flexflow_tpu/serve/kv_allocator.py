@@ -47,10 +47,17 @@ KV_BUFFER_NAMES = frozenset({"k", "v", "k_scale", "v_scale"})
 # attention layer keeps of its last positions, and a state-space layer's
 # conv tail and scan state (serve/hybrid_ops.py).  A slot holds them whole
 # from admission on, so they are priced per slot, never per token.
+# ``kv_compact`` is a cache that compacts itself (EvaAttention: a window of
+# raw entries behind the summaries of all closed ones): it does grow with
+# the context, but by a sixteenth of an entry a position and with a sawtooth
+# on top, so a position has no price — ``bytes_per_token`` leaves it out
+# (it is None for a graph that holds nothing else, and admission then gates
+# in positions against ``capacity_tokens``: a slot is a slot).
 STATE_KINDS = {
     "kv_full": KV_BUFFER_NAMES,
     "kv_window": frozenset({"wk", "wv"}),
     "recurrent": frozenset({"conv", "ssm"}),
+    "kv_compact": frozenset({"ck", "cv"}),
 }
 
 

@@ -1,4 +1,4 @@
-from . import falcon, llama, mpt, opt, phi4flash, starcoder  # noqa: F401
+from . import evabyte, falcon, llama, mpt, opt, phi4flash, starcoder  # noqa: F401
 from .base import MODEL_REGISTRY, ServeModelConfig, build_model
 
 __all__ = ["MODEL_REGISTRY", "ServeModelConfig", "build_model"]

@@ -56,6 +56,14 @@ class ServeModelConfig:
     mamba_d_conv: int = 4
     mamba_expand: int = 2
     mamba_dt_rank: Optional[int] = None  # None = ceil(hidden_size / 16)
+    # evabyte (EVA attention; ``models/evabyte.py``): exact attention inside
+    # ``window_size`` positions, one summary per ``chunk_size`` positions of
+    # everything before; ``num_pred_heads`` heads share the trunk (head 0
+    # is the next byte); norms scale by ``1 + gamma``
+    window_size: Optional[int] = None
+    chunk_size: Optional[int] = None
+    num_pred_heads: int = 1
+    norm_add_unit_offset: bool = False
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

@@ -22,6 +22,7 @@ import numpy as np
 
 from ..obs.telemetry import telemetry_or_null
 from .batch_config import BatchConfig, PrefillBatchConfig
+from .hybrid_ops import compact_geometry, compact_len
 from .inference_manager import EXIT_NOT_IN_BATCH
 from .resilience import ResilienceConfig, TransientServeError
 
@@ -196,6 +197,9 @@ class RequestManager:
         self.tokens_decoded = 0
         # dispatch-span arguments of the batch _build_next_batch made last
         self._step_counts: Optional[Dict[str, int]] = None
+        # (window, chunk, layers) of a cache that compacts itself, or None:
+        # what ``_compact_counts`` tells the dispatch spans of it
+        self._compact = compact_geometry(im.model.graph)
         self.scan_runs = 0      # decode stretches run as on-device scans
         # ONE Telemetry handle across the serving stack: syncing it onto the
         # InferenceManager (which forwards to pipeline stages) puts request
@@ -323,8 +327,7 @@ class RequestManager:
             name, cat="host", track="host",
             prof=self.profiler if phase else None, **args)
 
-    @staticmethod
-    def _launch_counts(spans, n_decode: int) -> Dict[str, int]:
+    def _launch_counts(self, spans, n_decode: int) -> Dict[str, int]:
         """Dispatch-span arguments of one flat step from its cache-write
         spans ``[(rid, lo, hi)]``, the ``n_decode`` decode rows first:
         ``ctx_sum`` is the decode rows' KV lengths, ``prompt_ctx_sum`` the
@@ -336,7 +339,29 @@ class RequestManager:
             "ctx_sum": sum(hi for _, _, hi in dec),
             "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
                                   for _, lo, hi in pre),
+            **self._compact_counts([(lo, hi) for _, lo, hi in spans]),
         }
+
+    def _compact_counts(self, writes) -> Dict[str, int]:
+        """What a launch that writes the positions ``[(lo, hi)]`` (one pair
+        a row) means to a cache that compacts itself (``hybrid_ops.
+        EvaAttention``): ``cache_len_sum``, the entries the rows' caches
+        hold at launch — their ``L(lo)``, where ``ctx_sum`` counts
+        positions — and ``compactions``, the windows the launch closes.
+        Counted too (``eva.windows_closed``, and the summary pairs written
+        over all layers); nothing for a graph without such a cache."""
+        if self._compact is None:
+            return {}
+        window, chunk, layers = self._compact
+        closed = sum(hi // window - lo // window for lo, hi in writes)
+        tel = self.telemetry
+        if closed and tel.enabled:
+            tel.metrics.counter("eva.windows_closed").inc(closed)
+            tel.metrics.counter("eva.summaries_written").inc(
+                closed * layers * (window // chunk))
+        return {"cache_len_sum": sum(compact_len(lo, window, chunk) + 1
+                                     for lo, hi in writes if hi > lo),
+                "compactions": closed}
 
     @staticmethod
     def _fold_for(req: Request) -> Tuple[int, int]:
@@ -1609,7 +1634,9 @@ class RequestManager:
             fed = sum(t for _, t in feeds[at: at + seg])
             cnt = {"rows": rows, "joiners": len(joiners or ()),
                    "prompt_tokens": fed,
-                   "ctx_sum": sum(st for st, _ in feeds[at: at + seg])}
+                   "ctx_sum": sum(st for st, _ in feeds[at: at + seg]),
+                   **self._compact_counts(
+                       [(st, st + t) for st, t in feeds[at: at + seg]])}
             res = self._guarded(
                 "prefill_scan",
                 lambda s=stacked, a=smp, c=cnt: im.prefill_scan(
@@ -1766,6 +1793,9 @@ class RequestManager:
                     if k > 0:   # a live row, and its KV length at launch
                         cnt["rows"] += 1
                         cnt["ctx_sum"] += dev_seq[req.rid]
+                cnt.update(self._compact_counts(
+                    [(dev_seq[req.rid] - 1, dev_seq[req.rid] - 1
+                      + ks[req.rid]) for req, _ in rows]))
                 if prof.enabled:
                     # k_i decode steps per row: each streams the weights
                     # and reads the growing causally-live prefix
