@@ -219,10 +219,12 @@ class SelectiveScan(_SlotStateOp):
     state ``ssm [max_requests + 1, C, N]`` are float32 (the recurrence
     multiplies thousands of factors near 1).
 
-    A flat step scans its rows in order, carrying ``h`` across a segment
-    and exchanging it with the slot's row at the segment's ends; the decode
-    scan (``one_row_per_request``: every live row a request of its own)
-    updates all rows at once.
+    A flat step or a prompt chunk scans its rows in order, carrying ``h``
+    across a segment and exchanging it with the slot's row at the segment's
+    ends — in one Pallas kernel where the kernels are on
+    (``ops/pallas/selective_scan.py``), by a ``lax.scan`` over the rows
+    otherwise; the decode scan (``one_row_per_request``: every live row a
+    request of its own) updates all rows at once.
     """
 
     type_name = "selective_scan"
@@ -267,6 +269,7 @@ class SelectiveScan(_SlotStateOp):
         delta = jax.nn.softplus(dt + params["dt_bias"])
         a = -jnp.exp(params["A_log"])
         dx = delta * xs
+        path = "rows_at_once"
         if ctx.extras.get("one_row_per_request"):
             h = jnp.where(seg.fresh[:, None, None], 0.0, hs[seg.rows])
             h = (jnp.exp(delta[:, :, None] * a) * h
@@ -275,7 +278,18 @@ class SelectiveScan(_SlotStateOp):
             y = jnp.sum(h * c_in[:, None, :], axis=-1)
             with jax.named_scope("state_write"):
                 hs = _set_rows(hs, seg.store, h)
+        elif ctx.extras.get("pallas_decode") and self.channels % LANE == 0:
+            # a prompt chunk or a flat step: the rows in order, in ONE
+            # kernel that keeps the state on chip across a segment's rows
+            from ..ops.pallas.selective_scan import selective_scan_rows
+
+            y, hs = selective_scan_rows(
+                delta, dx, b_in, c_in, a, hs, seg.start, seg.fresh, seg.rows,
+                seg.store,
+                interpret=bool(ctx.extras.get("pallas_interpret")))
+            path = "kernel"
         else:
+            # the CPU oracle of the kernel: one scan trip per row
             def row(carry, r):
                 h, hs = carry
                 delta_r, dx_r, b_r, c_r, start, fresh, at, store = r
@@ -290,6 +304,14 @@ class SelectiveScan(_SlotStateOp):
                 row, (jnp.zeros(hs.shape[1:], hs.dtype), hs),
                 (delta, dx, b_in, c_in, seg.start, seg.fresh, seg.rows,
                  seg.store), unroll=8)
+            path = "row_scan"
+        paths = ctx.extras.get("attention_paths")
+        if paths is not None:
+            # the decode scan's batch is a BatchConfig too: told apart, so
+            # that its path and the flat step's are both counted
+            batch = ("one_row_per_request" if path == "rows_at_once"
+                     else type(bc).__name__)
+            paths[(self.type_name, batch)] = path
         ctx.extras["state_out"] = {"ssm": hs}
         return [(y + params["D"] * xs).astype(self.dtype)]
 

@@ -147,27 +147,61 @@ def feed_tiled(im, slot, ids, seq_lens):
 
 PROMPT = tokens(150)
 
+# the selective scan's paths over a flat batch's rows: the ``lax.scan`` (the
+# oracle) and the Pallas kernel in interpret mode (``use_pallas`` on the CPU)
+SCAN_PATHS = pytest.mark.parametrize("use_pallas", [False, True],
+                                     ids=["row_scan", "kernel"])
+
+
+def scan_path(im, batch="BatchConfig"):
+    return im.attention_paths.get(("selective_scan", batch))
+
+
+def recurrent_state(im, slots):
+    """The scan state and the conv tail the Mamba layers hold for
+    ``slots``: {(node, buffer): array}."""
+    return {(node, name): np.asarray(bufs[name])[list(slots)]
+            for node, bufs in im.state.items()
+            for name in ("ssm", "conv") if name in bufs}
+
+
+def assert_same_recurrent_state(got, want):
+    """Float32 rounding apart: the kernel sums ``h . C`` in another order,
+    which the next layers' inputs (and so their states) feel."""
+    assert got.keys() == want.keys() and len(got) == 6   # 3 Mamba layers
+    for key, a in want.items():
+        assert float(np.abs(a).max()) > 1e-3, key
+        np.testing.assert_allclose(got[key], a, atol=2e-5, rtol=1e-4,
+                                   err_msg=str(key))
+
 
 @pytest.mark.parametrize("how", ["one_chunk", "even_chunks", "uneven_chunks",
                                  "tiled_scan", "tiled_scan_pallas",
-                                 "even_chunks_pallas"])
+                                 "even_chunks_pallas", "one_chunk_pallas",
+                                 "uneven_chunks_pallas"])
 def test_prompt_feeding_paths_agree_with_the_reference(how):
     """The same prompt in ONE flat chunk, in several, in uneven ones that
     cut a segment anywhere, and through the tiled prefill scan: the state
-    crosses every chunk boundary, and the ring (128 slots) wraps."""
+    crosses every chunk boundary, and the ring (128 slots) wraps.  With
+    ``_pallas`` the selective scan's rows go through its kernel (interpret
+    mode): a segment that starts from zero, segments that start mid-prompt
+    from the stored state, and the pads behind a chunk's last row."""
     want, want_tok = reference_logprobs(PROMPT + tokens(3, salt=1))
     n = len(PROMPT)
     seq_lens = [0] * SLOTS
-    if how == "one_chunk":
-        im = deployment(cap=160)
+    pallas = how.endswith("pallas")
+    if how.startswith("one_chunk"):
+        im = deployment(use_pallas=pallas, cap=160)
         got = feed_flat(im, 1, PROMPT, [160], seq_lens)
     elif how.startswith("tiled_scan"):
-        im = deployment(use_pallas=how.endswith("pallas"))
+        im = deployment(use_pallas=pallas)
         first, seq_lens = feed_tiled(im, 1, PROMPT, seq_lens)
         assert first == want_tok[n - 1]
+        assert scan_path(im, "PrefillBatchConfig") == \
+            ("kernel" if pallas else "row_scan")
         got = None
     else:
-        im = deployment(use_pallas=how.endswith("pallas"))
+        im = deployment(use_pallas=pallas)
         sizes = [CAP] if how.startswith("even") else [7, CAP, 1, 20, 3]
         got = feed_flat(im, 1, PROMPT, sizes, seq_lens)
     if got is not None:
@@ -176,14 +210,13 @@ def test_prompt_feeding_paths_agree_with_the_reference(how):
     for k, tok in enumerate(tokens(3, salt=1)):
         (lp,), _ = flat_step(im, [(1, [tok], n + k)], seq_lens)
         np.testing.assert_allclose(lp[0], want[n + k], atol=TOL, rtol=0)
+    assert scan_path(im) == ("kernel" if pallas else "row_scan")
 
 
-def test_flat_step_holds_rows_of_three_requests():
-    """A flat step is SEGMENTED by request: three requests' rows side by
-    side, each continuing its own conv tail, scan state, ring and cache."""
-    im = deployment()
+def three_requests_in_one_step(im):
+    """Three requests fed alone, then continued side by side in ONE flat
+    step; returns the step's log-probabilities per request and the cuts."""
     seqs = [tokens(40, salt=s) for s in (11, 12, 13)]
-    want = [reference_logprobs(s)[0] for s in seqs]
     seq_lens = [0] * SLOTS
     cuts = [(12, 9), (20, 10), (5, 11)]   # first step alone, then together
     for slot, (ids, (first, _)) in enumerate(zip(seqs, cuts)):
@@ -191,9 +224,27 @@ def test_flat_step_holds_rows_of_three_requests():
     pieces = [(slot, ids[first:first + more], first)
               for slot, (ids, (first, more)) in enumerate(zip(seqs, cuts))]
     got, _ = flat_step(im, pieces, seq_lens)
+    return seqs, cuts, got
+
+
+@SCAN_PATHS
+def test_flat_step_holds_rows_of_three_requests(use_pallas):
+    """A flat step is SEGMENTED by request: three requests' rows side by
+    side, each continuing its own conv tail, scan state, ring and cache —
+    three segments that start from stored states and two pads in one batch;
+    the kernel leaves the three slots the states the row scan leaves."""
+    im = deployment(use_pallas=use_pallas)
+    seqs, cuts, got = three_requests_in_one_step(im)
     for slot, (lp, (first, more)) in enumerate(zip(got, cuts)):
-        np.testing.assert_allclose(lp, want[slot][first:first + more],
+        want = reference_logprobs(seqs[slot])[0]
+        np.testing.assert_allclose(lp, want[first:first + more],
                                    atol=TOL, rtol=0)
+    if use_pallas:
+        assert scan_path(im) == "kernel"
+        oracle = deployment()
+        three_requests_in_one_step(oracle)
+        assert_same_recurrent_state(recurrent_state(im, range(3)),
+                                    recurrent_state(oracle, range(3)))
 
 
 # readings here: 0.0001 ulps at most, 0.0000 nats (four decimals)
@@ -214,7 +265,9 @@ def test_the_harness_drive_is_correct(use_pallas):
                                   77, HF["vocab_size"], LIMITS, lines.append)
     assert ok, "\n".join(lines)
     kinds = {k for k, _ in im.attention_paths}
-    assert kinds == {"window_attention", "full_attention", "cross_attention"}
+    assert kinds == {"window_attention", "full_attention", "cross_attention",
+                     "selective_scan"}
+    assert scan_path(im, "one_row_per_request") == "rows_at_once"
     if use_pallas:
         assert im.attention_paths[
             ("full_attention", "PrefillBatchConfig")] == "prefill_attention"
@@ -222,14 +275,18 @@ def test_the_harness_drive_is_correct(use_pallas):
             ("window_attention", "PrefillBatchConfig")] == "xla_tile"
         assert im.attention_paths[
             ("window_attention", "BatchConfig")] == "decode_attention"
+        assert scan_path(im, "PrefillBatchConfig") == scan_path(im) == "kernel"
     else:
-        assert set(im.attention_paths.values()) == {"xla"}
+        assert {p for (k, _), p in im.attention_paths.items()
+                if k != "selective_scan"} == {"xla"}
+        assert scan_path(im) == "row_scan"
 
 
-def test_a_reused_slot_starts_from_zero_state():
+@SCAN_PATHS
+def test_a_reused_slot_starts_from_zero_state(use_pallas):
     """A slot that served a LONG request (ring wrapped, scan state warm)
     then serves a short one: the short one reads what it would alone."""
-    im = deployment()
+    im = deployment(use_pallas=use_pallas)
     seq_lens = [0] * SLOTS
     feed_flat(im, 2, tokens(170, salt=21), [CAP], seq_lens)
     short = tokens(23, salt=22)
@@ -346,14 +403,16 @@ def test_tree_batches_are_refused_by_the_ops():
         _require(ctx, "selective_scan")
 
 
-def test_memory_ledger_and_path_counters_tell_the_kinds_apart():
+@SCAN_PATHS
+def test_memory_ledger_and_path_counters_tell_the_kinds_apart(use_pallas):
     """The memory ledger holds the bytes one slot keeps of each kind of
     state, ``host_admit`` says how many slots started anew, and every
-    attention layer kind leaves a counter naming the path it took."""
+    attention layer kind — and the selective scan — leaves a counter naming
+    the path it took."""
     from flexflow_tpu.obs import Telemetry
     from flexflow_tpu.serve import GenerationConfig, RequestManager
 
-    im = deployment()
+    im = deployment(use_pallas=use_pallas)
     tel = Telemetry()
     rm = RequestManager(im, GenerationConfig(stop_on_eos=False),
                         telemetry=tel)
@@ -368,8 +427,14 @@ def test_memory_ledger_and_path_counters_tell_the_kinds_apart():
             assert measured[f"slot_{kind}_bytes"]["measured"] == \
                 per_slot[kind] > 0
         counters = tel.metrics.snapshot()
-        for kind in ("window", "full", "cross"):
-            assert counters[f"attention_path.{kind}_attention.xla"] >= 1
+        if use_pallas:
+            assert counters["attention_path.selective_scan.kernel"] >= 1
+            assert "attention_path.selective_scan.row_scan" not in counters
+        else:
+            for kind in ("window", "full", "cross"):
+                assert counters[f"attention_path.{kind}_attention.xla"] >= 1
+            assert counters["attention_path.selective_scan.row_scan"] >= 1
+        assert counters["attention_path.selective_scan.rows_at_once"] >= 1
         resets = [e["args"]["state_reset"] for e in tel.trace.trace_events()
                   if e["name"] == "host_admit"
                   and "state_reset" in e.get("args", {})]
@@ -428,21 +493,18 @@ def test_the_published_config_builds_the_published_model():
     assert owners == {"model.layers.17.attn"}
 
 
-def test_chunked_feeding_leaves_the_state_one_chunk_leaves():
+@SCAN_PATHS
+def test_chunked_feeding_leaves_the_state_one_chunk_leaves(use_pallas):
     """The slot's recurrent state itself, not only what reads it: after the
     same prompt in one 160-row chunk and in chunks of 32, 7, 1, ... rows the
     scan state and the conv tail of every Mamba layer are equal — a state
     dropped or stale at any chunk boundary shows here whatever its weight
-    in the logits."""
-    whole, parts = deployment(cap=160), deployment()
+    in the logits.  The one chunk is always the row scan's; the parts are
+    the row scan's or the kernel's."""
+    whole, parts = deployment(cap=160), deployment(use_pallas=use_pallas)
     feed_flat(whole, 3, PROMPT, [160], [0] * SLOTS)
     feed_flat(parts, 3, PROMPT, [CAP, 7, 1, 20, 3], [0] * SLOTS)
-    checked = 0
-    for node, bufs in whole.state.items():
-        for name in ("ssm", "conv"):
-            if name in bufs:
-                a, b = bufs[name][3], parts.state[node][name][3]
-                assert float(jnp.abs(a).max()) > 1e-3, (node, name)
-                np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)
-                checked += 1
-    assert checked == 6     # three Mamba layers: a scan state and a tail each
+    assert scan_path(whole) == "row_scan"
+    assert scan_path(parts) == ("kernel" if use_pallas else "row_scan")
+    assert_same_recurrent_state(recurrent_state(parts, [3]),
+                                recurrent_state(whole, [3]))

@@ -185,3 +185,68 @@ def test_prefill_with_no_admissible_plan_raises(one_chip):
     kernel handed to a compiler that refuses it."""
     with pytest.raises(ValueError, match="prefill_attention.*m_rows"):
         _lower("prefill", one_chip, 1, 71, 64, 2048, "bf16")
+
+
+# the toy SambaY of tests/test_phi4flash.py: 3 Mamba layers of 128 channels
+_SAMBAY = dict(model_type="phi4flash", hidden_size=64, intermediate_size=96,
+               num_hidden_layers=8, num_attention_heads=8,
+               num_key_value_heads=4, vocab_size=320, sliding_window=16,
+               mb_per_layer=2, tie_word_embeddings=True, layer_norm_eps=1e-5,
+               max_position_embeddings=4096, initializer_range=0.125,
+               torch_dtype="float32")
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "row_scan"])
+def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
+    """The 512-row prefill-scan program of a toy SambaY, whole, for the
+    described chip.  With the kernels on, every selective scan is ONE Mosaic
+    kernel: no loop over the rows and no update-slice of the ``[C, N]`` state
+    is left under a ``SelectiveScan`` scope.  The row scan (kernels off) is
+    the control: it holds both, so the reading can see them."""
+    import numpy as np
+
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.model import FFModel
+    from flexflow_tpu.parallel.mesh import make_mesh
+    from flexflow_tpu.serve.batch_config import (BatchConfig,
+                                                 PrefillBatchConfig)
+    from flexflow_tpu.serve.inference_manager import InferenceManager
+    from flexflow_tpu.serve.models.base import (ServeModelConfig,
+                                                build_model)
+
+    cap, slots = 512, 4
+    ff = FFModel(FFConfig(), mesh=make_mesh({"tp": 1}, jax.devices()[:1]))
+    build_model(ff, ServeModelConfig.from_hf_config(_SAMBAY), cap)
+    im = InferenceManager(ff, max_requests=slots, max_tokens_per_batch=cap,
+                          max_seq_len=1024, use_pallas=use_pallas)
+    im.init_operators_inference()
+    # the manager sees this process's CPU and would ask for interpret mode:
+    # steered here, as the chip's compiler is what the case is about
+    im.pallas_interpret = False
+    tile = im.prefill_tile
+    fields, last_flat = PrefillBatchConfig.np_fields(
+        [(1, list(range(3, 3 + cap)), 0)], [0, cap, 0, 0], tile,
+        max_tokens=cap, max_requests=slots)
+    bcs = PrefillBatchConfig(
+        base=BatchConfig(*(jnp.asarray(f[None]) for f in fields[:5])),
+        tile_size=tile,
+        logit_slots=jnp.asarray(PrefillBatchConfig.np_logit_slots(
+            [1], last_flat, slots)[None]) if im.gate_lm_head else None)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (im.params, im.state, bcs))
+    hlo = jax.jit(im._prefill_scan_impl).lower(*args).compile().as_text()
+    scan = [ln for ln in hlo.splitlines() if "/SelectiveScan." in ln]
+    kernels = [ln for ln in scan if "tpu_custom_call" in ln]
+    loops = [ln for ln in scan if " while(" in ln]
+    writes = [ln for ln in scan if " dynamic-update-slice(" in ln
+              or "dynamic_update_slice" in ln.split("metadata=")[-1]]
+    if use_pallas:
+        assert len(kernels) == 3 and not loops and not writes, \
+            (len(kernels), loops[:1], writes[:1])
+        assert im.attention_paths[
+            ("selective_scan", "PrefillBatchConfig")] == "kernel"
+    else:
+        assert not kernels and loops and writes
