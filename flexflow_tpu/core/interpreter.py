@@ -85,6 +85,7 @@ def build_forward(plan: Plan, mode: str = "spmd") -> Callable:
                     "in_shardings": step.in_shardings,
                     "in_specs": step.in_specs,
                     "out_specs": step.out_specs,
+                    "node_name": step.node.name,
                 },
             )
             if extras:

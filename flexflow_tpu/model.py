@@ -364,6 +364,22 @@ class FFModel:
                           rope_theta, dtype=x.dtype)
         return self._add(op, [x], name or "eva_attention")[0]
 
+    def sparse_block_attention(self, x, embed_dim, num_q_heads, num_kv_heads,
+                               head_dim, name=None, **sizes):
+        from .serve.hybrid_ops import SparseBlockAttention
+
+        op = SparseBlockAttention(embed_dim, num_q_heads, num_kv_heads,
+                                  head_dim, dtype=x.dtype, **sizes)
+        return self._add(op, [x], name or "sparse_block_attention")[0]
+
+    def lightning_attention(self, x, embed_dim, num_heads, head_dim,
+                            name=None, **how):
+        from .serve.hybrid_ops import LightningAttention
+
+        op = LightningAttention(embed_dim, num_heads, head_dim,
+                                dtype=x.dtype, **how)
+        return self._add(op, [x], name or "lightning_attention")[0]
+
     def spec_inc_multihead_self_attention(self, x, embed_dim, num_q_heads,
                                           num_kv_heads=None, head_dim=None,
                                           rotary_embedding=True,

@@ -377,6 +377,162 @@ def decode_attention(
     return out.reshape(t, qh, d)
 
 
+def _sparse_decode_kernel(
+    rows_ref,       # scalar prefetch: i32[T] cache row per token
+    pos_ref,        # scalar prefetch: i32[T] absolute position per token
+    blocks_ref,     # scalar prefetch: i32[T * KV * NB] attended blocks, sorted
+    counts_ref,     # scalar prefetch: i32[T * KV] how many of them are real
+    q_ref,          # [1, 1, gq, D]
+    *refs,          # U key blocks, U value blocks [1, 1, block, D] each,
+                    # o_ref [1, 1, gq, D], m/l/acc scratch
+    block: int,
+    unroll: int,
+    list_len: int,
+    num_kv: int,
+    scale: float,
+):
+    k_refs, v_refs = refs[:unroll], refs[unroll:2 * unroll]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * unroll:]
+    t, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = pos_ref[t]
+    count = counts_ref[t * num_kv + g]
+    first = j * unroll            # this step's first entry of the list
+
+    @pl.when(first < count)
+    def _compute():
+        q = q_ref[0, 0].astype(jnp.float32)                  # [gq, D]
+        gq = q.shape[0]
+        width = unroll * block
+        k = jnp.concatenate([r[0, 0] for r in k_refs], axis=0)
+        sc = jax.lax.dot_general(
+            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [gq, U * block]
+        # the causal mask comes from each block's OWN position: entry u of
+        # this step is block ``blocks[first + u]`` of the cache
+        lane = jax.lax.broadcasted_iota(jnp.int32, (gq, width), 1)
+        key_pos = lane % block
+        real = lane < 0
+        for u in range(unroll):
+            here = lane // block == u
+            b = blocks_ref[(t * num_kv + g) * list_len + first + u]
+            key_pos = jnp.where(here, key_pos + b * block, key_pos)
+            real = real | (here & (first + u < count))
+        seen = real & (key_pos <= pos)
+        sc = jnp.where(seen, sc, NEG_INF)
+        m_prev = m_ref[:, 0:1]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+        l_new = alpha * l_ref[:, 0:1] + jnp.sum(p, -1, keepdims=True)
+        v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0)
+        pv = jax.lax.dot_general(
+            p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [gq, D]
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _finalize():
+        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
+        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+SPARSE_ROWS = 64    # rows per call: the block lists ride in scalar memory
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "block", "unroll",
+                                             "interpret"))
+def sparse_decode_attention(
+    q: jax.Array,        # [T, QH, D]
+    k_cache: jax.Array,  # [R+1, KV, S, D] (this step's K/V already written)
+    v_cache: jax.Array,  # [R+1, KV, S, D]
+    rows: jax.Array,     # i32[T] cache row per token
+    positions: jax.Array,  # i32[T]
+    blocks: jax.Array,   # i32[T, KV, NB]: the blocks each row and KV head
+                         # attends, sorted; entries past ``counts`` repeat
+                         # the last real one
+    counts: jax.Array,   # i32[T, KV]
+    scale: float,
+    block: int = 64,
+    unroll: int = 16,
+    interpret: bool = False,
+) -> jax.Array:
+    """Decode attention over a LIST of cache blocks per row and KV head (a
+    learned sparse selection: ``serve/hybrid_ops.SparseBlockAttention``),
+    where ``decode_attention`` reads a run.  Grid ``(rows, KV heads, list /
+    unroll)``: one KV head a grid column, ``unroll`` blocks of ``block``
+    positions a step (16: 1.45 ms a call of 48 rows x 97 blocks on the v5e,
+    where 8 read 1.73, 4 2.15 and 2 2.98) — each a K and a V operand of its own, fetched through
+    the scalar-prefetched list, so that a step keeps ``2 x unroll`` copies
+    in flight (a 64-position block of one head is 16 KB: one a step would
+    be all overhead).  The causal mask is taken from each block's own
+    position; a list's tail repeats its last block, whose copy Pallas then
+    skips, and a step wholly past the list computes nothing."""
+    t, qh, d = q.shape
+    _, num_kv, s_len, _ = k_cache.shape
+    gq = qh // num_kv
+    if s_len % block:
+        raise ValueError("the cache holds whole blocks")
+    if t > SPARSE_ROWS and t % SPARSE_ROWS == 0:
+        # a flat step's 512 rows: the lists of 64 rows at a time
+        cut = lambda a: a.reshape((t // SPARSE_ROWS, SPARSE_ROWS)
+                                  + a.shape[1:])
+        out = jax.lax.map(
+            lambda a: sparse_decode_attention(
+                a[0], k_cache, v_cache, a[1], a[2], a[3], a[4], scale=scale,
+                block=block, unroll=unroll, interpret=interpret),
+            (cut(q), cut(rows), cut(positions), cut(blocks), cut(counts)))
+        return out.reshape(t, qh, d)
+    unroll = min(unroll, blocks.shape[-1])
+    pad = -blocks.shape[-1] % unroll
+    if pad:
+        blocks = jnp.concatenate(
+            [blocks, jnp.repeat(blocks[..., -1:], pad, axis=-1)], axis=-1)
+    list_len = blocks.shape[-1]
+    qr = q.reshape(t, num_kv, gq, d)
+
+    def kv_map(u):
+        def index(i, g, j, rows, pos, blocks, counts):
+            return (rows[i], g,
+                    blocks[(i * num_kv + g) * list_len + j * unroll + u], 0)
+        return pl.BlockSpec((1, 1, block, d), index, memory_space=pltpu.VMEM)
+
+    q_spec = pl.BlockSpec((1, 1, gq, d), lambda i, g, j, *_: (i, g, 0, 0),
+                          memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(t, num_kv, list_len // unroll),
+        in_specs=[q_spec] + [kv_map(u) for u in range(unroll)] * 2,
+        out_specs=q_spec,
+        scratch_shapes=[
+            pltpu.VMEM((gq, 128), jnp.float32),
+            pltpu.VMEM((gq, 128), jnp.float32),
+            pltpu.VMEM((gq, d), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _sparse_decode_kernel, block=block, unroll=unroll, list_len=list_len,
+        num_kv=num_kv, scale=float(scale))
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, num_kv, gq, d), q.dtype),
+        interpret=interpret,
+    )(rows.astype(jnp.int32), positions.astype(jnp.int32),
+      blocks.astype(jnp.int32).reshape(-1),
+      counts.astype(jnp.int32).reshape(-1),
+      qr, *([k_cache] * unroll), *([v_cache] * unroll))
+    return out.reshape(t, qh, d)
+
+
 # Prefill streams K+V blocks against a Bq*gq-row query tile.  The grid
 # carries a KV-HEAD-CHUNK axis: each grid step works on ``kv_chunk <= KV``
 # heads, so the f32 score/softmax working set is [kv_chunk, Bq*gq, Bs] —

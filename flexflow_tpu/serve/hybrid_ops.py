@@ -18,6 +18,15 @@ beside ``IncMultiHeadSelfAttention``:
   softmax over both.  Its cache COMPACTS itself: when a window closes, its
   raw keys and values are replaced by their summaries, so a row's live cache
   is a contiguous prefix whose length is not the position, and falls.
+* :class:`SparseBlockAttention` — InfLLM-v2 sparse attention (MiniCPM-SALA's
+  ``minicpm4`` layers): a full-length K/V cache AND, beside it, an index of
+  compressed keys that gains an entry every ``kernel_stride`` positions; each
+  row CHOOSES by it which blocks of its cache to read (``BlockSelect``), per
+  KV-head group, and reads a list of blocks, not a run.
+* :class:`LightningAttention` — Lightning linear attention (its
+  ``lightning-attn`` layers): the state is one ``head_dim x head_dim``
+  float32 matrix per head and slot, decayed per head and updated by a
+  rank-one product a position.
 
 All of them run on the flat token batch every step program shares.  A flat
 batch mixes rows of several requests, so state is SEGMENTED by
@@ -41,6 +50,7 @@ import jax.numpy as jnp
 from ..core.graph import ParamSpec, TensorSpec
 from ..core.op import Op, OpContext, register_op
 from ..core.sharding import TensorSharding
+from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
 from .ops import (DUS_MAX_TOKENS, NEG_INF, IncMultiHeadSelfAttention,
                   apply_rope)
@@ -875,5 +885,577 @@ class EvaAttention(_SlotStateOp):
             o_w = dequant(params["o_proj"], params.get("o_proj_scale"),
                           x.dtype)
             y = jnp.dot(out.astype(x.dtype).reshape(x.shape[0], -1), o_w,
+                        preferred_element_type=jnp.float32)
+            return [y.astype(self.dtype)]
+
+
+def sparse_geometry(graph):
+    """What the graph's sparse-attention and linear-attention layers mean to
+    the scheduler's counters: ``(SparseBlockAttention op or None, number of
+    such layers, number of LightningAttention layers)``, or None for a graph
+    that has neither."""
+    sparse = [n.op for n in graph.nodes
+              if isinstance(n.op, SparseBlockAttention)]
+    linear = sum(isinstance(n.op, LightningAttention) for n in graph.nodes)
+    if not sparse and not linear:
+        return None
+    return (sparse[0] if sparse else None), len(sparse), linear
+
+
+@register_op
+class SparseBlockAttention(_SlotStateOp):
+    """InfLLM-v2 sparse attention over flat token batches (MiniCPM4 report,
+    arXiv:2506.07900, as MiniCPM-SALA's ``minicpm4`` layers run it): grouped
+    queries on a few K/V heads, no positional encoding, an output gate.
+
+    Two caches a slot.  ``k`` / ``v`` ``[rows, KV, S, D]`` hold every
+    position; ``kidx`` ``[rows, KV, S / stride, D]`` holds the COMPRESSED
+    keys: entry ``c`` is the mean of the keys ``[stride c, stride c +
+    kernel)`` and exists once position ``stride c + kernel - 1`` is written
+    (the row that writes it appends the entry, in a prompt chunk and in the
+    decode scan alike).
+
+    Between ``qkv_proj`` and ``attend`` each row SELECTS, per KV-head group
+    (scope ``BlockSelect.<node>``, an operator class of its own in a device
+    trace): a softmax per query head over the compressed keys it can see,
+    summed over the group's heads, max-pooled onto blocks of ``block``
+    positions (a block's score is the largest among the kernels that overlap
+    it); the row attends block 0 (``init_blocks``), the ``window / block``
+    newest blocks, and the ``topk`` highest-scoring of the rest — or every
+    block while its position is below ``dense_len``.  The selection is a
+    mask over blocks ``[T, KV, S / block]``; attention is ONE softmax over
+    the exact keys ``j <= t`` of the attended blocks.
+
+    Paths: the decode scan and flat steps on the chip turn the mask into a
+    sorted block list and run ``sparse_decode_attention`` (the blocks
+    gathered by scalar prefetch, the causal mask from each block's own
+    position); a prompt chunk and the CPU oracle compute masked-dense — all
+    blocks up to the furthest row's, in a loop over key spans, the
+    unselected masked per row and group (the same mathematics).
+    """
+
+    type_name = "sparse_block_attention"
+    KEY_SPAN = 2048   # keys per trip of the masked-dense loop
+
+    def __init__(self, embed_dim: int, num_q_heads: int, num_kv_heads: int,
+                 head_dim: int, kernel_size: int = 32, kernel_stride: int = 16,
+                 block_size: int = 64, topk: int = 64, window: int = 2048,
+                 init_blocks: int = 1, dense_len: int = 8192,
+                 output_gate: bool = True, dtype=jnp.float32):
+        if num_q_heads % num_kv_heads:
+            raise ValueError("query heads come in whole groups per K/V head")
+        if kernel_size % kernel_stride or block_size % kernel_stride \
+                or window % block_size:
+            raise ValueError("the stride divides the kernel and the block; "
+                             "the window holds whole blocks")
+        self.embed_dim = int(embed_dim)
+        self.num_q_heads = int(num_q_heads)
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.kernel_size = int(kernel_size)
+        self.kernel_stride = int(kernel_stride)
+        self.block_size = int(block_size)
+        self.topk = int(topk)
+        self.window = int(window)
+        self.init_blocks = int(init_blocks)
+        self.dense_len = int(dense_len)
+        self.output_gate = bool(output_gate)
+        self.scaling_factor = 1.0 / math.sqrt(self.head_dim)
+        self.dtype = jnp.dtype(dtype).name
+
+    # ---- geometry (ints and arrays alike) -------------------------------
+    @property
+    def group(self) -> int:
+        return self.num_q_heads // self.num_kv_heads
+
+    @property
+    def max_blocks(self) -> int:
+        """The most blocks a row attends: the forced and chosen ones, or
+        every block below ``dense_len``."""
+        return max(self.init_blocks + self.window // self.block_size
+                   + self.topk, -(-self.dense_len // self.block_size))
+
+    def index_len(self, position):
+        """Compressed keys a row at ``position`` can see (its own included
+        if it completes one)."""
+        n = (position + 1 - self.kernel_size) // self.kernel_stride + 1
+        return max(n, 0) if isinstance(position, int) else jnp.maximum(n, 0)
+
+    def attended_blocks(self, position: int) -> int:
+        """Blocks a row at ``position`` reads (host arithmetic for the
+        dispatch spans): all of them below ``dense_len``, else the forced
+        ones and ``topk`` of the rest."""
+        have = position // self.block_size + 1
+        if position < self.dense_len:
+            return have
+        return min(have, self.init_blocks + self.window // self.block_size
+                   + self.topk)
+
+    def attended_blocks_between(self, lo: int, hi: int) -> int:
+        """``sum(attended_blocks(p) for p in range(lo, hi))`` in closed
+        form (a launch's counters, on the host, every launch)."""
+        size = self.block_size
+        chosen = self.init_blocks + self.window // size + self.topk
+        # ``p // size + 1`` blocks up to ``dense_len`` and, past it, until
+        # there are more than the forced and chosen ones
+        edge = max(chosen * size, self.dense_len)
+
+        def below(n):   # sum over p < n of p // size + 1
+            m = n // size
+            return size * m * (m + 1) // 2 + (n - m * size) * (m + 1)
+
+        grow = lambda a, b, stop: below(min(b, stop)) - below(min(a, stop))
+        a, b = max(lo, self.dense_len), max(hi, self.dense_len)
+        return (grow(lo, hi, self.dense_len) + grow(a, b, edge)
+                + chosen * (max(b, edge) - max(a, edge)))
+
+    # ---- shapes / params ------------------------------------------------
+    @property
+    def _cols(self):
+        q = self.num_q_heads * self.head_dim
+        kv = self.num_kv_heads * self.head_dim
+        return q, kv, (q if self.output_gate else 0)
+
+    def infer_shapes(self, in_specs):
+        return [TensorSpec(in_specs[0].shape, jnp.dtype(self.dtype))]
+
+    def params(self) -> List[ParamSpec]:
+        dt = jnp.dtype(self.dtype)
+        q, kv, z = self._cols
+        # q | k | v | gate side by side: one GEMM, sliced
+        return [ParamSpec("qkv", TensorSpec((self.embed_dim, q + 2 * kv + z),
+                                            dt)),
+                ParamSpec("o_proj", TensorSpec((q, self.embed_dim), dt))]
+
+    def flops(self, in_specs):
+        q, kv, z = self._cols
+        return 2 * in_specs[0].shape[0] * self.embed_dim * (2 * q + 2 * kv + z)
+
+    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
+                    head_axes=()):
+        seq = -(-max_seq_len // LANE) * LANE
+        kv = (max_requests + 1, self.num_kv_heads, seq, self.head_dim)
+        idx = kv[:2] + (seq // self.kernel_stride, self.head_dim)
+        sh = TensorSharding.replicated(4)
+        return {"k": (kv, self.dtype, sh), "v": (kv, self.dtype, sh),
+                "kidx": (idx, self.dtype, sh)}
+
+    # ---- the index ------------------------------------------------------
+    @jax.named_scope("index_write")
+    def _append_index(self, kidx, kc, rows, pos):
+        """The compressed keys this step's rows complete, appended: a row at
+        ``pos`` with ``pos + 1`` a multiple of the stride (and a whole
+        kernel behind it) reads the kernel's keys back from the cache —
+        which already holds this step's — and writes their mean.  One trip
+        of a loop per COMPLETING row (a sixteenth of the rows, one slice of
+        32 keys each): a gather over all rows made XLA re-lay the whole K
+        cache for it, 0.8 GB a layer and step (PERF.md section 6, PR 44)."""
+        ks, st = self.kernel_size, self.kernel_stride
+        done = (rows != kc.shape[0] - 1) & ((pos + 1) % st == 0) \
+            & (pos + 1 >= ks)
+        order = jnp.argsort(~done, stable=True)
+        zero = jnp.int32(0)
+
+        def one(i, kidx):
+            f = order[i]
+            first = pos[f] + 1 - ks
+            span = jax.lax.dynamic_slice(
+                kc, (rows[f], zero, first, zero),
+                (1, self.num_kv_heads, ks, self.head_dim))
+            mean = jnp.mean(span.astype(jnp.float32), axis=2, keepdims=True)
+            return jax.lax.dynamic_update_slice(
+                kidx, mean.astype(kidx.dtype),
+                (rows[f], zero, first // st, zero))
+
+        return jax.lax.fori_loop(0, jnp.sum(done.astype(jnp.int32)), one,
+                                 kidx)
+
+    # ---- the selection --------------------------------------------------
+    def block_scores(self, q, idx, pos):
+        """``[T, KV, blocks]`` float32: each block's score for each row —
+        ``q [T, QH, D]``, the rows' compressed keys ``idx [T, KV, NC, D]``,
+        positions ``pos [T]``; -1 for a block no visible kernel overlaps."""
+        t = q.shape[0]
+        kv, g, st = self.num_kv_heads, self.group, self.kernel_stride
+        nc = idx.shape[2]
+        sc = jnp.einsum("tkgd,tkcd->tkgc", q.reshape(t, kv, g, -1), idx,
+                        preferred_element_type=jnp.float32)
+        seen = jnp.arange(nc, dtype=jnp.int32) < self.index_len(pos)[:, None]
+        sc = jnp.where(seen[:, None, None], sc * self.scaling_factor, NEG_INF)
+        p = jnp.where(seen[:, None, None], jax.nn.softmax(sc, axis=-1), 0.0)
+        r = jnp.where(seen[:, None], jnp.sum(p, axis=2), -1.0)  # [T, KV, NC]
+        # block b is overlapped by the kernels per * b - extra .. per * b +
+        # per - 1 (5 kernels of 32 at stride 16 on a block of 64)
+        per, extra = self.block_size // st, self.kernel_size // st - 1
+        r = jnp.pad(r, ((0, 0), (0, 0), (extra, 0)), constant_values=-1.0)
+        nb = nc // per
+        r = r[:, :, :nb * per + extra]
+        own = r[:, :, extra:].reshape(t, kv, nb, per).max(-1)
+        for e in range(extra):   # the kernels that begin before the block
+            own = jnp.maximum(own, r[:, :, e:e + nb * per:per])
+        return own
+
+    def select(self, q, idx, pos):
+        """The blocks each row attends, as a mask ``[T, KV, blocks]``."""
+        score = self.block_scores(q, idx, pos)
+        nb = score.shape[-1]
+        b = jnp.arange(nb, dtype=jnp.int32)
+        last = (pos // self.block_size)[:, None]
+        have = b <= last
+        forced = have & ((b < self.init_blocks)
+                         | (b > last - self.window // self.block_size))
+        free = (have & ~forced)[:, None]
+        k = min(self.topk, nb)
+        top, ids = jax.lax.top_k(jnp.where(free, score, -2.0), k)
+        chosen = jnp.zeros(score.shape, bool)
+        t_i = jnp.arange(score.shape[0])[:, None, None]
+        g_i = jnp.arange(score.shape[1])[None, :, None]
+        chosen = chosen.at[t_i, g_i, ids].set(top > -2.0)
+        sparse = forced[:, None] | chosen
+        return jnp.where((pos < self.dense_len)[:, None, None],
+                         have[:, None], sparse)
+
+    def _select_rows(self, q, kidx, rows, pos):
+        """``select`` for every row against its slot's index, ``ROWS`` rows
+        at a time (a row's index is a megabyte at the published sizes)."""
+        ROWS = 64
+
+        def some(args):
+            qs, rs, ps = args
+            return self.select(qs, kidx[rs], ps)
+
+        t = q.shape[0]
+        if t <= ROWS or t % ROWS:
+            return some((q, rows, pos))
+        cut = lambda a: a.reshape((t // ROWS, ROWS) + a.shape[1:])
+        out = jax.lax.map(some, (cut(q), cut(rows), cut(pos)))
+        return out.reshape((t,) + out.shape[2:])
+
+    # ---- attention ------------------------------------------------------
+    def _attend_masked(self, q, kc, vc, rows, pos, mask):
+        """Masked-dense attention of query groups against their slot's
+        cache: ``q [G, B, QH, D]``, cache row ``rows [G]``, positions ``pos
+        [G, B]``, block mask ``[G, B, KV, blocks]``.  A flat row is a group
+        of one, a prefill tile a group of ``tile`` rows.  Key spans of
+        ``KEY_SPAN`` go one after the other up to the furthest row's, under
+        one running softmax; float32."""
+        g_, b_, qh, d = q.shape
+        kv, grp, bs = self.num_kv_heads, self.group, self.block_size
+        s_len = kc.shape[2]
+        span = min(self.KEY_SPAN, s_len)
+        per = span // bs
+        qr = q.reshape(g_, b_, kv, grp, d)
+        zero = jnp.int32(0)
+
+        def one(i, carry):
+            m, l, acc = carry
+            lo = i * span
+            cut = lambda c: jax.lax.dynamic_slice(
+                c, (zero, zero, lo, zero),
+                (c.shape[0], kv, span, d))[rows]          # [G, KV, span, D]
+            sc = jnp.einsum("gbkhd,gksd->gbkhs", qr, cut(kc),
+                            preferred_element_type=jnp.float32)
+            at = lo + jnp.arange(span, dtype=jnp.int32)
+            sel = jax.lax.dynamic_slice_in_dim(mask, i * per, per, axis=3)
+            seen = jnp.repeat(sel, bs, axis=3) \
+                & (at <= pos[..., None])[:, :, None]      # [G, B, KV, span]
+            sc = jnp.where(seen[:, :, :, None], sc * self.scaling_factor,
+                           NEG_INF)
+            m_new = jnp.maximum(m, sc.max(-1))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.where(seen[:, :, :, None], jnp.exp(sc - m_new[..., None]),
+                          0.0)
+            pv = jnp.einsum("gbkhs,gksd->gbkhd", p.astype(vc.dtype), cut(vc),
+                            preferred_element_type=jnp.float32)
+            return (m_new, alpha * l + p.sum(-1),
+                    alpha[..., None] * acc + pv)
+
+        shape = (g_, b_, kv, grp)
+        init = (jnp.full(shape, NEG_INF, jnp.float32),
+                jnp.zeros(shape, jnp.float32),
+                jnp.zeros(shape + (d,), jnp.float32))
+        trips = jnp.minimum(jnp.max(pos) // span + 1, s_len // span)
+        _, l, acc = jax.lax.fori_loop(0, trips, one, init)
+        out = acc / jnp.maximum(l, 1e-30)[..., None]
+        return out.reshape(g_, b_, qh, d)
+
+    def block_list(self, mask):
+        """A block mask ``[T, KV, blocks]`` as sorted lists ``[T, KV,
+        max_blocks]`` and their lengths ``[T, KV]``; entries past the length
+        repeat the last attended block (no new copy in the kernel)."""
+        n = jnp.sum(mask, axis=-1).astype(jnp.int32)
+        order = jnp.argsort(~mask, axis=-1, stable=True)
+        order = order[..., :self.max_blocks].astype(jnp.int32)
+        at = jnp.minimum(jnp.arange(order.shape[-1], dtype=jnp.int32),
+                         jnp.maximum(n - 1, 0)[..., None])
+        return jnp.take_along_axis(order, at, axis=-1), n
+
+    def lower(self, ctx, inputs, params):
+        bc, state = _require(ctx, self.type_name)
+        x = inputs[0]
+        base = _flat(bc)
+        t = x.shape[0]
+        kc, vc, kidx = state["k"], state["v"], state["kidx"]
+        nreq = kc.shape[0] - 1
+        seg = Segments(base, nreq)
+        pos = jnp.where(seg.live, base.token_position, 0)
+        qn, kvn, zn = self._cols
+        kv, d = self.num_kv_heads, self.head_dim
+        with jax.named_scope("qkv_proj"):
+            w = dequant(params["qkv"], params.get("qkv_scale"), x.dtype)
+            y = jnp.dot(x, w, preferred_element_type=jnp.float32
+                        ).astype(x.dtype)
+            q = y[:, :qn].reshape(t, self.num_q_heads, d)
+            k = y[:, qn:qn + kvn].reshape(t, kv, d)
+            v = y[:, qn + kvn:qn + 2 * kvn].reshape(t, kv, d)
+            gate = y[:, qn + 2 * kvn:] if self.output_gate else None
+        pallas = bool(ctx.extras.get("pallas_decode"))
+        tiled = isinstance(bc, PrefillBatchConfig) and pallas
+        with jax.named_scope("attend"):
+            with jax.named_scope("kv_write"):
+                if tiled:
+                    bq = bc.tile_size
+                    g = t // bq
+                    block = lambda a: jnp.where(
+                        seg.live.reshape(g, 1, bq, 1),
+                        a.reshape(g, bq, kv, d).transpose(0, 2, 1, 3),
+                        0).astype(kc.dtype)
+                    kc, vc = _put_blocks(
+                        kc, vc, block(k), block(v),
+                        jnp.min(seg.rows.reshape(g, bq), axis=1),
+                        pos.reshape(g, bq)[:, 0])
+                else:
+                    put = IncMultiHeadSelfAttention._scatter_rows_pos
+                    kc = put(kc, seg.rows, pos, k)
+                    vc = put(vc, seg.rows, pos, v)
+                kidx = self._append_index(kidx, kc, seg.rows, pos)
+            ctx.extras["state_out"] = {"k": kc, "v": vc, "kidx": kidx}
+            # its own operator class in a device trace, apart from the node's
+            node = ctx.extras.get("node_name", "select")
+            with jax.named_scope(f"BlockSelect.{node}"):
+                mask = self._select_rows(q, kidx, seg.rows, pos)
+                if pallas and not tiled:
+                    blocks, count = self.block_list(mask)
+                    count = jnp.where(seg.live[:, None], count, 0)
+            if tiled:
+                rows = jnp.min(seg.rows.reshape(g, bq), axis=1)
+                out = self._attend_masked(
+                    q.reshape(g, bq, self.num_q_heads, d), kc, vc, rows,
+                    pos.reshape(g, bq), mask.reshape((g, bq) + mask.shape[1:]))
+                out, path = out.reshape(t, -1), "masked_dense_tile"
+            elif pallas:
+                from ..ops.pallas.attention import sparse_decode_attention
+
+                out = sparse_decode_attention(
+                    q, kc, vc, seg.rows, pos, blocks, count,
+                    scale=self.scaling_factor, block=self.block_size,
+                    interpret=bool(ctx.extras.get("pallas_interpret")))
+                out, path = out.reshape(t, -1), "sparse_decode_attention"
+            else:
+                out = self._attend_masked(q[:, None], kc, vc, seg.rows,
+                                          pos[:, None], mask[:, None])
+                out, path = out.reshape(t, -1), "xla"
+            paths = ctx.extras.get("attention_paths")
+            if paths is not None:
+                batch = ("one_row_per_request"
+                         if ctx.extras.get("one_row_per_request")
+                         else type(bc).__name__)
+                paths[(self.type_name, batch)] = path
+        with jax.named_scope("o_proj"):
+            out = out.astype(jnp.float32)
+            if gate is not None:
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32))
+            o_w = dequant(params["o_proj"], params.get("o_proj_scale"),
+                          x.dtype)
+            y = jnp.dot(out.astype(x.dtype), o_w,
+                        preferred_element_type=jnp.float32)
+            return [y.astype(self.dtype)]
+
+
+def lightning_slopes(num_heads: int):
+    """Lightning Attention's per-head decay rates: ``lambda_h = exp(-slope_h)``
+    with ``slope_h = 2 ** (-8 (h + 1) / H)``."""
+    h = jnp.arange(1, num_heads + 1, dtype=jnp.float32)
+    return jnp.exp2(-8.0 * h / num_heads)
+
+
+@register_op
+class LightningAttention(_SlotStateOp):
+    """Lightning linear attention over flat token batches (Qin et al.,
+    "Lightning Attention-2", as MiniCPM-SALA's ``lightning-attn`` layers run
+    it): ``q, k`` RMS-normed per head and rotated, ``S_t = lambda_h S_{t-1}
+    + k_t' v_t``, ``o_t = s q_t S_t``, an RMS norm of ``o`` per head, an
+    output gate ``sigmoid(x Wz)``.
+
+    State ``lin [rows, H, D, D]`` float32: one matrix per head and slot.
+    The decode scan (every live row a request of its own) updates the whole
+    state array in place, in slot order — decay, rank-one update and the
+    read-out in one elementwise pass, no gather of 2 MB a row and no scatter
+    back.  A prompt chunk or a flat step uses the CHUNKED form: inside the
+    batch ``((Q K') * D) V`` with the decay mask ``D`` (zero across
+    requests), and per request in the batch — a loop with as many trips as
+    the batch holds requests — the carried state's term ``lambda^(i+1) q_i
+    S0`` and the state it leaves, ``lambda^n S0 + sum_j lambda^(n-1-j) k_j'
+    v_j``: matrix products, no trip per row.
+    """
+
+    type_name = "lightning_attention"
+
+    def __init__(self, embed_dim: int, num_heads: int, head_dim: int,
+                 rope_theta: float = 10000.0, use_rope: bool = True,
+                 qk_norm: bool = True, output_norm: bool = True,
+                 output_gate: bool = True, eps: float = 1e-6,
+                 dtype=jnp.float32):
+        self.embed_dim = int(embed_dim)
+        self.num_q_heads = self.num_kv_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.rope_theta = float(rope_theta)
+        self.use_rope = bool(use_rope)
+        self.qk_norm = bool(qk_norm)
+        self.output_norm = bool(output_norm)
+        self.output_gate = bool(output_gate)
+        self.eps = float(eps)
+        self.scaling_factor = 1.0 / math.sqrt(self.head_dim)
+        self.dtype = jnp.dtype(dtype).name
+
+    def infer_shapes(self, in_specs):
+        return [TensorSpec(in_specs[0].shape, jnp.dtype(self.dtype))]
+
+    def params(self) -> List[ParamSpec]:
+        dt = jnp.dtype(self.dtype)
+        e, hd = self.embed_dim, self.head_dim
+        width = self.num_q_heads * hd
+        cols = (4 if self.output_gate else 3) * width
+        ps = [ParamSpec("qkv", TensorSpec((e, cols), dt)),   # q | k | v | gate
+              ParamSpec("o_proj", TensorSpec((width, e), dt))]
+        if self.qk_norm:
+            ps += [ParamSpec(n, TensorSpec((hd,), dt), _init(jnp.ones))
+                   for n in ("q_norm", "k_norm")]
+        if self.output_norm:
+            ps.append(ParamSpec("o_norm", TensorSpec((width,), dt),
+                                _init(jnp.ones)))
+        return ps
+
+    def flops(self, in_specs):
+        width = self.num_q_heads * self.head_dim
+        return 2 * in_specs[0].shape[0] * self.embed_dim * width * (
+            5 if self.output_gate else 4)
+
+    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
+                    head_axes=()):
+        shape = (max_requests + 1, self.num_q_heads, self.head_dim,
+                 self.head_dim)
+        return {"lin": (shape, "float32", TensorSharding.replicated(4))}
+
+    # ---- the three forms --------------------------------------------------
+    def _slot_order(self, q, k, v, lin, seg):
+        """The decode scan's step: every slot's matrix decayed, updated and
+        read where a row of the batch is its request's, untouched where
+        none is — ONE pass over the state array."""
+        nslot, h, d = lin.shape[0], self.num_q_heads, self.head_dim
+        slope = lightning_slopes(h)
+        at = seg.rows                      # pads land on the scratch row
+        by_slot = lambda a: jnp.zeros((nslot,) + a.shape[1:], jnp.float32
+                                      ).at[at].set(a.astype(jnp.float32))
+        qs, ks, vs = by_slot(q), by_slot(k), by_slot(v)
+        live = jnp.zeros((nslot,), bool).at[at].set(seg.live)
+        fresh = jnp.zeros((nslot,), bool).at[at].set(seg.fresh)
+        keep = jnp.where(fresh, 0.0, 1.0)[:, None] \
+            * jnp.exp(-slope)[None, :]                     # [slots, H]
+        # (no matmul: a float32 one would round the state to bf16 on the MXU)
+        new = keep[:, :, None, None] * lin \
+            + ks[:, :, :, None] * vs[:, :, None, :]
+        o = jnp.sum(qs[:, :, :, None] * new, axis=2) * self.scaling_factor
+        with jax.named_scope("state_write"):
+            lin = jnp.where(live[:, None, None, None], new, lin)
+        return o[at], lin
+
+    def _chunked(self, q, k, v, lin, seg):
+        """A prompt chunk or a flat step (see the class docstring)."""
+        t, h, d = q.shape
+        hi = jax.lax.Precision.HIGHEST
+        slope = lightning_slopes(h)
+        f32 = lambda a: a.astype(jnp.float32)
+        seg_id = jnp.cumsum(seg.start.astype(jnp.int32))
+        i = jnp.arange(t, dtype=jnp.int32)
+        back = (i[:, None] - i[None, :]).astype(jnp.float32)
+        same = (seg_id[:, None] == seg_id[None, :]) & (back >= 0) \
+            & seg.live[:, None]
+        decay = jnp.where(same[None], jnp.exp(
+            -slope[:, None, None] * jnp.maximum(back, 0.0)[None]), 0.0)
+        a = jnp.einsum("ihd,jhd->hij", q, k,
+                       preferred_element_type=jnp.float32) * decay
+        out = jnp.einsum("hij,jhd->ihd", a, f32(v), precision=hi)
+        # per request in the batch: what its stored state adds, and the
+        # state it leaves behind
+        first = seg.start & seg.live
+        order = jnp.argsort(~first, stable=True)
+        off = seg.offset.astype(jnp.float32)
+        zero = jnp.int32(0)
+
+        def one(n, carry):
+            out, lin = carry
+            f = order[n]
+            mine = (seg_id == seg_id[f]) & seg.live
+            count = jnp.sum(mine).astype(jnp.float32)
+            at = (seg.rows[f], zero, zero, zero)
+            s0 = jax.lax.dynamic_slice(lin, at, (1,) + lin.shape[1:])[0]
+            s0 = jnp.where(seg.fresh[f], 0.0, s0)
+            carried = jnp.einsum("thd,hde->the", f32(q), s0, precision=hi) \
+                * jnp.exp(-(off[:, None] + 1.0) * slope[None, :])[..., None]
+            out = out + jnp.where(mine[:, None, None], carried, 0.0)
+            left = jnp.where(mine[:, None], jnp.exp(
+                -jnp.maximum(count - 1.0 - off, 0.0)[:, None]
+                * slope[None, :]), 0.0)
+            s1 = jnp.exp(-count * slope)[:, None, None] * s0 + jnp.einsum(
+                "thd,the->hde", f32(k) * left[..., None], f32(v),
+                precision=hi)
+            return out, jax.lax.dynamic_update_slice(lin, s1[None], at)
+
+        with jax.named_scope("state_write"):
+            out, lin = jax.lax.fori_loop(
+                0, jnp.sum(first.astype(jnp.int32)), one, (out, lin))
+        return out * self.scaling_factor, lin
+
+    def lower(self, ctx, inputs, params):
+        bc, state = _require(ctx, self.type_name)
+        x = inputs[0]
+        base = _flat(bc)
+        t, h, d = x.shape[0], self.num_q_heads, self.head_dim
+        lin = state["lin"]
+        seg = Segments(base, lin.shape[0] - 1)
+        with jax.named_scope("qkv_proj"):
+            w = dequant(params["qkv"], params.get("qkv_scale"), x.dtype)
+            y = jnp.dot(x, w, preferred_element_type=jnp.float32
+                        ).astype(x.dtype)
+            q, k, v = (y[:, n * h * d:(n + 1) * h * d].reshape(t, h, d)
+                       for n in range(3))
+            gate = y[:, 3 * h * d:] if self.output_gate else None
+            if self.qk_norm:
+                q = _rms_norm(q, params["q_norm"], self.eps)
+                k = _rms_norm(k, params["k_norm"], self.eps)
+            if self.use_rope:
+                q = apply_rope(q, base.token_position, self.rope_theta)
+                k = apply_rope(k, base.token_position, self.rope_theta)
+        with jax.named_scope("attend"):
+            if ctx.extras.get("one_row_per_request"):
+                o, lin = self._slot_order(q, k, v, lin, seg)
+                path, batch = "slot_order", "one_row_per_request"
+            else:
+                o, lin = self._chunked(q, k, v, lin, seg)
+                path, batch = "chunked", type(bc).__name__
+            ctx.extras["state_out"] = {"lin": lin}
+            paths = ctx.extras.get("attention_paths")
+            if paths is not None:
+                paths[(self.type_name, batch)] = path
+        with jax.named_scope("o_proj"):
+            if self.output_norm:
+                o = _rms_norm(o, params["o_norm"].reshape(h, d), self.eps)
+            o = o.astype(jnp.float32).reshape(t, h * d)
+            if gate is not None:
+                o = o * jax.nn.sigmoid(gate.astype(jnp.float32))
+            o_w = dequant(params["o_proj"], params.get("o_proj_scale"),
+                          x.dtype)
+            y = jnp.dot(o.astype(x.dtype), o_w,
                         preferred_element_type=jnp.float32)
             return [y.astype(self.dtype)]

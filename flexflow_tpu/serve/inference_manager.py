@@ -171,8 +171,8 @@ def register_serve_capacities(graph, max_requests, max_seq_len,
 def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                                  max_spec_tokens=0, tp=1,
                                  pipelined=False) -> None:
-    """A graph that keeps window rings, recurrent state or a compacting
-    cache per slot (``slot_state`` ops: serve/hybrid_ops.py) runs
+    """A graph that keeps window rings, recurrent or matrix state, a
+    compacting cache or an index beside its cache per slot (``slot_state`` ops: serve/hybrid_ops.py) runs
     slot-contiguous, in its compute dtype, one token a step, on one chip.
     Each other deployment option needs something that is not written yet; it
     is refused here, at compile, by what is missing — none silently takes
@@ -185,20 +185,25 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
     if kv_page_size:
         missing.append("kv_page_size: a page table for a ring that wraps "
                        "or a cache that compacts (pages assume one entry a "
-                       "position), and copy-on-write of recurrent state or "
-                       "of an open window at a shared prefix's end")
+                       "position), pages for an index of compressed keys "
+                       "beside a cache, and copy-on-write of recurrent or "
+                       "matrix state or of an open window at a shared "
+                       "prefix's end")
     if kv_dtype == "int8":
         missing.append("kv_dtype='int8': quantise-on-write of the window "
-                       "ring, of the cache the cross-attention layers read "
-                       "and of a compacting cache's summaries")
+                       "ring, of the cache the cross-attention layers read, "
+                       "of a compacting cache's summaries and of a cache "
+                       "whose compressed keys choose what is read")
     if max_spec_tokens:
-        missing.append("speculation: a recurrent state or a closed window "
-                       "cannot be rolled back over rejected tokens without "
-                       "a snapshot per tree node")
+        missing.append("speculation: a recurrent or matrix state, a closed "
+                       "window or an appended index entry cannot be rolled "
+                       "back over rejected tokens without a snapshot per "
+                       "tree node")
     if tp > 1:
         missing.append("tp > 1: a sharding rule for the conv, the scan, the "
-                       "differential attention's head pairs and the "
-                       "per-head summaries")
+                       "differential attention's head pairs, the per-head "
+                       "summaries, a selection per K/V head on fewer K/V "
+                       "heads than chips and a matrix state per head")
     if pipelined:
         missing.append("pp > 1 (the pipelined manager, at any number of "
                        "stages): the exported scan output and the shared "

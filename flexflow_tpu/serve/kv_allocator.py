@@ -53,11 +53,20 @@ KV_BUFFER_NAMES = frozenset({"k", "v", "k_scale", "v_scale"})
 # on top, so a position has no price — ``bytes_per_token`` leaves it out
 # (it is None for a graph that holds nothing else, and admission then gates
 # in positions against ``capacity_tokens``: a slot is a slot).
+# ``kv_index`` is the index of compressed keys a sparse-attention layer keeps
+# BESIDE its full-length cache (SparseBlockAttention: one entry every
+# ``kernel_stride`` positions): it grows with the context in step with the
+# cache, so a position's price includes its share (``bytes_per_token``).
+# ``linear_state`` is a linear-attention layer's matrix per head
+# (LightningAttention): fixed, priced per slot like ``recurrent``.
+KV_INDEX_NAMES = frozenset({"kidx"})
 STATE_KINDS = {
     "kv_full": KV_BUFFER_NAMES,
     "kv_window": frozenset({"wk", "wv"}),
     "recurrent": frozenset({"conv", "ssm"}),
     "kv_compact": frozenset({"ck", "cv"}),
+    "kv_index": KV_INDEX_NAMES,
+    "linear_state": frozenset({"lin"}),
 }
 
 
@@ -197,9 +206,13 @@ class StageKV:
         total = 0.0
         for bufs in self.state.values():
             for name, arr in bufs.items():
+                rows = max(arr.shape[0] - 1, 1)  # minus the scratch row
                 if name in KV_BUFFER_NAMES:
-                    rows = max(arr.shape[0] - 1, 1)  # minus the scratch row
                     total += arr.nbytes / (rows * arr.shape[2])
+                elif name in KV_INDEX_NAMES:
+                    # an index entry per ``stride`` positions of the cache
+                    # it lies beside: its bytes over that cache's positions
+                    total += arr.nbytes / (rows * bufs["k"].shape[2])
         return total or None
 
     def bytes_per_slot(self) -> Dict[str, float]:

@@ -64,6 +64,37 @@ class ServeModelConfig:
     chunk_size: Optional[int] = None
     num_pred_heads: int = 1
     norm_add_unit_offset: bool = False
+    # minicpm_sala (``models/minicpm_sala.py``): ``mixer_types[i]`` says what
+    # layer i mixes with — ``minicpm4`` (InfLLM-v2 sparse attention over
+    # ``num_key_value_heads`` K/V heads, the ``attn_*`` keys and the
+    # ``sparse_*`` sizes) or ``lightning-attn`` (linear attention, the
+    # ``lightning_*`` keys, ``qk_norm``, ``use_output_norm``,
+    # ``use_output_gate``); muP: the embedding times ``scale_emb``, each
+    # block's output times ``scale_depth / sqrt(mup_denominator)`` (the
+    # PUBLISHED depth, whatever the depth run), the final hidden state over
+    # ``hidden_size / dim_model_base``.  The sparse sizes are MiniCPM4's
+    # InfLLM-v2 convention; HF's config.json nests them in ``sparse_config``.
+    mixer_types: Optional[tuple] = None
+    attn_use_rope: bool = False
+    attn_use_output_gate: bool = True
+    lightning_nh: Optional[int] = None
+    lightning_nkv: Optional[int] = None
+    lightning_head_dim: Optional[int] = None
+    lightning_use_rope: bool = True
+    qk_norm: bool = True
+    use_output_norm: bool = True
+    use_output_gate: bool = True
+    scale_emb: float = 1.0
+    scale_depth: float = 1.0
+    mup_denominator: Optional[int] = None
+    dim_model_base: Optional[int] = None
+    sparse_kernel_size: int = 32
+    sparse_kernel_stride: int = 16
+    sparse_block_size: int = 64
+    sparse_topk: int = 64
+    sparse_window_size: int = 2048
+    sparse_init_blocks: int = 1
+    sparse_dense_len: int = 8192
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

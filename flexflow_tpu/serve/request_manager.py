@@ -22,7 +22,7 @@ import numpy as np
 
 from ..obs.telemetry import telemetry_or_null
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .hybrid_ops import compact_geometry, compact_len
+from .hybrid_ops import compact_geometry, compact_len, sparse_geometry
 from .inference_manager import EXIT_NOT_IN_BATCH
 from .resilience import ResilienceConfig, TransientServeError
 
@@ -200,6 +200,9 @@ class RequestManager:
         # (window, chunk, layers) of a cache that compacts itself, or None:
         # what ``_compact_counts`` tells the dispatch spans of it
         self._compact = compact_geometry(im.model.graph)
+        # (a sparse-attention op, its layers, the linear-attention layers),
+        # or None: what ``_sparse_counts`` tells them
+        self._sparse = sparse_geometry(im.model.graph)
         self.scan_runs = 0      # decode stretches run as on-device scans
         # ONE Telemetry handle across the serving stack: syncing it onto the
         # InferenceManager (which forwards to pipeline stages) puts request
@@ -339,8 +342,50 @@ class RequestManager:
             "ctx_sum": sum(hi for _, _, hi in dec),
             "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
                                   for _, lo, hi in pre),
-            **self._compact_counts([(lo, hi) for _, lo, hi in spans]),
+            **self._slot_state_counts([(lo, hi) for _, lo, hi in spans]),
         }
+
+    def _slot_state_counts(self, writes) -> Dict[str, int]:
+        """Dispatch-span arguments (and counters) of a launch that writes
+        the positions ``[(lo, hi)]``, one pair a row, for the kinds of
+        per-slot state whose work ``ctx_sum`` does not tell."""
+        return {**self._compact_counts(writes), **self._sparse_counts(writes)}
+
+    def _sparse_counts(self, writes) -> Dict[str, int]:
+        """For a graph with sparse-attention layers (``hybrid_ops.
+        SparseBlockAttention``): ``attended_blocks_sum``, the cache blocks
+        the rows read at launch (per sparse layer and K/V head — every block
+        below ``dense_len``, the forced and chosen ones after it), and
+        ``index_len_sum``, the compressed keys they choose by.  Counted too:
+        ``sparse.blocks_attended`` and ``sparse.dense_rows`` over every
+        position the launch writes, ``sparse.index_entries_written``, and
+        ``linear.state_resets`` (requests whose linear-attention state
+        starts from zero).  Nothing for a graph with neither."""
+        if self._sparse is None:
+            return {}
+        op, layers, linear = self._sparse
+        writes = [(lo, hi) for lo, hi in writes if hi > lo]
+        tel = self.telemetry
+        if tel.enabled and linear:
+            fresh = sum(lo == 0 for lo, _ in writes)
+            if fresh:
+                tel.metrics.counter("linear.state_resets").inc(fresh * linear)
+        if op is None:
+            return {}
+        if tel.enabled:
+            dense = op.dense_len
+            count = tel.metrics.counter
+            count("sparse.blocks_attended").inc(layers * sum(
+                op.attended_blocks_between(lo, hi) for lo, hi in writes))
+            count("sparse.dense_rows").inc(layers * sum(
+                min(hi, dense) - min(lo, dense) for lo, hi in writes))
+            entries = sum(op.index_len(hi - 1) - op.index_len(lo - 1)
+                          for lo, hi in writes)
+            if entries:
+                count("sparse.index_entries_written").inc(entries * layers)
+        return {"attended_blocks_sum": sum(op.attended_blocks(lo)
+                                           for lo, _ in writes),
+                "index_len_sum": sum(op.index_len(lo) for lo, _ in writes)}
 
     def _compact_counts(self, writes) -> Dict[str, int]:
         """What a launch that writes the positions ``[(lo, hi)]`` (one pair
@@ -1635,7 +1680,7 @@ class RequestManager:
             cnt = {"rows": rows, "joiners": len(joiners or ()),
                    "prompt_tokens": fed,
                    "ctx_sum": sum(st for st, _ in feeds[at: at + seg]),
-                   **self._compact_counts(
+                   **self._slot_state_counts(
                        [(st, st + t) for st, t in feeds[at: at + seg]])}
             res = self._guarded(
                 "prefill_scan",
@@ -1793,7 +1838,7 @@ class RequestManager:
                     if k > 0:   # a live row, and its KV length at launch
                         cnt["rows"] += 1
                         cnt["ctx_sum"] += dev_seq[req.rid]
-                cnt.update(self._compact_counts(
+                cnt.update(self._slot_state_counts(
                     [(dev_seq[req.rid] - 1, dev_seq[req.rid] - 1
                       + ks[req.rid]) for req, _ in rows]))
                 if prof.enabled:

@@ -1,0 +1,590 @@
+"""MiniCPM-SALA (sparse block-selected attention beside Lightning linear
+attention) through the normal serve path, against the plain reference
+``benchmark/reference/minicpm_sala.py`` — logits, not tokens.
+
+Toy widths, the real mechanisms: hidden 64, 4 query heads on 2 K/V heads of
+16, lightning 4 heads of 16, 4 layers ``[minicpm4, lightning-attn,
+lightning-attn, minicpm4]``; kernel 4 / stride 2 / block 8 / top-k 3 / window
+16 / ``dense_len`` 48, 256 positions.  Past position 48 a row attends block 0,
+its 2 newest blocks and 3 CHOSEN ones of up to 29: every sequence here goes
+past that, in prefill and in decode.  Weights are the benchmark's seeded ones
+in float32 (``seeded_weights.program_params`` also holds the program's
+parameter tree to the reference's ``program_tree``, name by name).
+
+The reference is a dense softmax under a mask and a literal sum over earlier
+positions; the program keeps a cache, an index, block lists and a matrix
+state.  float32 on the CPU against float32 at HIGHEST precision: they differ
+by summation order alone and a log-probability agrees to 2e-4 nats — the
+selection replaced by the newest blocks, a stale index, a dropped decay or the
+residual scale taken from the cut depth move it by 4e-3 or more
+(``test_a_break_is_seen`` holds that).
+"""
+
+import functools
+import json
+import math
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import check, longctx, seeded_weights as sw  # noqa: E402
+from benchmark.reference import minicpm_sala as ref  # noqa: E402
+from flexflow_tpu.config import FFConfig  # noqa: E402
+from flexflow_tpu.model import FFModel  # noqa: E402
+from flexflow_tpu.parallel.mesh import make_mesh  # noqa: E402
+from flexflow_tpu.serve import BatchConfig  # noqa: E402
+from flexflow_tpu.serve import hybrid_ops  # noqa: E402
+from flexflow_tpu.serve.hybrid_ops import (  # noqa: E402
+    LightningAttention,
+    Segments,
+    SparseBlockAttention,
+    lightning_slopes,
+)
+from flexflow_tpu.serve.inference_manager import InferenceManager  # noqa: E402
+from flexflow_tpu.serve.models import minicpm_sala as builder  # noqa: E402
+from flexflow_tpu.serve.models.base import (  # noqa: E402
+    ServeModelConfig,
+    build_model,
+)
+
+HF = dict(model_type="minicpm_sala", hidden_size=64, intermediate_size=96,
+          num_hidden_layers=4, num_attention_heads=4, num_key_value_heads=2,
+          head_dim=16, lightning_nh=4, lightning_nkv=4, lightning_head_dim=16,
+          mixer_types=["minicpm4", "lightning-attn", "lightning-attn",
+                       "minicpm4"],
+          vocab_size=320, rms_norm_eps=1e-6, rope_theta=10000, scale_emb=12,
+          scale_depth=1.4, mup_denominator=32, dim_model_base=16,
+          qk_norm=True, use_output_norm=True, use_output_gate=True,
+          attn_use_output_gate=True, attn_use_rope=False,
+          lightning_use_rope=True, sparse_kernel_size=4,
+          sparse_kernel_stride=2, sparse_block_size=8, sparse_topk=3,
+          sparse_window_size=16, sparse_init_blocks=1, sparse_dense_len=48,
+          max_position_embeddings=256,
+          # std * sqrt(width) ~ 1, as 0.02 nearly is at the published 4096
+          init_std=0.125, torch_dtype="float32")
+SLOTS, CAP, SEQ = 3, 16, 256
+DENSE = HF["sparse_dense_len"]
+TOL = 2e-4          # nats, see the module docstring
+SEED = 4321
+
+
+def build(cap=CAP, seq=SEQ, use_pallas=False, hf=HF, **kw):
+    mesh = make_mesh({"tp": 1}, jax.devices()[:1])
+    ff = FFModel(FFConfig(), mesh=mesh)
+    build_model(ff, ServeModelConfig.from_hf_config(hf), cap)
+    return InferenceManager(ff, max_requests=SLOTS, max_tokens_per_batch=cap,
+                            max_seq_len=seq, topk=HF["vocab_size"],
+                            use_pallas=use_pallas, **kw)
+
+
+def seeded(im):
+    im.init_operators_inference()
+    like = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
+        im.params)
+    im.params = sw.program_params(ref, HF, sw.base_key(SEED), like, "float32")
+    return im
+
+
+@functools.lru_cache(maxsize=None)
+def deployment(use_pallas=False):
+    """One compiled deployment per kernel setting, shared by the tests (each
+    starts its sequences at position 0 of a slot, which is all a slot needs
+    to start clean)."""
+    return seeded(build(use_pallas=use_pallas))
+
+
+@functools.lru_cache(maxsize=None)
+def _ref_layer(padded_len):
+    return jax.jit(lambda key, i, x: ref.layer(
+        HF, sw.draw_table(key, i, ref.LAYER, HF, "float32"), x))
+
+
+def reference_logprobs(ids):
+    """Sorted log-probabilities at every position of ``ids``, and the
+    reference's greedy tokens, from its full forward pass."""
+    key = sw.base_key(SEED)
+    g = sw.draw_table(key, sw.GLOBAL_ID, ref.GLOBAL, HF, "float32")
+    padded = np.zeros(-(-len(ids) // 64) * 64, np.int32)
+    padded[:len(ids)] = ids
+    x = ref.embed(HF, g, jnp.asarray(padded[None]))
+    for i in range(ref.num_layers(HF)):
+        x = _ref_layer(len(padded))(key, jnp.int32(i), x)
+    logits = ref.head(HF, g, x[:, :len(ids)])[0]
+    lp = jax.nn.log_softmax(logits, axis=-1)
+    return np.asarray(jnp.sort(lp, axis=-1)[:, ::-1]), \
+        np.asarray(jnp.argmax(logits, axis=-1))
+
+
+def tokens(n, salt=0):
+    rng = np.random.default_rng([SEED, salt])
+    return rng.integers(4, HF["vocab_size"], size=n).tolist()
+
+
+def flat_step(im, pieces, seq_lens):
+    """One flat step holding ``pieces`` = [(slot, ids, start position)];
+    returns the sorted log-probabilities per piece, and the tokens."""
+    toks, slots, pos = [], [], []
+    for slot, ids, start in pieces:
+        toks += list(ids)
+        slots += [slot] * len(ids)
+        pos += list(range(start, start + len(ids)))
+        seq_lens[slot] = start + len(ids)
+    bc = BatchConfig.build(toks, slots, pos, seq_lens,
+                           max_tokens=im.max_tokens, max_requests=SLOTS)
+    res = im.step(bc)
+    lp, out, at = np.asarray(res.topk_logprobs), [], 0
+    for _, ids, _ in pieces:
+        out.append(lp[at:at + len(ids)])
+        at += len(ids)
+    return out, np.asarray(res.token_ids)
+
+
+def feed_flat(im, slot, ids, sizes, seq_lens):
+    """``ids`` into ``slot`` from position 0 by flat steps of the given
+    sizes (cycled); the log-probabilities at every position."""
+    rows, at, i = [], 0, 0
+    while at < len(ids):
+        take = min(sizes[i % len(sizes)], len(ids) - at)
+        (lp,), _ = flat_step(im, [(slot, ids[at:at + take], at)], seq_lens)
+        rows.append(lp)
+        at, i = at + take, i + 1
+    return np.concatenate(rows)
+
+
+def decode_scan(im, slot, first, position, steps):
+    """``steps`` decode steps of ``slot`` on the device, in chained scans of
+    at most 32: the tokens produced after ``first`` (fed at ``position``)."""
+    seq = np.zeros(SLOTS, np.int32)
+    seq[slot] = position + 1
+    bc = BatchConfig.build([first], [slot], [position], seq,
+                           max_tokens=im.max_tokens, max_requests=SLOTS)
+    out, done = [], 0
+    while done < steps:
+        n = min(32, steps - done)
+        allowed = np.zeros(im.max_tokens, np.int32)
+        allowed[0] = steps - done
+        toks, live, _, bc = im.decode_scan_async(
+            bc, n, allowed=allowed, max_position=position + done)
+        assert np.asarray(live)[:, 0].all()
+        out += np.asarray(toks)[:, 0].tolist()
+        done += n
+    return out
+
+
+PROMPT = tokens(150)    # 102 of its rows select: 19 blocks, 6 attended
+
+
+@pytest.mark.parametrize("how", ["uneven_chunks", "tiled_scan",
+                                 "tiled_scan_pallas", "uneven_chunks_pallas"])
+def test_prompt_feeding_paths_agree_with_the_reference(how):
+    """The same prompt in uneven flat chunks (segments of one request that
+    begin anywhere; the chunk that holds position 48 has rows on both sides
+    of ``dense_len``) and through the tiled prefill scan, kernels off (the
+    masked-dense oracle) and on (block lists through
+    ``sparse_decode_attention`` for flat rows, masked-dense tiles for the
+    scan): a decode step then reads what each left."""
+    want, want_tok = reference_logprobs(PROMPT + tokens(3, salt=1))
+    n = len(PROMPT)
+    seq_lens = [0] * SLOTS
+    im = deployment(use_pallas=how.endswith("pallas"))
+    if how.startswith("tiled_scan"):
+        first = check._prefill_scan(im, 1, PROMPT, list(seq_lens))
+        assert first == want_tok[n - 1]
+    else:
+        got = feed_flat(im, 1, PROMPT, [7, CAP, 1, 13, 3], seq_lens)
+        np.testing.assert_allclose(got, want[:n], atol=TOL, rtol=0)
+    for k, tok in enumerate(tokens(3, salt=1)):
+        (lp,), _ = flat_step(im, [(1, [tok], n + k)], seq_lens)
+        np.testing.assert_allclose(lp[0], want[n + k], atol=TOL, rtol=0)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+def test_decode_scan_passes_dense_len_and_appends_what_prefill_makes(
+        use_pallas):
+    """A 30-token prompt, then 70 decode steps on the device in chained
+    scans: the row passes ``dense_len`` (48) — from attending everything to
+    selecting — and completes 35 compressed keys INSIDE THE SCAN.  Flat
+    steps then read, at position 100 on, logits that depend on what the scan
+    selected, wrote and accumulated; and the K/V, the index and the matrix
+    states the scan left are those the same 100 tokens leave when PREFILLED
+    into another slot."""
+    im = deployment(use_pallas=use_pallas)
+    prompt = tokens(30, salt=5)
+    seq_lens = [0] * SLOTS
+    feed_flat(im, 0, prompt[:-1], [CAP], seq_lens)
+    _, toks = flat_step(im, [(0, prompt[-1:], 29)], seq_lens)
+    first = int(toks[0])
+    made = decode_scan(im, 0, first, 30, 70)
+    full = prompt + [first] + made                  # 101 tokens
+    # teacher forcing: the reference is fed what the program produced
+    want, want_tok = reference_logprobs(full + tokens(2, salt=6))
+    assert full[30:] == want_tok[29:100].tolist()
+    # the same tokens, prefilled: every index entry made by the prompt path
+    # (before the flat steps below: the matrix state is cumulative)
+    feed_flat(im, 2, full[:100], [CAP], seq_lens)
+    entries = (100 - 4) // 2 + 1
+    for node, bufs in im.state.items():
+        for name, live in (("k", 100), ("v", 100), ("kidx", entries),
+                           ("lin", None)):
+            if name not in bufs:
+                continue
+            a, b = bufs[name][0], bufs[name][2]
+            if live:
+                a, b = a[:, :live], b[:, :live]
+            assert float(jnp.abs(a).max()) > 1e-2, (node, name)
+            np.testing.assert_allclose(a, b, atol=2e-5, rtol=1e-4)
+    seq_lens[0] = 100
+    for k, tok in enumerate([full[100]] + tokens(2, salt=6)):
+        (got,), _ = flat_step(im, [(0, [tok], 100 + k)], seq_lens)
+        np.testing.assert_allclose(got[0], want[100 + k], atol=TOL, rtol=0)
+
+
+def _sparse_op():
+    return SparseBlockAttention(64, 4, 2, 16, kernel_size=4, kernel_stride=2,
+                                block_size=8, topk=3, window=16,
+                                init_blocks=1, dense_len=DENSE)
+
+
+@pytest.mark.parametrize("salt", [0, 1])
+def test_selected_sets_equal_the_references(salt):
+    """The op's selection (an index cache, a max pool by slices, ``top_k``,
+    a mask) against the reference's (means of slices of all keys, an overlap
+    table, ``top_k``) on the same random queries and keys, float32: the same
+    SETS, row by row and group by group — 6 blocks of up to 25 past
+    ``dense_len``, every block before it."""
+    op = _sparse_op()
+    rng = np.random.default_rng([SEED, 70 + salt])
+    t = 200
+    q = jnp.asarray(rng.normal(size=(t, 4, 16)) * 2, jnp.float32)
+    k = jnp.asarray(rng.normal(size=(t, 2, 16)) * 2, jnp.float32)
+    sizes = ref.sparse_sizes(HF)
+    pos = jnp.arange(t, dtype=jnp.int32)
+    want = ref.attended_blocks(q[None], pos, ref.compressed_keys(k[None],
+                                                                 sizes),
+                               SEQ // 8, sizes)[0]
+    kbar = ref.compressed_keys(k[None], sizes)[0]           # [NC, KV, hd]
+    idx = jnp.zeros((2, SEQ // 2, 16)).at[:, :kbar.shape[0]].set(
+        kbar.transpose(1, 0, 2))
+    got = op.select(q, jnp.broadcast_to(idx, (t,) + idx.shape), pos)
+    assert got.shape == want.shape == (t, 2, SEQ // 8)
+    assert bool(jnp.all(got == want))
+    count = np.asarray(got.sum(-1))
+    assert (count[:DENSE] == np.arange(DENSE)[:, None] // 8 + 1).all()
+    assert (count[DENSE:] == 6).all() and op.max_blocks == 6
+    # and the two groups do choose differently
+    assert bool(jnp.any(got[DENSE:, 0] != got[DENSE:, 1]))
+    blocks, n = op.block_list(got)
+    assert (np.asarray(n) == count).all()
+    assert (np.diff(np.asarray(blocks), axis=-1) >= 0).all()
+
+
+def test_chunked_lightning_form_equals_the_recurrence():
+    """``LightningAttention._chunked`` on a flat batch that holds a fresh
+    segment, a segment that continues a stored state, a one-row segment and
+    pads — against ``S = lambda S + k' v; o = s q S`` row by row; then the
+    batch cut in two at an arbitrary row (a chunk's end inside a segment):
+    the second half starts from the states the first stored."""
+    op = LightningAttention(64, 4, 16)
+    rng = np.random.default_rng([SEED, 90])
+    t, h, d, slots = 24, 4, 16, 3
+    q, k, v = (jnp.asarray(rng.normal(size=(t, h, d)), jnp.float32)
+               for _ in range(3))
+    stored = jnp.asarray(rng.normal(size=(slots + 1, h, d, d)), jnp.float32)
+    req = [0] * 9 + [2] * 11 + [1] + [-1] * 3
+    pos = list(range(9)) + list(range(40, 51)) + [7] + [0] * 3
+    lam = np.exp(-np.asarray(lightning_slopes(h)))
+
+    def by_rows(lo, hi, state):
+        state, outs = np.array(state), []
+        for i in range(lo, hi):
+            if req[i] < 0:
+                outs.append(np.zeros((h, d)))
+                continue
+            s0 = 0 if pos[i] == 0 else state[req[i]]
+            s1 = lam[:, None, None] * s0 + np.einsum(
+                "hd,he->hde", np.asarray(k[i]), np.asarray(v[i]))
+            state[req[i]] = s1
+            outs.append(np.einsum("hd,hde->he", np.asarray(q[i]), s1)
+                        / math.sqrt(d))
+        return np.stack(outs), state
+
+    def chunked(lo, hi, state):
+        bc = BatchConfig.build([5] * (hi - lo), req[lo:hi], pos[lo:hi],
+                               [9, 8, 51], max_tokens=hi - lo,
+                               max_requests=slots)
+        live = sum(r >= 0 for r in req[lo:hi])
+        out, state = op._chunked(q[lo:hi], k[lo:hi], v[lo:hi], state,
+                                 Segments(bc, slots))
+        return np.asarray(out)[:live], state
+
+    want, want_state = by_rows(0, t, stored)
+    got, got_state = chunked(0, t, stored)
+    np.testing.assert_allclose(got, want[:21], atol=2e-5, rtol=1e-5)
+    np.testing.assert_allclose(got_state[:slots], want_state[:slots],
+                               atol=2e-5, rtol=1e-5)
+    for cut in (5, 14):    # inside the first segment, inside the second
+        a, mid = chunked(0, cut, stored)
+        b, end = chunked(cut, t, mid)
+        np.testing.assert_allclose(np.concatenate([a, b]), want[:21],
+                                   atol=2e-5, rtol=1e-5)
+        np.testing.assert_allclose(end[:slots], want_state[:slots],
+                                   atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["xla", "pallas"])
+def test_the_harness_drives_are_correct(use_pallas):
+    """``benchmark/check.py``'s drive (the tiled prefill scan, a flat
+    prompt, a joiner spliced by ``join_slot`` between two chained decode
+    scans, flat steps on all three rows: row A selects from position 48 on)
+    and ``benchmark/longctx.py``'s (two rows decoding together, one across a
+    block's end, one across ``dense_len``)."""
+    im = deployment(use_pallas=use_pallas)
+    lines = []
+    ok, _ = check.run_check(im, ref, HF, sw.base_key(SEED), "float32", 77,
+                            HF["vocab_size"], LIMITS, lines.append)
+    assert ok, "\n".join(lines)
+    ok, _, reading = longctx.run_longctx(
+        im, ref, HF, sw.base_key(SEED), "float32", 78, LIMITS, lines.append)
+    assert ok, "\n".join(lines)
+    assert reading[0] == 1.0 and reading[2] == 2 * 2 * 68
+    paths = im.attention_paths
+    assert {k for k, _ in paths} == {"sparse_block_attention",
+                                     "lightning_attention"}
+    assert paths[("lightning_attention", "one_row_per_request")] == \
+        "slot_order"
+    assert paths[("lightning_attention", "PrefillBatchConfig")] == "chunked"
+    sparse = {b: p for (k, b), p in paths.items()
+              if k == "sparse_block_attention"}
+    if use_pallas:
+        assert sparse == {"PrefillBatchConfig": "masked_dense_tile",
+                          "BatchConfig": "sparse_decode_attention",
+                          "one_row_per_request": "sparse_decode_attention"}
+    else:
+        assert set(sparse.values()) == {"xla"}
+
+
+# readings here: 0.0003 ulps at most, 0.0000 nats (four decimals)
+LIMITS = {"logit_rms_ulps": 0.01, "logit_max_ulps": 0.05,
+          "logprob_rms": 5e-5, "logprob_max": 5e-4, "tail_logprob_rms": 5e-5,
+          "token_gap_ulps": 0.05}
+
+
+def test_a_reused_slot_starts_from_zero_state_and_an_empty_index():
+    """A slot that served a long request (a full index, a grown state) then
+    serves a short one, fed in chunks and decoded past ``dense_len``: it
+    reads what it would alone."""
+    im = deployment()
+    seq_lens = [0] * SLOTS
+    feed_flat(im, 2, tokens(170, salt=21), [CAP], seq_lens)
+    short = tokens(75, salt=22)
+    want, _ = reference_logprobs(short)
+    seq_lens[2] = 0
+    got = feed_flat(im, 2, short, [10], seq_lens)
+    np.testing.assert_allclose(got, want, atol=TOL, rtol=0)
+
+
+def _newest_blocks(self, q, idx, pos):
+    last = (pos // self.block_size)[:, None]
+    b = jnp.arange(idx.shape[2] * self.kernel_stride // self.block_size)
+    keep = (b <= last) & (b > last - self.max_blocks)
+    return jnp.broadcast_to(keep[:, None], (q.shape[0], self.num_kv_heads,
+                                            b.shape[0]))
+
+
+@pytest.mark.parametrize("broken", ["newest_blocks_instead_of_the_selection",
+                                    "index_not_appended_in_the_decode_scan",
+                                    "decay_dropped",
+                                    "scale_depth_from_the_cut_depth"])
+def test_a_break_is_seen(broken, monkeypatch):
+    """Each way of getting the new state wrong moves the logits by far more
+    than the tolerance the other tests hold: what they pass, a broken
+    program would not."""
+    hf = HF
+    if broken.startswith("newest"):
+        monkeypatch.setattr(SparseBlockAttention, "select", _newest_blocks)
+    elif broken.startswith("index"):
+        append = SparseBlockAttention._append_index
+        monkeypatch.setattr(
+            SparseBlockAttention, "_append_index",
+            lambda self, kidx, kc, rows, pos: kidx if rows.shape[0] == SLOTS
+            else append(self, kidx, kc, rows, pos))
+    elif broken.startswith("decay"):
+        monkeypatch.setattr(hybrid_ops, "lightning_slopes",
+                            lambda h: jnp.zeros((h,), jnp.float32))
+    else:
+        hf = {**HF, "mup_denominator": None}
+    im = seeded(build(hf=hf))
+    prompt = tokens(40, salt=50)
+    seq_lens = [0] * SLOTS
+    feed_flat(im, 0, prompt[:-1], [CAP], seq_lens)
+    _, toks = flat_step(im, [(0, prompt[-1:], 39)], seq_lens)
+    made = decode_scan(im, 0, int(toks[0]), 40, 64)
+    full = prompt + [int(toks[0])] + made
+    want, _ = reference_logprobs(full)
+    seq_lens[0] = 104
+    (got,), _ = flat_step(im, [(0, [full[104]], 104)], seq_lens)
+    assert np.abs(got[0] - want[104]).max() > 20 * TOL, broken
+
+
+def test_bytes_per_slot_and_per_token_against_the_hand_formula():
+    """A sparse layer: K and V of 2 heads x 16 at every position and an
+    index entry of 2 x 16 every 2 positions; a lightning layer: 4 heads of
+    16 x 16 float32 whatever ``max_seq_len``."""
+    def bytes_at(seq):
+        im = build(seq=seq)
+        im.allocate_kv_cache()
+        return im.kv.bytes_per_slot(), im.kv.bytes_per_token()
+
+    short, tok_short = bytes_at(256)
+    long, tok_long = bytes_at(2048)
+    spread = (SLOTS + 1) / SLOTS       # the scratch row, over the real slots
+    entry, layers = 2 * 16 * 4, 2
+    assert short["kv_full"] == layers * 256 * 2 * entry * spread
+    assert long["kv_full"] == layers * 2048 * 2 * entry * spread
+    assert short["kv_index"] == layers * 128 * entry * spread
+    assert long["kv_index"] == layers * 1024 * entry * spread
+    assert short["linear_state"] == long["linear_state"] == \
+        2 * 4 * 16 * 16 * 4 * spread
+    assert short["recurrent"] == short["kv_window"] == \
+        short["kv_compact"] == 0
+    # a position's price: its K and V entry and half an index entry a layer
+    assert tok_short == tok_long == layers * (2 * entry + entry / 2) * spread
+
+
+@pytest.mark.parametrize("kw,needs", [
+    (dict(kv_page_size=32), "index of compressed keys"),
+    (dict(kv_dtype="int8"), "compressed keys choose"),
+    (dict(max_spec_tokens=7), "appended index entry"),
+    (dict(tp=2), "selection per K/V head"),
+    (dict(pp=2), "stage boundaries"),
+])
+def test_combinations_not_written_yet_raise_at_compile(kw, needs):
+    kw = dict(kw)
+    tp, pp = kw.pop("tp", 1), kw.pop("pp", 1)
+    axes = {"pp": pp, "tp": tp} if pp > 1 else {"tp": tp}
+    mesh = make_mesh(axes, jax.devices()[:tp * pp])
+    ff = FFModel(FFConfig(), mesh=mesh)
+    build_model(ff, ServeModelConfig.from_hf_config(HF), CAP)
+    with pytest.raises(ValueError, match=needs):
+        if pp > 1:
+            from flexflow_tpu.serve.pp import PipelinedInferenceManager
+
+            PipelinedInferenceManager(ff, max_requests=SLOTS,
+                                      max_tokens_per_batch=CAP,
+                                      max_seq_len=SEQ)
+        else:
+            InferenceManager(ff, max_requests=SLOTS, max_tokens_per_batch=CAP,
+                             max_seq_len=SEQ, **kw).allocate_kv_cache()
+
+
+def test_spans_counters_and_the_ledger_name_the_new_state():
+    """Through ``RequestManager.generate``: the dispatch spans carry the
+    blocks the rows will read and the compressed keys they choose by, the
+    counters add up what every position read and wrote, the memory ledger
+    prices both new kinds, and the paths taken are counted."""
+    from flexflow_tpu.obs import Telemetry
+    from flexflow_tpu.serve import GenerationConfig, RequestManager
+
+    im = deployment()
+    tel = Telemetry()
+    rm = RequestManager(im, GenerationConfig(stop_on_eos=False),
+                        telemetry=tel)
+    try:
+        im._paths_counted = 0
+        outs = rm.generate([tokens(50, salt=31), tokens(9, salt=32)], 40)
+        assert [len(o) for o in outs] == [40, 40]
+        im.publish_memory(tel)
+        measured = tel.memory.report()["plans"][im.plan_key]
+        per_slot = im.kv.bytes_per_slot()
+        assert measured["slot_kv_index_bytes"]["measured"] == \
+            per_slot["kv_index"] > 0
+        assert measured["slot_linear_state_bytes"]["measured"] == \
+            per_slot["linear_state"] > 0
+        counters = tel.metrics.snapshot()
+        assert counters["attention_path.sparse_block_attention.xla"] >= 1
+        assert counters["attention_path.lightning_attention.slot_order"] >= 1
+        assert counters["attention_path.lightning_attention.chunked"] >= 1
+        assert counters["linear.state_resets"] == 2 * 2
+        op = _sparse_op()
+        written = [range(0, 50 + 39), range(0, 9 + 39)]
+        assert counters["sparse.blocks_attended"] == 2 * sum(
+            op.attended_blocks(p) for r in written for p in r)
+        assert counters["sparse.dense_rows"] == 2 * (48 + 48)
+        assert counters["sparse.index_entries_written"] == 2 * (
+            op.index_len(88) + op.index_len(47))
+        launches = [e["args"] for e in tel.trace.trace_events()
+                    if e["name"].endswith("_dispatch")
+                    and "attended_blocks_sum" in e.get("args", {})]
+        scans = [a for a in launches if a.get("kind") == "decode_scan"]
+        assert scans and all(
+            0 < a["attended_blocks_sum"] <= 6 * a["rows"]
+            and a["index_len_sum"] < a["ctx_sum"] // 2 for a in scans)
+    finally:
+        im.telemetry = type(im).telemetry
+
+
+CATALOG_FILE = "/opt/skills/guides/model-configs/architectures.jsonl"
+
+
+def test_the_published_config_builds_the_published_model():
+    """``from_hf_config`` on the published keys: 8 sparse layers of 253.8 M
+    parameters and 24 lightning layers of 285.2 M where ``mixer_types`` says,
+    RoPE on the lightning layers alone, the muP scalings with the PUBLISHED
+    depth; the benchmark's configuration file holds the catalog row's keys
+    unchanged but the two it lists as reduced, and its slice of
+    ``mixer_types`` is the published list's entries 9..16."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "minicpm-sala-d8.json")) as f:
+        conf = json.load(f)
+    reduced = conf["benchmark"]["reduced"]
+    assert set(reduced) == {"num_hidden_layers", "mixer_types"}
+    kinds = (["minicpm4"] + ["lightning-attn"] * 8 + ["minicpm4"]
+             + ["lightning-attn"] * 6 + ["minicpm4"] * 2
+             + ["lightning-attn"] * 4 + ["minicpm4"]
+             + ["lightning-attn"] * 6 + ["minicpm4"] * 3)
+    assert len(kinds) == 32 and kinds.count("minicpm4") == 8
+    assert conf["mixer_types"] == kinds[9:17] and conf["num_hidden_layers"] == 8
+    if os.path.exists(CATALOG_FILE):
+        with open(CATALOG_FILE) as f:
+            row = next(r for r in map(json.loads, f)
+                       if r["name"] == "MiniCPM-SALA")
+        assert row["config"]["mixer_types"] == kinds
+        assert {k: conf[k] for k in row["config"] if k not in reduced} == \
+            {k: v for k, v in row["config"].items() if k not in reduced}
+    published = {k: v for k, v in conf.items() if k != "benchmark"}
+    cfg = ServeModelConfig.from_hf_config(
+        {**published, "num_hidden_layers": 32, "mixer_types": kinds})
+    assert builder.residual_scale(cfg) == 1.4 / math.sqrt(32)
+    assert builder.residual_scale(ServeModelConfig.from_hf_config(
+        published)) == 1.4 / math.sqrt(32)
+    ff = FFModel(FFConfig(), mesh=make_mesh({"tp": 1}, jax.devices()[:1]))
+    build_model(ff, cfg, 16)
+    size = lambda i: sum(math.prod(p.spec.shape) for n in ff.graph.nodes
+                         for p in n.op.params() if f".layers.{i}." in n.name)
+    mlp = 3 * 4096 * 16384 + 2 * 4096
+    assert size(9) == 3 * 4096 ** 2 + 2 * 4096 * 256 + mlp
+    assert size(10) == 5 * 4096 ** 2 + mlp + 2 * 128 + 4096
+    ops = [n.op for n in ff.graph.nodes
+           if isinstance(n.op, (SparseBlockAttention, LightningAttention))]
+    assert [isinstance(o, SparseBlockAttention) for o in ops] == \
+        [k == "minicpm4" for k in kinds]
+    sparse = ops[0]
+    assert (sparse.num_q_heads, sparse.num_kv_heads, sparse.head_dim,
+            sparse.max_blocks, sparse.output_gate) == (32, 2, 128, 128, True)
+    assert ops[1].use_rope and ops[1].qk_norm and ops[1].output_norm
+    by_name = {n.name: n.op for n in ff.graph.nodes}
+    assert by_name["model.embed_tokens.scale"].scalar == 12
+    assert by_name["model.norm.scale"].scalar == 256 / 4096
+    with pytest.raises(ValueError, match="mixer_types"):
+        build_model(FFModel(FFConfig(), mesh=make_mesh(
+            {"tp": 1}, jax.devices()[:1])), ServeModelConfig.from_hf_config(
+                {**published, "mixer_types": kinds[:3]}), 16)

@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from flexflow_tpu.ops.pallas.attention import (
     decode_attention,
     prefill_attention,
+    sparse_decode_attention,
     tree_attention,
     tree_attention_batched,
 )
@@ -141,6 +142,23 @@ _CASES = [
     ids=[f"{c[0]}-kv{c[1]}gq{c[2]}d{c[3]}s{c[4]}-{c[5]}" for c in _CASES])
 def test_kernel_compiles_for_v5e(one_chip, kernel, kv, gq, d, s, variant):
     compiled = _lower(kernel, one_chip, kv, gq, d, s, variant).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [48, 512], ids=["scan48", "flat512"])
+def test_sparse_decode_kernel_compiles_for_v5e(one_chip, rows):
+    """``sparse_decode_attention`` at MiniCPM-SALA's published geometry (32
+    query heads on 2 K/V heads of 128, a cache of 32 768 positions, lists of
+    128 blocks of 64): the decode scan's 48 rows in one call, a flat step's
+    512 in calls of 64 (the block lists ride in scalar memory)."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    k = sds((49, 2, 32768, 128), jnp.bfloat16)
+    f = functools.partial(sparse_decode_attention, scale=128 ** -0.5,
+                          block=64)
+    compiled = jax.jit(f).lower(
+        sds((rows, 32, 128), jnp.bfloat16), k, k, sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), sds((rows, 2, 128), jnp.int32),
+        sds((rows, 2), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
