@@ -383,55 +383,137 @@ def _sparse_decode_kernel(
     blocks_ref,     # scalar prefetch: i32[T * KV * NB] attended blocks, sorted
     counts_ref,     # scalar prefetch: i32[T * KV] how many of them are real
     q_ref,          # [1, 1, gq, D]
-    *refs,          # U key blocks, U value blocks [1, 1, block, D] each,
-                    # o_ref [1, 1, gq, D], m/l/acc scratch
+    k_hbm,          # [(R+1) * KV * S / block, block, D], left in HBM: the
+    v_hbm,          # kernel copies what the lists name
+    o_ref,          # [1, 1, gq, D]
+    k_ring,         # VMEM [depth * chunk, block, D]
+    v_ring,         # VMEM [depth * chunk, block, D]
+    sems,           # DMA semaphores [2, depth]: K and V of each ring slot
+    cur,            # SMEM i32[4]: the fetch side's list and chunk, chunks
+                    # fetched, chunks consumed (over the whole call)
+    m_ref, l_ref, acc_ref,
+    *,
     block: int,
+    chunk: int,
     unroll: int,
+    depth: int,
     list_len: int,
     num_kv: int,
+    row_blocks: int,
     scale: float,
 ):
-    k_refs, v_refs = refs[:unroll], refs[unroll:2 * unroll]
-    o_ref, m_ref, l_ref, acc_ref = refs[2 * unroll:]
-    t, g, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    t, g = pl.program_id(0), pl.program_id(1)
+    here = t * num_kv + g            # this grid step's list
+    n_lists = pl.num_programs(0) * num_kv
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def geometry(li, c):
+        """Chunk ``c`` of list ``li``: chunks are cut from the list's END
+        (the forced window is its last ``chunk`` entries), so the short one
+        holds the list's first entries.  ``(lo, n, run)``: the entries
+        ``[lo, lo + n)`` of the list, and whether their blocks are ``chunk``
+        consecutive ones."""
+        hi = counts_ref[li] - c * chunk
+        lo = jnp.maximum(hi - chunk, 0)
+        first = blocks_ref[li * list_len + lo]
+        last = blocks_ref[li * list_len + jnp.maximum(hi - 1, 0)]
+        run = (hi - lo == chunk) & (last - first == chunk - 1)
+        return lo, hi - lo, run
 
+    def copies(li, chunk_of, slot, go):
+        """Start (``go``) or await the copies of one chunk (its
+        ``geometry``) into a ring slot: ONE copy of K and one of V for a
+        run, one per block otherwise.  A DMA semaphore counts bytes, so a
+        whole chunk is awaited ONCE however many copies brought it."""
+        lo, n, run = chunk_of
+        entry = li * list_len + lo
+        # the cache as blocks: this row and head's begin here
+        origin = (rows_ref[li // num_kv] * num_kv + li % num_kv) * row_blocks
+        to = slot * chunk
+
+        def both(e, length):
+            at = origin + blocks_ref[entry + e] if go else 0
+            for ring, hbm, sem in ((k_ring, k_hbm, sems.at[0, slot]),
+                                   (v_ring, v_hbm, sems.at[1, slot])):
+                cp = pltpu.make_async_copy(
+                    hbm.at[pl.ds(at, length)],
+                    ring.at[pl.ds(to + e, length)], sem)
+                cp.start() if go else cp.wait()
+
+        full = n == chunk
+        pl.when(run if go else full)(lambda: both(0, chunk))
+        if go:
+            @pl.when(full & jnp.logical_not(run))
+            def _whole():            # straight-line: nothing to branch on
+                for e in range(chunk):
+                    both(e, 1)
+
+        @pl.when(jnp.logical_not(full))
+        def _short():
+            def one(e, carry):
+                both(e, 1)
+                return carry
+
+            jax.lax.fori_loop(0, n, one, 0)
+
+    def fetch_next():
+        """Start the next chunk the call will need — of this list, or of the
+        first list after it that has one — into the slot freed longest ago."""
+        def spent(s):
+            li, c = s
+            return (li < n_lists) & (
+                c * chunk >= counts_ref[jnp.minimum(li, n_lists - 1)])
+
+        li, c = jax.lax.while_loop(spent, lambda s: (s[0] + 1, 0),
+                                   (cur[0], cur[1]))
+
+        @pl.when(li < n_lists)
+        def _start():
+            copies(li, geometry(li, c), cur[2] % depth, go=True)
+            cur[2] = cur[2] + 1
+
+        cur[0] = li
+        cur[1] = c + 1
+
+    @pl.when(here == 0)
+    def _open():
+        # a slot's unfilled part is multiplied by zero weights: it must not
+        # hold a NaN's bit pattern
+        k_ring[...] = jnp.zeros_like(k_ring)
+        v_ring[...] = jnp.zeros_like(v_ring)
+        for i in range(4):
+            cur[i] = 0
+        for _ in range(depth - 1):
+            fetch_next()
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
     pos = pos_ref[t]
-    count = counts_ref[t * num_kv + g]
-    first = j * unroll            # this step's first entry of the list
+    q = q_ref[0, 0]                                          # [gq, D]
+    d = q.shape[-1]
 
-    @pl.when(first < count)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                  # [gq, D]
-        gq = q.shape[0]
-        width = unroll * block
-        k = jnp.concatenate([r[0, 0] for r in k_refs], axis=0)
+    def attend(at, blocks, seen):
+        """``blocks`` ring blocks from ``at`` on under the running softmax;
+        ``seen [1, blocks * block]`` masks their keys, None: the row sees
+        them all."""
+        k = k_ring[pl.ds(at, blocks)].reshape(blocks * block, d)
+        v = v_ring[pl.ds(at, blocks)].reshape(blocks * block, d)
+        # operands of one type as they are (bf16 products are exact in the
+        # float32 they accumulate in), float32 otherwise
+        qk = (q, k) if q.dtype == k.dtype else (q.astype(jnp.float32),
+                                                k.astype(jnp.float32))
         sc = jax.lax.dot_general(
-            q, k.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale      # [gq, U * block]
-        # the causal mask comes from each block's OWN position: entry u of
-        # this step is block ``blocks[first + u]`` of the cache
-        lane = jax.lax.broadcasted_iota(jnp.int32, (gq, width), 1)
-        key_pos = lane % block
-        real = lane < 0
-        for u in range(unroll):
-            here = lane // block == u
-            b = blocks_ref[(t * num_kv + g) * list_len + first + u]
-            key_pos = jnp.where(here, key_pos + b * block, key_pos)
-            real = real | (here & (first + u < count))
-        seen = real & (key_pos <= pos)
-        sc = jnp.where(seen, sc, NEG_INF)
+            *qk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # [gq, blocks * block]
+        if seen is not None:
+            sc = jnp.where(seen, sc, NEG_INF)
         m_prev = m_ref[:, 0:1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(sc - m_new), 0.0)
+        p = jnp.exp(sc - m_new)
+        if seen is not None:
+            p = jnp.where(seen, p, 0.0)
         l_new = alpha * l_ref[:, 0:1] + jnp.sum(p, -1, keepdims=True)
-        v = jnp.concatenate([r[0, 0] for r in v_refs], axis=0)
         pv = jax.lax.dot_general(
             p, v.astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)              # [gq, D]
@@ -439,17 +521,57 @@ def _sparse_decode_kernel(
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
 
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    def one_chunk(c, carry):
+        fetch_next()                 # the queue stays ``depth`` chunks deep
+        slot = cur[3] % depth
+        lo, n, run = geometry(here, c)
+        copies(here, (lo, n, run), slot, go=False)
+        entry = here * list_len + lo
+        at = slot * chunk
+        # the causal mask comes from each block's OWN position.  The list is
+        # sorted: a whole chunk that ends before the row's own block needs
+        # none; a run's positions are one ramp; any other chunk goes
+        # ``unroll`` blocks at a time, each lane told how many of its
+        # block's keys the row sees (0 for an entry past the chunk's end)
+        clear = (n == chunk) & (blocks_ref[entry + chunk - 1] < pos // block)
+        pl.when(clear)(lambda: attend(at, chunk, None))
+
+        @pl.when(run & jnp.logical_not(clear))
+        def _ramp():
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, chunk * block), 1)
+            attend(at, chunk, blocks_ref[entry] * block + lane <= pos)
+
+        def some(s):
+            width = unroll * block
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+            sees = jnp.zeros((1, width), jnp.int32)
+            for u in range(unroll):
+                e = s * unroll + u
+                b = blocks_ref[here * list_len
+                               + jnp.minimum(lo + e, list_len - 1)]
+                keys = jnp.where(e < n,
+                                 jnp.clip(pos + 1 - b * block, 0, block), 0)
+                sees = jnp.where(lane // block == u, keys, sees)
+            attend(at + s * unroll, unroll, lane % block < sees)
+
+        for s in range(chunk // unroll):
+            pl.when(jnp.logical_not(clear | run) & (s * unroll < n))(
+                functools.partial(some, s))
+        cur[3] = cur[3] + 1
+        return carry
+
+    count = counts_ref[here]
+    jax.lax.fori_loop(0, (count + chunk - 1) // chunk, one_chunk, 0)
+    denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
+    o_ref[0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
 SPARSE_ROWS = 64    # rows per call: the block lists ride in scalar memory
+_SPARSE_DEPTH = 3   # ring slots: chunks in flight while one is computed
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "block", "unroll",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "block", "tail_run",
+                                             "unroll", "interpret"))
 def sparse_decode_attention(
     q: jax.Array,        # [T, QH, D]
     k_cache: jax.Array,  # [R+1, KV, S, D] (this step's K/V already written)
@@ -457,25 +579,43 @@ def sparse_decode_attention(
     rows: jax.Array,     # i32[T] cache row per token
     positions: jax.Array,  # i32[T]
     blocks: jax.Array,   # i32[T, KV, NB]: the blocks each row and KV head
-                         # attends, sorted; entries past ``counts`` repeat
-                         # the last real one
+                         # attends, sorted and distinct up to ``counts``
     counts: jax.Array,   # i32[T, KV]
     scale: float,
     block: int = 64,
+    tail_run: int = 32,
     unroll: int = 16,
     interpret: bool = False,
 ) -> jax.Array:
     """Decode attention over a LIST of cache blocks per row and KV head (a
     learned sparse selection: ``serve/hybrid_ops.SparseBlockAttention``),
-    where ``decode_attention`` reads a run.  Grid ``(rows, KV heads, list /
-    unroll)``: one KV head a grid column, ``unroll`` blocks of ``block``
-    positions a step (16: 1.45 ms a call of 48 rows x 97 blocks on the v5e,
-    where 8 read 1.73, 4 2.15 and 2 2.98) — each a K and a V operand of its own, fetched through
-    the scalar-prefetched list, so that a step keeps ``2 x unroll`` copies
-    in flight (a 64-position block of one head is 16 KB: one a step would
-    be all overhead).  The causal mask is taken from each block's own
-    position; a list's tail repeats its last block, whose copy Pallas then
-    skips, and a step wholly past the list computes nothing."""
+    where ``decode_attention`` reads a run.
+
+    The caches stay in HBM and the kernel issues its own copies.  Grid
+    ``(rows, KV heads)``; a grid step walks its list in CHUNKS of
+    ``tail_run`` entries cut from the list's end, each into one slot of a
+    VMEM ring (K and V, ``_SPARSE_DEPTH`` slots of ``tail_run`` blocks).  A
+    chunk whose blocks are consecutive — the caller's forced window of the
+    ``tail_run`` newest blocks by construction, and every whole chunk of a
+    dense row — is ONE copy of K and one of V; any other chunk is a copy per
+    block (a 64-position block of one head is 16 KB).  The lists ride in
+    scalar memory, so the fetch side runs ``_SPARSE_DEPTH - 1`` chunks ahead
+    of the arithmetic ACROSS lists: the next row-and-head's first chunks are
+    on their way while this one's last is computed, and nothing is fetched
+    or computed for a list's padding.  A whole chunk is one matrix product
+    wide under one running softmax (float32 scores, statistics and
+    accumulator); the causal mask is taken from each block's own position —
+    none for a chunk that ends before the row's own block, a ramp for a
+    run, per block (``unroll`` blocks at a time) for the rest.
+
+    On the v5e a call of 48 rows x 2 heads x 97 blocks (65 single blocks
+    and the window's run of 32; 305 MB) takes 0.48 ms, 0.43 with the
+    arithmetic stubbed out — what the same bytes take at the 722 GB/s a
+    dense list's four runs read (128 blocks: 0.56 ms); the ring's depth (2,
+    3, 4) and ``unroll`` (4 .. 32) move it by under 3 %.  (An operand per
+    block through the BlockSpec pipeline, 16 of K and 16 of V a grid step,
+    took 1.44 ms, 1.14 of them with neither a copy nor arithmetic to do.)
+    """
     t, qh, d = q.shape
     _, num_kv, s_len, _ = k_cache.shape
     gq = qh // num_kv
@@ -488,39 +628,40 @@ def sparse_decode_attention(
         out = jax.lax.map(
             lambda a: sparse_decode_attention(
                 a[0], k_cache, v_cache, a[1], a[2], a[3], a[4], scale=scale,
-                block=block, unroll=unroll, interpret=interpret),
+                block=block, tail_run=tail_run, unroll=unroll,
+                interpret=interpret),
             (cut(q), cut(rows), cut(positions), cut(blocks), cut(counts)))
         return out.reshape(t, qh, d)
-    unroll = min(unroll, blocks.shape[-1])
-    pad = -blocks.shape[-1] % unroll
-    if pad:
-        blocks = jnp.concatenate(
-            [blocks, jnp.repeat(blocks[..., -1:], pad, axis=-1)], axis=-1)
     list_len = blocks.shape[-1]
+    chunk = max(1, min(tail_run, list_len, s_len // block))
+    unroll = math.gcd(unroll, chunk)
+    slot_bytes = 2 * chunk * block * d * jnp.dtype(k_cache.dtype).itemsize
+    if _SPARSE_DEPTH * slot_bytes > _VMEM_BUDGET:
+        raise ValueError(f"a ring of {_SPARSE_DEPTH} chunks of {chunk} "
+                         f"blocks does not fit {_VMEM_BUDGET} bytes")
     qr = q.reshape(t, num_kv, gq, d)
-
-    def kv_map(u):
-        def index(i, g, j, rows, pos, blocks, counts):
-            return (rows[i], g,
-                    blocks[(i * num_kv + g) * list_len + j * unroll + u], 0)
-        return pl.BlockSpec((1, 1, block, d), index, memory_space=pltpu.VMEM)
-
-    q_spec = pl.BlockSpec((1, 1, gq, d), lambda i, g, j, *_: (i, g, 0, 0),
+    q_spec = pl.BlockSpec((1, 1, gq, d), lambda i, g, *_: (i, g, 0, 0),
                           memory_space=pltpu.VMEM)
+    ring = pltpu.VMEM((_SPARSE_DEPTH * chunk, block, d), k_cache.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(t, num_kv, list_len // unroll),
-        in_specs=[q_spec] + [kv_map(u) for u in range(unroll)] * 2,
+        grid=(t, num_kv),
+        in_specs=[q_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=q_spec,
         scratch_shapes=[
+            ring, ring,
+            pltpu.SemaphoreType.DMA((2, _SPARSE_DEPTH)),
+            pltpu.SMEM((4,), jnp.int32),
             pltpu.VMEM((gq, 128), jnp.float32),
             pltpu.VMEM((gq, 128), jnp.float32),
             pltpu.VMEM((gq, d), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _sparse_decode_kernel, block=block, unroll=unroll, list_len=list_len,
-        num_kv=num_kv, scale=float(scale))
+        _sparse_decode_kernel, block=block, chunk=chunk, unroll=unroll,
+        depth=_SPARSE_DEPTH, list_len=list_len, num_kv=num_kv,
+        row_blocks=s_len // block, scale=float(scale))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -528,8 +669,8 @@ def sparse_decode_attention(
         interpret=interpret,
     )(rows.astype(jnp.int32), positions.astype(jnp.int32),
       blocks.astype(jnp.int32).reshape(-1),
-      counts.astype(jnp.int32).reshape(-1),
-      qr, *([k_cache] * unroll), *([v_cache] * unroll))
+      counts.astype(jnp.int32).reshape(-1), qr,
+      k_cache.reshape(-1, block, d), v_cache.reshape(-1, block, d))
     return out.reshape(t, qh, d)
 
 

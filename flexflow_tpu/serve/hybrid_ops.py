@@ -927,8 +927,9 @@ class SparseBlockAttention(_SlotStateOp):
     the exact keys ``j <= t`` of the attended blocks.
 
     Paths: the decode scan and flat steps on the chip turn the mask into a
-    sorted block list and run ``sparse_decode_attention`` (the blocks
-    gathered by scalar prefetch, the causal mask from each block's own
+    sorted block list and run ``sparse_decode_attention`` (the kernel
+    copies the listed blocks itself, the forced window's ``tail_run``
+    consecutive blocks as one run; the causal mask from each block's own
     position); a prompt chunk and the CPU oracle compute masked-dense — all
     blocks up to the furthest row's, in a loop over key spans, the
     unselected masked per row and group (the same mathematics).
@@ -972,8 +973,20 @@ class SparseBlockAttention(_SlotStateOp):
     def max_blocks(self) -> int:
         """The most blocks a row attends: the forced and chosen ones, or
         every block below ``dense_len``."""
-        return max(self.init_blocks + self.window // self.block_size
-                   + self.topk, -(-self.dense_len // self.block_size))
+        return max(self.chosen_blocks,
+                   -(-self.dense_len // self.block_size))
+
+    @property
+    def tail_run(self) -> int:
+        """The forced window in blocks: the newest entries of a row's sorted
+        list, consecutive by construction — the chunk the kernel walks a
+        list in, from its end."""
+        return self.window // self.block_size
+
+    @property
+    def chosen_blocks(self) -> int:
+        """Blocks a row that selects attends: the forced and the chosen."""
+        return self.init_blocks + self.tail_run + self.topk
 
     def index_len(self, position):
         """Compressed keys a row at ``position`` can see (its own included
@@ -988,14 +1001,12 @@ class SparseBlockAttention(_SlotStateOp):
         have = position // self.block_size + 1
         if position < self.dense_len:
             return have
-        return min(have, self.init_blocks + self.window // self.block_size
-                   + self.topk)
+        return min(have, self.chosen_blocks)
 
     def attended_blocks_between(self, lo: int, hi: int) -> int:
         """``sum(attended_blocks(p) for p in range(lo, hi))`` in closed
         form (a launch's counters, on the host, every launch)."""
-        size = self.block_size
-        chosen = self.init_blocks + self.window // size + self.topk
+        size, chosen = self.block_size, self.chosen_blocks
         # ``p // size + 1`` blocks up to ``dense_len`` and, past it, until
         # there are more than the forced and chosen ones
         edge = max(chosen * size, self.dense_len)
@@ -1008,6 +1019,33 @@ class SparseBlockAttention(_SlotStateOp):
         a, b = max(lo, self.dense_len), max(hi, self.dense_len)
         return (grow(lo, hi, self.dense_len) + grow(a, b, edge)
                 + chosen * (max(b, edge) - max(a, edge)))
+
+    def run_blocks(self, position: int) -> int:
+        """Of ``attended_blocks(position)``, those the kernel fetches as
+        whole runs (one copy of ``tail_run`` consecutive blocks): the forced
+        window of a row that selects; every whole chunk, counted from the
+        list's end, of a row that attends all its blocks."""
+        have = position // self.block_size + 1
+        if self.attended_blocks(position) < have:
+            return self.tail_run
+        return have // self.tail_run * self.tail_run
+
+    def run_blocks_between(self, lo: int, hi: int) -> int:
+        """``sum(run_blocks(p) for p in range(lo, hi))`` in closed form."""
+        size, run = self.block_size, self.tail_run
+        # every block is attended below it
+        edge = max(self.chosen_blocks * size, self.dense_len)
+
+        def whole(m):   # sum over j <= m of j // run * run
+            a = m // run
+            return run * (run * a * (a - 1) // 2 + a * (m - a * run + 1))
+
+        def below(n):   # sum over p < n of (p // size + 1) // run * run
+            m = n // size
+            return size * whole(m) + (n - m * size) * ((m + 1) // run * run)
+
+        return (below(min(hi, edge)) - below(min(lo, edge))
+                + run * (max(hi, edge) - max(lo, edge)))
 
     # ---- shapes / params ------------------------------------------------
     @property
@@ -1182,7 +1220,7 @@ class SparseBlockAttention(_SlotStateOp):
     def block_list(self, mask):
         """A block mask ``[T, KV, blocks]`` as sorted lists ``[T, KV,
         max_blocks]`` and their lengths ``[T, KV]``; entries past the length
-        repeat the last attended block (no new copy in the kernel)."""
+        repeat the last attended block (the kernel reads none of them)."""
         n = jnp.sum(mask, axis=-1).astype(jnp.int32)
         order = jnp.argsort(~mask, axis=-1, stable=True)
         order = order[..., :self.max_blocks].astype(jnp.int32)
@@ -1249,6 +1287,7 @@ class SparseBlockAttention(_SlotStateOp):
                 out = sparse_decode_attention(
                     q, kc, vc, seg.rows, pos, blocks, count,
                     scale=self.scaling_factor, block=self.block_size,
+                    tail_run=self.tail_run,
                     interpret=bool(ctx.extras.get("pallas_interpret")))
                 out, path = out.reshape(t, -1), "sparse_decode_attention"
             else:

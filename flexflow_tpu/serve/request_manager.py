@@ -355,9 +355,12 @@ class RequestManager:
         """For a graph with sparse-attention layers (``hybrid_ops.
         SparseBlockAttention``): ``attended_blocks_sum``, the cache blocks
         the rows read at launch (per sparse layer and K/V head — every block
-        below ``dense_len``, the forced and chosen ones after it), and
-        ``index_len_sum``, the compressed keys they choose by.  Counted too:
-        ``sparse.blocks_attended`` and ``sparse.dense_rows`` over every
+        below ``dense_len``, the forced and chosen ones after it),
+        ``window_run_blocks_sum``, those of them the kernel fetches as whole
+        runs (one copy of ``window / block`` consecutive blocks: the forced
+        window of a row that selects), and ``index_len_sum``, the compressed
+        keys they choose by.  Counted too: ``sparse.blocks_attended``,
+        ``sparse.window_run_blocks`` and ``sparse.dense_rows`` over every
         position the launch writes, ``sparse.index_entries_written``, and
         ``linear.state_resets`` (requests whose linear-attention state
         starts from zero).  Nothing for a graph with neither."""
@@ -377,6 +380,8 @@ class RequestManager:
             count = tel.metrics.counter
             count("sparse.blocks_attended").inc(layers * sum(
                 op.attended_blocks_between(lo, hi) for lo, hi in writes))
+            count("sparse.window_run_blocks").inc(layers * sum(
+                op.run_blocks_between(lo, hi) for lo, hi in writes))
             count("sparse.dense_rows").inc(layers * sum(
                 min(hi, dense) - min(lo, dense) for lo, hi in writes))
             entries = sum(op.index_len(hi - 1) - op.index_len(lo - 1)
@@ -385,6 +390,8 @@ class RequestManager:
                 count("sparse.index_entries_written").inc(entries * layers)
         return {"attended_blocks_sum": sum(op.attended_blocks(lo)
                                            for lo, _ in writes),
+                "window_run_blocks_sum": sum(op.run_blocks(lo)
+                                             for lo, _ in writes),
                 "index_len_sum": sum(op.index_len(lo) for lo, _ in writes)}
 
     def _compact_counts(self, writes) -> Dict[str, int]:

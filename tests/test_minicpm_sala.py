@@ -287,6 +287,87 @@ def test_selected_sets_equal_the_references(salt):
     assert (np.diff(np.asarray(blocks), axis=-1) >= 0).all()
 
 
+def _kernel_case(case, rng):
+    """Rows ``(position, chosen blocks per K/V head | "dense" | None)`` for
+    one branch of ``sparse_decode_attention``'s copy engine, at block 8, a
+    forced window of 4 blocks (the kernel's chunk), lists of 16, 256
+    positions: a row past ``dense_len`` attends block 0, its 4 newest blocks
+    and the chosen ones."""
+    if case == "dead_row":              # count 0 between two live rows
+        return [(200, [[3, 9], [5]]), (0, None), (77, "dense")]
+    if case == "shorter_than_a_chunk":  # 1, 2 and 3 blocks: no run, no wait
+        return [(5, "dense"), (12, "dense"), (23, "dense")]
+    if case == "dense_128_blocks":      # every chunk whole and a run
+        return [(127, "dense"), (120, "dense")]
+    if case == "chosen_touch_the_run":  # chosen blocks next to the window's
+        # run and to each other: consecutive ids ACROSS a chunk's edge, and
+        # a scattered chunk that happens to be consecutive
+        return [(255, [[27, 26, 25, 24, 23], [1, 2, 3, 27]]),
+                (170, [[16, 15, 14, 13, 12, 11, 10, 9, 8, 7, 6], [16]])]
+    if case == "newest_block_holds_one":
+        return [(248, [[5, 6, 20], [9]]), (64, "dense"), (8, "dense")]
+    n = {"rows_64": 64, "rows_512": 512}[case]
+    out = []
+    for _ in range(n):
+        kind = rng.integers(4)
+        if kind == 0:
+            out.append((0, None))
+        elif kind == 1:
+            out.append((int(rng.integers(0, 128)), "dense"))
+        else:
+            p = int(rng.integers(128, 256))
+            free = np.arange(1, p // 8 - 3)
+            out.append((p, [rng.choice(free, size=min(len(free),
+                                                      int(rng.integers(0, 12))),
+                                       replace=False).tolist()
+                            for _ in range(2)]))
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    "dead_row", "shorter_than_a_chunk", "dense_128_blocks",
+    "chosen_touch_the_run", "newest_block_holds_one", "rows_64", "rows_512"])
+def test_the_kernel_reads_what_the_masked_dense_form_reads(case):
+    """``sparse_decode_attention`` (interpret mode: its own copies into the
+    ring, semaphores, the fetch cursor that runs ahead across lists) against
+    ``_attend_masked`` on the same block masks, through ``block_list``."""
+    from flexflow_tpu.ops.pallas.attention import sparse_decode_attention
+
+    op = SparseBlockAttention(64, 4, 2, 16, kernel_size=4, kernel_stride=2,
+                              block_size=8, topk=11, window=32,
+                              init_blocks=1, dense_len=128)
+    assert op.max_blocks == 16
+    rng = np.random.default_rng([SEED, sum(map(ord, case))])
+    spec = _kernel_case(case, rng)
+    t, slots, nb = len(spec), 5, 32
+    mask = np.zeros((t, 2, nb), bool)
+    pos = np.zeros(t, np.int32)
+    for i, (p, chosen) in enumerate(spec):
+        pos[i] = p
+        if chosen == "dense":
+            mask[i, :, :p // 8 + 1] = True
+        elif chosen is not None:
+            for g in range(2):
+                mask[i, g, [0] + list(chosen[g])] = True
+                mask[i, g, p // 8 - 3:p // 8 + 1] = True
+    rows = rng.integers(0, slots, size=t).astype(np.int32)
+    q = jnp.asarray(rng.normal(size=(t, 4, 16)), jnp.float32)
+    kc, vc = (jnp.asarray(rng.normal(size=(slots + 1, 2, 256, 16)),
+                          jnp.float32) for _ in range(2))
+    mask, pos, rows = jnp.asarray(mask), jnp.asarray(pos), jnp.asarray(rows)
+    blocks, count = op.block_list(mask)
+    assert blocks.shape == (t, 2, 16) and int(count.max()) <= 16
+    got = sparse_decode_attention(
+        q, kc, vc, rows, pos, blocks, count, scale=op.scaling_factor,
+        block=8, tail_run=4, unroll=2, interpret=True)
+    want = op._attend_masked(q[:, None], kc, vc, rows, pos[:, None],
+                             mask[:, None])[:, 0]
+    assert float(jnp.abs(want).max()) > 0.1
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
+    dead = np.asarray(count.sum(-1) == 0)
+    assert (np.asarray(got)[dead] == 0).all()
+
+
 def test_chunked_lightning_form_equals_the_recurrence():
     """``LightningAttention._chunked`` on a flat batch that holds a fresh
     segment, a segment that continues a stored state, a one-row segment and
@@ -518,6 +599,14 @@ def test_spans_counters_and_the_ledger_name_the_new_state():
         written = [range(0, 50 + 39), range(0, 9 + 39)]
         assert counters["sparse.blocks_attended"] == 2 * sum(
             op.attended_blocks(p) for r in written for p in r)
+        # the forced window (2 blocks) of a row that selects, the whole
+        # pairs of a row that attends every block: the kernel's run copies
+        assert [op.run_blocks(p) for p in (0, 7, 8, 23, 47, 48, 200)] == \
+            [0, 0, 2, 2, 6, 2, 2]
+        assert counters["sparse.window_run_blocks"] == 2 * sum(
+            op.run_blocks(p) for r in written for p in r)
+        assert 0 < counters["sparse.window_run_blocks"] < \
+            counters["sparse.blocks_attended"]
         assert counters["sparse.dense_rows"] == 2 * (48 + 48)
         assert counters["sparse.index_entries_written"] == 2 * (
             op.index_len(88) + op.index_len(47))
@@ -527,6 +616,7 @@ def test_spans_counters_and_the_ledger_name_the_new_state():
         scans = [a for a in launches if a.get("kind") == "decode_scan"]
         assert scans and all(
             0 < a["attended_blocks_sum"] <= 6 * a["rows"]
+            and 0 < a["window_run_blocks_sum"] <= a["attended_blocks_sum"]
             and a["index_len_sum"] < a["ctx_sum"] // 2 for a in scans)
     finally:
         im.telemetry = type(im).telemetry

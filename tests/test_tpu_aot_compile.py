@@ -154,7 +154,7 @@ def test_sparse_decode_kernel_compiles_for_v5e(one_chip, rows):
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     k = sds((49, 2, 32768, 128), jnp.bfloat16)
     f = functools.partial(sparse_decode_attention, scale=128 ** -0.5,
-                          block=64)
+                          block=64, tail_run=2048 // 64)
     compiled = jax.jit(f).lower(
         sds((rows, 32, 128), jnp.bfloat16), k, k, sds((rows,), jnp.int32),
         sds((rows,), jnp.int32), sds((rows, 2, 128), jnp.int32),
