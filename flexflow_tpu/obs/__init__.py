@@ -5,7 +5,9 @@ PipelinedInferenceManager / serve_with_arrivals) is instrumented behind one
 :class:`Telemetry` handle — a trace recorder (Chrome/Perfetto export), a
 metrics registry, and a predicted-vs-measured calibration ledger.  Host-side
 only by construction: telemetry never enters a jitted program, so serve
-outputs are bit-identical with it on or off.  See README "Observability".
+outputs are bit-identical with it on or off.  Beside the handle, and on
+without one: the scheduler's :class:`TickJournal` (one bounded record per
+tick; the slow-tick report).  See README "Observability".
 """
 
 from .calibration import (
@@ -20,6 +22,7 @@ from .drift import (
     drift_score,
     psi,
 )
+from .journal import TickJournal
 from .memory import (
     KV_OCCUPANCY_HIST,
     MEMORY_GAUGES,
@@ -73,6 +76,7 @@ __all__ = [
     "NULL_TELEMETRY",
     "telemetry_or_null",
     "TraceRecorder",
+    "TickJournal",
     "MetricsRegistry",
     "Counter",
     "Gauge",

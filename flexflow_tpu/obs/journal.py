@@ -1,0 +1,365 @@
+"""The scheduler's tick journal: one fixed-shape record per tick of the
+serving loops, in a bounded ring, always on.
+
+A profiler session (obs/trace.py ``Span``) shows a few seconds of a run;
+the telemetry ring exists only when a ``Telemetry`` handle is attached.
+The journal is what is there in every run: between two step boundaries it
+keeps the tick's wall time split by span (self time: a span's duration
+less what its child spans cover), what the tick launched, the tokens it
+committed and three process counters — and when a serving loop returns it
+reports the ticks that took over three times their class's median, with
+what the host was doing in them.
+
+It is the third consumer of :class:`~.trace.Span` (``jr=``, beside
+``rec`` and ``prof``): fed by the entries and exits of the spans that are
+there, with no ``with`` and no clock read of its own at any call site.
+The tick span's ``pc_ns`` argument IS the journal's stamp of that span's
+entry (one clock read, shared), so a record and its tick span on a
+profiler's host plane join on that number.
+
+Host-side only: integers the scheduler already holds, two clock reads a
+span, nothing waits for the device and nothing enters a jitted program.
+Measured cost: PERF.md section 6 (PR 46).
+
+Records abut: a record ends where the next begins (the entry of
+``loop_arrivals`` in ``serve_with_arrivals``, :meth:`TickJournal.begin` in
+``serve_incr_decoding``), and the last ends at :meth:`TickJournal.end`, so
+the records of one loop tile it with no hole.  Consecutive idle polls of
+the arrival loop fold into one record (``polls`` counts them).
+"""
+
+from __future__ import annotations
+
+import collections
+import itertools
+import logging
+import resource
+import time
+from typing import Callable, Dict, List, Optional, Sequence
+
+import numpy as np
+
+# the tick spans (they carry ``pc_ns``): a record's ``kind`` is the index
+# of its tick span's name here; 0 = no tick ran (an idle poll, or the
+# loop's last look), the last = a tick span of another manager
+KINDS = ("idle", "prefill_stretch", "decode_stretch", "serve_step", "other")
+# the spans whose SELF time a record keeps, each as ``<name>_ns``: the
+# closed vocabulary of PERF.md section 3's table.  Time under the tick
+# span itself, or under a span that is not here (pp's stages, the
+# speculative phases), is ``unattributed_ns``
+SPLIT = ("host_admit", "host_prepare", "kv_prepare", "sample_for",
+         "batch_sync", "join", "step_dispatch", "decode_scan_dispatch",
+         "prefill_scan_dispatch", "join_dispatch", "readback", "commit",
+         "loop_arrivals", "loop_bookkeep", "loop_idle", "loop_clock")
+# what ``Span.set`` — or an argument a span below the tick is entered
+# with — adds to a record, by argument name
+_SET = {"scan_tokens": "scan_tokens", "join_tokens": "join_tokens",
+        "step_tokens": "step_tokens", "prefill_tokens": "prefill_tokens",
+        "state_reset": "admitted"}
+
+FIELDS = (
+    # extent; ``tick_ns`` = the tick span's ``pc_ns`` (0: no tick ran)
+    "seq", "t0_ns", "t1_ns", "tick_ns", "kind", "polls",
+    *(f"{n}_ns" for n in SPLIT), "unattributed_ns",
+    # launches, from the dispatch spans' arguments
+    "step_launches", "decode_scans", "prefill_scans", "joins",
+    "decode_steps", "row_steps", "width_steps", "chunks", "chunk_rows",
+    "chunk_tokens", "prompt_tokens", "joiners", "ctx_sum", "ctx_rows",
+    # tokens, as ``commit`` sets them; admission
+    "scan_tokens", "join_tokens", "step_tokens", "prefill_tokens",
+    "admitted", "pending", "live",
+    # the process, over the record
+    "cpu_ns", "nivcsw", "majflt",
+)
+_F = {name: i for i, name in enumerate(FIELDS)}
+_SPLIT_AT = {n: _F[f"{n}_ns"] for n in SPLIT}
+_SET_AT = {arg: _F[field] for arg, field in _SET.items()}
+_KIND_AT = {n: i for i, n in enumerate(KINDS)}
+# fields a folded idle record does not add up
+_SUMMED = [i for i, n in enumerate(FIELDS)
+           if n not in ("seq", "t0_ns", "t1_ns", "tick_ns", "kind", "pending",
+                        "live", "ctx_sum", "ctx_rows")]
+(_SEQ, _T0, _T1, _TICK, _KIND, _POLLS, _UNATT, _PENDING, _LIVE, _CPU, _NIV,
+ _MAJ) = (_F[n] for n in (
+     "seq", "t0_ns", "t1_ns", "tick_ns", "kind", "polls", "unattributed_ns",
+     "pending", "live", "cpu_ns", "nivcsw", "majflt"))
+_SPLIT_LO, _SPLIT_HI = _F[f"{SPLIT[0]}_ns"], _F[f"{SPLIT[-1]}_ns"] + 1
+
+# the slow-tick report: constants, not options
+SLOW_FACTOR = 3       # an outlier lasts more than this many class medians
+SLOW_CLASS_MIN = 8    # a class needs this many records to have a median
+SLOW_LINES = 4        # lines a loop's report may write
+_RUSAGE = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
+_LOG = logging.getLogger("flexflow_tpu.serve")
+
+
+def _launch_step(row, args, chunk_width):
+    row[_F["step_launches"]] += 1
+    row[_F["prompt_tokens"]] += args.get("prompt_tokens", 0)
+    _first_ctx(row, args)
+
+
+def _launch_decode_scan(row, args, chunk_width):
+    n = args.get("n_steps", 0)
+    row[_F["decode_scans"]] += 1
+    row[_F["decode_steps"]] += n
+    row[_F["row_steps"]] += n * args.get("rows", 0)
+    row[_F["width_steps"]] += n * args.get("width", 0)
+    _first_ctx(row, args)
+
+
+def _launch_prefill_scan(row, args, chunk_width):
+    n = args.get("n_steps", 0)
+    fed = args.get("prompt_tokens", 0)
+    row[_F["prefill_scans"]] += 1
+    row[_F["chunks"]] += n
+    row[_F["chunk_rows"]] += n * chunk_width
+    row[_F["chunk_tokens"]] += fed
+    row[_F["prompt_tokens"]] += fed
+    row[_F["joiners"]] += args.get("joiners", 0)
+
+
+def _launch_join(row, args, chunk_width):
+    row[_F["joins"]] += 1
+
+
+def _first_ctx(row, args):
+    """``ctx_sum`` / ``ctx_rows``: the KV lengths and the rows of the
+    tick's FIRST launch that decodes (their ratio is the depth the tick
+    ran at)."""
+    rows = args.get("rows", 0)
+    if rows and not row[_F["ctx_rows"]]:
+        row[_F["ctx_rows"]] = rows
+        row[_F["ctx_sum"]] = args.get("ctx_sum", 0)
+
+
+_LAUNCH = {"step_dispatch": _launch_step,
+           "decode_scan_dispatch": _launch_decode_scan,
+           "prefill_scan_dispatch": _launch_prefill_scan,
+           "join_dispatch": _launch_join}
+
+
+def extent_ns(rows: np.ndarray) -> np.ndarray:
+    return rows[:, _T1] - rows[:, _T0]
+
+
+def slow_excess_ns(rows: np.ndarray) -> np.ndarray:
+    """THE rule of the slow-tick report, per record of ``rows`` (an
+    :meth:`TickJournal.array`): how far its extent lies over the median of
+    its class — (``kind``, ``decode_steps``, ``chunks``) where that class
+    has ``SLOW_CLASS_MIN`` records or more, else all records of its
+    ``kind`` if those are as many (a stall may hit a tick of a rare shape)
+    — where it lies over ``SLOW_FACTOR`` medians; 0 everywhere else, and
+    for idle records."""
+    out = np.zeros(len(rows), np.int64)
+    ext = extent_ns(rows)
+    kind = rows[:, _KIND]
+    todo = kind != _KIND_AT["idle"]
+    for cols in ([_KIND, _F["decode_steps"], _F["chunks"]], [_KIND]):
+        if not todo.any():
+            break
+        _, inverse, counts = np.unique(rows[:, cols], axis=0,
+                                       return_inverse=True,
+                                       return_counts=True)
+        inverse = inverse.reshape(-1)
+        for c in np.flatnonzero(counts >= SLOW_CLASS_MIN):
+            members = np.flatnonzero(inverse == c)
+            judged = members[todo[members]]
+            if not len(judged):
+                continue
+            median = int(np.median(ext[members]))
+            slow = judged[ext[judged] > SLOW_FACTOR * median]
+            out[slow] = ext[slow] - median
+            todo[judged] = False
+    return out
+
+
+def as_dict(row: Sequence[int]) -> Dict:
+    """One record under its field names, ``kind`` as the span's name."""
+    rec = {name: int(v) for name, v in zip(FIELDS, row)}
+    rec["kind"] = KINDS[rec["kind"]]
+    return rec
+
+
+def slow_line(rec: Dict, loop_t0_ns: int) -> str:
+    """One slow tick (a record of :meth:`TickJournal.slowest`) in one line:
+    when, how long against its class, the split, the process counters, the
+    backlog and the launches."""
+    split = sorted(((rec[f"{n}_ns"], n) for n in SPLIT), reverse=True)
+    split_s = " ".join(f"{n} {v / 1e6:.1f}" for v, n in split if v)
+    launches = " ".join(
+        f"{n} {rec[n]}" for n in ("decode_scans", "decode_steps",
+                                  "prefill_scans", "chunks",
+                                  "step_launches", "joins") if rec[n])
+    return (
+        f"slow tick: {rec['kind']} at "
+        f"{(rec['t0_ns'] - loop_t0_ns) / 1e9:.3f}s into the loop took "
+        f"{(rec['t1_ns'] - rec['t0_ns']) / 1e6:.1f} ms "
+        f"(class median {rec['median_ns'] / 1e6:.1f} ms); split ms: {split_s} "
+        f"unattributed {rec['unattributed_ns'] / 1e6:.1f}; "
+        f"cpu {rec['cpu_ns'] / 1e6:.1f} ms nivcsw {rec['nivcsw']} "
+        f"majflt {rec['majflt']}; pending {rec['pending']} "
+        f"live {rec['live']}; launches: {launches or 'none'}")
+
+
+class TickJournal:
+    """See the module docstring.  One per ``RequestManager``, synced onto
+    its ``InferenceManager`` as the telemetry handle is, so the launch
+    spans reach it too.
+
+    ``capacity``: records the ring holds; older ones drop and ``dropped``
+    counts them.  4096 holds twenty runs of the benchmark's shortest-tick
+    cell (``opt-6.7b-d12.decode-heavy``: 189 records for warm-up,
+    rehearsal and the 51 s window, 165 of them the window's; my chip
+    runs, PR 46), as lists of 47 integers.
+    ``chunk_width``: rows of one prefill-scan chunk
+    (``im.max_tokens``; ``chunk_rows`` = chunks x this).
+    ``clock_ns``: the journal's clock, and the tick spans' ``pc_ns``
+    (injectable for hermetic tests).
+    """
+
+    def __init__(self, capacity: int = 4096, chunk_width: int = 0,
+                 clock_ns: Optional[Callable[[], int]] = None):
+        if capacity < 1:
+            raise ValueError(f"capacity must be >= 1, got {capacity}")
+        self.capacity = capacity
+        self.chunk_width = int(chunk_width)
+        self.clock_ns = clock_ns or time.perf_counter_ns
+        self.emitted = 0      # lifetime count, records the ring dropped too
+        self._ring: collections.deque = collections.deque(maxlen=capacity)
+        self._row: Optional[List[int]] = None   # the open record
+        self._stack: List[List] = []   # open spans: [name, entry, covered]
+        self._begun = False            # the open record has had begin()
+        self._loop_t0 = 0              # where this loop's first record began
+        self._loop_seq = 0             # ... and its ``seq``
+        self._proc = (0, 0, 0)         # cpu_ns, nivcsw, majflt at the open
+
+    # ---- the ring -------------------------------------------------------
+    @property
+    def dropped(self) -> int:
+        return self.emitted - len(self._ring)
+
+    def array(self, newest: Optional[int] = None) -> np.ndarray:
+        """The ring's records (the ``newest`` of them), oldest first: an
+        int64 array ``[records, len(FIELDS)]`` (columns by
+        ``FIELDS.index``)."""
+        ring = self._ring
+        skip = 0 if newest is None else max(len(ring) - newest, 0)
+        rows = list(itertools.islice(ring, skip, None))
+        return np.array(rows, np.int64).reshape(-1, len(FIELDS))
+
+    def records(self) -> List[Dict]:
+        return [as_dict(r) for r in self.array()]
+
+    def slowest(self, k: int = SLOW_LINES,
+                newest: Optional[int] = None) -> List[Dict]:
+        """The outliers among the ring's records (the ``newest`` of them)
+        by :func:`slow_excess_ns`, longest first, at most ``k``; each a
+        record with its class's ``median_ns``."""
+        rows = self.array(newest)
+        excess = slow_excess_ns(rows)
+        ext = extent_ns(rows)
+        order = sorted(np.flatnonzero(excess), key=lambda i: -ext[i])[:k]
+        return [dict(as_dict(rows[i]), median_ns=int(ext[i] - excess[i]))
+                for i in order]
+
+    # ---- the loop's boundaries -----------------------------------------
+    def begin(self, pending: int, live: int) -> None:
+        """A tick is about to run (the sites of ``profiler.tick_begin``):
+        the backlog and the slots held at its entry.  Opens a record where
+        the loop's own span (``loop_arrivals``) has not."""
+        if self._row is None or self._begun:
+            self._roll(self.clock_ns())
+        self._begun = True
+        self._row[_PENDING] = pending
+        self._row[_LIVE] = live
+
+    def end(self) -> None:
+        """The serving loop returned: close the open record and write the
+        loop's slow ticks, one WARNING line each."""
+        if self._row is None:
+            return
+        self._close(self.clock_ns(), time.thread_time_ns(),
+                    resource.getrusage(_RUSAGE))
+        self._row = None
+        self._stack.clear()
+        in_loop = self.emitted - self._loop_seq
+        if in_loop >= SLOW_CLASS_MIN:
+            for rec in self.slowest(newest=in_loop):
+                _LOG.warning(slow_line(rec, self._loop_t0))
+
+    def _roll(self, t: int) -> None:
+        """``t`` ends the open record and begins the next; the process
+        counters are read once for both."""
+        cpu, ru = time.thread_time_ns(), resource.getrusage(_RUSAGE)
+        if self._row is None:
+            self._loop_t0, self._loop_seq = t, self.emitted
+        else:
+            self._close(t, cpu, ru)
+        row = self._row = [0] * len(FIELDS)
+        row[_T0] = t
+        row[_POLLS] = 1
+        self._begun = False
+        self._proc = (cpu, ru.ru_nivcsw, ru.ru_majflt)
+
+    def _close(self, t: int, cpu: int, ru) -> None:
+        row = self._row
+        row[_T1] = t
+        row[_UNATT] = t - row[_T0] - sum(row[_SPLIT_LO:_SPLIT_HI])
+        cpu0, niv0, maj0 = self._proc
+        row[_CPU] = cpu - cpu0
+        row[_NIV] = ru.ru_nivcsw - niv0
+        row[_MAJ] = ru.ru_majflt - maj0
+        if row[_KIND] == 0 and self.emitted > self._loop_seq:
+            last = self._ring[-1]
+            if last[_KIND] == 0:
+                # an idle poll after an idle poll: one record
+                for i in _SUMMED:
+                    last[i] += row[i]
+                last[_T1] = t
+                return
+        row[_SEQ] = self.emitted
+        self._ring.append(row)
+        self.emitted += 1
+
+    # ---- Span's hooks ---------------------------------------------------
+    def _enter(self, name: str, args: Dict) -> bool:
+        """A span opens.  False when no record is open and this span opens
+        none: the span then skips its exit too."""
+        t = (args and args.get("pc_ns")) or self.clock_ns()
+        if name == "loop_arrivals":
+            self._roll(t)
+        row = self._row
+        if row is None:
+            return False
+        self._stack.append([name, t, 0])
+        if args:
+            launch = _LAUNCH.get(name)
+            if launch is not None:
+                launch(row, args, self.chunk_width)
+            elif "pc_ns" in args:
+                row[_TICK] = t
+                row[_KIND] = _KIND_AT.get(name, len(KINDS) - 1)
+            else:
+                self._set(args)
+        return True
+
+    def _exit(self) -> None:
+        if not self._stack:
+            return   # the record was closed under this span (a hand-off)
+        name, t_in, covered = self._stack.pop()
+        dur = self.clock_ns() - t_in
+        at = _SPLIT_AT.get(name)
+        if at is not None:
+            self._row[at] += dur - covered
+        if self._stack:
+            self._stack[-1][2] += dur
+
+    def _set(self, args: Dict) -> None:
+        row = self._row
+        if row is None:
+            return
+        for k, v in args.items():
+            at = _SET_AT.get(k)
+            if at is not None:
+                row[at] += v

@@ -405,24 +405,21 @@ class StepProfiler:
 
     # ---- tick boundaries ----------------------------------------------
     def tick_begin(self) -> None:
-        self._tick_mark = {"work": dict(self.work),
-                           "phase_s": dict(self.phase_s)}
+        # the work counters at the tick's entry.  (A tick's TIME split is
+        # the tick journal's record, obs/journal.py: always on, self time
+        # by span; ``phase_s`` here is the run's accumulation)
+        self._tick_mark = dict(self.work)
 
     def tick_end(self) -> None:
         self._poll()
         self.ticks += 1
-        mark = self._tick_mark or {"work": {}, "phase_s": {}}
+        mark = self._tick_mark or {}
         self._tick_mark = None
-        dwork = {k: self.work[k] - mark["work"].get(k, 0)
-                 for k in self.work if self.work[k] != mark["work"].get(k, 0)}
-        dphase = {k: round((self.phase_s[k]
-                            - mark["phase_s"].get(k, 0.0)) * 1e3, 6)
-                  for k in self.phase_s
-                  if self.phase_s[k] != mark["phase_s"].get(k, 0.0)}
+        dwork = {k: self.work[k] - mark.get(k, 0)
+                 for k in self.work if self.work[k] != mark.get(k, 0)}
         notes = self._tick_notes
         self._tick_notes = {}
-        self.last_tick = {"tick": self.ticks, "work": dwork,
-                          "phases_ms": dphase}
+        self.last_tick = {"tick": self.ticks, "work": dwork}
         if notes:
             self.last_tick["notes"] = notes
         tel = self.telemetry

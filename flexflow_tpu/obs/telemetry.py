@@ -286,10 +286,11 @@ class Telemetry:
         return self._clock()
 
     def span(self, name, cat="serve", track="serve", prof=None, phase=None,
-             **args):
+             jr=None, **args):
         """The one boundary primitive (:class:`~.trace.Span`): profiler
-        annotation + ring event + (``prof``) a StepProfiler phase."""
-        return self.trace.span(name, cat, track, prof, phase, **args)
+        annotation + ring event + (``prof``) a StepProfiler phase +
+        (``jr``) the tick journal's record."""
+        return self.trace.span(name, cat, track, prof, phase, jr, **args)
 
     def instant(self, name, cat="serve", track="serve", **args):
         return self.trace.instant(name, cat, track, **args)
@@ -805,10 +806,11 @@ class NullTelemetry:
         return 0.0
 
     def span(self, name, cat="serve", track="serve", prof=None, phase=None,
-             **args):
-        # no ring, but still the profiler annotation (and the phase): an
-        # attached jax.profiler session sees the scheduler with no handle
-        return Span(name, args, prof=prof, phase=phase)
+             jr=None, **args):
+        # no ring, but still the profiler annotation (the phase, the tick
+        # journal): an attached jax.profiler session sees the scheduler
+        # with no handle
+        return Span(name, args, prof=prof, phase=phase, jr=jr)
 
     def instant(self, *a, **k):
         return 0.0

@@ -13,7 +13,11 @@ Every span is ALSO a ``jax.profiler.TraceAnnotation`` (:class:`Span`): a
 profiler session attached to the process — with or without a recorder —
 sees the same names and arguments on its host plane, on the clock of the
 device's operations.  The ring stays, for what a profiler session is not:
-always on, bounded, exportable per request, testable on a virtual clock.
+bounded, exportable per request, testable on a virtual clock — but it is
+there only while a ``Telemetry`` handle is attached (the default
+``NULL_TELEMETRY`` has none).  What is ALWAYS on is the scheduler's tick
+journal (obs/journal.py): one bounded record per tick, fed by the same
+spans.
 
 Overhead contract of the ring:
 
@@ -52,24 +56,30 @@ class Span:
     ``start_server``, the benchmark's ``--trace 1``) the span lands on the
     profiler's host plane, on the same time base as the device's ops, with
     ``args`` as its stats.  No handle, flag or environment variable turns
-    this on.  Two optional consumers ride the same entry/exit:
+    this on.  Three optional consumers ride the same entry/exit:
 
     * ``rec`` — a :class:`TraceRecorder`: one complete ("X" phase) event in
       the ring, emitted at ``__exit__`` with the entry timestamp, so buffer
       order is completion order; Perfetto sorts by ``ts`` and infers
       nesting from containment on a track;
     * ``prof`` — an enabled ``StepProfiler``: the body's wall time (the
-      profiler's own injectable clock) is added to ``phase_s[phase]``.
+      profiler's own injectable clock) is added to ``phase_s[phase]``;
+    * ``jr`` — the scheduler's :class:`~.journal.TickJournal`, the one
+      consumer that is always there: the span's self time, its launch
+      arguments and what :meth:`set` adds go into the open tick's record.
+      A tick span's ``pc_ns`` argument is the journal's stamp of its
+      entry (one clock read, shared).
 
     :meth:`set` appends arguments known only inside the body (the tokens a
-    commit loop appended) to the annotation and to the ring event.
+    commit loop appended) to the annotation, the ring event and the
+    journal's record.
     """
 
     __slots__ = ("_ann", "_rec", "_name", "_cat", "_track", "_args", "_t0",
-                 "_prof", "_phase", "_p0")
+                 "_prof", "_phase", "_p0", "_jr")
 
     def __init__(self, name, args=None, rec=None, cat="serve",
-                 track="serve", prof=None, phase=None):
+                 track="serve", prof=None, phase=None, jr=None):
         self._name = name
         self._args = args or {}
         self._rec = rec
@@ -77,13 +87,19 @@ class Span:
         self._track = track
         self._prof = prof if prof is not None and prof.enabled else None
         self._phase = phase or name
+        self._jr = jr
 
     def set(self, **args):
         self._ann.set_metadata(**args)
         if self._rec is not None:
             self._args = {**self._args, **args}
+        if self._jr is not None:
+            self._jr._set(args)
 
     def __enter__(self):
+        if self._jr is not None and not self._jr._enter(self._name,
+                                                        self._args):
+            self._jr = None   # no record is open: nothing to exit
         if self._rec is not None:
             self._t0 = self._rec._clock()
         if self._prof is not None:
@@ -95,6 +111,8 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         self._ann.__exit__(exc_type, exc, tb)
         prof, rec = self._prof, self._rec
+        if self._jr is not None:
+            self._jr._exit()
         if prof is not None:
             prof._phase_done(self._phase, prof._clock() - self._p0)
         if rec is not None:
@@ -144,11 +162,12 @@ class TraceRecorder:
 
     # ------------------------------------------------------------------
     def span(self, name: str, cat: str = "serve", track: str = "serve",
-             prof=None, phase: Optional[str] = None, **args) -> Span:
+             prof=None, phase: Optional[str] = None, jr=None,
+             **args) -> Span:
         """``with rec.span("decode_stretch", steps=8): ...`` — a complete
         event covering the body's wall time on ``track`` (see
-        :class:`Span` for ``prof``/``phase``)."""
-        return Span(name, args, self, cat, track, prof, phase)
+        :class:`Span` for ``prof``/``phase``/``jr``)."""
+        return Span(name, args, self, cat, track, prof, phase, jr)
 
     def instant(self, name: str, cat: str = "serve", track: str = "serve",
                 **args) -> float:

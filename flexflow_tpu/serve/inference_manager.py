@@ -312,6 +312,11 @@ class InferenceManager:
     # deterministic flops/byte accounting lives in the RequestManager
     # (host bookkeeping).  Host-side only — never traced into a program.
     profiler = NULL_PROFILER
+    # the scheduler's tick journal (obs/journal.py), synced by the
+    # RequestManager like the two handles above: the launch spans' self
+    # time and arguments go into the open tick's record.  None without a
+    # manager (direct ``step`` calls)
+    journal = None
 
     def __init__(
         self,
@@ -726,7 +731,8 @@ class InferenceManager:
         # spans, and per-track totals assume non-overlapping spans per track
         with self.telemetry.span("step_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
-                                 phase="dispatch", kind="step", n_steps=1,
+                                 phase="dispatch", jr=self.journal,
+                                 kind="step", n_steps=1,
                                  **(counts or {})):
             result, self.state = with_stack_room(
                 self._step, self.params, self.state, bc, sample, None, None,
@@ -969,7 +975,8 @@ class InferenceManager:
             self.fault_injector.maybe_fail("decode_scan")
         with self.telemetry.span("decode_scan_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
-                                 phase="dispatch", kind="decode_scan",
+                                 phase="dispatch", jr=self.journal,
+                                 kind="decode_scan",
                                  n_steps=n_steps, width=width,
                                  **(counts or {})):
             tokens, live, ecode, self.state, bc = with_stack_room(
@@ -1002,7 +1009,8 @@ class InferenceManager:
         """
         with self.telemetry.span("join_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
-                                 phase="dispatch", kind="join", n_steps=1,
+                                 phase="dispatch", jr=self.journal,
+                                 kind="join", n_steps=1,
                                  **(counts or {})):
             return with_stack_room(
                 self._join, bc, tok_src, jnp.int32(src_idx), jnp.int32(dst),
@@ -1157,7 +1165,8 @@ class InferenceManager:
         n_chunks = int(bcs.base.tokens.shape[0])
         with self.telemetry.span("prefill_scan_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
-                                 phase="dispatch", kind="prefill_scan",
+                                 phase="dispatch", jr=self.journal,
+                                 kind="prefill_scan",
                                  n_steps=n_chunks, n_chunks=n_chunks,
                                  **(counts or {})):
             tokens, last, self.state = with_stack_room(

@@ -15,7 +15,6 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -229,6 +228,14 @@ class RequestManager:
         if self.profiler.enabled:
             self.profiler.install(im)
             self.profiler.bind(self.telemetry)
+        # the tick journal (obs/journal.py): one bounded record per tick
+        # of the serving loops, ALWAYS on — fed by the spans below and,
+        # synced onto the InferenceManager like the two handles above, by
+        # its launch spans.  Host-side only: integers the scheduler holds
+        from ..obs.journal import TickJournal
+
+        self.journal = TickJournal(chunk_width=im.max_tokens)
+        im.journal = self.journal
         # KV ownership (serve/kv_allocator.py): a fresh manager restarts
         # rids from 0, so any attribution a previous manager left on a
         # shared/cached im must not alias the new rid space; and the
@@ -323,12 +330,20 @@ class RequestManager:
 
     def _span(self, name: str, phase: bool = False, **args):
         """A scheduler span below the tick (obs/trace.py ``Span``): always
-        a profiler annotation, a ring event on the ``host`` track when
-        telemetry is on, and — ``phase`` — the StepProfiler phase of the
-        same name.  ``args`` are ints the scheduler already holds."""
+        a profiler annotation and self time in the tick journal's record,
+        a ring event on the ``host`` track when telemetry is on, and —
+        ``phase`` — the StepProfiler phase of the same name.  ``args`` are
+        ints the scheduler already holds."""
         return self.telemetry.span(
             name, cat="host", track="host",
-            prof=self.profiler if phase else None, **args)
+            prof=self.profiler if phase else None, jr=self.journal, **args)
+
+    def _tick_begin(self) -> None:
+        """A serve loop is about to run a tick: the profiler's mark, and
+        the backlog and the slots held as the journal's record has them."""
+        self.profiler.tick_begin()
+        self.journal.begin(len(self.pending),
+                           sum(rid is not None for rid in self.slots))
 
     def _launch_counts(self, spans, n_decode: int) -> Dict[str, int]:
         """Dispatch-span arguments of one flat step from its cache-write
@@ -2151,23 +2166,24 @@ class RequestManager:
         * otherwise a single flat step: a 1-step trailer, or a mixed step
           where the tiled feed does not apply (see
           :meth:`prepare_next_batch`)."""
-        tel = self.telemetry
-        # ``pc_ns``: this clock at the tick's entry — the one subtraction
-        # that lays perf_counter stamps (the serving records, the ring)
-        # over a profiler session's time base
+        tel, jr = self.telemetry, self.journal
+        # ``pc_ns``: this clock (``perf_counter_ns``) at the tick's entry —
+        # the one subtraction that lays perf_counter stamps (the serving
+        # records, the ring) over a profiler session's time base, and the
+        # journal's stamp of the tick: a record and its span join on it
         if self._prefill_stretch_possible():
-            with tel.span("prefill_stretch", cat="serve",
-                          pc_ns=time.perf_counter_ns()):
+            with tel.span("prefill_stretch", cat="serve", jr=jr,
+                          pc_ns=jr.clock_ns()):
                 self._prefill_stretch()
             return
         n = self._scan_steps_possible()
         if n > 1:
-            with tel.span("decode_stretch", cat="serve", steps=n,
-                          pc_ns=time.perf_counter_ns()):
+            with tel.span("decode_stretch", cat="serve", steps=n, jr=jr,
+                          pc_ns=jr.clock_ns()):
                 self._decode_stretch(n)
             return
-        with tel.span("serve_step", cat="serve",
-                      pc_ns=time.perf_counter_ns()):
+        with tel.span("serve_step", cat="serve", jr=jr,
+                      pc_ns=jr.clock_ns()):
             # prepare_next_batch attributes its own host_admit /
             # host_prepare phases
             bc, sample_points = self.prepare_next_batch()
@@ -2612,7 +2628,15 @@ class RequestManager:
         """
         import time as _time
 
-        clock = clock or _time.perf_counter
+        caller_clock = clock or _time.perf_counter
+
+        def clock():
+            # a caller's clock may be a hook that does work of its own
+            # (the benchmark's walks every live request): named, so that
+            # the journal and a trace do not hold it as unattributed
+            with self._span("loop_clock"):
+                return caller_clock()
+
         t0 = clock() if _t0 is None else _t0
         if record_trace is not None:
             # idempotent: a migration successor re-entering this loop
@@ -2621,7 +2645,8 @@ class RequestManager:
             record_trace.begin_run(self.trace_run_meta())
         pending = sorted(arrivals, key=lambda a: a[0])
         records: Dict[int, Dict] = {} if _records is None else _records
-        saved_clock = self._swap_clock(clock)  # rebases armed deadlines
+        # (the deadline checks read the caller's clock itself)
+        saved_clock = self._swap_clock(caller_clock)  # rebases armed ones
         tel = self.telemetry
 
         # rids whose record still awaits a stamp — scanned per tick instead
@@ -2677,8 +2702,9 @@ class RequestManager:
             # live migration completed at this boundary: the successor
             # carries every request (rids preserved) — it re-enters this
             # loop with the remaining arrivals on the original time base
+            self.journal.end()   # this manager's part of the loop
             return new_rm.serve_with_arrivals(
-                pending, clock=clock, record_trace=record_trace,
+                pending, clock=caller_clock, record_trace=record_trace,
                 _t0=t0, _records=records, _open=open_rids)
 
         def stamp_joined(rids):
@@ -2722,7 +2748,7 @@ class RequestManager:
                             _time.sleep(min(1e-3, max(0.0,
                                                       pending[0][0] - now)))
                     continue
-                self.profiler.tick_begin()
+                self._tick_begin()
                 self._tick()
                 self.profiler.tick_end()
                 with self._span("loop_bookkeep"):
@@ -2748,7 +2774,8 @@ class RequestManager:
             self._arrival_pump = None
             self._join_stamp = None
             self._swap_clock(saved_clock)
-        end = clock() - t0
+            self.journal.end()
+        end = caller_clock() - t0
         for rid, rec in records.items():
             req = self.requests[rid]
             rec["tokens"] = req.generated
@@ -2799,22 +2826,25 @@ class RequestManager:
         plan at any tick boundary — the loop hands off to the successor
         manager, which carries every request under its original rid.
         """
-        while True:
-            self._check_lifecycle()
-            if not self.has_work():
-                new_rm = self._maybe_migrate(idle=True)
+        try:
+            while True:
+                self._check_lifecycle()
+                if not self.has_work():
+                    new_rm = self._maybe_migrate(idle=True)
+                    break
+                self._tick_begin()
+                self._tick()
+                self.profiler.tick_end()
+                self._sync_kv()
+                self._maybe_check_health()
+                self._maybe_brownout()
+                new_rm = self._maybe_migrate()
                 if new_rm is not None:
-                    return new_rm.serve_incr_decoding()
-                break
-            self.profiler.tick_begin()
-            self._tick()
-            self.profiler.tick_end()
-            self._sync_kv()
-            self._maybe_check_health()
-            self._maybe_brownout()
-            new_rm = self._maybe_migrate()
-            if new_rm is not None:
-                return new_rm.serve_incr_decoding()
+                    break
+        finally:
+            self.journal.end()   # the last record; the slow-tick report
+        if new_rm is not None:
+            return new_rm.serve_incr_decoding()
         self._maybe_check_health(force=True)
         return {rid: r.generated for rid, r in self.requests.items()}
 

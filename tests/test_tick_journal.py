@@ -1,0 +1,307 @@
+"""The scheduler's tick journal (obs/journal.py, ISSUE 46): one record per
+tick of the serving loops, always on, fed by the spans that are there.
+
+Pinned at toy size on a virtual clock, kernels interpreted (so prompts ride
+the tiled feed and joiners splice into running stretches): the records of
+a loop tile it and split every extent into self time by span; their launch
+and token sums are the serving records' and the telemetry counters'; the
+ring drops the oldest; the tokens served are the parent commit's; a clock
+that jumps inside one ``readback`` is reported, in one line that names it.
+"""
+
+import logging
+
+import numpy as np
+import pytest
+
+from flexflow_tpu.obs import Telemetry
+from flexflow_tpu.obs import journal as J
+from flexflow_tpu.obs.journal import TickJournal
+from flexflow_tpu.obs.trace import Span
+from flexflow_tpu.serve import GenerationConfig, RequestManager
+
+from test_serve import TINY, make_im
+
+CAP, SLOTS, SEQ = 24, 4, 64        # tile 8: three tiles a chunk
+STEP_NS = 10_000                   # one read of the virtual clock
+_RNG = np.random.RandomState(46)
+# six requests on four slots: prompts shorter than a tile, of whole tiles
+# and of several chunks; answers of 3 to 14 tokens
+PROMPTS = [_RNG.randint(1, TINY.vocab_size, size=n).tolist()
+           for n in (5, 16, 3, 2 * CAP + 3, 7, CAP)]
+ANSWERS = [9, 14, 3, 6, 12, 5]
+# what the parent commit (72ec002: no journal) serves for them, the same
+# whenever they arrive (continuous batching reorders work, not results)
+PARENT_TOKENS = [
+    [19, 29, 64, 0, 37, 43, 22, 16, 7],
+    [65, 1, 31, 43, 49, 2, 35, 33, 10, 53, 53, 53, 53, 53],
+    [52, 30, 15],
+    [4, 63, 54, 53, 40, 58],
+    [43, 43, 64, 26, 37, 43, 64, 26, 37, 43, 64, 64],
+    [17, 24, 33, 44, 3],
+]
+# offsets of the open loop's arrivals on the virtual clock: two at once,
+# three while those decode, one after the deployment has run empty
+OPEN_AT = [0.0, 0.0, 0.0005, 0.0012, 0.002, 0.03]
+
+
+class VirtualClock:
+    """Every read advances time by ``STEP_NS``; ``jump`` adds nanoseconds
+    at given reads.  ``ns`` is the journal's clock, ``s`` the loop's."""
+
+    def __init__(self, jump=None):
+        self.t, self.reads, self.jump = 0, 0, dict(jump or {})
+
+    def ns(self):
+        self.reads += 1
+        self.t += STEP_NS + self.jump.get(self.reads, 0)
+        return self.t
+
+    def s(self):
+        return self.ns() / 1e9
+
+
+def manager(vc, telemetry=None, capacity=4096, journal=TickJournal):
+    im = make_im(max_tokens=CAP, max_requests=SLOTS, max_seq=SEQ,
+                 use_pallas=True)
+    im.reset()
+    rm = RequestManager(im, GenerationConfig(stop_on_eos=False),
+                        telemetry=telemetry)
+    # the virtual clock: the journal is always there, its clock is real
+    rm.journal = im.journal = journal(
+        capacity=capacity, chunk_width=im.max_tokens, clock_ns=vc.ns)
+    return rm
+
+
+def serve(rm, vc, mode):
+    """The six requests through one serving loop; ``(tokens per request,
+    virtual nanoseconds the call took)``."""
+    t = vc.t
+    if mode == "generate":
+        rids = [rm.register_new_request(p, n)
+                for p, n in zip(PROMPTS, ANSWERS)]
+        out = rm.serve_incr_decoding()
+        return [out[r] for r in rids], vc.t - t
+    at = [0.0] * len(PROMPTS) if mode == "closed" else OPEN_AT
+    recs = rm.serve_with_arrivals(
+        [(a, p, n) for a, p, n in zip(at, PROMPTS, ANSWERS)], clock=vc.s)
+    return [recs[r]["tokens"] for r in sorted(recs)], vc.t - t
+
+
+MODES = ["generate", "closed", "open"]
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_records_tile_the_loop_and_split_every_extent(mode):
+    vc = VirtualClock()
+    rm = manager(vc)
+    tokens, wall_ns = serve(rm, vc, mode)
+    assert tokens == PARENT_TOKENS, "the journal changed what was served"
+    recs = rm.journal.records()
+    assert rm.journal.dropped == 0 and len(recs) >= 4
+    # records abut: no hole between the first entry and the loop's return
+    for a, b in zip(recs, recs[1:]):
+        assert a["t1_ns"] == b["t0_ns"] and a["seq"] + 1 == b["seq"]
+    total = sum(r["t1_ns"] - r["t0_ns"] for r in recs)
+    assert total == recs[-1]["t1_ns"] - recs[0]["t0_ns"]
+    # ... and all of the call but a few reads on either side of the loop
+    assert 0 <= wall_ns - total <= 8 * STEP_NS
+    kinds = {r["kind"] for r in recs}
+    assert {"decode_stretch", "prefill_stretch"} <= kinds
+    assert ("idle" in kinds) == (mode == "open")
+    for r in recs:
+        extent = r["t1_ns"] - r["t0_ns"]
+        split = sum(r[f"{n}_ns"] for n in J.SPLIT)
+        # self times: nested spans are counted once, so the split never
+        # exceeds the extent and the rest is what no span covers
+        assert split + r["unattributed_ns"] == extent
+        assert 0 <= r["unattributed_ns"] < extent
+        assert (r["tick_ns"] > 0) == (r["kind"] != "idle")
+        assert r["t0_ns"] <= r["tick_ns"] < r["t1_ns"] or not r["tick_ns"]
+    if mode != "generate":
+        # the loop's own spans are in the split, the clock hook by name
+        assert sum(r["loop_clock_ns"] for r in recs) > 0
+        assert sum(r["loop_arrivals_ns"] for r in recs) > 0
+    idle = [r for r in recs if r["kind"] == "idle"]
+    # consecutive idle polls are ONE record
+    assert all(a["kind"] != "idle" or b["kind"] != "idle"
+               for a, b in zip(recs, recs[1:]))
+    assert all(r["loop_idle_ns"] > 0 and r["polls"] > 1 for r in idle[:-1])
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_sums_are_the_records_and_the_counters(mode):
+    vc, tel = VirtualClock(), Telemetry()
+    rm = manager(vc, telemetry=tel)
+    tokens, _ = serve(rm, vc, mode)
+    assert tokens == PARENT_TOKENS
+    rows = rm.journal.array()
+    total = {name: int(rows[:, i].sum()) for i, name in enumerate(J.FIELDS)}
+    snap = tel.metrics.snapshot()
+    # tokens, by the program that made them
+    assert (total["scan_tokens"] + total["join_tokens"]
+            + total["step_tokens"] + total["prefill_tokens"]
+            == sum(len(t) for t in tokens) == rm.tokens_decoded)
+    assert total["admitted"] == len(PROMPTS)
+    # the prompt feed: every token through the tiled scan, chunk by chunk
+    fed = sum(len(p) for p in PROMPTS)
+    assert total["prompt_tokens"] == total["chunk_tokens"] == fed
+    assert snap["prompt_feed.tiled_tokens"] == total["chunk_tokens"]
+    assert snap["prompt_feed.tiled_chunks"] == total["chunks"]
+    assert (snap["prompt_feed.tiled_padded_rows"]
+            == total["chunk_rows"] - total["chunk_tokens"])
+    assert total["chunk_rows"] == total["chunks"] * CAP
+    assert "prompt_feed.flat_tokens" not in snap
+    # joins and decode scans
+    assert snap.get("stretch_joins", 0) == total["join_tokens"]
+    assert total["joins"] <= total["join_tokens"] <= total["joiners"]
+    assert snap["decode_scan_steps"] == total["decode_steps"]
+    assert total["row_steps"] <= total["width_steps"]
+    assert total["joins"] > 0 and total["prefill_tokens"] > 0
+    # the launches are the ring's launch spans, the stamps its tick spans'
+    ring = [e for e in tel.trace.trace_events() if e["ph"] == "X"]
+    count = {}
+    for e in ring:
+        count[e["name"]] = count.get(e["name"], 0) + 1
+    assert count["decode_scan_dispatch"] == total["decode_scans"]
+    assert count["prefill_scan_dispatch"] == total["prefill_scans"]
+    assert count["join_dispatch"] == total["joins"]
+    assert count.get("step_dispatch", 0) == total["step_launches"]
+    stamps = sorted(e["args"]["pc_ns"] for e in ring
+                    if "pc_ns" in e.get("args", {}))
+    assert stamps == sorted(r for r in rows[:, J.FIELDS.index("tick_ns")]
+                            if r)
+    # a tick's first decoding launch gives the depth it ran at
+    first = [e["args"] for e in ring if e["name"] == "decode_scan_dispatch"]
+    assert first[0]["ctx_sum"] in rows[:, J.FIELDS.index("ctx_sum")]
+
+
+def test_nested_spans_give_self_time():
+    vc = VirtualClock()
+    jr = TickJournal(clock_ns=vc.ns, chunk_width=CAP)
+    jr.begin(pending=3, live=2)
+    with Span("decode_stretch", {"pc_ns": jr.clock_ns()}, jr=jr):
+        with Span("join", {"rid": 7}, jr=jr):
+            with Span("host_prepare", jr=jr):
+                vc.t += 1_000
+            with Span("prefill_scan_dispatch",
+                      {"n_steps": 2, "prompt_tokens": 30, "joiners": 1,
+                       "rows": 1}, jr=jr):
+                vc.t += 5_000
+        with Span("stage_dispatch", jr=jr):      # not in the vocabulary
+            with Span("readback", jr=jr):
+                vc.t += 2_000
+        with Span("commit", jr=jr) as sp:
+            sp.set(scan_tokens=4, join_tokens=1)
+    jr.end()
+    (r,) = jr.records()
+    s = STEP_NS
+    assert r["kind"] == "decode_stretch" and r["tick_ns"] == r["t0_ns"] + s
+    assert (r["pending"], r["live"]) == (3, 2)
+    assert r["host_prepare_ns"] == s + 1_000
+    assert r["prefill_scan_dispatch_ns"] == s + 5_000
+    # ``join`` keeps what its two children do not cover
+    assert r["join_ns"] == 5 * s + 6_000 - (2 * s + 6_000)
+    assert r["readback_ns"] == s + 2_000 and r["commit_ns"] == s
+    # the tick span's own time and the unknown span's go unattributed
+    assert r["unattributed_ns"] == r["t1_ns"] - r["t0_ns"] - sum(
+        r[f"{n}_ns"] for n in J.SPLIT)
+    assert r["unattributed_ns"] == 8 * s
+    assert (r["chunks"], r["chunk_rows"], r["chunk_tokens"], r["joiners"]) \
+        == (2, 2 * CAP, 30, 1)
+    assert (r["scan_tokens"], r["join_tokens"]) == (4, 1)
+    # outside a record a span is no event at all
+    with Span("host_prepare", jr=jr):
+        pass
+    assert jr.emitted == 1
+
+
+def test_ring_drops_the_oldest_and_counts_them():
+    vc = VirtualClock()
+    rm = manager(vc, capacity=2)
+    serve(rm, vc, "closed")
+    jr = rm.journal
+    recs = jr.records()
+    assert jr.emitted > 2 and len(recs) == 2
+    assert jr.dropped == jr.emitted - 2
+    assert [r["seq"] for r in recs] == [jr.emitted - 2, jr.emitted - 1]
+    with pytest.raises(ValueError):
+        TickJournal(capacity=0)
+
+
+class Probe(TickJournal):
+    """Notes the clock read at which each span was entered."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.entered = []
+
+    def _enter(self, name, args):
+        ok = super()._enter(name, args)
+        self.entered.append((name, self.clock_ns.__self__.reads))
+        return ok
+
+
+def paced(vc, journal=TickJournal):
+    """Two requests of 41 tokens in stretches of 4 steps: ten ticks of one
+    class, (``decode_stretch``, 4 steps, no chunk)."""
+    rm = manager(vc, journal=journal)
+    rm.scan_chunk = 4
+    out = rm.generate(PROMPTS[:2], 41)
+    assert [len(t) for t in out] == [41, 41]
+    return rm
+
+
+def test_a_stall_inside_one_readback_is_one_line_that_names_it(caplog):
+    caplog.set_level(logging.WARNING, logger="flexflow_tpu.serve")
+    probe = paced(VirtualClock(), journal=Probe).journal
+    assert not caplog.records, "a steady run reported a slow tick"
+    stretches = [r for r in probe.records() if r["kind"] == "decode_stretch"]
+    assert len(stretches) == 10 and {r["decode_steps"]
+                                     for r in stretches} == {4}
+    # the read after the sixth stretch's ``readback`` was entered is that
+    # span's exit: 3 s pass there
+    reads = [at for name, at in probe.entered if name == "readback"]
+    vc = VirtualClock(jump={reads[6] + 1: 3_000_000_000})
+    jr = paced(vc).journal
+    lines = [r.getMessage() for r in caplog.records]
+    assert len(lines) == 1, lines
+    assert lines[0].startswith("slow tick: decode_stretch at ")
+    assert "split ms: readback 3000.0 " in lines[0]
+    assert "launches: decode_scans 1 decode_steps 4" in lines[0]
+    (slow,) = jr.slowest()
+    assert slow["readback_ns"] == 3_000_000_000 + STEP_NS
+    assert slow["median_ns"] < 100 * STEP_NS
+    # the share the benchmark reads is the same rule's
+    rows = jr.array()
+    excess = J.slow_excess_ns(rows)
+    assert np.count_nonzero(excess) == 1
+    assert excess.sum() == (slow["t1_ns"] - slow["t0_ns"]
+                            - slow["median_ns"])
+
+
+@pytest.mark.parametrize("peers,caught", [(9, True), (5, False)])
+def test_a_tick_of_a_rare_shape_is_judged_by_its_kind(peers, caught):
+    """A stall may hit a tick whose class — (kind, decode steps, chunks) —
+    has too few records for a median: it is held to its kind's records
+    then, if THOSE are eight or more."""
+    vc = VirtualClock()
+    jr = TickJournal(clock_ns=vc.ns, chunk_width=CAP)
+    for chunks, wait in [(0, 300)] * peers + [(3, 3_300)]:
+        jr.begin(0, 2)
+        with Span("decode_stretch", {"pc_ns": jr.clock_ns()}, jr=jr):
+            if chunks:
+                with Span("prefill_scan_dispatch", {"n_steps": chunks},
+                          jr=jr):
+                    pass
+            with Span("decode_scan_dispatch", {"n_steps": 32, "rows": 2},
+                      jr=jr):
+                pass
+            with Span("readback", jr=jr):
+                vc.t += wait * 1_000_000
+    jr.end()
+    slow = jr.slowest()
+    assert len(slow) == (1 if caught else 0)
+    if caught:
+        assert slow[0]["chunks"] == 3 and slow[0]["readback_ns"] > 3e9
+        assert abs(slow[0]["median_ns"] - 300e6) < 1e6
