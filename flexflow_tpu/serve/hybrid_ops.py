@@ -924,7 +924,11 @@ class SparseBlockAttention(_SlotStateOp):
     newest blocks, and the ``topk`` highest-scoring of the rest — or every
     block while its position is below ``dense_len``.  The selection is a
     mask over blocks ``[T, KV, S / block]``; attention is ONE softmax over
-    the exact keys ``j <= t`` of the attended blocks.
+    the exact keys ``j <= t`` of the attended blocks.  The decode scan,
+    where every live row is a slot of its own, scores in SLOT ORDER against
+    the index where it lies (``_select_slots``); a flat step and a prompt
+    chunk gather each row's index first (``_select_rows``) — one ``select``,
+    counted as ``attention_path.block_select.slot_order`` / ``.gathered``.
 
     Paths: the decode scan and flat steps on the chip turn the mask into a
     sorted block list and run ``sparse_decode_attention`` (the kernel
@@ -1135,7 +1139,10 @@ class SparseBlockAttention(_SlotStateOp):
 
     def select(self, q, idx, pos):
         """The blocks each row attends, as a mask ``[T, KV, blocks]``."""
-        score = self.block_scores(q, idx, pos)
+        return self._attended(self.block_scores(q, idx, pos), pos)
+
+    def _attended(self, score, pos):
+        """``select``'s mask from the rows' block scores."""
         nb = score.shape[-1]
         b = jnp.arange(nb, dtype=jnp.int32)
         last = (pos // self.block_size)[:, None]
@@ -1145,10 +1152,10 @@ class SparseBlockAttention(_SlotStateOp):
         free = (have & ~forced)[:, None]
         k = min(self.topk, nb)
         top, ids = jax.lax.top_k(jnp.where(free, score, -2.0), k)
-        chosen = jnp.zeros(score.shape, bool)
-        t_i = jnp.arange(score.shape[0])[:, None, None]
-        g_i = jnp.arange(score.shape[1])[None, :, None]
-        chosen = chosen.at[t_i, g_i, ids].set(top > -2.0)
+        # a compare per block, not a scatter of ``k`` single elements a row
+        # and group (which XLA lowers through a sort of its own on a TPU)
+        ids = jnp.where(top > -2.0, ids, -1)
+        chosen = jnp.any(ids[..., None] == b, axis=-2)
         sparse = forced[:, None] | chosen
         return jnp.where((pos < self.dense_len)[:, None, None],
                          have[:, None], sparse)
@@ -1168,6 +1175,17 @@ class SparseBlockAttention(_SlotStateOp):
         cut = lambda a: a.reshape((t // ROWS, ROWS) + a.shape[1:])
         out = jax.lax.map(some, (cut(q), cut(rows), cut(pos)))
         return out.reshape((t,) + out.shape[2:])
+
+    def _select_slots(self, q, kidx, rows, pos):
+        """``select`` where every live row is a slot of its own (the decode
+        scan; pads sit on the scratch row): the queries go to their slots,
+        the scores are taken against the index AS IT LIES and only they come
+        back by row — ``kidx[rows]`` copied every slot's index (50 MB a layer
+        and step at the published sizes) to read it once."""
+        by_slot = lambda a: jnp.zeros((kidx.shape[0],) + a.shape[1:],
+                                      a.dtype).at[rows].set(a)
+        score = self.block_scores(by_slot(q), kidx, by_slot(pos))[rows]
+        return self._attended(score, pos)
 
     # ---- attention ------------------------------------------------------
     def _attend_masked(self, q, kc, vc, rows, pos, mask):
@@ -1221,12 +1239,17 @@ class SparseBlockAttention(_SlotStateOp):
         """A block mask ``[T, KV, blocks]`` as sorted lists ``[T, KV,
         max_blocks]`` and their lengths ``[T, KV]``; entries past the length
         repeat the last attended block (the kernel reads none of them)."""
-        n = jnp.sum(mask, axis=-1).astype(jnp.int32)
-        order = jnp.argsort(~mask, axis=-1, stable=True)
-        order = order[..., :self.max_blocks].astype(jnp.int32)
-        at = jnp.minimum(jnp.arange(order.shape[-1], dtype=jnp.int32),
+        cum = jnp.cumsum(mask.astype(jnp.int32), axis=-1)
+        n = cum[..., -1]
+        width = min(self.max_blocks, mask.shape[-1])
+        at = jnp.minimum(jnp.arange(width, dtype=jnp.int32),
                          jnp.maximum(n - 1, 0)[..., None])
-        return jnp.take_along_axis(order, at, axis=-1), n
+        # the ``j``-th attended block has exactly ``j`` attended blocks
+        # before it: its id is the count of blocks whose running count is
+        # ``<= j`` (a sort of the mask says the same, at a sort's price)
+        blocks = jnp.sum(cum[..., None, :] <= at[..., None], axis=-1,
+                         dtype=jnp.int32)
+        return jnp.where((n > 0)[..., None], blocks, 0), n
 
     def lower(self, ctx, inputs, params):
         bc, state = _require(ctx, self.type_name)
@@ -1270,8 +1293,10 @@ class SparseBlockAttention(_SlotStateOp):
             ctx.extras["state_out"] = {"k": kc, "v": vc, "kidx": kidx}
             # its own operator class in a device trace, apart from the node's
             node = ctx.extras.get("node_name", "select")
+            slots = bool(ctx.extras.get("one_row_per_request"))
             with jax.named_scope(f"BlockSelect.{node}"):
-                mask = self._select_rows(q, kidx, seg.rows, pos)
+                mask = (self._select_slots if slots else self._select_rows)(
+                    q, kidx, seg.rows, pos)
                 if pallas and not tiled:
                     blocks, count = self.block_list(mask)
                     count = jnp.where(seg.live[:, None], count, 0)
@@ -1296,10 +1321,11 @@ class SparseBlockAttention(_SlotStateOp):
                 out, path = out.reshape(t, -1), "xla"
             paths = ctx.extras.get("attention_paths")
             if paths is not None:
-                batch = ("one_row_per_request"
-                         if ctx.extras.get("one_row_per_request")
-                         else type(bc).__name__)
+                batch = "one_row_per_request" if slots \
+                    else type(bc).__name__
                 paths[(self.type_name, batch)] = path
+                paths[("block_select", batch)] = \
+                    "slot_order" if slots else "gathered"
         with jax.named_scope("o_proj"):
             out = out.astype(jnp.float32)
             if gate is not None:

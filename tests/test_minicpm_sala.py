@@ -287,6 +287,128 @@ def test_selected_sets_equal_the_references(salt):
     assert (np.diff(np.asarray(blocks), axis=-1) >= 0).all()
 
 
+def _select_by_scatter(op, q, idx, pos):
+    """``select`` as it stood before PR 47 — the chosen ids SCATTERED into
+    the mask — kept as the reference of the compare that replaced it."""
+    score = op.block_scores(q, idx, pos)
+    b = jnp.arange(score.shape[-1], dtype=jnp.int32)
+    last = (pos // op.block_size)[:, None]
+    have = b <= last
+    forced = have & ((b < op.init_blocks)
+                     | (b > last - op.window // op.block_size))
+    free = (have & ~forced)[:, None]
+    top, ids = jax.lax.top_k(jnp.where(free, score, -2.0),
+                             min(op.topk, score.shape[-1]))
+    t_i = jnp.arange(score.shape[0])[:, None, None]
+    g_i = jnp.arange(score.shape[1])[None, :, None]
+    chosen = jnp.zeros(score.shape, bool).at[t_i, g_i, ids].set(top > -2.0)
+    return jnp.where((pos < op.dense_len)[:, None, None], have[:, None],
+                     forced[:, None] | chosen)
+
+
+def _block_list_by_sort(op, mask):
+    """``block_list`` as it stood before PR 47: a stable sort of the mask."""
+    n = jnp.sum(mask, axis=-1).astype(jnp.int32)
+    order = jnp.argsort(~mask, axis=-1, stable=True)
+    order = order[..., :op.max_blocks].astype(jnp.int32)
+    at = jnp.minimum(jnp.arange(order.shape[-1], dtype=jnp.int32),
+                     jnp.maximum(n - 1, 0)[..., None])
+    return jnp.take_along_axis(order, at, axis=-1), n
+
+
+def _scan_rows(rng, slots=12, dead=(3, 8)):
+    """A decode scan's batch over ``slots`` slots (+ the scratch row): one
+    row a slot in SHUFFLED slot order, the ``dead`` rows on the scratch row
+    at position 0, live positions on both sides of ``dense_len``."""
+    rows = rng.permutation(slots).astype(np.int32)
+    pos = rng.integers(DENSE, SEQ, size=slots).astype(np.int32)
+    pos[::3] = rng.integers(0, DENSE, size=len(pos[::3]))
+    for d in dead:
+        rows[d], pos[d] = slots, 0
+    assert (pos[rows < slots] < DENSE).any() and (pos >= DENSE).any()
+    return jnp.asarray(rows), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("salt", [0, 1, 2])
+def test_slot_order_selection_equals_the_gathered_one(salt):
+    """The decode scan's selection — queries laid out by slot, scores taken
+    against the index where it lies, the scores gathered back — against
+    ``select`` on each row's gathered index, and against the scattered mask
+    ``select`` built before: the masks are EQUAL, the sorted lists too."""
+    op = _sparse_op()
+    rng = np.random.default_rng([SEED, 90 + salt])
+    rows, pos = _scan_rows(rng)
+    t = rows.shape[0]
+    q = jnp.asarray(rng.normal(size=(t, 4, 16)) * 2, jnp.float32)
+    kidx = jnp.asarray(rng.normal(size=(t + 1, 2, SEQ // 2, 16)) * 2,
+                       jnp.float32)
+    want = _select_by_scatter(op, q, kidx[rows], pos)
+    got = jax.jit(op._select_slots)(q, kidx, rows, pos)
+    assert got.shape == want.shape == (t, 2, SEQ // 8)
+    assert bool(jnp.all(got == want))
+    assert bool(jnp.all(op._select_rows(q, kidx, rows, pos) == want))
+    live = np.asarray(rows) < t
+    count = np.asarray(got.sum(-1))
+    assert (count[live & (np.asarray(pos) >= DENSE)] == 6).all()
+    assert (count[~live] == 1).all()        # a pad "attends" block 0 of 0
+    for a, b in zip(op.block_list(got), _block_list_by_sort(op, want)):
+        assert a.dtype == b.dtype and bool(jnp.all(a == b))
+
+
+@pytest.mark.parametrize("count", [0, 1, 6, 9, "mixed"])
+def test_the_list_built_from_ranks_equals_the_sorted_one(count):
+    """``block_list`` (entry ``j`` = the count of blocks whose running count
+    is ``<= j``) against the stable sort of the mask it replaced: rows that
+    attend nothing, one block, exactly ``max_blocks`` (6) and more."""
+    op = _sparse_op()
+    rng = np.random.default_rng([SEED, 95])
+    nb = SEQ // 8
+    counts = rng.integers(0, 12, size=(40, 2)) if count == "mixed" \
+        else np.full((40, 2), count)
+    mask = np.zeros((40, 2, nb), bool)
+    for i, j in np.ndindex(40, 2):
+        mask[i, j, rng.choice(nb, size=counts[i, j], replace=False)] = True
+    got, n = jax.jit(op.block_list)(jnp.asarray(mask))
+    want, want_n = _block_list_by_sort(op, jnp.asarray(mask))
+    assert got.shape == (40, 2, op.max_blocks) and got.dtype == want.dtype
+    assert (np.asarray(n) == counts).all() and bool(jnp.all(n == want_n))
+    assert bool(jnp.all(got == want))
+
+
+@pytest.mark.parametrize("path", ["gathered", "slot_order"])
+def test_tied_scores_keep_top_k_order(path):
+    """An index whose entries repeat with period 8 (two blocks): block ``b``
+    and ``b + 2`` score the same to the bit, and ``top_k`` keeps the LOWER
+    id of a tie — the chosen three are those a stable sort of the scores
+    puts first, on both paths."""
+    op = _sparse_op()
+    rng = np.random.default_rng([SEED, 97])
+    rows, pos = _scan_rows(rng)
+    pos = jnp.where(rows < 12, jnp.maximum(pos, 200), pos)   # 25 blocks seen
+    t = rows.shape[0]
+    q = jnp.asarray(rng.normal(size=(t, 4, 16)), jnp.float32)
+    base = rng.normal(size=(t + 1, 2, 8, 16))
+    kidx = jnp.asarray(np.tile(base, (1, 1, SEQ // 16, 1)), jnp.float32)
+    score = np.asarray(op.block_scores(q, kidx[rows], pos))
+    if path == "gathered":
+        got = op.select(q, kidx[rows], pos)
+    else:
+        got = op._select_slots(q, kidx, rows, pos)
+    assert bool(jnp.all(got == _select_by_scatter(op, q, kidx[rows], pos)))
+    got, ties = np.asarray(got), 0
+    for i in np.flatnonzero(np.asarray(rows) < t):
+        last = int(pos[i]) // 8
+        free = np.arange(1, last - 1)       # not block 0, not the newest 2
+        for g in range(2):
+            ties += int((score[i, g, 1:last - 3] == score[i, g, 3:last - 1])
+                        .sum())
+            first = free[np.argsort(-score[i, g, free], kind="stable")[:3]]
+            want = np.zeros(SEQ // 8, bool)
+            want[[0, last - 1, last, *first]] = True
+            assert (got[i, g] == want).all(), (i, g)
+    assert ties > 100
+
+
 def _kernel_case(case, rng):
     """Rows ``(position, chosen blocks per K/V head | "dense" | None)`` for
     one branch of ``sparse_decode_attention``'s copy engine, at block 8, a
@@ -439,7 +561,10 @@ def test_the_harness_drives_are_correct(use_pallas):
     assert reading[0] == 1.0 and reading[2] == 2 * 2 * 68
     paths = im.attention_paths
     assert {k for k, _ in paths} == {"sparse_block_attention",
-                                     "lightning_attention"}
+                                     "lightning_attention", "block_select"}
+    assert {b: p for (k, b), p in paths.items() if k == "block_select"} == {
+        "PrefillBatchConfig": "gathered", "BatchConfig": "gathered",
+        "one_row_per_request": "slot_order"}
     assert paths[("lightning_attention", "one_row_per_request")] == \
         "slot_order"
     assert paths[("lightning_attention", "PrefillBatchConfig")] == "chunked"
@@ -594,6 +719,14 @@ def test_spans_counters_and_the_ledger_name_the_new_state():
         assert counters["attention_path.sparse_block_attention.xla"] >= 1
         assert counters["attention_path.lightning_attention.slot_order"] >= 1
         assert counters["attention_path.lightning_attention.chunked"] >= 1
+        # the selection by the batch's form: rows and slots coincide in
+        # the decode scan's programs alone
+        assert im.attention_paths[
+            ("block_select", "one_row_per_request")] == "slot_order"
+        assert im.attention_paths[("block_select", "BatchConfig")] == \
+            "gathered"
+        assert counters["attention_path.block_select.slot_order"] == 1
+        assert counters["attention_path.block_select.gathered"] >= 1
         assert counters["linear.state_resets"] == 2 * 2
         op = _sparse_op()
         written = [range(0, 50 + 39), range(0, 9 + 39)]

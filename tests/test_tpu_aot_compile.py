@@ -162,6 +162,44 @@ def test_sparse_decode_kernel_compiles_for_v5e(one_chip, rows):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("path", ["slot_order", "gathered"])
+def test_block_selection_reads_the_index_where_it_lies(one_chip, path):
+    """MiniCPM-SALA's block selection at the decode scan's published shapes
+    (48 rows, an index of ``[49, 2, 2048, 128]`` bf16, 512 blocks, lists of
+    128) for the described chip.  In slot order the scores' ``convolution``
+    reads the index parameter itself: no ``gather`` and no fusion makes an
+    array of the index's size (50 MB a layer and step, PERF.md section 6,
+    PR 47).  The gathered form — a flat step's, the control — holds both, so
+    the reading can see them.  Either way ONE ``sort`` is left, ``top_k``'s:
+    the mask comes from compares and the sorted list from ranks."""
+    import re
+
+    from flexflow_tpu.serve.hybrid_ops import SparseBlockAttention
+
+    op = SparseBlockAttention(4096, 32, 2, 128, dtype=jnp.bfloat16)
+    select = op._select_slots if path == "slot_order" else op._select_rows
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    hlo = jax.jit(lambda *a: op.block_list(select(*a))).lower(
+        sds((48, 32, 128), jnp.bfloat16), sds((49, 2, 2048, 128), jnp.bfloat16),
+        sds((48,), jnp.int32), sds((48,), jnp.int32)).compile().as_text()
+    made, sorts = [], 0
+    for ln in hlo.splitlines():
+        result, _, rest = ln.partition(" = ")
+        op_at = re.search(r"\s(gather|fusion|sort)\(", rest)
+        if not op_at or "bitcast_fusion" in rest:
+            continue
+        sorts += op_at.group(1) == "sort"
+        # half the index and more: XLA gathers it in two halves
+        if re.search(r"bf16\[4[89],2,(1024|2048),128\]",
+                     rest[:op_at.start()]):
+            made.append(f"{op_at.group(1)} {result.strip()}")
+    assert sorts == 1, sorts
+    if path == "slot_order":
+        assert not made, made
+    else:
+        assert {m.split()[0] for m in made} == {"gather", "fusion"}, made
+
+
 def test_decode_under_shard_map_on_four_chips(topo):
     """The tp=4 serve path: the decode kernel inside ``jax.shard_map`` over
     the KV-head axis, on a mesh of the described 2x2's four devices.  Each
