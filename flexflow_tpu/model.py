@@ -380,6 +380,49 @@ class FFModel:
                                 dtype=x.dtype, **how)
         return self._add(op, [x], name or "lightning_attention")[0]
 
+    # a Mamba-2 / routed-expert hybrid's own ops (serve/ssd_moe_ops.py)
+    def mamba2_scan(self, xbc, dt, num_heads, head_dim, n_groups, d_state,
+                    name=None, **init):
+        from .serve.ssd_moe_ops import Mamba2Scan
+
+        op = Mamba2Scan(num_heads, head_dim, n_groups, d_state,
+                        dtype=xbc.dtype, **init)
+        return self._add(op, [xbc, dt], name or "mamba2_scan")[0]
+
+    def gated_group_norm(self, y, z, n_groups, eps=1e-5, name=None):
+        from .serve.ssd_moe_ops import GatedGroupNorm
+
+        op = GatedGroupNorm(y.shape[-1], n_groups, eps, dtype=y.dtype)
+        return self._add(op, [y, z], name or "gated_group_norm")[0]
+
+    def moe_router(self, x, num_experts, top_k, scaling=1.0, norm_topk=True,
+                   name=None):
+        from .serve.ssd_moe_ops import MoERouter
+
+        op = MoERouter(x.shape[-1], num_experts, top_k, scaling, norm_topk,
+                       dtype=x.dtype)
+        return self._add(op, [x], name or "moe_router")
+
+    def moe_dispatch(self, x, ids, num_held, held_lo=0, name=None):
+        from .serve.ssd_moe_ops import MoEDispatch
+
+        return self._add(MoEDispatch(num_held, held_lo), [x, ids],
+                         name or "moe_dispatch")
+
+    def moe_experts(self, xs, sizes, num_held, width, name=None):
+        from .serve.ssd_moe_ops import MoEExperts
+
+        op = MoEExperts(num_held, xs.shape[-1], width, dtype=xs.dtype)
+        return self._add(op, [xs, sizes], name or "moe_experts")[0]
+
+    def moe_combine(self, ys, order, ids, weights, num_held, held_lo=0,
+                    dtype=None, name=None):
+        from .serve.ssd_moe_ops import MoECombine
+
+        op = MoECombine(num_held, held_lo, dtype=dtype or ys.dtype)
+        return self._add(op, [ys, order, ids, weights],
+                         name or "moe_combine")[0]
+
     def spec_inc_multihead_self_attention(self, x, embed_dim, num_q_heads,
                                           num_kv_heads=None, head_dim=None,
                                           rotary_embedding=True,

@@ -55,7 +55,11 @@ SPLIT = ("host_admit", "host_prepare", "kv_prepare", "sample_for",
 # with — adds to a record, by argument name
 _SET = {"scan_tokens": "scan_tokens", "join_tokens": "join_tokens",
         "step_tokens": "step_tokens", "prefill_tokens": "prefill_tokens",
-        "state_reset": "admitted"}
+        "state_reset": "admitted",
+        # a routed-expert graph's load, as ``commit`` sets it (0 elsewhere)
+        "experts_visited": "experts_visited", "expert_pairs": "expert_pairs",
+        "expert_pairs_max": "expert_pairs_max",
+        "expert_steps": "expert_steps"}
 
 FIELDS = (
     # extent; ``tick_ns`` = the tick span's ``pc_ns`` (0: no tick ran)
@@ -70,6 +74,10 @@ FIELDS = (
     "admitted", "pending", "live",
     # the process, over the record
     "cpu_ns", "nivcsw", "majflt",
+    # the decode scans' routed-expert load (``commit``): held experts that
+    # got a row, pairs on held experts, the fullest expert's pairs — summed
+    # over scan steps and routed layers — and steps x layers
+    "experts_visited", "expert_pairs", "expert_pairs_max", "expert_steps",
 )
 _F = {name: i for i, name in enumerate(FIELDS)}
 _SPLIT_AT = {n: _F[f"{n}_ns"] for n in SPLIT}
@@ -211,7 +219,7 @@ class TickJournal:
     counts them.  4096 holds twenty runs of the benchmark's shortest-tick
     cell (``opt-6.7b-d12.decode-heavy``: 189 records for warm-up,
     rehearsal and the 51 s window, 165 of them the window's; my chip
-    runs, PR 46), as lists of 47 integers.
+    runs, PR 46), as lists of 51 integers.
     ``chunk_width``: rows of one prefill-scan chunk
     (``im.max_tokens``; ``chunk_rows`` = chunks x this).
     ``clock_ns``: the journal's clock, and the tick spans' ``pc_ns``

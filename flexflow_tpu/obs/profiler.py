@@ -129,7 +129,12 @@ class PlanCostCard:
     * ``kv_bytes_per_token`` — the allocator's committed-KV price (int8
       scales + lane padding included — the admission gate's number);
       falls back to the plan's registered-state arithmetic before the
-      caches are allocated.
+      caches are allocated;
+    * ``routed_weight_bytes`` — the part of ``weight_bytes`` that is the
+      routed experts' matrices (``routed_experts`` held per layer, each
+      row choosing ``routed_top_k``): a pass streams only the experts its
+      rows visit, at most ``rows x top_k`` of them a layer
+      (:meth:`weight_bytes_for`); 0 for a graph without routed layers.
     """
 
     attn_flops_per_token: float = 0.0
@@ -137,6 +142,19 @@ class PlanCostCard:
     lm_head_flops_per_row: float = 0.0
     weight_bytes: float = 0.0
     kv_bytes_per_token: float = 0.0
+    routed_weight_bytes: float = 0.0
+    routed_experts: int = 0
+    routed_top_k: int = 0
+
+    def weight_bytes_for(self, rows_per_pass: float) -> float:
+        """Weight bytes ONE pass over ``rows_per_pass`` rows streams: all
+        of the dense weights, and of the held experts' as many as the
+        rows can visit (an upper bound: every pair on another expert)."""
+        if not self.routed_experts:
+            return self.weight_bytes
+        visited = min(self.routed_experts, rows_per_pass * self.routed_top_k)
+        return self.weight_bytes - self.routed_weight_bytes * (
+            1.0 - visited / self.routed_experts)
 
     def flops_for(self, n_tokens: int, logit_rows: int) -> float:
         return (n_tokens * (self.attn_flops_per_token
@@ -162,7 +180,8 @@ def plan_cost_card(im) -> PlanCostCard:
     rows = int(getattr(im, "max_tokens", 0)) or 1
     attn_fl = mlp_fl = lm_fl = 0.0
     lm_rows = 0
-    w_bytes = 0.0
+    w_bytes = routed_bytes = 0.0
+    routed_experts = routed_top_k = 0
     for plan in plans:
         mesh = plan.mesh
         for step in plan.steps:
@@ -170,6 +189,11 @@ def plan_cost_card(im) -> PlanCostCard:
                 continue
             op = step.node.op
             w_bytes += _step_param_bytes(step, plan, mesh)
+            if op.type_name == "moe_experts":
+                routed_bytes += _step_param_bytes(step, plan, mesh)
+                routed_experts = op.num_held
+            elif op.type_name == "moe_router":
+                routed_top_k = op.top_k
             if op.type_name not in HEAVY_OPS:
                 continue
             fl = _step_flops(step, mesh)
@@ -206,6 +230,9 @@ def plan_cost_card(im) -> PlanCostCard:
         lm_head_flops_per_row=(lm_fl / lm_rows) if lm_rows else 0.0,
         weight_bytes=w_bytes,
         kv_bytes_per_token=kv_bpt,
+        routed_weight_bytes=routed_bytes,
+        routed_experts=routed_experts,
+        routed_top_k=routed_top_k,
     )
 
 
@@ -361,7 +388,8 @@ class StepProfiler:
         kv_r = read_tokens * card.kv_bytes_per_token
         w = self.work
         w["flops"] += flops
-        w["hbm_bytes_read"] += passes * card.weight_bytes + kv_r
+        w["hbm_bytes_read"] += passes * card.weight_bytes_for(
+            total / max(passes, 1)) + kv_r
         w["hbm_bytes_written"] += kv_w
         w["kv_bytes_touched"] += kv_r + kv_w
         per_tok = (card.attn_flops_per_token + card.mlp_flops_per_token

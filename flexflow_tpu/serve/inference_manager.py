@@ -12,7 +12,7 @@ the update is in-place in HBM.
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -181,19 +181,24 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                     if getattr(n.op, "slot_state", False)})
     if not kinds:
         return
+    routed = any(getattr(n.op, "counts_load", False) for n in graph.nodes)
     missing = []
     if kv_page_size:
         missing.append("kv_page_size: a page table for a ring that wraps "
                        "or a cache that compacts (pages assume one entry a "
                        "position), pages for an index of compressed keys "
                        "beside a cache, and copy-on-write of recurrent or "
-                       "matrix state or of an open window at a shared "
+                       "matrix state (linear attention's, or a state-space "
+                       "scan's per head) or of an open window at a shared "
                        "prefix's end")
     if kv_dtype == "int8":
         missing.append("kv_dtype='int8': quantise-on-write of the window "
                        "ring, of the cache the cross-attention layers read, "
                        "of a compacting cache's summaries and of a cache "
-                       "whose compressed keys choose what is read")
+                       "whose compressed keys choose what is read; a graph "
+                       "that keeps plain K/V planes in a few layers beside "
+                       "float32 matrix state in the rest has no reading of "
+                       "'int8' for the state")
     if max_spec_tokens:
         missing.append("speculation: a recurrent or matrix state, a closed "
                        "window or an appended index entry cannot be rolled "
@@ -203,12 +208,18 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
         missing.append("tp > 1: a sharding rule for the conv, the scan, the "
                        "differential attention's head pairs, the per-head "
                        "summaries, a selection per K/V head on fewer K/V "
-                       "heads than chips and a matrix state per head")
+                       "heads than chips and a matrix state per head"
+                       + ("; for the routed experts an exchange of rows "
+                          "between the chips that hold them (here each "
+                          "graph computes the experts it holds and nothing "
+                          "brings the rest)" if routed else ""))
     if pipelined:
         missing.append("pp > 1 (the pipelined manager, at any number of "
                        "stages): the exported scan output and the shared "
                        "cache cross stage boundaries, and its per-stage "
-                       "state hand-over knows full-length K/V planes only")
+                       "state hand-over knows full-length K/V planes only"
+                       + (" (nor does it carry the routed layers' load "
+                          "counters out of a stage)" if routed else ""))
     if missing:
         raise ValueError(
             f"this graph keeps per-slot state in {kinds}, which cannot be "
@@ -420,6 +431,15 @@ class InferenceManager:
         # {(layer kind, batch type): path} — a fallback is never unseen
         self.attention_paths: Dict[Tuple[str, str], str] = {}
         self._paths_counted = 0
+        # routed-expert layers (ops that leave a load count: MoEDispatch),
+        # and per decode scan dispatched and not yet collected ``(steps,
+        # int32[3] on the device: experts visited, pairs, the fullest
+        # expert's pairs — summed over steps and layers)``; the scheduler
+        # takes them with the stretch's readback (``take_expert_load``)
+        self.expert_layers = sum(
+            bool(getattr(n.op, "counts_load", False))
+            for n in model.graph.nodes)
+        self.scan_expert_load: List[Tuple[int, Any]] = []
         if outputs is None:
             out_tids = [model.graph.nodes[-1].outputs[-1]]
         else:
@@ -648,7 +668,8 @@ class InferenceManager:
         return sample_tokens(logits, sample)
 
     def _step_impl(self, params, state, bc, sample=None, tree_layout=None,
-                   qkv0=None, pages=None, one_row_per_request=False):
+                   qkv0=None, pages=None, one_row_per_request=False,
+                   counters=None):
         # ``tree_layout`` is passed ONLY by SpecDecodeScan, whose verify
         # batches are guaranteed slot-major [R, P]; host-built tree batches
         # (SpecInferManager) have variable layouts and must not take the
@@ -660,6 +681,9 @@ class InferenceManager:
         # ``one_row_per_request`` (static; the decode scan passes it): every
         # live row is a request of its own, so an op with recurrent state
         # updates all rows at once instead of scanning them in order.
+        # ``counters``: a dict the caller hands in to collect what the ops
+        # count on the device in this step (a routed layer's load:
+        # ``{node: int32[3]}``, traced values of the caller's trace).
         base = bc if isinstance(bc, BatchConfig) else bc.base
         outs, new_state = self._fwd(
             params,
@@ -675,6 +699,7 @@ class InferenceManager:
                 "pages": pages,
                 "one_row_per_request": one_row_per_request,
                 "attention_paths": self.attention_paths,
+                "counters": counters,
             },
         )
         with jax.named_scope("sample"):
@@ -826,9 +851,11 @@ class InferenceManager:
             # the block table is CONSTANT across the scan: the manager's
             # prepare_write pre-mapped (and COW-resolved) every page the
             # n_steps positions can reach before dispatch
+            load = {} if self.expert_layers else None
             result, state = self._step_impl(params, state, bc, stp,
                                             pages=pages,
-                                            one_row_per_request=True)
+                                            one_row_per_request=True,
+                                            counters=load)
             toks = result.token_ids
             live = alive  # emission validity for THIS step
             with jax.named_scope("advance"):
@@ -848,12 +875,18 @@ class InferenceManager:
                         num_tokens=nxt.num_tokens,
                         seq_lens=nxt.seq_lens,
                     )
-            return (state, nxt, alive, eos_hit), (toks, live)
+            # the routed layers' load this step, summed over the layers:
+            # [experts visited, pairs, the fullest expert's pairs] (None,
+            # and nothing in the program, for a graph without such layers)
+            load = sum(load.values()) if load else None
+            return (state, nxt, alive, eos_hit), (toks, live, load)
 
         eos_hit0 = jnp.zeros_like(alive0)
-        (state, bc, alive_end, eos_hit), (tokens, live) = jax.lax.scan(
+        (state, bc, alive_end, eos_hit), (tokens, live, load) = jax.lax.scan(
             body, (state, bc, alive0, eos_hit0), jnp.arange(n_steps)
         )
+        if load is not None:
+            load = jnp.sum(load, axis=0)
         with jax.named_scope("advance"):
             ecode = jnp.where(
                 ~present, EXIT_NOT_IN_BATCH,
@@ -876,7 +909,7 @@ class InferenceManager:
                     num_tokens=bc.num_tokens,
                     seq_lens=bc.seq_lens,
                 )
-        return tokens, live, ecode, state, bc
+        return tokens, live, ecode, state, bc, load
 
     def _decode_scan_guards(self, bc, n_steps: int, max_position: int,
                             rows=None) -> int:
@@ -893,19 +926,19 @@ class InferenceManager:
         dispatch span's ``rows``), where it has one.  More than the scan's
         width would be cut by the compaction and come back
         ``EXIT_NOT_IN_BATCH`` without a word, so they are refused here."""
-        from .ops import DUS_MAX_TOKENS
+        from . import ops
 
         width = decode_scan_width(bc)
-        if width > DUS_MAX_TOKENS:
+        if width > ops.SCAN_DUS_MAX_ROWS:
             # the scan's KV writes are as wide as the scan (one row per
-            # slot, not max_tokens); past the DUS threshold they become an
-            # XLA scatter whose layout choice forces a per-step full-cache
-            # relayout (see ops.DUS_MAX_TOKENS)
+            # slot, not max_tokens); past the scan's DUS threshold they
+            # become an XLA scatter whose layout choice forces a per-step
+            # full-cache relayout (see ops.DUS_MAX_TOKENS)
             import warnings
 
             warnings.warn(
                 f"decode_scan runs {width} rows (one per request slot) > "
-                f"{DUS_MAX_TOKENS}: KV writes take the scatter path and "
+                f"{ops.SCAN_DUS_MAX_ROWS}: KV writes take the scatter path and "
                 "re-lay out the full cache every step",
                 stacklevel=2,
             )
@@ -979,13 +1012,33 @@ class InferenceManager:
                                  kind="decode_scan",
                                  n_steps=n_steps, width=width,
                                  **(counts or {})):
-            tokens, live, ecode, self.state, bc = with_stack_room(
+            tokens, live, ecode, self.state, bc, load = with_stack_room(
                 self._scan, self.params, self.state, bc, sample,
                 self._page_view(), allowed, n_steps=n_steps, eos=eos)
+        if self.expert_layers:
+            self.scan_expert_load.append((n_steps, load))
         if self.telemetry.enabled:
             self.telemetry.metrics.counter("decode_scan_steps").inc(n_steps)
         self._count_attention_paths()
         return tokens, live, ecode, bc
+
+    def take_expert_load(self):
+        """The routed layers' load of the decode scans dispatched since the
+        last call, read back: ``{"experts_visited", "expert_pairs",
+        "expert_pairs_max", "expert_steps"}`` (``expert_steps`` = scan steps
+        x routed layers), or None for a graph without routed layers or
+        when no scan ran."""
+        taken, self.scan_expert_load = self.scan_expert_load, []
+        if not taken:
+            return None
+        import numpy as np
+
+        total = np.sum([np.asarray(load) for _, load in taken], axis=0)
+        return {"experts_visited": int(total[0]),
+                "expert_pairs": int(total[1]),
+                "expert_pairs_max": int(total[2]),
+                "expert_steps": self.expert_layers * sum(
+                    n for n, _ in taken)}
 
     def _join_impl(self, bc, tok_src, src_idx, dst, slot, pos, seq_len,
                    num_tokens, eos: Optional[int]):

@@ -59,6 +59,11 @@ KV_BUFFER_NAMES = frozenset({"k", "v", "k_scale", "v_scale"})
 # cache, so a position's price includes its share (``bytes_per_token``).
 # ``linear_state`` is a linear-attention layer's matrix per head
 # (LightningAttention): fixed, priced per slot like ``recurrent``.
+# ``ssd_state`` is a Mamba-2 layer's matrix per head (Mamba2Scan,
+# serve/ssd_moe_ops.py): ``[heads, head_dim, state]`` float32, fixed, priced
+# per slot; its conv tail is ``recurrent`` like Mamba-1's.  Such a graph
+# keeps the PLAIN attention op's ``kv_full`` planes in some layers beside
+# slot state in others: both are allocated, joined and freed by slot.
 KV_INDEX_NAMES = frozenset({"kidx"})
 STATE_KINDS = {
     "kv_full": KV_BUFFER_NAMES,
@@ -67,6 +72,7 @@ STATE_KINDS = {
     "kv_compact": frozenset({"ck", "cv"}),
     "kv_index": KV_INDEX_NAMES,
     "linear_state": frozenset({"lin"}),
+    "ssd_state": frozenset({"ssd"}),
 }
 
 

@@ -95,6 +95,33 @@ class ServeModelConfig:
     sparse_window_size: int = 2048
     sparse_init_blocks: int = 1
     sparse_dense_len: int = 8192
+    # nemotron_h (``models/nemotron_h.py``): ``hybrid_override_pattern[i]``
+    # says what the ONE mixer of block i is — ``M`` Mamba-2 (the ``mamba_*``
+    # sizes, ``n_groups`` B/C groups, ``ssm_state_size``, ``conv_kernel``,
+    # the ``time_step_*`` of its initialisation), ``*`` attention, ``E`` a
+    # mixture of experts (the keys below), ``-`` a dense relu^2 MLP.
+    # ``n_routed_experts`` is what THIS graph holds: share
+    # ``expert_share_index`` of the ``router_num_experts`` the router scores
+    # (None: it holds them all).  ``layer_norm_epsilon`` maps to
+    # ``layer_norm_eps``.
+    hybrid_override_pattern: Optional[str] = None
+    mamba_num_heads: int = 64
+    mamba_head_dim: int = 64
+    n_groups: int = 8
+    ssm_state_size: int = 128
+    conv_kernel: int = 4
+    time_step_min: float = 1e-3
+    time_step_max: float = 1e-1
+    time_step_floor: float = 1e-4
+    n_routed_experts: int = 0
+    router_num_experts: Optional[int] = None
+    expert_share_index: int = 0
+    num_experts_per_tok: int = 1
+    n_shared_experts: int = 0
+    moe_intermediate_size: Optional[int] = None
+    moe_shared_expert_intermediate_size: Optional[int] = None
+    routed_scaling_factor: float = 1.0
+    norm_topk_prob: bool = True
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

@@ -99,6 +99,10 @@ def _gather_logical_rows(cache, pages, rows):
 # full-cache relayout, so SpecDecodeScan and InferenceManager.decode_scan
 # check those widths against it.
 DUS_MAX_TOKENS = 128
+# the decode scan keeps the chain up to this many rows (one per slot): there
+# the scatter's relayout copies the whole cache every step, which costs more
+# than the longest chain; the scan's guard warns past it
+SCAN_DUS_MAX_ROWS = 256
 
 
 @jax.jit
@@ -409,8 +413,9 @@ class IncMultiHeadSelfAttention(Op):
         return jnp.where(r >= 0, r, max_requests)
 
     @staticmethod
-    def _scatter_rows_pos(cache, rows, pos, updates):
-        """``cache[rows[t], :, pos[t]] = updates[t]`` without transposes.
+    def _scatter_rows_pos(cache, rows, pos, updates, chain_rows=None):
+        """``cache[rows[t], :, pos[t]] = updates[t]`` without transposes
+        (``chain_rows``: the widest chain, ``DUS_MAX_TOKENS`` if None).
 
         ``cache.at[rows, :, pos].set(...)`` is advanced indices split by a
         slice — NumPy semantics force jnp to transpose the whole cache to
@@ -435,7 +440,7 @@ class IncMultiHeadSelfAttention(Op):
         # undefined behavior for a hand-built BatchConfig with bad positions.
         rows = jnp.clip(rows.astype(jnp.int32), 0, cache.shape[0] - 1)
         pos = jnp.clip(pos.astype(jnp.int32), 0, cache.shape[2] - 1)
-        if t > DUS_MAX_TOKENS:
+        if t > (chain_rows or DUS_MAX_TOKENS):
             idx = jnp.stack([rows, pos], axis=-1)
             dnums = jax.lax.ScatterDimensionNumbers(
                 update_window_dims=(1, 2),
@@ -466,7 +471,7 @@ class IncMultiHeadSelfAttention(Op):
         return q.astype(jnp.int8), scale
 
     @staticmethod
-    def _scatter_scale(cache, rows, pos, updates):
+    def _scatter_scale(cache, rows, pos, updates, chain_rows=None):
         """``cache[rows[t], :, pos[t]] = updates[t]`` for scale buffers.
 
         ``cache``: [R, KV, S] f32, ``updates``: [T, KV] — the 3-D sibling of
@@ -477,7 +482,7 @@ class IncMultiHeadSelfAttention(Op):
         upd = updates.astype(cache.dtype)
         rows = jnp.clip(rows.astype(jnp.int32), 0, cache.shape[0] - 1)
         pos = jnp.clip(pos.astype(jnp.int32), 0, cache.shape[2] - 1)
-        if t > DUS_MAX_TOKENS:
+        if t > (chain_rows or DUS_MAX_TOKENS):
             idx = jnp.stack([rows, pos], axis=-1)
             dnums = jax.lax.ScatterDimensionNumbers(
                 update_window_dims=(1,),
@@ -491,10 +496,11 @@ class IncMultiHeadSelfAttention(Op):
         return _update_rows(cache, rows, pos, upd)
 
     @jax.named_scope("kv_write")
-    def _write_kv(self, state, rows, pos, k, v, pages=None):
+    def _write_kv(self, state, rows, pos, k, v, pages=None, chain_rows=None):
         """Write this step's K/V vectors into the committed caches,
         quantizing on write when the caches are int8.  Returns the updated
-        buffers as a dict of the state keys that changed.  ``pages``
+        buffers as a dict of the state keys that changed.  ``chain_rows``:
+        see ``_scatter_rows_pos`` (the decode scan's wider chain).  ``pages``
         (paged KV) translates the logical (row, position) coordinates to
         physical ones first — the scale planes ride the SAME translation,
         so int8 scales page alongside their K/V values."""
@@ -505,14 +511,16 @@ class IncMultiHeadSelfAttention(Op):
             kq, ks = self._kv_quant(k)
             vq, vs = self._kv_quant(v)
             return {
-                "k": self._scatter_rows_pos(kc, rows, pos, kq),
-                "v": self._scatter_rows_pos(vc, rows, pos, vq),
-                "k_scale": self._scatter_scale(state["k_scale"], rows, pos, ks),
-                "v_scale": self._scatter_scale(state["v_scale"], rows, pos, vs),
+                "k": self._scatter_rows_pos(kc, rows, pos, kq, chain_rows),
+                "v": self._scatter_rows_pos(vc, rows, pos, vq, chain_rows),
+                "k_scale": self._scatter_scale(state["k_scale"], rows, pos,
+                                               ks, chain_rows),
+                "v_scale": self._scatter_scale(state["v_scale"], rows, pos,
+                                               vs, chain_rows),
             }
         return {
-            "k": self._scatter_rows_pos(kc, rows, pos, k),
-            "v": self._scatter_rows_pos(vc, rows, pos, v),
+            "k": self._scatter_rows_pos(kc, rows, pos, k, chain_rows),
+            "v": self._scatter_rows_pos(vc, rows, pos, v, chain_rows),
         }
 
     @staticmethod
@@ -588,7 +596,10 @@ class IncMultiHeadSelfAttention(Op):
         rows = self._rows(bc, nreq)
         pos = bc.token_position
         pages = ctx.extras.get("pages") if ctx is not None else None
-        writes = self._write_kv(state, rows, pos, k, v, pages)
+        in_scan = ctx is not None and ctx.extras.get("one_row_per_request")
+        writes = self._write_kv(
+            state, rows, pos, k, v, pages,
+            chain_rows=SCAN_DUS_MAX_ROWS if in_scan else None)
         kc, vc = writes["k"], writes["v"]
         kv_q = kc.dtype == jnp.int8
         if ctx is not None and ctx.extras.get("pallas_decode"):

@@ -366,6 +366,29 @@ class RequestManager:
         per-slot state whose work ``ctx_sum`` does not tell."""
         return {**self._compact_counts(writes), **self._sparse_counts(writes)}
 
+    def _expert_load(self) -> Dict[str, int]:
+        """For a graph with routed-expert layers: what the decode scans of
+        the stretch just read back counted on the device, summed over steps
+        and layers — ``experts_visited`` (held experts that got a row),
+        ``expert_pairs`` (pairs on held experts), ``expert_pairs_max`` (the
+        fullest expert's pairs) and ``expert_steps`` (scan steps x routed
+        layers) — as arguments of the ``commit`` span (and so fields of the
+        tick's journal record) and, per tick, the trace counters
+        ``moe.experts_visited`` / ``moe.pairs`` / ``moe.pairs_max``.
+        Nothing for a graph with none, or a manager that counts none."""
+        take = getattr(self.im, "take_expert_load", None)
+        load = take() if take is not None else None
+        if not load:
+            return {}
+        tel = self.telemetry
+        if tel.enabled:
+            for name, key in (("moe.experts_visited", "experts_visited"),
+                              ("moe.pairs", "expert_pairs"),
+                              ("moe.pairs_max", "expert_pairs_max")):
+                tel.metrics.counter(name).inc(load[key])
+                tel.trace.counter(name, load[key])
+        return load
+
     def _sparse_counts(self, writes) -> Dict[str, int]:
         """For a graph with sparse-attention layers (``hybrid_ops.
         SparseBlockAttention``): ``attended_blocks_sum``, the cache blocks
@@ -1950,6 +1973,7 @@ class RequestManager:
                     _, req, token_ids, src = item
                     ready.append(("join", req,
                                   int(np.asarray(token_ids)[src])))
+            expert_load = self._expert_load()
         prof.host_sync()
         codes: Dict[int, int] = {}
         # which program made each token the host now appends: the decode
@@ -1984,7 +2008,8 @@ class RequestManager:
                     if c != EXIT_NOT_IN_BATCH:
                         codes[rid] = c   # the segment where the row ran last
                 made["scan"] += self.tokens_decoded - before
-            sp.set(scan_tokens=made["scan"], join_tokens=made["join"])
+            sp.set(scan_tokens=made["scan"], join_tokens=made["join"],
+                   **expert_load)
         self.last_exit_codes = codes
         self.steps += total
         self.scan_runs += 1

@@ -418,7 +418,7 @@ def test_guard_warns_on_the_scans_width_not_on_max_tokens(monkeypatch):
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         assert im._decode_scan_guards(bc, 2, max_position=3) == SLOTS
-    monkeypatch.setattr(serve_ops, "DUS_MAX_TOKENS", SLOTS - 1)
+    monkeypatch.setattr(serve_ops, "SCAN_DUS_MAX_ROWS", SLOTS - 1)
     with pytest.warns(UserWarning, match=f"runs {SLOTS} rows"):
         im._decode_scan_guards(bc, 2, max_position=3)
 

@@ -306,3 +306,75 @@ def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
             ("selective_scan", "PrefillBatchConfig")] == "kernel"
     else:
         assert not kernels and loops and writes
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["scan256", "chunk512"])
+def test_nemotron_routed_layer_compiles_for_v5e(one_chip, rows):
+    """The routed-expert layer at Nemotron-3-Nano's published widths (hidden
+    2688, a router over 128 experts with top-6, 64 HELD experts of width
+    1856) on the decode scan's 256 rows and on a prompt chunk's 512: router,
+    dispatch, both grouped GEMMs (Megablox, ragged last tiles: 1856 = 14.5 x
+    128) and the combine, for the described chip."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.ssd_moe_ops import (MoECombine, MoEDispatch,
+                                                MoEExperts, MoERouter)
+
+    d, f, held, scored, k = 2688, 1856, 64, 128, 6
+
+    def layer(x, gate, bias, up, down):
+        ctx = lambda: OpContext(extras={"node_name": "n",
+                                        "pallas_decode": True})
+        ids, w = MoERouter(d, scored, k, 2.5, dtype=x.dtype).lower(
+            ctx(), [x], {"weight": gate, "e_score_correction_bias": bias})
+        xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
+        ys = MoEExperts(held, d, f, dtype=x.dtype).lower(
+            ctx(), [xs, sizes], {"up": up, "down": down})[0]
+        return MoECombine(held, dtype=x.dtype).lower(
+            ctx(), [ys, order, ids, w], {})[0]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((d, scored), jnp.float32),
+        sds((scored,), jnp.float32), sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("form", ["slot_order", "chunked"])
+def test_nemotron_ssd_scan_compiles_for_v5e(one_chip, form):
+    """``Mamba2Scan`` at the published widths (64 heads of 64 in 8 groups,
+    state 128; 257 state rows of 2 MB): the decode scan's 256 rows in slot
+    order — ONE pass over the 539 MB state array, which the program's
+    memory shows: no second array of its size among the temporaries — and a
+    prompt chunk of 512 rows in the chunked form."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.batch_config import BatchConfig
+    from flexflow_tpu.serve.ssd_moe_ops import Mamba2Scan
+
+    h, p, g, n, slots = 64, 64, 8, 128, 256
+    rows = slots if form == "slot_order" else 512
+    op = Mamba2Scan(h, p, g, n, dtype=jnp.bfloat16)
+
+    def scan(xbc, dt, ssd, request_index, position, a_log, dd, dt_bias):
+        bc = BatchConfig(tokens=position, request_index=request_index,
+                         token_position=position,
+                         num_tokens=jnp.int32(rows),
+                         seq_lens=jnp.zeros((slots,), jnp.int32))
+        ctx = OpContext(extras={
+            "node_name": "n", "batch_config": bc, "state": {"ssd": ssd},
+            "one_row_per_request": form == "slot_order"})
+        y = op.lower(ctx, [xbc, dt],
+                     {"A_log": a_log, "D": dd, "dt_bias": dt_bias})[0]
+        return y, ctx.extras["state_out"]["ssd"]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    state = sds((slots + 1, h, p, n), jnp.float32)
+    compiled = jax.jit(scan, donate_argnums=(2,)).lower(
+        sds((rows, h * p + 2 * g * n), jnp.bfloat16),
+        sds((rows, h), jnp.bfloat16), state, sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), *([sds((h,), jnp.float32)] * 3)).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = (slots + 1) * h * p * n * 4
+    assert mem.alias_size_in_bytes >= state_bytes      # updated in place
+    if form == "slot_order":
+        assert mem.temp_size_in_bytes < state_bytes // 2
