@@ -952,6 +952,103 @@ def prefill_attention(
         .reshape(g, bq, qh, d)
 
 
+# One grid step of :func:`kv_block_write` moves this many bytes of the
+# chunk's K (and as many of V) at most: whole heads of one tile.
+_WRITE_BLOCK_BYTES = 512 * 2**10
+
+
+def _kv_block_write_kernel(
+    rows_ref,       # scalar prefetch: i32[G] cache row per tile
+    blk_ref,        # scalar prefetch: i32[G] the tile's block along seq
+    count_ref,      # scalar prefetch: i32[G] real tokens at the tile's head
+    k_ref,          # [heads, Bq, D]: this tile's rows of the chunk's fresh
+    v_ref,          # keys / values, head-major
+    kc_any,         # the caches themselves (aliased to the outputs; never
+    vc_any,         # read: a step writes whole blocks)
+    ko_ref,         # [1, heads, Bq, D] block of the K cache
+    vo_ref,         # [1, heads, Bq, D] block of the V cache
+):
+    del rows_ref, blk_ref, kc_any, vc_any
+    row = jax.lax.broadcasted_iota(jnp.int32, k_ref.shape, 1)
+    real = row < count_ref[pl.program_id(0)]
+    for src, dst in ((k_ref, ko_ref), (v_ref, vo_ref)):
+        x = src[...].astype(dst.dtype)
+        dst[0] = jnp.where(real, x, jnp.zeros_like(x))
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def kv_block_write(
+    k_cache: jax.Array,  # [R+1, KV, S, D]
+    v_cache: jax.Array,  # [R+1, KV, S, D]
+    k: jax.Array,        # [G * tile, KV, D] the chunk's fresh keys
+    v: jax.Array,        # [G * tile, KV, D]
+    rows: jax.Array,     # i32[G] cache row per tile (PHYSICAL under paging)
+    start: jax.Array,    # i32[G] first seq index per tile, a multiple of tile
+    count: jax.Array,    # i32[G] real tokens per tile (they sit at its head)
+    tile: int,
+    interpret: bool = False,
+):
+    """A tiled prefill chunk's K and V into their caches, IN PLACE, in one
+    call: ``cache[rows[g], :, start[g]:start[g] + tile] = x[g * tile:(g + 1)
+    * tile]`` head-major, cast to the cache's type, rows past ``count[g]``
+    as zeros — the tile contract of :func:`prefill_attention`
+    (request-homogeneous tiles, tile-aligned starts, ``S`` whole tiles, so
+    that a block neither wraps nor clamps), and the values, zeros and
+    positions of the chain of per-tile ``dynamic_update_slice`` operations it
+    replaces (``serve/ops.py`` ``put_blocks`` keeps that chain as its
+    fallback and the tests' reference).
+
+    Both caches are aliased in and out (``input_output_aliases``), so XLA
+    updates them where they lie; what a step does not visit keeps its
+    contents.  Grid ``(tiles, head groups)``: the fresh rows come in as
+    ``[heads, tile, D]`` blocks of the chunk seen head-major ``[KV, T, D]``
+    and leave as ``[1, heads, tile, D]`` blocks of the cache, cast and
+    masked on the way.  Head-major is how the fused QKV projection's output
+    lies on the chip (XLA lays ``[T, KV, G, D]`` out heads first, so the view
+    costs what the chain's per-tile blocks cost: one pass over K and one
+    over V); handed the rows as ``[T, KV * D]`` the compiler transposed
+    them first, at twice that (v5e, PR 49).  Heads of whole lanes only
+    (``D % 128 == 0``; ``put_blocks`` sends the rest down the chain): at 64
+    the compiler takes the kernel but re-lays both caches out around the
+    call (AOT, PR 49).  Tiles that land on one block (the fully-pad tiles,
+    all on the scratch row) all write zeros there.
+    """
+    r1, num_kv, s_len, d = k_cache.shape
+    t = k.shape[0]
+    g = t // tile
+    if t % tile or s_len % tile:
+        raise ValueError(f"the chunk ({t} rows) and the cache ({s_len} "
+                         f"positions) hold whole tiles of {tile}")
+    itemsize = max(jnp.dtype(a.dtype).itemsize for a in (k, k_cache))
+    heads = max(c for c in range(1, num_kv + 1) if num_kv % c == 0
+                and (c == 1 or c * tile * d * itemsize <= _WRITE_BLOCK_BYTES))
+    fresh = pl.BlockSpec((heads, tile, d), lambda i, c, *_: (c, i, 0),
+                         memory_space=pltpu.VMEM)
+    block = pl.BlockSpec(
+        (1, heads, tile, d),
+        lambda i, c, rows, blk, count: (rows[i], c, blk[i], 0),
+        memory_space=pltpu.VMEM)
+    cache = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(g, num_kv // heads),
+        in_specs=[fresh, fresh, cache, cache],
+        out_specs=[block, block],
+    )
+    return pl.pallas_call(
+        _kv_block_write_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(k_cache.shape, k_cache.dtype),
+                   jax.ShapeDtypeStruct(v_cache.shape, v_cache.dtype)],
+        # operands count from the first scalar-prefetch argument
+        input_output_aliases={5: 0, 6: 1},
+        interpret=interpret,
+    )(jnp.clip(rows.astype(jnp.int32), 0, r1 - 1),
+      jnp.clip(start.astype(jnp.int32) // tile, 0, s_len // tile - 1),
+      count.astype(jnp.int32),
+      jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1), k_cache, v_cache)
+
+
 def _tree_kernel(
     rows_ref,       # scalar prefetch: i32[T] cache row per token
     clens_ref,      # scalar prefetch: i32[T] committed cache depth per token

@@ -192,9 +192,9 @@ class PrefillBatchConfig:
     tile's head with pad slots only at the tail, (c) have contiguous
     ascending positions, and (d) start at a TILE-ALIGNED position
     (``start_pos % Bq == 0``) — the attention op writes each tile's KV as
-    one block dynamic-update-slice, and alignment (with the cache's seq
-    capacity a multiple of the tile) guarantees the DUS start is never
-    clamp-shifted.  The kernel then reconstructs every per-token causal
+    one block (``ops.put_blocks``), and alignment (with the cache's seq
+    capacity a multiple of the tile) guarantees the block is a whole tile
+    of the cache, never clamp-shifted.  The kernel then reconstructs every per-token causal
     mask from the tile's first position alone.
 
     **LM-head gating** (``logit_slots``): a prefill chunk only needs logits

@@ -53,7 +53,7 @@ from ..core.sharding import TensorSharding
 from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
 from .ops import (DUS_MAX_TOKENS, NEG_INF, IncMultiHeadSelfAttention,
-                  apply_rope)
+                  apply_rope, put_blocks, tile_coords)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
@@ -122,18 +122,6 @@ def _set_rows(buf, rows, upd):
     if upd.shape[0] > DUS_MAX_TOKENS:
         return buf.at[rows].set(upd)
     return _set_rows_chain(buf, rows, upd)
-
-
-def _put_blocks(kc, vc, kb, vb, rows, start):
-    """``kb[i]`` / ``vb[i]`` (``[heads, tile, D]`` each) into row ``rows[i]``
-    of the caches from seq index ``start[i]`` on: one in-place block write
-    per prefill tile (ops._prefill_attend says why not a scatter)."""
-    zero = jnp.int32(0)
-    for i in range(kb.shape[0]):
-        at = (rows[i], zero, start[i], zero)
-        kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
-        vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
-    return kc, vc
 
 
 def _init(fn):
@@ -471,7 +459,7 @@ class DiffAttention(_SlotStateOp):
         return q, k, v
 
     @jax.named_scope("kv_write")
-    def _write(self, kc, vc, k, v, bc, seg, tiled):
+    def _write(self, kc, vc, k, v, bc, seg, tiled, extras):
         """This step's keys and values into the cache (a ring for a window
         layer: position ``p`` at slot ``p % ring``)."""
         base = _flat(bc)
@@ -480,20 +468,15 @@ class DiffAttention(_SlotStateOp):
         if not tiled:
             put = IncMultiHeadSelfAttention._scatter_rows_pos
             return put(kc, seg.rows, pos, k), put(vc, seg.rows, pos, v)
-        # a tiled prefill chunk: one block write per request-homogeneous
-        # tile (ops._prefill_attend says why not a scatter).  A tile starts
+        # a tiled prefill chunk: one block per request-homogeneous tile
+        # (ops.put_blocks says why not a scatter).  A tile starts
         # tile-aligned and the ring is whole tiles, so a block never wraps;
         # its tail pads write zeros at positions no query of this chunk
         # sees, which a later chunk overwrites before any does.
         bq = bc.tile_size
-        g = k.shape[0] // bq
-        rows = jnp.min(seg.rows.reshape(g, bq), axis=1)
-        start = pos.reshape(g, bq)[:, 0]
-        valid = seg.live.reshape(g, 1, bq, 1)
-        block = lambda a: jnp.where(
-            valid, a.reshape(g, bq, self.kv_pairs, self.pair_dim)
-            .transpose(0, 2, 1, 3), 0).astype(kc.dtype)
-        return _put_blocks(kc, vc, block(k), block(v), rows, start)
+        return put_blocks(
+            kc, vc, k, v, *tile_coords(seg.rows, pos, bq, kc.shape[0] - 1),
+            bq, extras)
 
     def _attend_xla(self, q, kc, vc, rows, pos):
         """Plain attention of query groups against their slot's cache:
@@ -575,7 +558,8 @@ class DiffAttention(_SlotStateOp):
                  and bool(ctx.extras.get("pallas_decode")))
         with jax.named_scope("attend"):
             if self.mode != "cross":
-                kc, vc = self._write(kc, vc, k, v, bc, seg, tiled)
+                kc, vc = self._write(kc, vc, k, v, bc, seg, tiled,
+                                     ctx.extras)
                 ctx.extras["state_out"] = {names[0]: kc, names[1]: vc}
             out, path = self._attend(q, kc, vc, bc, seg, ctx, tiled)
             paths = ctx.extras.get("attention_paths")
@@ -756,25 +740,21 @@ class EvaAttention(_SlotStateOp):
         return q, k, qkv[:, :, 2]
 
     @jax.named_scope("kv_write")
-    def _write(self, kc, vc, k, v, rows, at, bc, tiled):
+    def _write(self, kc, vc, k, v, rows, at, bc, tiled, extras):
         """This pass's keys and values to ``(rows, at)``: ``at`` the compact
         index, ``rows`` the scratch row for what the pass leaves out."""
         if not tiled:
             put = IncMultiHeadSelfAttention._scatter_rows_pos
             return put(kc, rows, at, k), put(vc, rows, at, v)
-        # one block write per tile; a tile lies inside one window, so its
-        # entries are contiguous, and its tail pads land beyond the open
-        # window's newest entry (which a later chunk overwrites before any
-        # query reads it)
+        # one block per tile; a tile lies inside one window, so its entries
+        # are contiguous, and its tail pads land beyond the open window's
+        # newest entry (which a later chunk overwrites before any query
+        # reads it).  A window opens ``per_window`` entries after the last:
+        # a tile's first entry is a whole number of tiles where that is one
         bq = bc.tile_size
-        g = k.shape[0] // bq
-        h, hd = self.num_kv_heads, self.head_dim
-        valid = (rows != kc.shape[0] - 1).reshape(g, 1, bq, 1)
-        block = lambda a: jnp.where(
-            valid, a.reshape(g, bq, h, hd).transpose(0, 2, 1, 3),
-            0).astype(kc.dtype)
-        return _put_blocks(kc, vc, block(k), block(v),
-                           rows.reshape(g, bq)[:, 0], at.reshape(g, bq)[:, 0])
+        return put_blocks(
+            kc, vc, k, v, *tile_coords(rows, at, bq, kc.shape[0] - 1),
+            bq, extras, aligned=self.per_window % bq == 0)
 
     def _attend(self, q, kc, vc, rows, at, bc, ctx, tiled):
         """``[T, heads, D]``: causal attention of each row over its slot's
@@ -869,7 +849,8 @@ class EvaAttention(_SlotStateOp):
             for i, now in enumerate(passes):
                 rows = jnp.where(seg.live & now, seg.rows, nreq)
                 idx = jnp.where(rows == nreq, 0, at)
-                kc, vc = self._write(kc, vc, k, v, rows, idx, bc, tiled)
+                kc, vc = self._write(kc, vc, k, v, rows, idx, bc, tiled,
+                                     ctx.extras)
                 o, path = self._attend(q, kc, vc, rows, idx, bc, ctx, tiled)
                 if i == 0:
                     out = o
@@ -1277,14 +1258,9 @@ class SparseBlockAttention(_SlotStateOp):
                 if tiled:
                     bq = bc.tile_size
                     g = t // bq
-                    block = lambda a: jnp.where(
-                        seg.live.reshape(g, 1, bq, 1),
-                        a.reshape(g, bq, kv, d).transpose(0, 2, 1, 3),
-                        0).astype(kc.dtype)
-                    kc, vc = _put_blocks(
-                        kc, vc, block(k), block(v),
-                        jnp.min(seg.rows.reshape(g, bq), axis=1),
-                        pos.reshape(g, bq)[:, 0])
+                    kc, vc = put_blocks(
+                        kc, vc, k, v, *tile_coords(seg.rows, pos, bq, nreq),
+                        bq, ctx.extras)
                 else:
                     put = IncMultiHeadSelfAttention._scatter_rows_pos
                     kc = put(kc, seg.rows, pos, k)

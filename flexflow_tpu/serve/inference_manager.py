@@ -513,7 +513,7 @@ class InferenceManager:
         # per chunk, half the per-row DMA-wait boundaries.
         # RequestManager builds PrefillBatchConfigs with this tile size for
         # pure-prefill steps.  The tile must also divide max_seq_len
-        # (ADVICE r5 medium): the tiled-prefill block DUS assumes
+        # (ADVICE r5 medium): the tiled-prefill block write assumes
         # tile-aligned starts never clamp against the cache's seq capacity.
         self.prefill_tile = pick_prefill_tile(max_tokens_per_batch,
                                               max_seq_len)

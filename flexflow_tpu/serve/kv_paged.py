@@ -300,7 +300,7 @@ class HostPageTier:
 
 def validate_page_tile(page_size: int, prefill_tile: int) -> None:
     """Construction-time contract shared by both managers: the tiled
-    prefill path writes each tile as ONE block DUS, so a tile straddling
+    prefill path writes each tile as ONE block, so a tile straddling
     a page boundary would scatter across two physical pages — fail here,
     not inside a kernel grid (sibling of the page/max_seq_len asserts)."""
     if page_size and page_size % prefill_tile:

@@ -28,6 +28,7 @@ Design differences from the CUDA original, driven by TPU/XLA:
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict, List, Optional, Tuple
 
@@ -55,7 +56,7 @@ def _page_rows_pos(pages, rows, pos):
     The physical buffers keep the slot-contiguous ``[R+1, KV, S, D]``
     shape; a page id addresses ``(row, page-slot) = divmod(pid,
     pages_per_row)``, so every existing write path (DUS chain, scatter,
-    per-tile block DUS) runs unchanged on the translated coordinates —
+    per-tile block write) runs unchanged on the translated coordinates —
     the indirection is pure index arithmetic, which is what makes the
     paged path bit-identical to the contiguous one.
     """
@@ -123,6 +124,85 @@ def _update_rows(cache, rows, pos, upd):
         cache = jax.lax.dynamic_update_slice(
             cache, jnp.expand_dims(upd[i], (0, 2)), start)
     return cache
+
+
+def tile_coords(rows, pos, tile, scratch):
+    """A tiled chunk's per-row cache ``rows`` (pads on the ``scratch`` row,
+    the largest index) and seq indices ``pos`` as per-tile ``(row, start,
+    count)``: real rows sit at a tile's head, so ``min`` recovers its
+    request (the scratch row for a fully-pad tile), its first row gives its
+    start, and the rows off the scratch row are its count."""
+    g = rows.shape[0] // tile
+    rows = rows.reshape(g, tile)
+    return (jnp.min(rows, axis=1), pos.reshape(g, tile)[:, 0],
+            jnp.sum(rows != scratch, axis=1, dtype=jnp.int32))
+
+
+def _tile_blocks(a, count, tile, dtype):
+    """A chunk's rows ``[G * tile, H, ...]`` as head-major blocks ``[G, H,
+    tile, ...]`` in ``dtype``, rows past ``count[g]`` (a tile's tail pads)
+    as zeros."""
+    g = a.shape[0] // tile
+    b = jnp.swapaxes(a.reshape((g, tile) + a.shape[1:]), 1, 2).astype(dtype)
+    real = jnp.arange(tile)[None, :] < count[:, None]            # [G, tile]
+    return jnp.where(real.reshape((g, 1, tile) + (1,) * (a.ndim - 2)), b, 0)
+
+
+@jax.jit
+def _block_chain(cache, blocks, rows, start):
+    """``cache[rows[g], :, start[g]:start[g] + tile] = blocks[g]`` as a chain
+    of in-place dynamic-update-slices, one per tile (``cache`` [R, H, S, D]
+    with ``blocks`` [G, H, tile, D], or a scale plane [R, H, S] with
+    [G, H, tile]).  Traced once per shape, as :func:`_update_rows` is."""
+    zero = jnp.int32(0)
+    for i in range(blocks.shape[0]):
+        at = (rows[i], zero, start[i]) + (zero,) * (cache.ndim - 3)
+        cache = jax.lax.dynamic_update_slice(cache, blocks[i][None], at)
+    return cache
+
+
+def put_blocks(kc, vc, k, v, rows, start, count, tile, extras, wrap=None,
+               aligned=True):
+    """A tiled prefill chunk's fresh ``k`` / ``v`` ``[G * tile, H, D]`` into
+    the caches ``[R + 1, H, S, D]``: tile ``g`` to row ``rows[g]`` from seq
+    index ``start[g]`` on, its first ``count[g]`` rows as they are and its
+    tail pads as zeros.  Returns the two caches.
+
+    The callers' contract (``PrefillBatchConfig``; every tiled caller states
+    it): a tile is one request's, starts on a multiple of ``tile``, and ``S``
+    is whole tiles — a block never wraps or clamps.  A block write and not a
+    scatter, because a chunk carries more than ``DUS_MAX_TOKENS`` rows and
+    the scatter's layout choice forces a relayout copy of the whole cache per
+    prefill-scan step (as :meth:`_scatter_rows_pos` says of the decode scan).
+
+    ONE aliased Pallas call writes all tiles of both caches
+    (``ops/pallas/attention.py`` ``kv_block_write``) where the kernels are on
+    (``extras["pallas_decode"]``), a head is whole lanes (narrower, the TPU
+    compiler re-lays the caches out around the call; the interpreter has no
+    lanes) and the starts are ``aligned`` (a caller whose starts are whole
+    tiles only at some sizes says at which: the kernel addresses the cache in
+    tiles); else
+    the chain of one ``dynamic_update_slice`` per tile and cache it replaced
+    (17 us a 1 MB slice on the v5e, a tenth of the memory's pace).  The path
+    taken is recorded in ``extras["attention_paths"]``.  ``wrap`` places the
+    kernel under a caller's ``shard_map`` over the head axis.
+    """
+    from ..ops.pallas.attention import kv_block_write
+
+    interp = bool(extras.get("pallas_interpret"))
+    kernel = bool(extras.get("pallas_decode")) and aligned and (
+        interp or kc.shape[-1] % 128 == 0)
+    paths = extras.get("attention_paths")
+    if paths is not None:
+        paths[("kv_block_write", PrefillBatchConfig.__name__)] = \
+            "pallas" if kernel else "dus_chain"
+    if not kernel:
+        return (_block_chain(kc, _tile_blocks(k, count, tile, kc.dtype),
+                             rows, start),
+                _block_chain(vc, _tile_blocks(v, count, tile, vc.dtype),
+                             rows, start))
+    write = functools.partial(kv_block_write, tile=tile, interpret=interp)
+    return (wrap or (lambda f: f))(write)(kc, vc, k, v, rows, start, count)
 
 
 def alibi_slopes(num_heads: int) -> jax.Array:
@@ -732,70 +812,48 @@ class IncMultiHeadSelfAttention(Op):
         )
         if sm is None:  # unsupported sharding off the chip: gather oracle
             return self._inc_attend(q, k, v, state, base, ctx)
-        # tile row: real slots sit at the tile head, pads map to the scratch
-        # row nreq (the largest index), so min() recovers the tile's request
-        tile_rows = jnp.min(rows.reshape(g, bq), axis=1)
-        pstart = pos.reshape(g, bq)[:, 0]
+        tile_rows, pstart, count = tile_coords(rows, pos, bq, nreq)
         with jax.named_scope("kv_write"):
             if pages is not None:
-                # physical coordinates for the per-tile block DUS: a tile sits
+                # physical coordinates for the per-tile block write: a tile sits
                 # inside ONE page (tile-aligned start, tile divides page — the
                 # manager validates page % prefill_tile == 0), so translating
                 # the tile's start translates the whole block
                 w_rows, w_start = _page_rows_pos(pages, tile_rows, pstart)
             else:
                 w_rows, w_start = tile_rows, pstart
-            # KV-cache write as G per-tile BLOCK dynamic-update-slices instead of
-            # a flat-token scatter: a prefill chunk carries max_tokens (>
-            # DUS_MAX_TOKENS) tokens, so _scatter_rows_pos would take the XLA
-            # scatter path — whose layout choice forces a full-cache relayout
-            # copy per prefill_scan step (the same hazard _scatter_rows_pos
-            # documents for the decode scan, ~2x the chunk's whole HBM traffic
-            # at the 7B bench shape).  PrefillBatchConfig's contract makes the
+            # the chunk's K/V go in as one BLOCK per tile (put_blocks says
+            # why not a scatter).  PrefillBatchConfig's contract makes the
             # block write exact for real tokens: tile g is one request, its
             # positions contiguous from a TILE-ALIGNED pstart (RequestManager
             # only advances prefill_offset by whole tiles until completion), so
-            # the DUS start is never clamp-shifted.  Tail-pad slots write ZEROS
-            # at the request's next positions (junk-free: fresh caches are
-            # zeros, so the tiled and flat paths stay bit-identical); even a
-            # non-zero value there would be benign, since every future step
-            # WRITES position p before any token's causal frontier reaches p
-            # (the scratch-row behavior of fully-pad tiles is unchanged: min()
-            # maps them to row nreq).
+            # a block never clamps.  Tail-pad slots write ZEROS at the
+            # request's next positions (junk-free: fresh caches are zeros, so
+            # the tiled and flat paths stay bit-identical); even a non-zero
+            # value there would be benign, since every future step WRITES
+            # position p before any token's causal frontier reaches p.  A
+            # fully-pad tile writes zeros to the scratch row.
             if kv_q:
-                # quantize-on-write: the int8 VALUES ride the same per-tile
-                # block DUS as the fp path; the per-(token, head) scales ride a
-                # matching [1, KV, bq] block DUS into the scale caches.  Tile
-                # pads write value 0 AND scale 0, so they dequantize to the
-                # zeros the fp path writes (the tiled/flat bit-identity note
-                # above carries over to the quantized representation).
+                # quantize-on-write: the int8 VALUES ride the same block
+                # write as the fp path; the per-(token, head) scales ride a
+                # [1, KV, bq] block dynamic-update-slice per tile into the
+                # scale planes (16 KB a tile).  Tile pads write value 0 AND
+                # scale 0, so they dequantize to the zeros the fp path writes
+                # (the tiled/flat bit-identity note above carries over to the
+                # quantized representation).
                 k, ks = self._kv_quant(k)   # int8 [T, KV, D], f32 [T, KV]
                 v, vs = self._kv_quant(v)
-                ksc, vsc = state["k_scale"], state["v_scale"]  # [R+1, KV, S]
-                valid_s = (base.request_index >= 0).reshape(g, 1, bq)
-                ksb = jnp.where(
-                    valid_s, ks.reshape(g, bq, self.num_kv_heads)
-                    .transpose(0, 2, 1), 0.0)
-                vsb = jnp.where(
-                    valid_s, vs.reshape(g, bq, self.num_kv_heads)
-                    .transpose(0, 2, 1), 0.0)
-            valid = (base.request_index >= 0).reshape(g, 1, bq, 1)
-            kb = k.reshape(g, bq, self.num_kv_heads, self.head_dim) \
-                 .transpose(0, 2, 1, 3).astype(kc.dtype)
-            vb = v.reshape(g, bq, self.num_kv_heads, self.head_dim) \
-                 .transpose(0, 2, 1, 3).astype(vc.dtype)
-            kb = jnp.where(valid, kb, 0)
-            vb = jnp.where(valid, vb, 0)
-            zero = jnp.int32(0)
-            for i in range(g):
-                at = (w_rows[i], zero, w_start[i], zero)
-                kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
-                vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
-                if kv_q:
-                    ksc = jax.lax.dynamic_update_slice(
-                        ksc, ksb[i][None], at[:3])
-                    vsc = jax.lax.dynamic_update_slice(
-                        vsc, vsb[i][None], at[:3])
+                ksc = _block_chain(
+                    state["k_scale"],                        # [R+1, KV, S]
+                    _tile_blocks(ks, count, bq, jnp.float32), w_rows, w_start)
+                vsc = _block_chain(
+                    state["v_scale"],
+                    _tile_blocks(vs, count, bq, jnp.float32), w_rows, w_start)
+            kv_sm = self._head_shard_map(
+                ctx, h, [P(None, h)] * 4 + [P()] * 3,
+                (P(None, h), P(None, h)), "prefill K/V block write")
+            kc, vc = put_blocks(kc, vc, k, v, w_rows, w_start, count, bq,
+                                ctx.extras, wrap=kv_sm)
         scales = (ksc, vsc) if kv_q else ()
         pg = (pages.table,) if pages is not None else ()
         pg_size = pages.page_size if pages is not None else 0

@@ -254,6 +254,11 @@ def test_the_harness_drive_is_correct(use_pallas):
     ok, _ = check.run_check(im, ref, HF, sw.base_key(SEED), "float32", 77,
                             HF["vocab_size"], LIMITS, lines.append)
     assert ok, "\n".join(lines)
+    # the prompt chunk's block write is counted where the tiled path runs;
+    # this toy's windows open 8 entries apart, not a whole tile: the chain
+    write = im.attention_paths.pop(("kv_block_write", "PrefillBatchConfig"),
+                                   None)
+    assert write == ("dus_chain" if use_pallas else None)
     assert {k for k, _ in im.attention_paths} == {"eva_attention"}
     if use_pallas:
         assert im.attention_paths[

@@ -560,6 +560,8 @@ def test_the_harness_drives_are_correct(use_pallas):
     assert ok, "\n".join(lines)
     assert reading[0] == 1.0 and reading[2] == 2 * 2 * 68
     paths = im.attention_paths
+    assert paths.pop(("kv_block_write", "PrefillBatchConfig"), None) == (
+        "pallas" if use_pallas else None)
     assert {k for k, _ in paths} == {"sparse_block_attention",
                                      "lightning_attention", "block_select"}
     assert {b: p for (k, b), p in paths.items() if k == "block_select"} == {

@@ -277,6 +277,8 @@ def test_the_harness_drive_is_correct(use_pallas):
                             HF["vocab_size"], LIMITS, lines.append)
     assert ok, "\n".join(lines)
     paths = im.attention_paths
+    assert paths.pop(("kv_block_write", "PrefillBatchConfig"), None) == (
+        "pallas" if use_pallas else None)
     assert {k for k, _ in paths} == {"mamba2_scan", "moe_experts"}
     assert paths[("mamba2_scan", "PrefillBatchConfig")] == "chunked"
 

@@ -239,3 +239,143 @@ def test_e2e_decode_with_pallas_kernel():
     got = rm.generate([prompt], max_new_tokens=8)[0]
     want = ref_greedy_decode(im.params, TINY, prompt, 8)
     assert got == want
+
+
+# ---- the prefill chunk's block write (kv_block_write) -------------------
+def _chain_reference(kc, vc, k, v, rows, start, count, tile):
+    """The chain ``kv_block_write`` replaced, as ``ops._prefill_attend`` and
+    ``hybrid_ops._put_blocks`` held it: the chunk re-laid out head-major, cast
+    to the cache's type, tail pads zeroed, one ``dynamic_update_slice`` per
+    tile and cache."""
+    g = k.shape[0] // tile
+    valid = (jnp.arange(tile)[None, :] < count[:, None]).reshape(
+        g, 1, tile, 1)
+    block = lambda a, dt: jnp.where(
+        valid, a.reshape((g, tile) + a.shape[1:]).transpose(0, 2, 1, 3)
+        .astype(dt), 0)
+    kb, vb = block(k, kc.dtype), block(v, vc.dtype)
+    zero = jnp.int32(0)
+    for i in range(g):
+        at = (rows[i], zero, start[i], zero)
+        kc = jax.lax.dynamic_update_slice(kc, kb[i][None], at)
+        vc = jax.lax.dynamic_update_slice(vc, vb[i][None], at)
+    return kc, vc
+
+
+def _block_write_case(kv, d, tile, cache_dt, fresh_dt, slots=3, seed=0):
+    """Caches that hold something everywhere, and a chunk of four tiles: two
+    of one request (the second tail-padded), one of another request, one
+    fully pad (scratch row, count 0)."""
+    rng = np.random.default_rng(seed)
+    s = 4 * tile
+
+    def draw(shape, dt):
+        if jnp.dtype(dt) == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        return jnp.asarray(rng.normal(size=shape), dt)
+
+    kc, vc = (draw((slots + 1, kv, s, d), cache_dt) for _ in range(2))
+    k, v = (draw((4 * tile, kv, d), fresh_dt) for _ in range(2))
+    rows = jnp.asarray([1, 1, 0, slots], jnp.int32)
+    start = jnp.asarray([tile, 2 * tile, 3 * tile, 0], jnp.int32)
+    count = jnp.asarray([tile, tile - 5, tile, 0], jnp.int32)
+    return kc, vc, k, v, rows, start, count
+
+
+@pytest.mark.parametrize("kv,d,tile,cache_dt,fresh_dt", [
+    (32, 128, 128, jnp.bfloat16, jnp.bfloat16),   # OPT-6.7B
+    (1, 128, 128, jnp.bfloat16, jnp.bfloat16),    # StarCoderBase (MQA)
+    (10, 128, 128, jnp.bfloat16, jnp.bfloat16),   # phi-4-mini-flash: pairs
+    (32, 128, 128, jnp.int8, jnp.int8),           # int8 values
+    (2, 128, 32, jnp.bfloat16, jnp.float32),      # the cast to the cache's
+    (2, 16, 8, jnp.float32, jnp.float32),         # the CPU tests' toy widths
+], ids=["opt", "starcoder_mqa", "phi4_pairs", "int8", "cast", "toy"])
+def test_block_write_equals_the_chain(kv, d, tile, cache_dt, fresh_dt):
+    """ONE aliased call writes what the chain of per-tile
+    dynamic-update-slices wrote, bit for bit: same values, same zeros for
+    tail pads, same positions, the rest of both caches as it was."""
+    from flexflow_tpu.ops.pallas.attention import kv_block_write
+
+    kc, vc, k, v, rows, start, count = _block_write_case(
+        kv, d, tile, cache_dt, fresh_dt)
+    want_k, want_v = _chain_reference(kc, vc, k, v, rows, start, count, tile)
+    got_k, got_v = kv_block_write(kc, vc, k, v, rows, start, count,
+                                  tile=tile, interpret=True)
+    assert got_k.dtype == kc.dtype and got_v.dtype == vc.dtype
+    as_bits = lambda a: np.asarray(a).view(
+        {1: np.uint8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+    np.testing.assert_array_equal(as_bits(got_k), as_bits(want_k))
+    np.testing.assert_array_equal(as_bits(got_v), as_bits(want_v))
+    # the write landed: the tail-padded tile holds its rows, then zeros
+    tail = np.asarray(got_k[1, :, 2 * tile:3 * tile].astype(jnp.float32))
+    assert np.any(tail[:, :tile - 5] != 0) and not np.any(tail[:, tile - 5:])
+    assert not np.any(np.asarray(got_v[-1, :, :tile].astype(jnp.float32)))
+
+
+def test_block_write_lowers_to_one_call_that_aliases_both_caches():
+    """Lowered for the TPU (no chip needed to LOWER): one Mosaic call whose
+    two outputs are the two cache operands — XLA updates the caches where
+    they lie; a cache-sized copy would be an operand that is not aliased."""
+    import functools
+    import re
+
+    from flexflow_tpu.ops.pallas.attention import kv_block_write
+
+    kc, vc, k, v, rows, start, count = (
+        jax.ShapeDtypeStruct(a.shape, a.dtype) for a in _block_write_case(
+            32, 128, 128, jnp.bfloat16, jnp.bfloat16))
+    text = jax.jit(functools.partial(kv_block_write, tile=128)).trace(
+        kc, vc, k, v, rows, start, count).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call.*", text)
+    assert len(calls) == 1
+    aliases = re.findall(
+        r"output_tuple_indices = \[(\d*)\], operand_index = (\d+)", calls[0])
+    # operands: rows, blocks, counts, k, v, K cache, V cache
+    assert sorted(aliases) == [("0", "5"), ("1", "6")], calls[0][:400]
+    assert "dynamic_update_slice" not in text
+
+
+@pytest.mark.parametrize("why,extras,d,aligned,want", [
+    ("kernels_on", {"pallas_decode": True, "pallas_interpret": True}, 16,
+     True, "pallas"),
+    ("kernels_off", {}, 128, True, "dus_chain"),
+    ("head_not_whole_lanes", {"pallas_decode": True}, 64, True, "dus_chain"),
+    ("starts_not_whole_tiles",
+     {"pallas_decode": True, "pallas_interpret": True}, 16, False,
+     "dus_chain"),
+])
+def test_block_write_path_is_chosen_by_what_is_observed(why, extras, d,
+                                                        aligned, want):
+    """``put_blocks`` takes the kernel where the kernels are on and the
+    shapes and starts suit it, the chain otherwise — same caches either way — and
+    says which in ``attention_paths``."""
+    from flexflow_tpu.serve.ops import put_blocks
+
+    kc, vc, k, v, rows, start, count = _block_write_case(
+        2, d, 8, jnp.float32, jnp.float32)
+    paths = {}
+    got = put_blocks(kc, vc, k, v, rows, start, count, 8,
+                     dict(extras, attention_paths=paths), aligned=aligned)
+    assert paths == {("kv_block_write", "PrefillBatchConfig"): want}
+    for a, b in zip(got, _chain_reference(kc, vc, k, v, rows, start, count,
+                                          8)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("use_pallas", [True, False], ids=["pallas", "xla"])
+def test_tiled_prefill_counts_its_block_write(use_pallas):
+    """A tiled prompt chunk through the manager: with the kernels on the
+    program's block write is the Pallas call and is counted as such; without
+    them the chunk is a flat batch and writes no block at all."""
+    from flexflow_tpu.serve.batch_config import PrefillBatchConfig
+
+    im = make_im(max_tokens=8, max_requests=2, max_seq=32,
+                 use_pallas=use_pallas)
+    pbc, _ = PrefillBatchConfig.build(
+        [(0, [5, 9, 2, 11, 3], 0)], [5], max(im.prefill_tile, 4),
+        max_tokens=8, max_requests=2)
+    im.step(pbc)
+    assert im.attention_paths.get(
+        ("kv_block_write", "PrefillBatchConfig")) == (
+        "pallas" if use_pallas else None)

@@ -264,6 +264,9 @@ def test_the_harness_drive_is_correct(use_pallas):
     ok, numbers = check.run_check(im, ref, HF, sw.base_key(SEED), "float32",
                                   77, HF["vocab_size"], LIMITS, lines.append)
     assert ok, "\n".join(lines)
+    assert im.attention_paths.pop(
+        ("kv_block_write", "PrefillBatchConfig"), None) == (
+        "pallas" if use_pallas else None)
     kinds = {k for k, _ in im.attention_paths}
     assert kinds == {"window_attention", "full_attention", "cross_attention",
                      "selective_scan"}

@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from flexflow_tpu.ops.pallas.attention import (
     decode_attention,
+    kv_block_write,
     prefill_attention,
     sparse_decode_attention,
     tree_attention,
@@ -160,6 +161,45 @@ def test_sparse_decode_kernel_compiles_for_v5e(one_chip, rows):
         sds((rows,), jnp.int32), sds((rows, 2, 128), jnp.int32),
         sds((rows, 2), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# (KV, D, S, slots, cache type, fresh type): a 512-row chunk in tiles of 128
+_WRITE_CASES = {
+    "opt": (32, 128, 2048, 16, jnp.bfloat16, jnp.bfloat16),
+    "starcoder_mqa": (1, 128, 8192, 16, jnp.bfloat16, jnp.bfloat16),
+    "phi4_pairs": (10, 128, 8192, 16, jnp.bfloat16, jnp.bfloat16),
+    "int8": (32, 128, 2048, 16, jnp.int8, jnp.int8),
+    "sala_kv2": (2, 128, 32768, 48, jnp.bfloat16, jnp.bfloat16),
+    "cast": (8, 128, 2048, 16, jnp.bfloat16, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_WRITE_CASES))
+def test_block_write_kernel_updates_the_caches_in_place(one_chip, case):
+    """``kv_block_write`` at the widths the cells run: the compiler tiles
+    it, and with the caches donated (the prefill scan's carry) the compiled
+    program holds NO temporary — both caches are written where they lie."""
+    kv, d, s, slots, cache_dt, fresh_dt = _WRITE_CASES[case]
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    cache, fresh = sds((slots + 1, kv, s, d), cache_dt), \
+        sds((kv, 512, d), fresh_dt)
+    tiles = sds((512 // TILE,), jnp.int32)
+
+    def write(kc, vc, k, v, rows, start, count):
+        # the fresh rows as the fused QKV projection leaves them: heads first
+        return kv_block_write(kc, vc, jnp.swapaxes(k, 0, 1),
+                              jnp.swapaxes(v, 0, 1), rows, start, count,
+                              tile=TILE)
+
+    compiled = jax.jit(write, donate_argnums=(0, 1)).lower(
+        cache, cache, fresh, fresh, tiles, tiles, tiles).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    assert "dynamic-update-slice" not in text and " copy(" not in text
+    mem = compiled.memory_analysis()
+    cache_bytes = (slots + 1) * kv * s * d * jnp.dtype(cache_dt).itemsize
+    assert mem.alias_size_in_bytes == 2 * cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 64
 
 
 @pytest.mark.parametrize("path", ["slot_order", "gathered"])
