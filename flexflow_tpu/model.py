@@ -396,11 +396,11 @@ class FFModel:
         return self._add(op, [y, z], name or "gated_group_norm")[0]
 
     def moe_router(self, x, num_experts, top_k, scaling=1.0, norm_topk=True,
-                   name=None):
+                   bias=True, name=None):
         from .serve.ssd_moe_ops import MoERouter
 
         op = MoERouter(x.shape[-1], num_experts, top_k, scaling, norm_topk,
-                       dtype=x.dtype)
+                       dtype=x.dtype, bias=bias)
         return self._add(op, [x], name or "moe_router")
 
     def moe_dispatch(self, x, ids, num_held, held_lo=0, name=None):
@@ -409,11 +409,30 @@ class FFModel:
         return self._add(MoEDispatch(num_held, held_lo), [x, ids],
                          name or "moe_dispatch")
 
-    def moe_experts(self, xs, sizes, num_held, width, name=None):
+    def moe_experts(self, xs, sizes, num_held, width, form="relu2",
+                    name=None):
         from .serve.ssd_moe_ops import MoEExperts
 
-        op = MoEExperts(num_held, xs.shape[-1], width, dtype=xs.dtype)
+        op = MoEExperts(num_held, xs.shape[-1], width, dtype=xs.dtype,
+                        form=form)
         return self._add(op, [xs, sizes], name or "moe_experts")[0]
+
+    def shared_expert_dense(self, x, out_dim, name=None):
+        from .serve.ssd_moe_ops import SharedExpertLinear
+
+        op = SharedExpertLinear(out_dim, None, False, dtype=x.dtype)
+        return self._add(op, [x], name or "shared_expert_dense")[0]
+
+    def sliding_window_attention(self, x, embed_dim, num_q_heads,
+                                 num_kv_heads, head_dim, window,
+                                 rope_theta=10000.0, rope_interleaved=False,
+                                 name=None):
+        from .serve.hybrid_ops import SlidingWindowAttention
+
+        op = SlidingWindowAttention(embed_dim, num_q_heads, num_kv_heads,
+                                    head_dim, window, rope_theta,
+                                    rope_interleaved, dtype=x.dtype)
+        return self._add(op, [x], name or "sliding_window_attention")[0]
 
     def moe_combine(self, ys, order, ids, weights, num_held, held_lo=0,
                     dtype=None, name=None):

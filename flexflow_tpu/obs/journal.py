@@ -78,6 +78,9 @@ FIELDS = (
     # got a row, pairs on held experts, the fullest expert's pairs — summed
     # over scan steps and routed layers — and steps x layers
     "experts_visited", "expert_pairs", "expert_pairs_max", "expert_steps",
+    # beside ``ctx_sum``, of the same launch: the positions its rows' plain
+    # RING layers read, sum of min(context, window) (0: a graph without)
+    "ring_ctx_sum",
 )
 _F = {name: i for i, name in enumerate(FIELDS)}
 _SPLIT_AT = {n: _F[f"{n}_ns"] for n in SPLIT}
@@ -86,7 +89,7 @@ _KIND_AT = {n: i for i, n in enumerate(KINDS)}
 # fields a folded idle record does not add up
 _SUMMED = [i for i, n in enumerate(FIELDS)
            if n not in ("seq", "t0_ns", "t1_ns", "tick_ns", "kind", "pending",
-                        "live", "ctx_sum", "ctx_rows")]
+                        "live", "ctx_sum", "ctx_rows", "ring_ctx_sum")]
 (_SEQ, _T0, _T1, _TICK, _KIND, _POLLS, _UNATT, _PENDING, _LIVE, _CPU, _NIV,
  _MAJ) = (_F[n] for n in (
      "seq", "t0_ns", "t1_ns", "tick_ns", "kind", "polls", "unattributed_ns",
@@ -134,11 +137,12 @@ def _launch_join(row, args, chunk_width):
 def _first_ctx(row, args):
     """``ctx_sum`` / ``ctx_rows``: the KV lengths and the rows of the
     tick's FIRST launch that decodes (their ratio is the depth the tick
-    ran at)."""
+    ran at); ``ring_ctx_sum``: what that launch's ring layers read."""
     rows = args.get("rows", 0)
     if rows and not row[_F["ctx_rows"]]:
         row[_F["ctx_rows"]] = rows
         row[_F["ctx_sum"]] = args.get("ctx_sum", 0)
+        row[_F["ring_ctx_sum"]] = args.get("ring_ctx_sum", 0)
 
 
 _LAUNCH = {"step_dispatch": _launch_step,
@@ -219,7 +223,7 @@ class TickJournal:
     counts them.  4096 holds twenty runs of the benchmark's shortest-tick
     cell (``opt-6.7b-d12.decode-heavy``: 189 records for warm-up,
     rehearsal and the 51 s window, 165 of them the window's; my chip
-    runs, PR 46), as lists of 51 integers.
+    runs, PR 46), as lists of 52 integers.
     ``chunk_width``: rows of one prefill-scan chunk
     (``im.max_tokens``; ``chunk_rows`` = chunks x this).
     ``clock_ns``: the journal's clock, and the tick spans' ``pc_ns``

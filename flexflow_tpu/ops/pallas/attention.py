@@ -279,8 +279,9 @@ def decode_attention(
             raise ValueError("a ring cache is slot-contiguous, fp, and has "
                              "no positional bias")
         # finer blocks than a full cache's: a window of 512 in a ring of
-        # 1024 touches 3 blocks of 256 but all of 2 blocks of 512
-        block_s = min(block_s, 256)
+        # 1024 touches 3 blocks of 256 but all of 2 blocks of 512; a window
+        # of 4096 keeps the full cache's (an eighth of it)
+        block_s = min(block_s, max(256, window // 8))
     # cap the block so K+V (+ scale) double-buffered blocks fit the budget
     block_s = _fit_block_s(block_s, s_len, num_kv, d,
                            jnp.dtype(k_cache.dtype).itemsize, kv_quant,
@@ -762,6 +763,8 @@ def _prefill_kernel(
     scale: float,
     kv_quant: bool,
     paged: bool = False,
+    window: int = 0,
+    s_len: int = 0,
 ):
     if paged:
         refs = refs[1:]  # page table: index-map-only prefetch operand
@@ -787,8 +790,17 @@ def _prefill_kernel(
     fmax = fmax_ref[g]
     pstart = pstart_ref[g]
     base = s * block_s
+    if window:
+        # a ring: worth computing if the block holds a slot that SOME query
+        # of the tile sees — the tile's queries together see the
+        # ``window + tile - 1`` newest positions at ``fmax`` (the others'
+        # DMA was skipped by the index map)
+        run = _ring_blocks(fmax, block_s, s_len,
+                           window + m_rows // gq - 1)[0](s)
+    else:
+        run = base <= fmax  # blocks past the frontier: DMA already clamped
 
-    @pl.when(base <= fmax)  # blocks past the frontier: DMA already clamped
+    @pl.when(run)
     def _compute():
         q = q_ref[0].astype(jnp.float32)               # [KV, M, D]
         k = k_ref[0].astype(jnp.float32)               # [KV, Bs, D]
@@ -807,7 +819,17 @@ def _prefill_kernel(
         key_pos = base + jax.lax.broadcasted_iota(
             jnp.int32, (m_rows, block_s), 1
         )
-        live = jnp.broadcast_to((key_pos <= qpos)[None], sc.shape)
+        if window:
+            # ``key_pos`` is a ring SLOT: it holds the position ``age`` back
+            # from the query's, seen if that is one of its min(qpos + 1,
+            # window) newest (a later tile's keys, written before any tile
+            # attends, lie a ring less a chunk back: never seen)
+            age = qpos % s_len - key_pos
+            age = jnp.where(age < 0, age + s_len, age)
+            seen = age < jnp.minimum(qpos + 1, window)
+        else:
+            seen = key_pos <= qpos
+        live = jnp.broadcast_to(seen[None], sc.shape)
         sc = jnp.where(live, sc, NEG_INF)
 
         m_prev = m_ref[:, :, 0:1]                       # [KV, M, 1]
@@ -833,7 +855,7 @@ def _prefill_kernel(
 
 @functools.partial(
     jax.jit, static_argnames=("scale", "block_s", "kv_chunk", "interpret",
-                              "page_size")
+                              "page_size", "window")
 )
 def prefill_attention(
     q: jax.Array,        # [G, Bq, QH, D] tile queries (RoPE applied)
@@ -849,6 +871,7 @@ def prefill_attention(
     v_scale: Optional[jax.Array] = None,  # scales (None = fp cache)
     page_table: Optional[jax.Array] = None,  # i32[R+1, S//page_size]
     page_size: int = 0,                      # static; 0 = slot-contiguous
+    window: int = 0,     # static; > 0: the cache is a RING (see below)
 ) -> jax.Array:
     """Q-tiled prefill attention (the prompt phase of the reference's IncMHA).
 
@@ -868,6 +891,17 @@ def prefill_attention(
     admits a WIDER Q tile — at the 7B shape tile 128 with kv_chunk 16 and
     256-position seq blocks, vs the old unchunked ceiling of tile 64 with
     128-position blocks: half the grid rows AND 2x the bytes per DMA wait.
+
+    ``window > 0``: a sliding-window layer whose cache is a RING, as
+    :func:`decode_attention`'s — position ``p`` at slot ``p % S``, ``S`` at
+    least the window plus the widest chunk (the whole chunk is written
+    before any tile attends, so a tile's oldest key must not lie under a
+    later tile's newest) — and the query at ``p`` sees the ``min(p + 1,
+    window)`` newest positions.  The window's LOWER bound is in the kernel:
+    a block that holds no slot any query of the tile sees — wholly before
+    ``pstart - window + 1``, or past the tile's end — is neither fetched
+    (the index map sends it to one that is) nor computed, so a tile of a
+    long prompt reads ``window + tile`` positions and not its whole prefix.
     """
     g, bq, qh, d = q.shape
     _, num_kv, s_len, _ = k_cache.shape
@@ -875,6 +909,8 @@ def prefill_attention(
     m_rows = bq * gq
     kv_quant = k_scale is not None
     paged = page_table is not None
+    if window and (paged or kv_quant):
+        raise ValueError("a ring cache is slot-contiguous and fp")
     if kv_chunk is not None and num_kv % kv_chunk:  # forced chunk (tests)
         raise ValueError(f"kv_chunk {kv_chunk} must divide KV {num_kv}")
     kv_chunk, block_s = _prefill_plan(
@@ -888,7 +924,9 @@ def prefill_attention(
     # fold tiles into the query-group dim, b-major: row = b*gq + g'
     qr = q.reshape(g, bq, num_kv, gq, d).transpose(0, 2, 1, 3, 4) \
          .reshape(g, num_kv, m_rows, d)
-    fmax = jnp.clip(pstart + bq - 1, 0, s_len - 1)
+    # a ring's positions are logical: they pass its length and never clamp
+    fmax = (pstart + bq - 1).astype(jnp.int32) if window \
+        else jnp.clip(pstart + bq - 1, 0, s_len - 1)
 
     if paged:
         ppr = s_len // page_size
@@ -901,6 +939,15 @@ def prefill_attention(
 
         prefetch = (rows.astype(jnp.int32), pstart.astype(jnp.int32), fmax,
                     page_table.astype(jnp.int32))
+    elif window:
+        def kv_map(i, kc, j, rows, pstart, fmax):
+            # as decode_attention's ring map, for the tile's whole run
+            needed, first, newest = _ring_blocks(fmax[i], block_s, s_len,
+                                                 window + bq - 1)
+            skip_to = jnp.where(j > newest, newest, first)
+            return (rows[i], kc, jnp.where(needed(j), j, skip_to), 0)
+
+        prefetch = (rows.astype(jnp.int32), pstart.astype(jnp.int32), fmax)
     else:
         def kv_map(i, kc, j, rows, pstart, fmax):
             return (rows[i], kc, jnp.minimum(j, fmax[i] // block_s), 0)
@@ -941,6 +988,7 @@ def prefill_attention(
         _prefill_kernel,
         block_s=block_s, num_kv=kv_chunk, gq=gq, m_rows=m_rows,
         scale=float(scale), kv_quant=kv_quant, paged=paged,
+        window=window, s_len=s_len,
     )
     out = pl.pallas_call(
         kernel,

@@ -66,7 +66,8 @@ def _local_size(spec, sh, mesh) -> int:
 # shape ops, reductions) is glue XLA fuses into its neighbors: it adds VPU
 # flops but no extra HBM round trips.
 HEAVY_OPS = frozenset({
-    "linear", "batch_matmul", "conv2d", "embedding", "experts",
+    "linear", "shared_expert_linear", "batch_matmul", "conv2d", "embedding",
+    "experts",
     "multihead_attention", "inc_multihead_self_attention",
     "spec_inc_multihead_self_attention", "tree_inc_multihead_self_attention",
     "group_by", "aggregate", "aggregate_spec",

@@ -13,6 +13,10 @@ beside ``IncMultiHeadSelfAttention``:
   kernels' tile; independent of ``max_seq_len``), ``full`` owns an ordinary
   full-length cache, and ``cross`` projects queries only and reads the cache
   a ``full`` node (its ``state_owner``) wrote earlier in the same step.
+* :class:`SlidingWindowAttention` — PLAIN grouped-query attention with
+  rotary over a sliding window (``cohere2_moe``'s sliding layers), its
+  cache the same ring: :class:`SlotCacheAttention` is the one ring (and
+  full-length, and borrowed) cache implementation both kinds share.
 * :class:`EvaAttention` — EVA attention (EvaByte): exact attention inside
   the query's own window, one summary per chunk of every earlier window, one
   softmax over both.  Its cache COMPACTS itself: when a window closes, its
@@ -52,8 +56,9 @@ from ..core.op import Op, OpContext, register_op
 from ..core.sharding import TensorSharding
 from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .ops import (DUS_MAX_TOKENS, NEG_INF, IncMultiHeadSelfAttention,
-                  apply_rope, put_blocks, tile_coords)
+from .ops import (DUS_MAX_TOKENS, NEG_INF, SCAN_DUS_MAX_ROWS,
+                  IncMultiHeadSelfAttention, apply_rope, put_blocks,
+                  tile_coords)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
@@ -319,7 +324,186 @@ def diff_lambda_init(layer: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * layer)
 
 
-class DiffAttention(_SlotStateOp):
+class SlotCacheAttention(_SlotStateOp):
+    """What the attention ops that keep their K/V per slot share: the cache
+    itself — a full-length plane (``mode`` ``full``: ``k``/``v`` ``[rows,
+    heads, max_seq, D]``), a RING of the last positions (``window``:
+    ``wk``/``wv`` of ``ring_len`` slots, position ``p`` at slot ``p %
+    ring_len``; a query at ``t`` sees ``t - window + 1 .. t``) or another
+    node's (``cross``: queries only, over what ``state_owner`` wrote) —,
+    its write, the attention over it by the kernels or by XLA, and the
+    skeleton of ``lower`` (``qkv_proj``, ``attend`` with ``kv_write`` inside
+    it, the subclass's ``_combine``, ``o_proj``).  ONE ring implementation:
+    the differential window layers (:class:`WindowDiffAttention`) and the
+    plain ones (:class:`SlidingWindowAttention`) are both this.
+
+    A subclass says how many heads the cache holds (``cache_heads``), how
+    many kernel query heads read each (``q_per_cache_head``) and their size
+    (``cache_dim``), projects (``_project`` -> ``q [T, cache_heads,
+    q_per_cache_head, cache_dim]``, ``k``/``v`` ``[T, cache_heads,
+    cache_dim]``) and turns the kernels' output into ``o_proj``'s input
+    (``_combine``)."""
+
+    mode = "full"
+    # a window layer's prompt tiles through ``prefill_attention`` with the
+    # window's lower bound in the kernel; off: XLA per tile (the
+    # differential window layers', whose programs PR 50 left as they were)
+    window_prefill_kernel = False
+
+    @property
+    def path_kind(self) -> str:
+        """The op's key in ``attention_paths``."""
+        return f"{self.mode}_attention"
+
+    # ---- state ---------------------------------------------------------
+    def ring_len(self, max_seq_len: int) -> int:
+        """Slots of a window layer's ring: the window plus the widest step
+        that writes before it attends, rounded up to the kernels' granule —
+        and never more than a full-length cache would hold."""
+        pad = lambda n: -(-n // LANE) * LANE
+        widest = getattr(self, "cost_max_tokens", None) or max_seq_len
+        return min(pad(self.window + widest), pad(max_seq_len))
+
+    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
+                    head_axes=()):
+        if self.mode == "cross":
+            return {}
+        seq = max_seq_len if self.mode == "full" \
+            else self.ring_len(max_seq_len)
+        shape = (max_requests + 1, self.cache_heads, seq, self.cache_dim)
+        sh = TensorSharding.replicated(4)
+        return {n: (shape, self.dtype, sh) for n in self._state_names}
+
+    @property
+    def _state_names(self):
+        return ("wk", "wv") if self.mode == "window" else ("k", "v")
+
+    # ---- compute -------------------------------------------------------
+    @jax.named_scope("kv_write")
+    def _write(self, kc, vc, k, v, bc, seg, tiled, extras):
+        """This step's keys and values into the cache (a ring for a window
+        layer: position ``p`` at slot ``p % ring``)."""
+        base = _flat(bc)
+        ring = kc.shape[2] if self.mode == "window" else 0
+        pos = base.token_position % ring if ring else base.token_position
+        if not tiled:
+            put = IncMultiHeadSelfAttention._scatter_rows_pos
+            # the decode scan keeps the chain of in-place writes past
+            # DUS_MAX_TOKENS rows, as IncMultiHeadSelfAttention does
+            chain = (SCAN_DUS_MAX_ROWS
+                     if extras.get("one_row_per_request") else None)
+            return (put(kc, seg.rows, pos, k, chain),
+                    put(vc, seg.rows, pos, v, chain))
+        # a tiled prefill chunk: one block per request-homogeneous tile
+        # (ops.put_blocks says why not a scatter).  A tile starts
+        # tile-aligned and the ring is whole tiles, so a BLOCK never wraps
+        # (a chunk may: its tiles past the ring's end land on its first
+        # slots); its tail pads write zeros at positions no query of this
+        # chunk sees, which a later chunk overwrites before any does.
+        bq = bc.tile_size
+        return put_blocks(
+            kc, vc, k, v, *tile_coords(seg.rows, pos, bq, kc.shape[0] - 1),
+            bq, extras)
+
+    def _attend_xla(self, q, kc, vc, rows, pos):
+        """Plain attention of query groups against their slot's cache:
+        ``q [G, B, cached heads, heads on each, D]``, the group's cache row
+        ``rows [G]``, positions ``pos [G, B]``.  A flat row is a group of
+        one; a prefill tile is a group of ``tile`` rows, which reads its
+        slot's ring ONCE.  The CPU oracle of the kernels, and on the chip
+        too the prefill path of the window layers that keep
+        ``window_prefill_kernel`` off."""
+        kr, vr = kc[rows], vc[rows]                  # [G, heads, S, D]
+        s = kr.shape[2]
+        sc = jnp.einsum("gbphd,gpsd->gphbs", q, kr,
+                        preferred_element_type=jnp.float32)
+        sc = sc * self.scaling_factor
+        slot = jnp.arange(s, dtype=jnp.int32)
+        if self.mode == "window":
+            # slot s holds the newest position <= t that lands on it; it is
+            # in the window if that is fewer than min(t + 1, window) back
+            age = (pos[..., None] % s - slot) % s
+            mask = age < jnp.minimum(pos + 1, self.window)[..., None]
+        else:
+            mask = slot <= pos[..., None]
+        sc = jnp.where(mask[:, None, None], sc, NEG_INF)
+        w = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("gphbs,gpsd->gbphd", w, vr.astype(w.dtype),
+                          preferred_element_type=jnp.float32)
+
+    def _attend(self, q, kc, vc, bc, seg, ctx, tiled):
+        """``[T, cached heads, heads on each, D]``: each head's softmax over
+        its slot's cache times the cached head's value — in the kernels'
+        output type (the queries'; their accumulator is float32) or float32
+        from XLA —, and the path taken."""
+        from ..ops.pallas.attention import decode_attention, prefill_attention
+
+        base = _flat(bc)
+        t = q.shape[0]
+        nq, d = self.q_per_cache_head, self.cache_dim
+        nreq = kc.shape[0] - 1
+        pallas = bool(ctx.extras.get("pallas_decode"))
+        interp = bool(ctx.extras.get("pallas_interpret"))
+        if tiled:
+            bq = bc.tile_size
+            g = t // bq
+            rows = jnp.min(seg.rows.reshape(g, bq), axis=1)
+            pos = base.token_position.reshape(g, bq)
+            if self.mode == "window" and not self.window_prefill_kernel:
+                out = self._attend_xla(
+                    q.reshape(g, bq, self.cache_heads, nq, d), kc, vc, rows,
+                    pos)
+                return out.reshape(t, self.cache_heads, nq, d), "xla_tile"
+            out = prefill_attention(
+                q.reshape(g, bq, self.cache_heads * nq, d), kc, vc, rows,
+                pos[:, 0], scale=self.scaling_factor, interpret=interp,
+                window=self.window)
+            return out.reshape(t, self.cache_heads, nq, d), "prefill_attention"
+        if pallas:
+            # pads stream one block, not a stale row's whole prefix
+            pos = jnp.where(seg.rows == nreq, 0, base.token_position)
+            out = decode_attention(
+                q.reshape(t, self.cache_heads * nq, d), kc, vc, seg.rows, pos,
+                scale=self.scaling_factor, interpret=interp,
+                window=self.window)
+            return out.reshape(t, self.cache_heads, nq, d), "decode_attention"
+        out = self._attend_xla(q[:, None], kc, vc, seg.rows,
+                               base.token_position[:, None])
+        return out[:, 0], "xla"
+
+    def lower(self, ctx, inputs, params):
+        bc, state = _require(ctx, self.type_name)
+        x = inputs[0]
+        names = self._state_names
+        kc, vc = state[names[0]], state[names[1]]
+        seg = Segments(_flat(bc), kc.shape[0] - 1)
+        with jax.named_scope("qkv_proj"):
+            q, k, v = self._project(x, params, _flat(bc).token_position)
+        # a tiled prefill chunk takes the per-tile paths (block writes, the
+        # prefill kernel) where the kernels are on; off them it is a flat
+        # batch like any other (the CPU oracle)
+        tiled = (isinstance(bc, PrefillBatchConfig)
+                 and bool(ctx.extras.get("pallas_decode")))
+        with jax.named_scope("attend"):
+            if self.mode != "cross":
+                kc, vc = self._write(kc, vc, k, v, bc, seg, tiled,
+                                     ctx.extras)
+                ctx.extras["state_out"] = {names[0]: kc, names[1]: vc}
+            out, path = self._attend(q, kc, vc, bc, seg, ctx, tiled)
+            paths = ctx.extras.get("attention_paths")
+            if paths is not None:
+                paths[(self.path_kind, type(bc).__name__)] = path
+        o = self._combine(out, params, x)
+        with jax.named_scope("o_proj"):
+            o_w = dequant(params["o_proj"], params.get("o_proj_scale"),
+                          o.dtype)
+            y = jnp.dot(o, o_w, preferred_element_type=jnp.float32)
+            if "o_bias" in params:
+                y = y + params["o_bias"]
+            return [y.astype(self.dtype)]
+
+
+class DiffAttention(SlotCacheAttention):
     """Differential attention over flat token batches, cached per slot (the
     three modes are registered as classes of their own below, so that a
     device trace names the window layers, the cache owner and its readers
@@ -379,6 +563,11 @@ class DiffAttention(_SlotStateOp):
     def pair_dim(self) -> int:
         return 2 * self.head_dim
 
+    # what the shared cache code calls them: a K/V pair is ONE cached head
+    cache_heads = property(lambda self: self.kv_pairs)
+    q_per_cache_head = property(lambda self: self.q_per_pair)
+    cache_dim = pair_dim
+
     @property
     def _qkv_cols(self) -> int:
         cols = self.q_per_pair * self.head_dim
@@ -416,28 +605,9 @@ class DiffAttention(_SlotStateOp):
         return 2 * t * self.embed_dim * (
             self.kv_pairs * self._qkv_cols + self.num_q_heads * self.head_dim)
 
-    # ---- state ---------------------------------------------------------
-    def ring_len(self, max_seq_len: int) -> int:
-        """Slots of a window layer's ring: the window plus the widest step
-        that writes before it attends, rounded up to the kernels' granule —
-        and never more than a full-length cache would hold."""
-        pad = lambda n: -(-n // LANE) * LANE
-        widest = getattr(self, "cost_max_tokens", None) or max_seq_len
-        return min(pad(self.window + widest), pad(max_seq_len))
-
-    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
-                    head_axes=()):
-        if self.mode == "cross":
-            return {}
-        seq = max_seq_len if self.mode == "full" \
-            else self.ring_len(max_seq_len)
-        shape = (max_requests + 1, self.kv_pairs, seq, self.pair_dim)
-        sh = TensorSharding.replicated(4)
-        names = ("k", "v") if self.mode == "full" else ("wk", "wv")
-        return {n: (shape, self.dtype, sh) for n in names}
-
     # ---- compute -------------------------------------------------------
-    def _project(self, x, params):
+    def _project(self, x, params, pos):
+        del pos     # no positional term: the Mamba layers carry position
         proj = "q" if self.mode == "cross" else "qkv"
         # weight-only int8 (serve/quant.py quantises ``qkv`` and ``o_proj``)
         w = dequant(params[proj], params.get(f"{proj}_scale"), x.dtype)
@@ -458,113 +628,8 @@ class DiffAttention(_SlotStateOp):
         v = out[:, :, nq * hd + self.pair_dim:]
         return q, k, v
 
-    @jax.named_scope("kv_write")
-    def _write(self, kc, vc, k, v, bc, seg, tiled, extras):
-        """This step's keys and values into the cache (a ring for a window
-        layer: position ``p`` at slot ``p % ring``)."""
-        base = _flat(bc)
-        ring = kc.shape[2] if self.mode == "window" else 0
-        pos = base.token_position % ring if ring else base.token_position
-        if not tiled:
-            put = IncMultiHeadSelfAttention._scatter_rows_pos
-            return put(kc, seg.rows, pos, k), put(vc, seg.rows, pos, v)
-        # a tiled prefill chunk: one block per request-homogeneous tile
-        # (ops.put_blocks says why not a scatter).  A tile starts
-        # tile-aligned and the ring is whole tiles, so a block never wraps;
-        # its tail pads write zeros at positions no query of this chunk
-        # sees, which a later chunk overwrites before any does.
-        bq = bc.tile_size
-        return put_blocks(
-            kc, vc, k, v, *tile_coords(seg.rows, pos, bq, kc.shape[0] - 1),
-            bq, extras)
-
-    def _attend_xla(self, q, kc, vc, rows, pos):
-        """Plain attention of query groups against their slot's cache:
-        ``q [G, B, pairs, heads, D]``, the group's cache row ``rows [G]``,
-        positions ``pos [G, B]``.  A flat row is a group of one; a prefill
-        tile is a group of ``tile`` rows, which reads its slot's ring ONCE.
-        The CPU oracle of the kernels, and the window layers' prefill path
-        on the chip too."""
-        kr, vr = kc[rows], vc[rows]                  # [G, pairs, S, D]
-        s = kr.shape[2]
-        sc = jnp.einsum("gbphd,gpsd->gphbs", q, kr,
-                        preferred_element_type=jnp.float32)
-        sc = sc * self.scaling_factor
-        slot = jnp.arange(s, dtype=jnp.int32)
-        if self.mode == "window":
-            # slot s holds the newest position <= t that lands on it; it is
-            # in the window if that is fewer than min(t + 1, window) back
-            age = (pos[..., None] % s - slot) % s
-            mask = age < jnp.minimum(pos + 1, self.window)[..., None]
-        else:
-            mask = slot <= pos[..., None]
-        sc = jnp.where(mask[:, None, None], sc, NEG_INF)
-        w = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("gphbs,gpsd->gbphd", w, vr.astype(w.dtype),
-                          preferred_element_type=jnp.float32)
-
-    def _attend(self, q, kc, vc, bc, seg, ctx, tiled):
-        """``[T, pairs, heads, D]``: each head's softmax over its slot's
-        cache times the pair's value — in the kernels' output type (the
-        queries'; their accumulator is float32) or float32 from XLA —, and
-        the path taken.  ``lower`` subtracts the two heads of a pair in
-        float32."""
-        from ..ops.pallas.attention import decode_attention, prefill_attention
-
-        base = _flat(bc)
-        t = q.shape[0]
-        nq, d = self.q_per_pair, self.pair_dim
-        nreq = kc.shape[0] - 1
-        pallas = bool(ctx.extras.get("pallas_decode"))
-        interp = bool(ctx.extras.get("pallas_interpret"))
-        if tiled:
-            bq = bc.tile_size
-            g = t // bq
-            rows = jnp.min(seg.rows.reshape(g, bq), axis=1)
-            pos = base.token_position.reshape(g, bq)
-            if self.mode == "window":
-                out = self._attend_xla(
-                    q.reshape(g, bq, self.kv_pairs, nq, d), kc, vc, rows, pos)
-                return out.reshape(t, self.kv_pairs, nq, d), "xla_tile"
-            out = prefill_attention(
-                q.reshape(g, bq, self.kv_pairs * nq, d), kc, vc, rows,
-                pos[:, 0], scale=self.scaling_factor, interpret=interp)
-            return out.reshape(t, self.kv_pairs, nq, d), "prefill_attention"
-        if pallas:
-            # pads stream one block, not a stale row's whole prefix
-            pos = jnp.where(seg.rows == nreq, 0, base.token_position)
-            out = decode_attention(
-                q.reshape(t, self.kv_pairs * nq, d), kc, vc, seg.rows, pos,
-                scale=self.scaling_factor, interpret=interp,
-                window=self.window)
-            return out.reshape(t, self.kv_pairs, nq, d), "decode_attention"
-        out = self._attend_xla(q[:, None], kc, vc, seg.rows,
-                               base.token_position[:, None])
-        return out[:, 0], "xla"
-
-    def lower(self, ctx, inputs, params):
-        bc, state = _require(ctx, self.type_name)
-        x = inputs[0]
+    def _combine(self, out, params, x):
         t = x.shape[0]
-        names = ("wk", "wv") if self.mode == "window" else ("k", "v")
-        kc, vc = state[names[0]], state[names[1]]
-        seg = Segments(_flat(bc), kc.shape[0] - 1)
-        with jax.named_scope("qkv_proj"):
-            q, k, v = self._project(x, params)
-        # a tiled prefill chunk takes the per-tile paths (block writes, the
-        # prefill kernel) where the kernels are on; off them it is a flat
-        # batch like any other (the CPU oracle)
-        tiled = (isinstance(bc, PrefillBatchConfig)
-                 and bool(ctx.extras.get("pallas_decode")))
-        with jax.named_scope("attend"):
-            if self.mode != "cross":
-                kc, vc = self._write(kc, vc, k, v, bc, seg, tiled,
-                                     ctx.extras)
-                ctx.extras["state_out"] = {names[0]: kc, names[1]: vc}
-            out, path = self._attend(q, kc, vc, bc, seg, ctx, tiled)
-            paths = ctx.extras.get("attention_paths")
-            if paths is not None:
-                paths[(f"{self.mode}_attention", type(bc).__name__)] = path
         with jax.named_scope("diff_combine"):
             lam0 = diff_lambda_init(self.layer)
             lam = (jnp.exp(jnp.sum(params["lambda_q1"] * params["lambda_k1"]))
@@ -576,12 +641,8 @@ class DiffAttention(_SlotStateOp):
             o = o * jax.lax.rsqrt(
                 jnp.mean(o * o, axis=-1, keepdims=True) + self.eps)
             o = o * params["subln"].astype(jnp.float32) * (1.0 - lam0)
-            o = o.astype(x.dtype).reshape(t, self.num_q_heads * self.head_dim)
-        with jax.named_scope("o_proj"):
-            o_w = dequant(params["o_proj"], params.get("o_proj_scale"),
-                          o.dtype)
-            y = jnp.dot(o, o_w, preferred_element_type=jnp.float32)
-            return [(y + params["o_bias"]).astype(self.dtype)]
+            return o.astype(x.dtype).reshape(
+                t, self.num_q_heads * self.head_dim)
 
 
 @register_op
@@ -612,6 +673,84 @@ DIFF_ATTENTION = {c.mode: c for c in (FullDiffAttention, WindowDiffAttention,
                                       CrossDiffAttention)}
 
 
+@register_op
+class SlidingWindowAttention(SlotCacheAttention):
+    """PLAIN grouped-query attention over a sliding window, its cache a ring
+    (``cohere2_moe``'s ``sliding_attention`` layers): ``q = x W_q``, ``k``,
+    ``v`` likewise, no bias, rotary over the whole head — on interleaved
+    pairs ``(2i, 2i + 1)`` (``rope_interleaved``: ``rope_gptj``) or half
+    against half —, scale ``1 / sqrt(head size)``, query head ``i`` on K/V
+    head ``i // (heads per K/V head)``, key ``j`` visible to query ``t`` iff
+    ``t - window < j <= t``.  The fused projection has
+    ``IncMultiHeadSelfAttention``'s layout (``qkv [embed, K/V heads, heads
+    on each + 2, head size]``, ``o_proj``), so weight-only int8 and a
+    parameter table written for that op fit this one.
+
+    State kind ``kv_window`` (kv_allocator.py): ``ring_len`` slots a K/V
+    head and slot, whatever ``max_seq_len``.  The decode scan reads it
+    through ``decode_attention``'s ring path; a tiled prompt chunk writes it
+    by ``kv_block_write`` and reads it through ``prefill_attention`` with
+    the window's lower bound in the kernel."""
+
+    type_name = "sliding_window_attention"
+    mode = "window"
+    window_prefill_kernel = True
+    path_kind = type_name
+
+    def __init__(self, embed_dim: int, num_q_heads: int, num_kv_heads: int,
+                 head_dim: int, window: int, rope_theta: float = 10000.0,
+                 rope_interleaved: bool = False, dtype=jnp.float32):
+        if num_q_heads % num_kv_heads:
+            raise ValueError("num_q_heads must be a multiple of num_kv_heads")
+        if window <= 0:
+            raise ValueError("a sliding-window layer needs its window")
+        self.embed_dim = int(embed_dim)
+        self.num_q_heads = int(num_q_heads)
+        self.num_kv_heads = int(num_kv_heads)
+        self.head_dim = int(head_dim)
+        self.window = int(window)
+        self.rope_theta = float(rope_theta)
+        self.rope_interleaved = bool(rope_interleaved)
+        self.dtype = jnp.dtype(dtype).name
+        self.scaling_factor = 1.0 / math.sqrt(self.head_dim)
+
+    cache_heads = property(lambda self: self.num_kv_heads)
+    q_per_cache_head = property(
+        lambda self: self.num_q_heads // self.num_kv_heads)
+    cache_dim = property(lambda self: self.head_dim)
+
+    def infer_shapes(self, in_specs):
+        return [TensorSpec(in_specs[0].shape, jnp.dtype(self.dtype))]
+
+    def params(self) -> List[ParamSpec]:
+        dt = jnp.dtype(self.dtype)
+        return [
+            ParamSpec("qkv", TensorSpec(
+                (self.embed_dim, self.num_kv_heads,
+                 self.q_per_cache_head + 2, self.head_dim), dt)),
+            ParamSpec("o_proj", TensorSpec(
+                (self.num_q_heads * self.head_dim, self.embed_dim), dt)),
+        ]
+
+    def flops(self, in_specs):
+        t = in_specs[0].shape[0]
+        cols = (2 * self.num_q_heads + 2 * self.num_kv_heads) * self.head_dim
+        return 2 * t * self.embed_dim * cols
+
+    def _project(self, x, params, pos):
+        w = dequant(params["qkv"], params.get("qkv_scale"), x.dtype)
+        qkv = jnp.einsum("te,ekgd->tkgd", x, w,
+                         preferred_element_type=jnp.float32).astype(x.dtype)
+        nq = self.q_per_cache_head
+        rope = lambda a: apply_rope(a, pos, self.rope_theta,
+                                    interleaved=self.rope_interleaved)
+        return rope(qkv[:, :, :nq]), rope(qkv[:, :, nq]), qkv[:, :, nq + 1]
+
+    def _combine(self, out, params, x):
+        return out.astype(x.dtype).reshape(
+            x.shape[0], self.num_q_heads * self.head_dim)
+
+
 def compact_cache_len(max_seq_len: int, window: int, chunk: int) -> int:
     """Entries of a slot's compacting cache: the summaries of every window
     but the last, then one window of raw entries, padded to the decode
@@ -629,6 +768,15 @@ def compact_len(position, window: int, chunk: int):
     at its offset into the open one.  A query at ``t`` reads the entries
     ``0 .. compact_len(t)``; works on ints and on arrays alike."""
     return (window // chunk) * (position // window) + position % window
+
+
+def ring_window(graph):
+    """The window of the graph's PLAIN ring layers
+    (:class:`SlidingWindowAttention`), or None for a graph that has none."""
+    for n in graph.nodes:
+        if isinstance(n.op, SlidingWindowAttention):
+            return n.op.window
+    return None
 
 
 def compact_geometry(graph):

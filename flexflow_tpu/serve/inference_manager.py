@@ -185,6 +185,8 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
     missing = []
     if kv_page_size:
         missing.append("kv_page_size: a page table for a ring that wraps "
+                       "(a differential or a plain window layer's: a page "
+                       "would hold positions a ring apart) "
                        "or a cache that compacts (pages assume one entry a "
                        "position), pages for an index of compressed keys "
                        "beside a cache, and copy-on-write of recurrent or "
@@ -193,7 +195,8 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "prefix's end")
     if kv_dtype == "int8":
         missing.append("kv_dtype='int8': quantise-on-write of the window "
-                       "ring, of the cache the cross-attention layers read, "
+                       "ring (the kernels' ring paths take no scale planes), "
+                       "of the cache the cross-attention layers read, "
                        "of a compacting cache's summaries and of a cache "
                        "whose compressed keys choose what is read; a graph "
                        "that keeps plain K/V planes in a few layers beside "
@@ -206,7 +209,8 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "tree node")
     if tp > 1:
         missing.append("tp > 1: a sharding rule for the conv, the scan, the "
-                       "differential attention's head pairs, the per-head "
+                       "differential attention's head pairs, a plain ring's "
+                       "K/V groups (its state is replicated), the per-head "
                        "summaries, a selection per K/V head on fewer K/V "
                        "heads than chips and a matrix state per head"
                        + ("; for the routed experts an exchange of rows "

@@ -64,7 +64,13 @@ KV_BUFFER_NAMES = frozenset({"k", "v", "k_scale", "v_scale"})
 # per slot; its conv tail is ``recurrent`` like Mamba-1's.  Such a graph
 # keeps the PLAIN attention op's ``kv_full`` planes in some layers beside
 # slot state in others: both are allocated, joined and freed by slot.
+# A model may keep BOTH: a plain ring (``kv_window``: SlidingWindowAttention)
+# in most layers and a full-length cache (``kv_full``) in the rest.  A
+# request's bytes are then a FIXED part (what its slot holds whatever its
+# context: ``FIXED_KINDS``) plus a PER-POSITION part (``bytes_per_token``),
+# and admission prices both (``request_bytes``).
 KV_INDEX_NAMES = frozenset({"kidx"})
+FIXED_KINDS = ("kv_window", "recurrent", "linear_state", "ssd_state")
 STATE_KINDS = {
     "kv_full": KV_BUFFER_NAMES,
     "kv_window": frozenset({"wk", "wv"}),
@@ -198,7 +204,11 @@ class StageKV:
     def bytes_per_token(self) -> Optional[float]:
         """Committed-KV bytes one request's cache position costs across
         this plan's attention ops — THE shape walk admission control,
-        preemption pricing, and the memory ledger all share.
+        preemption pricing, and the memory ledger all share.  It is the
+        PER-POSITION part of a request's bytes only: the full-length planes
+        (and an index beside them).  What a slot holds whatever its context
+        — a window layer's ring, recurrent or matrix state — is the fixed
+        part (:meth:`fixed_bytes_per_slot`); :meth:`request_bytes` is both.
 
         Buffers are ``[max_requests+1, heads, seq, dim]``, so the
         per-request-token price divides by the REAL request rows as well
@@ -234,6 +244,12 @@ class StageKV:
                     if name in names:
                         out[kind] += arr.nbytes / max(arr.shape[0] - 1, 1)
         return out
+
+    def fixed_bytes_per_slot(self) -> float:
+        """The part of a request's bytes that does not grow with its
+        context (``FIXED_KINDS`` of :meth:`bytes_per_slot`)."""
+        per_slot = self.bytes_per_slot()
+        return sum(per_slot[kind] for kind in FIXED_KINDS)
 
 
 class KVAllocator:
@@ -314,6 +330,23 @@ class KVAllocator:
         if any(p is None for p in parts):
             return None
         return sum(parts) or None
+
+    def fixed_bytes_per_slot(self) -> float:
+        """Bytes a request holds whatever its context, across all stages
+        (:meth:`StageKV.fixed_bytes_per_slot`): 0 for a graph of
+        full-length caches alone."""
+        return sum(s.fixed_bytes_per_slot() for s in self.stages)
+
+    def request_bytes(self, positions: int) -> Optional[float]:
+        """Bytes a request of ``positions`` cache positions holds: the
+        fixed part plus ``positions`` x :meth:`bytes_per_token` — the price
+        admission commits for it.  None where no position has a price
+        (unallocated caches, or a graph whose only cache compacts itself):
+        the gate then counts positions."""
+        per_tok = self.bytes_per_token()
+        if per_tok is None:
+            return None
+        return self.fixed_bytes_per_slot() + positions * per_tok
 
     @property
     def capacity_tokens(self) -> int:

@@ -122,6 +122,27 @@ class ServeModelConfig:
     moe_shared_expert_intermediate_size: Optional[int] = None
     routed_scaling_factor: float = 1.0
     norm_topk_prob: bool = True
+    # cohere2_moe (``models/cohere2_moe.py``): ``layer_types[i]`` says
+    # whether layer i is ``sliding_attention`` (a ring of ``sliding_window``
+    # positions, rotary at ``rope_theta`` on the pairs
+    # ``position_embedding_type`` names: ``rope_gptj`` interleaved) or
+    # ``full_attention`` (a full-length cache, no positional term); every
+    # layer a parallel block (``use_parallel_block``) of attention and a
+    # mixture of ``num_experts`` gated experts of width
+    # ``intermediate_size`` — what THIS graph holds: share
+    # ``expert_share_index`` of the ``router_num_experts`` the router
+    # scores (None: all) — top
+    # ``num_experts_per_tok`` by sigmoid, and ``num_shared_experts`` combined
+    # by ``shared_expert_combination_strategy``; the head tied to the
+    # embedding, times ``logit_scale``.
+    layer_types: Optional[tuple] = None
+    position_embedding_type: str = "rope_gptj"
+    use_parallel_block: bool = True
+    num_experts: int = 0
+    num_shared_experts: int = 0
+    shared_expert_combination_strategy: str = "average"
+    first_k_dense_replace: int = 0
+    logit_scale: float = 1.0
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

@@ -218,8 +218,12 @@ def alibi_slopes(num_heads: int) -> jax.Array:
     return slopes[:num_heads]
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary embedding; x: [T, ..., D] with positions [T]."""
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               interleaved: bool = False) -> jax.Array:
+    """Rotary embedding; x: [T, ..., D] with positions [T].  Pair ``i`` of
+    the ``D / 2`` turns by ``position * theta^(-2 i / D)``: the pair is
+    ``(x[i], x[i + D / 2])`` (half against half, GPT-NeoX's), or with
+    ``interleaved`` ``(x[2 i], x[2 i + 1])`` (GPT-J's, ``rope_gptj``)."""
     d = x.shape[-1]
     half = d // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
@@ -228,6 +232,10 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
     cos = jnp.cos(angles).reshape(shape)
     sin = jnp.sin(angles).reshape(shape)
+    if interleaved:
+        x1, x2 = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                         axis=-1).reshape(x.shape).astype(x.dtype)
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1

@@ -21,7 +21,8 @@ import numpy as np
 
 from ..obs.telemetry import telemetry_or_null
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .hybrid_ops import compact_geometry, compact_len, sparse_geometry
+from .hybrid_ops import (compact_geometry, compact_len, ring_window,
+                         sparse_geometry)
 from .inference_manager import EXIT_NOT_IN_BATCH
 from .resilience import ResilienceConfig, TransientServeError
 
@@ -202,6 +203,9 @@ class RequestManager:
         # (a sparse-attention op, its layers, the linear-attention layers),
         # or None: what ``_sparse_counts`` tells them
         self._sparse = sparse_geometry(im.model.graph)
+        # the window of a graph's plain ring layers, or None: what
+        # ``_ring_counts`` tells the dispatch spans beside ``ctx_sum``
+        self._ring = ring_window(im.model.graph)
         self.scan_runs = 0      # decode stretches run as on-device scans
         # ONE Telemetry handle across the serving stack: syncing it onto the
         # InferenceManager (which forwards to pipeline stages) puts request
@@ -355,10 +359,21 @@ class RequestManager:
             "rows": n_decode,
             "prompt_tokens": sum(hi - lo for _, lo, hi in pre),
             "ctx_sum": sum(hi for _, _, hi in dec),
+            **self._ring_counts(hi for _, _, hi in dec),
             "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
                                   for _, lo, hi in pre),
             **self._slot_state_counts([(lo, hi) for _, lo, hi in spans]),
         }
+
+    def _ring_counts(self, contexts) -> Dict[str, int]:
+        """For a graph with plain sliding-window layers (``hybrid_ops.
+        SlidingWindowAttention``): ``ring_ctx_sum``, the positions the
+        decode rows' RING layers read at launch — ``min(context, window)``
+        a row, where ``ctx_sum`` counts the whole contexts (what the
+        full-length layers read).  Nothing for a graph without them."""
+        if self._ring is None:
+            return {}
+        return {"ring_ctx_sum": sum(min(c, self._ring) for c in contexts)}
 
     def _slot_state_counts(self, writes) -> Dict[str, int]:
         """Dispatch-span arguments (and counters) of a launch that writes
@@ -558,6 +573,12 @@ class RequestManager:
                 return ("kv_budget_bytes is a byte cap but the KV caches "
                         "are unallocated (no byte price); re-allocate "
                         "caches or gate with kv_headroom_frac")
+            # a request's bytes: what its slot holds whatever its context
+            # (window rings, recurrent or matrix state) plus its positions
+            # at the per-position price (KVAllocator.request_bytes); in
+            # token-slot units (no byte price) a request is its positions
+            fixed = (self.im.kv.fixed_bytes_per_slot()
+                     if per_tok is not None else 0.0)
             per_tok = per_tok or 1.0  # token-slot units for the frac gate
             live = [self.requests[r] for r in self.pending] + [
                 r for r in self._active()
@@ -567,8 +588,8 @@ class RequestManager:
             # ever hold whole pages, so its worst-case need rounds up to
             # the page size (round_need is identity for slot-contiguous)
             rnd = self.im.kv.round_need
-            committed = sum(rnd(self._seq_len_needed(r)) for r in live) \
-                + rnd(self._seq_len_needed(req))
+            price = lambda r: fixed + rnd(self._seq_len_needed(r)) * per_tok
+            committed = sum(price(r) for r in live) + price(req)
             # the budget: an explicit byte cap when configured (this is
             # where the per-token BYTE pricing decides — int8 vs bf16 KV
             # admit differently under the same cap), else the headroom
@@ -578,9 +599,10 @@ class RequestManager:
             cap_bytes = (res.kv_budget_bytes
                          if res.kv_budget_bytes is not None
                          else res.kv_headroom_frac
-                         * self.im.kv.capacity_tokens * per_tok)
-            if committed * per_tok > cap_bytes:
-                return (f"KV headroom: {committed * per_tok / 2**20:.2f}"
+                         * (self.im.kv.capacity_tokens * per_tok
+                            + self.im.kv.max_requests * fixed))
+            if committed > cap_bytes:
+                return (f"KV headroom: {committed / 2**20:.2f}"
                         f" MiB committed > {cap_bytes / 2**20:.2f} MiB "
                         "budget")
             # reserved-lane gate (serve/slo.py): same budget, same
@@ -588,9 +610,8 @@ class RequestManager:
             # its own reservation first, only the overflow competes for
             # the shared pool, so batch traffic can never consume the
             # latency-critical lane's reservation
-            reason = self._lane_reservation_reason(
-                req, live, cap_bytes,
-                lambda r: rnd(self._seq_len_needed(r)) * per_tok)
+            reason = self._lane_reservation_reason(req, live, cap_bytes,
+                                                   price)
             if reason is not None:
                 return reason
         return None
@@ -1883,6 +1904,8 @@ class RequestManager:
                     if k > 0:   # a live row, and its KV length at launch
                         cnt["rows"] += 1
                         cnt["ctx_sum"] += dev_seq[req.rid]
+                cnt.update(self._ring_counts(
+                    dev_seq[req.rid] for req, _ in rows if ks[req.rid] > 0))
                 cnt.update(self._slot_state_counts(
                     [(dev_seq[req.rid] - 1, dev_seq[req.rid] - 1
                       + ks[req.rid]) for req, _ in rows]))

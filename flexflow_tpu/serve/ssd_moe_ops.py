@@ -1,4 +1,5 @@
-"""Serving ops of a Mamba-2 / routed-expert hybrid (``nemotron_h``).
+"""Serving ops of a Mamba-2 / routed-expert hybrid (``nemotron_h``); the
+routed-expert layer also serves ``cohere2_moe``'s gated experts.
 
 What such a decoder adds to the serve graph beside ``CausalConv1d`` and
 ``IncMultiHeadSelfAttention``, each mechanism a class of its own so that a
@@ -12,10 +13,15 @@ device trace names it (``<OpClass>.<node>``):
 * :class:`MoERouter`, :class:`MoEDispatch`, :class:`MoEExperts`,
   :class:`MoECombine` — a DROPLESS routed-expert layer: sigmoid scores over
   ALL the published experts, top-k, the (row, choice) pairs that fall on the
-  experts THIS chip holds sorted by expert, one grouped GEMM up, ``relu^2``,
-  one grouped GEMM down, the weighted sum back in row order.  Shapes are
+  experts THIS chip holds sorted by expert, the experts as grouped GEMMs
+  over those sorted rows in the form the model states — ``relu2``: one up,
+  ``relu^2``, one down; ``swiglu``: gate and up, ``silu(gate) * up``, one
+  down —, the weighted sum back in row order.  Shapes are
   static by the upper bound ``rows x k`` on pairs, never by a capacity that
   drops; a pair routed to an expert another chip holds adds nothing here.
+* :class:`SharedExpertLinear` — a ``Linear`` under a name of its own, for
+  the projections of experts every row visits (a device trace then tells
+  them from the head's and the other dense GEMMs).
 
 Imported where a graph uses them (``FFModel.mamba2_scan`` ...), so that no
 other model pays for the import.
@@ -32,6 +38,7 @@ import jax.numpy as jnp
 from ..core.graph import ParamSpec, TensorSpec
 from ..core.op import Op, register_op
 from ..core.sharding import TensorSharding
+from ..ops.linear import Linear
 from .hybrid_ops import Segments, _flat, _init, _require, _SlotStateOp
 
 HI = jax.lax.Precision.HIGHEST
@@ -262,7 +269,8 @@ class MoERouter(_Replicated):
     ``s = sigmoid(x W)``; the ``top_k`` largest of ``s + bias`` are chosen
     (ties to the lower id: ``lax.top_k``); their weights are ``s`` (without
     the bias), normalised to sum 1 (``norm_topk``) and times ``scaling``.
-    Outputs ``(ids int32 [T, k], weights float32 [T, k])``; a caller that
+    ``bias=False``: a router without a correction bias has no such parameter
+    either (plain sigmoid top-k).  Outputs ``(ids int32 [T, k], weights float32 [T, k])``; a caller that
     hands the forward an ``extras["routing"]`` dict finds the ids there by
     node (``benchmark/routing.py`` counts the choices that differ from the
     float32 reference's)."""
@@ -271,12 +279,13 @@ class MoERouter(_Replicated):
 
     def __init__(self, embed_dim: int, num_experts: int, top_k: int,
                  scaling: float = 1.0, norm_topk: bool = True,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, bias: bool = True):
         self.embed_dim = int(embed_dim)
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
         self.scaling = float(scaling)
         self.norm_topk = bool(norm_topk)
+        self.bias = bool(bias)
         self.dtype = jnp.dtype(dtype).name
 
     def infer_shapes(self, in_specs):
@@ -286,12 +295,13 @@ class MoERouter(_Replicated):
 
     def params(self) -> List[ParamSpec]:
         f32 = jnp.dtype("float32")
-        return [ParamSpec("weight", TensorSpec(
-                    (self.embed_dim, self.num_experts), f32),
-                    pin_dtype=True),
-                ParamSpec("e_score_correction_bias",
-                          TensorSpec((self.num_experts,), f32),
-                          _init(jnp.zeros), pin_dtype=True)]
+        ps = [ParamSpec("weight", TensorSpec(
+            (self.embed_dim, self.num_experts), f32), pin_dtype=True)]
+        if self.bias:
+            ps.append(ParamSpec("e_score_correction_bias",
+                                TensorSpec((self.num_experts,), f32),
+                                _init(jnp.zeros), pin_dtype=True))
+        return ps
 
     def flops(self, in_specs):
         return 2 * in_specs[0].size * self.num_experts
@@ -299,8 +309,9 @@ class MoERouter(_Replicated):
     def lower(self, ctx, inputs, params):
         x = inputs[0].astype(jnp.float32)
         s = jax.nn.sigmoid(jnp.dot(x, params["weight"], precision=HI))
-        _, ids = jax.lax.top_k(s + params["e_score_correction_bias"],
-                               self.top_k)
+        _, ids = jax.lax.top_k(
+            s + params["e_score_correction_bias"] if self.bias else s,
+            self.top_k)
         w = jnp.take_along_axis(s, ids, axis=-1)
         if self.norm_topk:
             w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
@@ -378,21 +389,48 @@ class MoEDispatch(_Replicated):
 
 @register_op
 class MoEExperts(_Replicated):
-    """The held experts on their sorted rows: ``down_e(relu(up_e x)^2)`` as
-    two GROUPED GEMMs (``up [E, d, f]``, ``down [E, f, d]``) — Megablox's
-    Pallas kernel where the kernels are on, which streams a visited
-    expert's matrix once per row tile it touches and no unvisited
+    """The held experts on their sorted rows, as GROUPED GEMMs over the same
+    sorted rows, in the ``form`` the model states:
+
+    * ``relu2``: ``down_e(relu(up_e x)^2)`` — ``up [E, d, f]``, ``down [E,
+      f, d]``;
+    * ``swiglu``: ``down_e(silu(gate_e x) * up_e x)`` — ``gate`` and ``up``
+      ``[E, d, f]``, ``down [E, f, d]``.
+
+    Megablox's Pallas kernel where the kernels are on, which streams a
+    visited expert's matrix once per row tile it touches and no unvisited
     expert's; ``lax.ragged_dot`` otherwise (the CPU oracle).  Rows past the
     groups' sum are whatever the kernel left there: ``MoECombine`` reads
-    none of them."""
+    none of them.
+
+    The kernel's tiles (``OUT_TILES``: the widest output tile of the GEMMs
+    into and out of the hidden width; rows ``GMM_ROWS``): the contraction
+    WHOLE — no k loop, so a row tile's block index does not change while the
+    grid walks the experts that share it and it is fetched once per output
+    tile —, the output in tiles of a few MB of weights.  Scoped VMEM (16 MiB
+    on the v5e), the pipeline's two buffers each: ``relu2`` at 2688 x 1856
+    (nemotron_h): rows 128 x 2688 x 2 B = 0.7 MB, weights 2688 x 640 x 2 B =
+    3.4 MB up and 1856 x 896 x 2 B = 3.3 MB down, the float32 output tile
+    and accumulator 0.3-0.5 MB: 9 MB.  ``swiglu`` at 4096 x 4096
+    (cohere2_moe): the whole contraction beside a 1024-wide tile would be
+    8.4 MB of weights a buffer, 17 MB for two — over the limit; at 512: rows
+    128 x 4096 x 2 B = 1 MB, weights 4096 x 512 x 2 B = 4.2 MB, output and
+    accumulator 128 x 512 x 4 B = 0.26 MB: (1 + 4.2 + 0.26) x 2 + 0.26 =
+    11.2 MB (the TPU compiler takes it: tests/test_tpu_aot_compile.py)."""
 
     type_name = "moe_experts"
+    # form -> (widest tile of the hidden width, widest of the model width)
+    OUT_TILES = {"relu2": (640, 1024), "swiglu": (512, 512)}
 
     def __init__(self, num_held: int, embed_dim: int, width: int,
-                 dtype=jnp.float32):
+                 dtype=jnp.float32, form: str = "relu2"):
+        if form not in self.OUT_TILES:
+            raise ValueError(f"an expert's form is one of "
+                             f"{sorted(self.OUT_TILES)}, not {form!r}")
         self.num_held = int(num_held)
         self.embed_dim = int(embed_dim)
         self.width = int(width)
+        self.form = form
         self.dtype = jnp.dtype(dtype).name
 
     def infer_shapes(self, in_specs):
@@ -401,11 +439,13 @@ class MoEExperts(_Replicated):
     def params(self) -> List[ParamSpec]:
         dt = jnp.dtype(self.dtype)
         e, d, f = self.num_held, self.embed_dim, self.width
-        return [ParamSpec("up", TensorSpec((e, d, f), dt)),
-                ParamSpec("down", TensorSpec((e, f, d), dt))]
+        into = ("gate", "up") if self.form == "swiglu" else ("up",)
+        return [ParamSpec(n, TensorSpec((e, d, f), dt)) for n in into] + [
+            ParamSpec("down", TensorSpec((e, f, d), dt))]
 
     def flops(self, in_specs):
-        return 4 * in_specs[0].shape[0] * self.embed_dim * self.width
+        gemms = 3 if self.form == "swiglu" else 2
+        return 2 * gemms * in_specs[0].shape[0] * self.embed_dim * self.width
 
     @staticmethod
     def _tile(n: int, most: int) -> int:
@@ -422,15 +462,10 @@ class MoEExperts(_Replicated):
 
     def lower(self, ctx, inputs, params):
         xs, sizes = inputs
-        up, down = params["up"], params["down"]
-        d, f = self.embed_dim, self.width
         if ctx.extras.get("pallas_decode"):
             from jax.experimental.pallas.ops.tpu.megablox import gmm
 
             interp = bool(ctx.extras.get("pallas_interpret"))
-            # ``out_tile``: the contraction whole (no k loop: the rows' tile
-            # is fetched once per row tile), the output in tiles of a few MB
-            # of weights
             grouped = lambda a, w, out_tile: gmm(
                 a, w, sizes, jnp.float32,
                 (GMM_ROWS, w.shape[1], out_tile), interpret=interp)
@@ -439,15 +474,33 @@ class MoEExperts(_Replicated):
             grouped = lambda a, w, out_tile: jax.lax.ragged_dot(
                 a, w, sizes, preferred_element_type=jnp.float32)
             path = "ragged_dot"
-        h = grouped(xs, up, self._tile(f, 640))
-        h = jnp.square(jnp.maximum(h, 0.0)).astype(xs.dtype)
-        y = grouped(h, down, self._tile(d, 1024))
+        hidden_tile, model_tile = (
+            self._tile(n, most) for n, most in
+            zip((self.width, self.embed_dim), self.OUT_TILES[self.form]))
+        h = grouped(xs, params["up"], hidden_tile)
+        if self.form == "swiglu":
+            h = jax.nn.silu(grouped(xs, params["gate"], hidden_tile)) * h
+        else:
+            h = jnp.square(jnp.maximum(h, 0.0))
+        y = grouped(h.astype(xs.dtype), params["down"], model_tile)
         bc = ctx.extras.get("batch_config")
         batch = ("one_row_per_request"
                  if ctx.extras.get("one_row_per_request")
                  else type(bc).__name__)
         _note_path(ctx, self.type_name, batch, path)
         return [y]
+
+
+@register_op
+class SharedExpertLinear(Linear):
+    """A projection of the experts EVERY row visits (``cohere2_moe``'s four
+    shared experts side by side: gate and up ``[d, n f]``, down ``[n f,
+    d]``).  ``Linear`` in everything (weight-only int8 knows it, the search
+    prices it) but its class's name, which is what a device trace files its
+    operations under (``SharedExpertLinear.<node>``): the shared experts'
+    time can then be told from the head's and the attention's GEMMs."""
+
+    type_name = "shared_expert_linear"
 
 
 @register_op

@@ -300,6 +300,42 @@ def convert_state_dict(sd, cfg: ServeModelConfig, dtype=jnp.float32):
     return CONVERTERS[cfg.model_type](sd, cfg, dtype)
 
 
+# ``cohere2_moe`` (Command A+): NO importer yet — no checkpoint of this
+# family is in the repository, so the names below are the family's
+# convention (HF ``Cohere2``'s module names with the mixture's added) and
+# stay ASSUMED until one is.  ``{torch tensor: (graph node, parameter,
+# layout)}`` per layer ``model.layers.<l>.``, beside the reference's tables
+# (``benchmark/reference/cohere2_moe.py`` ``LAYER`` / ``program_tree`` hold
+# the same map in kernel form, ``[in, out]``): a projection transposes;
+# ``q/k/v`` fuse as :func:`fuse_qkv` does; expert ``e`` of the held ones is
+# row ``e - expert_share_index x num_experts`` of ``mlp.experts``' three
+# ``[E, in, out]`` tensors; shared expert ``j`` is columns (gate, up) / rows
+# (down) ``j f .. (j + 1) f`` of the three ``SharedExpertLinear`` kernels;
+# the head is the embedding transposed (``tie_word_embeddings``).  A chip
+# that holds a share takes its K/V groups' columns of q/k/v and rows of
+# ``o_proj``, its experts by id and its rows of the embedding.
+COHERE2_MOE_TENSORS = {
+    "model.embed_tokens.weight": ("model.embed_tokens", "weight", "[V, d]"),
+    "model.norm.weight": ("model.norm", "gamma", "[d]"),
+    "(tied) model.embed_tokens.weight": ("lm_head", "kernel", "[d, V] = .T"),
+    "input_layernorm.weight": ("input_layernorm", "gamma", "[d]"),
+    "self_attn.q_proj.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.k_proj.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.v_proj.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.o_proj.weight": ("self_attn", "o_proj", "[H hd, d] = .T"),
+    "mlp.gate.weight": ("mlp.gate", "weight", "[d, experts] = .T, float32"),
+    "mlp.experts.<e>.gate_proj.weight": ("mlp.experts", "gate", "[e] = .T"),
+    "mlp.experts.<e>.up_proj.weight": ("mlp.experts", "up", "[e] = .T"),
+    "mlp.experts.<e>.down_proj.weight": ("mlp.experts", "down", "[e] = .T"),
+    "mlp.shared_experts.<j>.gate_proj.weight":
+        ("mlp.shared_experts.gate_proj", "kernel", "[:, j f:(j + 1) f] = .T"),
+    "mlp.shared_experts.<j>.up_proj.weight":
+        ("mlp.shared_experts.up_proj", "kernel", "[:, j f:(j + 1) f] = .T"),
+    "mlp.shared_experts.<j>.down_proj.weight":
+        ("mlp.shared_experts.down_proj", "kernel", "[j f:(j + 1) f] = .T"),
+}
+
+
 def load_hf_model(name_or_path: str):
     """Load a local HF checkpoint (config + weights + tokenizer if present).
 

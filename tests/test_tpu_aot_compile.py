@@ -135,6 +135,14 @@ _CASES = [
     ("decode", 10, 4, 128, 8192, "bf16"),
     ("prefill", 10, 4, 128, 8192, "bf16"),
     ("decode", 10, 4, 128, 1024, "ring512"),
+    # a plain ring (cohere2_moe's sliding layers, one chip's eighth: 16
+    # query heads on 1 K/V head): a window of 4096 in a ring of 4608 slots,
+    # read by the decode scan and — the window's lower bound in the kernel —
+    # by a prompt chunk's tiles; and its full layer's cache of 18432
+    ("decode", 1, 16, 128, 4608, "ring4096"),
+    ("prefill", 1, 16, 128, 4608, "ring4096"),
+    ("decode", 1, 16, 128, 18432, "bf16"),
+    ("prefill", 1, 16, 128, 18432, "bf16"),
 ]
 
 
@@ -171,6 +179,7 @@ _WRITE_CASES = {
     "int8": (32, 128, 2048, 16, jnp.int8, jnp.int8),
     "sala_kv2": (2, 128, 32768, 48, jnp.bfloat16, jnp.bfloat16),
     "cast": (8, 128, 2048, 16, jnp.bfloat16, jnp.float32),
+    "command_ring": (1, 128, 4608, 128, jnp.bfloat16, jnp.bfloat16),
 }
 
 
@@ -378,6 +387,39 @@ def test_nemotron_routed_layer_compiles_for_v5e(one_chip, rows):
         sds((scored,), jnp.float32), sds((held, d, f), jnp.bfloat16),
         sds((held, f, d), jnp.bfloat16)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("rows", [128, 512], ids=["scan128", "chunk512"])
+def test_gated_routed_layer_compiles_for_v5e(one_chip, rows):
+    """The routed-expert layer at Command A+'s published widths (hidden
+    4096, a router over 128 experts with top-8 and no bias, 16 HELD gated
+    experts of width 4096 — 100.7 MB each) on the decode scan's 128 rows and
+    on a prompt chunk's 512: the three grouped GEMMs take the ``swiglu``
+    tiles (the whole 4096 contraction beside a 512-wide output tile: 11 MB
+    of the 16 MiB scoped VMEM; a 1024-wide one the compiler refuses)."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.ssd_moe_ops import (MoECombine, MoEDispatch,
+                                                MoEExperts, MoERouter)
+
+    d, f, held, scored, k = 4096, 4096, 16, 128, 8
+
+    def layer(x, router, gate, up, down):
+        ctx = lambda: OpContext(extras={"node_name": "n",
+                                        "pallas_decode": True})
+        ids, w = MoERouter(d, scored, k, dtype=x.dtype, bias=False).lower(
+            ctx(), [x], {"weight": router})
+        xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
+            ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
+        return MoECombine(held, dtype=x.dtype).lower(
+            ctx(), [ys, order, ids, w], {})[0]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((d, scored), jnp.float32),
+        sds((held, d, f), jnp.bfloat16), sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
 @pytest.mark.parametrize("form", ["slot_order", "chunked"])
