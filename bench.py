@@ -211,16 +211,17 @@ def step_byte_parts(im, ctx, block_s=None):
 
 def decode_block_s(im):
     """The seq-block the Pallas decode kernel actually picks for this im's
-    cache shape (``attention._fit_block_s`` under the decode VMEM budget) —
-    the granularity of its causal-clamped KV fetches and therefore the
-    right quantum for ``step_bytes``'s block-granular accounting.  For the
-    llama2-7b-shape caches the VMEM fit shrinks the default 512 to 256."""
-    from flexflow_tpu.ops.pallas.attention import _VMEM_BUDGET, _fit_block_s
+    cache shape (``attention._decode_plan``) — the granularity of its
+    causal-clamped KV fetches and therefore the right quantum for
+    ``step_bytes``'s block-granular accounting.  For the llama2-7b-shape
+    caches the VMEM fit shrinks the default 512 to 256; one K/V head takes
+    2048."""
+    from flexflow_tpu.ops.pallas.attention import _decode_plan
 
     bufs = next(iter(im.state.values()))
     k = bufs["k"]  # [R+1, KV, S, D]
-    return _fit_block_s(512, k.shape[2], k.shape[1], k.shape[3],
-                        k.dtype.itemsize, "k_scale" in bufs, _VMEM_BUDGET)
+    return _decode_plan(k.shape[1], k.shape[3], k.dtype.itemsize,
+                        "k_scale" in bufs, k.shape[2])
 
 
 def prefill_im(im, prompts):

@@ -23,6 +23,15 @@ Design (v2 — measured on a real v5e chip):
   and this alone is worth ~2x at half-full caches.
 * online softmax in f32; optional ALiBi bias (slopes passed in) so MPT-style
   models ride the same kernel.
+* **the block is planned by bytes** (``_decode_plan``; the kernel alone on
+  the v5e by block size: PERF.md section 6, PR 51): a grid step has a price
+  of its own whatever it copies, so where a position's K is narrow the step
+  copies more positions.  32 K/V heads of 128 move 2 MB of K at 256
+  positions and keep that block; ONE K/V head (256 bytes of K a position)
+  takes 2048 positions, two take 1024: 512 KB of K a copy.  A narrow ring,
+  which is read whole once wrapped, is ONE block where K and V fit the VMEM
+  budget twice.  The body is the same at every size: it scores the copied
+  block whole.
 * **fused int8-KV dequant**: when the cache is int8 with per-(row, head,
   position) f32 scales (``serve/ops.py`` quantize-on-write), the kernels take
   ``k_scale``/``v_scale`` operands ``[rows, KV, S]`` streamed in the same
@@ -83,6 +92,72 @@ def _fit_block_s(block_s, s_len, num_kv, d, itemsize, kv_quant, budget):
     if s_len % block_s:
         block_s = math.gcd(block_s, s_len)
     return block_s
+
+
+# What ONE copy of K by a grid step of ``decode_attention`` should move where
+# a layer's positions are narrow (one or two K/V heads of 128: 256 or 512
+# bytes of K a position).  Chosen from the kernel alone on the v5e by block
+# size, at three cells' decode shapes (``scripts/decode_kernel_bench.py``;
+# PERF.md section 6, PR 51): at 128 KB of K a copy a grid step takes more
+# than twice the time of its copy; from 512 KB (2048 positions on one head,
+# 1024 on two) the copy is most of it, and larger blocks lose more to the
+# block a row reads past its frontier (and to a pad row's one block) than
+# they save in steps.
+_COPY_TARGET_BYTES = 512 * 2**10
+
+
+def _decode_plan(num_kv, d, itemsize, kv_quant, s_len, window=0,
+                 page_size=0, block_s=None):
+    """The seq block ONE grid step of :func:`decode_attention` copies and
+    scores.
+
+    The block by POSITIONS the kernel always had — 512, an eighth of a
+    ring's window (at least 256), fitted to the VMEM budget and to ``s_len``
+    (:func:`_fit_block_s`), inside one page when paged — is the plan
+    wherever its copy of K is ``_COPY_TARGET_BYTES`` or more, and wherever
+    the caller names a ``block_s``.  A narrower layer's block is planned by
+    BYTES: the largest multiple of that block that still divides ``s_len``
+    (the page, when paged) and, on a full cache, copies at most
+    ``_COPY_TARGET_BYTES`` of K — a row reads up to a block past its
+    frontier, and a pad row one whole block.  A RING is read whole once
+    wrapped, whatever its blocks, so its block is bounded by
+    ``_VMEM_BUDGET`` alone: the whole ring where its K and V fit twice (the
+    compiler's scoped use is those buffers and ~0.3 MB: 5.05 MB for 4608
+    slots of one head, tests/test_tpu_aot_compile.py).
+    """
+    block = block_s or 512
+    if window:
+        # finer blocks than a full cache's where the layer is wide: a window
+        # of 512 in a ring of 1024 touches 3 blocks of 256 but all of 2
+        # blocks of 512
+        block = min(block, max(256, window // 8))
+    block = _fit_block_s(block, s_len, num_kv, d, itemsize, kv_quant,
+                         _VMEM_BUDGET)
+    if page_size:
+        # a seq-block must sit inside ONE page (page_size divides the padded
+        # seq length by the allocator's construction-time assert, so the
+        # gcd keeps a dividing block)
+        block = math.gcd(block, page_size)
+    k_pos = num_kv * d * itemsize
+    if block_s or block * k_pos >= _COPY_TARGET_BYTES:
+        return block
+    if window:
+        most = _VMEM_BUDGET // (4 * k_pos)  # K and V, two buffers each
+    else:
+        most = _COPY_TARGET_BYTES // k_pos
+    span = page_size or s_len
+    return max(m * block for m in range(1, span // block + 1)
+               if span % (m * block) == 0 and (m == 1 or m * block <= most))
+
+
+def decode_block_plan(k_cache, kv_quant=False, window=0, page_size=0):
+    """The plan :func:`decode_attention` takes on this cache, as the
+    ``attention_path.decode_block.*`` counter names it: ``ring4608`` (a ring
+    copied whole), ``full2048``, ``full256``."""
+    _, num_kv, s_len, d = k_cache.shape
+    block = _decode_plan(num_kv, d, jnp.dtype(k_cache.dtype).itemsize,
+                         kv_quant, s_len, window, page_size)
+    return f"{'ring' if window else 'full'}{block}"
 
 
 def _page_coords(pt, row, jc, block_s, page_size, ppr):
@@ -254,7 +329,7 @@ def decode_attention(
     positions: jax.Array,  # i32[T]
     scale: float,
     slopes: Optional[jax.Array] = None,  # [QH] alibi slopes
-    block_s: int = 512,
+    block_s: Optional[int] = None,  # None: planned (_decode_plan)
     use_alibi: bool = False,
     interpret: bool = False,
     k_scale: Optional[jax.Array] = None,  # [R+1, KV, S] int8-KV dequant
@@ -268,7 +343,11 @@ def decode_attention(
     plus the widest step that writes before it attends) — and a query at
     ``positions[i]`` sees the ``min(positions[i] + 1, window)`` newest
     positions.  Blocks that hold none of them are neither fetched (the index
-    map sends them to a block that is) nor computed."""
+    map sends them to a block that is) nor computed.
+
+    ``block_s``: None plans the seq block from the bytes of a position's K
+    (:func:`_decode_plan`); a number is the block by positions, fitted to
+    the VMEM budget, to ``S`` and to the page, and never grown."""
     t, qh, d = q.shape
     _, num_kv, s_len, _ = k_cache.shape
     gq = qh // num_kv
@@ -278,19 +357,9 @@ def decode_attention(
         if paged or use_alibi or kv_quant:
             raise ValueError("a ring cache is slot-contiguous, fp, and has "
                              "no positional bias")
-        # finer blocks than a full cache's: a window of 512 in a ring of
-        # 1024 touches 3 blocks of 256 but all of 2 blocks of 512; a window
-        # of 4096 keeps the full cache's (an eighth of it)
-        block_s = min(block_s, max(256, window // 8))
-    # cap the block so K+V (+ scale) double-buffered blocks fit the budget
-    block_s = _fit_block_s(block_s, s_len, num_kv, d,
-                           jnp.dtype(k_cache.dtype).itemsize, kv_quant,
-                           _VMEM_BUDGET)
-    if paged:
-        # a seq-block must sit inside ONE page (page_size divides the padded
-        # seq length by the allocator's construction-time assert, so the
-        # gcd keeps a dividing block)
-        block_s = math.gcd(block_s, page_size)
+    block_s = _decode_plan(
+        num_kv, d, jnp.dtype(k_cache.dtype).itemsize, kv_quant, s_len,
+        window, page_size if paged else 0, block_s)
     n_blocks = s_len // block_s
     qr = q.reshape(t, num_kv, gq, d)
     if slopes is None:

@@ -57,8 +57,8 @@ from ..core.sharding import TensorSharding
 from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
 from .ops import (DUS_MAX_TOKENS, NEG_INF, SCAN_DUS_MAX_ROWS,
-                  IncMultiHeadSelfAttention, apply_rope, put_blocks,
-                  tile_coords)
+                  IncMultiHeadSelfAttention, apply_rope, note_decode_block,
+                  put_blocks, tile_coords)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
@@ -466,6 +466,8 @@ class SlotCacheAttention(_SlotStateOp):
                 q.reshape(t, self.cache_heads * nq, d), kc, vc, seg.rows, pos,
                 scale=self.scaling_factor, interpret=interp,
                 window=self.window)
+            note_decode_block(ctx.extras, self.path_kind, type(bc).__name__,
+                              kc, window=self.window)
             return out.reshape(t, self.cache_heads, nq, d), "decode_attention"
         out = self._attend_xla(q[:, None], kc, vc, seg.rows,
                                base.token_position[:, None])
@@ -922,6 +924,8 @@ class EvaAttention(_SlotStateOp):
             out = decode_attention(q, kc, vc, rows, at,
                                    scale=self.scaling_factor,
                                    interpret=interp)
+            note_decode_block(ctx.extras, "eva_attention", type(bc).__name__,
+                              kc)
             return out, "decode_attention"
         # the CPU oracle of the kernels
         sc = jnp.einsum("thd,thsd->ths", q, kc[rows],

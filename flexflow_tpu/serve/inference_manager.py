@@ -432,8 +432,10 @@ class InferenceManager:
                                   max_tokens=max_tokens_per_batch)
         # which path each kind of attention layer took in each program
         # (Pallas kernel or XLA), written by the ops as they are traced:
-        # {(layer kind, batch type): path} — a fallback is never unseen
-        self.attention_paths: Dict[Tuple[str, str], str] = {}
+        # {(layer kind, batch type): path} — a fallback is never unseen; and
+        # {("decode_block", (layer kind, batch type)): the seq block
+        # ``decode_attention`` planned there} (``ops.note_decode_block``)
+        self.attention_paths: Dict[Tuple[str, Any], str] = {}
         self._paths_counted = 0
         # routed-expert layers (ops that leave a load count: MoEDispatch),
         # and per decode scan dispatched and not yet collected ``(steps,

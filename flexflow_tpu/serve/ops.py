@@ -205,6 +205,21 @@ def put_blocks(kc, vc, k, v, rows, start, count, tile, extras, wrap=None,
     return (wrap or (lambda f: f))(write)(kc, vc, k, v, rows, start, count)
 
 
+def note_decode_block(extras, kind, batch, k_cache, **plan):
+    """Record in ``extras["attention_paths"]`` the seq block
+    ``decode_attention`` plans on ``k_cache`` for op ``kind`` under batch
+    class ``batch`` — the ``attention_path.decode_block.*`` counter says
+    which layers took a block grown by bytes and which the block by
+    positions.  ``plan``: ``kv_quant`` / ``window`` / ``page_size`` as the
+    kernel gets them."""
+    paths = extras.get("attention_paths")
+    if paths is not None:
+        from ..ops.pallas.attention import decode_block_plan
+
+        paths[("decode_block", (kind, batch))] = decode_block_plan(
+            k_cache, **plan)
+
+
 def alibi_slopes(num_heads: int) -> jax.Array:
     """ALiBi per-head slopes (Press et al.; matches HF's power-of-2 recipe)."""
     import math as _math
@@ -713,6 +728,11 @@ class IncMultiHeadSelfAttention(Op):
                 kv_l, gq = q_.shape[1], q_.shape[2]
                 scales_ = rest[:len(scales)]
                 pt_ = rest[len(scales)] if pg else None
+                # of the shard's heads: the plan follows what ONE chip copies
+                note_decode_block(
+                    ctx.extras, self.type_name,
+                    "one_row_per_request" if in_scan else type(bc).__name__,
+                    kc_, kv_quant=kv_q, page_size=pg_size)
                 return decode_attention(
                     q_.reshape(t, kv_l * gq, self.head_dim),
                     kc_, vc_, rows_, pos_,

@@ -299,8 +299,9 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert ok, "\n".join(lines)
     assert "contexts up to 401" in lines[-1], lines[-1]
     kinds = {k for k, _ in im.attention_paths}
-    assert kinds - {"kv_block_write"} == {"sliding_window_attention",
-                                          "moe_experts"}
+    assert kinds - {"kv_block_write"} == {
+        "sliding_window_attention", "moe_experts"} | (
+            {"decode_block"} if use_pallas else set())
 
 
 def test_flat_rows_of_several_requests_go_by_segments():
@@ -480,32 +481,79 @@ def test_the_prefill_kernel_skips_the_blocks_before_the_window():
     np.testing.assert_array_equal(got, want)
 
 
-def test_decode_kernel_reads_a_wide_ring_in_blocks_of_an_eighth_of_it():
-    """``decode_attention``'s ring path at a window of 1024 in a ring of
-    1536 (blocks of 256 would do for a window of 512; here the block is the
-    full cache's 512): equal to the slot-by-slot loop on both sides of the
-    ring's end."""
-    rng = np.random.default_rng([SEED, 8])
-    s_len, window, kv, gq, d = 1536, 1024, 1, 2, 128
-    pos = np.asarray([5, 1023, 1024, 1535, 1536, 4000], np.int32)
+def _ring_rows(rng, pos, s_len, kv, d, dtype=np.float32):
+    """One ring row a position of ``pos``, holding what positions
+    ``0 .. pos`` leave in it (the newest on a slot stays), and the keys and
+    values by position ``[1, KV, P, D]`` for the oracle."""
+    upto = int(max(pos)) + 1
+    # float32 values that the cache's type holds exactly
+    keys, vals = (np.asarray(jnp.asarray(
+        rng.normal(size=(upto, kv, d)), dtype).astype(jnp.float32))
+        for _ in range(2))
     kc = np.zeros((len(pos) + 1, kv, s_len, d), np.float32)
     vc = np.zeros_like(kc)
-    keys = rng.normal(size=(4001, kv, d)).astype(np.float32)
-    vals = rng.normal(size=(4001, kv, d)).astype(np.float32)
     for r, p in enumerate(pos):
-        for j in range(max(0, p - s_len + 1), p + 1):
-            kc[r, :, j % s_len], vc[r, :, j % s_len] = keys[j], vals[j]
+        live = np.arange(max(0, p - s_len + 1), p + 1)
+        kc[r][:, live % s_len] = np.moveaxis(keys[live], 0, 1)
+        vc[r][:, live % s_len] = np.moveaxis(vals[live], 0, 1)
+    return (kc, vc, np.moveaxis(keys, 0, 1)[None],
+            np.moveaxis(vals, 0, 1)[None])
+
+
+@pytest.mark.parametrize("s_len,window,kv,gq,cache_dt,forced", [
+    # PR 50's case (ring 1536, window 1024, float32: 512 bytes of K a slot):
+    # the ring is one block now
+    (1536, 1024, 1, 2, "float32", None),
+    # the cell's ring: 16 query heads on ONE K/V head in bf16, a window of
+    # 4096 in 4608 slots - whole, and in blocks that the window straddles
+    (4608, 4096, 1, 16, "bfloat16", None),
+    (4608, 4096, 1, 16, "bfloat16", 2304),
+    (4608, 4096, 1, 16, "bfloat16", 1536),
+    (2304, 2048, 2, 8, "bfloat16", None),
+    (2304, 2048, 2, 8, "bfloat16", 1152),
+])
+def test_decode_kernel_reads_a_narrow_ring_whole(
+        monkeypatch, s_len, window, kv, gq, cache_dt, forced):
+    """``decode_attention``'s ring path on one or two K/V heads of 128:
+    equal to the slot-by-slot loop before the window fills, with the window
+    inside ONE copied block, on both sides of the window's filling, of the
+    ring's end and of the wrap, and rings later.  A slot that the window
+    does not hold is NaN wherever its whole block lies outside the window:
+    skipped blocks are not read."""
+    from flexflow_tpu.ops.pallas import attention
+
+    d = 128
+    block = forced or attention._decode_plan(
+        kv, d, jnp.dtype(cache_dt).itemsize, False, s_len, window)
+    assert forced is not None or block == s_len
+    part = block // 3
+    rng = np.random.default_rng([SEED, 8, s_len, block])
+    pos = np.asarray([5, part - 1, part + 40, window - 1, window, s_len - 1,
+                      s_len, s_len + part // 2, s_len + block + 3,
+                      2 * s_len + window // 2 + 1], np.int32)
+    kc, vc, by_pos_k, by_pos_v = _ring_rows(rng, pos, s_len, kv, d, cache_dt)
+    # outside the window, in blocks that hold no slot of it
+    for r, p in enumerate(pos):
+        held = np.zeros(s_len, bool)
+        held[np.arange(max(0, p - window + 1), p + 1) % s_len] = True
+        dead = ~held.reshape(-1, block).any(axis=1).repeat(block)
+        kc[r][:, dead] = vc[r][:, dead] = np.nan
     q = rng.normal(size=(len(pos), kv * gq, d)).astype(np.float32)
     rows = np.arange(len(pos), dtype=np.int32)
-    got = decode_attention(jnp.asarray(q), jnp.asarray(kc), jnp.asarray(vc),
-                           jnp.asarray(rows), jnp.asarray(pos), scale=0.09,
-                           interpret=True, window=window)
-    by_pos_k = np.broadcast_to(np.moveaxis(keys, 0, 1)[None],
-                               (len(pos), kv, 4001, d))
-    by_pos_v = np.broadcast_to(np.moveaxis(vals, 0, 1)[None],
-                               (len(pos), kv, 4001, d))
-    want = _ring_oracle(q, by_pos_k, by_pos_v, rows, pos, window, 0.09)
-    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
+    if forced is not None:
+        monkeypatch.setattr(attention, "_decode_plan",
+                            lambda *a, **k: forced)
+    got = np.asarray(decode_attention.__wrapped__(
+        jnp.asarray(q), jnp.asarray(kc, cache_dt), jnp.asarray(vc, cache_dt),
+        jnp.asarray(rows), jnp.asarray(pos), scale=0.09, interpret=True,
+        window=window))
+    assert np.isfinite(got).all()
+    want = _ring_oracle(q, np.broadcast_to(by_pos_k, (len(pos),) +
+                                           by_pos_k.shape[1:]),
+                        np.broadcast_to(by_pos_v, (len(pos),) +
+                                        by_pos_v.shape[1:]),
+                        rows, pos, window, 0.09)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
 
 
 def test_interleaved_rotary_by_hand():
@@ -841,3 +889,56 @@ def test_the_decode_scans_launch_says_what_the_rings_read():
         assert sum(r["expert_steps"] for r in records) == steps
     finally:
         im.telemetry = type(im).telemetry
+
+
+def test_the_decode_kernels_block_plans_are_counted():
+    """``attention_path.decode_block.<plan>`` once per (layer kind, batch
+    class) whose program holds ``decode_attention``: the sliding layers' ring
+    plan and the full layer's, in the flat step and in the decode scan."""
+    from flexflow_tpu.obs import Telemetry
+    from flexflow_tpu.serve import GenerationConfig, RequestManager
+
+    im = deployment(use_pallas=True)
+    tel = Telemetry()
+    rm = RequestManager(im, GenerationConfig(stop_on_eos=False),
+                        telemetry=tel)
+    try:
+        im._paths_counted = 0
+        rm.generate([tokens(9, salt=91), tokens(60, salt=92)], 6)
+        plans = {b: p for (k, b), p in im.attention_paths.items()
+                 if k == "decode_block"}
+        # the toy's positions are 64 bytes of K: every cache is one block
+        assert plans[("sliding_window_attention", "BatchConfig")] == \
+            f"ring{RING}"
+        full = {b: p for (kind, b), p in plans.items()
+                if kind == "inc_multihead_self_attention"}
+        assert full == {"BatchConfig": f"full{SEQ}",
+                        "one_row_per_request": f"full{SEQ}"}
+        counters = tel.metrics.snapshot()
+        assert counters[f"attention_path.decode_block.ring{RING}"] == 1
+        assert counters[f"attention_path.decode_block.full{SEQ}"] == 2
+        assert counters[
+            "attention_path.sliding_window_attention.decode_attention"] == 1
+    finally:
+        im.telemetry = type(im).telemetry
+
+
+@pytest.mark.parametrize("cache,kw,want", [
+    # 32 K/V heads of 128: the block by positions, as before PR 51
+    (((17, 32, 2048, 128), jnp.bfloat16), {}, "full256"),
+    (((17, 32, 2048, 128), jnp.int8), dict(kv_quant=True), "full256"),
+    (((17, 32, 2048, 128), jnp.bfloat16), dict(page_size=512), "full256"),
+    (((17, 10, 1024, 128), jnp.bfloat16), dict(window=512), "ring256"),
+    # one or two: grown by bytes
+    (((129, 1, 18432, 128), jnp.bfloat16), {}, "full2048"),
+    (((129, 1, 4608, 128), jnp.bfloat16), dict(window=4096), "ring4608"),
+    (((257, 2, 8192, 128), jnp.bfloat16), {}, "full1024"),
+], ids=lambda v: v if isinstance(v, str) else None)
+def test_a_layers_block_plan_is_noted_by_its_cache(cache, kw, want):
+    paths = {}
+    serve_ops.note_decode_block(
+        {"attention_paths": paths}, "some_attention", "BatchConfig",
+        jax.ShapeDtypeStruct(*cache), **kw)
+    assert paths == {("decode_block", ("some_attention", "BatchConfig")): want}
+    serve_ops.note_decode_block({}, "some_attention", "BatchConfig",
+                                jax.ShapeDtypeStruct(*cache), **kw)

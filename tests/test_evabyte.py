@@ -259,6 +259,10 @@ def test_the_harness_drive_is_correct(use_pallas):
     write = im.attention_paths.pop(("kv_block_write", "PrefillBatchConfig"),
                                    None)
     assert write == ("dus_chain" if use_pallas else None)
+    block = im.attention_paths.pop(
+        ("decode_block", ("eva_attention", "BatchConfig")), None)
+    assert (block or "full").startswith("full") and \
+        (block is not None) == use_pallas
     assert {k for k, _ in im.attention_paths} == {"eva_attention"}
     if use_pallas:
         assert im.attention_paths[

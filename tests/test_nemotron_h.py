@@ -279,6 +279,14 @@ def test_the_harness_drive_is_correct(use_pallas):
     paths = im.attention_paths
     assert paths.pop(("kv_block_write", "PrefillBatchConfig"), None) == (
         "pallas" if use_pallas else None)
+    # the one GQA layer's decode kernel says which block it planned, in
+    # the flat step's program and in the decode scan's
+    blocks = {b: paths.pop(("decode_block", b))
+              for b in [b for k, b in paths if k == "decode_block"]}
+    assert set(blocks) == ({("inc_multihead_self_attention", "BatchConfig"),
+                            ("inc_multihead_self_attention",
+                             "one_row_per_request")} if use_pallas else set())
+    assert all(p.startswith("full") for p in blocks.values())
     assert {k for k, _ in paths} == {"mamba2_scan", "moe_experts"}
     assert paths[("mamba2_scan", "PrefillBatchConfig")] == "chunked"
 

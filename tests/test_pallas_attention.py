@@ -4,6 +4,8 @@ Runs in interpret mode on the CPU test mesh (same kernel logic, no TPU
 needed); the real-TPU compile is exercised by bench.py.
 """
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -379,3 +381,114 @@ def test_tiled_prefill_counts_its_block_write(use_pallas):
     assert im.attention_paths.get(
         ("kv_block_write", "PrefillBatchConfig")) == (
         "pallas" if use_pallas else None)
+
+
+# ---- the seq block a grid step copies, planned by bytes (PR 51) ------------
+_KB = 2 ** 10
+
+
+@pytest.mark.parametrize("why,shape,kw,want", [
+    # (KV heads, head, itemsize, int8 scales, cache seq) -> block.
+    # Wide layers: the block by positions the kernel always had, to the digit
+    ("opt_bf16", (32, 128, 2, False, 2048), {}, 256),
+    ("opt_int8", (32, 128, 1, True, 2048), {}, 256),
+    ("opt_paged_512", (32, 128, 2, False, 2048), dict(page_size=512), 256),
+    ("evabyte_compacted", (32, 128, 2, False, 4096), {}, 256),
+    ("phi4flash_full", (10, 128, 2, False, 8192), {}, 512),
+    ("phi4flash_ring", (10, 128, 2, False, 1024), dict(window=512), 256),
+    # ONE K/V head of 128 in bf16 is 256 bytes of K a position: 2048 of them
+    # a copy; a ring is one block
+    ("command_a_full", (1, 128, 2, False, 18432), {}, 2048),
+    ("command_a_ring", (1, 128, 2, False, 4608), dict(window=4096), 4608),
+    ("starcoder_mqa", (1, 128, 2, False, 8192), {}, 2048),
+    ("nemotron_two_heads", (2, 128, 2, False, 8192), {}, 1024),
+    ("narrow_paged", (1, 128, 2, False, 8192), dict(page_size=1024), 1024),
+    ("narrow_odd_length", (1, 128, 2, False, 4608), {}, 1536),
+    # a block the caller names is kept by positions, never grown
+    ("named_block", (1, 128, 2, False, 8192), dict(block_s=512), 512),
+    ("named_block_ring", (1, 128, 2, False, 4608),
+     dict(window=4096, block_s=512), 512),
+])
+def test_decode_plan_follows_the_bytes_of_a_position(why, shape, kw, want):
+    from flexflow_tpu.ops.pallas.attention import (_COPY_TARGET_BYTES,
+                                                   _VMEM_BUDGET,
+                                                   _decode_plan,
+                                                   _fit_block_s)
+
+    num_kv, d, itemsize, kv_quant, s_len = shape
+    block = _decode_plan(*shape, **kw)
+    assert block == want, why
+    span = kw.get("page_size") or s_len
+    assert span % block == 0
+    pos_bytes = 2 * num_kv * d * itemsize + (8 * num_kv if kv_quant else 0)
+    assert 2 * block * pos_bytes <= _VMEM_BUDGET
+    window = kw.get("window", 0)
+    today = _fit_block_s(min(512, max(256, window // 8)) if window else 512,
+                         s_len, num_kv, d, itemsize, kv_quant, _VMEM_BUDGET)
+    if today * num_kv * d * itemsize >= _COPY_TARGET_BYTES or "block_s" in kw:
+        # the parent's block where its copy already was large
+        assert block == math.gcd(today, span)
+    else:
+        assert block > today
+        assert window or block * num_kv * d * itemsize <= _COPY_TARGET_BYTES
+
+
+def _decode_with_block(monkeypatch, block, *args, **kw):
+    """``decode_attention`` (interpreted) under a forced seq block — the
+    undecorated function, so that no compiled program of another block is
+    found again; ``block`` None is the plan the kernel makes."""
+    from flexflow_tpu.ops.pallas import attention
+
+    if block is not None:
+        monkeypatch.setattr(attention, "_decode_plan", lambda *a, **k: block)
+    return attention.decode_attention.__wrapped__(*args, interpret=True, **kw)
+
+
+@pytest.mark.parametrize("kv,s_len,forced", [
+    (1, 6144, None),            # the plan as made: blocks of 2048
+    (1, 6144, 1024),
+    (1, 6144, 3072),
+    (2, 4096, None),            # two K/V heads: blocks of 1024
+    (2, 4096, 2048),
+])
+def test_a_narrow_full_cache_is_read_in_blocks_grown_by_bytes(
+        monkeypatch, kv, s_len, forced):
+    """16 query heads on one or two K/V heads of 128, a bf16 cache: rows
+    whose frontier falls in the first, a middle and the last quarter of a
+    copied block, on the last position of a block and on the first of the
+    next, at 0 and at the cache's end, and a pad row — against the gathered
+    reference.  What lies past a row's frontier is NaN: a block that is
+    skipped never reads it, one that is masked must not let it through."""
+    from flexflow_tpu.ops.pallas.attention import _decode_plan
+
+    block = forced or _decode_plan(kv, 128, 2, False, s_len)
+    assert s_len // block >= 2 and (forced is not None or block > 512)
+    part = block // 4
+    rng = np.random.default_rng([51, kv, block])
+    gq, d = 16 // kv, 128
+    pos = np.asarray(
+        [0, part // 2, part - 1, part, block - part + 7, block - 1, block,
+         block + part + part // 3, 2 * block - 1, s_len - part - 1,
+         s_len - 1, 0], np.int32)
+    t = len(pos)
+    rows = np.arange(t, dtype=np.int32)
+    rows[-1] = t                                    # the pad: the scratch row
+    kc = rng.normal(size=(t + 1, kv, s_len, d)).astype(np.float32)
+    vc = rng.normal(size=(t + 1, kv, s_len, d)).astype(np.float32)
+    kc, vc = (jnp.asarray(a, jnp.bfloat16) for a in (kc, vc))
+    q = jnp.asarray(rng.normal(size=(t, kv * gq, d)), jnp.float32)
+    want = ref_attention(q, kc.astype(jnp.float32), vc.astype(jnp.float32),
+                         jnp.asarray(rows), jnp.asarray(pos), 0.09)
+    # past the frontier: never seen.  NaN only where a whole block is past
+    # it (0 * NaN of a MASKED position would poison a sum; those of the
+    # frontier's own block stay finite, as every cache holds them); the
+    # scratch row stays whole
+    dead = np.arange(s_len)[None, :] >= (pos[:, None] // block + 1) * block
+    kc_h, vc_h = (jnp.concatenate([
+        jnp.where(dead[:, None, :, None], jnp.nan, a[:t]).astype(a.dtype),
+        a[t:]]) for a in (kc, vc))
+    got = np.asarray(_decode_with_block(
+        monkeypatch, forced, q, kc_h, vc_h, jnp.asarray(rows),
+        jnp.asarray(pos), 0.09))
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)

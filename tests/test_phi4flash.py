@@ -269,7 +269,8 @@ def test_the_harness_drive_is_correct(use_pallas):
         "pallas" if use_pallas else None)
     kinds = {k for k, _ in im.attention_paths}
     assert kinds == {"window_attention", "full_attention", "cross_attention",
-                     "selective_scan"}
+                     "selective_scan"} | (
+                         {"decode_block"} if use_pallas else set())
     assert scan_path(im, "one_row_per_request") == "rows_at_once"
     if use_pallas:
         assert im.attention_paths[
@@ -279,6 +280,14 @@ def test_the_harness_drive_is_correct(use_pallas):
         assert im.attention_paths[
             ("window_attention", "BatchConfig")] == "decode_attention"
         assert scan_path(im, "PrefillBatchConfig") == scan_path(im) == "kernel"
+        # the block decode_attention planned, by layer kind: a ring's, and
+        # the two full-length caches' (the toy's are narrow: one block)
+        blocks = {b[0]: p for (k, b), p in im.attention_paths.items()
+                  if k == "decode_block"}
+        assert set(blocks) == {"window_attention", "full_attention",
+                               "cross_attention"}
+        assert blocks["window_attention"].startswith("ring")
+        assert blocks["full_attention"].startswith("full")
     else:
         assert {p for (k, _), p in im.attention_paths.items()
                 if k != "selective_scan"} == {"xla"}

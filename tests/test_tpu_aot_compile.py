@@ -143,6 +143,8 @@ _CASES = [
     ("prefill", 1, 16, 128, 4608, "ring4096"),
     ("decode", 1, 16, 128, 18432, "bf16"),
     ("prefill", 1, 16, 128, 18432, "bf16"),
+    # nemotron_h's one GQA layer in nine: 32 query heads on 2 K/V heads
+    ("decode", 2, 16, 128, 8192, "bf16"),
 ]
 
 
@@ -152,6 +154,37 @@ _CASES = [
 def test_kernel_compiles_for_v5e(one_chip, kernel, kv, gq, d, s, variant):
     compiled = _lower(kernel, one_chip, kv, gq, d, s, variant).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kv,s,variant,plan", [
+    (1, 18432, "bf16", "full2048"),
+    (1, 8192, "bf16", "full2048"),
+    (2, 8192, "bf16", "full1024"),
+    (1, 4608, "ring4096", "ring4608"),
+])
+def test_a_block_grown_by_bytes_compiles_in_the_default_scoped_vmem(
+        one_chip, kv, s, variant, plan):
+    """``decode_attention`` on one or two K/V heads takes the block planned
+    by bytes (PR 51) — 1 MB of K + V a grid step, a ring of 4608 slots whole
+    (2.4 MB, double-buffered) — and asks the compiler for no more scoped
+    VMEM than its default."""
+    from flexflow_tpu.ops.pallas.attention import decode_block_plan
+
+    window = int(variant[4:]) if variant.startswith("ring") else 0
+    assert decode_block_plan(
+        jax.ShapeDtypeStruct((R + 1, kv, s, 128), jnp.bfloat16),
+        window=window) == plan
+    text = _lower("decode", one_chip, kv, 16, 128, s, variant).compile(
+        ).as_text()
+    import re
+
+    call, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    # a ``vmem_limit_bytes`` would stand here as a scoped-memory entry
+    assert re.search(r'[^_]scoped_memory_configs":\[\]', call)
+    used = [int(n) for n in re.findall(
+        r'used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', call)]
+    assert used and 0 < max(used) < 16 * 2**20
 
 
 @pytest.mark.parametrize("rows", [48, 512], ids=["scan48", "flat512"])
