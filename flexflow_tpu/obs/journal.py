@@ -123,6 +123,10 @@ def _launch_prefill_scan(row, args, chunk_width):
     n = args.get("n_steps", 0)
     fed = args.get("prompt_tokens", 0)
     row[_F["prefill_scans"]] += 1
+    if args.get("pad"):
+        # all-pad chunks that build the program of a scan length
+        # (``InferenceManager.prefill_scan``): a launch, no prompt chunk
+        return
     row[_F["chunks"]] += n
     row[_F["chunk_rows"]] += n * chunk_width
     row[_F["chunk_tokens"]] += fed

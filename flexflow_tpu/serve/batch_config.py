@@ -231,9 +231,14 @@ class PrefillBatchConfig:
         """Tile-aligned constructor.
 
         ``segments``: iterable of ``(slot, token_ids, start_pos)`` — one
-        contiguous prompt chunk per request.  Returns ``(pbc, last_flat)``
+        contiguous prompt chunk per request, laid end to end in whole tiles:
+        a prefill wave's chunk holds several (the tail of one prompt, whole
+        short ones, the head of the next: ``RequestManager._prefill_chunks``),
+        a lone request's feed one.  Returns ``(pbc, last_flat)``
         where ``last_flat[slot]`` is the flat index of that segment's final
         token (where its first-generated-token logits appear).
+        ``num_tokens`` is the flat index past the last real row — the pads
+        between segments included, NOT the tokens fed.
 
         ``gate_slots``: iterable of slots whose segment ENDS its prompt in
         this chunk — enables LM-head gating (``logit_slots`` built from

@@ -12,10 +12,12 @@ the update is in-place in HBM.
 
 from __future__ import annotations
 
+import dataclasses
 from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core.interpreter import build_forward
 from ..core.pcg import PCG
@@ -300,6 +302,30 @@ def sample_tokens(logits, sample):
     return jax.lax.cond(temperature <= 0.0, lambda _: greedy, draw, None)
 
 
+def _pad_chunks(bcs, sample, n: int):
+    """``n`` all-pad chunks shaped as the stacked chunks ``bcs`` (and the
+    sample argument that goes with them): no token, every row of no request,
+    no logit slot, no sample fold — a stack the prefill scan runs without
+    touching a slot, under the program of its length.  Filled on the host
+    (a ``jnp.full`` per field and length would be a program each)."""
+    def stack(x, fill):
+        return None if x is None else jnp.asarray(
+            np.full((n,) + x.shape[1:], fill, x.dtype))
+
+    b = bcs.base
+    pads = dataclasses.replace(
+        bcs,
+        base=BatchConfig(tokens=stack(b.tokens, 0),
+                         request_index=stack(b.request_index, -1),
+                         token_position=stack(b.token_position, 0),
+                         num_tokens=stack(b.num_tokens, 0),
+                         seq_lens=stack(b.seq_lens, 0)),
+        logit_slots=stack(bcs.logit_slots, -1))
+    if sample is not None and len(sample) > 3:
+        sample = (*sample[:3], stack(sample[3], 0))
+    return pads, sample
+
+
 def decode_scan_width(bc) -> int:
     """Rows the decode scan's body runs on for the batch ``bc``: one per
     request slot.  A pure-decode batch holds at most one live row per
@@ -566,6 +592,8 @@ class InferenceManager:
         self._pscan = jax.jit(self._prefill_scan_impl, donate_argnums=(1,),
                               static_argnames=("overlap",),
                               compiler_options=opts)
+        # static signature -> the scan lengths run (see prefill_scan)
+        self._pscan_lengths: Dict[Tuple, set] = {}
         # mid-stretch slot join (on-device continuous batching): a tiny
         # program that activates one batch row between scan segments
         self._join = jax.jit(self._join_impl, static_argnames=("eos",),
@@ -1208,8 +1236,30 @@ class InferenceManager:
         short = self.max_tokens - last.shape[0]
         return jnp.pad(last, (0, short)) if short > 0 else last
 
+    @property
+    def _pscan_overlap(self) -> bool:
+        return bool(self.prefill_overlap and self._overlap_steps is not None)
+
+    def _pscan_ran(self, gated: bool, sample) -> set:
+        """The prefill-scan lengths run so far under one static signature
+        of ``_pscan``: LM head ``gated`` or not, the structure of the
+        ``sample`` argument (None: greedy), ``overlap``."""
+        return self._pscan_lengths.setdefault(
+            (gated, len(sample or ()), self._pscan_overlap), set())
+
+    def prefill_scan_longest(self, gated: bool, sample=None) -> int:
+        """The longest power of two such that it and every smaller one
+        have run as a prefill scan of chunks ``gated`` or not with a
+        ``sample`` argument of this structure (0: not even a scan of one
+        chunk): the lengths a feed can be cut into and compile nothing."""
+        ran = self._pscan_ran(gated, sample)
+        longest = 1
+        while longest in ran:
+            longest *= 2
+        return longest // 2
+
     def prefill_scan(self, bcs, sample=None, counts=None,
-                     flat_last: bool = False):
+                     flat_last: bool = False, coming=()):
         """Run a stacked PrefillBatchConfig (leading chunk axis) on device.
 
         ``sample``: optional ``(key, temperature, top_p)`` so the chunks
@@ -1217,10 +1267,38 @@ class InferenceManager:
         ``flat_last``: return ``(tokens, last)`` — ``last`` is the final
         chunk's ids in ``join_slot``'s flat layout (the same program either
         way: the caller that splices a prompt in just keeps it).
+
+        The set of scan lengths stays CLOSED under what has run: the scan
+        length is a static shape, one program a length, and a caller that
+        cuts its feeds into powers of two no longer than
+        :meth:`prefill_scan_longest` (``RequestManager._prefill_feed``)
+        must find each of them built.  So a length asked for the first time
+        is preceded, once, by every smaller power of two not run yet, each
+        on all-pad chunks — no token, every row of no request (the scratch
+        row), no logit slot: what a chunk's pad tiles are, so no slot's
+        cache or state moves, live decoders' included — under the same
+        span with ``pad=1`` (the journal counts the launch and no prompt
+        chunk).  ``coming``: the lengths the caller launches next, as the
+        rest of the same feed; they build themselves, so no pad scan is
+        spent on them.  After a feed has run, every later one of its
+        longest length or shorter compiles nothing.
         """
         assert self.params is not None, "call init_operators_inference() first"
         if self.fault_injector is not None:
             self.fault_injector.maybe_fail("prefill_scan")
+        n_chunks = int(bcs.base.tokens.shape[0])
+        ran = self._pscan_ran(bcs.logit_slots is not None, sample)
+        if n_chunks not in ran:
+            for k in range((n_chunks - 1).bit_length()):
+                if (1 << k) not in ran and (1 << k) not in coming:
+                    self._launch_prefill_scan(
+                        *_pad_chunks(bcs, sample, 1 << k), {"pad": 1})
+                    ran.add(1 << k)
+        out = self._launch_prefill_scan(bcs, sample, counts)
+        ran.add(n_chunks)
+        return out if flat_last else out[0]
+
+    def _launch_prefill_scan(self, bcs, sample, counts):
         n_chunks = int(bcs.base.tokens.shape[0])
         with self.telemetry.span("prefill_scan_dispatch", cat="dispatch",
                                  track="dispatch", prof=self.profiler,
@@ -1230,11 +1308,9 @@ class InferenceManager:
                                  **(counts or {})):
             tokens, last, self.state = with_stack_room(
                 self._pscan, self.params, self.state, bcs, sample,
-                self._page_view(),
-                overlap=bool(self.prefill_overlap
-                             and self._overlap_steps is not None))
+                self._page_view(), overlap=self._pscan_overlap)
         self._count_attention_paths()
-        return (tokens, last) if flat_last else tokens
+        return tokens, last
 
     def reset(self):
         """Clear all cache contents (new serving session)."""

@@ -1315,7 +1315,8 @@ class RequestManager:
                 spans, logit_rows=len(sample_points) if gate else None)
             n_prefill = sum(len(s[1]) for s in segments)
             self._note_batch(0, n_prefill, seq_lens)
-            self._count_feed("tiled", n_prefill, chunks=1)
+            self._count_feed("tiled", n_prefill, chunks=1,
+                             shared=int(len(segments) > 1))
             self._step_counts = self._launch_counts(spans, 0)
             return pbc, sample_points
 
@@ -1592,10 +1593,13 @@ class RequestManager:
                 and hasattr(im, "prefill_scan")
                 and req.prefill_offset % im.prefill_tile == 0)
 
-    def _count_feed(self, path: str, tokens: int, chunks: int = 0) -> None:
+    def _count_feed(self, path: str, tokens: int, chunks: int = 0,
+                    shared: int = 0) -> None:
         """Prompt tokens fed, by path (``prompt_feed.tiled_tokens`` /
-        ``.flat_tokens``); the tiled feed also counts its chunks and the
-        rows of them that held no prompt token."""
+        ``.flat_tokens``); the tiled feed also counts its chunks, the rows
+        of them that held no prompt token, and the ``shared`` of them that
+        held rows of more than one request (a wave's packed chunks; 0 where
+        every feed is one request's)."""
         tel = self.telemetry
         if not tel.enabled or not tokens:
             return
@@ -1604,6 +1608,7 @@ class RequestManager:
             tel.metrics.counter("prompt_feed.tiled_chunks").inc(chunks)
             tel.metrics.counter("prompt_feed.tiled_padded_rows").inc(
                 chunks * self.im.max_tokens - tokens)
+            tel.metrics.counter("prompt_feed.shared_chunks").inc(shared)
 
     def _prefill_stretch_possible(self) -> bool:
         """Can the whole current prefill wave run as on-device scans?
@@ -1628,15 +1633,25 @@ class RequestManager:
     def _prefill_chunks(self, gate: bool, sampling: bool, reqs=None,
                         depths=None):
         """Cut the remaining feed of ``reqs`` (default: every prefilling
-        request) into tile-aligned chunks: per-chunk numpy fields, logit
-        slots, sample folds, the sample points ``(chunk_idx, result_idx,
-        rid)`` and each chunk's ``(start, take)``.  Advances
-        ``prefill_offset``.  ``depths``: ``{slot: cache depth}`` of rows a
-        running chain is ahead of the committed host view on (their DEVICE
-        depths go into ``seq_lens``)."""
+        request) into tile-aligned chunks, the requests' tiles laid END TO
+        END: a request's remaining prompt is ``ceil(left / tile)`` tiles,
+        they fill the open chunk, the rest opens the next, and the next
+        request starts on the next free tile of the same chunk.  So a chunk
+        holds one segment ``(slot, tokens, start)`` for every request with
+        rows in it (at most one a slot; a prompt that crosses a chunk's end
+        is two segments in two consecutive chunks, which the scan runs in
+        order), and ONE request's feed is the chunks it always was.
+
+        Returns, per chunk: the numpy fields, the logit slots, the sample
+        folds and the segments' ``[(start, take)]``; and the sample points
+        ``(chunk_idx, result_idx, rid)``, one for every prompt that ENDS in
+        a chunk.  Advances ``prefill_offset``.  ``depths``: ``{slot: cache
+        depth}`` of rows a running chain is ahead of the committed host
+        view on (their DEVICE depths go into ``seq_lens``)."""
         im = self.im
         tile = im.prefill_tile
         cap = im.max_tokens
+        room = (cap // tile) * tile  # a chunk's rows, in whole tiles
         n_rows = im.max_requests if gate else cap
         chunks: List = []  # per-chunk numpy field tuples (BatchConfig order)
         ls_chunks: List = []  # per-chunk logit_slots (gated path)
@@ -1644,118 +1659,186 @@ class RequestManager:
         # (chunk_idx, result_idx, rid): result_idx is the SLOT when gated
         # (result arrays are [max_requests]), the flat token index otherwise
         points: List[Tuple[int, int, int]] = []
-        feeds: List[Tuple[int, int]] = []  # per-chunk (start, take)
+        feeds: List[List[Tuple[int, int]]] = []  # per-chunk [(start, take)]
         seq = np.zeros(im.max_requests, np.int32)
         for req in self._active():
             seq[req.slot] = req.seq_len
         for slot, depth in (depths or {}).items():
             seq[slot] = depth
+        segs: List[Tuple[Request, int, int]] = []  # the open chunk's
+        used = 0                                   # ... and its rows taken
+
+        def close():
+            nonlocal used
+            fields, last_flat = PrefillBatchConfig.np_fields(
+                [(r.slot, r.prefill_tokens[st: st + t], st)
+                 for r, st, t in segs],
+                seq, tile, max_tokens=cap, max_requests=im.max_requests)
+            # the requests whose prompts END here, each with its result row
+            done = [(r, r.slot if gate else last_flat[r.slot])
+                    for r, st, t in segs if st + t == len(r.prefill_tokens)]
+            points.extend((len(chunks), ridx, r.rid) for r, ridx in done)
+            # deterministic accounting: one model pass per chunk; gated
+            # chunks materialize logits only at the slots of the requests
+            # whose prompts end in them
+            self._prof_account(
+                [(r.rid, st, st + t) for r, st, t in segs],
+                logit_rows=len(done) if gate else None)
+            if sampling:
+                fc = np.zeros((n_rows, 2), np.int32)
+                for r, ridx in done:
+                    fc[ridx] = self._fold_for(r)
+                fold_chunks.append(fc)
+            ls_chunks.append(PrefillBatchConfig.np_logit_slots(
+                [r.slot for r, _ in done], last_flat, im.max_requests))
+            chunks.append(fields)
+            feeds.append([(st, t) for _, st, t in segs])
+            segs.clear()
+            used = 0
+
         for req in (self._active() if reqs is None else reqs):
             if req.status is not RequestStatus.PREFILLING:
                 continue
             while req.prefill_offset < len(req.prefill_tokens):
-                take = min((cap // tile) * tile,
-                           len(req.prefill_tokens) - req.prefill_offset)
                 start = req.prefill_offset
+                take = min(room - used, len(req.prefill_tokens) - start)
+                # (seq_lens as the chunk that holds this segment sees them:
+                # ``close`` copies them before a later segment moves them)
                 seq[req.slot] = start + take
-                fields, last_flat = PrefillBatchConfig.np_fields(
-                    [(req.slot, req.prefill_tokens[start: start + take],
-                      start)],
-                    seq, tile,
-                    max_tokens=cap, max_requests=im.max_requests,
-                )
+                segs.append((req, start, take))
                 req.prefill_offset += take
-                done = req.prefill_offset == len(req.prefill_tokens)
-                ridx = req.slot if gate else last_flat[req.slot]
-                if done:
-                    points.append((len(chunks), ridx, req.rid))
-                # deterministic accounting: one model pass per chunk;
-                # gated chunks materialize logits only at the (single)
-                # completing request's slot
-                self._prof_account(
-                    [(req.rid, start, start + take)],
-                    logit_rows=(1 if done else 0) if gate else None)
-                if sampling:
-                    fc = np.zeros((n_rows, 2), np.int32)
-                    if done:
-                        fc[ridx] = self._fold_for(req)
-                    fold_chunks.append(fc)
-                ls_chunks.append(PrefillBatchConfig.np_logit_slots(
-                    [req.slot] if done else [], last_flat, im.max_requests))
-                chunks.append(fields)
-                feeds.append((start, take))
+                used += -(-take // tile) * tile
+                if used == room:
+                    close()
+        if segs:
+            close()
         return chunks, ls_chunks, fold_chunks, points, feeds
+
+    def _sample_args(self, fold_chunks):
+        """The prefill scan's ``sample`` argument for a feed whose chunks'
+        folds are ``fold_chunks`` (kept on the host: :meth:`_stack_chunks`
+        ships a launch's share), or None where this manager decodes
+        greedily.  The per-request key schedule: the chunk carrying request
+        rid's completion samples its token n with fold (rid, n) — the same
+        key whatever chunking, segmentation or preemption produced it."""
+        import jax
+        import jax.numpy as jnp
+
+        if not len(fold_chunks):
+            return None
+        return (jax.random.PRNGKey(self.gen.seed),
+                jnp.float32(self.gen.temperature),
+                jnp.float32(self.gen.top_p), np.stack(fold_chunks))
+
+    def _stack_chunks(self, chunks, ls_chunks, sample, at: int = 0):
+        """One launch's arguments: ``chunks`` (numpy fields) stacked on the
+        host — ONE device transfer per field per launch, not five tiny ones
+        per chunk — with their logit slots (None: ungated) and the folds of
+        ``sample`` from chunk ``at`` on."""
+        import jax.numpy as jnp
+
+        stacked = PrefillBatchConfig(
+            base=BatchConfig(*(jnp.asarray(np.stack([c[i] for c in chunks]))
+                               for i in range(5))),
+            tile_size=self.im.prefill_tile,
+            logit_slots=None if ls_chunks is None
+            else jnp.asarray(np.stack(ls_chunks)))
+        return stacked, sample and (
+            *sample[:3], jnp.asarray(sample[3][at: at + len(chunks)]))
+
+    def build_prefill_scans(self, n_chunks: int) -> None:
+        """Build the prefill-scan programs of every power of two up to
+        ``n_chunks`` (at most 64), as this manager's feeds ask for them
+        (its LM-head gate, its sampling), on all-pad chunks, which move no
+        slot's cache or state.  :meth:`_prefill_feed` cuts no feed into
+        launches longer than the manager has run, so that a feed among live
+        decoders never waits for a compile; a deployment whose first feeds
+        are short would serve its long waves in short launches ever after.
+        This is how it (or a warm-up) asks for the longest wave it expects,
+        at start-up; a no-op where the lengths are built, or where no feed
+        is tiled (:meth:`_tiled_feed`)."""
+        im = self.im
+        if not (im.prefill_tile > 1 and im.use_pallas
+                and hasattr(im, "prefill_scan")):
+            return
+        n = 1 << (max(1, min(n_chunks, 64)).bit_length() - 1)
+        gate = im.gate_lm_head
+        fields, last_flat = PrefillBatchConfig.np_fields(
+            (), (), im.prefill_tile, im.max_tokens, im.max_requests)
+        ls = PrefillBatchConfig.np_logit_slots((), last_flat, im.max_requests)
+        rows = im.max_requests if gate else im.max_tokens
+        sample = self._sample_args(
+            [np.zeros((rows, 2), np.int32)] * n
+            if self.gen.temperature > 0.0 else [])
+        if im.prefill_scan_longest(gate, sample) < n:
+            im.prefill_scan(*self._stack_chunks(
+                [fields] * n, [ls] * n if gate else None, sample),
+                counts={"pad": 1})
 
     def _prefill_feed(self, joiners=None, depths=None, rows: int = 0):
         """THE tiled prompt feed, a wave's and a joiner's alike: cut the
-        remaining prompts (:meth:`_prefill_chunks`) — of every prefilling
-        request, or of ``joiners``, requests about to be spliced into a
-        running batch of ``rows`` live decode rows whose device depths are
-        ``depths`` — and dispatch the chunks through ``im.prefill_scan``,
-        asynchronously.  Returns ``(points, outs)`` — ``outs`` holds
+        remaining prompts (:meth:`_prefill_chunks`: several requests' tiles
+        share chunks) — of every prefilling request, or of ``joiners``,
+        requests about to be spliced into a running batch of ``rows`` live
+        decode rows whose device depths are ``depths`` (one a feed today:
+        :meth:`_join_one` takes its token from the feed's LAST chunk) — and
+        dispatch the chunks through ``im.prefill_scan``, asynchronously, in
+        power-of-two segments of lengths the manager has run.  Returns
+        ``(points, outs)`` — ``outs`` holds
         ``(first chunk, tokens [segment, T or R], last)`` per dispatched
         segment, all on the device (``last``: the segment's final chunk in
         ``join_slot``'s flat layout) — or None when a dispatch failed past
         the retry budget (:meth:`_fail_inflight` already requeued or
         failed the requests fed: the joiners, or every active one).
         """
-        import jax
-        import jax.numpy as jnp
-
         im = self.im
-        tile = im.prefill_tile
         gate = im.gate_lm_head
-        sampling = self.gen.temperature > 0.0
         with self._span("host_prepare", phase=True):
             chunks, ls_chunks, fold_chunks, points, feeds = \
-                self._prefill_chunks(gate, sampling, joiners, depths)
+                self._prefill_chunks(gate, self.gen.temperature > 0.0,
+                                     joiners, depths)
+            sample = self._sample_args(fold_chunks)
         affected = None if joiners is None else (
             lambda: [r.rid for r in joiners])
-        # stack chunk fields host-side (ONE device transfer per field per
-        # segment, not five tiny transfers per chunk) and scan in power-of-
-        # two segments so each distinct scan length compiles at most once
+        # scan in power-of-two segments so each distinct scan length
+        # compiles at most once — none longer than the longest the manager
+        # has run with every shorter one (``im.prefill_scan`` keeps that
+        # set closed), so a feed under load finds its programs built
+        # whatever totals the warm-up made.  The price: the first feeds'
+        # lengths bound every later feed's launches
+        # (:meth:`build_prefill_scans` raises the bound)
+        longest = im.prefill_scan_longest(gate, sample)
+        cut, left = [], len(chunks)
+        while left:
+            cut.append(1 << (min(left, longest or 64, 64).bit_length() - 1))
+            left -= cut[-1]
         outs = []
         at = 0
-        while at < len(chunks):
-            seg = 1 << (min(len(chunks) - at, 64).bit_length() - 1)
+        for k, seg in enumerate(cut):
             with self._span("host_prepare", phase=True):
-                stacked = PrefillBatchConfig(
-                    base=BatchConfig(*(
-                        jnp.asarray(np.stack(
-                            [c[i] for c in chunks[at: at + seg]]))
-                        for i in range(5)
-                    )),
-                    tile_size=tile,
-                    logit_slots=jnp.asarray(
-                        np.stack(ls_chunks[at: at + seg]))
-                    if gate else None,
-                )
-                smp = None
-                if sampling:
-                    # per-request key schedule: the chunk carrying request
-                    # rid's completion samples its token n with fold
-                    # (rid, n) — same key whatever chunking/segmentation/
-                    # preemption produced it
-                    smp = (jax.random.PRNGKey(self.gen.seed),
-                           jnp.float32(self.gen.temperature),
-                           jnp.float32(self.gen.top_p),
-                           jnp.asarray(np.stack(fold_chunks[at: at + seg])))
-            # each chunk's start offset and size: what the prefill
-            # kernel's least work is computed from
-            fed = sum(t for _, t in feeds[at: at + seg])
+                stacked, smp = self._stack_chunks(
+                    chunks[at: at + seg], ls_chunks[at: at + seg] if gate
+                    else None, sample, at)
+            # each request segment's start offset and size: what the
+            # prefill kernel's least work is computed from (``segments``
+            # above ``n_chunks``: a launch whose chunks are shared)
+            parts = [p for f in feeds[at: at + seg] for p in f]
+            fed = sum(t for _, t in parts)
             cnt = {"rows": rows, "joiners": len(joiners or ()),
-                   "prompt_tokens": fed,
-                   "ctx_sum": sum(st for st, _ in feeds[at: at + seg]),
+                   "prompt_tokens": fed, "segments": len(parts),
+                   "ctx_sum": sum(st for st, _ in parts),
                    **self._slot_state_counts(
-                       [(st, st + t) for st, t in feeds[at: at + seg]])}
+                       [(st, st + t) for st, t in parts])}
             res = self._guarded(
                 "prefill_scan",
-                lambda s=stacked, a=smp, c=cnt: im.prefill_scan(
-                    s, a, counts=c, flat_last=True),
+                lambda s=stacked, a=smp, c=cnt, rest=cut[k + 1:]:
+                im.prefill_scan(s, a, counts=c, flat_last=True, coming=rest),
                 affected_fn=affected)
             if res is None:
                 return None
-            self._count_feed("tiled", fed, chunks=seg)
+            self._count_feed(
+                "tiled", fed, chunks=seg,
+                shared=sum(len(f) > 1 for f in feeds[at: at + seg]))
             outs.append((at, *res))
             at += seg
         return points, outs

@@ -335,7 +335,7 @@ def test_a_hybrid_model_joiner_starts_from_zero_state(where):
 # ---------------------------------------------------------------------------
 # after the benchmark's warm-up nothing lowers or compiles
 # ---------------------------------------------------------------------------
-_EVENTS, _WATCHING = [], []
+_EVENTS, _WATCHING, _LISTENING = [], [], []
 
 
 def _on_duration(name, secs, **_):
@@ -344,17 +344,19 @@ def _on_duration(name, secs, **_):
         _EVENTS.append(name.rsplit("/", 1)[-1])
 
 
-@pytest.fixture(scope="module")
-def warmed_llm():
+@functools.lru_cache(maxsize=None)
+def _warmed(longest):
     """A toy ``LLM`` with the kernels on (interpreted) and the gated LM
-    head, warmed by ``benchmark.warmup.warm`` — the call list the timed
-    runs rely on — under the listener ``benchmark/run.py``'s
-    ``CompileWatch`` registers."""
+    head, warmed by ``benchmark.warmup.warm`` for prompts up to ``longest``
+    tokens — the call list the timed runs rely on — under the listener
+    ``benchmark/run.py``'s ``CompileWatch`` registers."""
     import flexflow_tpu.serve.api as api
     from benchmark import warmup
     from flexflow_tpu.serve import LLM
 
-    jax.monitoring.register_event_duration_secs_listener(_on_duration)
+    if not _LISTENING:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _LISTENING.append(True)
     real = api.InferenceManager
     api.InferenceManager = functools.partial(real, use_pallas=True)
     try:
@@ -365,12 +367,18 @@ def warmed_llm():
         api.InferenceManager = real
     assert llm.im.use_pallas and llm.im.gate_lm_head
     assert 1 < llm.im.prefill_tile < llm.im.max_tokens
-    mix = {"prompt_len": {"hi": 70}}
+    mix = {"prompt_len": {"hi": longest}}
+    del _EVENTS[:]
     _WATCHING.append("warm-up")
     warmup.warm(llm, mix, TINY.vocab_size, lambda msg: None)
     _WATCHING.pop()
     assert "backend_compile_duration" in _EVENTS, "the listener is deaf"
     return llm
+
+
+@pytest.fixture(scope="module")
+def warmed_llm():
+    return _warmed(70)
 
 
 def _toy_requests(n, seed):
@@ -409,5 +417,67 @@ def test_nothing_lowers_or_compiles_after_the_warm_up(warmed_llm, loop):
     assert spans(tel, "join_dispatch")
     assert all(a["prompt_tokens"] == 0
                for a in spans(tel, "step_dispatch"))
+    assert _EVENTS == [], \
+        f"{len(_EVENTS)} lowerings or compiles after the warm-up: {_EVENTS}"
+
+
+def _wave_chunks(rm):
+    """Chunks of every prefill wave in the journal, oldest first."""
+    from flexflow_tpu.obs import journal as J
+
+    rows = rm.journal.array()
+    kind = rows[:, J.FIELDS.index("kind")]
+    return rows[kind == J.KINDS.index("prefill_stretch"),
+                J.FIELDS.index("chunks")].tolist()
+
+
+# prompts up to ``hi`` tokens -> waves of an empty deployment (4 slots, 48
+# rows a chunk, tile 16).  ``warm`` reckons chunks a request: for 40 it
+# means waves of 4 and 3 chunks, whose prompts pack into 2 and 1 — and a
+# wave of four 40-token prompts is 4 (2 + 2, not a scan of 4 built under
+# load); for 70 it means 8 and 7, which pack into 6 and 5
+WAVE_TRAFFIC = {
+    40: [[40, 40, 40, 40], [33, 40, 17, 35], [40, 3, 40]],
+    70: [[70, 70, 70, 70], [70, 33, 70, 50], [3, 19, 64, 41], [70, 70, 66]],
+}
+
+
+@pytest.mark.parametrize("hi", sorted(WAVE_TRAFFIC))
+def test_waves_find_their_programs_built_after_the_warm_up(hi):
+    # waves of several prompts on an empty deployment share chunks, so
+    # their totals are none that ``benchmark.warmup.warm``'s arithmetic
+    # made: the program keeps its own set of scan lengths closed
+    llm = _warmed(hi)
+    rm, im = llm.rm, llm.im
+    warm_totals = set(_wave_chunks(rm))
+    longest = im.prefill_scan_longest(True)
+    assert longest == (2 if hi == 40 else 4)
+    before = len(_wave_chunks(rm))
+    tel = Telemetry()
+    rm.telemetry = im.telemetry = tel
+    rng = np.random.RandomState(hi)
+    del _EVENTS[:]
+    _WATCHING.append("waves")
+    try:
+        for lengths in WAVE_TRAFFIC[hi]:
+            out = rm.generate(
+                [rng.randint(1, TINY.vocab_size, size=n).tolist()
+                 for n in lengths], 5)
+            assert all(len(t) == 5 for t in out)
+    finally:
+        _WATCHING.pop()
+        from flexflow_tpu.obs import NULL_TELEMETRY
+
+        rm.telemetry = im.telemetry = NULL_TELEMETRY
+    served = _wave_chunks(rm)[before:]
+    tile, per = im.prefill_tile, im.max_tokens // im.prefill_tile
+    assert served == [-(-sum(-(-n // tile) for n in lengths) // per)
+                      for lengths in WAVE_TRAFFIC[hi]]
+    assert set(served) - warm_totals, "no total that the warm-up never made"
+    scans = spans(tel, "prefill_scan_dispatch")
+    assert not [a for a in scans if a.get("pad")]
+    assert max(a["n_chunks"] for a in scans) <= longest < max(served)
+    assert any(a["segments"] > a["n_chunks"] for a in scans)
+    assert tel.metrics.snapshot()["prompt_feed.shared_chunks"] > 0
     assert _EVENTS == [], \
         f"{len(_EVENTS)} lowerings or compiles after the warm-up: {_EVENTS}"
