@@ -31,7 +31,7 @@ import os
 from typing import Dict, Optional
 
 # repo-level default artifact: deliberate persistence only — nothing writes
-# here unless an operator (or bench) calls CalibrationStore.save() on it
+# here unless an operator calls CalibrationStore.save() on it
 DEFAULT_STORE_PATH = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     "artifacts", "calibration_store.json",

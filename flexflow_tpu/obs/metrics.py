@@ -2,14 +2,12 @@
 
 One process-local registry per :class:`~flexflow_tpu.obs.telemetry.Telemetry`
 handle, snapshotable to a plain dict — the shared accounting layer that
-``bench.py``'s serving sections, ``RequestManager.serve_with_arrivals``, and
-``scripts/trace_report.py`` consume instead of each keeping bespoke stat
-code.  Pure host-side Python (no jax import): updating a metric can never
+``RequestManager.serve_with_arrivals`` and ``scripts/trace_report.py``
+consume instead of each keeping bespoke stat code.  Pure host-side Python (no jax import): updating a metric can never
 touch a jitted program.
 
-Percentile convention matches the bench's historical reduction
-(``sorted[min(int(q*n), n-1)]`` — nearest-rank, err-low), so numbers are
-comparable across BENCH rounds that predate the registry.
+Percentile convention: ``sorted[min(int(q*n), n-1)]`` — nearest-rank,
+err-low (``benchmark/stats.py`` keeps its own copy of it).
 """
 
 from __future__ import annotations
@@ -139,6 +137,6 @@ class MetricsRegistry:
 
     def snapshot(self) -> Dict:
         """Plain-dict state: counters/gauges as scalars, histograms as
-        their summary dicts — JSON-ready for bench lines and JSONL export."""
+        their summary dicts — JSON-ready for the JSONL export."""
         return {name: m.snapshot()
                 for name, m in sorted(self._metrics.items())}

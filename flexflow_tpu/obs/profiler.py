@@ -31,8 +31,8 @@ decomposed half:
     already prices with (``simulator._step_flops`` / ``Linear.flops`` /
     ``_step_param_bytes`` / the KVAllocator's ``bytes_per_token``), so
     two runs of the same workload produce identical counters with no
-    device attached — the basis of the ``scripts/bench_compare.py``
-    perf-regression guardrail.
+    device attached — the basis of :func:`~flexflow_tpu.obs.report.
+    compare`'s exact class.
 
 * :class:`PlanCostCard` — the per-deployment constants that accounting
   uses, derived once per compiled plan (per stage under pp) from the
@@ -99,7 +99,7 @@ TIME_COMPONENT_FIELDS = tuple(f"{c}_ms" for c in COMPONENTS)
 
 # the deterministic work-counter vocabulary (see the accounting model in
 # the module docstring).  report.py folds these into the under-load /
-# time-budget sections and scripts/bench_compare.py treats every field
+# time-budget sections and obs.report.compare treats every field
 # with one of these names as an exact-by-default regression guard.
 WORK_COUNTERS = (
     "flops", "hbm_bytes_read", "hbm_bytes_written", "kv_bytes_touched",
@@ -108,7 +108,7 @@ WORK_COUNTERS = (
 )
 
 # per-request attribution subset (stamped into serve_with_arrivals
-# records — satellite: bench_compare gets deterministic per-run fields
+# records — obs.report.compare gets deterministic per-run fields
 # even with no device attached)
 REQUEST_WORK_COUNTERS = ("flops", "kv_bytes_touched", "dispatches")
 

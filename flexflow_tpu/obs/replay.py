@@ -37,8 +37,8 @@ Three pieces:
   ``pp_serve_cost``) and runs the recorded arrivals through a
   deterministic slot-level event simulation — per-class latency /
   goodput / outcome-mix deltas with no device attached, compared under
-  ``scripts/bench_compare.py``'s exact-counter/thresholded-latency
-  discipline (:meth:`ReplayHarness.diff`).
+  :func:`~flexflow_tpu.obs.report.compare`'s exact-counter/
+  thresholded-latency discipline (:meth:`ReplayHarness.diff`).
 
 Everything here is host-side Python on the virtual clock: recording a
 trace can never change serve outputs (the recorder only appends to
@@ -130,7 +130,7 @@ def injector_meta(injector) -> Optional[Dict]:
 
 class VirtualClock:
     """Deterministic replay clock: advances ``step`` seconds per reading
-    (the same contract as the bench dry-run sections' ``_Tick``)."""
+    (what ``serve_with_arrivals(clock=)`` reads in a hermetic run)."""
 
     def __init__(self, step: float = 1e-3, t: float = 0.0):
         self.step = step
@@ -340,8 +340,8 @@ class ReplayHarness:
     ``telemetry`` (optional) emits the EVENT_SCHEMA "replay" vocabulary:
     ``replay_started`` / ``replay_completed`` instants plus one
     ``replay_mismatch`` per fidelity violation, and the
-    ``replays_run`` / ``replay_mismatches`` exact counters
-    ``scripts/bench_compare.py`` guards.
+    ``replays_run`` / ``replay_mismatches`` exact counters (the last in
+    ``obs.report.compare``'s exact class).
     """
 
     def __init__(self, trace: TrafficTrace, telemetry=None):
@@ -586,30 +586,11 @@ class ReplayHarness:
     def diff(self, old_summary: Dict, new_summary: Dict,
              default_threshold: float = 0.10) -> Dict:
         """Compare two run summaries (recorded vs replayed, or two
-        what-if candidates) under ``scripts/bench_compare.py``'s
-        discipline: deterministic counters exact, latency fields
-        thresholded (increase = regression), throughput fields
+        what-if candidates) under :func:`~flexflow_tpu.obs.report.
+        compare`'s discipline: deterministic counters exact, latency
+        fields thresholded (increase = regression), throughput fields
         directional (decrease = regression)."""
-        bc = load_bench_compare()
-        return bc.compare(old_summary, new_summary,
-                          default_threshold=default_threshold)
+        from .report import compare
 
-
-def load_bench_compare():
-    """Import ``scripts/bench_compare.py`` (a script, not a package
-    module) by path — obs and the scripts share ONE comparison
-    discipline, so the replay diff can never drift from the CI gate."""
-    import importlib.util
-    import sys
-
-    cached = sys.modules.get("_ff_bench_compare")
-    if cached is not None:
-        return cached
-    root = os.path.dirname(os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))))
-    path = os.path.join(root, "scripts", "bench_compare.py")
-    spec = importlib.util.spec_from_file_location("_ff_bench_compare", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    sys.modules["_ff_bench_compare"] = mod
-    return mod
+        return compare(old_summary, new_summary,
+                       default_threshold=default_threshold)

@@ -1,23 +1,34 @@
 """Reductions over telemetry artifacts: JSONL summaries + serving records.
 
-Two consumers share this module:
+Consumers of this module:
 
 * ``scripts/trace_report.py`` — CLI over :func:`summarize_jsonl`: p50/p95
   TTFT/TPOT/queue-wait derived from the request-lifecycle events a
   ``Telemetry`` export carries, per-track span totals (the pp stage
   interleave), the pipeline bubble fraction, and the per-plan
   predicted-vs-measured error table.
-* ``bench.py`` — :func:`under_load_summary` is the ``serving_under_load``
-  section's record reduction (moved here from bench so the bench, the
-  hermetic tests, and the report CLI all run the SAME accounting).
+* ``scripts/replay_report.py`` / :class:`~flexflow_tpu.obs.replay.
+  ReplayHarness` — :func:`under_load_summary` reduces the records of a
+  ``serve_with_arrivals`` run (live, recorded or simulated), and
+  :func:`compare` diffs two such summaries.
 """
 
 from __future__ import annotations
 
 import json
+import re
 from typing import Dict, List, Optional, Sequence
 
 from .metrics import percentile
+from .profiler import WORK_COUNTERS
+from .telemetry import (
+    FLEET_REGRESSION_COUNTERS,
+    HOST_TICK_REGRESSION_COUNTERS,
+    REPLAY_REGRESSION_COUNTERS,
+    SLO_REGRESSION_COUNTERS,
+    TIER_REGRESSION_COUNTERS,
+    TRACE_REGRESSION_COUNTERS,
+)
 
 # request-lifecycle event names (the Telemetry.request_* schema)
 _ENQ = "request_enqueue"
@@ -346,7 +357,7 @@ def summarize_jsonl(path: str) -> Dict:
         if k.startswith("lane_pending_depth_")}
     # time-travel serving view: the replay events summarize_events
     # collected + the exact registry counters (REPLAY_COUNTERS —
-    # replay_mismatches joins bench_compare's exact class at threshold
+    # replay_mismatches joins compare's exact class at threshold
     # zero: any mismatch means determinism regressed)
     from .telemetry import REPLAY_COUNTERS
 
@@ -354,7 +365,7 @@ def summarize_jsonl(path: str) -> Dict:
         k: metrics[k] for k in REPLAY_COUNTERS if k in metrics}
     # host-tier view: the swap events summarize_events collected + the
     # exact registry counters (TIER_COUNTERS — kv_restore_failures joins
-    # bench_compare's exact class at threshold zero: a clean-path restore
+    # compare's exact class at threshold zero: a clean-path restore
     # must never degrade to recompute)
     from .telemetry import TIER_COUNTERS
 
@@ -362,9 +373,8 @@ def summarize_jsonl(path: str) -> Dict:
         k: metrics[k] for k in TIER_COUNTERS if k in metrics}
     # trace-drop hardening: surface the ring buffer's dropped-event
     # count under the exact-class regression counter name, so every
-    # bench section that embeds a summary carries it into bench_compare
-    # (a section silently losing telemetry events fails CI, not just a
-    # stderr warning in trace_report)
+    # summary carries it into compare (a run silently losing telemetry
+    # events fails the diff, not just a stderr warning in trace_report)
     summary["telemetry_events_dropped"] = summary["dropped"]
 
     pred_err: Dict[str, Dict] = {}
@@ -426,8 +436,7 @@ def memory_section(memory: Dict, metrics: Dict) -> Dict:
     dict (the ``{"kind": "memory"}`` JSONL line); ``metrics`` a registry
     snapshot — the gauge/histogram names come from ``MEMORY_GAUGES`` /
     ``KV_OCCUPANCY_HIST`` so the emitter and this reduction share one
-    vocabulary.  Shared by ``bench.py --dry-run``'s ``memory_ledger``
-    section and the trace-report CLI (one accounting, two consumers).
+    vocabulary.
     """
     from .memory import (HOST_TIER_GAUGES, KV_OCCUPANCY_HIST, MEMORY_GAUGES,
                          PAGED_GAUGES)
@@ -490,9 +499,8 @@ def validate_jsonl(path: str) -> List[str]:
     with their required fields, well-formed trace events per phase, and —
     for the typed ``request``/``dispatch``/``plan`` categories — names and
     required args from ``telemetry.EVENT_SCHEMA``, the single vocabulary
-    the emitters share.  ``bench.py --dry-run``'s export is validated by a
-    tier-1 test, so the bench-side emitters and this parser cannot drift
-    apart silently (``scripts/trace_report.py --check`` is the CLI).
+    the emitters share (tests/test_trace_report.py holds each of its events
+    to this function; ``scripts/trace_report.py --check`` is the CLI).
 
     Free-form spans/counters on other categories are NOT constrained —
     instrumentation may add tracks freely; only the typed vocabulary is
@@ -624,7 +632,7 @@ def under_load_summary(records: Dict, makespan_s: Optional[float] = None,
     total_tokens = sum(len(r["tokens"]) for r in done)
     # deterministic work counters (obs/profiler.py): records carry a
     # per-request "work" dict when a StepProfiler was attached — the
-    # totals give bench_compare device-free regression fields
+    # totals give compare device-free regression fields
     work_recs = [r["work"] for r in recs if isinstance(r.get("work"), dict)]
     work = None
     if work_recs:
@@ -683,4 +691,109 @@ def under_load_summary(records: Dict, makespan_s: Optional[float] = None,
            if class_summary is not None else {}),
         **({"deferred_requests": deferred_total}
            if deferred_total is not None else {}),
+    }
+
+
+# ---- the comparator of two summaries (``ReplayHarness.diff``) ----
+# Leaf keys are classed by NAME.  Counters computed from host bookkeeping
+# (the work counters, and the bad-if-increasing subsets telemetry.py
+# names) are compared always and exactly: two runs of one seeded workload
+# must agree to the digit with no device attached, so any increase — or
+# a counter that vanished from the new document — is a regression.
+# Latency names regress when they rise past the threshold, throughput
+# names when they fall past it; every other field is ignored (the
+# comparator guards cost, not content).
+_EXACT_COUNTERS = frozenset(
+    WORK_COUNTERS
+    + FLEET_REGRESSION_COUNTERS
+    + SLO_REGRESSION_COUNTERS
+    + HOST_TICK_REGRESSION_COUNTERS
+    + REPLAY_REGRESSION_COUNTERS
+    + TIER_REGRESSION_COUNTERS
+    + TRACE_REGRESSION_COUNTERS)
+_LATENCY_RE = re.compile(
+    r"(tpot|ttft|queue_wait|prefill(?!_tokens)|transfer|wall|downtime"
+    r"|latency|overhead)", re.I)
+_THROUGHPUT_RE = re.compile(r"(goodput|tokens_per_sec|tok_s|mfu)", re.I)
+_TIME_SUFFIX_RE = re.compile(r"_(ms|s|us)$")
+
+
+def classify(leaf_key: str) -> Optional[str]:
+    """'counter' | 'latency' | 'throughput' | None for one leaf key."""
+    if leaf_key in _EXACT_COUNTERS:
+        return "counter"
+    if _THROUGHPUT_RE.search(leaf_key):
+        return "throughput"
+    if _LATENCY_RE.search(leaf_key) and (
+            _TIME_SUFFIX_RE.search(leaf_key)
+            or "ticks" in leaf_key or "frac" in leaf_key):
+        return "latency"
+    return None
+
+
+def walk(doc, prefix=""):
+    """Yield (dotted_path, leaf_key, numeric_value) for every numeric
+    leaf (bools excluded; list indices join the path)."""
+    if isinstance(doc, dict):
+        for k, v in doc.items():
+            yield from walk(v, f"{prefix}.{k}" if prefix else str(k))
+    elif isinstance(doc, (list, tuple)):
+        for i, v in enumerate(doc):
+            yield from walk(v, f"{prefix}[{i}]")
+    elif isinstance(doc, bool):
+        return
+    elif isinstance(doc, (int, float)):
+        leaf = prefix.rsplit(".", 1)[-1]
+        leaf = re.sub(r"\[\d+\]$", "", leaf)
+        yield prefix, leaf, float(doc)
+
+
+def compare(old: dict, new: dict, default_threshold: float = 0.10,
+            counter_threshold: float = 0.0,
+            overrides=None) -> dict:
+    """Compare two JSON documents field by field (``overrides`` maps a
+    leaf key to its own threshold): returns ``{"ok", "compared",
+    "regressions", "improvements"}``, a vanished counter among the
+    regressions."""
+    overrides = overrides or {}
+    old_leaves = {path: (leaf, v) for path, leaf, v in walk(old)}
+    new_leaves = {path: (leaf, v) for path, leaf, v in walk(new)}
+    regressions, improvements, missing = [], [], []
+    compared = 0
+    for path, (leaf, v_old) in sorted(old_leaves.items()):
+        kind = classify(leaf)
+        if kind is None:
+            continue
+        if path not in new_leaves:
+            if kind == "counter":
+                # a deterministic guard field that vanished IS a
+                # regression: the new run no longer proves its work
+                missing.append({"field": path, "kind": kind,
+                                "old": v_old})
+            continue
+        v_new = new_leaves[path][1]
+        compared += 1
+        thr = overrides.get(leaf,
+                            counter_threshold if kind == "counter"
+                            else default_threshold)
+        if v_old == 0:
+            delta = 0.0 if v_new == 0 else float("inf")
+        else:
+            delta = (v_new - v_old) / abs(v_old)
+        worse = delta > thr if kind != "throughput" else (-delta) > thr
+        better = delta < -thr if kind != "throughput" else delta > thr
+        entry = {"field": path, "kind": kind, "old": v_old, "new": v_new,
+                 "delta_frac": (round(delta, 4)
+                                if delta != float("inf") else None),
+                 "threshold": thr}
+        if worse:
+            regressions.append(entry)
+        elif better:
+            improvements.append(entry)
+    regressions.extend(missing)
+    return {
+        "ok": not regressions,
+        "compared": compared,
+        "regressions": regressions,
+        "improvements": improvements,
     }

@@ -15,10 +15,9 @@ the InferenceManager (and, for pipeline serving, every stage dispatch) —
 one handle, one clock, one export.
 
 **Serving lifecycle schema.**  The ``request_*`` methods are the canonical
-event vocabulary: ``RequestManager`` emits through them, ``bench.py
---dry-run`` synthesizes through them, and ``scripts/trace_report.py``
-parses exactly their names/args — adding a lifecycle event means adding a
-method here, so the three cannot drift apart.
+event vocabulary: ``RequestManager`` emits through them and
+``scripts/trace_report.py`` parses exactly their names/args — adding a
+lifecycle event means adding a method here, so the two cannot drift apart.
 
 **Disabled = no-op, guaranteed.**  ``NULL_TELEMETRY`` (a
 :class:`NullTelemetry`) answers every instrumentation call with a constant
@@ -43,8 +42,8 @@ from .trace import Span, TraceRecorder
 
 # the resilience counter vocabulary (emitted by the request_rejected/
 # cancelled/timed_out/preempted/failed + dispatch_retry/fault_observed
-# methods below) — report.summarize_jsonl and bench's dry-run section both
-# import THIS tuple, so a renamed counter cannot silently drop from either
+# methods below) — report.summarize_jsonl imports THIS tuple, so a
+# renamed counter cannot silently drop from the report
 RESILIENCE_COUNTERS = (
     "requests_rejected", "requests_cancelled", "requests_timeout",
     "requests_preempted", "requests_failed", "recompute_tokens",
@@ -55,8 +54,7 @@ RESILIENCE_COUNTERS = (
 # Telemetry's methods emit exactly these; report.summarize_jsonl parses
 # them; scripts/trace_report.py --check validates exported JSONLs against
 # THIS table — adding a lifecycle/plan event means adding a row here, so
-# the three cannot drift apart (satellite of ISSUE 6: bench and
-# trace_report schemas can never diverge silently).
+# the three cannot drift apart.
 EVENT_SCHEMA = {
     "request_enqueue": ("request", ("trace_id",)),
     "request_admit": ("request", ("trace_id",)),
@@ -140,7 +138,7 @@ EVENT_SCHEMA = {
 }
 
 # migration counter/gauge vocabulary (report.py folds these into the
-# ``migrations`` summary section; the dry-run section and trace_report
+# ``migrations`` summary section; the emitters and trace_report
 # share THIS tuple so a renamed metric cannot silently drop from either).
 # The first two are exact cumulative counters; the downtime/preempted
 # entries are gauges holding the LAST migration's values — per-migration
@@ -151,9 +149,8 @@ MIGRATION_COUNTERS = (
 )
 
 # fleet counter/gauge vocabulary (serve/fleet.py; report.py folds these
-# into the ``fleet`` summary section — one tuple shared by the emitters,
-# the report, and the bench dry-run so a renamed metric cannot silently
-# drop from any of them).  The ``replica_*``/``failovers_total`` entries
+# into the ``fleet`` summary section — one tuple shared by the emitters
+# and the report so a renamed metric cannot silently drop from either).  The ``replica_*``/``failovers_total`` entries
 # are exact cumulative counters; ``fleet_replicas_healthy`` /
 # ``fleet_replicas_alive`` / ``fleet_queue_depth`` are gauges the router
 # publishes every fleet tick.
@@ -164,7 +161,7 @@ FLEET_COUNTERS = (
     "fleet_replicas_total", "fleet_queue_depth",
 )
 
-# the monotone bad-if-increasing subset scripts/bench_compare.py treats
+# the monotone bad-if-increasing subset obs.report.compare treats
 # like deterministic WORK_COUNTERS (exact compare, any increase between
 # two runs of the same workload is a regression — more replicas failing
 # per served token); the health gauges stay out (a gauge's direction is
@@ -176,7 +173,7 @@ FLEET_REGRESSION_COUNTERS = (
 
 # SLO-lane / brownout counter vocabulary (serve/slo.py; report.py folds
 # these into the ``slo`` summary section — one tuple shared by the
-# emitters, the report, and the bench dry-run).  All are exact cumulative
+# emitters and the report).  All are exact cumulative
 # counters except ``brownout_level``, a gauge holding the ladder's
 # current level.
 SLO_COUNTERS = (
@@ -184,7 +181,7 @@ SLO_COUNTERS = (
     "brownout_escalations", "brownout_deescalations", "brownout_level",
 )
 
-# the monotone bad-if-increasing subset that joins bench_compare's exact
+# the monotone bad-if-increasing subset that joins compare's exact
 # class (deterministic on the seeded virtual clock): more shed /
 # deferred requests or more ladder escalations for the same workload
 # means the lanes got less graceful.  De-escalations and the level gauge
@@ -197,10 +194,10 @@ SLO_REGRESSION_COUNTERS = (
 # Host-tick elimination ratios (on-device continuous batching,
 # serve/request_manager.py chained decode stretches).  Raw ``dispatches``
 # and ``host_syncs`` are already exact-class via WORK_COUNTERS; these are
-# the DERIVED per-unit ratios the ``host_tick`` bench section emits —
+# the DERIVED per-unit ratios of a run's summary —
 # deterministic on the virtual clock and monotone bad-if-increasing
 # (more dispatches per token or host syncs per stretch means the host
-# tick crept back in), so bench_compare compares them exactly too.
+# tick crept back in), so obs.report.compare holds them exactly too.
 # ``stretch_joins`` (mid-stretch slot joins) is reported but stays out
 # of the regression class — its direction depends on the arrival mix.
 HOST_TICK_REGRESSION_COUNTERS = (
@@ -209,13 +206,12 @@ HOST_TICK_REGRESSION_COUNTERS = (
 
 # Trace-replay counter vocabulary (obs/replay.py; report.py folds these
 # into the ``replay`` summary section — one tuple shared by the
-# emitters, the report, and the bench ``trace_replay`` dry-run).  All
-# exact cumulative counters.
+# emitters and the report).  All exact cumulative counters.
 REPLAY_COUNTERS = (
     "traces_recorded", "replays_run", "replay_mismatches",
 )
 
-# the monotone bad-if-increasing subset joining bench_compare's exact
+# the monotone bad-if-increasing subset joining compare's exact
 # class: ANY replay mismatch means a recorded run stopped replaying
 # bit-identically — the strongest determinism regression signal the
 # repo has, so the threshold is exactly zero.
@@ -225,8 +221,8 @@ REPLAY_REGRESSION_COUNTERS = (
 
 # Trace-drop hardening: the TraceRecorder ring buffer's dropped-event
 # count was only a stderr WARNING in trace_report; as an exact-class
-# counter, a bench section that silently starts losing telemetry events
-# (capacity regression, emit storm) fails bench_compare instead.
+# counter, a run that silently starts losing telemetry events
+# (capacity regression, emit storm) fails obs.report.compare instead.
 # report.py stamps it into every summary from the telemetry_meta line.
 TRACE_REGRESSION_COUNTERS = (
     "telemetry_events_dropped",
@@ -234,14 +230,14 @@ TRACE_REGRESSION_COUNTERS = (
 
 # Host-tier KV spill/restore counter vocabulary (serve/kv_paged.py;
 # report.py folds these into the ``tier`` summary section — one tuple
-# shared by the emitters, the report, and the bench ``kv_tiering``
-# dry-run).  All exact cumulative counters on the seeded virtual clock.
+# shared by the emitters and the report).  All exact cumulative counters
+# on the seeded virtual clock.
 TIER_COUNTERS = (
     "kv_pages_spilled", "kv_pages_restored", "kv_swap_bytes",
     "kv_restore_failures", "recompute_tokens_saved",
 )
 
-# the monotone bad-if-increasing subset joining bench_compare's exact
+# the monotone bad-if-increasing subset joining compare's exact
 # class: a restore failure means a checksum-verified swap-in degraded to
 # recompute — correct but strictly worse, so the clean-path threshold is
 # exactly zero (kv_spilled/kv_restored materialize it at 0 so a healthy
@@ -623,7 +619,7 @@ class Telemetry:
         """One per-request fidelity violation: ``field`` (tokens /
         outcome / failovers / presence) diverged from the recording.
         Exact-class regression counter — any increase fails
-        bench_compare."""
+        ``obs.report.compare``."""
         self.metrics.counter("replay_mismatches").inc()
         return self.trace.instant("replay_mismatch", "replay", "replay",
                                   trace_id=trace_id, field=field)
@@ -664,7 +660,7 @@ class Telemetry:
         """One restore degraded to the r9 recompute feed (checksum
         corruption or swap-in retry exhaustion).  Exact-class regression
         counter — any increase on a clean-path workload fails
-        bench_compare."""
+        ``obs.report.compare``."""
         self.metrics.counter("kv_restore_failures").inc()
         return self.trace.instant("kv_restore_failed", "tier", "tier",
                                   trace_id=trace_id, reason=reason)

@@ -45,9 +45,9 @@ class TPUSpec:
     # tree-verify pass) costs the same PER TOKEN as incremental decoding —
     # macro_cost = tpot * (1 + break_even * depth) by definition, so the
     # serve search prices a spec plan as tpot * (1 + be*d) / (1 + a*d) for
-    # live acceptance a (search/serve_search.py).  MEASURED: BENCH r05's
-    # spec_break_even_acceptance (0.439 at the 7B-slice bench shape,
-    # depth 5); calibratable like every constant here (with_calibration
+    # live acceptance a (search/serve_search.py).  NOT MEASURED on this
+    # installation (ROADMAP C6): 0.439 is a figure from before the chip,
+    # at depth 5; calibratable like every constant here (with_calibration
     # field + CalibrationStore time-like scaling — a machine whose verify
     # step is relatively slower than modeled raises the break-even).
     spec_break_even_acceptance: float = 0.439

@@ -270,7 +270,7 @@ def _spec_factor(machine: MachineModel, feats: Optional[Dict], opt: Dict):
     """Speculative TPOT multiplier for one draft shape under one machine
     and workload: ``(1 + break_even*depth) / (1 + acceptance*depth)``.
 
-    The measured break-even acceptance (BENCH r05: the acceptance at
+    The break-even acceptance (``TPUSpec``'s constant: the acceptance at
     which one macro-step — ``depth`` draft levels + one tree-verify pass
     — costs the same per token as incremental decoding) parametrizes the
     ENTIRE macro-step overhead as ``macro = tpot * (1 + be*depth)``;
@@ -288,8 +288,8 @@ def _spec_factor(machine: MachineModel, feats: Optional[Dict], opt: Dict):
 
     NOT priced here: the draft model's weights/KV and the spec-tree
     buffers (co-resident HBM — gate them via ``hbm_cap`` or the spec
-    manager's dual-allocator accounting); a draft much larger than the
-    bench's would also shift the measured break-even.
+    manager's dual-allocator accounting); a larger draft would also
+    shift the break-even.
 
     Returns ``(factor, acceptance, break_even, depth)``.
     """
@@ -507,8 +507,8 @@ def search_serve_plan(
     :func:`_spec_factor`: TPOT scales by ``(1 + break_even*depth) /
     (1 + acceptance*depth)`` with acceptance read from the workload
     profile's ``mean_spec_acceptance`` (the live histogram the verify
-    rounds feed) and the MEASURED break-even acceptance a calibratable
-    machine constant (``TPUSpec.spec_break_even_acceptance``, BENCH r05).
+    rounds feed) and the break-even acceptance a calibratable
+    machine constant (``TPUSpec.spec_break_even_acceptance``).
     Above break-even the spec variant wins and the plan key gains a
     ``_spec_w{w}d{d}`` suffix (+ a ``spec`` sub-dict with the pricing
     inputs); at or below it the incremental plan is returned — so the
@@ -765,8 +765,8 @@ def search_serve_plan(
                              f"d{best['spec']['depth']}")
     if spec_opts and spec_be is not None:
         # the flip threshold the decision was priced against — visible in
-        # the spec_serving dry-run bench section even when the non-spec
-        # plan wins
+        # the returned plan (tests/test_serve_search.py) even when the
+        # non-spec plan wins
         best["spec_break_even"] = round(spec_be, 4)
     best["memory_parts_gb"] = \
         candidates[f"tp{best['tp']}_pp{best['pp']}"]["memory_parts_gb"]

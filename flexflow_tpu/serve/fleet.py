@@ -177,7 +177,7 @@ class FleetRouter:
     :meth:`generate` / :meth:`serve_with_arrivals` drive the replicas
     round-robin (one replica tick each per fleet tick), and
     :meth:`kill_replica` / :meth:`schedule_kill` are the chaos levers the
-    seeded tests and the hermetic bench section drive.
+    seeded tests (tests/test_fleet.py) drive.
     """
 
     def __init__(self, replicas: Sequence, gen: Optional[GenerationConfig]
@@ -928,8 +928,8 @@ class FleetRouter:
 
     def schedule_kill(self, name: str, at_tick: int) -> None:
         """Arrange :meth:`kill_replica` at fleet tick ``at_tick`` —
-        deterministic on the virtual clock (the seeded chaos runs and
-        the hermetic bench section stage mid-decode deaths with it)."""
+        deterministic on the virtual clock (the seeded chaos runs of
+        tests/test_fleet.py stage mid-decode deaths with it)."""
         self._kills[name] = int(at_tick)
 
     # ------------------------------------------------------------------

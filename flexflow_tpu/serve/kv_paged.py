@@ -375,7 +375,7 @@ class PagedKVAllocator(KVAllocator):
         # ``pages_mapped`` work counter (obs/profiler.py)
         self.pages_mapped = 0
         # host-tier swap counters (cumulative; the tier regression class
-        # in bench_compare).  The tier itself is attached explicitly
+        # of obs.report.compare).  The tier itself is attached explicitly
         # (attach_host_tier) and survives allocate()/teardown(): KV at a
         # position is a pure function of the fed token prefix, so a host
         # copy stays valid across buffer reallocation.

@@ -753,7 +753,7 @@ class RequestManager:
             # class's output cap applied up front (truncation only — the
             # served tokens stay a bit-identical PREFIX of the unloaded
             # run's stream).  Counted only when something actually
-            # changed — lane_degraded_total is in bench_compare's exact
+            # changed — lane_degraded_total is in obs.report.compare's exact
             # class, so a no-op "degradation" must not inflate it
             changed = req.spec
             req.spec = False
@@ -2708,8 +2708,8 @@ class RequestManager:
     def serve_with_arrivals(self, arrivals, clock=None, record_trace=None,
                             _t0=None, _records=None, _open=None):
         """Arrival-driven serving: requests join the running admit/retire
-        loop at their offered times (open-loop load, the serving_under_load
-        bench's engine).
+        loop at their offered times (open-loop load; the loop every cell
+        of ``benchmark/`` is driven through).
 
         ``arrivals``: iterable of ``(t_offset_s, prompt_tokens,
         max_new_tokens_or_None)`` — offsets from loop start; admitted once
@@ -2923,7 +2923,7 @@ class RequestManager:
             rec["kv_bytes"] = req.kv_bytes
             # deterministic per-request work counters (obs/profiler.py):
             # flops / kv_bytes_touched / dispatches — device-free fields
-            # the under-load summary totals and bench_compare guards
+            # the under-load summary totals and obs.report.compare guards
             if self.profiler.enabled:
                 rec["work"] = self.profiler.request_work(rid)
             # ALWAYS emit the TTFT decomposition: queue wait runs from

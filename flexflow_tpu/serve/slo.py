@@ -283,7 +283,7 @@ class BrownoutController:
 
     Host-side only and deterministic: given the same signal sequence the
     level walk is identical, which is what lets the hermetic
-    ``slo_overload`` bench pin "up the ladder and back down, zero
+    tests (tests/test_slo.py) pin "up the ladder and back down, zero
     flapping" on a virtual clock.
     """
 
@@ -303,7 +303,7 @@ class BrownoutController:
         self._slo_seen: Dict[str, int] = {}  # hist name -> count consumed
         self.evaluations = 0
         # (evaluation index, new level, reason) per transition — the
-        # hermetic bench reads this to pin the monotone up-then-down walk
+        # hermetic tests read this to pin the monotone up-then-down walk
         self.history: List[Tuple[int, BrownoutLevel, str]] = []
 
     # ------------------------------------------------------------------

@@ -10,7 +10,7 @@ Usage::
 
     # what-if: price tp/pp/micro-batch candidates against the recorded
     # arrival stream with NO device attached, and diff them under
-    # bench_compare's discipline
+    # obs.report.compare's discipline
     python scripts/replay_report.py trace.jsonl \
         --what-if tp1_pp2_m2 --what-if tp2_pp1 --fleet-size 2
 
@@ -23,7 +23,7 @@ Three modes over one versioned trace artifact
   Exit nonzero on any violation, same contract as
   ``trace_report.py --check``.
 * default — ``under_load_summary`` of the RECORDED outcomes: the same
-  reduction a live ``serve_with_arrivals`` run feeds the bench, so a
+  reduction a live ``serve_with_arrivals`` run's records get, so a
   trace summarizes with identical accounting (goodput, per-class
   TTFT/TPOT p50/p95, outcome mix, per-replica breakdown).
 * ``--what-if KEY`` (repeatable) — price candidate plans against the
@@ -33,15 +33,15 @@ Three modes over one versioned trace artifact
   2-cpu machine unless ``--calibrated`` points at real telemetry), then
   run through the harness's deterministic slot-level simulation.  The
   FIRST candidate is the baseline; every further candidate is diffed
-  against it with ``scripts/bench_compare.py``'s exact-counter /
-  thresholded-latency discipline (``ReplayHarness.diff``).  Exit code
+  against it with :func:`flexflow_tpu.obs.report.compare`'s exact-counter
+  / thresholded-latency discipline (``ReplayHarness.diff``).  Exit code
   reflects the LAST diff (nonzero = the later candidate regresses the
   baseline) so CI can gate on a planned downgrade.
 
 Fidelity replay (re-driving a real deployment and asserting
 bit-identity) needs a built engine, so it lives in the library
-(``ReplayHarness.replay`` / ``verify``) and the bench's hermetic
-``trace_replay`` dry-run section — not behind this CLI.
+(``ReplayHarness.replay`` / ``verify``; ``tests/test_replay.py``) — not
+behind this CLI.
 """
 
 import argparse

@@ -22,7 +22,7 @@ exporting handle): per-phase host time totals/fractions (host_admit /
 host_prepare / dispatch / per-stage + hop / readback), the deterministic
 work counters
 (flops, KV bytes touched, dispatches, jit recompiles, host syncs, pages
-mapped/COW'd — the ``scripts/bench_compare.py`` guardrail fields), and
+mapped/COW'd — the fields ``obs.report.compare`` holds exactly), and
 the per-plan per-COMPONENT predicted-vs-executed error table
 (``attention_ms`` ... ``host_overhead_ms``) whose ``suggested_scale``
 entries feed component-level ``MachineModel``/search calibration.
@@ -50,8 +50,7 @@ request the ladder rejected, the exact ``SLO_COUNTERS`` registry view
 (deferral/shed/degrade totals, escalation/de-escalation counts, the
 ``brownout_level`` gauge), and the per-class ``lane_pending_depth_*``
 gauges.  Per-class TTFT/TPOT attainment lives in the
-``under_load_summary`` ``per_class`` breakdown the bench sections
-carry.
+``under_load_summary`` ``per_class`` breakdown.
 
 The ``replay`` section is the time-travel view (obs/replay.py):
 ``trace_recorded`` artifact saves, ``replay_started`` /
@@ -59,28 +58,28 @@ The ``replay`` section is the time-travel view (obs/replay.py):
 bit-identity verdict), per-request ``replay_mismatch`` fidelity
 violations, and the exact ``REPLAY_COUNTERS`` registry view
 (``traces_recorded`` / ``replays_run`` / ``replay_mismatches`` — the
-last joins ``bench_compare``'s exact class at threshold zero).  The
+last joins ``obs.report.compare``'s exact class at threshold zero).  The
 recorded-vs-replayed diff itself is ``scripts/replay_report.py``.
 
 A trace whose ring buffer dropped events is TRUNCATED — the summary is
 computed from what survived — so ``dropped > 0`` prints an explicit
 warning to stderr (satellite of ISSUE 6: a truncated trace must not
 masquerade as a complete one), and the count is ALSO surfaced as the
-``telemetry_events_dropped`` exact-class counter so a bench section
-that starts losing events fails ``bench_compare`` instead of just
-warning here.
+``telemetry_events_dropped`` exact-class counter so a run that starts
+losing events fails ``obs.report.compare`` instead of just warning
+here.
 
 ``--check`` validates the JSONL against the expected event schema
 (:func:`flexflow_tpu.obs.report.validate_jsonl` — line kinds, per-phase
 trace-event fields, and the typed request/dispatch/plan vocabulary from
 ``telemetry.EVENT_SCHEMA``) and exits nonzero on unknown/missing fields,
-so the bench emitters and this report's parser can never drift apart
-silently (a tier-1 test runs it on ``bench.py --dry-run`` output).
+so the emitters and this report's parser can never drift apart
+silently (tests/test_trace_report.py holds every event of the vocabulary
+to it).
 
 The reduction itself lives in :mod:`flexflow_tpu.obs.report`
-(``summarize_jsonl``) so ``bench.py --dry-run``'s observability section and
-this CLI can never disagree — a tier-1 test round-trips one through the
-other (tests/test_trace_report.py).
+(``summarize_jsonl``); a tier-1 test round-trips a real export through
+this CLI (tests/test_trace_report.py).
 """
 
 import argparse

@@ -91,14 +91,6 @@ def test_machine_model_unknown_device_kind_raises():
         MachineModel.for_mesh(_fake_mesh("TPU v9"))
 
 
-def test_bench_peak_table_unknown_device_kind_raises():
-    import bench
-
-    assert bench.peak_hbm("TPU v5 lite") == 819e9
-    with pytest.raises(ValueError, match="TPU v9"):
-        bench.peak_hbm("TPU v9")
-
-
 def test_broken_cost_cache_raises(tmp_path):
     from flexflow_tpu.search.measure import CostCache
 

@@ -13,6 +13,7 @@ Pins:
   after the store is committed and auto-applied by ``search_serve_plan``.
 """
 
+import dataclasses
 import json
 import os
 
@@ -203,16 +204,40 @@ def _serve_graph():
     return ff
 
 
+def calibration_scenario():
+    """The shared hermetic calibration-loop scenario (tests/
+    test_plan_health.py imports it too): the tiny llama-shaped serve
+    graph, a "true" machine with expensive ICI (so decode-heavy vs
+    prompt-heavy mixes have DIFFERENT winning plans), a "skewed" machine
+    whose hardware constants over-promise 2.5x (the deliberate mis-scale
+    the loop must correct), and the reference traffic features.  Graph
+    building is shape inference only, nothing executes on a device."""
+    true_spec = dataclasses.replace(
+        TPU_SPECS["cpu"], ici_bandwidth=0.5e9, ici_latency=2e-5)
+    skew = 2.5
+    mm_true = MachineModel(true_spec)
+    mm_skewed = MachineModel(dataclasses.replace(
+        true_spec, hbm_bandwidth=true_spec.hbm_bandwidth * skew,
+        mxu_efficiency=min(true_spec.mxu_efficiency * skew, 1.0),
+        ici_bandwidth=true_spec.ici_bandwidth * skew))
+    return {
+        "ff": _serve_graph(),
+        "devices": jax.devices()[:2],
+        "mm_true": mm_true,
+        "mm_skewed": mm_skewed,
+        # decode-heavy reference mix (long outputs amortize TTFT -> the
+        # pp plan's cheaper steady-state ticks win under expensive TP
+        # collectives); the drifted prompt-heavy mix flips the winner
+        "ref_feats": {"mean_prompt_len": 24.0, "mean_output_len": 96.0,
+                      "arrival_rate_per_s": 10.0, "mean_occupancy": 0.5},
+    }
+
+
 def test_store_auto_apply_reduces_prediction_error(tmp_path):
     """The acceptance loop in miniature: search on a machine whose specs
     over-promise, measure reality via price_plan on the true constants,
     commit the ledger into a store — the replayed search with the store
-    applied must cut the per-component error_frac.  The scenario (graph,
-    machine pair, skew, reference mix) is bench.calibration_scenario —
-    the SAME definition the ``--dry-run`` demonstration runs, so the two
-    cannot drift apart."""
-    from bench import calibration_scenario
-
+    applied must cut the per-component error_frac."""
     scen = calibration_scenario()
     ff, devices = scen["ff"], scen["devices"]
     mm_true, mm_skewed = scen["mm_true"], scen["mm_skewed"]
@@ -294,8 +319,6 @@ def test_workload_features_flip_the_plan():
     the pp plan (cheaper steady-state ticks under expensive TP
     collectives), a prompt-heavy mix flips to tp (which parallelizes a
     single prefill; pp crosses stages serially and buys TTFT nothing)."""
-    from bench import calibration_scenario
-
     scen = calibration_scenario()
     ff, devices, mm = scen["ff"], scen["devices"], scen["mm_true"]
     decode_heavy = scen["ref_feats"]

@@ -250,7 +250,7 @@ def test_more_rows_than_slots_is_refused(path):
 @pytest.mark.parametrize("flat", [WIDE // 2, SLOTS // 2])
 def test_batch_of_another_capacity_runs_at_its_own_width(flat):
     """A caller may hand in a batch narrower than the manager's flat
-    capacity (``bench.py`` does).  The width is worked out in ONE place,
+    capacity.  The width is worked out in ONE place,
     ``decode_scan_width(bc)``, from the batch in hand: the program, the
     guard and the span cannot speak of different widths."""
     prompts = PROMPTS[:2]

@@ -6,11 +6,12 @@ attention paths, with the dequant fused into the kernels (int8 KV never
 materializes as bf16 in HBM on the Pallas path), across the
 prefill -> decode continuation; and the capacity planner must admit the
 full-depth 32-layer llama2-7b-shape config (int8 weights + int8 KV) within
-one v5e chip's 16 GB HBM — the configuration the full-model bench runs.
+one v5e chip's 16 GB HBM.
 
 Kernel logic runs in interpret mode on the CPU test mesh (the strategy of
-test_pallas_attention.py); the real-TPU compile is exercised by bench.py's
-``kv_int8`` / ``full_model`` sections.
+test_pallas_attention.py); the compile for a v5e is exercised by
+tests/test_tpu_aot_compile.py, the chip by ``benchmark/control.py``'s
+``int8_kv`` control.
 """
 
 import jax

@@ -310,7 +310,7 @@ def test_pp2_trace_stage_spans_and_calibration(tmp_path):
 # ---------------------------------------------------------------------------
 def test_every_emitted_typed_event_is_in_event_schema():
     """Grep-based CI gate: every typed instant (cat request/dispatch/plan)
-    emitted anywhere in flexflow_tpu/ (and the bench emitters) must appear
+    emitted anywhere in flexflow_tpu/ must appear
     in ``telemetry.EVENT_SCHEMA`` — new instrumentation that skips the
     schema would silently dodge ``trace_report.py --check``."""
     import os
@@ -323,7 +323,7 @@ def test_every_emitted_typed_event_is_in_event_schema():
     pat = re.compile(
         r"""\.instant\(\s*["'](\w+)["']\s*,\s*(?:cat\s*=\s*)?["'](\w+)["']""",
         re.S)
-    sources = [os.path.join(repo, "bench.py")]
+    sources = []
     for root, _dirs, files in os.walk(os.path.join(repo, "flexflow_tpu")):
         sources += [os.path.join(root, f) for f in files
                     if f.endswith(".py")]

@@ -1,7 +1,9 @@
 """Pallas decode-attention kernel: equivalence with the pure-JAX path.
 
 Runs in interpret mode on the CPU test mesh (same kernel logic, no TPU
-needed); the real-TPU compile is exercised by bench.py.
+needed); the compile for a v5e is exercised by
+tests/test_tpu_aot_compile.py, the chip by ``chip_smoke.py`` and
+``benchmark/run.py``.
 """
 
 import math

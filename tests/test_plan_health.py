@@ -14,6 +14,7 @@ from flexflow_tpu.obs import (
 )
 from flexflow_tpu.serve import GenerationConfig, RequestManager
 
+from test_calibration_loop import calibration_scenario
 from test_serve import TINY, make_im
 
 
@@ -287,10 +288,9 @@ def test_acceptance_drift_recommends_non_spec_plan():
     acceptance then degrades below break-even, the spec_acceptance
     dimension's PSI crosses the drift threshold, and the monitor's
     re-search on the LIVE profile recommends the NON-SPEC plan."""
-    import bench
     from flexflow_tpu.search.serve_search import search_serve_plan
 
-    scen = bench.calibration_scenario()
+    scen = calibration_scenario()
     ff, devices, mm = scen["ff"], scen["devices"], scen["mm_true"]
     be = mm.spec.spec_break_even_acceptance
 

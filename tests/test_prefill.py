@@ -2,7 +2,9 @@
 PrefillBatchConfig tiling contract.
 
 Strategy mirrors test_pallas_attention.py: interpret mode on the CPU test
-mesh for kernel logic; the real-TPU compile is exercised by bench.py (TTFT).
+mesh for kernel logic; the compile for a v5e is exercised by
+tests/test_tpu_aot_compile.py, the chip by ``chip_smoke.py`` and
+``benchmark/run.py``.
 """
 
 import jax

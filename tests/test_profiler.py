@@ -320,7 +320,7 @@ def test_counter_arithmetic_matches_plan_cost_model():
 
 def test_counters_are_deterministic_across_runs():
     """Two identical sessions produce bit-identical work counters — the
-    property bench_compare.py's exact counter diff rests on."""
+    property ``obs.report.compare``'s exact counter diff rests on."""
     def run():
         im = make_im(max_seq=64)
         prof = StepProfiler()

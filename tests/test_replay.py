@@ -20,7 +20,7 @@ The load-bearing contracts (ISSUE 19 acceptance):
   recorded arrival stream runs through the slot-level simulator under a
   ``price_plan``-style candidate; latencies and the OUTCOME MIX respond
   (ttl/deadline re-applied to simulated queueing), and two candidates
-  diff under scripts/bench_compare.py's exact discipline.
+  diff under ``obs.report.compare``'s exact discipline.
 """
 
 import json
@@ -384,7 +384,7 @@ def test_what_if_prices_latency_outcome_mix_and_fleet_size():
     assert wait(harness.what_if({"tpot_s": 5e-3}, fleet_size=2)) < \
         wait(harness.what_if({"tpot_s": 5e-3}))
 
-    # deltas ride bench_compare's discipline: identical candidates diff
+    # deltas ride obs.report.compare's discipline: identical candidates diff
     # clean, the slow candidate is a latency/throughput regression of
     # the fast one with the thresholded-field vocabulary
     assert harness.diff(fast["summary"], fast["summary"])["ok"]

@@ -1,24 +1,14 @@
-"""scripts/bench_compare.py: the hermetic perf-regression guardrail.
+"""obs/report.py ``compare``: the hermetic comparator of two summaries.
 
 Deterministic work counters (obs/profiler.WORK_COUNTERS) diff EXACTLY —
-any increase (or a vanished counter) exits nonzero; measured latency /
+any increase (or a vanished counter) is a regression; measured latency /
 throughput fields diff against relative thresholds with direction
-(latency up = bad, throughput down = bad).  Identical artifacts exit 0.
+(latency up = bad, throughput down = bad).  Identical documents are ok.
 """
 
 import copy
-import json
-import os
-import subprocess
-import sys
 
-HERE = os.path.dirname(os.path.abspath(__file__))
-REPO = os.path.dirname(HERE)
-SCRIPT = os.path.join(REPO, "scripts", "bench_compare.py")
-
-sys.path.insert(0, os.path.join(REPO, "scripts"))
-
-import bench_compare  # noqa: E402
+from flexflow_tpu.obs.report import classify, compare
 
 DOC = {
     "serving_under_load": {
@@ -36,111 +26,94 @@ DOC = {
 }
 
 
-def run_cli(old_doc, new_doc, tmp_path, *extra):
-    old = tmp_path / "old.json"
-    new = tmp_path / "new.json"
-    old.write_text(json.dumps(old_doc))
-    new.write_text(json.dumps(new_doc))
-    proc = subprocess.run(
-        [sys.executable, SCRIPT, str(old), str(new), *extra],
-        capture_output=True, text=True, timeout=120, cwd=REPO,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"))
-    return proc.returncode, json.loads(proc.stdout.strip().splitlines()[-1])
-
-
-def test_identical_artifacts_pass(tmp_path):
-    rc, res = run_cli(DOC, DOC, tmp_path)
-    assert rc == 0 and res["ok"]
+def test_identical_artifacts_pass():
+    res = compare(DOC, DOC)
+    assert res["ok"]
     assert res["regressions"] == []
     assert res["compared"] > 0
 
 
-def test_counter_regression_fails_exactly(tmp_path):
+def test_counter_regression_fails_exactly():
     new = copy.deepcopy(DOC)
     # one extra dispatch: deterministic counters are exact by default
     new["serving_under_load"]["0.5x"]["work"]["dispatches"] = 43
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 1 and not res["ok"]
+    res = compare(DOC, new)
+    assert not res["ok"]
     [reg] = res["regressions"]
     assert reg["field"].endswith("work.dispatches")
     assert reg["kind"] == "counter"
     assert reg["old"] == 42 and reg["new"] == 43
 
 
-def test_recompile_regression_fails(tmp_path):
+def test_recompile_regression_fails():
     new = copy.deepcopy(DOC)
     new["serving_under_load"]["0.5x"]["step_profile"][
         "recompiles_total"] = 9
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 1
+    res = compare(DOC, new)
+    assert not res["ok"]
     assert any(r["field"].endswith("recompiles_total")
                for r in res["regressions"])
 
 
-def test_counter_improvement_is_not_a_regression(tmp_path):
+def test_counter_improvement_is_not_a_regression():
     new = copy.deepcopy(DOC)
     new["serving_under_load"]["0.5x"]["work"]["flops"] = 1.0e9  # less work
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 0
+    res = compare(DOC, new)
+    assert res["ok"]
     assert any(i["field"].endswith("work.flops")
                for i in res["improvements"])
 
 
-def test_missing_counter_is_a_regression(tmp_path):
+def test_missing_counter_is_a_regression():
     new = copy.deepcopy(DOC)
     del new["serving_under_load"]["0.5x"]["work"]
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 1
+    res = compare(DOC, new)
+    assert not res["ok"]
     missing = [r for r in res["regressions"] if "new" not in r]
     assert any(r["field"].endswith("work.flops") for r in missing)
 
 
-def test_latency_threshold_and_direction(tmp_path):
+def test_latency_threshold_and_direction():
     # +5% TPOT: inside the default 10% threshold
     new = copy.deepcopy(DOC)
     new["serving_under_load"]["0.5x"]["tpot_p50_ms"] = 7.35
-    rc, _ = run_cli(DOC, new, tmp_path)
-    assert rc == 0
+    assert compare(DOC, new)["ok"]
     # +20% TPOT: regression
     new["serving_under_load"]["0.5x"]["tpot_p50_ms"] = 8.4
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 1
+    res = compare(DOC, new)
+    assert not res["ok"]
     assert any(r["field"].endswith("tpot_p50_ms")
                for r in res["regressions"])
     # -20% TPOT: improvement, not regression
     new["serving_under_load"]["0.5x"]["tpot_p50_ms"] = 5.6
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 0
+    res = compare(DOC, new)
+    assert res["ok"]
     assert any(i["field"].endswith("tpot_p50_ms")
                for i in res["improvements"])
 
 
-def test_throughput_direction_is_inverted(tmp_path):
+def test_throughput_direction_is_inverted():
     new = copy.deepcopy(DOC)
     new["serving_under_load"]["0.5x"]["goodput_tokens_per_sec"] = 700.0
-    rc, res = run_cli(DOC, new, tmp_path)
-    assert rc == 1
+    res = compare(DOC, new)
+    assert not res["ok"]
     [reg] = [r for r in res["regressions"]
              if r["field"].endswith("goodput_tokens_per_sec")]
     assert reg["kind"] == "throughput"
     # higher goodput is fine
     new["serving_under_load"]["0.5x"]["goodput_tokens_per_sec"] = 1100.0
-    rc, _ = run_cli(DOC, new, tmp_path)
-    assert rc == 0
+    assert compare(DOC, new)["ok"]
 
 
-def test_per_field_threshold_override(tmp_path):
+def test_per_field_threshold_override():
     new = copy.deepcopy(DOC)
     new["serving_under_load"]["0.5x"]["tpot_p50_ms"] = 7.35  # +5%
-    rc, _ = run_cli(DOC, new, tmp_path, "--threshold", "tpot_p50_ms=0.03")
-    assert rc == 1
+    assert not compare(DOC, new, overrides={"tpot_p50_ms": 0.03})["ok"]
     # and counters can be given slack explicitly
     new = copy.deepcopy(DOC)
     new["serving_under_load"]["0.5x"]["work"]["flops"] = 1.5e9 * 1.01
-    rc, _ = run_cli(DOC, new, tmp_path)
-    assert rc == 1
-    rc, _ = run_cli(DOC, new, tmp_path, "--counter-threshold", "0.05")
-    assert rc == 0
+    assert not compare(DOC, new)["ok"]
+    assert compare(DOC, new, counter_threshold=0.05)["ok"]
 
 
 def test_compare_importable_and_measured_only_where_present():
@@ -148,26 +121,8 @@ def test_compare_importable_and_measured_only_where_present():
     regressions); deterministic counters are the strict class."""
     old = {"tpot_p50_ms": 7.0, "extra_latency_ms": 3.0}
     new = {"tpot_p50_ms": 7.0}
-    res = bench_compare.compare(old, new)
+    res = compare(old, new)
     assert res["ok"] and res["compared"] == 1
-
-
-def test_json_output_sink(tmp_path):
-    """``--json PATH`` writes the same result document to a file for
-    machine consumption (CI, the replay diff report) — stdout and the
-    exit code are unchanged."""
-    sink = tmp_path / "diff.json"
-    rc, res = run_cli(DOC, DOC, tmp_path, "--json", str(sink))
-    assert rc == 0
-    on_disk = json.loads(sink.read_text())
-    assert on_disk == res
-    # a regressing diff still writes the sink and still exits 1
-    new = copy.deepcopy(DOC)
-    new["serving_under_load"]["0.5x"]["work"]["dispatches"] = 43
-    rc, res = run_cli(DOC, new, tmp_path, "--json", str(sink))
-    assert rc == 1
-    on_disk = json.loads(sink.read_text())
-    assert not on_disk["ok"] and on_disk["regressions"] == res["regressions"]
 
 
 def test_replay_and_trace_counters_join_the_exact_compare_class():
@@ -175,23 +130,23 @@ def test_replay_and_trace_counters_join_the_exact_compare_class():
     determinism regression, and a telemetry ring that starts dropping
     events fails the diff instead of just warning in trace_report."""
     for k in ("replay_mismatches", "telemetry_events_dropped"):
-        assert bench_compare.classify(k) == "counter", k
+        assert classify(k) == "counter", k
     # the bookkeeping counters stay unclassified (more traces recorded
     # or replays run is not monotone-bad)
-    assert bench_compare.classify("traces_recorded") is None
-    assert bench_compare.classify("replays_run") is None
+    assert classify("traces_recorded") is None
+    assert classify("replays_run") is None
     old = {"replay": {"counters": {"replay_mismatches": 0}},
            "telemetry_events_dropped": 0}
-    assert bench_compare.compare(old, old)["ok"]
+    assert compare(old, old)["ok"]
     worse = {"replay": {"counters": {"replay_mismatches": 1}},
              "telemetry_events_dropped": 0}
-    res = bench_compare.compare(old, worse)
+    res = compare(old, worse)
     assert not res["ok"]
     assert any(r["field"].endswith("replay_mismatches")
                for r in res["regressions"])
     dropped = {"replay": {"counters": {"replay_mismatches": 0}},
                "telemetry_events_dropped": 7}
-    res = bench_compare.compare(old, dropped)
+    res = compare(old, dropped)
     assert not res["ok"]
     assert any(r["field"].endswith("telemetry_events_dropped")
                for r in res["regressions"])
@@ -205,12 +160,12 @@ def test_fleet_counters_join_the_exact_compare_class():
     direction is not monotone-bad)."""
     for k in ("failovers_total", "replica_deaths", "replica_quarantines",
               "replica_degradations"):
-        assert bench_compare.classify(k) == "counter", k
-    assert bench_compare.classify("fleet_replicas_healthy") is None
+        assert classify(k) == "counter", k
+    assert classify("fleet_replicas_healthy") is None
     old = {"fleet": {"failovers_total": 1, "replica_deaths": 1}}
     worse = {"fleet": {"failovers_total": 2, "replica_deaths": 1}}
-    res = bench_compare.compare(old, worse)
+    res = compare(old, worse)
     assert not res["ok"]
     assert any(r["field"].endswith("failovers_total")
                for r in res["regressions"])
-    assert bench_compare.compare(old, old)["ok"]
+    assert compare(old, old)["ok"]

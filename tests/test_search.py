@@ -114,7 +114,7 @@ def test_grad_allreduce_cost_counted(tf_model):
 def test_search_with_measured_v5e_costs_beats_dp(tf_model):
     """North-star #1 shape: with the committed v5e measured-cost artifact and
     the v5e machine model, the searched strategy beats hand-DP-over-all-axes
-    in simulated step time (the bench_search.py path)."""
+    in simulated step time."""
     import os
 
     from flexflow_tpu.search.measure import CostCache
@@ -132,38 +132,3 @@ def test_search_with_measured_v5e_costs_beats_dp(tf_model):
     c_best = simulate(PCG(model.graph, mesh, best).plan(), v5e,
                       measured=costs).total
     assert c_best < c_dp
-
-
-def test_bench_merge_carries_perturbation_regret(monkeypatch):
-    """VERDICT r5 weak #1: bench_search.py computes ``perturbation_regret``
-    (the per-knob regret that grounds ``strategy_stable``) but the field
-    whitelist in ``bench.searched_vs_dp_fields`` dropped it.  Fake the
-    subprocess so the merge itself is tested hermetically: the key must
-    survive into the bench artifact dict."""
-    import json
-    import subprocess
-
-    import bench
-
-    payload = {
-        "searched_vs_dp_sim": 1.2,
-        "searched_vs_dp_wallclock": 1.1,
-        "strategy_stable": False,
-        "perturbation_ratios": {"mxu_efficiency+30%": 1.18},
-        "perturbation_regret": {"mxu_efficiency+30%": 1.07},
-    }
-
-    class FakeProc:
-        stdout = "compile noise\n" + json.dumps(payload)
-        stderr = ""
-
-    monkeypatch.setattr(subprocess, "run", lambda *a, **k: FakeProc())
-    fields = bench.searched_vs_dp_fields()
-    assert fields["perturbation_regret"] == payload["perturbation_regret"]
-    assert fields["strategy_stable"] is False
-    # and the producer really emits the key (source-level, no 9-min search)
-    import os
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, "bench_search.py")) as f:
-        assert '"perturbation_regret"' in f.read()

@@ -94,8 +94,8 @@ def test_arrival_records_are_complete_and_ordered():
 
 
 def test_under_load_metrics_helper():
-    # the bench's metric reduction, hermetically (shared with bench.py)
-    import bench
+    # the record reduction (obs/report.py), hermetically
+    from flexflow_tpu.obs.report import under_load_summary
 
     im = make_im(max_seq=64, max_requests=2)
     rng = np.random.RandomState(11)
@@ -103,16 +103,12 @@ def test_under_load_metrics_helper():
                                 vocab=TINY.vocab_size, max_new=5)
     rm = RequestManager(im, GenerationConfig(max_new_tokens=5))
     records = rm.serve_with_arrivals(arrivals, clock=VirtualClock())
-    m = bench.under_load_metrics(records)
+    m = under_load_summary(records)
     assert m["requests"] == 6 and m["completed"] == 6
     assert m["ttft_p50_ms"] <= m["ttft_p95_ms"]
     assert m["tpot_p50_ms"] <= m["tpot_p95_ms"]
     assert m["goodput_tokens_per_sec"] > 0
-    # the reduction now lives in the obs layer (one accounting for bench,
-    # tests, and trace_report) and splits TTFT into queue wait + prefill
-    from flexflow_tpu.obs.report import under_load_summary
-
-    assert m == under_load_summary(records)
+    # TTFT splits into queue wait + prefill
     assert m["queue_wait_p50_ms"] is not None
     assert m["queue_wait_p50_ms"] <= m["ttft_p50_ms"]
     assert m["prefill_p50_ms"] is not None

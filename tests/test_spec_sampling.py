@@ -119,58 +119,3 @@ def test_scan_sample_greedy_path_unaffected(rig):
     a = scan_emitted(rig, None)
     b = scan_emitted(rig, None)
     np.testing.assert_array_equal(a, b)
-
-
-def test_bench_draft_forward_matches_reference():
-    """bench._draft_logits (the distillation training forward) computes the
-    same function as ref_llama_logits — which is itself equality-tested
-    against the serve stack — so the trained draft's weights mean the same
-    thing at serve time as during training."""
-    import bench
-    from test_serve import TINY, make_im, ref_llama_logits
-
-    im = make_im()
-    toks = np.asarray([[3, 11, 25, 40, 7, 1], [2, 2, 9, 30, 4, 5]], np.int32)
-    got = bench._draft_logits(
-        im.params, jnp.asarray(toks), n_layers=2,
-        gq=TINY.num_attention_heads // TINY.kv_heads,
-        d=TINY.hdim, theta=TINY.rope_theta, eps=TINY.rms_norm_eps)
-    for b in range(2):
-        want = ref_llama_logits(im.params, TINY, toks[b].tolist())
-        np.testing.assert_allclose(np.asarray(got[b]), np.asarray(want),
-                                   atol=2e-4, rtol=2e-3)
-
-
-def test_distill_pipeline_earns_acceptance_on_tiny_teacher():
-    """End-to-end trained-draft pipeline (VERDICT r4 #6) at toy scale: LLM
-    trajectories -> on-device distillation (batched forward) -> serve-path
-    speculative decoding.  On a learnable (tiny) teacher the held-out
-    acceptance must be real (>0) — the 7B bench's random-weight teacher is
-    only memorizable, so this is the pipeline-correctness gate."""
-    import bench
-    from flexflow_tpu.serve.spec_scan import SpecDecodeScan
-
-    shape_t = dict(hidden=32, heads=4, kv=2, inter=48, vocab=67)
-    llm = bench.build_im(use_pallas=False, layers=3, max_requests=4,
-                         max_seq=64, max_tokens=24, max_spec=8, **shape_t)
-    params_t, loss = bench._train_draft(
-        llm, shape_t, np.random.RandomState(11), steps=600, seq_len=25,
-        batch_slots=4, lr=1e-3)
-    assert loss < 1.5  # learned something (vocab-67 uniform would be ~4.2)
-    llm.reset()
-    ssm = bench.build_im(use_pallas=False, layers=2, max_requests=4,
-                         max_seq=64, max_tokens=24, max_spec=8, topk=1,
-                         params=params_t, **shape_t)
-    sc = SpecDecodeScan(llm, ssm, width=1, depth=5)
-    rng = np.random.RandomState(0)
-    prompts = rng.randint(1, 66, size=(4, 8)).tolist()  # HELD-OUT prompts
-    firsts = bench.prefill_im(llm, prompts)
-    bench.prefill_im(ssm, prompts)
-    carry = sc.init_carry(firsts, [8] * 4, [8] * 4, [False] * 4)
-    emitted, _ = sc.run(carry, 5)
-    em = np.asarray(emitted).reshape(-1, 4, 6)
-    acceptance = (float((em >= 0).sum()) / (em.shape[0] * 4) - 1.0) / 5
-    # a genuinely random-init draft on a tiny random teacher earns only a
-    # little held-out acceptance — but it must be REAL (> 0), which the 7B
-    # random-teacher point cannot show (knife-edge argmax margins)
-    assert acceptance > 0.01, f"held-out acceptance {acceptance}"

@@ -1,11 +1,9 @@
-"""Round trip: ``bench.py --dry-run``'s observability section through
-``scripts/trace_report.py``.
+"""``scripts/trace_report.py`` and the schema it parses.
 
-The dry run drives the telemetry pipeline on a virtual clock (no device
-work), exports the JSONL, and embeds the in-process ``summarize_jsonl``
-summary; the report CLI must reproduce that summary from the file alone —
-the schema the serving stack emits and the schema the report parses are
-pinned to each other.
+``validate_jsonl`` (``--check``) holds an export to the typed vocabulary
+``telemetry.EVENT_SCHEMA`` — every event of it is put to the validator
+below, whole, short of one argument, and under a wrong category — and
+the CLI must reproduce ``summarize_jsonl``'s summary from the file alone.
 """
 
 import json
@@ -14,6 +12,9 @@ import subprocess
 import sys
 
 import pytest
+
+from flexflow_tpu.obs.report import validate_jsonl
+from flexflow_tpu.obs.telemetry import EVENT_SCHEMA
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(HERE)
@@ -25,546 +26,38 @@ def _run_raw(args, **kw):
                           text=True, timeout=300, cwd=REPO, env=env, **kw)
 
 
-def _run(args, **kw):
-    proc = _run_raw(args, **kw)
-    assert proc.returncode == 0, (proc.stdout, proc.stderr)
-    return proc.stdout.strip().splitlines()[-1]
+def _export(tmp_path, name, cat, args):
+    """A two-line export: the meta line and ONE typed instant."""
+    path = tmp_path / "one.jsonl"
+    path.write_text("\n".join([
+        json.dumps({"kind": "telemetry_meta", "version": 1, "ts_unit": "us",
+                    "events": 1, "dropped": 0}),
+        json.dumps({"kind": "event", "name": name, "cat": cat, "ph": "i",
+                    "pid": 1, "tid": 1, "ts": 1.0, "s": "t", "args": args}),
+    ]) + "\n")
+    return str(path)
 
 
-@pytest.fixture(scope="module")
-def dryrun(tmp_path_factory):
-    """ONE bench --dry-run subprocess shared by every test here (the
-    feedback-loop sections build graphs — not free to repeat per test)."""
-    out = str(tmp_path_factory.mktemp("telemetry"))
-    doc = json.loads(_run([os.path.join(REPO, "bench.py"),
-                           "--dry-run", "--out", out]))
-    return out, doc
+@pytest.mark.parametrize("event", sorted(EVENT_SCHEMA))
+def test_each_typed_event_is_held_to_its_schema(event, tmp_path):
+    """Every event of the vocabulary meets the validator: under its
+    declared category with exactly its required arguments it passes; short
+    of one argument, or under another typed category, it is refused by an
+    error that says which."""
+    cat, required = EVENT_SCHEMA[event]
+    assert required, f"{event} declares no required argument"
+    args = {a: 1 for a in required}
+    assert validate_jsonl(_export(tmp_path, event, cat, args)) == []
 
+    dropped = required[-1]
+    short = {a: 1 for a in required if a != dropped}
+    [error] = validate_jsonl(_export(tmp_path, event, cat, short))
+    assert event in error and repr(dropped) in error
 
-def test_dry_run_observability_roundtrips_through_trace_report(dryrun):
-    out, doc = dryrun
-    obs = doc["observability"]
-    jsonl = obs["paths"]["jsonl"]
-    assert os.path.exists(jsonl)
-    assert os.path.exists(obs["paths"]["trace_json"])
-
-    # the section's summary has real content (6 plain requests + the
-    # resilience trio: rejected / preempted-then-finished / cancelled)
-    s = obs["summary"]
-    assert s["requests"] == 9 and s["completed"] == 7
-    assert s["ttft_p50_ms"] is not None
-    assert s["ttft_p50_ms"] <= s["ttft_p95_ms"]
-    assert s["tpot_p50_ms"] is not None
-    assert s["queue_wait_p50_ms"] is not None
-    assert s["bubble_frac"] == 0.0
-    err = s["prediction_error"]["tp1_pp2_m2"]["tpot_ms"]
-    assert err["predicted"] == 7.0 and err["measured"] == 7.7
-    assert abs(err["error_frac"] - 0.1) < 1e-9
-    assert any(k.startswith("stage") for k in s["span_ms_by_track"])
-
-    # resilient-serving outcomes + counters round-trip through the JSONL
-    assert s["outcomes"] == {"ok": 7, "rejected": 1, "cancelled": 1}
-    assert s["preemptions"] == 1
-    assert s["dispatch_retries"] == 1 and s["dispatch_faults"] == 1
-    assert s["robustness"]["requests_rejected"] == 1
-    assert s["robustness"]["requests_preempted"] == 1
-    assert s["robustness"]["recompute_tokens"] == 43
-    res = obs["serving_resilience"]["counters"]
-    assert res["requests_rejected"] == 1
-    assert res["requests_cancelled"] == 1
-    assert res["dispatch_retries"] == 1
-
-    # metrics snapshot rode along
-    assert obs["metrics"]["requests_finished"] == 7
-
-    # the CLI reproduces the summary from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"), jsonl]))
-    assert reported == s, "trace_report.py diverged from the in-process summary"
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 6: the observe->calibrate->re-plan loop, hermetically on the
-# virtual clock, round-tripped through trace_report
-# ---------------------------------------------------------------------------
-def test_dry_run_calibration_loop_reduces_error(dryrun):
-    _, doc = dryrun
-    cl = doc["observability"]["feedback_loop"]["calibration_loop"]
-    # deliberately mis-scaled constants produced a real ledger...
-    assert cl["error_frac_before"] > 0.3
-    comps = cl["components"]
-    assert comps["tpot_ms"]["n"] >= 2 and not comps["tpot_ms"]["low_confidence"]
-    # ...and the auto-applied store scales cut the replayed error
-    assert cl["improved"]
-    assert cl["error_frac_after"] < cl["error_frac_before"] * 0.5
-    assert cl["applied_scales"]["tpot_ms"] > 1.2
-    assert os.path.exists(cl["store_path"])
-
-
-def test_dry_run_workload_drift_recommends_replan(dryrun):
-    _, doc = dryrun
-    fb = doc["observability"]["feedback_loop"]
-    wd = fb["workload_drift"]
-    # clean before the shift, drifted after, and the candidate differs
-    assert wd["healthy_before"] and wd["drift_score_before"] < 0.25
-    assert wd["drifted"] and wd["drift_score_after"] >= 0.25
-    assert "workload_drift" in wd["reasons"]
-    assert wd["replan_recommended"]
-    assert wd["candidate"]["plan_key"] != wd["incumbent"]
-    # the shifted mix is visible in the live features
-    assert wd["live_features"]["mean_prompt_len"] > 256
-
-    # full round trip: the loop JSONL reproduces drift + replan + scales
-    s = fb["summary"]
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         fb["paths"]["jsonl"]]))
-    assert reported == s
-    assert reported["workload_drift_score"] >= 0.25
-    assert len(reported["drift_detected"]) == 1
-    [replan] = reported["replan_recommended"]
-    assert replan["incumbent"] == wd["incumbent"]
-    assert replan["candidate"] == wd["candidate"]["plan_key"]
-    assert reported["applied_scales"] == fb["calibration_loop"][
-        "applied_scales"]
-    assert reported["workload"]["prompt_len"]["mean"] > 256
-
-
-# ---------------------------------------------------------------------------
-# ISSUE 8: the memory ledger (predicted vs allocated vs live), hermetically
-# on the virtual clock, round-tripped through trace_report
-# ---------------------------------------------------------------------------
-def test_dry_run_memory_ledger_reconciles_and_roundtrips(dryrun):
-    _, doc = dryrun
-    ml = doc["observability"]["memory_ledger"]
-
-    # fill -> preempt -> release left no attribution behind
-    assert ml["leak_free"]
-    assert ml["preempt_released_bytes"] > 0
-    assert ml["kv_bytes_per_token"] > 0
-
-    # predicted vs allocated reconcile per component within tolerance
-    # (max_seq = the 128-lane pad quantum, so the model error is tiny)
-    [(plan, fields)] = ml["ledger"]["plans"].items()
-    for comp in ("weights_gb", "kv_gb", "static_gb"):
-        assert fields[comp]["predicted"] > 0
-        assert fields[comp]["measured"] > 0
-        assert abs(fields[comp]["error_frac"]) <= 0.02, comp
-    # the transient-inclusive total stays one-sided by design: nothing
-    # "allocates" an activation, so reconciling it would book the
-    # transient share as model error
-    assert fields["total_gb"]["measured"] is None
-    comps = ml["ledger"]["components"]
-    assert 0.98 <= comps["kv_gb"]["suggested_scale"] <= 1.02
-
-    # live watermarks: the fill phase peak survived the releases
-    live = ml["ledger"]["live"]
-    assert live["hwm_tokens"] > 0
-    assert 0 < live["hwm_frac"] < 1
-    assert ml["summary"]["live"] == live
-    assert ml["summary"]["occupancy_p95"] >= ml["summary"]["occupancy_p50"]
-    g = ml["summary"]["gauges"]
-    assert g["kv_live_bytes_hwm"] == live["hwm_bytes"]
-    assert 0 < g["kv_fragmentation_frac"] < 1
-    assert ml["summary"]["request_kv_bytes"]["count"] == 2
-
-    # stamp-ready device fields for the r6-r9 hbm_frac close-out
-    assert set(ml["device_fields"]) == {"hbm_frac", "hbm_capacity_gb",
-                                        "kv_hwm_gb"}
-
-    # the CLI reproduces the memory section from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         ml["paths"]["jsonl"]]))
-    assert reported["memory"] == ml["summary"]
-
-
-@pytest.mark.paged
-def test_dry_run_shared_prefix_exercises_page_pool_lifecycle(dryrun):
-    """ISSUE 9 acceptance: the hermetic shared_prefix section shows the
-    shared prefix prefilled ONCE (prefix_hit = N-1 in the first wave),
-    TTFT collapsed to the unshared suffix, kv_fragmentation_frac ~ 0
-    under fill->release->refill churn, and a COW on mid-decode
-    divergence — the full page-pool lifecycle with no device."""
-    _, doc = dryrun
-    sp = doc["observability"]["shared_prefix"]
-    users = sp["users"]
-    n = len(users)
-    # the shared prefix is prefilled once: user 0 feeds the whole prompt,
-    # every later user only the unshared remainder
-    assert users[0]["cached"] == 0
-    assert all(u["cached"] == sp["shared_len"] for u in users[1:])
-    hits_wave1 = sum(1 for u in users if u["cached"] > 0)
-    assert hits_wave1 == n - 1
-    assert sp["prefix_hits"] >= n - 1  # JSONL event count (incl. churn)
-    # TTFT collapse-to-suffix: warm users pay only the suffix share
-    assert sp["ttft_collapse"] == pytest.approx(
-        sp["suffix_len"] / (sp["shared_len"] + sp["suffix_len"]), abs=1e-3)
-    assert max(sp["ttft_warm_s"]) < sp["ttft_cold_s"] / 4
-    # fragmentation: reserved-span waste (before) collapses to intra-page
-    # tail waste (after, ~0) and the churn leaves no leak
-    assert sp["fragmentation_after"] < 0.1
-    assert sp["fragmentation_after"] < sp["fragmentation_before"] / 4
-    assert sp["leak_free"]
-    # divergence mid-decode copy-on-wrote exactly once
-    assert sp["cow_on_divergence"] == 1
-    # the paged gauge vocabulary + prefix counters rode the export
-    assert sp["summary"]["paged"]["kv_pages_live"] >= 0
-    assert sp["summary"]["prefix_cache"]["prefix_hits"] == sp["prefix_hits"]
-
-    # the CLI reproduces the memory section from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         sp["paths"]["jsonl"]]))
-    assert reported["memory"] == sp["summary"]
-    assert reported["prefix_hits"] == sp["prefix_hits"]
-
-
-def test_dry_run_spec_serving_flips_at_break_even(dryrun):
-    """ISSUE 11 acceptance: the hermetic spec_serving section shows the
-    acceptance-aware planning decision — a spec plan above the measured
-    break-even acceptance, the incremental plan below it — plus the
-    runtime spec_mode_changed events and the mixed-batch composition
-    gauge riding the real telemetry schema."""
-    _, doc = dryrun
-    sp = doc["observability"]["spec_serving"]
-    be = sp["break_even_acceptance"]
-    assert be == 0.439  # BENCH r05, wired as the calibratable constant
-    hi, lo = sp["high_acceptance"], sp["low_acceptance"]
-    assert hi["mean_spec_acceptance"] > be > lo["mean_spec_acceptance"]
-    assert "_spec_" in hi["plan_key"] and hi["spec"]["acceptance"] > be
-    assert "_spec_" not in lo["plan_key"] and lo["spec"] is None
-    assert sp["flipped"]
-    # speculation is priced as a win only above break-even
-    assert hi["tpot_ms"] < lo["tpot_ms"]
-    # runtime events: 4 flips recorded, mix gauge exported
-    assert sp["spec_mode_changes"] == 4
-    assert len(sp["summary"]["spec_mode_changes"]) == 4
-    assert all(ev["spec"] is False
-               for ev in sp["summary"]["spec_mode_changes"])
-
-    # the CLI reproduces the summary from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         sp["paths"]["jsonl"]]))
-    assert reported["spec_mode_changes"] == \
-        sp["summary"]["spec_mode_changes"]
-
-
-def test_dry_run_live_migration_roundtrips(dryrun):
-    """ISSUE 12 acceptance: the hermetic live_migration section records a
-    REAL mid-flight plan switch — migration downtime (serve ticks with
-    admission closed) and the preempted-request count — plus one forced
-    rollback, all riding the real schema and reproduced by the CLI."""
-    _, doc = dryrun
-    lm = doc["observability"]["live_migration"]
-    assert lm["bit_identical"], "tokens diverged across the dry-run switch"
-    mig = lm["migration"]
-    assert mig["preempted_requests"] >= 1, "the switch was not in-flight"
-    assert mig["downtime_ticks"] >= 1
-    assert mig["downtime_s"] > 0
-    assert mig["kv_leak_free"]
-    assert mig["candidate"] == "tp1_pp1_m1_paged"
-    assert lm["rollback"]["phase"] == "rebuild"
-    assert lm["rollback"]["requests_recovered_on_incumbent"]
-    assert lm["migrations_completed"] == 1
-    assert lm["migrations_rolled_back"] == 1
-
-    s = lm["summary"]
-    migs = s["migrations"]
-    assert len(migs["started"]) == 2
-    [done] = migs["completed"]
-    assert done["preempted_requests"] == mig["preempted_requests"]
-    assert done["downtime_ticks"] == mig["downtime_ticks"]
-    [rolled] = migs["rolled_back"]
-    assert rolled["phase"] == "rebuild" and "RuntimeError" in rolled["reason"]
-    assert migs["counters"]["migrations_completed"] == 1
-    assert migs["counters"]["migrations_rolled_back"] == 1
-
-    # the CLI reproduces the summary from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         lm["paths"]["jsonl"]]))
-    assert reported == s, "trace_report.py diverged on migration events"
-
-
-def test_dry_run_fleet_serving_roundtrips(dryrun):
-    """ISSUE 14 acceptance: the hermetic fleet_serving section kills one
-    of three replicas MID-DECODE — every request terminal, failed-over
-    token streams bit-identical to the fault-free fleet run, the dead
-    replica refcount-clean — and the goodput delta, the fleet event
-    vocabulary, and the per-replica under-load breakdown all ride the
-    real schema and reproduce through the CLI."""
-    _, doc = dryrun
-    fs = doc["observability"]["fleet_serving"]
-    assert fs["bit_identical"], "failover diverged from the fault-free run"
-    assert fs["all_terminal"]
-    assert fs["outcomes"].get("ok") == fs["requests"]
-    assert fs["failovers"] >= 1 and fs["failovers_total"] >= 1
-    assert fs["replica_deaths"] == 1
-    assert fs["kv_leak_free"]
-    # losing a third of the fleet costs goodput, but bounded (the
-    # survivors absorb the failed-over work)
-    g = fs["goodput"]
-    assert g["fault_free_tok_s"] > 0 and g["replica_killed_tok_s"] > 0
-    assert g["delta_frac"] is not None and g["delta_frac"] <= 0
-
-    s = fs["summary"]
-    assert len(s["fleet"]["replica_events"]["dead"]) == 1
-    assert len(s["fleet"]["failed_over"]) == fs["failovers_total"]
-    assert s["fleet"]["counters"]["replica_deaths"] == 1
-    assert s["fleet"]["counters"]["failovers_total"] == \
-        fs["failovers_total"]
-    # per-replica + fleet-aggregate under-load views
-    ul = fs["under_load"]["replica_killed"]
-    assert "per_replica" in ul
-    assert sum(v["requests"] for v in ul["per_replica"].values()) \
-        == fs["requests"]
-
-    # the CLI reproduces the summary from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         fs["paths"]["jsonl"]]))
-    assert reported == s, "trace_report.py diverged on fleet events"
-
-
-def test_dry_run_step_profile_reconciles_per_component(dryrun):
-    """ISSUE 13 acceptance: a machine model skewed on ONE component (hop
-    time x2.5) yields a component-level ``suggested_scale`` that corrects
-    only that component's prediction error (error_frac drops below 0.1
-    for the skewed component, others unchanged) — and the profiled tiny
-    serve is bit-identical with the profiler on, its time budget riding
-    the real schema through ``scripts/trace_report.py``."""
-    _, doc = dryrun
-    sp = doc["observability"]["step_profile"]
-    assert sp["bit_identical"], "profiler changed dry-run serve outputs"
-
-    rec = sp["reconciliation"]
-    assert rec["skewed_component"] == "hop_ms"
-    scales = rec["suggested_scales"]
-    assert scales["hop_ms"] == pytest.approx(2.5, abs=0.01)
-    for c, s in scales.items():
-        if c != "hop_ms":
-            assert s == pytest.approx(1.0, abs=0.01), c
-    # before: only the hop is mispriced; after the store's component
-    # scales apply, the hop error collapses and the others are untouched
-    assert abs(rec["error_frac_before"]["hop_ms"]) > 0.3
-    assert abs(rec["error_frac_after"]["hop_ms"]) < 0.1
-    for c in rec["error_frac_before"]:
-        if c != "hop_ms":
-            assert rec["error_frac_after"][c] == pytest.approx(
-                rec["error_frac_before"][c], abs=1e-6), c
-    assert os.path.exists(rec["store_path"])
-    # search_serve_plan consulted the same component scales directly
-    assert rec["search_applied_scales"]["hop_ms"] == scales["hop_ms"]
-
-    # the profiled serve accumulated real phase/counter content
-    work = sp["profiler"]["work"]
-    assert work["flops"] > 0 and work["dispatches"] > 0
-    assert work["host_syncs"] > 0
-    tb = sp["summary"]["time_budget"]
-    assert tb["ticks"] == sp["profiler"]["ticks"]
-    assert tb["work"] == work
-    assert "dispatch" in tb["phases"] and "host_prepare" in tb["phases"]
-    # the per-component error table rode the calibration line
-    assert tb["components"]["tp1_pp2_m1"]["hop_ms"]["error_frac"] \
-        == pytest.approx(1.5, abs=0.01)
-
-    # the CLI reproduces the summary (time budget included) from the file
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         sp["paths"]["jsonl"]]))
-    assert reported == sp["summary"]
-    assert reported["time_budget"] == tb
-
-
-def test_dry_run_slo_overload_demonstrates_graceful_degradation(dryrun):
-    """ISSUE 15 acceptance: under 2x Poisson overload the latency-
-    critical class holds its p95 TTFT/TPOT targets while the batch
-    class degrades through the ladder with only explicit outcomes,
-    admitted requests are bit-identical (greedy + seeded) to an
-    unloaded run, batch KV never dips into the latency-critical
-    reservation, and the controller de-escalates to NORMAL with zero
-    flapping — all riding the real ``slo`` schema through
-    ``scripts/trace_report.py``."""
-    _, doc = dryrun
-    so = doc["observability"]["slo_overload"]
-    for variant in (so, so["seeded"]):
-        assert variant["bit_identical_prefixes"], \
-            "admitted streams diverged from the unloaded run"
-        assert variant["lc_streams_exact"]
-        assert variant["lc_slo_held"], (
-            variant["lc_ttft_p95_ms"], variant["lc_tpot_p95_ms"])
-        assert variant["batch_never_failed"]
-        assert set(variant["batch_outcomes"]) <= {"ok", "rejected",
-                                                  "timeout"}
-        assert variant["reservation_respected"]
-        assert variant["batch_kv_hwm_tokens"] \
-            <= variant["batch_kv_cap_tokens"]
-        assert variant["deescalated_to_normal"] and variant["no_flap"]
-        # the ladder genuinely walked: up past DEFER and back down
-        assert variant["ladder"][0] == "DEFER_BATCH"
-        assert variant["peak_level"] in ("SHED_BATCH", "CRITICAL_ONLY")
-        assert variant["ladder"][-1] == "NORMAL"
-        assert variant["deferred_requests"] > 0
-    # deterministic lane counters (bench_compare's exact class) + the
-    # slo section round-trips through the report
-    assert so["counters"]["lane_shed_total"] > 0
-    assert so["counters"]["lane_deferred_total"] > 0
-    assert so["counters"]["brownout_escalations"] \
-        == so["counters"]["brownout_deescalations"]
-    s = so["summary"]
-    assert s["slo"]["brownout_changes"], "no ladder events in the export"
-    assert s["slo"]["lane_shed"]
-    assert s["slo"]["counters"]["lane_shed_total"] \
-        == so["counters"]["lane_shed_total"]
-    ul = so["under_load"]
-    assert set(ul["per_class"]) >= {"latency_critical", "batch"}
-    # the CLI reproduces the summary from the JSONL alone
-    reported = json.loads(_run(
-        [os.path.join(REPO, "scripts", "trace_report.py"),
-         so["paths"]["jsonl"]]))
-    assert reported == s, "trace_report.py diverged on slo events"
-
-
-def test_dry_run_host_tick_kills_the_host_tick(dryrun):
-    """ISSUE 17 acceptance: the same seeded Poisson stream served on the
-    legacy quantum-1 loop and on the chained decode engine — token
-    streams bit-identical (greedy AND seeded), exactly one host sync per
-    decode stretch (arrivals pending mid-stretch included), dispatches
-    amortized across the stretch, and a second identical serve on the
-    same manager recompiles nothing."""
-    _, doc = dryrun
-    ht = doc["observability"]["host_tick"]
-    for variant in (ht, ht["seeded"]):
-        assert variant["bit_identical"], \
-            "legacy and chained streams diverged"
-        legacy = variant["legacy_quantum1"]
-        chain = variant["chained"]
-        # the host-sync collapse: exactly one readback per stretch
-        assert chain["host_syncs_per_stretch"] == 1.0
-        assert chain["max_syncs_per_stretch"] == 1
-        assert chain["host_syncs"] < legacy["host_syncs"]
-        # dispatch amortization: strictly fewer dispatches per token
-        assert chain["dispatches_per_token"] < legacy["dispatches_per_token"]
-        assert chain["total_tokens"] == legacy["total_tokens"]
-    # greedy-only instrumentation: a mid-stretch arrival joined the
-    # running batch, and steady state compiles nothing
-    assert ht["chained"]["stretch_joins"] >= 1
-    assert ht["chained"]["steady_state_recompiles"] == 0
-    # stretches genuinely chained segments (not one dispatch per stretch)
-    assert ht["chained"]["dispatches_per_stretch"] > 1.0
-
-
-def test_dry_run_trace_replay_roundtrips(dryrun, tmp_path):
-    """ISSUE 19 acceptance: the hermetic record -> replay -> what-if
-    section.  A recorded ``serve_with_arrivals`` run (greedy AND seeded,
-    with a TTL-timeout outcome in the stream) replayed from its trace
-    artifact on a FRESH engine yields bit-identical per-request token
-    streams and terminal outcomes; the artifact validates through
-    ``replay_report.py --check``; the what-if tp1 vs pp2 delta table is
-    present and priced; the telemetry JSONL's counters join
-    ``bench_compare``'s exact class (replay_mismatches at zero)."""
-    _, doc = dryrun
-    tr = doc["observability"]["trace_replay"]
-    # fidelity: greedy AND seeded, from the artifact alone
-    for variant in (tr, tr["seeded"]):
-        assert variant["bit_identical"], "replayed run diverged"
-        assert variant["mismatches"] == 0
-        assert variant["requests"] == 6
-    # a non-ok outcome (TTL timeout) was recorded AND replayed
-    assert "timeout" in tr["outcomes"].values()
-    # the trace artifact validates through the replay-report CLI
-    check_script = os.path.join(REPO, "scripts", "replay_report.py")
-    for mode in ("greedy", "seeded"):
-        trace_path = tr["trace_paths"][mode]
-        assert os.path.exists(trace_path)
-        res = json.loads(_run([check_script, "--check", trace_path]))
-        assert res["ok"] and res["errors"] == []
-        assert res["arrivals"] == 6 and res["requests"] == 6
-    # ...and summarizes the RECORDED run with the under-load accounting
-    rep = json.loads(_run([check_script, tr["trace_paths"]["seeded"]]))
-    assert rep["recorded"]["requests"] == 6
-    assert rep["recorded"]["outcomes"].get("timeout") == 1
-    # what-if: the tp1_pp1 vs tp1_pp2_m2 delta table, priced and diffed
-    # under bench_compare's discipline
-    wi = tr["what_if"]
-    assert wi["old"]["plan_key"].startswith("tp1_pp1")
-    assert wi["new"]["plan_key"].startswith("tp1_pp2")
-    assert wi["old"]["tpot_ms"] != wi["new"]["tpot_ms"]
-    assert wi["old_goodput_tokens_per_sec"] > 0
-    assert wi["diff"]["compared"] > 0
-    # the exported counters join bench_compare's exact class: a clean
-    # section diffs clean against itself, and an injected mismatch (or
-    # a trace drop) trips the guardrail
-    script = os.path.join(REPO, "scripts", "bench_compare.py")
-    counters = tr["summary"]["replay"]["counters"]
-    assert counters["replay_mismatches"] == 0
-    assert counters["replays_run"] >= 4  # 2 fidelity + 2 what-if
-    assert tr["summary"]["telemetry_events_dropped"] == 0
-    ref = tmp_path / "replay_ref.json"
-    ref.write_text(json.dumps(tr["summary"]))
-    res = json.loads(_run([script, str(ref), str(ref)]))
-    assert res["ok"]
-    import copy
-
-    for field in ("replay_mismatches", "telemetry_events_dropped"):
-        bad = copy.deepcopy(tr["summary"])
-        if field == "replay_mismatches":
-            bad["replay"]["counters"][field] += 1
-        else:
-            bad[field] += 1
-        cand = tmp_path / f"replay_{field}.json"
-        cand.write_text(json.dumps(bad))
-        proc = _run_raw([script, str(ref), str(cand)])
-        assert proc.returncode == 1, f"{field} increase must regress"
-        out = json.loads(proc.stdout.strip().splitlines()[-1])
-        assert any(r["field"].endswith(field) for r in out["regressions"])
-
-
-def test_dry_run_artifact_guards_with_bench_compare(dryrun, tmp_path):
-    """The regression comparator is the loop's guardrail: the dry-run
-    section compares clean against itself and trips on an injected
-    deterministic-counter regression."""
-    _, doc = dryrun
-    sp = doc["observability"]["step_profile"]
-    script = os.path.join(REPO, "scripts", "bench_compare.py")
-    ref = tmp_path / "ref.json"
-    ref.write_text(json.dumps(sp))
-    # identical artifacts: exit 0, no regressions
-    res = json.loads(_run([script, str(ref), str(ref)]))
-    assert res["ok"] and res["regressions"] == []
-    assert res["compared"] > 0
-    # injected counter regression (one silent recompile): exit nonzero
-    import copy
-
-    bad = copy.deepcopy(sp)
-    bad["profiler"]["work"]["recompiles_total"] += 1
-    cand = tmp_path / "cand.json"
-    cand.write_text(json.dumps(bad))
-    proc = _run_raw([script, str(ref), str(cand)])
-    assert proc.returncode == 1
-    out = json.loads(proc.stdout.strip().splitlines()[-1])
-    assert any(r["field"].endswith("recompiles_total")
-               for r in out["regressions"])
-
-
-def test_check_mode_validates_dry_run_schema(dryrun):
-    out, doc = dryrun
-    script = os.path.join(REPO, "scripts", "trace_report.py")
-    for jsonl in (doc["observability"]["paths"]["jsonl"],
-                  doc["observability"]["feedback_loop"]["paths"]["jsonl"],
-                  doc["observability"]["memory_ledger"]["paths"]["jsonl"],
-                  doc["observability"]["shared_prefix"]["paths"]["jsonl"],
-                  doc["observability"]["spec_serving"]["paths"]["jsonl"],
-                  doc["observability"]["live_migration"]["paths"]["jsonl"],
-                  doc["observability"]["step_profile"]["paths"]["jsonl"],
-                  doc["observability"]["fleet_serving"]["paths"]["jsonl"],
-                  doc["observability"]["slo_overload"]["paths"]["jsonl"],
-                  doc["observability"]["host_tick"]["paths"]["jsonl"],
-                  doc["observability"]["trace_replay"]["paths"]["jsonl"]):
-        res = json.loads(_run([script, "--check", jsonl]))
-        assert res["ok"] and res["errors"] == []
+    other = next(c for c in sorted({c for c, _ in EVENT_SCHEMA.values()})
+                 if c != cat)
+    [error] = validate_jsonl(_export(tmp_path, event, other, args))
+    assert event in error and repr(cat) in error and repr(other) in error
 
 
 def test_check_mode_rejects_schema_violations(tmp_path):
