@@ -396,11 +396,11 @@ class FFModel:
         return self._add(op, [y, z], name or "gated_group_norm")[0]
 
     def moe_router(self, x, num_experts, top_k, scaling=1.0, norm_topk=True,
-                   bias=True, name=None):
+                   bias=True, scoring="sigmoid", name=None):
         from .serve.ssd_moe_ops import MoERouter
 
         op = MoERouter(x.shape[-1], num_experts, top_k, scaling, norm_topk,
-                       dtype=x.dtype, bias=bias)
+                       dtype=x.dtype, bias=bias, scoring=scoring)
         return self._add(op, [x], name or "moe_router")
 
     def moe_dispatch(self, x, ids, num_held, held_lo=0, name=None):
@@ -433,6 +433,16 @@ class FFModel:
                                     head_dim, window, rope_theta,
                                     rope_interleaved, dtype=x.dtype)
         return self._add(op, [x], name or "sliding_window_attention")[0]
+
+    def latent_attention(self, x, embed_dim, num_heads, nope_dim, rope_dim,
+                         v_dim, kv_rank, rope_theta=10000.0,
+                         rope_scaling=None, eps=1e-6, name=None):
+        from .serve.hybrid_ops import LatentAttention
+
+        op = LatentAttention(embed_dim, num_heads, nope_dim, rope_dim, v_dim,
+                             kv_rank, rope_theta, rope_scaling, eps,
+                             dtype=x.dtype)
+        return self._add(op, [x], name or "latent_attention")[0]
 
     def moe_combine(self, ys, order, ids, weights, num_held, held_lo=0,
                     dtype=None, name=None):

@@ -51,6 +51,15 @@ Design (v2 — measured on a real v5e chip):
   The causal DMA clamp composes: a clamped future block re-maps to the
   frontier's PHYSICAL page, whose copy Pallas then skips as before.
 
+* **a latent cache** (``decode_attention(v_cache=None, q_rope=, k_rope=)``:
+  multi-head latent attention, absorbed): ONE latent per position is the
+  key and the value of every head, so the block is copied once and used by
+  both contractions; a second, narrower key plane (the rotated part) adds
+  its dot to the score under the same index map.  Score width ``D + Dr``,
+  value width ``D``; 16 query heads on the one cached "head"; the block is
+  planned from the bytes of both planes (1 152 B a position at 512 + 64:
+  512 positions a copy).  Same grid, clamp, masks and online softmax.
+
 Under tensor parallelism the caller (serve/ops.py) wraps these kernels in a
 ``shard_map`` over the kv-head axis — the cache's head dim is the shard dim,
 GQA groups stay intact per shard, so the kernel body is sharding-agnostic.
@@ -150,12 +159,15 @@ def _decode_plan(num_kv, d, itemsize, kv_quant, s_len, window=0,
                if span % (m * block) == 0 and (m == 1 or m * block <= most))
 
 
-def decode_block_plan(k_cache, kv_quant=False, window=0, page_size=0):
+def decode_block_plan(k_cache, kv_quant=False, window=0, page_size=0,
+                      rope_dim=0):
     """The plan :func:`decode_attention` takes on this cache, as the
     ``attention_path.decode_block.*`` counter names it: ``ring4608`` (a ring
-    copied whole), ``full2048``, ``full256``."""
+    copied whole), ``full2048``, ``full256``.  ``rope_dim``: the width of
+    the second key plane a latent cache keeps beside this one."""
     _, num_kv, s_len, d = k_cache.shape
-    block = _decode_plan(num_kv, d, jnp.dtype(k_cache.dtype).itemsize,
+    block = _decode_plan(num_kv, d + rope_dim,
+                         jnp.dtype(k_cache.dtype).itemsize,
                          kv_quant, s_len, window, page_size)
     return f"{'ring' if window else 'full'}{block}"
 
@@ -218,8 +230,9 @@ def _ring_blocks(pos, block_s, s_len, window):
 def _decode_kernel(
     rows_ref,       # scalar prefetch: i32[T] cache row per token
     pos_ref,        # scalar prefetch: i32[T] absolute position per token
-    *refs,          # [pt_ref (paged),] q_ref, k_ref, v_ref,
-                    # [ks_ref, vs_ref,] slopes_ref, o_ref, m/l/acc scratch
+    *refs,          # [pt_ref (paged),] q_ref, k_ref, [v_ref,]
+                    # [qr_ref, kr_ref,] [ks_ref, vs_ref,] slopes_ref, o_ref,
+                    # m/l/acc scratch
     block_s: int,
     num_kv: int,
     gq: int,
@@ -229,11 +242,19 @@ def _decode_kernel(
     paged: bool = False,
     window: int = 0,
     s_len: int = 0,
+    latent: bool = False,
+    rope: bool = False,
 ):
     if paged:
         # the page-table prefetch ref is consumed by the index maps only
         refs = refs[1:]
-    q_ref, k_ref, v_ref, *rest = refs
+    q_ref, k_ref, *rest = refs
+    # a LATENT cache has no value plane: the copied key block is the value
+    v_ref = k_ref if latent else rest.pop(0)
+    if rope:
+        # the score's second term: a narrow rotated key part of its own
+        # plane, one per position, under the same block index map
+        qr_ref, kr_ref = rest.pop(0), rest.pop(0)
     if kv_quant:
         # ks/vs: [1, KV, Bs] f32 per-position dequant scales, same block
         # index map as K/V
@@ -261,12 +282,24 @@ def _decode_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [KV, gq, D]
-        k = k_ref[0].astype(jnp.float32)               # [KV, Bs, D]
+        if latent:
+            # the block as cached (bf16 products are exact in the float32
+            # accumulator, so nothing is lost to the narrower operands)
+            k = k_ref[0]                                # [KV, Bs, D]
+            q = q_ref[0].astype(k.dtype)                # [KV, gq, D]
+        else:
+            q = q_ref[0].astype(jnp.float32)            # [KV, gq, D]
+            k = k_ref[0].astype(jnp.float32)            # [KV, Bs, D]
         sc = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        ) * scale                                       # [KV, gq, Bs]
+        )
+        if rope:
+            kr = kr_ref[0]                              # [KV, Bs, Dr]
+            sc = sc + jax.lax.dot_general(
+                qr_ref[0].astype(kr.dtype), kr, (((2,), (2,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
+        sc = sc * scale                                 # [KV, gq, Bs]
         if kv_quant:
             # fused dequant: q·(k_int8*ks) == (q·k_int8)*ks per key position
             sc = sc * ks_ref[0][:, None, :]
@@ -298,12 +331,17 @@ def _decode_kernel(
         p = jnp.where(seen, p, 0.0)
 
         l_new = alpha * l_ref[:, :, 0:1] + jnp.sum(p, -1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)                # [KV, Bs, D]
-        pv = jax.lax.dot_general(
+        if latent:
+            # the value IS the key block already in VMEM: read once, used
+            # twice; the weights go to the matrix unit in the block's type
+            v, pw = k, p.astype(k.dtype)
+        else:
+            v = v_ref[0].astype(jnp.float32)            # [KV, Bs, D]
             # fused dequant: (p*vs)·v_int8 == p·(v_int8*vs); the softmax
             # denominator above uses the UNSCALED p
-            p * vs_ref[0][:, None, :] if kv_quant else p,
-            v, (((2,), (1,)), ((0,), (0,))),
+            pw = p * vs_ref[0][:, None, :] if kv_quant else p
+        pv = jax.lax.dot_general(
+            pw, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )                                               # [KV, gq, D]
         acc_ref[...] = acc_ref[...] * alpha + pv
@@ -324,7 +362,7 @@ def _decode_kernel(
 def decode_attention(
     q: jax.Array,        # [T, QH, D] (RoPE already applied)
     k_cache: jax.Array,  # [R+1, KV, S, D] (current step's KV already written)
-    v_cache: jax.Array,  # [R+1, KV, S, D]
+    v_cache: Optional[jax.Array],  # [R+1, KV, S, D]; None: a LATENT cache
     rows: jax.Array,     # i32[T] cache row per token
     positions: jax.Array,  # i32[T]
     scale: float,
@@ -337,8 +375,22 @@ def decode_attention(
     page_table: Optional[jax.Array] = None,  # i32[R+1, S//page_size] paged KV
     page_size: int = 0,                      # static; 0 = slot-contiguous
     window: int = 0,     # static; > 0: the cache is a RING (see below)
+    q_rope: Optional[jax.Array] = None,  # [T, QH, Dr] the score's 2nd term:
+    k_rope: Optional[jax.Array] = None,  # [R+1, KV, S, Dr] q_rope . k_rope
 ) -> jax.Array:
-    """``window > 0``: a sliding-window layer.  The cache's seq dim is then a
+    """K and V planes of one head size ``D``, each read once — or, with
+    ``v_cache=None``, a LATENT cache (multi-head latent attention in its
+    absorbed form): ``k_cache`` holds one latent per position that is the
+    key AND the value, so each block is copied ONCE and used for the score
+    and for the weighted sum, and the output is ``D`` wide like the latent.
+    ``q_rope`` / ``k_rope`` add ``q_rope . k_rope`` to the score from a
+    second, narrower plane under the same block index map (the rotated key
+    part a latent cache keeps beside the latent: score width ``D + Dr``,
+    value width ``D``).  The planning, the index maps, the masks and the
+    online softmax are the ones below, whatever the mode; a latent block's
+    dots run in the cache's own type with a float32 accumulator.
+
+    ``window > 0``: a sliding-window layer.  The cache's seq dim is then a
     ring — position ``p`` lives at slot ``p % S`` (``S`` at least the window
     plus the widest step that writes before it attends) — and a query at
     ``positions[i]`` sees the ``min(positions[i] + 1, window)`` newest
@@ -353,13 +405,18 @@ def decode_attention(
     gq = qh // num_kv
     kv_quant = k_scale is not None
     paged = page_table is not None
+    latent, rope = v_cache is None, k_rope is not None
     if window:
         if paged or use_alibi or kv_quant:
             raise ValueError("a ring cache is slot-contiguous, fp, and has "
                              "no positional bias")
+    if (latent or rope) and (paged or kv_quant or window or use_alibi):
+        raise ValueError("a latent cache is slot-contiguous, fp, full-length "
+                         "and has no positional bias")
+    d_rope = k_rope.shape[-1] if rope else 0
     block_s = _decode_plan(
-        num_kv, d, jnp.dtype(k_cache.dtype).itemsize, kv_quant, s_len,
-        window, page_size if paged else 0, block_s)
+        num_kv, d + d_rope, jnp.dtype(k_cache.dtype).itemsize, kv_quant,
+        s_len, window, page_size if paged else 0, block_s)
     n_blocks = s_len // block_s
     qr = q.reshape(t, num_kv, gq, d)
     if slopes is None:
@@ -402,20 +459,21 @@ def decode_attention(
 
     scale_specs, scale_args = _scale_plumbing(
         kv_map, num_kv, block_s, k_scale, v_scale)
+    q_spec = lambda width: pl.BlockSpec(
+        (1, num_kv, gq, width), lambda i, j, *_: (i, 0, 0, 0),
+        memory_space=pltpu.VMEM)
+    kv_spec = lambda width: pl.BlockSpec(
+        (1, num_kv, block_s, width), kv_map, memory_space=pltpu.VMEM)
+    value = () if latent else (v_cache,)
+    second = (q_rope.reshape(t, num_kv, gq, d_rope), k_rope) if rope else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(t, n_blocks),
         in_specs=[
-            pl.BlockSpec(
-                (1, num_kv, gq, d), lambda i, j, *_: (i, 0, 0, 0),
-                memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, num_kv, block_s, d), kv_map, memory_space=pltpu.VMEM,
-            ),
-            pl.BlockSpec(
-                (1, num_kv, block_s, d), kv_map, memory_space=pltpu.VMEM,
-            ),
+            q_spec(d),
+            kv_spec(d),
+            *[kv_spec(d)] * len(value),
+            *([q_spec(d_rope), kv_spec(d_rope)] if rope else []),
             *scale_specs,
             pl.BlockSpec(
                 (num_kv, gq), lambda i, j, *_: (0, 0),
@@ -436,14 +494,14 @@ def decode_attention(
         _decode_kernel,
         block_s=block_s, num_kv=num_kv, gq=gq,
         scale=float(scale), use_alibi=use_alibi, kv_quant=kv_quant,
-        paged=paged, window=window, s_len=s_len,
+        paged=paged, window=window, s_len=s_len, latent=latent, rope=rope,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, num_kv, gq, d), q.dtype),
         interpret=interpret,
-    )(*prefetch, qr, k_cache, v_cache, *scale_args, slopes)
+    )(*prefetch, qr, k_cache, *value, *second, *scale_args, slopes)
     return out.reshape(t, qh, d)
 
 

@@ -250,7 +250,7 @@ def plan_memory_parts(plan: Plan, training: bool = True) -> Dict[str, float]:
     # 1 byte/element plus the per-out-channel f32 scale instead of the
     # ParamSpec dtype — this is what makes the full-depth 7B-shape serve
     # config (int8 weights + int8 KV) admissible within one chip's HBM.
-    _INT8_PARAM_NAMES = ("kernel", "qkv", "o_proj")
+    _INT8_PARAM_NAMES = ("kernel", "qkv", "o_proj", "q_proj", "kv_a", "kv_b")
     for step in plan.steps:
         if step.is_parallel:
             continue

@@ -17,6 +17,11 @@ beside ``IncMultiHeadSelfAttention``:
   rotary over a sliding window (``cohere2_moe``'s sliding layers), its
   cache the same ring: :class:`SlotCacheAttention` is the one ring (and
   full-length, and borrowed) cache implementation both kinds share.
+* :class:`LatentAttention` — multi-head LATENT attention (``deepseek_v2``):
+  the cache holds one normed latent and one rotated key part a position,
+  shared by all heads, and nothing per head; decode reads it in the
+  ABSORBED form (the per-head up-projections folded into the query and the
+  output), so the latent is the key and the value at once.
 * :class:`EvaAttention` — EVA attention (EvaByte): exact attention inside
   the query's own window, one summary per chunk of every earlier window, one
   softmax over both.  Its cache COMPACTS itself: when a window closes, its
@@ -45,6 +50,7 @@ scratch row, as a pad token's K/V does.
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional
 
@@ -57,11 +63,14 @@ from ..core.sharding import TensorSharding
 from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
 from .ops import (DUS_MAX_TOKENS, NEG_INF, SCAN_DUS_MAX_ROWS,
-                  IncMultiHeadSelfAttention, apply_rope, note_decode_block,
-                  put_blocks, tile_coords)
+                  IncMultiHeadSelfAttention, _block_chain, _tile_blocks,
+                  apply_rope, note_decode_block, put_blocks, tile_coords,
+                  yarn_mscale)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
+# prefixes of a latent cache a prompt chunk's XLA attention may be cut to
+PROMPT_SPANS = 8
 
 
 def _flat(bc) -> BatchConfig:
@@ -751,6 +760,283 @@ class SlidingWindowAttention(SlotCacheAttention):
     def _combine(self, out, params, x):
         return out.astype(x.dtype).reshape(
             x.shape[0], self.num_q_heads * self.head_dim)
+
+
+@register_op
+class LatentAttention(_SlotStateOp):
+    """Multi-head LATENT attention (MLA, ``deepseek_v2``) over flat token
+    batches.  Per position the layer caches ONE latent ``c = RMSNorm(x
+    W_kv_a[:, :r])`` (``r = kv_rank``) and ONE rotated key part ``k_r =
+    rope(x W_kv_a[:, r:])`` (``rope_dim`` wide), shared by ALL heads — ``(r
+    + rope_dim)`` values a position and nothing per head (1 152 B in bf16 at
+    512 + 64, where the same 16 heads as plain K/V would hold 10 240 B).
+
+    Head ``i``: ``[q_n | q_r] = x W_q`` (``nope_dim`` + ``rope_dim``),
+    ``q_r`` rotated; ``[k_n,i | v_i] = c W_kv_b`` per head; score ``s =
+    scale (q_n,i . k_n,i + q_r,i . k_r)``, ``scale = (nope_dim +
+    rope_dim)^-1/2 m^2`` with YaRN's ``m = yarn_mscale(factor,
+    mscale_all_dim)``; ``o_i = softmax(s) v_i``; the heads' ``v_dim``-wide
+    outputs concatenate into ``o_proj``.  Rotary on INTERLEAVED pairs with
+    YaRN's frequencies (``rope_scaling``), on the ``rope_dim`` part only.
+
+    Two forms, one result.  ABSORBED: with ``W_kv_b`` split per head into
+    ``U_k [r, nope_dim]`` and ``U_v [r, v_dim]``, ``q_lat = q_n U_k'`` (in
+    ``qkv_proj``), ``s = scale (q_lat . c + q_r . k_r)``, ``o_lat =
+    softmax(s) c`` and ``o = o_lat U_v`` (in ``o_proj``): the latent is key
+    AND value, read once — ``2 H (2 r + rope_dim)`` operations a (query,
+    position) pair.  MATERIALISED: ``k_n`` and ``v`` expanded from the
+    cached latents, then plain attention — ``2 H (nope_dim + rope_dim +
+    v_dim)`` a pair plus ``2 r H (nope_dim + v_dim)`` a cached position
+    expanded.  At the published widths (16 heads, 512 + 64, 128 / 128) that
+    is 34.8k against 10.2k a pair plus 4.2M a position and query group: a
+    decode row (one query a group) is absorbed, always — through
+    ``decode_attention``'s latent mode where the kernels are on, which
+    copies each latent block once for score and value.  A prompt chunk
+    takes ``prompt_form`` by XLA, a request-homogeneous tile of queries
+    against its slot's cache: ``absorbed`` (the default: a tile of 128
+    queries x 16 heads fills the matrix unit's rows, and nothing per head is
+    written to memory) or ``materialised`` (fewer operations from ~0.2 of
+    the cache's length on, but the prefix's K and V per head re-expanded
+    each chunk, 10 KB a position and tile in HBM) — one tile after the
+    other, against the shortest of ``PROMPT_SPANS`` prefixes of the cache
+    that holds the chunk's furthest position (XLA scores what it is given
+    whole: no causal clamp).  The CPU oracle (kernels off) runs every batch
+    in ``prompt_form``.
+
+    State kind ``kv_latent`` (kv_allocator.py): planes ``ckv [rows, 1,
+    max_seq, r]`` and ``kpe [rows, 1, max_seq, rope_dim]`` — two planes, not
+    one padded to whole lanes, so that the allocated bytes are the
+    mechanism's (a narrow plane's lanes are the layout's to pad).  The
+    chunk's block writes go down the ``dynamic_update_slice`` chain
+    (``kv_block_write`` takes K and V planes of one lane-multiple width)."""
+
+    type_name = "latent_attention"
+    path_kind = type_name
+    # the projections weight-only int8 replaces (serve/quant.py)
+    int8_params = ("q_proj", "kv_a", "kv_b", "o_proj")
+
+    def __init__(self, embed_dim: int, num_heads: int, nope_dim: int,
+                 rope_dim: int, v_dim: int, kv_rank: int,
+                 rope_theta: float = 10000.0,
+                 rope_scaling: Optional[dict] = None, eps: float = 1e-6,
+                 prompt_form: str = "absorbed", dtype=jnp.float32):
+        if prompt_form not in ("absorbed", "materialised"):
+            raise ValueError("prompt_form is 'absorbed' or 'materialised'")
+        if rope_scaling and rope_scaling.get(
+                "type", rope_scaling.get("rope_type")) != "yarn":
+            raise ValueError("latent attention knows plain rotary and YaRN "
+                             f"(rope_scaling {rope_scaling!r})")
+        self.embed_dim = int(embed_dim)
+        self.num_heads = int(num_heads)
+        self.nope_dim = int(nope_dim)
+        self.rope_dim = int(rope_dim)
+        self.v_dim = int(v_dim)
+        self.kv_rank = int(kv_rank)
+        self.rope_theta = float(rope_theta)
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
+        self.eps = float(eps)
+        self.prompt_form = prompt_form
+        self.dtype = jnp.dtype(dtype).name
+        m = 1.0
+        if self.rope_scaling and self.rope_scaling.get("mscale_all_dim"):
+            m = yarn_mscale(float(self.rope_scaling["factor"]),
+                            float(self.rope_scaling["mscale_all_dim"]))
+        self.scaling_factor = m * m / math.sqrt(self.nope_dim + self.rope_dim)
+
+    # ---- shapes / params ----------------------------------------------
+    def infer_shapes(self, in_specs):
+        return [TensorSpec(in_specs[0].shape, jnp.dtype(self.dtype))]
+
+    def params(self) -> List[ParamSpec]:
+        dt = jnp.dtype(self.dtype)
+        e, h, r = self.embed_dim, self.num_heads, self.kv_rank
+        return [
+            ParamSpec("q_proj", TensorSpec(
+                (e, h, self.nope_dim + self.rope_dim), dt)),
+            ParamSpec("kv_a", TensorSpec((e, r + self.rope_dim), dt)),
+            ParamSpec("kv_norm", TensorSpec((r,), dt), _init(jnp.ones)),
+            ParamSpec("kv_b", TensorSpec(
+                (r, h, self.nope_dim + self.v_dim), dt)),
+            ParamSpec("o_proj", TensorSpec((h * self.v_dim, e), dt)),
+        ]
+
+    def flops(self, in_specs):
+        t = in_specs[0].shape[0]
+        e, h, r = self.embed_dim, self.num_heads, self.kv_rank
+        return 2 * t * (e * h * (self.nope_dim + self.rope_dim)
+                        + e * (r + self.rope_dim)
+                        + h * r * (self.nope_dim + self.v_dim)
+                        + h * self.v_dim * e)
+
+    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
+                    head_axes=()):
+        sh = TensorSharding.replicated(4)
+        rows = max_requests + 1
+        return {"ckv": ((rows, 1, max_seq_len, self.kv_rank), self.dtype, sh),
+                "kpe": ((rows, 1, max_seq_len, self.rope_dim), self.dtype,
+                        sh)}
+
+    # ---- compute -------------------------------------------------------
+    def _weight(self, params, name, dtype):
+        return dequant(params[name], params.get(f"{name}_scale"), dtype)
+
+    def _project(self, x, params, pos):
+        """``(q_n [T, H, nope], q_r [T, H, rope] rotated, c [T, r] normed,
+        k_r [T, rope] rotated)``."""
+        r = self.kv_rank
+        rope = lambda a: apply_rope(a, pos, self.rope_theta,
+                                    interleaved=True, yarn=self.rope_scaling)
+        q = jnp.einsum("te,ehc->thc", x, self._weight(params, "q_proj",
+                                                      x.dtype),
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+        ckr = jnp.dot(x, self._weight(params, "kv_a", x.dtype),
+                      preferred_element_type=jnp.float32)
+        c = _rms_norm(ckr[:, :r], params["kv_norm"].astype(jnp.float32),
+                      self.eps).astype(x.dtype)
+        k_r = rope(ckr[:, r:].astype(x.dtype))
+        return q[..., :self.nope_dim], rope(q[..., self.nope_dim:]), c, k_r
+
+    @jax.named_scope("kv_write")
+    def _write(self, ckv, kpe, c, k_r, bc, seg, tiled, extras):
+        """This step's latents and rotated key parts into the two planes."""
+        pos = _flat(bc).token_position
+        c, k_r = c[:, None], k_r[:, None]           # one cached "head"
+        if not tiled:
+            put = IncMultiHeadSelfAttention._scatter_rows_pos
+            chain = (SCAN_DUS_MAX_ROWS
+                     if extras.get("one_row_per_request") else None)
+            return (put(ckv, seg.rows, pos, c, chain),
+                    put(kpe, seg.rows, pos, k_r, chain))
+        # a tiled prompt chunk: one block per request-homogeneous tile and
+        # plane, tail pads as zeros (ops.put_blocks says why not a scatter)
+        bq = bc.tile_size
+        rows, start, count = tile_coords(seg.rows, pos, bq, ckv.shape[0] - 1)
+        paths = extras.get("attention_paths")
+        if paths is not None:
+            paths[("kv_block_write", type(bc).__name__)] = "dus_chain"
+        return (_block_chain(ckv, _tile_blocks(c, count, bq, ckv.dtype),
+                             rows, start),
+                _block_chain(kpe, _tile_blocks(k_r, count, bq, kpe.dtype),
+                             rows, start))
+
+    def _attend_xla(self, q_n, q_r, kv_b, ckv, kpe, rows, pos, form,
+                    length=None):
+        """Query groups against their slot's latent cache by XLA: ``q_n [G,
+        B, H, nope]`` (``form`` ``materialised``) or the absorbed ``q_lat
+        [G, B, H, r]``, ``q_r [G, B, H, rope]``, the group's cache row
+        ``rows [G]``, positions ``pos [G, B]``; ``length``: the cache's
+        first positions alone (every ``pos`` below it).  Returns ``[G, B, H,
+        v_dim]`` (materialised) or the latent-wide ``o_lat [G, B, H, r]``,
+        float32."""
+        # [G, S, r], [G, S, rope]
+        cr, kr = ckv[rows, 0, :length], kpe[rows, 0, :length]
+        f32 = jnp.float32
+        sc = jnp.einsum("gbhr,gsr->ghbs", q_r, kr, preferred_element_type=f32)
+        if form == "materialised":
+            kv = jnp.einsum("gsc,chn->gshn", cr, kv_b,
+                            preferred_element_type=f32).astype(cr.dtype)
+            keys, values = kv[..., :self.nope_dim], kv[..., self.nope_dim:]
+            sc = sc + jnp.einsum("gbhn,gshn->ghbs", q_n, keys,
+                                 preferred_element_type=f32)
+        else:
+            sc = sc + jnp.einsum("gbhc,gsc->ghbs", q_n, cr,
+                                 preferred_element_type=f32)
+        seen = jnp.arange(cr.shape[1], dtype=jnp.int32) <= pos[..., None]
+        sc = jnp.where(seen[:, None], sc * self.scaling_factor, NEG_INF)
+        w = jax.nn.softmax(sc, axis=-1)
+        if form == "materialised":
+            return jnp.einsum("ghbs,gshv->gbhv", w, values.astype(w.dtype),
+                              preferred_element_type=f32)
+        return jnp.einsum("ghbs,gsc->gbhc", w, cr.astype(w.dtype),
+                          preferred_element_type=f32)
+
+    def lower(self, ctx, inputs, params):
+        from ..ops.pallas.attention import decode_attention
+
+        bc, state = _require(ctx, self.type_name)
+        x = inputs[0]
+        base = _flat(bc)
+        t, h = x.shape[0], self.num_heads
+        ckv, kpe = state["ckv"], state["kpe"]
+        nreq = ckv.shape[0] - 1
+        seg = Segments(base, nreq)
+        pallas = bool(ctx.extras.get("pallas_decode"))
+        tiled = isinstance(bc, PrefillBatchConfig) and pallas
+        # one query a group reads its whole prefix for itself: absorbed,
+        # whatever the prompt form (the class's docstring has the counts)
+        form = "absorbed" if pallas and not tiled else self.prompt_form
+        kv_b = self._weight(params, "kv_b", x.dtype)     # [r, H, nope + v]
+        with jax.named_scope("qkv_proj"):
+            q_n, q_r, c, k_r = self._project(x, params, base.token_position)
+            if form == "absorbed":
+                q_n = jnp.einsum(
+                    "thn,chn->thc", q_n, kv_b[..., :self.nope_dim],
+                    preferred_element_type=jnp.float32).astype(x.dtype)
+        with jax.named_scope("attend"):
+            ckv, kpe = self._write(ckv, kpe, c, k_r, bc, seg, tiled,
+                                   ctx.extras)
+            ctx.extras["state_out"] = {"ckv": ckv, "kpe": kpe}
+            if tiled:
+                bq = bc.tile_size
+                g = t // bq
+                pos = jnp.where(seg.live, base.token_position, 0)
+                per_tile = (q_n.reshape(g, bq, h, -1),
+                            q_r.reshape(g, bq, h, -1),
+                            jnp.min(seg.rows.reshape(g, bq), axis=1),
+                            pos.reshape(g, bq))
+
+                def tiles(length):
+                    # one tile after the other: a chunk's float32 scores
+                    # are [heads, tile, length] at a time, not all tiles'
+                    return jax.lax.map(
+                        lambda a: self._attend_xla(
+                            a[0][None], a[1][None], kv_b, ckv, kpe,
+                            a[2][None], a[3][None], form, length=length)[0],
+                        per_tile)
+
+                # XLA scores the cache it is given whole, so the chunk is
+                # given the shortest of PROMPT_SPANS prefixes of the cache
+                # that holds its furthest position: an eighth of the
+                # scores' operations and float32 traffic early in a prompt
+                spans = [n for n in (ckv.shape[2] * (i + 1) // PROMPT_SPANS
+                                     for i in range(PROMPT_SPANS))
+                         if n and n % LANE == 0] or [ckv.shape[2]]
+                spans[-1] = ckv.shape[2]
+                furthest = jnp.max(pos)
+                out = jax.lax.switch(
+                    sum((furthest >= n).astype(jnp.int32)
+                        for n in spans[:-1]),
+                    [functools.partial(tiles, n) for n in spans])
+                out, path = out.reshape(t, h, -1), f"xla_tile_{form}"
+            elif pallas:
+                # pads stream one block, not a stale row's whole prefix
+                pos = jnp.where(seg.rows == nreq, 0, base.token_position)
+                out = decode_attention(
+                    q_n, ckv, None, seg.rows, pos, scale=self.scaling_factor,
+                    interpret=bool(ctx.extras.get("pallas_interpret")),
+                    q_rope=q_r, k_rope=kpe)
+                note_decode_block(ctx.extras, self.path_kind,
+                                  type(bc).__name__, ckv,
+                                  rope_dim=self.rope_dim)
+                path = "decode_attention_latent"
+            else:
+                out = self._attend_xla(
+                    q_n[:, None], q_r[:, None], kv_b, ckv, kpe, seg.rows,
+                    base.token_position[:, None], form)[:, 0]
+                path = f"xla_{form}"
+            paths = ctx.extras.get("attention_paths")
+            if paths is not None:
+                paths[(self.path_kind, type(bc).__name__)] = path
+        with jax.named_scope("o_proj"):
+            if form == "absorbed":
+                out = jnp.einsum(
+                    "thc,chv->thv", out.astype(x.dtype),
+                    kv_b[..., self.nope_dim:],
+                    preferred_element_type=jnp.float32)
+            y = jnp.dot(out.astype(x.dtype).reshape(t, h * self.v_dim),
+                        self._weight(params, "o_proj", x.dtype),
+                        preferred_element_type=jnp.float32)
+            return [y.astype(self.dtype)]
 
 
 def compact_cache_len(max_seq_len: int, window: int, chunk: int) -> int:

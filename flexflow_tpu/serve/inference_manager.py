@@ -184,6 +184,9 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
     if not kinds:
         return
     routed = any(getattr(n.op, "counts_load", False) for n in graph.nodes)
+    # a latent cache (LatentAttention): two planes a position, shared by all
+    # heads, that are not K and V planes — each option says what IT lacks
+    latent = "LatentAttention" in kinds
     missing = []
     if kv_page_size:
         missing.append("kv_page_size: a page table for a ring that wraps "
@@ -194,7 +197,13 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "beside a cache, and copy-on-write of recurrent or "
                        "matrix state (linear attention's, or a state-space "
                        "scan's per head) or of an open window at a shared "
-                       "prefix's end")
+                       "prefix's end"
+                       + ("; for a latent cache, pages, copy-on-write, spill "
+                          "and swap_signature over a latent plane and a "
+                          "rotated-key plane of another width (kv_paged.py "
+                          "pools K and V planes of one head size), and a "
+                          "paged mode of the latent decode kernel"
+                          if latent else ""))
     if kv_dtype == "int8":
         missing.append("kv_dtype='int8': quantise-on-write of the window "
                        "ring (the kernels' ring paths take no scale planes), "
@@ -203,18 +212,31 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "whose compressed keys choose what is read; a graph "
                        "that keeps plain K/V planes in a few layers beside "
                        "float32 matrix state in the rest has no reading of "
-                       "'int8' for the state")
+                       "'int8' for the state"
+                       + ("; for a latent cache, scale planes beside the "
+                          "latent and the rotated key part (one latent is "
+                          "key AND value of every head: a per-vector scale "
+                          "folds into neither contraction as the K/V "
+                          "kernels' do) and the latent kernel mode that "
+                          "reads them" if latent else ""))
     if max_spec_tokens:
         missing.append("speculation: a recurrent or matrix state, a closed "
                        "window or an appended index entry cannot be rolled "
                        "back over rejected tokens without a snapshot per "
-                       "tree node")
+                       "tree node"
+                       + ("; a latent cache has no spec-tree buffers, commit "
+                          "copy or tree-mask kernel over latents"
+                          if latent else ""))
     if tp > 1:
         missing.append("tp > 1: a sharding rule for the conv, the scan, the "
                        "differential attention's head pairs, a plain ring's "
                        "K/V groups (its state is replicated), the per-head "
                        "summaries, a selection per K/V head on fewer K/V "
                        "heads than chips and a matrix state per head"
+                       + ("; for latent attention a rule that shards the "
+                          "absorbed heads (W_q, the per-head up-projections, "
+                          "W_o's rows) with the latent cache replicated"
+                          if latent else "")
                        + ("; for the routed experts an exchange of rows "
                           "between the chips that hold them (here each "
                           "graph computes the experts it holds and nothing "
@@ -224,6 +246,8 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "stages): the exported scan output and the shared "
                        "cache cross stage boundaries, and its per-stage "
                        "state hand-over knows full-length K/V planes only"
+                       + (" (not a latent cache's two planes)"
+                          if latent else "")
                        + (" (nor does it carry the routed layers' load "
                           "counters out of a stage)" if routed else ""))
     if missing:

@@ -11,6 +11,9 @@ accounting layer for the slot-contiguous cache (and the interface the
 ROADMAP's paged/prefix-shared KV item will re-implement with a block
 table behind the same API):
 
+* the vocabulary of what a slot holds (``STATE_KINDS``): K and V planes per
+  head (``kv_full``), a latent cache's two planes shared by all heads
+  (``kv_latent``) — both by position —, and the kinds a slot holds whole;
 * :class:`StageKV` — buffers of ONE compiled plan (the single-plan
   :class:`~flexflow_tpu.serve.inference_manager.InferenceManager`, or one
   pipeline stage of the
@@ -40,9 +43,21 @@ import jax
 import jax.numpy as jnp
 
 # the committed-KV buffer names (k/v planes and, under int8 KV, their f32
-# scale planes) — THE byte-accounting vocabulary every consumer shares
-# (admission headroom, the serve search's KV-stream pricing, the ledger)
+# scale planes) — the byte-accounting vocabulary of PLAIN K/V that the paged
+# allocator, the serve search's KV-stream pricing and the pipelined hand-over
+# share: what pages, scale planes, spill and ``swap_signature`` are written for
 KV_BUFFER_NAMES = frozenset({"k", "v", "k_scale", "v_scale"})
+# a LATENT cache (LatentAttention, serve/hybrid_ops.py: ``kv_latent``): one
+# normed latent (``ckv``) and one rotated key part (``kpe``) a position and
+# layer, shared by every head — it grows with the context like K/V planes
+# and is NOT K and V planes: nothing per head, no value plane, two widths.
+# Slot-contiguous only (paging, int8 and the stage hand-over are refused at
+# compile for it: inference_manager.refuse_unsupported_slot_state).
+KV_LATENT_NAMES = frozenset({"ckv", "kpe"})
+# every plane that holds ONE entry per cache position: what a position is
+# priced by (``bytes_per_token``), what ``allocated_bytes(kv_only=True)``
+# counts, and what the allocator pads to whole lanes of positions
+POSITION_NAMES = KV_BUFFER_NAMES | KV_LATENT_NAMES
 # per-slot state that does NOT grow with the context: the ring a window
 # attention layer keeps of its last positions, and a state-space layer's
 # conv tail and scan state (serve/hybrid_ops.py).  A slot holds them whole
@@ -73,6 +88,7 @@ KV_INDEX_NAMES = frozenset({"kidx"})
 FIXED_KINDS = ("kv_window", "recurrent", "linear_state", "ssd_state")
 STATE_KINDS = {
     "kv_full": KV_BUFFER_NAMES,
+    "kv_latent": KV_LATENT_NAMES,
     "kv_window": frozenset({"wk", "wv"}),
     "recurrent": frozenset({"conv", "ssm"}),
     "kv_compact": frozenset({"ck", "cv"}),
@@ -118,10 +134,11 @@ def allocate_attention_state(nodes, strategy, mesh, max_requests,
     serving (so the seq-pad rule and buffer name set cannot diverge from
     the bit-identity contract the pp tests pin).
 
-    The k/v (+ int8 scale) seq dim is rounded up to a lane-width (128)
-    multiple so the Pallas kernels always get a dividing power-of-two
-    block; extra slots sit beyond every mask, and the int8 scale buffers
-    share the caches' seq dim so they pad identically.
+    The seq dim of k/v (+ int8 scale) and of a latent cache's planes is
+    rounded up to a lane-width (128) multiple so the Pallas kernels always
+    get a dividing power-of-two block; extra slots sit beyond every mask,
+    and the int8 scale buffers share the caches' seq dim so they pad
+    identically.
 
     ``always_place``: commit buffers to ``mesh`` even when it is a single
     device — per-stage KV residency is the capacity contract of PP serving
@@ -143,7 +160,7 @@ def allocate_attention_state(nodes, strategy, mesh, max_requests,
                                head_axes)
         bufs = {}
         for name, (shape, dt, sh) in specs.items():
-            if name in KV_BUFFER_NAMES:
+            if name in POSITION_NAMES:
                 s_pad = -(-shape[2] // 128) * 128
                 shape = shape[:2] + (s_pad,) + shape[3:]
             arr = jnp.zeros(shape, jnp.dtype(dt))
@@ -185,18 +202,18 @@ class StageKV:
     def allocated_bytes(self, kv_only: bool = True,
                         per_device: bool = False) -> float:
         """Bytes of the allocated serve-state buffers (``kv_only``
-        restricts to the committed k/v (+scale) planes; False adds the
-        spec-tree buffers too).  ``per_device`` counts one device's share
-        (the ledger's reconciliation basis against per-device
-        ``plan_memory_bytes``); the default is global bytes, matching the
-        admission gate's historical accounting.  0.0 before
-        :meth:`allocate`."""
+        restricts to the per-position planes — committed k/v (+scale), or a
+        latent cache's two; False adds the spec-tree buffers too).
+        ``per_device`` counts one device's share (the ledger's
+        reconciliation basis against per-device ``plan_memory_bytes``); the
+        default is global bytes, matching the admission gate's historical
+        accounting.  0.0 before :meth:`allocate`."""
         if not self.state:
             return 0.0
         total = 0.0
         for bufs in self.state.values():
             for name, arr in bufs.items():
-                if kv_only and name not in KV_BUFFER_NAMES:
+                if kv_only and name not in POSITION_NAMES:
                     continue
                 total += per_device_nbytes(arr) if per_device else arr.nbytes
         return total
@@ -206,9 +223,11 @@ class StageKV:
         this plan's attention ops — THE shape walk admission control,
         preemption pricing, and the memory ledger all share.  It is the
         PER-POSITION part of a request's bytes only: the full-length planes
-        (and an index beside them).  What a slot holds whatever its context
-        — a window layer's ring, recurrent or matrix state — is the fixed
-        part (:meth:`fixed_bytes_per_slot`); :meth:`request_bytes` is both.
+        — K and V per head, or a latent cache's latent and rotated key part
+        (``POSITION_NAMES``) — and an index beside them.  What a slot holds
+        whatever its context — a window layer's ring, recurrent or matrix
+        state — is the fixed part (:meth:`fixed_bytes_per_slot`);
+        :meth:`request_bytes` is both.
 
         Buffers are ``[max_requests+1, heads, seq, dim]``, so the
         per-request-token price divides by the REAL request rows as well
@@ -223,7 +242,7 @@ class StageKV:
         for bufs in self.state.values():
             for name, arr in bufs.items():
                 rows = max(arr.shape[0] - 1, 1)  # minus the scratch row
-                if name in KV_BUFFER_NAMES:
+                if name in POSITION_NAMES:
                     total += arr.nbytes / (rows * arr.shape[2])
                 elif name in KV_INDEX_NAMES:
                     # an index entry per ``stride`` positions of the cache
@@ -236,7 +255,8 @@ class StageKV:
         (``STATE_KINDS``), read off the allocated arrays: ``kv_full`` is the
         slot's whole reserved span (``bytes_per_token`` x the padded seq
         length), ``kv_window`` and ``recurrent`` do not depend on
-        ``max_seq_len`` at all.  Zeros before :meth:`allocate`."""
+        ``max_seq_len`` at all; ``kv_latent`` is a latent cache's reserved
+        span, by position like ``kv_full``.  Zeros before :meth:`allocate`."""
         out = {kind: 0.0 for kind in STATE_KINDS}
         for bufs in (self.state or {}).values():
             for name, arr in bufs.items():

@@ -143,6 +143,28 @@ class ServeModelConfig:
     shared_expert_combination_strategy: str = "average"
     first_k_dense_replace: int = 0
     logit_scale: float = 1.0
+    # deepseek_v2 (``models/deepseek_v2.py``): latent attention — queries of
+    # ``qk_nope_head_dim`` + ``qk_rope_head_dim`` a head (``q_lora_rank``
+    # None: no query down-projection), a cached latent of ``kv_lora_rank``
+    # and one rotated key part of ``qk_rope_head_dim`` a position, values of
+    # ``v_head_dim`` a head; ``rope_scaling`` (type ``yarn``) on the rotary
+    # part; layers below ``first_k_dense_replace`` a dense gated MLP of
+    # ``intermediate_size``, the others (every ``moe_layer_freq``-th) a
+    # mixture of ``n_routed_experts`` gated experts of
+    # ``moe_intermediate_size``, top ``num_experts_per_tok`` by
+    # ``scoring_func`` (``topk_method`` ``greedy``: no group limit), beside
+    # ``n_shared_experts`` shared ones summed.
+    q_lora_rank: Optional[int] = None
+    kv_lora_rank: Optional[int] = None
+    qk_nope_head_dim: Optional[int] = None
+    qk_rope_head_dim: Optional[int] = None
+    v_head_dim: Optional[int] = None
+    rope_scaling: Optional[dict] = None
+    moe_layer_freq: int = 1
+    scoring_func: str = "softmax"
+    topk_method: str = "greedy"
+    n_group: int = 1
+    topk_group: int = 1
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

@@ -233,20 +233,60 @@ def alibi_slopes(num_heads: int) -> jax.Array:
     return slopes[:num_heads]
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention-temperature term ``0.1 m ln s + 1`` (1 at ``s <=
+    1``)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(half: int, theta: float, yarn: Optional[dict] = None):
+    """The ``half`` pairs' turns per position, float32, computed once per
+    trace: ``theta^(-i / half)``, or with ``yarn`` (a ``rope_scaling`` of
+    type ``yarn``: ``factor`` over ``original_max_position_embeddings``,
+    the ``beta_fast`` / ``beta_slow`` ramp) NTK-by-parts — pair ``i`` keeps
+    its turn where it makes more than ``beta_fast`` rotations over the
+    original context, takes ``1 / factor`` of it where fewer than
+    ``beta_slow``, and a linear blend by pair index between the two
+    (``low = floor(corr(beta_fast))``, ``high = ceil(corr(beta_slow))``,
+    ``corr(r) = D ln(L / (2 pi r)) / (2 ln theta)``, clamped to ``[0, D -
+    1]``).  The cos/sin factor ``yarn_mscale(s, mscale) / yarn_mscale(s,
+    mscale_all_dim)`` is the caller's (1 where the two are equal)."""
+    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if not yarn:
+        return freq
+    d, factor = 2 * half, float(yarn["factor"])
+    orig = float(yarn["original_max_position_embeddings"])
+    corr = lambda r: d * math.log(orig / (2 * math.pi * r)) / (
+        2 * math.log(theta))
+    low = max(math.floor(corr(float(yarn.get("beta_fast", 32)))), 0)
+    high = min(math.ceil(corr(float(yarn.get("beta_slow", 1)))), d - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 1e-3), 0.0, 1.0)
+    return freq * (1.0 - ramp) + freq / factor * ramp
+
+
 def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
-               interleaved: bool = False) -> jax.Array:
+               interleaved: bool = False,
+               yarn: Optional[dict] = None) -> jax.Array:
     """Rotary embedding; x: [T, ..., D] with positions [T].  Pair ``i`` of
-    the ``D / 2`` turns by ``position * theta^(-2 i / D)``: the pair is
+    the ``D / 2`` turns by ``position * theta^(-2 i / D)`` — or by YaRN's
+    frequencies (``yarn``: :func:`rope_frequencies`) —: the pair is
     ``(x[i], x[i + D / 2])`` (half against half, GPT-NeoX's), or with
     ``interleaved`` ``(x[2 i], x[2 i + 1])`` (GPT-J's, ``rope_gptj``)."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq = rope_frequencies(half, theta, yarn)
     angles = positions.astype(jnp.float32)[:, None] * freq  # [T, half]
     # broadcast over middle dims
     shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
     cos = jnp.cos(angles).reshape(shape)
     sin = jnp.sin(angles).reshape(shape)
+    if yarn:
+        scale = float(yarn.get("factor", 1.0))
+        amp = (yarn_mscale(scale, float(yarn.get("mscale", 1.0)))
+               / yarn_mscale(scale, float(yarn.get("mscale_all_dim", 0.0))))
+        if amp != 1.0:
+            cos, sin = cos * amp, sin * amp
     if interleaved:
         x1, x2 = x[..., 0::2], x[..., 1::2]
         return jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin],

@@ -29,7 +29,9 @@ def _quantize_array(w):
     """int8-quantize ``w`` with per-out-channel scales.
 
     Every weight here contracts over its FIRST dim (Linear ``[in, out]``,
-    fused QKV ``[E, KV, G, D]``, o_proj ``[QH*D, E]``), so the scale spans
+    fused QKV ``[E, KV, G, D]``, o_proj ``[QH*D, E]``; the latent operator's
+    ``q_proj [E, H, D]``, ``kv_a [E, r + rope]`` and ``kv_b [r, H, D]``,
+    which is dequantised whole before either of its uses), so the scale spans
     ``w.shape[1:]`` — one scale per output channel.  Returns ``(q int8,
     scale f32)`` with ``q * scale ~= w`` and per-element error bounded by
     ``scale / 2``.
@@ -102,8 +104,11 @@ def quantize_int8(im, include: Optional[Sequence[str]] = None,
                                  if ssh is not None else jnp.asarray(scale))
             op.quantization = "int8"
             n += 1
-        elif attention and hasattr(op, "num_kv_heads"):
-            for pname in ("qkv", "o_proj"):
+        elif attention and (hasattr(op, "num_kv_heads")
+                            or hasattr(op, "int8_params")):
+            # an attention op names its projections (``int8_params``: the
+            # latent operator's four); the others have the fused pair
+            for pname in getattr(op, "int8_params", ("qkv", "o_proj")):
                 w = g.get(pname)
                 if w is None or w.dtype == jnp.int8:
                     continue
@@ -144,7 +149,8 @@ def annotate_int8(graph, include: Optional[Sequence[str]] = None,
                    for p in op.params()):
                 op.quantization = "int8"
                 n += 1
-        elif attention and hasattr(op, "num_kv_heads"):
+        elif attention and (hasattr(op, "num_kv_heads")
+                            or hasattr(op, "int8_params")):
             op.quantization = "int8"
             n += 1
     return n

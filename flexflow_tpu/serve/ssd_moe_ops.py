@@ -266,9 +266,13 @@ class GatedGroupNorm(_Replicated):
 @register_op
 class MoERouter(_Replicated):
     """The router over ALL ``num_experts`` published experts, in float32:
-    ``s = sigmoid(x W)``; the ``top_k`` largest of ``s + bias`` are chosen
+    ``s = sigmoid(x W)`` (``scoring`` ``sigmoid``) or ``s = softmax(x W)``
+    over all of them (``softmax``: ``deepseek_v2``'s ``scoring_func``); the
+    ``top_k`` largest of ``s + bias`` are chosen
     (ties to the lower id: ``lax.top_k``); their weights are ``s`` (without
-    the bias), normalised to sum 1 (``norm_topk``) and times ``scaling``.
+    the bias), normalised to sum 1 (``norm_topk``; off, a softmax router's
+    chosen weights stay the shares of ALL experts' mass they are) and times
+    ``scaling``.
     ``bias=False``: a router without a correction bias has no such parameter
     either (plain sigmoid top-k).  Outputs ``(ids int32 [T, k], weights float32 [T, k])``; a caller that
     hands the forward an ``extras["routing"]`` dict finds the ids there by
@@ -279,7 +283,12 @@ class MoERouter(_Replicated):
 
     def __init__(self, embed_dim: int, num_experts: int, top_k: int,
                  scaling: float = 1.0, norm_topk: bool = True,
-                 dtype=jnp.float32, bias: bool = True):
+                 dtype=jnp.float32, bias: bool = True,
+                 scoring: str = "sigmoid"):
+        if scoring not in ("sigmoid", "softmax"):
+            raise ValueError("a router scores by 'sigmoid' or 'softmax', "
+                             f"not {scoring!r}")
+        self.scoring = scoring
         self.embed_dim = int(embed_dim)
         self.num_experts = int(num_experts)
         self.top_k = int(top_k)
@@ -308,7 +317,9 @@ class MoERouter(_Replicated):
 
     def lower(self, ctx, inputs, params):
         x = inputs[0].astype(jnp.float32)
-        s = jax.nn.sigmoid(jnp.dot(x, params["weight"], precision=HI))
+        s = jnp.dot(x, params["weight"], precision=HI)
+        s = (jax.nn.softmax(s, axis=-1) if self.scoring == "softmax"
+             else jax.nn.sigmoid(s))
         _, ids = jax.lax.top_k(
             s + params["e_score_correction_bias"] if self.bias else s,
             self.top_k)
@@ -403,30 +414,45 @@ class MoEExperts(_Replicated):
     groups' sum are whatever the kernel left there: ``MoECombine`` reads
     none of them.
 
-    The kernel's tiles (``OUT_TILES``: the widest output tile of the GEMMs
-    into and out of the hidden width; rows ``GMM_ROWS``): the contraction
-    WHOLE — no k loop, so a row tile's block index does not change while the
-    grid walks the experts that share it and it is fetched once per output
-    tile —, the output in tiles of a few MB of weights.  Scoped VMEM (16 MiB
-    on the v5e), the pipeline's two buffers each: ``relu2`` at 2688 x 1856
-    (nemotron_h): rows 128 x 2688 x 2 B = 0.7 MB, weights 2688 x 640 x 2 B =
-    3.4 MB up and 1856 x 896 x 2 B = 3.3 MB down, the float32 output tile
-    and accumulator 0.3-0.5 MB: 9 MB.  ``swiglu`` at 4096 x 4096
-    (cohere2_moe): the whole contraction beside a 1024-wide tile would be
-    8.4 MB of weights a buffer, 17 MB for two — over the limit; at 512: rows
-    128 x 4096 x 2 B = 1 MB, weights 4096 x 512 x 2 B = 4.2 MB, output and
-    accumulator 128 x 512 x 4 B = 0.26 MB: (1 + 4.2 + 0.26) x 2 + 0.26 =
-    11.2 MB (the TPU compiler takes it: tests/test_tpu_aot_compile.py)."""
+    The kernel's tiles (:meth:`out_tile`, from the GEMM's shapes and
+    ``VMEM_BUDGET``; rows ``GMM_ROWS``): the contraction WHOLE — no k loop,
+    so a row tile's block index does not change while the grid walks the
+    experts that share it and it is fetched once per output tile —, the
+    output in the fewest tiles whose working set fits the budget, evened out
+    to whole lanes.  The working set of a GEMM ``[rows, c] x [c, n]`` at
+    output tile ``t``, the pipeline's two buffers each: rows ``128 c`` and
+    weights ``c t`` in the parameters' type, the float32 output tile ``128
+    t``, and the float32 accumulator once: ``2 (128 c b + c t b + 512 t) +
+    512 t`` bytes, held to 11.5 MB of the v5e's 16 MiB of scoped VMEM.
+
+    * ``relu2`` at 2688 x 1856 (nemotron_h): up — ``c`` 2688, at most 823
+      wide, so 3 tiles of 1856: 640 (rows 0.7 MB, weights 3.4 MB); down —
+      ``c`` 1856, at most 1177, so 3 tiles of 2688: 896 (3.3 MB of
+      weights): 9 MB each.
+    * ``swiglu`` at 4096 x 4096 (cohere2_moe): at most 524 wide, so 8 tiles
+      of 512 either way: rows 1 MB, weights 4.2 MB, output and accumulator
+      0.26 MB: (1 + 4.2 + 0.26) x 2 + 0.26 = 11.2 MB (a 1024-wide tile
+      would be 17 MB of weights for two buffers — over the limit).
+    * ``swiglu`` at 2048 x 1408 (deepseek_v2; 1408 = 11 x 128): gate and up
+      — ``c`` 2048, at most 1074 wide, so 2 tiles of 1408: 768 (the second
+      ragged, 640): rows 0.5 MB, weights 3.1 MB, output 0.4 MB: 8.5 MB;
+      down — ``c`` 1408, at most 1503, so 2 tiles of 2048: 1024: rows
+      0.4 MB, weights 2.9 MB, output 0.5 MB: 8.0 MB.  (The widest
+      lane-multiple DIVISOR of 1408 under the budget is 128: eleven tiles,
+      each re-reading the row tile.)
+
+    The TPU compiler takes all three (tests/test_tpu_aot_compile.py)."""
 
     type_name = "moe_experts"
-    # form -> (widest tile of the hidden width, widest of the model width)
-    OUT_TILES = {"relu2": (640, 1024), "swiglu": (512, 512)}
+    FORMS = ("relu2", "swiglu")
+    # of the 16 MiB of scoped VMEM: what a grouped GEMM's working set may take
+    VMEM_BUDGET = 11.5e6
 
     def __init__(self, num_held: int, embed_dim: int, width: int,
                  dtype=jnp.float32, form: str = "relu2"):
-        if form not in self.OUT_TILES:
+        if form not in self.FORMS:
             raise ValueError(f"an expert's form is one of "
-                             f"{sorted(self.OUT_TILES)}, not {form!r}")
+                             f"{sorted(self.FORMS)}, not {form!r}")
         self.num_held = int(num_held)
         self.embed_dim = int(embed_dim)
         self.width = int(width)
@@ -447,18 +473,22 @@ class MoEExperts(_Replicated):
         gemms = 3 if self.form == "swiglu" else 2
         return 2 * gemms * in_specs[0].shape[0] * self.embed_dim * self.width
 
-    @staticmethod
-    def _tile(n: int, most: int) -> int:
-        """``n`` whole where it is at most ``most``; else the widest
-        multiple of 128 up to ``most`` that divides ``n``, or that multiple
-        itself where none does (the kernel's last tile is then ragged)."""
+    @classmethod
+    def out_tile(cls, contraction: int, n: int, itemsize: int) -> int:
+        """The output tile of a grouped GEMM ``[GMM_ROWS, contraction] x
+        [contraction, n]``: ``n`` whole where its working set fits
+        ``VMEM_BUDGET`` (the class's docstring has the sum); else the
+        fewest tiles that do, evened out — ``n`` over that many, rounded up
+        to whole lanes (the kernel's last tile is ragged where that does not
+        divide ``n``)."""
+        fixed = 2 * GMM_ROWS * contraction * itemsize
+        per_col = 2 * (contraction * itemsize + GMM_ROWS * 4) + GMM_ROWS * 4
+        most = int((cls.VMEM_BUDGET - fixed) // per_col)
         if n <= most:
             return n
-        widest = most - most % 128
-        for cand in range(widest, 0, -128):
-            if n % cand == 0:
-                return cand
-        return widest
+        most = max(most - most % 128, 128)
+        tiles = -(-n // most)
+        return -(-n // (tiles * 128)) * 128
 
     def lower(self, ctx, inputs, params):
         xs, sizes = inputs
@@ -474,9 +504,9 @@ class MoEExperts(_Replicated):
             grouped = lambda a, w, out_tile: jax.lax.ragged_dot(
                 a, w, sizes, preferred_element_type=jnp.float32)
             path = "ragged_dot"
-        hidden_tile, model_tile = (
-            self._tile(n, most) for n, most in
-            zip((self.width, self.embed_dim), self.OUT_TILES[self.form]))
+        itemsize = jnp.dtype(params["up"].dtype).itemsize
+        hidden_tile = self.out_tile(self.embed_dim, self.width, itemsize)
+        model_tile = self.out_tile(self.width, self.embed_dim, itemsize)
         h = grouped(xs, params["up"], hidden_tile)
         if self.form == "swiglu":
             h = jax.nn.silu(grouped(xs, params["gate"], hidden_tile)) * h

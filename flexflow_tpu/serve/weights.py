@@ -336,6 +336,52 @@ COHERE2_MOE_TENSORS = {
 }
 
 
+# The published tensor names of ``deepseek_v2`` (DeepSeek-V2-Lite: no
+# ``q_a_proj`` / ``q_b_proj``, ``q_lora_rank`` null), for an importer to be
+# written when a checkpoint is in the repository (none is: they are the
+# family's convention and stay ASSUMED until one is).  ``{torch tensor:
+# (graph node, parameter, layout)}`` per layer ``model.layers.<l>.``, beside
+# the reference's tables (``benchmark/reference/deepseek_v2.py`` ``LAYER`` /
+# ``program_tree``: the same map in kernel form, ``[in, out]``): a projection
+# transposes.  ``kv_b_proj`` ``[H (nope + v), r]`` transposed is ``[r, H,
+# nope + v]``: head ``i``'s ``U_k = kv_b[:, i, :nope]`` (absorbed into the
+# query) and ``U_v = kv_b[:, i, nope:]`` (applied to the latent-wide
+# output).  ``kv_a_proj_with_mqa``'s last ``rope`` columns are the ONE
+# rotated key part all heads share.  Layer ``l < first_k_dense_replace`` has
+# the dense ``mlp.{gate,up,down}_proj``; the others the router ``mlp.gate``,
+# ``mlp.experts.<e>`` stacked into ``[E, in, out]`` and ONE module
+# ``mlp.shared_experts`` of width ``n_shared_experts x moe_intermediate_size``.
+DEEPSEEK_V2_TENSORS = {
+    "model.embed_tokens.weight": ("model.embed_tokens", "weight", "[V, d]"),
+    "model.norm.weight": ("model.norm", "gamma", "[d]"),
+    "lm_head.weight": ("lm_head", "kernel", "[d, V] = .T"),
+    "input_layernorm.weight": ("input_layernorm", "gamma", "[d]"),
+    "post_attention_layernorm.weight":
+        ("post_attention_layernorm", "gamma", "[d]"),
+    "self_attn.q_proj.weight":
+        ("self_attn", "q_proj", "[d, H, nope + rope] = .T reshaped"),
+    "self_attn.kv_a_proj_with_mqa.weight":
+        ("self_attn", "kv_a", "[d, r + rope] = .T"),
+    "self_attn.kv_a_layernorm.weight": ("self_attn", "kv_norm", "[r]"),
+    "self_attn.kv_b_proj.weight":
+        ("self_attn", "kv_b", "[r, H, nope + v] = .T reshaped: U_k | U_v"),
+    "self_attn.o_proj.weight": ("self_attn", "o_proj", "[H v, d] = .T"),
+    "mlp.gate_proj.weight": ("mlp.gate_proj", "kernel", "[d, I] = .T"),
+    "mlp.up_proj.weight": ("mlp.up_proj", "kernel", "[d, I] = .T"),
+    "mlp.down_proj.weight": ("mlp.down_proj", "kernel", "[I, d] = .T"),
+    "mlp.gate.weight": ("mlp.gate", "weight", "[d, experts] = .T, float32"),
+    "mlp.experts.N.gate_proj.weight": ("mlp.experts", "gate", "[N] = .T"),
+    "mlp.experts.N.up_proj.weight": ("mlp.experts", "up", "[N] = .T"),
+    "mlp.experts.N.down_proj.weight": ("mlp.experts", "down", "[N] = .T"),
+    "mlp.shared_experts.gate_proj.weight":
+        ("mlp.shared_experts.gate_proj", "kernel", "[d, n f] = .T"),
+    "mlp.shared_experts.up_proj.weight":
+        ("mlp.shared_experts.up_proj", "kernel", "[d, n f] = .T"),
+    "mlp.shared_experts.down_proj.weight":
+        ("mlp.shared_experts.down_proj", "kernel", "[n f, d] = .T"),
+}
+
+
 def load_hf_model(name_or_path: str):
     """Load a local HF checkpoint (config + weights + tokenizer if present).
 

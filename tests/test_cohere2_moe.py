@@ -662,11 +662,11 @@ def test_relu2_experts_keep_their_parameters_and_tiles():
     op = MoEExperts(4, 2688, 1856)
     assert op.form == "relu2"
     assert [p.name for p in op.params()] == ["up", "down"]
-    assert [op._tile(n, most) for n, most in
-            zip((1856, 2688), op.OUT_TILES["relu2"])] == [640, 896]
-    wide = MoEExperts(16, 4096, 4096, form="swiglu")
-    assert [wide._tile(4096, most) for most in wide.OUT_TILES["swiglu"]] == \
-        [512, 512]
+    # since PR 54 the tiles come from the shapes and the VMEM budget
+    # (MoEExperts.out_tile): the two committed shapes keep what they had
+    assert [op.out_tile(2688, 1856, 2), op.out_tile(1856, 2688, 2)] == \
+        [640, 896]
+    assert [MoEExperts.out_tile(4096, 4096, 2)] * 2 == [512, 512]
     with pytest.raises(ValueError, match="form"):
         MoEExperts(4, 32, 24, form="geglu")
 

@@ -455,6 +455,68 @@ def test_gated_routed_layer_compiles_for_v5e(one_chip, rows):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("rows", [64, 512], ids=["scan64", "chunk512"])
+def test_softmax_routed_layer_compiles_for_v5e(one_chip, rows):
+    """The routed-expert layer at DeepSeek-V2-Lite's published widths
+    (hidden 2048, a softmax router over 64 experts with top-6 un-normalised,
+    ALL 64 gated experts of width 1408 = 11 x 128 held) on the decode scan's
+    64 rows and on a prompt chunk's 512: the tiles ``MoEExperts.out_tile``
+    plans from the shapes — 768 of 1408 into the hidden width (a ragged
+    second tile), 1024 of 2048 out of it."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.ssd_moe_ops import (MoECombine, MoEDispatch,
+                                                MoEExperts, MoERouter)
+
+    d, f, held, k = 2048, 1408, 64, 6
+    assert (MoEExperts.out_tile(d, f, 2), MoEExperts.out_tile(f, d, 2)) == \
+        (768, 1024)
+
+    def layer(x, router, gate, up, down):
+        ctx = lambda: OpContext(extras={"node_name": "n",
+                                        "pallas_decode": True})
+        ids, w = MoERouter(d, held, k, dtype=x.dtype, bias=False,
+                           norm_topk=False, scoring="softmax").lower(
+            ctx(), [x], {"weight": router})
+        xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
+            ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
+        return MoECombine(held, dtype=x.dtype).lower(
+            ctx(), [ys, order, ids, w], {})[0]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((d, held), jnp.float32),
+        sds((held, d, f), jnp.bfloat16), sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("rows", [64, 512], ids=["scan64", "flat512"])
+def test_latent_decode_kernel_compiles_for_v5e(one_chip, rows):
+    """``decode_attention``'s LATENT mode at DeepSeek-V2-Lite's widths: 16
+    query heads on one latent of 512 (key AND value: no V operand) beside a
+    rotated key plane of 64 — not a lane multiple —, 65 cache rows of 15 360
+    positions, bf16; the decode scan's 64 rows and a flat step's 512.  One
+    Mosaic kernel with ONE cache-sized operand per plane."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    slots, s, h, r, dr = 64, 15360, 16, 512, 64
+    f = functools.partial(decode_attention, scale=0.1147)
+    lowered = jax.jit(
+        lambda q, ckv, rw, ps, qr, kpe: f(q, ckv, None, rw, ps, q_rope=qr,
+                                          k_rope=kpe)).lower(
+        sds((rows, h, r), jnp.bfloat16),
+        sds((slots + 1, 1, s, r), jnp.bfloat16),
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+        sds((rows, h, dr), jnp.bfloat16),
+        sds((slots + 1, 1, s, dr), jnp.bfloat16))
+    text = lowered.compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+            and "custom-call(" in ln][0]
+    # the latent plane goes in ONCE: no second operand of its shape
+    assert call.count(f"bf16[{slots + 1},1,{s},{r}]") == 1, call[:400]
+
+
 @pytest.mark.parametrize("form", ["slot_order", "chunked"])
 def test_nemotron_ssd_scan_compiles_for_v5e(one_chip, form):
     """``Mamba2Scan`` at the published widths (64 heads of 64 in 8 groups,
