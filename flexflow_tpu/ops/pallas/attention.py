@@ -52,13 +52,18 @@ Design (v2 — measured on a real v5e chip):
   frontier's PHYSICAL page, whose copy Pallas then skips as before.
 
 * **a latent cache** (``decode_attention(v_cache=None, q_rope=, k_rope=)``:
-  multi-head latent attention, absorbed): ONE latent per position is the
-  key and the value of every head, so the block is copied once and used by
-  both contractions; a second, narrower key plane (the rotated part) adds
-  its dot to the score under the same index map.  Score width ``D + Dr``,
-  value width ``D``; 16 query heads on the one cached "head"; the block is
-  planned from the bytes of both planes (1 152 B a position at 512 + 64:
-  512 positions a copy).  Same grid, clamp, masks and online softmax.
+  multi-head latent attention, absorbed) has a kernel of its own,
+  ``_latent_decode_kernel``: ONE latent per position is the key and the
+  value of every head, beside a second, narrower key plane (the rotated
+  part: score width ``D + Dr``, value width ``D``; 16 query heads on the one
+  cached "head").  The latents stay in HBM and the kernel copies each row's
+  LIVE blocks itself into a VMEM ring, the next copies in flight while a
+  block is scored — across rows too — and the frontier block in
+  128-position pieces: no grid step per block, no latent copied, awaited or
+  stepped over past a row's frontier.  A block is copied once and used by
+  both contractions; the online softmax is the one above.  The rotated
+  plane, which Mosaic lets no kernel slice by itself (rows narrower than the
+  lanes), rides the BlockSpec pipeline in spans of 3072 positions.
 
 Under tensor parallelism the caller (serve/ops.py) wraps these kernels in a
 ``shard_map`` over the kv-head axis — the cache's head dim is the shard dim,
@@ -160,14 +165,15 @@ def _decode_plan(num_kv, d, itemsize, kv_quant, s_len, window=0,
 
 
 def decode_block_plan(k_cache, kv_quant=False, window=0, page_size=0,
-                      rope_dim=0):
+                      latent=False):
     """The plan :func:`decode_attention` takes on this cache, as the
     ``attention_path.decode_block.*`` counter names it: ``ring4608`` (a ring
-    copied whole), ``full2048``, ``full256``.  ``rope_dim``: the width of
-    the second key plane a latent cache keeps beside this one."""
+    copied whole), ``full2048``, ``full256``; ``live1024`` for a ``latent``
+    cache, whose kernel copies a row's live blocks of 1024 itself."""
     _, num_kv, s_len, d = k_cache.shape
-    block = _decode_plan(num_kv, d + rope_dim,
-                         jnp.dtype(k_cache.dtype).itemsize,
+    if latent:
+        return f"live{_latent_plan(s_len)[0]}"
+    block = _decode_plan(num_kv, d, jnp.dtype(k_cache.dtype).itemsize,
                          kv_quant, s_len, window, page_size)
     return f"{'ring' if window else 'full'}{block}"
 
@@ -230,9 +236,8 @@ def _ring_blocks(pos, block_s, s_len, window):
 def _decode_kernel(
     rows_ref,       # scalar prefetch: i32[T] cache row per token
     pos_ref,        # scalar prefetch: i32[T] absolute position per token
-    *refs,          # [pt_ref (paged),] q_ref, k_ref, [v_ref,]
-                    # [qr_ref, kr_ref,] [ks_ref, vs_ref,] slopes_ref, o_ref,
-                    # m/l/acc scratch
+    *refs,          # [pt_ref (paged),] q_ref, k_ref, v_ref,
+                    # [ks_ref, vs_ref,] slopes_ref, o_ref, m/l/acc scratch
     block_s: int,
     num_kv: int,
     gq: int,
@@ -242,19 +247,11 @@ def _decode_kernel(
     paged: bool = False,
     window: int = 0,
     s_len: int = 0,
-    latent: bool = False,
-    rope: bool = False,
 ):
     if paged:
         # the page-table prefetch ref is consumed by the index maps only
         refs = refs[1:]
-    q_ref, k_ref, *rest = refs
-    # a LATENT cache has no value plane: the copied key block is the value
-    v_ref = k_ref if latent else rest.pop(0)
-    if rope:
-        # the score's second term: a narrow rotated key part of its own
-        # plane, one per position, under the same block index map
-        qr_ref, kr_ref = rest.pop(0), rest.pop(0)
+    q_ref, k_ref, v_ref, *rest = refs
     if kv_quant:
         # ks/vs: [1, KV, Bs] f32 per-position dequant scales, same block
         # index map as K/V
@@ -282,24 +279,12 @@ def _decode_kernel(
 
     @pl.when(run)
     def _compute():
-        if latent:
-            # the block as cached (bf16 products are exact in the float32
-            # accumulator, so nothing is lost to the narrower operands)
-            k = k_ref[0]                                # [KV, Bs, D]
-            q = q_ref[0].astype(k.dtype)                # [KV, gq, D]
-        else:
-            q = q_ref[0].astype(jnp.float32)            # [KV, gq, D]
-            k = k_ref[0].astype(jnp.float32)            # [KV, Bs, D]
+        q = q_ref[0].astype(jnp.float32)               # [KV, gq, D]
+        k = k_ref[0].astype(jnp.float32)               # [KV, Bs, D]
         sc = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )
-        if rope:
-            kr = kr_ref[0]                              # [KV, Bs, Dr]
-            sc = sc + jax.lax.dot_general(
-                qr_ref[0].astype(kr.dtype), kr, (((2,), (2,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)
-        sc = sc * scale                                 # [KV, gq, Bs]
+        ) * scale                                       # [KV, gq, Bs]
         if kv_quant:
             # fused dequant: q·(k_int8*ks) == (q·k_int8)*ks per key position
             sc = sc * ks_ref[0][:, None, :]
@@ -331,17 +316,12 @@ def _decode_kernel(
         p = jnp.where(seen, p, 0.0)
 
         l_new = alpha * l_ref[:, :, 0:1] + jnp.sum(p, -1, keepdims=True)
-        if latent:
-            # the value IS the key block already in VMEM: read once, used
-            # twice; the weights go to the matrix unit in the block's type
-            v, pw = k, p.astype(k.dtype)
-        else:
-            v = v_ref[0].astype(jnp.float32)            # [KV, Bs, D]
+        v = v_ref[0].astype(jnp.float32)                # [KV, Bs, D]
+        pv = jax.lax.dot_general(
             # fused dequant: (p*vs)·v_int8 == p·(v_int8*vs); the softmax
             # denominator above uses the UNSCALED p
-            pw = p * vs_ref[0][:, None, :] if kv_quant else p
-        pv = jax.lax.dot_general(
-            pw, v, (((2,), (1,)), ((0,), (0,))),
+            p * vs_ref[0][:, None, :] if kv_quant else p,
+            v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )                                               # [KV, gq, D]
         acc_ref[...] = acc_ref[...] * alpha + pv
@@ -375,20 +355,16 @@ def decode_attention(
     page_table: Optional[jax.Array] = None,  # i32[R+1, S//page_size] paged KV
     page_size: int = 0,                      # static; 0 = slot-contiguous
     window: int = 0,     # static; > 0: the cache is a RING (see below)
-    q_rope: Optional[jax.Array] = None,  # [T, QH, Dr] the score's 2nd term:
-    k_rope: Optional[jax.Array] = None,  # [R+1, KV, S, Dr] q_rope . k_rope
+    q_rope: Optional[jax.Array] = None,  # [T, QH, Dr] a latent score's 2nd
+    k_rope: Optional[jax.Array] = None,  # term: [R+1, 1, S, Dr]
 ) -> jax.Array:
-    """K and V planes of one head size ``D``, each read once — or, with
-    ``v_cache=None``, a LATENT cache (multi-head latent attention in its
-    absorbed form): ``k_cache`` holds one latent per position that is the
-    key AND the value, so each block is copied ONCE and used for the score
-    and for the weighted sum, and the output is ``D`` wide like the latent.
+    """K and V planes of one head size ``D``, each read once, through the
+    BlockSpec pipeline below — or, with ``v_cache=None``, a LATENT cache
+    (multi-head latent attention in its absorbed form), which goes to a
+    kernel of its own (:func:`_latent_decode`): ``k_cache`` holds one latent
+    per position that is the key AND the value of all ``QH`` heads, and
     ``q_rope`` / ``k_rope`` add ``q_rope . k_rope`` to the score from a
-    second, narrower plane under the same block index map (the rotated key
-    part a latent cache keeps beside the latent: score width ``D + Dr``,
-    value width ``D``).  The planning, the index maps, the masks and the
-    online softmax are the ones below, whatever the mode; a latent block's
-    dots run in the cache's own type with a float32 accumulator.
+    second, narrower plane (score width ``D + Dr``, value width ``D``).
 
     ``window > 0``: a sliding-window layer.  The cache's seq dim is then a
     ring — position ``p`` lives at slot ``p % S`` (``S`` at least the window
@@ -405,18 +381,22 @@ def decode_attention(
     gq = qh // num_kv
     kv_quant = k_scale is not None
     paged = page_table is not None
-    latent, rope = v_cache is None, k_rope is not None
     if window:
         if paged or use_alibi or kv_quant:
             raise ValueError("a ring cache is slot-contiguous, fp, and has "
                              "no positional bias")
-    if (latent or rope) and (paged or kv_quant or window or use_alibi):
-        raise ValueError("a latent cache is slot-contiguous, fp, full-length "
-                         "and has no positional bias")
-    d_rope = k_rope.shape[-1] if rope else 0
+    if v_cache is None:
+        if paged or kv_quant or window or use_alibi or num_kv != 1:
+            raise ValueError("a latent cache is slot-contiguous, fp, "
+                             "full-length, one cached head, and has no "
+                             "positional bias")
+        return _latent_decode(q, k_cache, rows, positions, float(scale),
+                              q_rope, k_rope, interpret)
+    if k_rope is not None:
+        raise ValueError("a second key plane belongs to a latent cache")
     block_s = _decode_plan(
-        num_kv, d + d_rope, jnp.dtype(k_cache.dtype).itemsize, kv_quant,
-        s_len, window, page_size if paged else 0, block_s)
+        num_kv, d, jnp.dtype(k_cache.dtype).itemsize, kv_quant, s_len,
+        window, page_size if paged else 0, block_s)
     n_blocks = s_len // block_s
     qr = q.reshape(t, num_kv, gq, d)
     if slopes is None:
@@ -459,21 +439,20 @@ def decode_attention(
 
     scale_specs, scale_args = _scale_plumbing(
         kv_map, num_kv, block_s, k_scale, v_scale)
-    q_spec = lambda width: pl.BlockSpec(
-        (1, num_kv, gq, width), lambda i, j, *_: (i, 0, 0, 0),
-        memory_space=pltpu.VMEM)
-    kv_spec = lambda width: pl.BlockSpec(
-        (1, num_kv, block_s, width), kv_map, memory_space=pltpu.VMEM)
-    value = () if latent else (v_cache,)
-    second = (q_rope.reshape(t, num_kv, gq, d_rope), k_rope) if rope else ()
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(t, n_blocks),
         in_specs=[
-            q_spec(d),
-            kv_spec(d),
-            *[kv_spec(d)] * len(value),
-            *([q_spec(d_rope), kv_spec(d_rope)] if rope else []),
+            pl.BlockSpec(
+                (1, num_kv, gq, d), lambda i, j, *_: (i, 0, 0, 0),
+                memory_space=pltpu.VMEM,
+            ),
+            pl.BlockSpec(
+                (1, num_kv, block_s, d), kv_map, memory_space=pltpu.VMEM,
+            ),
+            pl.BlockSpec(
+                (1, num_kv, block_s, d), kv_map, memory_space=pltpu.VMEM,
+            ),
             *scale_specs,
             pl.BlockSpec(
                 (num_kv, gq), lambda i, j, *_: (0, 0),
@@ -494,15 +473,259 @@ def decode_attention(
         _decode_kernel,
         block_s=block_s, num_kv=num_kv, gq=gq,
         scale=float(scale), use_alibi=use_alibi, kv_quant=kv_quant,
-        paged=paged, window=window, s_len=s_len, latent=latent, rope=rope,
+        paged=paged, window=window, s_len=s_len,
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, num_kv, gq, d), q.dtype),
         interpret=interpret,
-    )(*prefetch, qr, k_cache, *value, *second, *scale_args, slopes)
+    )(*prefetch, qr, k_cache, v_cache, *scale_args, slopes)
     return out.reshape(t, qh, d)
+
+
+# A latent cache's decode kernel (``_latent_decode_kernel``) copies a row's
+# live latents itself: whole blocks of ``_LATENT_BLOCK`` positions into a
+# VMEM ring of ``_LATENT_DEPTH`` slots, the frontier block in pieces of
+# ``_LATENT_PIECE``.  The rotated key parts, a plane too narrow for a copy of
+# the kernel's own (below), come through the BlockSpec pipeline in spans of
+# ``_LATENT_SPAN`` positions.  Chosen from the kernel alone on the v5e at
+# DeepSeek-V2-Lite's decode shape (``scripts/decode_kernel_bench.py --shapes
+# doc-latent --stub``; PERF.md section 6, PR 55): the copies alone take 907 us
+# a call whatever the block; the whole call 943 at 512 x 3, 909 at 1024 x 3,
+# 903 at 1536 x 3, 960 at 1024 x 2.
+_LATENT_BLOCK = 1024
+_LATENT_DEPTH = 3
+_LATENT_PIECE = 128
+_LATENT_SPAN = 3072
+
+
+def _latent_plan(s_len):
+    """``(block, span)`` of :func:`_latent_decode` on a cache of ``s_len``
+    positions: the block divides the span, the span divides ``s_len`` (the
+    largest such multiple of the block up to ``_LATENT_SPAN``)."""
+    block = math.gcd(_LATENT_BLOCK, s_len)
+    if block % _LATENT_PIECE:
+        raise ValueError("a latent cache holds whole pieces of "
+                         f"{_LATENT_PIECE} positions")
+    span = max(m * block for m in range(1, max(_LATENT_SPAN // block, 1) + 1)
+               if s_len % (m * block) == 0)
+    return block, span
+
+
+def _latent_attend(q, c, rope, seen, m_ref, l_ref, acc_ref, scale):
+    """One copied block ``c [block, D]`` of latents — key AND value, used
+    twice from the one copy — under the running softmax of the queries ``q
+    [H, D]``; ``rope``: None, or ``(q_r [H, Dr], k_r [block, Dr])`` whose
+    dot joins the score.  Operands in the cache's type (bf16 products are
+    exact in the float32 they accumulate in), float32 scores and statistics.
+    ``seen [1, block]`` masks the frontier block's keys; None: the row sees
+    them all."""
+    nt = (((1,), (1,)), ((), ()))
+    sc = jax.lax.dot_general(q, c, nt, preferred_element_type=jnp.float32)
+    if rope is not None:
+        sc = sc + jax.lax.dot_general(*rope, nt,
+                                      preferred_element_type=jnp.float32)
+    sc = sc * scale                                          # [H, block]
+    if seen is not None:
+        sc = jnp.where(seen, sc, NEG_INF)
+    m_prev = m_ref[:, 0:1]
+    m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(sc - m_new)
+    if seen is not None:
+        p = jnp.where(seen, p, 0.0)
+    l_new = alpha * l_ref[:, 0:1] + jnp.sum(p, -1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p.astype(c.dtype), c, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                  # [H, D]
+    acc_ref[...] = acc_ref[...] * alpha + pv
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _latent_decode_kernel(
+    rows_ref,       # scalar prefetch: i32[T] cache row per token
+    pos_ref,        # scalar prefetch: i32[T] absolute position per token
+    *refs,          # q_ref [1, H, D], [qr_ref [1, H, Dr],] c_hbm [R+1, 1, S,
+                    # D] left in HBM, [kr_ref [1, 1, span, Dr],] o_ref [1, H,
+                    # D], ring: VMEM [depth, block, D], sems: DMA semaphores
+                    # [depth], cur: SMEM i32[4] — the fetch side's row and
+                    # block, blocks fetched, blocks consumed (over the whole
+                    # call) —, m/l/acc scratch
+    rope: bool,
+    block: int,
+    span: int,
+    depth: int,
+    scale: float,
+):
+    if rope:
+        q_ref, qr_ref, c_hbm, kr_ref, *rest = refs
+    else:
+        q_ref, c_hbm, *rest = refs
+    o_ref, ring, sems, cur, m_ref, l_ref, acc_ref = rest
+    t, g = pl.program_id(0), pl.program_id(1)
+    n_rows = pl.num_programs(0)
+    per_span = span // block
+
+    def copy(source, slot, off, n, go):
+        """Start (``go``) or await the copy of ``n`` latents, from ``off``
+        into the block that begins at ``source`` (cache row, position), to
+        the same offset of ring slot ``slot``."""
+        row, start = source if go else (0, 0)
+        cp = pltpu.make_async_copy(
+            c_hbm.at[row, 0, pl.ds(pl.multiple_of(start + off, n), n)],
+            ring.at[slot, pl.ds(off, n)], sems.at[slot])
+        cp.start() if go else cp.wait()
+
+    def frontier(source, slot, pos, go):
+        """The frontier block's live pieces alone: a row reads at most
+        ``_LATENT_PIECE - 1`` latents past its own."""
+        for off in range(0, block, _LATENT_PIECE):
+            pl.when(off <= pos % block)(functools.partial(
+                copy, source, slot, off, _LATENT_PIECE, go))
+
+    def fetch_next():
+        """Start the call's next block — of this row, or the first of the
+        row after it — into the slot freed longest ago."""
+        row, b = cur[0], cur[1]
+
+        @pl.when(row < n_rows)
+        def _start():
+            pos = pos_ref[row]
+            last = b == pos // block
+            source = rows_ref[row], b * block
+            slot = cur[2] % depth
+            pl.when(jnp.logical_not(last))(functools.partial(
+                copy, source, slot, 0, block, True))
+            pl.when(last)(functools.partial(frontier, source, slot, pos,
+                                            True))
+            cur[0] = row + last.astype(jnp.int32)
+            cur[1] = jnp.where(last, 0, b + 1)
+            cur[2] = cur[2] + 1
+
+    @pl.when((t == 0) & (g == 0))
+    def _open():
+        # a slot's pieces past a frontier are multiplied by zero weights:
+        # they must not hold a NaN's bit pattern
+        ring[...] = jnp.zeros_like(ring)
+        for i in range(4):
+            cur[i] = 0
+        for _ in range(depth - 1):
+            fetch_next()
+
+    @pl.when(g == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    pos = pos_ref[t]
+    last = pos // block              # the row's frontier block
+    first = g * per_span             # this grid step's blocks begin here
+
+    def one_block(j, seen):
+        """Block ``first + j`` of the row: the next one in the ring."""
+        fetch_next()                 # the queue stays ``depth`` blocks deep
+        slot = cur[3] % depth
+        if seen is None:
+            copy(None, slot, 0, block, False)
+        else:
+            frontier(None, slot, pos, False)
+        second = None
+        if rope:
+            kr = kr_ref[0, 0, pl.ds(pl.multiple_of(j * block, block), block)]
+            second = qr_ref[0].astype(kr.dtype), kr
+        _latent_attend(q_ref[0].astype(ring.dtype), ring[slot], second, seen,
+                       m_ref, l_ref, acc_ref, scale)
+        cur[3] = cur[3] + 1
+
+    @pl.when(first <= last)          # a span past the frontier: nothing
+    def _span():
+        def interior(j, carry):
+            one_block(j, None)
+            return carry
+
+        jax.lax.fori_loop(0, jnp.minimum(last - first, per_span), interior,
+                          0)
+
+        @pl.when(last < first + per_span)
+        def _frontier():             # the causal mask is this block's alone
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+            one_block(last - first, last * block + lane <= pos)
+
+    @pl.when(g == pl.num_programs(1) - 1)
+    def _finalize():
+        denom = jnp.maximum(l_ref[:, 0:1], 1e-30)
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _latent_decode(q, ckv, rows, positions, scale, q_rope, k_rope,
+                   interpret):
+    """:func:`decode_attention` over a LATENT cache: ``ckv [R+1, 1, S, D]``
+    holds one latent per position, key and value of all ``H`` heads of ``q
+    [T, H, D]``; ``k_rope [R+1, 1, S, Dr]`` beside it (or None) adds
+    ``q_rope . k_rope`` to the score.
+
+    The latents — eight ninths of the bytes at 512 + 64 — stay in HBM and
+    the kernel issues its own copies: a row's ``pos // block + 1`` LIVE
+    blocks, each copied once into a VMEM ring of ``_LATENT_DEPTH`` slots
+    (one DMA semaphore a slot) and used for the score and for the weighted
+    sum.  The fetch side runs ``_LATENT_DEPTH - 1`` blocks ahead of the
+    arithmetic ACROSS rows (its row and block ride in scalar memory): the
+    next row's first block is on its way while this row's last is scored.
+    The frontier block comes in ``_LATENT_PIECE``-position pieces, its live
+    ones alone, and is the only block masked; a pad row (position 0) costs
+    one piece.  No latent is copied, awaited or stepped over past a row's
+    frontier.
+
+    The rotated key parts cannot be copied so: Mosaic holds a plane whose
+    rows are narrower than 128 lanes padded in HBM and refuses every slice
+    of it in a copy of the kernel's own ("slice shape along dimension 3
+    must be aligned to tiling (128), but is 64").  They come through the
+    BlockSpec pipeline in SPANS of ``_LATENT_SPAN`` positions, clamped to
+    the row's frontier as the K/V kernel's blocks are: the grid is ``(rows,
+    S / span)`` — 5 steps a row at ``S`` 15 360 where the K/V kernel's grid
+    takes 30 — and a grid step walks the ring for its span's live blocks."""
+    t, h, d = q.shape
+    s_len = ckv.shape[2]
+    block, span = _latent_plan(s_len)
+    rope = k_rope is not None
+    row_spec = lambda width: pl.BlockSpec(
+        (1, h, width), lambda i, j, *_: (i, 0, 0), memory_space=pltpu.VMEM)
+    second = ()
+    if rope:
+        d_rope = k_rope.shape[-1]
+        second = (row_spec(d_rope), pl.BlockSpec(
+            (1, 1, span, d_rope),
+            lambda i, j, rows, pos: (rows[i], 0,
+                                     jnp.minimum(j, pos[i] // span), 0),
+            memory_space=pltpu.VMEM))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(t, s_len // span),
+        in_specs=[row_spec(d), *second[:1],
+                  pl.BlockSpec(memory_space=pl.ANY), *second[1:]],
+        out_specs=row_spec(d),
+        scratch_shapes=[
+            pltpu.VMEM((_LATENT_DEPTH, block, d), ckv.dtype),
+            pltpu.SemaphoreType.DMA((_LATENT_DEPTH,)),
+            pltpu.SMEM((4,), jnp.int32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, 128), jnp.float32),
+            pltpu.VMEM((h, d), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(
+        _latent_decode_kernel, rope=rope, block=block, span=span,
+        depth=_LATENT_DEPTH, scale=scale)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, h, d), q.dtype),
+        interpret=interpret,
+    )(rows.astype(jnp.int32), positions.astype(jnp.int32), q,
+      *([q_rope, ckv, k_rope] if rope else [ckv]))
 
 
 def _sparse_decode_kernel(

@@ -790,8 +790,11 @@ class LatentAttention(_SlotStateOp):
     expanded.  At the published widths (16 heads, 512 + 64, 128 / 128) that
     is 34.8k against 10.2k a pair plus 4.2M a position and query group: a
     decode row (one query a group) is absorbed, always — through
-    ``decode_attention``'s latent mode where the kernels are on, which
-    copies each latent block once for score and value.  A prompt chunk
+    ``decode_attention``'s latent kernel where the kernels are on, which
+    leaves the latents in HBM and copies each row's LIVE blocks itself,
+    once for score and value, the next copy in flight while a block is
+    scored (a pad row, sent at position 0, costs one 128-position piece).
+    A prompt chunk
     takes ``prompt_form`` by XLA, a request-homogeneous tile of queries
     against its slot's cache: ``absorbed`` (the default: a tile of 128
     queries x 16 heads fills the matrix unit's rows, and nothing per head is
@@ -1016,8 +1019,7 @@ class LatentAttention(_SlotStateOp):
                     interpret=bool(ctx.extras.get("pallas_interpret")),
                     q_rope=q_r, k_rope=kpe)
                 note_decode_block(ctx.extras, self.path_kind,
-                                  type(bc).__name__, ckv,
-                                  rope_dim=self.rope_dim)
+                                  type(bc).__name__, ckv, latent=True)
                 path = "decode_attention_latent"
             else:
                 out = self._attend_xla(

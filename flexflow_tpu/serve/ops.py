@@ -211,7 +211,7 @@ def note_decode_block(extras, kind, batch, k_cache, **plan):
     class ``batch`` — the ``attention_path.decode_block.*`` counter says
     which layers took a block grown by bytes and which the block by
     positions.  ``plan``: ``kv_quant`` / ``window`` / ``page_size`` as the
-    kernel gets them."""
+    kernel gets them; ``latent`` for a cache with no V plane."""
     paths = extras.get("attention_paths")
     if paths is not None:
         from ..ops.pallas.attention import decode_block_plan

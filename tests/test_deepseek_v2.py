@@ -215,7 +215,7 @@ def test_prompt_feeding_paths_agree_with_the_reference(how):
             "xla_tile_absorbed"
         assert paths[("kv_block_write", "PrefillBatchConfig")] == "dus_chain"
         assert paths[("decode_block", ("latent_attention", "BatchConfig"))] \
-            == "full512"
+            == "live512"
 
 
 # readings here: 0.0003 ulps at most, 0.0000 nats (four decimals)
@@ -349,10 +349,10 @@ def test_absorbed_and_materialised_agree_on_the_same_cache():
 
 @pytest.mark.parametrize("cache_dt", ["float32", "bfloat16"])
 def test_latent_decode_kernel_equals_the_xla_oracle(cache_dt):
-    """``decode_attention``'s latent mode (interpret) against the absorbed
+    """``decode_attention``'s latent kernel (interpret) against the absorbed
     XLA path on the same cache: a row at the cache's end (every block), one
     mid-block, one in the first block; a pad row on the scratch row."""
-    op, (kv_b, ckv, kpe, q_n, q_r, pos) = _op_and_cache(s_len=1024)
+    op, (kv_b, ckv, kpe, q_n, q_r, pos) = _op_and_cache(s_len=2048)
     del q_n
     rng = np.random.default_rng(9)
     q_lat = jnp.asarray(rng.standard_normal((3, 4, RANK)), jnp.float32)
@@ -372,6 +372,70 @@ def test_latent_decode_kernel_equals_the_xla_oracle(cache_dt):
     bare = decode_attention(q_lat, ckv, None, rows, pos,
                             scale=op.scaling_factor, interpret=True)
     assert float(jnp.abs(bare.astype(jnp.float32) - want).max()) > 20 * tol
+
+
+# frontiers the latent kernel's copies turn on (block 1024, pieces of 128,
+# spans of 2048 in a cache of 4096): cache rows and positions of the flat rows
+FRONTIERS = {
+    "position_0": ([0], [0]),
+    "a_blocks_last_position": ([1], [1023]),
+    "a_blocks_first_position": ([2], [1024]),
+    "the_caches_last_position": ([0], [4095]),
+    "a_spans_last_and_first": ([1, 2], [2047, 2048]),
+    "a_single_row_mid_piece": ([2], [1350]),
+    "unlike_lengths_side_by_side": ([0, 1, 2, 1, 0, 2, 1],
+                                    [4095, 3, 1500, 0, 2600, 128, 127]),
+    "pad_rows_between_live_ones": ([0, 3, 3, 1, 3], [900, 0, 0, 2600, 0]),
+}
+
+
+@pytest.mark.parametrize("cache_dt", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(FRONTIERS))
+def test_latent_kernel_copies_each_rows_live_blocks(case, cache_dt):
+    """The kernel's own copies against the absorbed XLA oracle wherever they
+    branch: a frontier at position 0, at a block's last and first position,
+    at the cache's end, across a span of the rotated plane; rows of very
+    unlike lengths side by side (the fetch side runs ahead ACROSS rows); a
+    single row; pad rows on the scratch row."""
+    op, (kv_b, ckv, kpe, _, _, _) = _op_and_cache(s_len=4096)
+    rows, pos = (jnp.asarray(a, jnp.int32) for a in FRONTIERS[case])
+    rng = np.random.default_rng(len(case))
+    dt = jnp.dtype(cache_dt)
+    q_lat, q_r = (jnp.asarray(rng.standard_normal((len(pos), 4, w)), dt)
+                  for w in (RANK, ROPE))
+    ckv, kpe = ckv.astype(dt), kpe.astype(dt)
+    want = op._attend_xla(q_lat[:, None], q_r[:, None], kv_b, ckv, kpe, rows,
+                          pos[:, None], "absorbed")[:, 0]
+    got = decode_attention(q_lat, ckv, None, rows, pos,
+                           scale=op.scaling_factor, interpret=True,
+                           q_rope=q_r, k_rope=kpe)
+    tol = 2e-5 if cache_dt == "float32" else 2e-2
+    np.testing.assert_allclose(got.astype(jnp.float32), want, atol=tol,
+                               rtol=tol)
+
+
+def test_latent_kernel_copies_nothing_past_a_frontiers_piece():
+    """No latent past the 128-position piece that holds a row's frontier
+    reaches the ring (NaNs there would poison the weighted sum: zero weights
+    do not clear them), and the rotated parts past the frontier, which ride
+    a pipeline in whole spans, are masked out of the score."""
+    op, (kv_b, ckv, kpe, _, q_r, _) = _op_and_cache(s_len=2048)
+    rng = np.random.default_rng(11)
+    q_lat = jnp.asarray(rng.standard_normal((3, 4, RANK)), jnp.float32)
+    rows = jnp.arange(3, dtype=jnp.int32)
+    pos = jnp.asarray([700, 1023, 0], jnp.int32)
+    call = lambda c, r: decode_attention(
+        q_lat, c, None, rows, pos, scale=op.scaling_factor, interpret=True,
+        q_rope=q_r, k_rope=r)
+    want = call(ckv, kpe)
+    at = jnp.arange(2048)[None, None, :, None]
+    piece_end = ((pos // 128 + 1) * 128)[:, None, None, None]
+    poisoned = call(
+        ckv.at[:3].set(jnp.where(at >= piece_end, jnp.nan, ckv[:3])),
+        kpe.at[:3].set(jnp.where(at > pos[:, None, None, None], jnp.nan,
+                                 kpe[:3])))
+    assert bool(jnp.isfinite(poisoned).all())
+    np.testing.assert_array_equal(poisoned, want)
 
 
 def test_the_latent_is_passed_to_the_kernel_once():

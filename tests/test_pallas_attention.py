@@ -494,3 +494,92 @@ def test_a_narrow_full_cache_is_read_in_blocks_grown_by_bytes(
         jnp.asarray(pos), 0.09))
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, np.asarray(want), atol=2e-5, rtol=2e-5)
+
+
+# ---- a latent cache: decode_attention(v_cache=None) -------------------------
+def ref_latent_attention(q, ckv, rows, pos, scale, q_rope=None, k_rope=None):
+    """One latent a position, key AND value of every head, by gather."""
+    f32 = jnp.float32
+    c = ckv[rows, 0].astype(f32)                            # [T, S, D]
+    sc = jnp.einsum("thd,tsd->ths", q.astype(f32), c)
+    if k_rope is not None:
+        sc = sc + jnp.einsum("thr,tsr->ths", q_rope.astype(f32),
+                             k_rope[rows, 0].astype(f32))
+    seen = jnp.arange(c.shape[1])[None, :] <= pos[:, None]
+    w = jax.nn.softmax(jnp.where(seen[:, None], sc * scale, -1e30), axis=-1)
+    return jnp.einsum("ths,tsd->thd", w, c)
+
+
+def _latent_case(s_len, t, seed=0, slots=3, h=8, d=128, dr=64,
+                 dt=jnp.float32):
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dt)
+    return (draw(t, h, d), draw(slots + 1, 1, s_len, d), draw(t, h, dr),
+            draw(slots + 1, 1, s_len, dr))
+
+
+@pytest.mark.parametrize("s_len,rows,pos", [
+    (2048, [0, 1, 2], [2047, 1030, 5]),      # one span, two blocks
+    (1536, [2, 3, 0, 3], [1535, 0, 511, 0]),  # pads; three blocks of 512
+    (4096, [1], [2048]),                     # one row, its span's first block
+    (4096, [0, 1, 2, 0], [4095, 0, 2047, 1024]),  # two spans, unlike lengths
+    (384, [0, 1], [383, 128]),               # a cache of three pieces
+], ids=["two_blocks", "pads", "one_row", "two_spans", "three_pieces"])
+@pytest.mark.parametrize("rope", [True, False], ids=["rope", "bare"])
+def test_latent_kernel_matches_reference(s_len, rows, pos, rope):
+    q, ckv, q_r, kpe = _latent_case(s_len, len(rows), seed=s_len)
+    rows, pos = jnp.asarray(rows, jnp.int32), jnp.asarray(pos, jnp.int32)
+    second = dict(q_rope=q_r, k_rope=kpe) if rope else {}
+    got = decode_attention(q, ckv, None, rows, pos, 0.09, interpret=True,
+                           **second)
+    want = ref_latent_attention(q, ckv, rows, pos, 0.09, **second)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("s_len,want", [
+    (15360, (1024, 3072)),   # deepseek-v2-lite-d5.doc-decode
+    (4096, (1024, 2048)),    # the largest multiple of the block that divides
+    (1024, (1024, 1024)),
+    (1536, (512, 1536)),     # the block divides the cache
+    (384, (128, 384)),       # a short cache: a block of whole pieces
+    (200, None),             # no whole piece: refused
+])
+def test_latent_plan_divides_the_cache(s_len, want):
+    from flexflow_tpu.ops.pallas.attention import (_latent_plan,
+                                                   decode_block_plan)
+    cache = jax.ShapeDtypeStruct((3, 1, s_len, 512), jnp.bfloat16)
+    if want is None:
+        with pytest.raises(ValueError, match="whole pieces"):
+            _latent_plan(s_len)
+        return
+    block, span = _latent_plan(s_len)
+    assert (block, span) == want
+    assert s_len % span == 0 and span % block == 0 and block % 128 == 0
+    # the counter names the kernel and its block; a K/V cache keeps its plan
+    assert decode_block_plan(cache, latent=True) == f"live{block}"
+    assert decode_block_plan(cache).startswith("full")
+
+
+@pytest.mark.parametrize("kw", [
+    dict(window=64), dict(use_alibi=True), dict(page_size=128, paged=True),
+    dict(quant=True), dict(two_heads=True), dict(rope_on_kv=True),
+], ids=lambda kw: next(iter(kw)))
+def test_a_latent_cache_refuses_what_it_lacks(kw):
+    """A latent cache is slot-contiguous, fp, full-length, one cached head,
+    no positional bias; and the second key plane is a latent cache's alone."""
+    q, ckv, q_r, kpe = _latent_case(256, 2)
+    rows, pos = jnp.arange(2, dtype=jnp.int32), jnp.asarray([9, 200], jnp.int32)
+    value, more = None, dict(q_rope=q_r, k_rope=kpe)
+    if kw.pop("paged", False):
+        more["page_table"] = jnp.zeros((4, 2), jnp.int32)
+    if kw.pop("quant", False):
+        more["k_scale"] = more["v_scale"] = jnp.ones((4, 1, 256))
+    if kw.pop("two_heads", False):
+        ckv = jnp.concatenate([ckv, ckv], axis=1)
+    if kw.pop("rope_on_kv", False):
+        value = ckv
+    with pytest.raises(ValueError, match="latent cache"):
+        decode_attention(q, ckv, value, rows, pos, 0.1, interpret=True,
+                         **kw, **more)

@@ -72,10 +72,10 @@ def _no_compile_cache():
     cc.reset_cache()
 
 
-def _lower(kernel, sh, kv, gq, d, s, variant):
-    """Lowered (not yet compiled) ``kernel`` at the serve path's shapes,
-    for one cache variant: bf16, int8 (+ scale planes), paged-512, or a
-    ring read through a window."""
+def _call(kernel, sh, kv, gq, d, s, variant):
+    """``kernel`` at the serve path's shapes, for one cache variant: bf16,
+    int8 (+ scale planes), paged-512, or a ring read through a window — the
+    function, its operands' shapes, its traced keyword operands."""
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=sh)
     k = sds((R + 1, kv, s, d),
             jnp.int8 if variant == "int8" else jnp.bfloat16)
@@ -113,6 +113,12 @@ def _lower(kernel, sh, kv, gq, d, s, variant):
             args = (sds((R, P_SPEC, qh, d), jnp.bfloat16), k, k, spec, spec,
                     sds((R,), jnp.int32), sds((R,), jnp.int32),
                     sds((R, P_SPEC, P_SPEC), jnp.bool_))
+    return f, args, kw
+
+
+def _lower(*case):
+    """Lowered (not yet compiled) :func:`_call`."""
+    f, args, kw = _call(*case)
     return jax.jit(f).lower(*args, **kw)
 
 
@@ -491,30 +497,97 @@ def test_softmax_routed_layer_compiles_for_v5e(one_chip, rows):
     assert compiled.as_text().count("tpu_custom_call") >= 3
 
 
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of a jaxpr, nested ones too."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(eqn)
+            continue
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                found += _pallas_calls(inner)
+    return found
+
+
 @pytest.mark.parametrize("rows", [64, 512], ids=["scan64", "flat512"])
 def test_latent_decode_kernel_compiles_for_v5e(one_chip, rows):
-    """``decode_attention``'s LATENT mode at DeepSeek-V2-Lite's widths: 16
-    query heads on one latent of 512 (key AND value: no V operand) beside a
-    rotated key plane of 64 — not a lane multiple —, 65 cache rows of 15 360
-    positions, bf16; the decode scan's 64 rows and a flat step's 512.  One
-    Mosaic kernel with ONE cache-sized operand per plane."""
+    """``decode_attention`` over a LATENT cache at DeepSeek-V2-Lite's widths:
+    16 query heads on one latent of 512 (key AND value: no V operand) beside
+    a rotated key plane of 64 — not a lane multiple —, 65 cache rows of
+    15 360 positions, bf16; the decode scan's 64 rows and a flat step's 512.
+    One Mosaic kernel with ONE cache-sized operand per plane; the latents
+    enter un-blocked (left in HBM: the kernel copies a row's live blocks
+    itself), the rotated plane — which Mosaic lets no kernel slice by itself
+    — in spans of 3072 positions; the ring of three blocks and the two spans
+    in flight fit the default scoped VMEM with room."""
+    import re
+
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     slots, s, h, r, dr = 64, 15360, 16, 512, 64
     f = functools.partial(decode_attention, scale=0.1147)
-    lowered = jax.jit(
-        lambda q, ckv, rw, ps, qr, kpe: f(q, ckv, None, rw, ps, q_rope=qr,
-                                          k_rope=kpe)).lower(
+    call = lambda q, ckv, rw, ps, qr, kpe: f(q, ckv, None, rw, ps, q_rope=qr,
+                                             k_rope=kpe)
+    shapes = (
         sds((rows, h, r), jnp.bfloat16),
         sds((slots + 1, 1, s, r), jnp.bfloat16),
         sds((rows,), jnp.int32), sds((rows,), jnp.int32),
         sds((rows, h, dr), jnp.bfloat16),
         sds((slots + 1, 1, s, dr), jnp.bfloat16))
-    text = lowered.compile().as_text()
+    kernel, = _pallas_calls(jax.make_jaxpr(call)(*shapes).jaxpr)
+    assert kernel.params["jaxpr"].debug_info.func_name == \
+        "_latent_decode_kernel"
+    space = {tuple(bm.array_aval.shape):
+             (str(bm.transformed_block_aval.memory_space),
+              tuple(bm.transformed_block_aval.shape))
+             for bm in kernel.params["grid_mapping"].block_mappings}
+    assert space[(slots + 1, 1, s, r)] == ("any", (slots + 1, 1, s, r))
+    assert space[(slots + 1, 1, s, dr)] == ("vmem", (1, 1, 3072, dr))
+    text = jax.jit(call).lower(*shapes).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     call = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
             and "custom-call(" in ln][0]
     # the latent plane goes in ONCE: no second operand of its shape
     assert call.count(f"bf16[{slots + 1},1,{s},{r}]") == 1, call[:400]
+    # no ``vmem_limit_bytes`` asked for; the ring (3 x 1 MB) and the
+    # rotated plane's two spans (rows padded to 128 lanes: 2 x 768 KB)
+    assert re.search(r'[^_]scoped_memory_configs":\[\]', call)
+    used = [int(n) for n in re.findall(
+        r'used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', call)]
+    assert used and 5 * 2**20 < max(used) < 6 * 2**20
+
+
+@pytest.mark.parametrize("variant", ["bf16", "ring512", "paged", "int8",
+                                     "latent"])
+def test_a_latent_cache_alone_leaves_the_shared_decode_kernel(variant):
+    """K and V planes — plain, a ring, paged, int8 — trace ``_decode_kernel``
+    and nothing else; a latent cache traces ``_latent_decode_kernel`` and
+    nothing else; and the shared kernel holds no latent or rope branch."""
+    import inspect
+
+    from flexflow_tpu.ops.pallas import attention
+
+    if variant == "latent":
+        f = lambda q, c, rw, ps, qr, kr: decode_attention(
+            q, c, None, rw, ps, 0.1, q_rope=qr, k_rope=kr)
+        sds, s = jax.ShapeDtypeStruct, 1024
+        args, kw = (sds((R, 16, 512), jnp.bfloat16),
+                    sds((R + 1, 1, s, 512), jnp.bfloat16),
+                    sds((R,), jnp.int32), sds((R,), jnp.int32),
+                    sds((R, 16, 64), jnp.bfloat16),
+                    sds((R + 1, 1, s, 64), jnp.bfloat16)), {}
+    else:
+        f, args, kw = _call("decode", None, 4, 4, 128, 1024, variant)
+    kernels = [c.params["jaxpr"].debug_info.func_name for c in
+               _pallas_calls(jax.make_jaxpr(f)(*args, **kw).jaxpr)]
+    assert kernels == ["_latent_decode_kernel" if variant == "latent"
+                       else "_decode_kernel"]
+    shared = inspect.signature(attention._decode_kernel).parameters
+    assert not {"latent", "rope"} & set(shared)
+    assert "rope" in inspect.signature(
+        attention._latent_decode_kernel).parameters
 
 
 @pytest.mark.parametrize("form", ["slot_order", "chunked"])
