@@ -1447,6 +1447,137 @@ def kv_block_write(
       jnp.swapaxes(k, 0, 1), jnp.swapaxes(v, 0, 1), k_cache, v_cache)
 
 
+# One grid step of :func:`kv_row_write` copies this many bytes of a cache
+# in and out at most (a group of positions, all heads), and the fresh rows
+# wait in VMEM in blocks of at most as many.
+_ROW_WRITE_BLOCK_BYTES = 2**20
+
+
+def row_write_group(cache) -> int:
+    """Positions of ``cache`` ``[R+1, KV, S, D]`` that :func:`kv_row_write`
+    reads, merges and writes back around the one it sets: those that share a
+    native tile along the seq axis (8 sublanes of 32 bits: 8 positions of
+    float32, 16 of bfloat16, 32 of int8).  0 where the kernel cannot take
+    the cache: not 4-D, not whole groups, or a group past a grid step's
+    bytes."""
+    itemsize = jnp.dtype(cache.dtype).itemsize
+    group = 8 * 4 // itemsize
+    if cache.ndim != 4 or cache.shape[2] % group or (
+            cache.shape[1] * group * cache.shape[3] * itemsize
+            > _ROW_WRITE_BLOCK_BYTES):
+        return 0
+    return group
+
+
+def _kv_row_write_kernel(
+    rows_ref,       # scalar prefetch: i32[T] cache row per fresh row
+    pos_ref,        # scalar prefetch: i32[T] its seq index in that row
+    *refs,          # a plane: [Tb, KV, D] a block of the step's fresh rows;
+                    # then a plane: [1, KV, G, D] the group of positions
+                    # around pos[t] as the cache holds it (aliased to the
+                    # outputs); then a plane: the same block, going back
+    group: int,
+):
+    n = len(refs) // 3
+    t = pl.program_id(0)
+    tb, heads = refs[0].shape[:2]
+    before = jnp.maximum(t - 1, 0)
+    # the step before wrote this very block (pads, all on the scratch row):
+    # Pallas then copies it neither in again nor out yet, so what that step
+    # wrote is in the OUTPUT block and the input block is stale
+    again = (t > 0) & (rows_ref[t] == rows_ref[before]) & (
+        pos_ref[t] // group == pos_ref[before] // group)
+    hit = jax.lax.broadcasted_iota(
+        jnp.int32, refs[-1].shape[2:], 0) == pos_ref[t] % group     # [G, D]
+
+    def merge(held):
+        for src, base, dst in zip(refs[:n], held, refs[2 * n:]):
+            for h in range(heads):
+                x = jnp.broadcast_to(src[t % tb, pl.ds(h, 1), :], hit.shape)
+                dst[0, h] = jnp.where(hit, x, base[0, h])
+
+    pl.when(again)(lambda: merge(refs[2 * n:]))
+    pl.when(jnp.logical_not(again))(lambda: merge(refs[n:2 * n]))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def kv_row_write(
+    k_cache: jax.Array,            # [R+1, KV, S, D]
+    v_cache: Optional[jax.Array],  # the same shape and type (None: one plane)
+    k: jax.Array,                  # [T, KV, D] the step's fresh keys, a row each
+    v: Optional[jax.Array],        # [T, KV, D]
+    rows: jax.Array,     # i32[T] cache row per fresh row (PHYSICAL if paged)
+    pos: jax.Array,      # i32[T] seq index per fresh row
+    interpret: bool = False,
+):
+    """A decode step's K and V rows into their caches, IN PLACE, in one
+    call: ``cache[rows[t], :, pos[t]] = x[t]``, cast to the cache's type,
+    ``rows`` clamped to ``[0, R]`` and ``pos`` to ``[0, S - 1]`` — the
+    values, positions and untouched contents of the chain of one-row
+    ``dynamic_update_slice`` operations it replaces (``serve/ops.py``
+    ``_update_rows``, which ``put_rows`` keeps as its fallback and the tests'
+    reference: a fixed 0.65-1.4 us an operation on the v5e, 2 a row).
+    Returns the two caches (one where ``v_cache`` is None).
+
+    :func:`kv_block_write`'s shape, one position high.  A position is
+    narrower than the cache's native tile (:func:`row_write_group` positions
+    share one), so grid step ``t`` copies the aligned group of positions
+    around ``pos[t]`` in — block ``(1, KV, G, D)`` at ``(rows[t], 0, pos[t]
+    // G, 0)`` of the cache itself, aliased in and out
+    (``input_output_aliases``) — sets the one position by a sublane compare
+    and copies the group back; the pipeline has the next row's group in
+    flight meanwhile.  On the v5e a row of both caches takes 0.31 us on one
+    K/V head, 0.63 on ten, 1.2 on 32 (512 KB in and out; PERF.md section 6,
+    PR 56).
+
+    The caller's contract (the decode scan: ``one_row_per_request``): no two
+    of a call's ``(rows[t], pos[t] // G)`` are the same block, EXCEPT on the
+    scratch row (the last), where pads land any number of times in any
+    order: a step reads its block before an earlier step's write of it has
+    landed, unless that step is the one just before it.  Pads next to each
+    other therefore leave the scratch row as the chain does; pads apart may
+    leave one of their positions as it was.
+    """
+    caches = (k_cache,) if v_cache is None else (k_cache, v_cache)
+    fresh = tuple(x.astype(k_cache.dtype)
+                  for x in ((k,) if v_cache is None else (k, v)))
+    group = row_write_group(k_cache)
+    if not group or any(c.shape != k_cache.shape or c.dtype != k_cache.dtype
+                        for c in caches):
+        raise ValueError(
+            f"caches {[(c.shape, str(c.dtype)) for c in caches]}: one shape "
+            "and type, [R+1, KV, S, D] with S whole groups of positions of at "
+            f"most {_ROW_WRITE_BLOCK_BYTES} bytes")
+    r1, num_kv, s_len, d = k_cache.shape
+    t = k.shape[0]
+    n = len(caches)
+    tb = max(c for c in range(1, t + 1) if t % c == 0 and (
+        c == 1 or c * max(num_kv, group) * d * k_cache.dtype.itemsize
+        <= _ROW_WRITE_BLOCK_BYTES))
+    rows_in = pl.BlockSpec((tb, num_kv, d), lambda i, *_: (i // tb, 0, 0),
+                           memory_space=pltpu.VMEM)
+    block = pl.BlockSpec(
+        (1, num_kv, group, d),
+        lambda i, rows, pos: (rows[i], 0, pos[i] // group, 0),
+        memory_space=pltpu.VMEM)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(t,),
+        in_specs=[rows_in] * n + [block] * n,
+        out_specs=[block] * n,
+    )
+    out = pl.pallas_call(
+        functools.partial(_kv_row_write_kernel, group=group),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(c.shape, c.dtype) for c in caches],
+        # operands count from the first scalar-prefetch argument
+        input_output_aliases={2 + n + i: i for i in range(n)},
+        interpret=interpret,
+    )(jnp.clip(rows.astype(jnp.int32), 0, r1 - 1),
+      jnp.clip(pos.astype(jnp.int32), 0, s_len - 1), *fresh, *caches)
+    return out[0] if v_cache is None else tuple(out)
+
+
 def _tree_kernel(
     rows_ref,       # scalar prefetch: i32[T] cache row per token
     clens_ref,      # scalar prefetch: i32[T] committed cache depth per token

@@ -62,10 +62,9 @@ from ..core.op import Op, OpContext, register_op
 from ..core.sharding import TensorSharding
 from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .ops import (DUS_MAX_TOKENS, NEG_INF, SCAN_DUS_MAX_ROWS,
-                  IncMultiHeadSelfAttention, _block_chain, _tile_blocks,
-                  apply_rope, note_decode_block, put_blocks, tile_coords,
-                  yarn_mscale)
+from .ops import (DUS_MAX_TOKENS, NEG_INF, _block_chain, _tile_blocks,
+                  apply_rope, note_decode_block, put_blocks, put_rows,
+                  tile_coords, yarn_mscale)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
@@ -396,13 +395,7 @@ class SlotCacheAttention(_SlotStateOp):
         ring = kc.shape[2] if self.mode == "window" else 0
         pos = base.token_position % ring if ring else base.token_position
         if not tiled:
-            put = IncMultiHeadSelfAttention._scatter_rows_pos
-            # the decode scan keeps the chain of in-place writes past
-            # DUS_MAX_TOKENS rows, as IncMultiHeadSelfAttention does
-            chain = (SCAN_DUS_MAX_ROWS
-                     if extras.get("one_row_per_request") else None)
-            return (put(kc, seg.rows, pos, k, chain),
-                    put(vc, seg.rows, pos, v, chain))
+            return put_rows(kc, vc, k, v, seg.rows, pos, extras)
         # a tiled prefill chunk: one block per request-homogeneous tile
         # (ops.put_blocks says why not a scatter).  A tile starts
         # tile-aligned and the ring is whole tiles, so a BLOCK never wraps
@@ -905,11 +898,7 @@ class LatentAttention(_SlotStateOp):
         pos = _flat(bc).token_position
         c, k_r = c[:, None], k_r[:, None]           # one cached "head"
         if not tiled:
-            put = IncMultiHeadSelfAttention._scatter_rows_pos
-            chain = (SCAN_DUS_MAX_ROWS
-                     if extras.get("one_row_per_request") else None)
-            return (put(ckv, seg.rows, pos, c, chain),
-                    put(kpe, seg.rows, pos, k_r, chain))
+            return put_rows(ckv, kpe, c, k_r, seg.rows, pos, extras)
         # a tiled prompt chunk: one block per request-homogeneous tile and
         # plane, tail pads as zeros (ops.put_blocks says why not a scatter)
         bq = bc.tile_size
@@ -1182,8 +1171,7 @@ class EvaAttention(_SlotStateOp):
         """This pass's keys and values to ``(rows, at)``: ``at`` the compact
         index, ``rows`` the scratch row for what the pass leaves out."""
         if not tiled:
-            put = IncMultiHeadSelfAttention._scatter_rows_pos
-            return put(kc, rows, at, k), put(vc, rows, at, v)
+            return put_rows(kc, vc, k, v, rows, at, extras)
         # one block per tile; a tile lies inside one window, so its entries
         # are contiguous, and its tail pads land beyond the open window's
         # newest entry (which a later chunk overwrites before any query
@@ -1702,9 +1690,8 @@ class SparseBlockAttention(_SlotStateOp):
                         kc, vc, k, v, *tile_coords(seg.rows, pos, bq, nreq),
                         bq, ctx.extras)
                 else:
-                    put = IncMultiHeadSelfAttention._scatter_rows_pos
-                    kc = put(kc, seg.rows, pos, k)
-                    vc = put(vc, seg.rows, pos, v)
+                    kc, vc = put_rows(kc, vc, k, v, seg.rows, pos,
+                                      ctx.extras)
                 kidx = self._append_index(kidx, kc, seg.rows, pos)
             ctx.extras["state_out"] = {"k": kc, "v": vc, "kidx": kidx}
             # its own operator class in a device trace, apart from the node's

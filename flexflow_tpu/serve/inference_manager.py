@@ -984,22 +984,11 @@ class InferenceManager:
         dispatch span's ``rows``), where it has one.  More than the scan's
         width would be cut by the compaction and come back
         ``EXIT_NOT_IN_BATCH`` without a word, so they are refused here."""
-        from . import ops
-
+        # no bound on the width here: the scan's K/V rows go in by ONE
+        # call a layer however many they are (ops.put_rows); where a plane
+        # stays on the update-slice chain, put_rows warns past the chain's
+        # widest (ops.SCAN_DUS_MAX_ROWS) as the program is traced
         width = decode_scan_width(bc)
-        if width > ops.SCAN_DUS_MAX_ROWS:
-            # the scan's KV writes are as wide as the scan (one row per
-            # slot, not max_tokens); past the scan's DUS threshold they
-            # become an XLA scatter whose layout choice forces a per-step
-            # full-cache relayout (see ops.DUS_MAX_TOKENS)
-            import warnings
-
-            warnings.warn(
-                f"decode_scan runs {width} rows (one per request slot) > "
-                f"{ops.SCAN_DUS_MAX_ROWS}: KV writes take the scatter path and "
-                "re-lay out the full cache every step",
-                stacklevel=2,
-            )
         if rows is not None and rows > width:
             raise ValueError(
                 f"decode_scan got {rows} rows with a request; a "

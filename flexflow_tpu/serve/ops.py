@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import functools
 import math
+import warnings
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -97,12 +98,12 @@ def _gather_logical_rows(cache, pages, rows):
 # scatter), one row per request slot in the decode scan (which compacts its
 # batch: InferenceManager._decode_scan_impl), max_requests*(depth+1) in the
 # spec scan.  Inside a scan the scatter's layout choice forces a per-step
-# full-cache relayout, so SpecDecodeScan and InferenceManager.decode_scan
-# check those widths against it.
+# full-cache relayout, so SpecDecodeScan checks its width against it.
 DUS_MAX_TOKENS = 128
-# the decode scan keeps the chain up to this many rows (one per slot): there
-# the scatter's relayout copies the whole cache every step, which costs more
-# than the longest chain; the scan's guard warns past it
+# where the decode scan's rows stay on the chain (put_rows: the kernels off,
+# a plane kv_row_write cannot take) it keeps the chain up to this many rows
+# (one per slot): there the scatter's relayout copies the whole cache every
+# step, which costs more than the longest chain; _chain_rows warns past it
 SCAN_DUS_MAX_ROWS = 256
 
 
@@ -203,6 +204,74 @@ def put_blocks(kc, vc, k, v, rows, start, count, tile, extras, wrap=None,
                              rows, start))
     write = functools.partial(kv_block_write, tile=tile, interpret=interp)
     return (wrap or (lambda f: f))(write)(kc, vc, k, v, rows, start, count)
+
+
+def _chain_rows(extras, t):
+    """The widest chain of update-slices a write of ``t`` rows may be
+    (``_scatter_rows_pos``'s ``chain_rows``): as wide as the decode scan up
+    to ``SCAN_DUS_MAX_ROWS``, past which the scan is warned of the scatter."""
+    if not extras.get("one_row_per_request"):
+        return None
+    if t > SCAN_DUS_MAX_ROWS:
+        warnings.warn(
+            f"decode_scan writes {t} rows (one per request slot) > "
+            f"{SCAN_DUS_MAX_ROWS} off the kv_row_write kernel: they take "
+            "the scatter path and re-lay out the full cache every step",
+            stacklevel=3)
+    return SCAN_DUS_MAX_ROWS
+
+
+def put_rows(kc, vc, k, v, rows, pos, extras, wrap=None):
+    """A flat batch's fresh ``k`` / ``v`` ``[T, H, D]`` into their planes
+    ``[R + 1, H, S, D]``: ``plane[rows[t], :, pos[t]] = x[t]``, cast to the
+    plane's type, out-of-range coordinates clamped
+    (:meth:`IncMultiHeadSelfAttention._scatter_rows_pos`).  Returns the two
+    planes.  ``rows`` / ``pos`` are PHYSICAL under paging.
+
+    Inside the decode scan (``extras["one_row_per_request"]``: a live slot's
+    row is written at most once a step, pads land on the scratch row) ONE
+    aliased Pallas call writes the rows of both planes
+    (``ops/pallas/attention.py`` ``kv_row_write``) where the kernels are on
+    (``extras["pallas_decode"]``) and a plane is 4-D, whole position groups
+    (``row_write_group``) and whole lanes wide (narrower, the TPU compiler
+    re-lays the caches out around the call; the interpreter has no lanes);
+    two planes of one shape and type share the call, a plane the kernel
+    cannot take (``deepseek_v2``'s 64-wide rotated part) stays on the chain
+    beside it.  Else the chain of one ``dynamic_update_slice`` per row and
+    plane (0.65-1.4 us each on the v5e whatever it moves), as wide as the
+    scan up to ``SCAN_DUS_MAX_ROWS`` and ``DUS_MAX_TOKENS`` outside it, then
+    a scatter: a flat step or the spec scan may write several positions of
+    ONE row in a call, which the kernel's pipelined read-merge-write would
+    lose.  The scan's path is recorded in ``extras["attention_paths"]``,
+    a key per plane where the two differ.  ``wrap`` places the kernel under
+    a caller's ``shard_map`` over the head axis.
+    """
+    from ..ops.pallas.attention import kv_row_write, row_write_group
+
+    put = IncMultiHeadSelfAttention._scatter_rows_pos
+    in_scan = bool(extras.get("one_row_per_request"))
+    interp = bool(extras.get("pallas_interpret"))
+    alike = (kc.shape, kc.dtype) == (vc.shape, vc.dtype)
+    takes = [in_scan and bool(extras.get("pallas_decode"))
+             and bool(row_write_group(c))
+             and (interp or c.shape[-1] % 128 == 0) for c in (kc, vc)]
+    if wrap is not None and not (alike and all(takes)):
+        takes = [False, False]  # the caller's shard_map is of the one call
+    paths = extras.get("attention_paths")
+    if paths is not None and in_scan:
+        for c, kernel in zip((kc, vc), takes):
+            batch = "one_row_per_request" if takes[0] == takes[1] \
+                else ("one_row_per_request", c.shape[-1])
+            paths[("kv_row_write", batch)] = \
+                "pallas" if kernel else "dus_chain"
+    write = functools.partial(kv_row_write, interpret=interp)
+    if alike and all(takes):
+        return (wrap or (lambda f: f))(write)(kc, vc, k, v, rows, pos)
+    chain_rows = _chain_rows(extras, k.shape[0])
+    return tuple(
+        write(c, None, x, None, rows, pos) if kernel
+        else put(c, rows, pos, x, chain_rows)
+        for c, x, kernel in zip((kc, vc), (k, v), takes))
 
 
 def note_decode_block(extras, kind, batch, k_cache, **plan):
@@ -639,32 +708,31 @@ class IncMultiHeadSelfAttention(Op):
         return _update_rows(cache, rows, pos, upd)
 
     @jax.named_scope("kv_write")
-    def _write_kv(self, state, rows, pos, k, v, pages=None, chain_rows=None):
+    def _write_kv(self, state, rows, pos, k, v, pages=None, extras=None,
+                  wrap=None):
         """Write this step's K/V vectors into the committed caches,
         quantizing on write when the caches are int8.  Returns the updated
-        buffers as a dict of the state keys that changed.  ``chain_rows``:
-        see ``_scatter_rows_pos`` (the decode scan's wider chain).  ``pages``
+        buffers as a dict of the state keys that changed.  ``extras`` /
+        ``wrap``: see :func:`put_rows` (the decode scan's one aliased call,
+        or its wider chain; None: a plain chain).  ``pages``
         (paged KV) translates the logical (row, position) coordinates to
         physical ones first — the scale planes ride the SAME translation,
         so int8 scales page alongside their K/V values."""
+        extras = extras or {}
         if pages is not None:
             rows, pos = _page_rows_pos(pages, rows, pos)
-        kc, vc = state["k"], state["v"]
-        if kc.dtype == jnp.int8:
-            kq, ks = self._kv_quant(k)
-            vq, vs = self._kv_quant(v)
-            return {
-                "k": self._scatter_rows_pos(kc, rows, pos, kq, chain_rows),
-                "v": self._scatter_rows_pos(vc, rows, pos, vq, chain_rows),
-                "k_scale": self._scatter_scale(state["k_scale"], rows, pos,
-                                               ks, chain_rows),
-                "v_scale": self._scatter_scale(state["v_scale"], rows, pos,
-                                               vs, chain_rows),
-            }
-        return {
-            "k": self._scatter_rows_pos(kc, rows, pos, k, chain_rows),
-            "v": self._scatter_rows_pos(vc, rows, pos, v, chain_rows),
-        }
+        scales = {}
+        if state["k"].dtype == jnp.int8:
+            # the scale planes [R, KV, S] stay on the chain
+            chain_rows = _chain_rows(extras, k.shape[0])
+            k, ks = self._kv_quant(k)
+            v, vs = self._kv_quant(v)
+            scales = {n: self._scatter_scale(state[n], rows, pos, x,
+                                             chain_rows)
+                      for n, x in (("k_scale", ks), ("v_scale", vs))}
+        kc, vc = put_rows(state["k"], state["v"], k, v, rows, pos, extras,
+                          wrap)
+        return {"k": kc, "v": vc, **scales}
 
     @staticmethod
     def _dequant_rows(cache_tok, sc_tok, dtype):
@@ -740,16 +808,25 @@ class IncMultiHeadSelfAttention(Op):
         pos = bc.token_position
         pages = ctx.extras.get("pages") if ctx is not None else None
         in_scan = ctx is not None and ctx.extras.get("one_row_per_request")
-        writes = self._write_kv(
-            state, rows, pos, k, v, pages,
-            chain_rows=SCAN_DUS_MAX_ROWS if in_scan else None)
-        kc, vc = writes["k"], writes["v"]
-        kv_q = kc.dtype == jnp.int8
-        if ctx is not None and ctx.extras.get("pallas_decode"):
+        pallas = ctx is not None and ctx.extras.get("pallas_decode")
+        h = self._config_head_axes(ctx)
+        kv_sm = None
+        if pallas:
             from jax.sharding import PartitionSpec as P
 
             from ..ops.pallas.attention import decode_attention
 
+            kv_sm = self._head_shard_map(
+                ctx, h, [P(None, h)] * 4 + [P()] * 2,
+                (P(None, h), P(None, h)), "decode K/V row write")
+        extras = ctx.extras if ctx is not None else None
+        if pallas and kv_sm is None:
+            # a sharding the kernel cannot express, off the chip: the chain
+            extras = dict(extras, pallas_decode=False)
+        writes = self._write_kv(state, rows, pos, k, v, pages, extras, kv_sm)
+        kc, vc = writes["k"], writes["v"]
+        kv_q = kc.dtype == jnp.int8
+        if pallas:
             t = q.shape[0]
             interp = bool(ctx.extras.get("pallas_interpret"))
             # pad tokens (scratch row) otherwise stream a full cache row
@@ -784,7 +861,6 @@ class IncMultiHeadSelfAttention(Op):
                     page_table=pt_, page_size=pg_size,
                 ).reshape(t, kv_l, gq, self.head_dim)
 
-            h = self._config_head_axes(ctx)
             sm = self._head_shard_map(
                 ctx, h,
                 [P(None, h), P(None, h), P(None, h), P(), P(), P(h)]

@@ -116,3 +116,33 @@ def _resource_log(request):
             )
     except OSError:
         pass
+
+
+@pytest.fixture
+def row_write_on_and_off(monkeypatch):
+    """``check(make, prompts, new_tokens)``: serve ``prompts`` through the
+    decode scan of two fresh deployments ``make()`` with the kernels on —
+    one as it is (its K/V rows go in by ``kv_row_write``), one with every
+    plane refused to that kernel (``row_write_group`` -> 0: the chain of
+    update-slices) — and hold them to the same tokens, the same caches bit
+    for bit off the scratch row, and the path each says it took."""
+    from flexflow_tpu.ops.pallas import attention
+    from flexflow_tpu.serve import GenerationConfig, RequestManager
+
+    def serve(im, prompts, new_tokens):
+        rm = RequestManager(im, GenerationConfig(stop_on_eos=False))
+        outs = rm.generate(prompts, new_tokens)
+        took = {path for (kind, _), path in im.attention_paths.items()
+                if kind == "kv_row_write"}
+        return outs, took, jax.tree.map(np.asarray, im.state)
+
+    def check(make, prompts, new_tokens=6):
+        on, took_on, state_on = serve(make(), prompts, new_tokens)
+        monkeypatch.setattr(attention, "row_write_group", lambda cache: 0)
+        off, took_off, state_off = serve(make(), prompts, new_tokens)
+        assert took_on == {"pallas"} and took_off == {"dus_chain"}
+        assert on == off and all(len(o) == new_tokens for o in on)
+        for a, b in zip(jax.tree.leaves(state_on), jax.tree.leaves(state_off)):
+            np.testing.assert_array_equal(a[:-1], b[:-1])
+
+    return check

@@ -320,9 +320,10 @@ def test_scan_body_is_slot_wide_and_scatters_into_no_cache(kv_dtype):
         if e.primitive.name.startswith("scatter"):
             assert e.invars[0].aval.shape not in caches, e
     names = [e.primitive.name for e in body]
-    # the KV write is the in-place chain: K and V (and their scales) of
-    # every layer, one update-slice per row
-    planes = 4 if kv_dtype else 2
+    # the KV write is ONE aliased call a layer (kv_row_write); an int8
+    # cache's scale planes stay on the in-place chain, one update-slice
+    # per row and plane ...
+    planes = 2 if kv_dtype else 0
     written = [e for e in body if e.primitive.name == "dynamic_update_slice"
                and e.invars[0].aval.shape in caches]
     assert len(written) == TINY.num_hidden_layers * planes * SLOTS
@@ -332,9 +333,14 @@ def test_scan_body_is_slot_wide_and_scatters_into_no_cache(kv_dtype):
     assert len(calls) == TINY.num_hidden_layers * planes
     dots = [e for e in body if e.primitive.name == "dot_general"]
     assert dots and all(e.outvars[0].aval.shape[0] == SLOTS for e in dots)
+    # the row write, then the decode kernel: both a grid step a slot
     kernels = [e for e in body if e.primitive.name == "pallas_call"]
-    assert len(kernels) == TINY.num_hidden_layers, names
+    assert len(kernels) == 2 * TINY.num_hidden_layers, names
     assert all(k.params["grid_mapping"].grid[0] == SLOTS for k in kernels)
+    assert [len(k.params["input_output_aliases"]) for k in kernels] == \
+        [2, 0] * TINY.num_hidden_layers
+    assert im.attention_paths[
+        ("kv_row_write", "one_row_per_request")] == "pallas"
     # nothing in the body is as wide as the flat batch
     assert not any(WIDE in v.aval.shape for e in body for v in e.outvars)
 
@@ -411,16 +417,28 @@ def test_joins_between_narrowed_segments_equal_the_per_tick_loop(gen):
     assert joins and max(joins) >= 1
 
 
-def test_guard_warns_on_the_scans_width_not_on_max_tokens(monkeypatch):
+def test_chain_warns_on_the_scans_width_and_the_kernel_has_no_bound(
+        monkeypatch):
+    """The width that costs is the chain's: a scan whose rows stay on the
+    update-slice chain is warned past ``SCAN_DUS_MAX_ROWS`` as its program
+    is traced; one whose rows go in by ``kv_row_write`` is not held by it,
+    and the guard itself has no bound on the width."""
+    def trace(im):
+        return jax.make_jaxpr(functools.partial(
+            im._decode_scan_impl, n_steps=2, eos=None))(
+                im.params, im.state, feed(im, PROMPTS), None, None,
+                np.full(WIDE, 9, np.int32))
+
     im = wide_im()
     bc = BatchConfig.build([1], [0], [3], [4] * SLOTS,
                            max_tokens=WIDE, max_requests=SLOTS)
+    monkeypatch.setattr(serve_ops, "SCAN_DUS_MAX_ROWS", SLOTS - 1)
     with warnings.catch_warnings():
         warnings.simplefilter("error")
         assert im._decode_scan_guards(bc, 2, max_position=3) == SLOTS
-    monkeypatch.setattr(serve_ops, "SCAN_DUS_MAX_ROWS", SLOTS - 1)
-    with pytest.warns(UserWarning, match=f"runs {SLOTS} rows"):
-        im._decode_scan_guards(bc, 2, max_position=3)
+        trace(wide_im(use_pallas=True))
+    with pytest.warns(UserWarning, match=f"writes {SLOTS} rows"):
+        trace(im)
 
 
 def test_dispatch_span_carries_the_scans_width():

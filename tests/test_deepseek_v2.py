@@ -235,6 +235,11 @@ def test_the_harness_drive_is_correct(use_pallas):
                             HF["vocab_size"], LIMITS, lines.append)
     assert ok, "\n".join(lines)
     assert "contexts up to 401" in lines[-1], lines[-1]
+    # the decode scans' K/V rows: ONE aliased call a layer where the kernels
+    # are on, the chain of update-slices where they are off
+    assert im.attention_paths.pop(
+        ("kv_row_write", "one_row_per_request")) == (
+        "pallas" if use_pallas else "dus_chain")
     kinds = {k for k, _ in im.attention_paths}
     assert kinds - {"kv_block_write"} == {"latent_attention", "moe_experts"} \
         | ({"decode_block"} if use_pallas else set())
@@ -739,3 +744,10 @@ def test_the_published_tensor_names_are_listed_for_an_importer():
             "mlp.experts.", "mlp.experts.N.")
         assert stem + ".weight" in DEEPSEEK_V2_TENSORS, name
     assert "U_k | U_v" in DEEPSEEK_V2_TENSORS["self_attn.kv_b_proj.weight"][2]
+
+
+def test_row_write_kernel_on_and_off_serves_the_same(row_write_on_and_off):
+    """The decode scan's K/V rows by ``kv_row_write`` and by the chain it
+    replaced — the latent plane and its rotated part: the same tokens, the same caches."""
+    row_write_on_and_off(lambda: seeded(build(use_pallas=True)),
+                         [tokens(40, salt=51), tokens(9, salt=52)])

@@ -263,6 +263,11 @@ def test_the_harness_drive_is_correct(use_pallas):
         ("decode_block", ("eva_attention", "BatchConfig")), None)
     assert (block or "full").startswith("full") and \
         (block is not None) == use_pallas
+    # the decode scans' K/V rows: ONE aliased call a layer where the kernels
+    # are on, the chain of update-slices where they are off
+    assert im.attention_paths.pop(
+        ("kv_row_write", "one_row_per_request")) == (
+        "pallas" if use_pallas else "dus_chain")
     assert {k for k, _ in im.attention_paths} == {"eva_attention"}
     if use_pallas:
         assert im.attention_paths[
@@ -528,3 +533,10 @@ def test_the_published_config_builds_the_published_model():
     assert {k: conf[k] for k in CATALOG if k not in reduced} == \
         {k: v for k, v in CATALOG.items() if k not in reduced}
     assert conf["num_hidden_layers"] == 8
+
+
+def test_row_write_kernel_on_and_off_serves_the_same(row_write_on_and_off):
+    """The decode scan's K/V rows by ``kv_row_write`` and by the chain it
+    replaced — the compacted cache, at compact indices: the same tokens, the same caches."""
+    row_write_on_and_off(lambda: seeded(build(use_pallas=True)),
+                         [tokens(40, salt=51), tokens(9, salt=52)])

@@ -562,6 +562,11 @@ def test_the_harness_drives_are_correct(use_pallas):
     paths = im.attention_paths
     assert paths.pop(("kv_block_write", "PrefillBatchConfig"), None) == (
         "pallas" if use_pallas else None)
+    # the decode scans' K/V rows: ONE aliased call a layer where the kernels
+    # are on, the chain of update-slices where they are off
+    assert paths.pop(
+        ("kv_row_write", "one_row_per_request")) == (
+        "pallas" if use_pallas else "dus_chain")
     assert {k for k, _ in paths} == {"sparse_block_attention",
                                      "lightning_attention", "block_select"}
     assert {b: p for (k, b), p in paths.items() if k == "block_select"} == {
@@ -813,3 +818,10 @@ def test_the_published_config_builds_the_published_model():
         build_model(FFModel(FFConfig(), mesh=make_mesh(
             {"tp": 1}, jax.devices()[:1])), ServeModelConfig.from_hf_config(
                 {**published, "mixer_types": kinds[:3]}), 16)
+
+
+def test_row_write_kernel_on_and_off_serves_the_same(row_write_on_and_off):
+    """The decode scan's K/V rows by ``kv_row_write`` and by the chain it
+    replaced — the sparse layers' caches (the index append stays as it is): the same tokens, the same caches."""
+    row_write_on_and_off(lambda: seeded(build(use_pallas=True)),
+                         [tokens(40, salt=51), tokens(9, salt=52)])

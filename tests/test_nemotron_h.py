@@ -287,6 +287,11 @@ def test_the_harness_drive_is_correct(use_pallas):
                             ("inc_multihead_self_attention",
                              "one_row_per_request")} if use_pallas else set())
     assert all(p.startswith("full") for p in blocks.values())
+    # the decode scans' K/V rows: ONE aliased call a layer where the kernels
+    # are on, the chain of update-slices where they are off
+    assert paths.pop(
+        ("kv_row_write", "one_row_per_request")) == (
+        "pallas" if use_pallas else "dus_chain")
     assert {k for k, _ in paths} == {"mamba2_scan", "moe_experts"}
     assert paths[("mamba2_scan", "PrefillBatchConfig")] == "chunked"
 
@@ -848,3 +853,10 @@ def test_the_published_config_builds_the_published_model():
                 sw.draw_table(key, i, ref.LAYER, hf, "bfloat16")
                 for i in range(9)]), sw.base_key(1))
     assert sum(math.prod(a.shape) for a in jax.tree.leaves(tree)) == total
+
+
+def test_row_write_kernel_on_and_off_serves_the_same(row_write_on_and_off):
+    """The decode scan's K/V rows by ``kv_row_write`` and by the chain it
+    replaced — the one attention layer's cache: the same tokens, the same caches."""
+    row_write_on_and_off(lambda: seeded(build(use_pallas=True)),
+                         [tokens(40, salt=51), tokens(9, salt=52)])

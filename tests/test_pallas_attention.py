@@ -246,6 +246,11 @@ def test_e2e_decode_with_pallas_kernel():
 
 
 # ---- the prefill chunk's block write (kv_block_write) -------------------
+def _bits(a):
+    return np.asarray(a).view(
+        {1: np.uint8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
+
+
 def _chain_reference(kc, vc, k, v, rows, start, count, tile):
     """The chain ``kv_block_write`` replaced, as ``ops._prefill_attend`` and
     ``hybrid_ops._put_blocks`` held it: the chunk re-laid out head-major, cast
@@ -306,10 +311,8 @@ def test_block_write_equals_the_chain(kv, d, tile, cache_dt, fresh_dt):
     got_k, got_v = kv_block_write(kc, vc, k, v, rows, start, count,
                                   tile=tile, interpret=True)
     assert got_k.dtype == kc.dtype and got_v.dtype == vc.dtype
-    as_bits = lambda a: np.asarray(a).view(
-        {1: np.uint8, 2: np.uint16, 4: np.uint32}[a.dtype.itemsize])
-    np.testing.assert_array_equal(as_bits(got_k), as_bits(want_k))
-    np.testing.assert_array_equal(as_bits(got_v), as_bits(want_v))
+    np.testing.assert_array_equal(_bits(got_k), _bits(want_k))
+    np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
     # the write landed: the tail-padded tile holds its rows, then zeros
     tail = np.asarray(got_k[1, :, 2 * tile:3 * tile].astype(jnp.float32))
     assert np.any(tail[:, :tile - 5] != 0) and not np.any(tail[:, tile - 5:])
@@ -383,6 +386,243 @@ def test_tiled_prefill_counts_its_block_write(use_pallas):
     assert im.attention_paths.get(
         ("kv_block_write", "PrefillBatchConfig")) == (
         "pallas" if use_pallas else None)
+
+
+# ---- the decode scan's row write (kv_row_write) --------------------------
+def _row_write_case(kv, d, cache_dt, fresh_dt, s=64, slots=8, seed=0,
+                    pads="together"):
+    """Caches that hold something everywhere and a decode step's rows: live
+    slots once each — at a cache's first and last group of positions, at a
+    group's first and last place, at a ring position ``p % ring``, at
+    coordinates out of range (clamped) — and pads on the scratch row, some
+    on one position."""
+    rng = np.random.default_rng(seed)
+
+    def draw(shape, dt):
+        if jnp.dtype(dt) == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, size=shape), jnp.int8)
+        return jnp.asarray(rng.normal(size=shape), dt)
+
+    kc, vc = (draw((slots + 1, kv, s, d), cache_dt) for _ in range(2))
+    g = 32 // jnp.dtype(cache_dt).itemsize
+    live = [(1, 0), (3, g - 1), (7, s - g), (5, s - 1), (2, (s + g + 3) % s),
+            (4, s + 10), (-2, g + 1), (6, -3)]
+    scratch = [(slots, 5), (slots + 3, 7), (slots, 5)]
+    if pads == "together":
+        cells = live + scratch
+    else:   # as a row that stopped mid-batch leaves them
+        cells = scratch[:1] + live[:4] + scratch[1:2] + live[4:] + scratch[2:]
+    rows, pos = (jnp.asarray(c, jnp.int32) for c in zip(*cells))
+    k, v = (draw((len(cells), kv, d), fresh_dt) for _ in range(2))
+    return kc, vc, k, v, rows, pos
+
+
+def _row_chain(kc, vc, k, v, rows, pos):
+    """The chain ``kv_row_write`` replaced: ``_scatter_rows_pos`` on each
+    cache (cast, clamp, one ``dynamic_update_slice`` a row)."""
+    from flexflow_tpu.serve.ops import IncMultiHeadSelfAttention as Attn
+
+    return (Attn._scatter_rows_pos(kc, rows, pos, k),
+            Attn._scatter_rows_pos(vc, rows, pos, v))
+
+
+_ROW_WRITE_CASES = {
+    # kv heads, head, the cache's type, the fresh rows'
+    "command_ring_mqa": (1, 128, jnp.bfloat16, jnp.bfloat16),
+    "sala_kv2": (2, 128, jnp.bfloat16, jnp.bfloat16),
+    "heads8_f32": (8, 128, jnp.float32, jnp.float32),
+    "opt_32": (32, 128, jnp.bfloat16, jnp.bfloat16),
+    "latent_512": (1, 512, jnp.bfloat16, jnp.bfloat16),
+    "cast_down": (2, 128, jnp.bfloat16, jnp.float32),
+    "cast_up": (8, 128, jnp.float32, jnp.bfloat16),
+    "int8": (32, 128, jnp.int8, jnp.int8),
+    "toy": (2, 16, jnp.float32, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(_ROW_WRITE_CASES))
+def test_row_write_equals_the_chain(case):
+    """ONE aliased call writes what the chain of one-row
+    dynamic-update-slices wrote, bit for bit: same values, same cast, same
+    clamped coordinates, every untouched position of both caches as it was
+    — the scratch row too, where the pads are next to each other."""
+    from flexflow_tpu.ops.pallas.attention import kv_row_write
+
+    kc, vc, k, v, rows, pos = _row_write_case(*_ROW_WRITE_CASES[case])
+    want_k, want_v = _row_chain(kc, vc, k, v, rows, pos)
+    got_k, got_v = kv_row_write(kc, vc, k, v, rows, pos, interpret=True)
+    assert got_k.dtype == kc.dtype and got_v.dtype == vc.dtype
+    np.testing.assert_array_equal(_bits(got_k), _bits(want_k))
+    np.testing.assert_array_equal(_bits(got_v), _bits(want_v))
+    # the write landed, and moved something
+    assert (_bits(got_k) != _bits(kc)).any(axis=(1, 3)).sum() == 10
+    np.testing.assert_array_equal(
+        _bits(got_v[5, :, -1]), _bits(v[3].astype(vc.dtype)))
+
+
+@pytest.mark.parametrize("case", ["command_ring_mqa", "heads8_f32",
+                                  "opt_32"])
+def test_row_write_tolerates_pads_apart_on_the_scratch_row(case):
+    """Pads between live rows (a request that ended mid-batch): every row
+    but the scratch row is the chain's, bit for bit, and so is every
+    position of the scratch row that no pad wrote; a pad's own position
+    holds what it, another pad or nobody wrote there."""
+    from flexflow_tpu.ops.pallas.attention import kv_row_write
+
+    kc, vc, k, v, rows, pos = _row_write_case(*_ROW_WRITE_CASES[case],
+                                              pads="apart")
+    want = _row_chain(kc, vc, k, v, rows, pos)
+    got = kv_row_write(kc, vc, k, v, rows, pos, interpret=True)
+    for g, w, c, x in zip(got, want, (kc, vc), (k, v)):
+        np.testing.assert_array_equal(_bits(g[:-1]), _bits(w[:-1]))
+        quiet = np.setdiff1d(np.arange(c.shape[2]), [5, 7])
+        np.testing.assert_array_equal(_bits(g[-1][:, quiet]),
+                                      _bits(c[-1][:, quiet]))
+        for p, writers in ((5, (0, 10)), (7, (5,))):
+            held = [_bits(c[-1, :, p])] + [
+                _bits(x[i].astype(c.dtype)) for i in writers]
+            assert any((_bits(g[-1, :, p]) == h).all() for h in held)
+
+
+def test_row_write_of_one_plane():
+    """A cache with no second plane of its shape (the latent plane beside
+    its narrower rotated part): ``v_cache=None`` writes the one."""
+    from flexflow_tpu.ops.pallas.attention import kv_row_write
+
+    kc, vc, k, v, rows, pos = _row_write_case(1, 512, jnp.bfloat16,
+                                              jnp.bfloat16)
+    got = kv_row_write(kc, None, k, None, rows, pos, interpret=True)
+    np.testing.assert_array_equal(
+        _bits(got), _bits(_row_chain(kc, vc, k, v, rows, pos)[0]))
+
+
+def test_row_write_refuses_caches_it_cannot_tile():
+    from flexflow_tpu.ops.pallas.attention import kv_row_write
+
+    kc, vc, k, v, rows, pos = _row_write_case(2, 16, jnp.float32,
+                                              jnp.float32, s=20)
+    with pytest.raises(ValueError, match="whole groups"):
+        kv_row_write(kc, vc, k, v, rows, pos, interpret=True)
+    with pytest.raises(ValueError, match="one shape and type"):
+        kv_row_write(kc[:, :, :16], vc[:, :, :8], k, v, rows, pos,
+                     interpret=True)
+
+
+def test_row_write_lowers_to_one_call_that_aliases_both_caches():
+    """Lowered for the TPU (no chip needed to LOWER): one Mosaic call whose
+    two outputs are the two cache operands, and no update-slice."""
+    import re
+
+    from flexflow_tpu.ops.pallas.attention import kv_row_write
+
+    kc, vc, k, v, rows, pos = (
+        jax.ShapeDtypeStruct(a.shape, a.dtype) for a in _row_write_case(
+            32, 128, jnp.bfloat16, jnp.bfloat16))
+    text = jax.jit(kv_row_write).trace(kc, vc, k, v, rows, pos).lower(
+        lowering_platforms=("tpu",)).as_text()
+    calls = re.findall(r"stablehlo\.custom_call @tpu_custom_call.*", text)
+    assert len(calls) == 1
+    aliases = re.findall(
+        r"output_tuple_indices = \[(\d*)\], operand_index = (\d+)", calls[0])
+    # operands: rows, positions, k, v, K cache, V cache
+    assert sorted(aliases) == [("0", "4"), ("1", "5")], calls[0][:400]
+    assert "dynamic_update_slice" not in text
+
+
+_SCAN = {"pallas_decode": True, "pallas_interpret": True,
+         "one_row_per_request": True}
+
+
+@pytest.mark.parametrize("why,extras,shape,want", [
+    ("scan_kernels_on", _SCAN, (2, 16, 64), "pallas"),
+    ("scan_kernels_off", {"one_row_per_request": True}, (2, 128, 64),
+     "dus_chain"),
+    ("flat_step", {"pallas_decode": True, "pallas_interpret": True},
+     (2, 16, 64), None),
+    ("head_not_whole_lanes", dict(_SCAN, pallas_interpret=False),
+     (2, 64, 64), "dus_chain"),
+    ("seq_not_whole_groups", _SCAN, (2, 16, 20), "dus_chain"),
+])
+def test_row_write_path_is_chosen_by_what_is_observed(why, extras, shape,
+                                                      want):
+    """``put_rows`` takes the kernel inside the decode scan where the
+    kernels are on and the planes suit it, the chain otherwise — same caches
+    either way — and says which in ``attention_paths``; outside the scan it
+    records nothing and keeps the chain."""
+    from flexflow_tpu.serve.ops import put_rows
+
+    kv, d, s = shape
+    kc, vc, k, v, rows, pos = _row_write_case(kv, d, jnp.float32,
+                                              jnp.float32, s=s)
+    pos = pos % s
+    paths = {}
+    got = put_rows(kc, vc, k, v, rows, pos,
+                   dict(extras, attention_paths=paths))
+    assert paths == ({("kv_row_write", "one_row_per_request"): want}
+                     if want else {})
+    for a, b in zip(got, _row_chain(kc, vc, k, v, rows, pos)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_row_write_takes_each_plane_by_itself_where_they_differ():
+    """A latent cache's two planes: the wide one by the kernel, the 64-wide
+    rotated part (narrower than the lanes) by the chain, a key a plane."""
+    from unittest import mock
+
+    from flexflow_tpu.ops.pallas import attention
+    from flexflow_tpu.ops.pallas.attention import kv_row_write
+    from flexflow_tpu.serve.ops import put_rows
+
+    kc, _, k, _, rows, pos = _row_write_case(1, 512, jnp.bfloat16,
+                                             jnp.bfloat16)
+    _, vc, _, v, _, _ = _row_write_case(1, 64, jnp.bfloat16, jnp.bfloat16)
+    paths = {}
+    # the chip's choice (the interpreter has no lanes), run by the
+    # interpreter
+    with mock.patch.object(
+            attention, "kv_row_write",
+            lambda *a, interpret: kv_row_write(*a, interpret=True)):
+        got = put_rows(kc, vc, k, v, rows, pos,
+                       dict(_SCAN, pallas_interpret=False,
+                            attention_paths=paths))
+    assert paths == {
+        ("kv_row_write", ("one_row_per_request", 512)): "pallas",
+        ("kv_row_write", ("one_row_per_request", 64)): "dus_chain"}
+    for a, b in zip(got, _row_chain(kc, vc, k, v, rows, pos)):
+        np.testing.assert_array_equal(_bits(a), _bits(b))
+
+
+@pytest.mark.parametrize("kw", [
+    dict(),
+    dict(kv_dtype="int8", max_seq=64),      # the scale planes keep the chain
+    dict(kv_page_size=16, max_seq=64),      # physical (row, position)
+    dict(mesh_axes={"tp": 2}),              # under the head-axis shard_map
+], ids=["plain", "int8", "paged", "tp2"])
+def test_row_write_kernel_on_and_off_serves_the_same(kw, monkeypatch,
+                                                     row_write_on_and_off):
+    """``IncMultiHeadSelfAttention._write_kv`` (OPT, StarCoder, the GQA layer
+    of a hybrid): the decode scan's rows by ``kv_row_write`` and by the
+    chain — the same tokens, the same caches."""
+    import test_serve
+
+    def make():
+        monkeypatch.setattr(test_serve, "_IM_CACHE", {})
+        return make_im(use_pallas=True, **kw)
+
+    row_write_on_and_off(make, [[3, 11, 25, 40, 7], [9, 2]])
+
+
+def test_a_flat_step_asks_for_no_row_write(monkeypatch):
+    """Outside the decode scan a call may hold several positions of one row
+    (a prompt's): ``put_rows`` keeps the chain there and records nothing."""
+    import test_serve
+    from flexflow_tpu.serve.batch_config import BatchConfig
+
+    monkeypatch.setattr(test_serve, "_IM_CACHE", {})    # one no scan ran on
+    im = make_im(max_tokens=8, max_requests=2, max_seq=32, use_pallas=True)
+    im.step(BatchConfig.build([5, 9, 2], [0, 0, 0], [0, 1, 2], [3, 0],
+                              max_tokens=8, max_requests=2))
+    assert "kv_row_write" not in {k for k, _ in im.attention_paths}
 
 
 # ---- the seq block a grid step copies, planned by bytes (PR 51) ------------

@@ -267,6 +267,11 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert im.attention_paths.pop(
         ("kv_block_write", "PrefillBatchConfig"), None) == (
         "pallas" if use_pallas else None)
+    # the decode scans' K/V rows: ONE aliased call a layer where the kernels
+    # are on, the chain of update-slices where they are off
+    assert im.attention_paths.pop(
+        ("kv_row_write", "one_row_per_request")) == (
+        "pallas" if use_pallas else "dus_chain")
     kinds = {k for k, _ in im.attention_paths}
     assert kinds == {"window_attention", "full_attention", "cross_attention",
                      "selective_scan"} | (
@@ -520,3 +525,10 @@ def test_chunked_feeding_leaves_the_state_one_chunk_leaves(use_pallas):
     assert scan_path(parts) == ("kernel" if use_pallas else "row_scan")
     assert_same_recurrent_state(recurrent_state(parts, [3]),
                                 recurrent_state(whole, [3]))
+
+
+def test_row_write_kernel_on_and_off_serves_the_same(row_write_on_and_off):
+    """The decode scan's K/V rows by ``kv_row_write`` and by the chain it
+    replaced — the rings and the one shared cache: the same tokens, the same caches."""
+    row_write_on_and_off(lambda: deployment.__wrapped__(use_pallas=True),
+                         [tokens(40, salt=51), tokens(9, salt=52)])

@@ -113,6 +113,11 @@ def test_scan_matches_incremental_pallas():
     want = RequestManager(im, GenerationConfig(max_new_tokens=10)).generate(PROMPTS)
     got, _ = scan_generate(2, 2, n_new=10, use_pallas=True)
     assert got == want
+    # a macro-step commits several positions of ONE row in a call: its
+    # writes keep the chain and never ask for kv_row_write (the decode
+    # scan's, one position a row)
+    for rig in _rig(2, 2, True):
+        assert "kv_row_write" not in {k for k, _ in rig.attention_paths}
 
 
 def test_scan_eos_freezes_slot():

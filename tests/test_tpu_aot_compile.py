@@ -26,6 +26,7 @@ from jax.sharding import PartitionSpec as P
 from flexflow_tpu.ops.pallas.attention import (
     decode_attention,
     kv_block_write,
+    kv_row_write,
     prefill_attention,
     sparse_decode_attention,
     tree_attention,
@@ -244,6 +245,85 @@ def test_block_write_kernel_updates_the_caches_in_place(one_chip, case):
     text = compiled.as_text()
     assert text.count("tpu_custom_call") >= 1
     assert "dynamic-update-slice" not in text and " copy(" not in text
+    mem = compiled.memory_analysis()
+    cache_bytes = (slots + 1) * kv * s * d * jnp.dtype(cache_dt).itemsize
+    assert mem.alias_size_in_bytes == 2 * cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 64
+
+
+# (KV, D, S, slots, cache type, fresh type): a decode scan's step, a row a
+# slot
+_ROW_WRITE_CASES = {
+    "opt": (32, 128, 2048, 8, jnp.bfloat16, jnp.bfloat16),
+    "starcoder_mqa": (1, 128, 8192, 16, jnp.bfloat16, jnp.bfloat16),
+    "phi4_pairs": (10, 128, 8192, 32, jnp.bfloat16, jnp.bfloat16),
+    "evabyte": (32, 128, 4096, 16, jnp.bfloat16, jnp.bfloat16),
+    "sala_kv2": (2, 128, 32768, 48, jnp.bfloat16, jnp.bfloat16),
+    "nemotron_kv2": (2, 128, 8192, 256, jnp.bfloat16, jnp.bfloat16),
+    "command_ring": (1, 128, 4608, 128, jnp.bfloat16, jnp.bfloat16),
+    "latent": (1, 512, 16384, 64, jnp.bfloat16, jnp.bfloat16),
+    "int8": (32, 128, 2048, 8, jnp.int8, jnp.int8),
+    "cast": (8, 128, 2048, 16, jnp.float32, jnp.bfloat16),
+}
+
+
+def _holds_no_copy_of(text, shape):
+    """No ``copy`` and no ``dynamic-update-slice`` of an array of ``shape``
+    in a compiled program's text."""
+    dims = ",".join(map(str, shape))
+    return not [ln for ln in text.splitlines()
+                if (" copy(" in ln or "dynamic-update-slice(" in ln)
+                and f"[{dims}]" in ln.partition(" = ")[2].partition("(")[0]]
+
+
+@pytest.mark.parametrize("case", list(_ROW_WRITE_CASES))
+def test_row_write_kernel_updates_the_caches_in_place(one_chip, case):
+    """``kv_row_write`` at the widths the cells' decode scans run: the
+    compiler tiles it (a group of positions in and out, the row set by a
+    sublane compare on the cache's own packed type), and with the caches
+    donated (the scan's carry) the compiled program holds no update-slice,
+    no copy and NO temporary of a cache — both are written where they
+    lie."""
+    kv, d, s, slots, cache_dt, fresh_dt = _ROW_WRITE_CASES[case]
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    cache = sds((slots + 1, kv, s, d), cache_dt)
+    fresh, at = sds((slots, kv, d), fresh_dt), sds((slots,), jnp.int32)
+    compiled = jax.jit(kv_row_write, donate_argnums=(0, 1)).lower(
+        cache, cache, fresh, fresh, at, at).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 1
+    assert "dynamic-update-slice" not in text
+    assert _holds_no_copy_of(text, cache.shape)
+    mem = compiled.memory_analysis()
+    cache_bytes = (slots + 1) * kv * s * d * jnp.dtype(cache_dt).itemsize
+    assert mem.alias_size_in_bytes == 2 * cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 64
+
+
+@pytest.mark.parametrize("case", ["opt", "command_ring", "phi4_pairs"])
+def test_a_scan_that_writes_rows_then_attends_re_lays_no_cache(one_chip,
+                                                               case):
+    """A decode-scan body — ``kv_row_write``, then ``decode_attention`` on
+    the caches it returned, both the scan's carry: the two kernels agree on
+    the caches' layout, so the loop copies neither (what an XLA scatter
+    there cost: a cache re-laid out every step)."""
+    kv, d, s, slots, cache_dt, fresh_dt = _ROW_WRITE_CASES[case]
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    cache = sds((slots + 1, kv, s, d), cache_dt)
+    fresh, at = sds((slots, kv, d), fresh_dt), sds((slots,), jnp.int32)
+
+    def scan(kc, vc, k, v, rows, pos):
+        def body(carry, i):
+            kc, vc = kv_row_write(*carry, k, v, rows, pos + i)
+            out = decode_attention(k, kc, vc, rows, pos + i, scale=d ** -0.5)
+            return (kc, vc), out
+        return jax.lax.scan(body, (kc, vc), jnp.arange(4))
+
+    compiled = jax.jit(scan, donate_argnums=(0, 1)).lower(
+        cache, cache, fresh, fresh, at, at).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert _holds_no_copy_of(text, cache.shape)
     mem = compiled.memory_analysis()
     cache_bytes = (slots + 1) * kv * s * d * jnp.dtype(cache_dt).itemsize
     assert mem.alias_size_in_bytes == 2 * cache_bytes
