@@ -334,11 +334,19 @@ class FFModel:
     # ops with per-slot state that is not a full-length K/V cache
     # (serve/hybrid_ops.py): Mamba's conv and scan, differential attention
     # over a window ring, a full cache, or another node's cache
-    def causal_conv1d(self, x, kernel=4, name=None):
+    def causal_conv1d(self, x, kernel=4, bias=True, name=None):
         from .serve.hybrid_ops import CausalConv1d
 
-        op = CausalConv1d(x.shape[-1], kernel, dtype=x.dtype)
+        op = CausalConv1d(x.shape[-1], kernel, dtype=x.dtype, bias=bias)
         return self._add(op, [x], name or "causal_conv1d")[0]
+
+    def kimi_delta_attention(self, qkv, x, embed_dim, num_heads, head_dim,
+                             name=None, **how):
+        from .serve.hybrid_ops import KimiDeltaAttention
+
+        op = KimiDeltaAttention(embed_dim, num_heads, head_dim,
+                                dtype=x.dtype, **how)
+        return self._add(op, [qkv, x], name or "kimi_delta_attention")[0]
 
     def selective_scan(self, xs, dt, b, c, d_state=16, name=None):
         from .serve.hybrid_ops import SelectiveScan
@@ -436,12 +444,13 @@ class FFModel:
 
     def latent_attention(self, x, embed_dim, num_heads, nope_dim, rope_dim,
                          v_dim, kv_rank, rope_theta=10000.0,
-                         rope_scaling=None, eps=1e-6, name=None):
+                         rope_scaling=None, eps=1e-6, use_rope=True,
+                         name=None):
         from .serve.hybrid_ops import LatentAttention
 
         op = LatentAttention(embed_dim, num_heads, nope_dim, rope_dim, v_dim,
                              kv_rank, rope_theta, rope_scaling, eps,
-                             dtype=x.dtype)
+                             dtype=x.dtype, use_rope=use_rope)
         return self._add(op, [x], name or "latent_attention")[0]
 
     def moe_combine(self, ys, order, ids, weights, num_held, held_lo=0,

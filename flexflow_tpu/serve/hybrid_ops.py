@@ -17,11 +17,12 @@ beside ``IncMultiHeadSelfAttention``:
   rotary over a sliding window (``cohere2_moe``'s sliding layers), its
   cache the same ring: :class:`SlotCacheAttention` is the one ring (and
   full-length, and borrowed) cache implementation both kinds share.
-* :class:`LatentAttention` — multi-head LATENT attention (``deepseek_v2``):
-  the cache holds one normed latent and one rotated key part a position,
-  shared by all heads, and nothing per head; decode reads it in the
-  ABSORBED form (the per-head up-projections folded into the query and the
-  output), so the latent is the key and the value at once.
+* :class:`LatentAttention` — multi-head LATENT attention (``deepseek_v2``;
+  un-rotated, ``kimi_linear``'s one layer in four): the cache holds one
+  normed latent and one shared key part a position (rotated where the model
+  rotates), shared by all heads, and nothing per head; decode reads it in
+  the ABSORBED form (the per-head up-projections folded into the query and
+  the output), so the latent is the key and the value at once.
 * :class:`EvaAttention` — EVA attention (EvaByte): exact attention inside
   the query's own window, one summary per chunk of every earlier window, one
   softmax over both.  Its cache COMPACTS itself: when a window closes, its
@@ -36,6 +37,10 @@ beside ``IncMultiHeadSelfAttention``:
   ``lightning-attn`` layers): the state is one ``head_dim x head_dim``
   float32 matrix per head and slot, decayed per head and updated by a
   rank-one product a position.
+* :class:`KimiDeltaAttention` — a gated DELTA rule with a per-channel decay
+  (``kimi_linear``'s ``kda_layers``): the same matrix state, but the update
+  subtracts what the state already returns for the key — it READS the state
+  it changes — and the decay is a vector a head computed from the token.
 
 All of them run on the flat token batch every step program shares.  A flat
 batch mixes rows of several requests, so state is SEGMENTED by
@@ -156,16 +161,20 @@ class _SlotStateOp(Op):
 @register_op
 class CausalConv1d(_SlotStateOp):
     """Depthwise causal convolution over each request's own positions:
-    ``y_t = silu(sum_j w[j] * x_{t-(K-1-j)} + b)``, positions before the
-    request's first read zero.  Input/output ``[max_tokens, channels]``;
-    state ``conv [max_requests + 1, K - 1, channels]``: the last ``K - 1``
-    inputs of each slot."""
+    ``y_t = silu(sum_j w[j] * x_{t-(K-1-j)} [+ b])``, positions before the
+    request's first read zero; the bias ``b`` is an OPTION (``bias``: Mamba's
+    convs carry one, ``kimi_linear``'s three short convs over q | k | v do
+    not, and then the op has no such parameter).  Input/output
+    ``[max_tokens, channels]``; state ``conv [max_requests + 1, K - 1,
+    channels]``: the last ``K - 1`` inputs of each slot."""
 
     type_name = "causal_conv1d"
 
-    def __init__(self, channels: int, kernel: int = 4, dtype=jnp.float32):
+    def __init__(self, channels: int, kernel: int = 4, dtype=jnp.float32,
+                 bias: bool = True):
         self.channels = int(channels)
         self.kernel = int(kernel)
+        self.bias = bool(bias)
         self.dtype = jnp.dtype(dtype).name
 
     def infer_shapes(self, in_specs):
@@ -173,9 +182,11 @@ class CausalConv1d(_SlotStateOp):
 
     def params(self) -> List[ParamSpec]:
         dt = jnp.dtype(self.dtype)
-        return [ParamSpec("weight",
-                          TensorSpec((self.kernel, self.channels), dt)),
-                ParamSpec("bias", TensorSpec((self.channels,), dt))]
+        ps = [ParamSpec("weight",
+                        TensorSpec((self.kernel, self.channels), dt))]
+        if self.bias:
+            ps.append(ParamSpec("bias", TensorSpec((self.channels,), dt)))
+        return ps
 
     def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
                     head_axes=()):
@@ -205,7 +216,7 @@ class CausalConv1d(_SlotStateOp):
             taps.append(jnp.where(
                 ((seg.pos >= back) & seg.live)[:, None], val, 0))
         w = params["weight"].astype(jnp.float32)
-        y = params["bias"].astype(jnp.float32)
+        y = params["bias"].astype(jnp.float32) if self.bias else 0.0
         for back, tap in enumerate(taps):
             y = y + tap.astype(jnp.float32) * w[k - 1 - back]
         y = jax.nn.silu(y)
@@ -757,20 +768,26 @@ class SlidingWindowAttention(SlotCacheAttention):
 
 @register_op
 class LatentAttention(_SlotStateOp):
-    """Multi-head LATENT attention (MLA, ``deepseek_v2``) over flat token
-    batches.  Per position the layer caches ONE latent ``c = RMSNorm(x
-    W_kv_a[:, :r])`` (``r = kv_rank``) and ONE rotated key part ``k_r =
-    rope(x W_kv_a[:, r:])`` (``rope_dim`` wide), shared by ALL heads — ``(r
-    + rope_dim)`` values a position and nothing per head (1 152 B in bf16 at
-    512 + 64, where the same 16 heads as plain K/V would hold 10 240 B).
+    """Multi-head LATENT attention (MLA: ``deepseek_v2``'s every layer, and
+    ``kimi_linear``'s one layer in four) over flat token batches.  Per
+    position the layer caches ONE latent ``c = RMSNorm(x W_kv_a[:, :r])``
+    (``r = kv_rank``) and ONE shared key part ``k_r = x W_kv_a[:, r:]``
+    (``rope_dim`` wide), shared by ALL heads — ``(r + rope_dim)`` values a
+    position and nothing per head (1 152 B in bf16 at 512 + 64, where the
+    same 16 heads as plain K/V would hold 10 240 B).
 
-    Head ``i``: ``[q_n | q_r] = x W_q`` (``nope_dim`` + ``rope_dim``),
-    ``q_r`` rotated; ``[k_n,i | v_i] = c W_kv_b`` per head; score ``s =
-    scale (q_n,i . k_n,i + q_r,i . k_r)``, ``scale = (nope_dim +
-    rope_dim)^-1/2 m^2`` with YaRN's ``m = yarn_mscale(factor,
-    mscale_all_dim)``; ``o_i = softmax(s) v_i``; the heads' ``v_dim``-wide
-    outputs concatenate into ``o_proj``.  Rotary on INTERLEAVED pairs with
-    YaRN's frequencies (``rope_scaling``), on the ``rope_dim`` part only.
+    Head ``i``: ``[q_n | q_r] = x W_q`` (``nope_dim`` + ``rope_dim``);
+    ``[k_n,i | v_i] = c W_kv_b`` per head; score ``s = scale (q_n,i . k_n,i
+    + q_r,i . k_r)``, ``scale = (nope_dim + rope_dim)^-1/2 m^2`` with YaRN's
+    ``m = yarn_mscale(factor, mscale_all_dim)`` (1 without ``rope_scaling``);
+    ``o_i = softmax(s) v_i``; the heads' ``v_dim``-wide outputs concatenate
+    into ``o_proj``.  ``use_rope`` (``deepseek_v2``): ``q_r`` and ``k_r`` are
+    ROTATED, on INTERLEAVED pairs with YaRN's frequencies (``rope_scaling``),
+    the ``rope_dim`` part only.  ``use_rope=False`` (``kimi_linear``:
+    ``mla_use_nope``): neither part is rotated — the layer has no positional
+    term at all (the delta-rule layers around it carry position) and the
+    "rope" plane is a plain second key part; the cache, the kernel and both
+    forms are the same.
 
     Two forms, one result.  ABSORBED: with ``W_kv_b`` split per head into
     ``U_k [r, nope_dim]`` and ``U_v [r, v_dim]``, ``q_lat = q_n U_k'`` (in
@@ -815,7 +832,8 @@ class LatentAttention(_SlotStateOp):
                  rope_dim: int, v_dim: int, kv_rank: int,
                  rope_theta: float = 10000.0,
                  rope_scaling: Optional[dict] = None, eps: float = 1e-6,
-                 prompt_form: str = "absorbed", dtype=jnp.float32):
+                 prompt_form: str = "absorbed", dtype=jnp.float32,
+                 use_rope: bool = True):
         if prompt_form not in ("absorbed", "materialised"):
             raise ValueError("prompt_form is 'absorbed' or 'materialised'")
         if rope_scaling and rope_scaling.get(
@@ -832,6 +850,7 @@ class LatentAttention(_SlotStateOp):
         self.rope_scaling = dict(rope_scaling) if rope_scaling else None
         self.eps = float(eps)
         self.prompt_form = prompt_form
+        self.use_rope = bool(use_rope)
         self.dtype = jnp.dtype(dtype).name
         m = 1.0
         if self.rope_scaling and self.rope_scaling.get("mscale_all_dim"):
@@ -877,11 +896,13 @@ class LatentAttention(_SlotStateOp):
         return dequant(params[name], params.get(f"{name}_scale"), dtype)
 
     def _project(self, x, params, pos):
-        """``(q_n [T, H, nope], q_r [T, H, rope] rotated, c [T, r] normed,
-        k_r [T, rope] rotated)``."""
+        """``(q_n [T, H, nope], q_r [T, H, rope], c [T, r] normed, k_r [T,
+        rope])``, ``q_r`` and ``k_r`` rotated where the layer rotates."""
         r = self.kv_rank
-        rope = lambda a: apply_rope(a, pos, self.rope_theta,
-                                    interleaved=True, yarn=self.rope_scaling)
+        rope = (lambda a: apply_rope(a, pos, self.rope_theta,
+                                     interleaved=True,
+                                     yarn=self.rope_scaling)) \
+            if self.use_rope else (lambda a: a)
         q = jnp.einsum("te,ehc->thc", x, self._weight(params, "q_proj",
                                                       x.dtype),
                        preferred_element_type=jnp.float32).astype(x.dtype)
@@ -894,7 +915,7 @@ class LatentAttention(_SlotStateOp):
 
     @jax.named_scope("kv_write")
     def _write(self, ckv, kpe, c, k_r, bc, seg, tiled, extras):
-        """This step's latents and rotated key parts into the two planes."""
+        """This step's latents and shared key parts into the two planes."""
         pos = _flat(bc).token_position
         c, k_r = c[:, None], k_r[:, None]           # one cached "head"
         if not tiled:
@@ -1926,4 +1947,272 @@ class LightningAttention(_SlotStateOp):
                           x.dtype)
             y = jnp.dot(o.astype(x.dtype), o_w,
                         preferred_element_type=jnp.float32)
+            return [y.astype(self.dtype)]
+
+
+def unit_lower_inverse(n):
+    """``(I + N)^-1`` for STRICTLY lower-triangular ``N [..., C, C]`` (``C``
+    a power of two), by exact block forward substitution: with the two
+    halves of a block inverted, ``[[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B
+    A^-1, D^-1]]`` — ``log2 C`` levels of two matrix products over all the
+    blocks of a level at once (``inv`` stays block-diagonal at the level's
+    size, so whole-matrix products touch nothing outside the blocks).  No
+    Neumann powers: nothing is summed that cancels."""
+    c = n.shape[-1]
+    i = jnp.arange(c, dtype=jnp.int32)
+    inv = jnp.broadcast_to(jnp.eye(c, dtype=n.dtype), n.shape)
+    hi = jax.lax.Precision.HIGHEST
+    b = 1
+    while b < c:
+        same = (i[:, None] // (2 * b)) == (i[None, :] // (2 * b))
+        lower_left = same & ((i[:, None] % (2 * b)) >= b) \
+            & ((i[None, :] % (2 * b)) < b)
+        inv = inv - jnp.matmul(
+            jnp.matmul(inv, jnp.where(lower_left, n, 0.0), precision=hi),
+            inv, precision=hi)
+        b *= 2
+    return inv
+
+
+@register_op
+class KimiDeltaAttention(_SlotStateOp):
+    """Kimi Delta Attention (``kimi_linear``'s ``kda_layers``) over flat
+    token batches: a gated DELTA rule with a PER-CHANNEL decay.
+
+    Inputs: ``qkv [T, 3 H D]`` — the three projections behind their three
+    short depthwise convolutions with SiLU (ONE ``CausalConv1d(bias=False)``
+    node over ``q | k | v``: a depthwise conv treats every channel alone, so
+    one node over the 3 H D channels is the three convs' arithmetic and one
+    tail) — and the normed stream ``n [T, E]``.  Per head ``h``:
+
+        q = q / max(|q|, 1e-6) D^-1/2;   k = k / max(|k|, 1e-6)
+        g = -exp(A_log_h) softplus((n W_fa W_fb)_h + dt_bias_h)   [D], float32
+        beta = sigmoid((n W_beta)_h)                              scalar
+        S' = Diag(exp g) S;  S <- S' + beta k (v - S'^T k)^T;  o = S^T q
+        y = RMSNorm(o, g_o) * sigmoid((n W_ga W_gb)_h);  out = concat(y) W_o
+
+    State kind ``delta_state`` (kv_allocator.py): ``kda [rows, H, D, D]``
+    float32, key channel x value channel, fixed a slot.  Unlike the repo's
+    other two matrix states (``S <- a S + k v^T``) the update READS the
+    state it is about to change (``S'^T k``), and the decay is a vector.
+
+    * the decode scan (every live row a request of its own): the Pallas step
+      kernel ``delta_rule_step`` — a row's matrices read once and written
+      once, in place; by XLA (gather, update, scatter) where the kernels are
+      off or the head is not whole lanes: the CPU oracle.
+    * a prompt chunk, a join's prefill or a flat step: the CHUNKED form over
+      PIECES — runs of at most ``chunk`` rows of one request (``Segments``
+      has the boundaries) — one loop trip a piece, never one a row.  Inside
+      a piece entered with ``S0``, ``G_i`` the running sum of ``g`` (<= 0):
+      ``A_ij = sum_d k_i k_j exp(G_i - G_j)`` (j < i), ``B_ij`` likewise with
+      ``q_i`` (j <= i), both by EXPLICIT differences — every exponent is
+      <= 0 as written, the worst exactly 0; no ``exp(-G)`` is ever formed —;
+      the pseudo-values solve ``(I + Diag(beta) A) U = Diag(beta) (V - (K *
+      exp G) S0)`` (:func:`unit_lower_inverse`); ``o = (q * exp G) S0 + B
+      U``; ``S = Diag(exp G_C) S0 + (K * exp(G_C - G))^T U``.  The state's
+      products at HIGHEST precision (a float32 matmul would otherwise round
+      the state to bf16 on the MXU).
+    """
+
+    type_name = "kimi_delta_attention"
+    # the projections weight-only int8 replaces (serve/quant.py); the
+    # decay's pair and ``W_beta`` stay as they are: they are float32 paths
+    int8_params = ("g_a", "g_b", "o_proj")
+    NORM_EPS = 1e-6     # the L2 norms' floor
+
+    def __init__(self, embed_dim: int, num_heads: int, head_dim: int,
+                 eps: float = 1e-5, chunk: int = 32, dtype=jnp.float32):
+        if chunk & (chunk - 1):
+            raise ValueError("the chunked form's piece is a power of two")
+        self.embed_dim = int(embed_dim)
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.eps = float(eps)
+        self.chunk = int(chunk)
+        self.dtype = jnp.dtype(dtype).name
+
+    @property
+    def inner(self) -> int:
+        return self.num_heads * self.head_dim
+
+    def infer_shapes(self, in_specs):
+        return [TensorSpec(in_specs[1].shape, jnp.dtype(self.dtype))]
+
+    def params(self) -> List[ParamSpec]:
+        dt, f32 = jnp.dtype(self.dtype), jnp.dtype("float32")
+        # the two low-rank pairs (decay, output gate) are head_dim wide
+        e, r, w, h = self.embed_dim, self.head_dim, self.inner, self.num_heads
+        # the family's own initialisation (as Mamba-2's): A spread over
+        # [1, 16], a step bias whose softplus spreads log-uniformly over
+        # [1e-3, 1e-1]
+        a_log = _init(lambda s: jnp.log(jnp.linspace(1.0, 16.0, s[0])))
+        dt_bias = _init(lambda s: jnp.log(jnp.expm1(jnp.exp(jnp.linspace(
+            math.log(1e-3), math.log(1e-1), s[0])))))
+        return [
+            ParamSpec("f_a", TensorSpec((e, r), dt)),
+            ParamSpec("f_b", TensorSpec((r, w), dt)),
+            ParamSpec("dt_bias", TensorSpec((w,), f32), dt_bias,
+                      pin_dtype=True),
+            ParamSpec("A_log", TensorSpec((h,), f32), a_log, pin_dtype=True),
+            ParamSpec("b_proj", TensorSpec((e, h), dt)),
+            ParamSpec("g_a", TensorSpec((e, r), dt)),
+            ParamSpec("g_b", TensorSpec((r, w), dt)),
+            ParamSpec("o_norm", TensorSpec((self.head_dim,), dt),
+                      _init(jnp.ones)),
+            ParamSpec("o_proj", TensorSpec((w, e), dt)),
+        ]
+
+    def flops(self, in_specs):
+        t = in_specs[1].shape[0]
+        e, r, w = self.embed_dim, self.head_dim, self.inner
+        return 2 * t * (2 * (e * r + r * w) + e * self.num_heads + w * e
+                        + 4 * w * self.head_dim)
+
+    def state_specs(self, max_requests, max_seq_len, max_spec_tokens=0,
+                    head_axes=()):
+        shape = (max_requests + 1, self.num_heads, self.head_dim,
+                 self.head_dim)
+        return {"kda": (shape, "float32", TensorSharding.replicated(4))}
+
+    def _unit(self, a):
+        """``a / max(|a|, NORM_EPS)`` over the head's channels."""
+        return a / jnp.maximum(jnp.sqrt(jnp.sum(a * a, axis=-1,
+                                                keepdims=True)),
+                               self.NORM_EPS)
+
+    def _gated_norm(self, o, gate, gain):
+        """The head's RMS norm, THEN the sigmoid gate."""
+        return _rms_norm(o, gain.astype(jnp.float32), self.eps) \
+            * jax.nn.sigmoid(gate)
+
+    # ---- the two forms ----------------------------------------------------
+    def _step(self, q, k, v, g, beta, kda, seg, ctx):
+        """The decode scan's step: one row a request."""
+        alpha = jnp.where(seg.fresh[:, None, None], 0.0, jnp.exp(g))
+        kb = beta[..., None] * k
+        if ctx.extras.get("pallas_decode") and (
+                self.head_dim % LANE == 0
+                or ctx.extras.get("pallas_interpret")):
+            from ..ops.pallas.delta_rule import delta_rule_step
+
+            o, kda = delta_rule_step(
+                kda, alpha, k, kb, q, v, seg.rows, seg.live,
+                interpret=bool(ctx.extras.get("pallas_interpret")))
+            return o, kda, "delta_rule_step"
+        s = kda[seg.rows] * alpha[..., None]
+        u = v - jnp.sum(s * k[..., None], axis=2)
+        s = s + kb[..., None] * u[:, :, None, :]
+        o = jnp.sum(s * q[..., None], axis=2)
+        with jax.named_scope("state_write"):
+            kda = _set_rows(kda, seg.store, s)
+        return jnp.where(seg.live[:, None, None], o, 0.0), kda, "xla_rows"
+
+    def _chunked(self, q, k, v, g, beta, kda, seg):
+        """A prompt chunk or a flat step (see the class docstring)."""
+        t, h, d = q.shape
+        c, nreq = self.chunk, kda.shape[0] - 1
+        hi = jax.lax.Precision.HIGHEST
+        piece = (seg.start | (seg.offset % c == 0)) & seg.live
+        order = jnp.argsort(~piece, stable=True).astype(jnp.int32)
+        piece_id = jnp.cumsum(piece.astype(jnp.int32))
+        # a piece's window may run past the batch's end: rows of no piece
+        pad = lambda a: jnp.concatenate(
+            [a, jnp.zeros((c,) + a.shape[1:], a.dtype)])
+        q, k, v, g, beta = (pad(a) for a in (q, k, v, g, beta))
+        live, piece_id, last = pad(seg.live), pad(piece_id), pad(seg.last)
+        i = jnp.arange(c, dtype=jnp.int32)
+        causal = (i[:, None] >= i[None, :])[:, :, None, None]
+        strict = (i[:, None] > i[None, :])[None]
+        zero = jnp.int32(0)
+
+        def one(p, carry):
+            out, kda, s_prev = carry
+            f = order[p]
+            win = lambda a: jax.lax.dynamic_slice_in_dim(a, f, c, axis=0)
+            mine = win(live) & (win(piece_id) == piece_id[f])       # [C]
+            m3 = mine[:, None, None]
+            qc, kc, vc = (jnp.where(m3, win(a), 0.0) for a in (q, k, v))
+            gc = jnp.where(m3, win(g), 0.0)
+            bc = jnp.where(mine[:, None], win(beta), 0.0)           # [C, H]
+            at = (seg.rows[f], zero, zero, zero)
+            own = jax.lax.dynamic_slice(kda, at, (1,) + kda.shape[1:])[0]
+            s0 = jnp.where(seg.start[f],
+                           jnp.where(seg.fresh[f], 0.0, own), s_prev)
+            run = jnp.cumsum(gc, axis=0)                            # G <= 0
+            # exp(G_i - G_j), j <= i: explicit differences, none above 0
+            between = jnp.exp(jnp.where(
+                causal, run[:, None] - run[None, :], -jnp.inf))  # [C,C,H,D]
+            kk = kc[None] * between
+            a = jnp.sum(kc[:, None] * kk, axis=-1).transpose(2, 0, 1)
+            b = jnp.sum(qc[:, None] * kk, axis=-1).transpose(2, 0, 1)
+            decayed = jnp.exp(run)
+            rhs = bc[..., None] * (vc - jnp.einsum(
+                "chk,hkv->chv", kc * decayed, s0, precision=hi))
+            solve = unit_lower_inverse(
+                jnp.where(strict, bc.T[:, :, None] * a, 0.0))       # [H,C,C]
+            u = jnp.einsum("hij,jhv->ihv", solve, rhs, precision=hi)
+            o = jnp.einsum("chk,hkv->chv", qc * decayed, s0, precision=hi) \
+                + jnp.einsum("hij,jhv->ihv", b, u, precision=hi)
+            s1 = decayed[-1][..., None] * s0 + jnp.einsum(
+                "jhk,jhv->hkv", kc * jnp.exp(run[-1][None] - run), u,
+                precision=hi)
+            # the window's rows past the piece are later pieces' (written
+            # after this one, in row order) or pads (zero)
+            out = jax.lax.dynamic_update_slice(
+                out, jnp.where(m3, o, 0.0), (f, zero, zero))
+            # the slot's own row after a segment's last row, else scratch
+            store = jnp.where(jnp.any(mine & win(last)), seg.rows[f], nreq)
+            kda = jax.lax.dynamic_update_slice(
+                kda, s1[None], (store, zero, zero, zero))
+            return out, kda, s1
+
+        with jax.named_scope("state_write"):
+            out, kda, _ = jax.lax.fori_loop(
+                0, jnp.sum(piece.astype(jnp.int32)), one,
+                (jnp.zeros((t + c, h, d), jnp.float32), kda,
+                 jnp.zeros(kda.shape[1:], kda.dtype)))
+        return out[:t], kda
+
+    def lower(self, ctx, inputs, params):
+        bc, state = _require(ctx, self.type_name)
+        qkv, x = inputs
+        t, h, d = x.shape[0], self.num_heads, self.head_dim
+        f32 = jnp.float32
+        hi = jax.lax.Precision.HIGHEST
+        kda = state["kda"]
+        seg = Segments(_flat(bc), kda.shape[0] - 1)
+        weight = lambda name: dequant(params[name],
+                                      params.get(f"{name}_scale"), x.dtype)
+        with jax.named_scope("qkv_proj"):
+            q, k, v = (qkv[:, n * h * d:(n + 1) * h * d].astype(f32)
+                       .reshape(t, h, d) for n in range(3))
+            q, k = self._unit(q) * d ** -0.5, self._unit(k)
+            # the decay and beta in float32: the low-rank pair's second
+            # product on float32 operands (HIGHEST: the MXU would round
+            # them to bf16)
+            low = jnp.dot(x, params["f_a"], preferred_element_type=f32)
+            raw = jnp.dot(low, params["f_b"].astype(f32), precision=hi)
+            g = -jnp.exp(params["A_log"])[None, :, None] * jax.nn.softplus(
+                raw + params["dt_bias"]).reshape(t, h, d)
+            beta = jax.nn.sigmoid(jnp.dot(x, params["b_proj"],
+                                          preferred_element_type=f32))
+        with jax.named_scope("attend"):
+            if ctx.extras.get("one_row_per_request"):
+                o, kda, path = self._step(q, k, v, g, beta, kda, seg, ctx)
+                batch = "one_row_per_request"
+            else:
+                o, kda = self._chunked(q, k, v, g, beta, kda, seg)
+                path, batch = "chunked", type(bc).__name__
+            ctx.extras["state_out"] = {"kda": kda}
+            paths = ctx.extras.get("attention_paths")
+            if paths is not None:
+                paths[(self.type_name, batch)] = path
+        with jax.named_scope("o_proj"):
+            gate = jnp.dot(
+                jnp.dot(x, weight("g_a"), preferred_element_type=f32
+                        ).astype(x.dtype),
+                weight("g_b"), preferred_element_type=f32)
+            o = self._gated_norm(o, gate.reshape(t, h, d), params["o_norm"])
+            y = jnp.dot(o.reshape(t, h * d).astype(x.dtype), weight("o_proj"),
+                        preferred_element_type=f32)
             return [y.astype(self.dtype)]

@@ -187,6 +187,10 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
     # a latent cache (LatentAttention): two planes a position, shared by all
     # heads, that are not K and V planes — each option says what IT lacks
     latent = "LatentAttention" in kinds
+    # a delta-rule state (KimiDeltaAttention): a float32 matrix a head whose
+    # update READS the state it changes — nothing snapshots it or rolls it
+    # back (ROADMAP B-I 5)
+    delta = "KimiDeltaAttention" in kinds
     missing = []
     if kv_page_size:
         missing.append("kv_page_size: a page table for a ring that wraps "
@@ -203,7 +207,11 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                           "rotated-key plane of another width (kv_paged.py "
                           "pools K and V planes of one head size), and a "
                           "paged mode of the latent decode kernel"
-                          if latent else ""))
+                          if latent else "")
+                       + ("; for a delta state, a snapshot of the float32 "
+                          "matrix a head at a shared prefix's end (every "
+                          "token rewrites it whole: no page of it outlives a "
+                          "position)" if delta else ""))
     if kv_dtype == "int8":
         missing.append("kv_dtype='int8': quantise-on-write of the window "
                        "ring (the kernels' ring paths take no scale planes), "
@@ -218,7 +226,11 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                           "key AND value of every head: a per-vector scale "
                           "folds into neither contraction as the K/V "
                           "kernels' do) and the latent kernel mode that "
-                          "reads them" if latent else ""))
+                          "reads them" if latent else "")
+                       + ("; a delta state is float32 by its recurrence (the "
+                          "correction subtracts what the state already "
+                          "holds): 'int8' has no reading for it"
+                          if delta else ""))
     if max_spec_tokens:
         missing.append("speculation: a recurrent or matrix state, a closed "
                        "window or an appended index entry cannot be rolled "
@@ -226,7 +238,10 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "tree node"
                        + ("; a latent cache has no spec-tree buffers, commit "
                           "copy or tree-mask kernel over latents"
-                          if latent else ""))
+                          if latent else "")
+                       + ("; a delta state has no rollback at all: its "
+                          "update reads the state it changes, so a rejected "
+                          "token leaves nothing to invert" if delta else ""))
     if tp > 1:
         missing.append("tp > 1: a sharding rule for the conv, the scan, the "
                        "differential attention's head pairs, a plain ring's "
@@ -237,6 +252,10 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                           "absorbed heads (W_q, the per-head up-projections, "
                           "W_o's rows) with the latent cache replicated"
                           if latent else "")
+                       + ("; for the delta rule a rule that shards its heads "
+                          "(the fused projection's columns, the conv's "
+                          "channels, the state's head axis, W_o's rows)"
+                          if delta else "")
                        + ("; for the routed experts an exchange of rows "
                           "between the chips that hold them (here each "
                           "graph computes the experts it holds and nothing "
@@ -248,6 +267,7 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                        "state hand-over knows full-length K/V planes only"
                        + (" (not a latent cache's two planes)"
                           if latent else "")
+                       + (" (nor a delta state's matrices)" if delta else "")
                        + (" (nor does it carry the routed layers' load "
                           "counters out of a stage)" if routed else ""))
     if missing:

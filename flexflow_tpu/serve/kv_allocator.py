@@ -79,13 +79,21 @@ POSITION_NAMES = KV_BUFFER_NAMES | KV_LATENT_NAMES
 # per slot; its conv tail is ``recurrent`` like Mamba-1's.  Such a graph
 # keeps the PLAIN attention op's ``kv_full`` planes in some layers beside
 # slot state in others: both are allocated, joined and freed by slot.
+# ``delta_state`` is a delta-rule layer's matrix per head (KimiDeltaAttention:
+# ``[heads, head_dim, head_dim]`` float32, key channel x value channel):
+# fixed, priced per slot like the other two matrix states; its three short
+# convs' one tail is ``recurrent``.  ``kimi_linear`` keeps it in three layers
+# of four beside a LATENT cache (``kv_latent``, by position) in the fourth:
+# ``bytes_per_token`` prices the latent layers alone, ``fixed_bytes_per_slot``
+# the delta states and tails, and admission (``request_bytes``) both.
 # A model may keep BOTH: a plain ring (``kv_window``: SlidingWindowAttention)
 # in most layers and a full-length cache (``kv_full``) in the rest.  A
 # request's bytes are then a FIXED part (what its slot holds whatever its
 # context: ``FIXED_KINDS``) plus a PER-POSITION part (``bytes_per_token``),
 # and admission prices both (``request_bytes``).
 KV_INDEX_NAMES = frozenset({"kidx"})
-FIXED_KINDS = ("kv_window", "recurrent", "linear_state", "ssd_state")
+FIXED_KINDS = ("kv_window", "recurrent", "linear_state", "ssd_state",
+               "delta_state")
 STATE_KINDS = {
     "kv_full": KV_BUFFER_NAMES,
     "kv_latent": KV_LATENT_NAMES,
@@ -95,6 +103,7 @@ STATE_KINDS = {
     "kv_index": KV_INDEX_NAMES,
     "linear_state": frozenset({"lin"}),
     "ssd_state": frozenset({"ssd"}),
+    "delta_state": frozenset({"kda"}),
 }
 
 

@@ -1,6 +1,6 @@
 from . import (cohere2_moe, deepseek_v2, evabyte, falcon,  # noqa: F401
-               llama, minicpm_sala, mpt, nemotron_h, opt, phi4flash,
-               starcoder)
+               kimi_linear, llama, minicpm_sala, mpt, nemotron_h, opt,
+               phi4flash, starcoder)
 from .base import MODEL_REGISTRY, ServeModelConfig, build_model
 
 __all__ = ["MODEL_REGISTRY", "ServeModelConfig", "build_model"]

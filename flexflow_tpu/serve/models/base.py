@@ -165,6 +165,22 @@ class ServeModelConfig:
     topk_method: str = "greedy"
     n_group: int = 1
     topk_group: int = 1
+    # kimi_linear (``models/kimi_linear.py``): ``linear_attn_config`` holds the
+    # two 1-BASED lists that name every layer's mixer (``kda_layers``: Kimi
+    # Delta Attention; ``full_attn_layers``: latent attention, the
+    # ``deepseek_v2`` keys above) and the delta rule's ``num_heads``,
+    # ``head_dim`` and ``short_conv_kernel_size``; ``mla_use_nope``: the
+    # latent layers rotate nothing; the mixture is ``num_experts`` held of
+    # ``router_num_experts``, top ``num_experts_per_token`` by
+    # ``moe_router_activation_func`` with ``moe_renormalize``,
+    # ``num_expert_group`` / ``topk_group`` 1 (no group limit), beside
+    # ``num_shared_experts`` of the same width.
+    linear_attn_config: Optional[dict] = None
+    mla_use_nope: bool = False
+    num_experts_per_token: int = 1
+    moe_router_activation_func: str = "sigmoid"
+    moe_renormalize: bool = True
+    num_expert_group: int = 1
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

@@ -440,8 +440,13 @@ class MoEExperts(_Replicated):
       0.4 MB, weights 2.9 MB, output 0.5 MB: 8.0 MB.  (The widest
       lane-multiple DIVISOR of 1408 under the budget is 128: eleven tiles,
       each re-reading the row tile.)
+    * ``swiglu`` at 2304 x 1024 (kimi_linear): gate and up — ``c`` 2304, at
+      most 959 wide, so 2 tiles of 1024: 512: rows 0.6 MB, weights 2.4 MB,
+      output 0.26 MB: (0.6 + 2.4 + 0.26) x 2 + 0.26 = 6.7 MB; down — ``c``
+      1024, at most 1948, so 2 tiles of 2304: 1152: rows 0.26 MB, weights
+      2.4 MB, output 0.6 MB: 7.0 MB.
 
-    The TPU compiler takes all three (tests/test_tpu_aot_compile.py)."""
+    The TPU compiler takes all four (tests/test_tpu_aot_compile.py)."""
 
     type_name = "moe_experts"
     FORMS = ("relu2", "swiglu")

@@ -382,6 +382,85 @@ DEEPSEEK_V2_TENSORS = {
 }
 
 
+# ``kimi_linear`` (Kimi-Linear-48B-A3B: ``serve/models/kimi_linear.py``).
+# ASSUMED like the two tables above — the family's published convention, from
+# memory; no checkpoint is on this machine and NO import path is written.
+# Published ``model.layers.<l>.<name>`` -> (node under ``model.layers.<l>.``,
+# parameter, what to do).  A layer in ``linear_attn_config.kda_layers``
+# (1-based) carries the KDA tensors: its three projections go side by side
+# into ONE ``qkv_proj`` kernel (q | k | v) and its three depthwise convs
+# (torch ``[channels, 1, taps]``) into ONE conv ``[taps, 3 H D]``; a layer in
+# ``full_attn_layers`` carries the latent tensors under the SAME
+# ``self_attn.q_proj`` / ``self_attn.o_proj`` names with other shapes (keys
+# marked ``@latent`` here, as in benchmark/reference/kimi_linear.py).  Layer
+# ``l <= first_k_dense_replace`` has the dense ``mlp.{gate,up,down}_proj``;
+# the others ``block_sparse_moe``: the router ``gate`` with its
+# ``e_score_correction_bias``, the experts ``experts.<e>.{w1, w3, w2}`` (gate,
+# up, down) stacked into ``[E, in, out]`` and ONE ``shared_experts`` module.
+KIMI_LINEAR_TENSORS = {
+    "model.embed_tokens.weight": ("model.embed_tokens", "weight", "[V, d]"),
+    "model.norm.weight": ("model.norm", "gamma", "[d]"),
+    "lm_head.weight": ("lm_head", "kernel", "[d, V] = .T"),
+    "input_layernorm.weight": ("input_layernorm", "gamma", "[d]"),
+    "post_attention_layernorm.weight":
+        ("post_attention_layernorm", "gamma", "[d]"),
+    "self_attn.q_proj.weight":
+        ("self_attn.qkv_proj", "kernel", "[d, 3 H D] columns 0 .. H D = .T"),
+    "self_attn.k_proj.weight":
+        ("self_attn.qkv_proj", "kernel", "columns H D .. 2 H D = .T"),
+    "self_attn.v_proj.weight":
+        ("self_attn.qkv_proj", "kernel", "columns 2 H D .. 3 H D = .T"),
+    "self_attn.q_conv1d.weight":
+        ("self_attn.qkv_conv1d", "weight", "[taps, 3 H D] columns 0 .. H D "
+         "= [:, 0, :].T"),
+    "self_attn.k_conv1d.weight":
+        ("self_attn.qkv_conv1d", "weight", "columns H D .. 2 H D"),
+    "self_attn.v_conv1d.weight":
+        ("self_attn.qkv_conv1d", "weight", "columns 2 H D .. 3 H D"),
+    "self_attn.A_log": ("self_attn", "A_log", "[H] float32"),
+    "self_attn.dt_bias": ("self_attn", "dt_bias", "[H D] float32"),
+    "self_attn.f_a_proj.weight": ("self_attn", "f_a", "[d, D] = .T"),
+    "self_attn.f_b_proj.weight": ("self_attn", "f_b", "[D, H D] = .T"),
+    "self_attn.b_proj.weight": ("self_attn", "b_proj", "[d, H] = .T"),
+    "self_attn.g_a_proj.weight": ("self_attn", "g_a", "[d, D] = .T"),
+    "self_attn.g_b_proj.weight": ("self_attn", "g_b", "[D, H D] = .T"),
+    "self_attn.o_norm.weight": ("self_attn", "o_norm", "[D]"),
+    "self_attn.o_proj.weight": ("self_attn", "o_proj", "[H D, d] = .T"),
+    "self_attn.q_proj@latent.weight":
+        ("self_attn", "q_proj", "[d, H, nope + rope] = .T reshaped"),
+    "self_attn.kv_a_proj_with_mqa.weight":
+        ("self_attn", "kv_a", "[d, r + rope] = .T"),
+    "self_attn.kv_a_layernorm.weight": ("self_attn", "kv_norm", "[r]"),
+    "self_attn.kv_b_proj.weight":
+        ("self_attn", "kv_b", "[r, H, nope + v] = .T reshaped: U_k | U_v"),
+    "self_attn.o_proj@latent.weight":
+        ("self_attn", "o_proj", "[H v, d] = .T"),
+    "mlp.gate_proj.weight": ("mlp.gate_proj", "kernel", "[d, I] = .T"),
+    "mlp.up_proj.weight": ("mlp.up_proj", "kernel", "[d, I] = .T"),
+    "mlp.down_proj.weight": ("mlp.down_proj", "kernel", "[I, d] = .T"),
+    "block_sparse_moe.gate.weight":
+        ("block_sparse_moe.gate", "weight", "[d, experts] = .T, float32"),
+    "block_sparse_moe.gate.e_score_correction_bias":
+        ("block_sparse_moe.gate", "e_score_correction_bias",
+         "[experts] float32"),
+    "block_sparse_moe.experts.N.w1.weight":
+        ("block_sparse_moe.experts", "gate", "[N] = .T"),
+    "block_sparse_moe.experts.N.w3.weight":
+        ("block_sparse_moe.experts", "up", "[N] = .T"),
+    "block_sparse_moe.experts.N.w2.weight":
+        ("block_sparse_moe.experts", "down", "[N] = .T"),
+    "block_sparse_moe.shared_experts.gate_proj.weight":
+        ("block_sparse_moe.shared_experts.gate_proj", "kernel",
+         "[d, n f] = .T"),
+    "block_sparse_moe.shared_experts.up_proj.weight":
+        ("block_sparse_moe.shared_experts.up_proj", "kernel",
+         "[d, n f] = .T"),
+    "block_sparse_moe.shared_experts.down_proj.weight":
+        ("block_sparse_moe.shared_experts.down_proj", "kernel",
+         "[n f, d] = .T"),
+}
+
+
 def load_hf_model(name_or_path: str):
     """Load a local HF checkpoint (config + weights + tokenizer if present).
 

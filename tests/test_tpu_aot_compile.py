@@ -708,3 +708,109 @@ def test_nemotron_ssd_scan_compiles_for_v5e(one_chip, form):
     assert mem.alias_size_in_bytes >= state_bytes      # updated in place
     if form == "slot_order":
         assert mem.temp_size_in_bytes < state_bytes // 2
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["scan256", "chunk512"])
+def test_kimi_routed_layer_compiles_for_v5e(one_chip, rows):
+    """The routed-expert layer at Kimi-Linear's published widths (hidden
+    2304, a sigmoid router with its correction bias over 256 experts with
+    top-8 renormalised x 2.446, 32 HELD gated experts of width 1024) on the
+    decode scan's 256 rows and on a prompt chunk's 512: the tiles
+    ``MoEExperts.out_tile`` plans from the shapes — 512 of 1024 into the
+    hidden width, 1152 of 2304 out of it, two tiles either way."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.ssd_moe_ops import (MoECombine, MoEDispatch,
+                                                MoEExperts, MoERouter)
+
+    d, f, held, scored, k = 2304, 1024, 32, 256, 8
+    assert (MoEExperts.out_tile(d, f, 2), MoEExperts.out_tile(f, d, 2)) == \
+        (512, 1152)
+
+    def layer(x, router, bias, gate, up, down):
+        ctx = lambda: OpContext(extras={"node_name": "n",
+                                        "pallas_decode": True})
+        ids, w = MoERouter(d, scored, k, 2.446, dtype=x.dtype).lower(
+            ctx(), [x], {"weight": router, "e_score_correction_bias": bias})
+        xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
+            ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
+        return MoECombine(held, dtype=x.dtype).lower(
+            ctx(), [ys, order, ids, w], {})[0]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((d, scored), jnp.float32),
+        sds((scored,), jnp.float32), sds((held, d, f), jnp.bfloat16),
+        sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("form", ["step_kernel", "chunked"])
+def test_kimi_delta_attention_compiles_for_v5e(one_chip, form):
+    """``KimiDeltaAttention`` at the published widths (32 heads of 128 x 128
+    float32: 257 state rows of 2 MB): the decode scan's 256 rows through the
+    step kernel ``delta_rule_step`` — ONE Mosaic kernel, the 539 MB state
+    array aliased in and out and no second array of its size among the
+    temporaries — and a prompt chunk of 512 rows in the chunked form, the
+    state updated in place there too."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.batch_config import BatchConfig
+    from flexflow_tpu.serve.hybrid_ops import KimiDeltaAttention
+
+    e, h, d, slots = 2304, 32, 128, 256
+    rows = slots if form == "step_kernel" else 512
+    op = KimiDeltaAttention(e, h, d, dtype=jnp.bfloat16)
+    names = [p.name for p in op.params()]
+
+    def mix(qkv, x, kda, request_index, position, *weights):
+        bc = BatchConfig(tokens=position, request_index=request_index,
+                         token_position=position,
+                         num_tokens=jnp.int32(rows),
+                         seq_lens=jnp.zeros((slots,), jnp.int32))
+        ctx = OpContext(extras={
+            "node_name": "n", "batch_config": bc, "state": {"kda": kda},
+            "pallas_decode": True,
+            "one_row_per_request": form == "step_kernel"})
+        y = op.lower(ctx, [qkv, x], dict(zip(names, weights)))[0]
+        return y, ctx.extras["state_out"]["kda"]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    weights = [sds(p.spec.shape, p.spec.dtype) for p in op.params()]
+    compiled = jax.jit(mix, donate_argnums=(2,)).lower(
+        sds((rows, 3 * h * d), jnp.bfloat16), sds((rows, e), jnp.bfloat16),
+        sds((slots + 1, h, d, d), jnp.float32), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), *weights).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = (slots + 1) * h * d * d * 4
+    assert mem.alias_size_in_bytes >= state_bytes      # updated in place
+    assert mem.temp_size_in_bytes < state_bytes // 2
+    kernels = compiled.as_text().count("tpu_custom_call")
+    assert kernels == (1 if form == "step_kernel" else 0)
+
+
+@pytest.mark.parametrize("rows", [256, 512], ids=["scan256", "flat512"])
+def test_latent_decode_kernel_on_32_heads_compiles_for_v5e(one_chip, rows):
+    """The latent decode kernel at Kimi-Linear's latent layer: 32 query
+    heads (twice DeepSeek-V2-Lite's head rows) on one latent of 512 beside a
+    plain second key plane of 64, 257 cache rows of 10 240 positions, bf16;
+    the decode scan's 256 rows and a flat step's 512: one Mosaic kernel,
+    one cache-sized operand a plane."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    slots, s, h, r, dr = 256, 10240, 32, 512, 64
+    call = lambda q, ckv, rw, ps, qr, kpe: decode_attention(
+        q, ckv, None, rw, ps, scale=192 ** -0.5, q_rope=qr, k_rope=kpe)
+    shapes = (
+        sds((rows, h, r), jnp.bfloat16),
+        sds((slots + 1, 1, s, r), jnp.bfloat16),
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+        sds((rows, h, dr), jnp.bfloat16),
+        sds((slots + 1, 1, s, dr), jnp.bfloat16))
+    kernel, = _pallas_calls(jax.make_jaxpr(call)(*shapes).jaxpr)
+    assert kernel.params["jaxpr"].debug_info.func_name == \
+        "_latent_decode_kernel"
+    text = jax.jit(call).lower(*shapes).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    line = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+            and "custom-call(" in ln][0]
+    assert line.count(f"bf16[{slots + 1},1,{s},{r}]") == 1, line[:400]
