@@ -166,7 +166,16 @@ class CausalConv1d(_SlotStateOp):
     convs carry one, ``kimi_linear``'s three short convs over q | k | v do
     not, and then the op has no such parameter).  Input/output
     ``[max_tokens, channels]``; state ``conv [max_requests + 1, K - 1,
-    channels]``: the last ``K - 1`` inputs of each slot."""
+    channels]``: the last ``K - 1`` inputs of each slot.
+
+    A prompt chunk, a flat step or a spec scan goes by ROWS: each row
+    gathers its slot's tail and picks every tap from it or from the rows
+    before it, and a segment's last row writes the new tail back by index.
+    The decode scan (``one_row_per_request``: every live row a request of
+    its own) steps the tails in SLOT ORDER, where they lie.  Both sum the
+    same float32 taps in the same order: the tails they leave are equal to
+    the bit, and ``y`` is wherever the backend rounds the two fusions
+    alike."""
 
     type_name = "causal_conv1d"
 
@@ -196,12 +205,48 @@ class CausalConv1d(_SlotStateOp):
     def flops(self, in_specs):
         return 2 * self.kernel * in_specs[0].size
 
-    def lower(self, ctx, inputs, params):
-        bc, state = _require(ctx, self.type_name)
-        x = inputs[0]
-        tails = state["conv"]
+    # ---- the two forms ----------------------------------------------------
+    def _taps_out(self, taps, w, b):
+        """``silu(b + sum_back w[K-1-back] * taps[back])`` in float32, the
+        newest tap first: ONE order of summation for both forms, so that
+        they round alike."""
+        y = b.astype(jnp.float32) if self.bias else 0.0
+        w = w.astype(jnp.float32)
+        for back, tap in enumerate(taps):
+            y = y + tap.astype(jnp.float32) * w[self.kernel - 1 - back]
+        return jax.nn.silu(y).astype(self.dtype)
+
+    def _slot_order(self, x, tails, seg, w, b):
+        """The decode scan's step: every slot's tail read, shifted and
+        written where a row of the batch is its request's, untouched where
+        none is — ONE pass over the tails where they lie, no gather of every
+        row's tail, no pick per tap and no scatter of the new tails back."""
+        nslot, k = tails.shape[0], self.kernel
+        at = seg.rows                      # pads land on the scratch row
+        # each slot's row of the batch (row 0 where none is: masked below),
+        # so that the rows come to their slots by a GATHER — on the chip a
+        # scatter of as many rows costs twice as much — and its position
+        # this step, -1 where no live row is its request's
+        row = jnp.zeros((nslot,), jnp.int32).at[at].set(
+            jnp.arange(x.shape[0], dtype=jnp.int32))
+        pos = jnp.full((nslot,), -1, seg.pos.dtype).at[at].set(
+            jnp.where(seg.live, seg.pos, -1))
+        xs = x[row]
+        # taps[back] = the input ``back`` positions before the row's own:
+        # the slot's stored tail, or (before the request began) zero
+        taps = [xs] + [jnp.where((pos >= back)[:, None],
+                                 tails[:, k - 1 - back], 0)
+                       for back in range(1, k)]
+        y = self._taps_out(taps, w, b)
+        with jax.named_scope("state_write"):
+            tails = jnp.where((pos >= 0)[:, None, None],
+                              jnp.stack(taps[k - 2::-1], axis=1), tails)
+        return y[at], tails
+
+    def _rows(self, x, tails, seg, w, b):
+        """A prompt chunk, a flat step or a spec scan: each row gathers its
+        slot's tail, and a segment's last row writes the new one back."""
         k = self.kernel
-        seg = Segments(_flat(bc), tails.shape[0] - 1)
         tail = tails[seg.rows]                       # [T, K-1, C]
         # taps[j] = the input j positions back from the row's own:
         # a row of this step where the segment reaches that far, else the
@@ -215,18 +260,30 @@ class CausalConv1d(_SlotStateOp):
             val = jnp.where((seg.offset >= back)[:, None], here, stored)
             taps.append(jnp.where(
                 ((seg.pos >= back) & seg.live)[:, None], val, 0))
-        w = params["weight"].astype(jnp.float32)
-        y = params["bias"].astype(jnp.float32) if self.bias else 0.0
-        for back, tap in enumerate(taps):
-            y = y + tap.astype(jnp.float32) * w[k - 1 - back]
-        y = jax.nn.silu(y)
+        y = self._taps_out(taps, w, b)
         with jax.named_scope("state_write"):
             # what the segment leaves behind: the K-1 newest inputs as of
             # its last row (oldest first, as the tail is read)
             left = jnp.stack(taps[k - 2::-1], axis=1)
-            ctx.extras["state_out"] = {
-                "conv": _set_rows(tails, seg.store, left)}
-        return [y.astype(self.dtype)]
+            tails = _set_rows(tails, seg.store, left)
+        return y, tails
+
+    def lower(self, ctx, inputs, params):
+        bc, state = _require(ctx, self.type_name)
+        tails = state["conv"]
+        seg = Segments(_flat(bc), tails.shape[0] - 1)
+        if ctx.extras.get("one_row_per_request"):
+            form, path, batch = (self._slot_order, "slot_order",
+                                 "one_row_per_request")
+        else:
+            form, path, batch = self._rows, "rows", type(bc).__name__
+        y, tails = form(inputs[0], tails, seg, params["weight"],
+                        params.get("bias"))
+        paths = ctx.extras.get("attention_paths")
+        if paths is not None:
+            paths[(self.type_name, batch)] = path
+        ctx.extras["state_out"] = {"conv": tails}
+        return [y]
 
 
 @register_op

@@ -255,8 +255,14 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert paths[("kimi_delta_attention", "PrefillBatchConfig")] == "chunked"
     kinds = {k for k, _ in paths}
     assert kinds - {"kv_block_write", "kv_row_write"} == {
-        "kimi_delta_attention", "latent_attention", "moe_experts"} \
+        "kimi_delta_attention", "latent_attention", "moe_experts",
+        "causal_conv1d"} \
         | ({"decode_block"} if use_pallas else set())
+    # the conv's two forms: the decode scans step the tails in slot order,
+    # the prompt's chunks and the flat steps go by rows
+    assert {b: p for (k, b), p in paths.items() if k == "causal_conv1d"} == {
+        "one_row_per_request": "slot_order", "PrefillBatchConfig": "rows",
+        "BatchConfig": "rows"}
 
 
 def test_flat_rows_of_several_requests_go_by_segments():
@@ -751,6 +757,8 @@ def test_spans_counters_and_the_ledger_name_the_new_state():
         assert counters["attention_path.kimi_delta_attention.chunked"] >= 1
         assert counters["attention_path.kimi_delta_attention.xla_rows"] >= 1
         assert counters["attention_path.latent_attention.xla_absorbed"] >= 1
+        assert counters["attention_path.causal_conv1d.slot_order"] >= 1
+        assert counters["attention_path.causal_conv1d.rows"] >= 1
         scans = [e["args"] for e in tel.trace.trace_events()
                  if e["name"] == "decode_scan_dispatch"]
         assert scans and all(a["ctx_sum"] > 0 and a["rows"] == 2

@@ -292,7 +292,13 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert paths.pop(
         ("kv_row_write", "one_row_per_request")) == (
         "pallas" if use_pallas else "dus_chain")
-    assert {k for k, _ in paths} == {"mamba2_scan", "moe_experts"}
+    assert {k for k, _ in paths} == {"mamba2_scan", "moe_experts",
+                                     "causal_conv1d"}
+    # the conv's two forms: the decode scans step the tails in slot order,
+    # the prompt's chunks and the flat steps go by rows
+    assert {b: p for (k, b), p in paths.items() if k == "causal_conv1d"} == {
+        "one_row_per_request": "slot_order", "PrefillBatchConfig": "rows",
+        "BatchConfig": "rows"}
     assert paths[("mamba2_scan", "PrefillBatchConfig")] == "chunked"
 
 
@@ -654,6 +660,110 @@ def test_chunked_form_and_slot_order_equal_the_recurrence():
     run([2, -1, 0], [9, 0, 0], True)
 
 
+# ---- the conv alone --------------------------------------------------------
+def _conv_batch(layout, rng):
+    """``(slots, request_index, positions)`` of a one-row-per-request
+    batch; ``-1`` rows are pads."""
+    if layout == "pads_before_between_and_after":
+        slots, rows = 40, np.full(32, -1)
+        live = np.r_[3:11, 14:21, 24:27]
+        rows[live] = rng.permutation(slots)[:live.size]
+    elif layout == "rows_out_of_slot_order":
+        slots, rows = 32, np.arange(32)[::-1].copy()
+        rows[[5, 6]] = rows[[6, 5]]
+    elif layout == "one_live_row":
+        slots, rows = 32, np.full(32, -1)
+        rows[17] = 9
+    elif layout == "256_rows":       # over DUS_MAX_TOKENS: the row form
+        slots = 256                  # writes back by one scatter
+        rows = rng.permutation(slots)
+        rows[[0, 100, 255]] = -1
+    else:
+        raise ValueError(layout)
+    # positions 0, 1, 2 on live rows: taps before the request began
+    pos = rng.integers(0, 7, size=rows.size)
+    return slots, rows.astype(np.int32), pos.astype(np.int32)
+
+
+@pytest.mark.parametrize("operands", ["bfloat16", "float32"])
+@pytest.mark.parametrize("kernel,bias", [(4, True), (4, False), (3, True),
+                                         (2, False)],
+                         ids=["k4_bias", "k4", "k3_bias", "k2"])
+@pytest.mark.parametrize("layout", [
+    "pads_before_between_and_after", "rows_out_of_slot_order",
+    "one_live_row", "256_rows"])
+def test_the_convs_slot_order_form_equals_its_row_form(layout, kernel, bias,
+                                                       operands):
+    """``CausalConv1d``'s two forms on one decode-scan batch (every live row
+    a request of its own; 32 rows — the row form's chain of update-slices —
+    and 256 — its scatter): every slot's stored tail holds a PREVIOUS
+    request's values, so a row at position 0, 1 or 2 shows the zeros.  The
+    tails left (pure copies) are equal TO THE BIT on every slot, touched or
+    not; ``y`` before the cast to the op's type (the op is built in float32
+    over operands of the given type) is equal to the bit over bf16 operands,
+    the deployments', and to float32 rounding over float32 ones: the taps
+    are summed in ONE order, but this backend contracts a product and a sum
+    into one rounding in some fusions and not in others.  The scratch row
+    is any pad's to write and is not compared."""
+    rng = np.random.default_rng([SEED, 97, kernel, bias])
+    slots, rows, pos = _conv_batch(layout, rng)
+    c, t = 48, rows.size
+    op = CausalConv1d(c, kernel, dtype=jnp.float32, bias=bias)
+    draw = lambda *shape: jnp.asarray(
+        rng.normal(size=shape).astype(np.float32), operands)
+    x, tails = draw(t, c), draw(slots + 1, kernel - 1, c)
+    params = {"weight": draw(kernel, c)}
+    if bias:
+        params["bias"] = draw(c)
+    bc = BatchConfig(tokens=jnp.zeros((t,), jnp.int32),
+                     request_index=jnp.asarray(rows),
+                     token_position=jnp.asarray(pos),
+                     num_tokens=jnp.int32((rows >= 0).sum()),
+                     seq_lens=jnp.zeros((slots,), jnp.int32))
+
+    @functools.partial(jax.jit, static_argnums=0)
+    def run(one_row, x, tails, params):
+        paths = {}
+        ctx = _ctx({"batch_config": bc, "state": {"conv": tails},
+                    "one_row_per_request": one_row,
+                    "attention_paths": paths})
+        y = op.lower(ctx, [x], params)[0]
+        assert paths == {("causal_conv1d", "one_row_per_request" if one_row
+                          else "BatchConfig"):
+                         "slot_order" if one_row else "rows"}
+        return y, ctx.extras["state_out"]["conv"]
+
+    (y_rows, left_rows), (y_slots, left_slots) = (
+        jax.tree.map(np.asarray, run(one, x, tails, params))
+        for one in (False, True))
+    live = rows >= 0
+    assert left_slots.dtype == left_rows.dtype == tails.dtype
+    np.testing.assert_array_equal(left_slots[:slots].view(np.uint8),
+                                  left_rows[:slots].view(np.uint8))
+    # ... and they are what the definition says, not only each other
+    x32, tails32, w, b = (np.asarray(a, np.float32) for a in (
+        x, tails, params["weight"], params.get("bias", 0.0)))
+    want = tails32.copy()
+    back = np.arange(kernel - 2, -1, -1)            # of tail entry j
+    for r in np.flatnonzero(live):
+        new = np.concatenate([tails32[rows[r], 1:], x32[r][None]])
+        new[pos[r] < back] = 0.0
+        want[rows[r]] = new
+    np.testing.assert_array_equal(
+        left_slots[:slots].astype(np.float32), want[:slots])
+    # each form rounds K + 1 times, by half a unit of a partial sum at most
+    size = np.abs(x32 * w[-1]) + np.abs(b) + np.abs(
+        tails32[np.where(live, rows, 0)] * w[:-1]).sum(axis=1)
+    assert np.abs(y_rows).max() > 0.1
+    gap = np.abs(y_slots[live] - y_rows[live])
+    assert (gap <= (kernel + 2) * 2.0 ** -23 * size[live]).all(), \
+        (gap / size[live]).max() * 2.0 ** 23
+    if operands == "bfloat16":
+        # the deployments' type: a bf16 x bf16 product is exact in float32,
+        # so contracted or not it rounds once — equal to the bit
+        assert not gap.any()
+
+
 # ---- what the manager, the allocator and the planner make of it -------------
 def test_bytes_per_slot_and_the_plan_against_the_hand_formula():
     """Two M layers: 8 heads x 8 x 16 float32 of state and a conv tail of
@@ -756,6 +866,8 @@ def test_the_scans_load_counters_reach_the_span_the_journal_and_the_trace():
         assert any(e["name"] == "moe.pairs" and e["ph"] == "C"
                    for e in tel.trace.trace_events())
         assert counters["attention_path.mamba2_scan.slot_order"] >= 1
+        assert counters["attention_path.causal_conv1d.slot_order"] >= 1
+        assert counters["attention_path.causal_conv1d.rows"] >= 1
         assert counters["attention_path.moe_experts.ragged_dot"] >= 1
         # every scan step ran both rows (no row ends before the other);
         # the first token of each answer is the prefill's

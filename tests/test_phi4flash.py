@@ -274,8 +274,14 @@ def test_the_harness_drive_is_correct(use_pallas):
         "pallas" if use_pallas else "dus_chain")
     kinds = {k for k, _ in im.attention_paths}
     assert kinds == {"window_attention", "full_attention", "cross_attention",
-                     "selective_scan"} | (
+                     "selective_scan", "causal_conv1d"} | (
                          {"decode_block"} if use_pallas else set())
+    # the conv's two forms: the decode scans step the tails in slot order,
+    # the prompt's chunks and the flat steps go by rows
+    assert {b: p for (k, b), p in im.attention_paths.items()
+            if k == "causal_conv1d"} == {
+        "one_row_per_request": "slot_order", "PrefillBatchConfig": "rows",
+        "BatchConfig": "rows"}
     assert scan_path(im, "one_row_per_request") == "rows_at_once"
     if use_pallas:
         assert im.attention_paths[
@@ -295,7 +301,7 @@ def test_the_harness_drive_is_correct(use_pallas):
         assert blocks["full_attention"].startswith("full")
     else:
         assert {p for (k, _), p in im.attention_paths.items()
-                if k != "selective_scan"} == {"xla"}
+                if k not in ("selective_scan", "causal_conv1d")} == {"xla"}
         assert scan_path(im) == "row_scan"
 
 
@@ -452,6 +458,8 @@ def test_memory_ledger_and_path_counters_tell_the_kinds_apart(use_pallas):
                 assert counters[f"attention_path.{kind}_attention.xla"] >= 1
             assert counters["attention_path.selective_scan.row_scan"] >= 1
         assert counters["attention_path.selective_scan.rows_at_once"] >= 1
+        assert counters["attention_path.causal_conv1d.slot_order"] >= 1
+        assert counters["attention_path.causal_conv1d.rows"] >= 1
         resets = [e["args"]["state_reset"] for e in tel.trace.trace_events()
                   if e["name"] == "host_admit"
                   and "state_reset" in e.get("args", {})]

@@ -476,6 +476,73 @@ def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
         assert not kernels and loops and writes
 
 
+@pytest.mark.parametrize("slots", [32, 160], ids=["scan32", "scan160"])
+def test_sambay_decode_scan_steps_the_conv_tails_where_they_lie(one_chip,
+                                                                slots):
+    """The decode-scan program of the same toy SambaY, whole, for the
+    described chip, on 32 rows and on 160 (either side of
+    ``DUS_MAX_TOKENS``): under a ``CausalConv1d`` scope NO array of
+    channels is written by index — no ``dynamic-update-slice`` at all, no
+    scatter but the two of one ``int32`` a slot (each slot's row and
+    position; the step's rows come to their slots by a gather of
+    ``[slots + 1, C]``, a third of a tail) — and no tail is gathered.  The
+    flat step (the row form) is the control: it holds the gather and the
+    indexed write-back, so the reading can see them."""
+    import numpy as np
+
+    from flexflow_tpu.config import FFConfig
+    from flexflow_tpu.model import FFModel
+    from flexflow_tpu.parallel.mesh import make_mesh
+    from flexflow_tpu.serve.batch_config import BatchConfig
+    from flexflow_tpu.serve.inference_manager import InferenceManager
+    from flexflow_tpu.serve.models.base import (ServeModelConfig,
+                                                build_model)
+
+    cap = 256
+    ff = FFModel(FFConfig(), mesh=make_mesh({"tp": 1}, jax.devices()[:1]))
+    build_model(ff, ServeModelConfig.from_hf_config(_SAMBAY), cap)
+    im = InferenceManager(ff, max_requests=slots, max_tokens_per_batch=cap,
+                          max_seq_len=1024, use_pallas=True)
+    im.init_operators_inference()
+    im.pallas_interpret = False     # as in the prefill-scan case above
+    bc = BatchConfig.build([5] * slots, list(range(slots)), [40] * slots,
+                           [41] * slots, max_tokens=cap, max_requests=slots)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
+                                       sharding=one_chip),
+        (im.params, im.state, bc))
+    tails = {tuple(bufs["conv"].shape) for bufs in im.state.values()
+             if "conv" in bufs}
+    assert tails == {(slots + 1, 3, 128)}
+
+    def conv_lines(text):
+        """What the program does by index under a conv node's scope:
+        (update-slices, scatters of anything but one ``int32`` a slot,
+        gathers of tails)."""
+        conv = [ln for ln in text.splitlines() if "/CausalConv1d." in ln]
+        made = lambda ln: ln.partition(" = ")[2].partition("(")[0]
+        dus = [ln for ln in conv if " dynamic-update-slice(" in ln]
+        scatters = [ln for ln in conv if " scatter(" in ln
+                    and not made(ln).startswith("s32[%d]" % (slots + 1))]
+        gathers = [ln for ln in conv if " gather(" in ln
+                   and ",3,128]" in made(ln)]
+        return conv, dus, scatters, gathers
+
+    scan = jax.jit(im._decode_scan_impl, static_argnames=("n_steps", "eos"),
+                   donate_argnums=(1,)).lower(
+        *args, None, None, None, n_steps=4, eos=None).compile().as_text()
+    conv, dus, scatters, gathers = conv_lines(scan)
+    assert conv and not dus and not scatters and not gathers, \
+        (dus[:1], scatters[:1], gathers[:1])
+    assert im.attention_paths[
+        ("causal_conv1d", "one_row_per_request")] == "slot_order"
+    step = jax.jit(im._step_impl, donate_argnums=(1,)).lower(
+        *args).compile().as_text()
+    conv, dus, scatters, gathers = conv_lines(step)
+    assert gathers and (dus or scatters)
+    assert im.attention_paths[("causal_conv1d", "BatchConfig")] == "rows"
+
+
 @pytest.mark.parametrize("rows", [256, 512], ids=["scan256", "chunk512"])
 def test_nemotron_routed_layer_compiles_for_v5e(one_chip, rows):
     """The routed-expert layer at Nemotron-3-Nano's published widths (hidden
