@@ -8,7 +8,11 @@ keeps the tick's wall time split by span (self time: a span's duration
 less what its child spans cover), what the tick launched, the tokens it
 committed and three process counters — and when a serving loop returns it
 reports the ticks that took over three times their class's median, with
-what the host was doing in them.
+what the host was doing in them — and what it was waiting FOR: the device
+(``device_wait``, the span in which a readback blocks until its last
+result is ready, apart from the copies ``readback`` then makes) and the
+compiler (the build log below: every trace, lowering and backend compile
+JAX reports, by program, as an overlay on the record it fell into).
 
 It is the third consumer of :class:`~.trace.Span` (``jr=``, beside
 ``rec`` and ``prof``): fed by the entries and exits of the spans that are
@@ -19,7 +23,8 @@ profiler's host plane join on that number.
 
 Host-side only: integers the scheduler already holds, two clock reads a
 span, nothing waits for the device and nothing enters a jitted program.
-Measured cost: PERF.md section 6 (PR 46).
+Measured cost: PERF.md section 6 (PR 46; ``device_wait`` and the build
+log: PR 59).
 
 Records abut: a record ends where the next begins (the entry of
 ``loop_arrivals`` in ``serve_with_arrivals``, :meth:`TickJournal.begin` in
@@ -35,8 +40,11 @@ import itertools
 import logging
 import resource
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+import weakref
+from typing import (Callable, Dict, Iterable, List, NamedTuple, Optional,
+                    Sequence)
 
+import jax.monitoring
 import numpy as np
 
 # the tick spans (they carry ``pc_ns``): a record's ``kind`` is the index
@@ -49,7 +57,8 @@ KINDS = ("idle", "prefill_stretch", "decode_stretch", "serve_step", "other")
 # speculative phases), is ``unattributed_ns``
 SPLIT = ("host_admit", "host_prepare", "kv_prepare", "sample_for",
          "batch_sync", "join", "step_dispatch", "decode_scan_dispatch",
-         "prefill_scan_dispatch", "join_dispatch", "readback", "commit",
+         "prefill_scan_dispatch", "join_dispatch", "device_wait", "readback",
+         "commit",
          "loop_arrivals", "loop_bookkeep", "loop_idle", "loop_clock")
 # what ``Span.set`` — or an argument a span below the tick is entered
 # with — adds to a record, by argument name
@@ -81,6 +90,10 @@ FIELDS = (
     # beside ``ctx_sum``, of the same launch: the positions its rows' plain
     # RING layers read, sum of min(context, window) (0: a graph without)
     "ring_ctx_sum",
+    # the build log's events that arrived while the record was open, and
+    # their seconds: an OVERLAY (the time already lies under the launch
+    # span that triggered the build), not part of the split
+    "builds", "build_ns",
 )
 _F = {name: i for i, name in enumerate(FIELDS)}
 _SPLIT_AT = {n: _F[f"{n}_ns"] for n in SPLIT}
@@ -91,15 +104,16 @@ _SUMMED = [i for i, n in enumerate(FIELDS)
            if n not in ("seq", "t0_ns", "t1_ns", "tick_ns", "kind", "pending",
                         "live", "ctx_sum", "ctx_rows", "ring_ctx_sum")]
 (_SEQ, _T0, _T1, _TICK, _KIND, _POLLS, _UNATT, _PENDING, _LIVE, _CPU, _NIV,
- _MAJ) = (_F[n] for n in (
+ _MAJ, _BUILDS, _BUILD_NS) = (_F[n] for n in (
      "seq", "t0_ns", "t1_ns", "tick_ns", "kind", "polls", "unattributed_ns",
-     "pending", "live", "cpu_ns", "nivcsw", "majflt"))
+     "pending", "live", "cpu_ns", "nivcsw", "majflt", "builds", "build_ns"))
 _SPLIT_LO, _SPLIT_HI = _F[f"{SPLIT[0]}_ns"], _F[f"{SPLIT[-1]}_ns"] + 1
 
 # the slow-tick report: constants, not options
 SLOW_FACTOR = 3       # an outlier lasts more than this many class medians
 SLOW_CLASS_MIN = 8    # a class needs this many records to have a median
 SLOW_LINES = 4        # lines a loop's report may write
+SLOW_BUILT = 3        # builds a line names, longest first
 _RUSAGE = getattr(resource, "RUSAGE_THREAD", resource.RUSAGE_SELF)
 _LOG = logging.getLogger("flexflow_tpu.serve")
 
@@ -197,16 +211,146 @@ def as_dict(row: Sequence[int]) -> Dict:
     return rec
 
 
+class Build(NamedTuple):
+    """One event of the build log."""
+    t_ns: int        # the log's clock when the event arrived (its END)
+    what: str        # "trace" | "lower" | "compile"
+    fun_name: str    # the program, as ``jax.jit`` names it ("?": unnamed)
+    dur_ns: int
+    cached: bool     # a compile the persistent cache answered
+
+
+# JAX's duration events that the log keeps, by the ``what`` it files them
+# under (jax 0.9.0: jax/_src/dispatch.py); ``compile`` holds the cache
+# read on a hit
+BUILD_WHATS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+BUILD_LOG_CAPACITY = 1024
+
+
+class BuildLog:
+    """What the process waited on the compiler for: every trace, lowering
+    and backend compile ``jax.monitoring`` reports, newest
+    ``BUILD_LOG_CAPACITY`` kept (``dropped`` counts the rest), in every
+    run — a listener costs nothing until JAX builds something.  One a
+    process (:func:`build_log`); each live journal's open record counts
+    the events that arrive under it (``builds`` / ``build_ns``).
+
+    ``fun_name`` is JAX's keyword with the ``jit(...)`` its lowering and
+    compile events wrap the name in taken off, so a program has ONE name
+    over its three events (``_decode_scan_impl``).
+
+    Only the OUTERMOST build is an event: the trace of a program holds a
+    trace of every jitted function it calls (``add``, ``multiply``: 30
+    such for one lowering in a toy run of the benchmark, 6 400 events a
+    run), whose seconds the program's own already hold.  JAX reports a
+    build's START as a scalar under the event's name, so the log knows
+    how many are open; one that ends while another is open is dropped
+    unseen.  A process's builds are then hundreds, and the seconds of any
+    set of events add up without counting a second twice.
+    """
+
+    def __init__(self, capacity: int = BUILD_LOG_CAPACITY):
+        self.events: collections.deque = collections.deque(maxlen=capacity)
+        self.emitted = 0      # lifetime count, dropped events too
+        self.clock_ns = time.perf_counter_ns   # the journal's default clock
+        self.journals: "weakref.WeakSet[TickJournal]" = weakref.WeakSet()
+        self._hit = False     # a cache hit arrived since the last compile
+        self._open = 0        # builds that have started and not ended
+
+    @property
+    def dropped(self) -> int:
+        return self.emitted - len(self.events)
+
+    def since(self, n: int) -> List[Build]:
+        """The kept events from the ``n``-th the log ever took on."""
+        return list(itertools.islice(
+            self.events, max(n - self.dropped, 0), None))
+
+    def _on_event(self, event: str, **_) -> None:
+        if event == _CACHE_HIT:
+            self._hit = True
+
+    def _on_scalar(self, event: str, value, **_) -> None:
+        if event in BUILD_WHATS:
+            self._open += 1
+
+    def _on_duration(self, event: str, secs: float, **kw) -> None:
+        what = BUILD_WHATS.get(event)
+        if what is None:
+            return
+        self._open = max(self._open - 1, 0)
+        if self._open:
+            return   # inside another build, whose seconds hold this one's
+        name = str(kw.get("fun_name") or "?")
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]
+        cached = what == "compile" and self._hit
+        if what == "compile":
+            self._hit = False
+        dur = int(secs * 1e9)
+        self.events.append(Build(self.clock_ns(), what, name, dur, cached))
+        self.emitted += 1
+        for jr in self.journals:
+            row = jr._row
+            if row is not None:
+                row[_BUILDS] += 1
+                row[_BUILD_NS] += dur
+
+
+_BUILD_LOG: Optional[BuildLog] = None
+
+
+def build_log() -> BuildLog:
+    """THE log of this process; the first call registers its listeners
+    with ``jax.monitoring`` (there is no unregistering: once)."""
+    global _BUILD_LOG
+    if _BUILD_LOG is None:
+        _BUILD_LOG = BuildLog()
+        jax.monitoring.register_event_listener(_BUILD_LOG._on_event)
+        jax.monitoring.register_scalar_listener(_BUILD_LOG._on_scalar)
+        jax.monitoring.register_event_duration_secs_listener(
+            _BUILD_LOG._on_duration)
+    return _BUILD_LOG
+
+
+def builds(programs: Optional[Iterable[str]] = None,
+           before_ns: Optional[int] = None,
+           after_ns: Optional[int] = None) -> List[Build]:
+    """The build log's events, oldest first: those of ``programs`` (all),
+    stamped at or before ``before_ns`` and at or after ``after_ns`` on
+    ``time.perf_counter_ns``."""
+    names = None if programs is None else set(programs)
+    return [b for b in build_log().events
+            if (names is None or b.fun_name in names)
+            and (before_ns is None or b.t_ns <= before_ns)
+            and (after_ns is None or b.t_ns >= after_ns)]
+
+
 def slow_line(rec: Dict, loop_t0_ns: int) -> str:
     """One slow tick (a record of :meth:`TickJournal.slowest`) in one line:
     when, how long against its class, the split, the process counters, the
-    backlog and the launches."""
+    backlog, the launches and — where there are any — what the NEXT record
+    waited for the device (as usual: the device is healthy again; long
+    too: the stall outlived the tick) and what was built under it."""
     split = sorted(((rec[f"{n}_ns"], n) for n in SPLIT), reverse=True)
     split_s = " ".join(f"{n} {v / 1e6:.1f}" for v, n in split if v)
     launches = " ".join(
         f"{n} {rec[n]}" for n in ("decode_scans", "decode_steps",
                                   "prefill_scans", "chunks",
                                   "step_launches", "joins") if rec[n])
+    tail = ""
+    if rec.get("next_device_wait_ns") is not None:
+        tail += f"; next device_wait {rec['next_device_wait_ns'] / 1e6:.1f}"
+    built = sorted(rec.get("built", ()), key=lambda b: -b.dur_ns)
+    if built:
+        tail += "; built: " + ", ".join(
+            f"{b.fun_name} {b.what} {b.dur_ns / 1e6:.1f}"
+            + (" cached" if b.cached else "") for b in built[:SLOW_BUILT])
     return (
         f"slow tick: {rec['kind']} at "
         f"{(rec['t0_ns'] - loop_t0_ns) / 1e9:.3f}s into the loop took "
@@ -215,7 +359,7 @@ def slow_line(rec: Dict, loop_t0_ns: int) -> str:
         f"unattributed {rec['unattributed_ns'] / 1e6:.1f}; "
         f"cpu {rec['cpu_ns'] / 1e6:.1f} ms nivcsw {rec['nivcsw']} "
         f"majflt {rec['majflt']}; pending {rec['pending']} "
-        f"live {rec['live']}; launches: {launches or 'none'}")
+        f"live {rec['live']}; launches: {launches or 'none'}{tail}")
 
 
 class TickJournal:
@@ -227,7 +371,7 @@ class TickJournal:
     counts them.  4096 holds twenty runs of the benchmark's shortest-tick
     cell (``opt-6.7b-d12.decode-heavy``: 189 records for warm-up,
     rehearsal and the 51 s window, 165 of them the window's; my chip
-    runs, PR 46), as lists of 52 integers.
+    runs, PR 46), as lists of 55 integers.
     ``chunk_width``: rows of one prefill-scan chunk
     (``im.max_tokens``; ``chunk_rows`` = chunks x this).
     ``clock_ns``: the journal's clock, and the tick spans' ``pc_ns``
@@ -249,6 +393,7 @@ class TickJournal:
         self._loop_t0 = 0              # where this loop's first record began
         self._loop_seq = 0             # ... and its ``seq``
         self._proc = (0, 0, 0)         # cpu_ns, nivcsw, majflt at the open
+        build_log().journals.add(self)
 
     # ---- the ring -------------------------------------------------------
     @property
@@ -271,13 +416,26 @@ class TickJournal:
                 newest: Optional[int] = None) -> List[Dict]:
         """The outliers among the ring's records (the ``newest`` of them)
         by :func:`slow_excess_ns`, longest first, at most ``k``; each a
-        record with its class's ``median_ns``."""
+        record with its class's ``median_ns``, ``next_device_wait_ns`` of
+        the next record in which a tick ran, where there is one, and
+        ``built``, the build log's events inside it, where it counted
+        any."""
         rows = self.array(newest)
         excess = slow_excess_ns(rows)
         ext = extent_ns(rows)
         order = sorted(np.flatnonzero(excess), key=lambda i: -ext[i])[:k]
-        return [dict(as_dict(rows[i]), median_ns=int(ext[i] - excess[i]))
-                for i in order]
+        out = []
+        for i in order:
+            rec = dict(as_dict(rows[i]), median_ns=int(ext[i] - excess[i]))
+            ran = np.flatnonzero(rows[i + 1:, _KIND] != _KIND_AT["idle"])
+            if len(ran):
+                rec["next_device_wait_ns"] = int(
+                    rows[i + 1 + ran[0], _SPLIT_AT["device_wait"]])
+            if rec["builds"]:
+                rec["built"] = builds(after_ns=rec["t0_ns"],
+                                      before_ns=rec["t1_ns"])
+            out.append(rec)
+        return out
 
     # ---- the loop's boundaries -----------------------------------------
     def begin(self, pending: int, live: int) -> None:

@@ -54,9 +54,11 @@ cross-checks against ``Linear.flops`` / ``plan_memory_parts``):
 * ``dispatches`` — host program launches (per stage per micro-batch
   under pp); per-request ``dispatches`` counts the model passes whose
   batch carried the request's tokens;
-* ``recompiles_total`` — jit cache misses: the registered jitted
-  callables' ``_cache_size()`` growth since registration (a silent
-  steady-state recompile is the most likely invisible perf bug);
+* ``recompiles_total`` — backend compiles of the registered deployments'
+  programs since registration, counted from the journal's build log
+  (obs/journal.py ``BuildLog``: what JAX itself reports, by program
+  name; a silent steady-state recompile is the most likely invisible
+  perf bug);
 * ``host_syncs`` — device→host result materializations (multi-step
   decode must perform exactly ONE, the final readback — the r7 "never
   host-syncs" claim, now a pinned counter);
@@ -84,8 +86,9 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, Optional, Sequence, Tuple
 
+from .journal import build_log
 from .trace import Span
 
 # the per-component time vocabulary: calibration-ledger field names are
@@ -254,12 +257,12 @@ class StepProfiler:
         self.ticks = 0
         self.last_tick: Dict = {}
         self.telemetry = None   # bound via bind(); step_profile instants
-        # jitted callables polled for cache growth, per deployment:
-        # id(im) -> [(name, fn, base_size)] (keyed so uninstall() can
-        # release a retired deployment's programs)
-        self._jits: Dict[int, List[Tuple[str, object, int]]] = {}
-        # compile counts already folded in from uninstalled deployments
-        self._retired_compiles = 0
+        # the installed deployments' programs, by the name the build log
+        # files their compiles under (names only: a retired deployment's
+        # callables and buffers are not held), and how far into the log
+        # ``recompiles`` has counted
+        self._programs: set = set()
+        self._builds_seen = build_log().emitted
         self._installed: set = set()
         # paged allocators polled for cumulative page activity:
         # id(im) -> (kv, {counter: last_seen})
@@ -280,24 +283,21 @@ class StepProfiler:
             telemetry.profiler = self
 
     def install(self, im) -> None:
-        """Register a deployment: its jitted step callables join the
-        recompile poll and its paged allocator (if any) the page poll.
-        Idempotent per ``im``; called by the RequestManager when the
-        handle is synced (and again by a migration's successor)."""
+        """Register a deployment: its jitted step programs' names join
+        the recompile count and its paged allocator (if any) the page
+        poll.  Idempotent per ``im``; called by the RequestManager when
+        the handle is synced (and again by a migration's successor)."""
         key = id(im)
         if key in self._installed:
             return
         self._installed.add(key)
-        label = type(im).__name__
-        jits = self._jits.setdefault(key, [])
-        for name in ("_step", "_scan", "_pscan", "_advance", "_join"):
-            fn = getattr(im, name, None)
-            if fn is not None and hasattr(fn, "_cache_size"):
-                jits.append((f"{label}{name}", fn, fn._cache_size()))
-        for s, stage in enumerate(getattr(im, "stages", None) or []):
-            fn = getattr(stage, "step", None)
-            if fn is not None and hasattr(fn, "_cache_size"):
-                jits.append((f"{label}.stage{s}", fn, fn._cache_size()))
+        self.recompiles()   # what was built before now is not this one's
+        fns = [getattr(im, name, None)
+               for name in ("_step", "_scan", "_pscan", "_advance", "_join")]
+        fns += [getattr(stage, "step", None)
+                for stage in getattr(im, "stages", None) or []]
+        self._programs.update(fn.__name__ for fn in fns
+                              if hasattr(fn, "lower"))
         kv = getattr(im, "kv", None)
         if kv is not None and getattr(kv, "paged", False):
             # baseline NOW (registration), so page activity from the very
@@ -308,15 +308,13 @@ class StepProfiler:
 
     def uninstall(self, im) -> None:
         """Release a RETIRED deployment (live-migration incumbent
-        teardown): its jitted callables leave the recompile poll — their
-        compiles-so-far fold into a retained total, so the counter stays
-        monotonic — and its cost card / page poll entries drop.  Without
-        this, a long-migrating session would pin every retired manager's
-        programs (and their buffers) alive through the poll list."""
+        teardown): its cost card / page poll entries drop, so a
+        long-migrating session does not pin every retired manager (and
+        its buffers) alive.  The compiles it performed stay counted (the
+        counter is monotonic), and its programs' NAMES stay registered:
+        the successor's are the same."""
         key = id(im)
         self._installed.discard(key)
-        for _, fn, base in self._jits.pop(key, ()):  # noqa: B007
-            self._retired_compiles += max(fn._cache_size() - base, 0)
         self._cards.pop(key, None)
         self._paged.pop(key, None)
 
@@ -413,16 +411,19 @@ class StepProfiler:
 
     # ---- polled counters ----------------------------------------------
     def recompiles(self) -> int:
-        """Jit cache misses since registration, summed over the
-        registered callables (``_cache_size()`` growth — a compile per
-        new (shapes, static args) signature), plus retired deployments'
-        folded totals."""
-        return self._retired_compiles + int(sum(
-            max(fn._cache_size() - base, 0)
-            for jits in self._jits.values() for _, fn, base in jits))
+        """Backend compiles of the registered programs since their
+        registration: the build log's ``compile`` events under their
+        names (a cache-answered one too: the serving loop waited for it),
+        counted as they come in."""
+        log = build_log()
+        self.work["recompiles_total"] += sum(
+            b.what == "compile" and b.fun_name in self._programs
+            for b in log.since(self._builds_seen))
+        self._builds_seen = log.emitted
+        return self.work["recompiles_total"]
 
     def _poll(self) -> None:
-        self.work["recompiles_total"] = self.recompiles()
+        self.recompiles()
         for kv, seen in self._paged.values():
             for name, attr in (("pages_mapped", "pages_mapped"),
                                ("pages_cow", "cow_copies")):

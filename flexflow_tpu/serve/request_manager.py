@@ -17,6 +17,7 @@ import dataclasses
 import enum
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from ..obs.telemetry import telemetry_or_null
@@ -341,6 +342,25 @@ class RequestManager:
         return self.telemetry.span(
             name, cat="host", track="host",
             prof=self.profiler if phase else None, jr=self.journal, **args)
+
+    def _device_wait(self, results) -> None:
+        """First thing inside a ``readback`` span.  ``results``: every
+        array the span will copy, in launch order (any pytree).  Starts
+        their copies to the host — an earlier result's copy then overlaps
+        the device's work on the later ones, as the ``np.asarray`` calls
+        did before they stood behind this wait (without it
+        ``decode-heavy`` lost 0.6 %: PERF.md section 6, PR 59) — and
+        blocks until the LAST is ready (the stream runs in order) under a
+        span of its own.  ``device_wait`` is then the wait for the device
+        (or for the runtime to hand its results over) and ``readback``'s
+        self time the copies alone: a stall reads as one or the other on
+        the journal's slow-tick line."""
+        leaves = jax.tree.leaves(results)
+        for x in leaves:
+            if hasattr(x, "copy_to_host_async"):
+                x.copy_to_host_async()
+        with self._span("device_wait"):
+            jax.block_until_ready(leaves[-1])
 
     def _tick_begin(self) -> None:
         """A serve loop is about to run a tick: the profiler's mark, and
@@ -1435,6 +1455,7 @@ class RequestManager:
             # device so chunked prefill dispatches stay fully async
             return
         with self._span("readback", phase=True):
+            self._device_wait(result.token_ids)
             token_ids = np.asarray(result.token_ids)
         self.profiler.host_sync()
         with self._span("commit") as sp:
@@ -1864,6 +1885,7 @@ class RequestManager:
             return
         points, outs = fed
         with self._span("readback", phase=True):
+            self._device_wait([t for _, t, _ in outs])
             toks = {start: np.asarray(t) for start, t, _ in outs}  # one sync
         self.profiler.host_sync(len(outs))
         starts = sorted(toks)
@@ -2069,6 +2091,10 @@ class RequestManager:
 
         # ---- single readback + chronological commit -------------------
         with self._span("readback", phase=True):
+            # the scans' expert load comes out of the scans themselves:
+            # nothing the span copies was launched after ``commits[-1]``
+            self._device_wait([c[3:] if c[0] == "scan" else c[2]
+                               for c in commits])
             ready = []
             for item in commits:
                 if item[0] == "scan":

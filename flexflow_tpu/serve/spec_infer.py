@@ -373,6 +373,7 @@ class SpecInferManager(RequestManager):
                 return
             self.llm_steps += 1
             with self._span("readback", phase=True):
+                self._device_wait(result.token_ids)
                 ids = np.asarray(result.token_ids)
             self.profiler.host_sync()
             for flat, rid in points:
@@ -505,6 +506,7 @@ class SpecInferManager(RequestManager):
             if result is None:
                 return []
             with self._span("readback", phase=True):
+                self._device_wait((result.topk_ids, result.topk_logprobs))
                 topk_ids = np.asarray(result.topk_ids)
                 topk_lp = np.asarray(result.topk_logprobs)
             prof.host_sync()
@@ -647,6 +649,7 @@ class SpecInferManager(RequestManager):
             return
         self.llm_steps += 1
         with self._span("readback", phase=True):
+            self._device_wait(result.token_ids)
             ids = np.asarray(result.token_ids)
         prof.host_sync()
 

@@ -2,8 +2,11 @@
 """What one ``Span`` costs on this host, with and without the tick journal
 as its consumer (no profiler session, no telemetry ring): ns per span over
 ``--spans`` spans, in ticks of ``--per-tick`` spans each (a tick = one
-record: ``begin``, a tick span, the spans nested in it).  A count of host
-nanoseconds from wherever it runs — never a device metric."""
+record: ``begin``, a tick span, the spans nested in it).  Then what ONE
+``readback`` costs with and without the ``device_wait`` nested in it (ISSUE
+59: the start of the copy, the span and a ``jax.block_until_ready`` of a
+result that is ready; the array lives on whatever backend JAX finds).  A count of host nanoseconds
+from wherever it runs — never a device metric."""
 
 import argparse
 import logging
@@ -38,6 +41,26 @@ def run(spans, per_tick, jr):
     return (time.perf_counter_ns() - t0) / spans
 
 
+def run_readback(n, jr, wait, result):
+    """``n`` ticks of one ``readback`` each, copying ``result``; ``wait``:
+    with the nested ``device_wait`` the serving loops enter first."""
+    import jax
+    import numpy as np
+
+    t0 = time.perf_counter_ns()
+    for _ in range(n):
+        jr.begin(100, 8)
+        with Span("decode_stretch", {"pc_ns": jr.clock_ns()}, jr=jr):
+            with Span("readback", jr=jr):
+                if wait:
+                    result.copy_to_host_async()
+                    with Span("device_wait", jr=jr):
+                        jax.block_until_ready(result)
+                np.asarray(result)
+    jr.end()
+    return (time.perf_counter_ns() - t0) / n
+
+
 def main():
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--spans", type=int, default=1_000_000)
@@ -50,6 +73,16 @@ def main():
         ns = run(a.spans, a.per_tick, jr)
         print(f"{label} the journal: {ns:.0f} ns a span, "
               f"{ns * a.per_tick / 1e3:.2f} us a tick of {a.per_tick}")
+    import jax.numpy as jnp
+
+    result = jnp.zeros((32, 256), jnp.int32)     # a stretch's tokens: 32 KB
+    result.block_until_ready()
+    n = a.spans // 20
+    for wait in (False, True, False, True):
+        ns = run_readback(n, TickJournal(), wait, result)
+        print(f"a readback of a ready {result.nbytes // 1024} KB result on "
+              f"{result.devices().pop().platform}, "
+              f"{'with' if wait else 'without'} device_wait: {ns:.0f} ns")
 
 
 if __name__ == "__main__":

@@ -351,6 +351,36 @@ def test_zero_steady_state_recompiles_decode():
         "steady-state decode recompiled a jitted program"
 
 
+def test_recompiles_are_the_build_log_s_compiles_of_the_programs():
+    """The guard's count is not blind: a deployment that IS built counts
+    a compile for each program its workload ran, under the program's name
+    in the journal's build log (ISSUE 59: no poll of ``_cache_size()``),
+    and nothing for a program that is not the deployment's."""
+    import jax
+    import jax.numpy as jnp
+
+    from flexflow_tpu.obs import journal
+
+    im = make_im(max_seq=72)   # a deployment no other test has built
+    prof = StepProfiler()
+    n0 = journal.build_log().emitted
+    rm = RequestManager(im, GenerationConfig(max_new_tokens=6),
+                        profiler=prof)
+    try:
+        assert prof.recompiles() == 0
+        rm.generate(PROMPTS)
+    finally:
+        im.profiler = NULL_PROFILER
+    built = prof.recompiles()
+    compiles = [b for b in journal.build_log().since(n0)
+                if b.what == "compile" and b.fun_name in prof._programs]
+    assert built == len(compiles) == prof.work["recompiles_total"]
+    assert {"_step_impl", "_decode_scan_impl"} <= {
+        b.fun_name for b in compiles}
+    jax.jit(lambda x: x + 59)(jnp.ones(5))
+    assert prof.recompiles() == built
+
+
 def test_zero_recompiles_pp_microbatch_population_change():
     """A pp decode with fewer live requests pads to the SAME micro-batch
     shapes — serving 1 request after 2 must hit the compiled programs."""
@@ -489,17 +519,21 @@ def test_first_tick_page_activity_is_counted():
 
 def test_profiler_uninstall_releases_retired_deployment():
     """A live migration retires the incumbent through
-    ``profiler.uninstall``: its jitted programs leave the poll list (no
-    unbounded growth across switches) while the compiles it performed
-    stay folded into the monotonic counter."""
+    ``profiler.uninstall``: the profiler holds nothing of it any more —
+    its programs were only ever held by NAME, the names the build log
+    counts compiles under (no unbounded growth across switches) — while
+    the compiles it performed stay in the monotonic counter."""
     im = make_im(max_seq=64)
     prof = StepProfiler()
     RequestManager(im, GenerationConfig(max_new_tokens=2), profiler=prof)
-    assert id(im) in prof._jits
+    assert id(im) in prof._installed
+    assert {"_step_impl", "_decode_scan_impl", "_prefill_scan_impl",
+            "_join_impl"} <= prof._programs
+    assert all(isinstance(name, str) for name in prof._programs)
     before = prof.recompiles()
     prof.uninstall(im)
-    assert id(im) not in prof._jits and id(im) not in prof._installed
-    assert prof.recompiles() == before  # folded, not lost
+    assert id(im) not in prof._installed and id(im) not in prof._cards
+    assert prof.recompiles() == before  # counted, not lost
     im.profiler = NULL_PROFILER
 
 

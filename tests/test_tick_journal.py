@@ -6,11 +6,16 @@ the tiled feed and joiners splice into running stretches): the records of
 a loop tile it and split every extent into self time by span; their launch
 and token sums are the serving records' and the telemetry counters'; the
 ring drops the oldest; the tokens served are the parent commit's; a clock
-that jumps inside one ``readback`` is reported, in one line that names it.
+that jumps inside one ``readback`` is reported, in one line that names it
+— ``device_wait`` if the wait for the last result was late, ``readback``
+if the copies were (ISSUE 59) — with what the next tick waited; what JAX
+built while a record was open is in the record and on its line, and in the
+process's build log wherever it happened.
 """
 
 import logging
 
+import jax.monitoring
 import numpy as np
 import pytest
 
@@ -252,25 +257,37 @@ def paced(vc, journal=TickJournal):
     return rm
 
 
-def test_a_stall_inside_one_readback_is_one_line_that_names_it(caplog):
+# where the 3 s pass, counted in clock reads after ``device_wait`` was
+# entered: its own exit (the wait for the last result), or the exit of the
+# ``readback`` around it (the copies)
+@pytest.mark.parametrize("late,reads_after,self_reads",
+                         [("device_wait", 1, 1), ("readback", 2, 2)])
+def test_a_stall_inside_one_readback_is_one_line_that_names_it(
+        caplog, late, reads_after, self_reads):
     caplog.set_level(logging.WARNING, logger="flexflow_tpu.serve")
     probe = paced(VirtualClock(), journal=Probe).journal
     assert not caplog.records, "a steady run reported a slow tick"
     stretches = [r for r in probe.records() if r["kind"] == "decode_stretch"]
     assert len(stretches) == 10 and {r["decode_steps"]
                                      for r in stretches} == {4}
-    # the read after the sixth stretch's ``readback`` was entered is that
-    # span's exit: 3 s pass there
-    reads = [at for name, at in probe.entered if name == "readback"]
-    vc = VirtualClock(jump={reads[6] + 1: 3_000_000_000})
+    # one ``device_wait`` a ``readback``, entered right after it
+    reads = [at for name, at in probe.entered if name == "device_wait"]
+    assert [at - 1 for at in reads] == [
+        at for name, at in probe.entered if name == "readback"]
+    vc = VirtualClock(jump={reads[6] + reads_after: 3_000_000_000})
     jr = paced(vc).journal
     lines = [r.getMessage() for r in caplog.records]
     assert len(lines) == 1, lines
     assert lines[0].startswith("slow tick: decode_stretch at ")
-    assert "split ms: readback 3000.0 " in lines[0]
+    assert f"split ms: {late} 3000.0 " in lines[0]
     assert "launches: decode_scans 1 decode_steps 4" in lines[0]
+    # the stretch after the stalled one waited its usual time
+    assert lines[0].endswith("; next device_wait 0.0")
     (slow,) = jr.slowest()
-    assert slow["readback_ns"] == 3_000_000_000 + STEP_NS
+    assert slow[f"{late}_ns"] == 3_000_000_000 + self_reads * STEP_NS
+    other = "readback" if late == "device_wait" else "device_wait"
+    assert slow[f"{other}_ns"] == (3 - self_reads) * STEP_NS
+    assert slow["next_device_wait_ns"] == STEP_NS
     assert slow["median_ns"] < 100 * STEP_NS
     # the share the benchmark reads is the same rule's
     rows = jr.array()
@@ -278,6 +295,189 @@ def test_a_stall_inside_one_readback_is_one_line_that_names_it(caplog):
     assert np.count_nonzero(excess) == 1
     assert excess.sum() == (slow["t1_ns"] - slow["t0_ns"]
                             - slow["median_ns"])
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_device_wait_and_readback_are_the_parents_readback(mode):
+    """At the parent a ``readback`` span was one clock read long (its
+    exit); the span now nested in it adds its own entry and exit: the
+    wait takes the read at its exit, the copies keep the other two."""
+    vc = VirtualClock()
+    rm = manager(vc, journal=Probe)
+    tokens, _ = serve(rm, vc, mode)
+    assert tokens == PARENT_TOKENS
+    n = sum(name == "readback" for name, _ in rm.journal.entered)
+    assert n == sum(name == "device_wait" for name, _ in rm.journal.entered)
+    recs = rm.journal.records()
+    parent_readback_ns = n * STEP_NS
+    wait = sum(r["device_wait_ns"] for r in recs)
+    copies = sum(r["readback_ns"] for r in recs)
+    assert wait == n * STEP_NS
+    assert wait + copies - 2 * n * STEP_NS == parent_readback_ns
+    for r in recs:
+        assert r["readback_ns"] == 2 * r["device_wait_ns"]
+        # still tiled, the new field in the split
+        assert r["t1_ns"] - r["t0_ns"] == r["unattributed_ns"] + sum(
+            r[f"{name}_ns"] for name in J.SPLIT)
+    assert "device_wait" in J.SPLIT and "device_wait_ns" in J.FIELDS
+
+
+def test_device_wait_takes_whatever_a_readback_will_copy():
+    """Device arrays, host arrays (a stage that answered on the host) and
+    tuples of them: one span, entered once, inside the open record."""
+    import jax.numpy as jnp
+
+    vc = VirtualClock()
+    rm = manager(vc, journal=Probe)
+    rm.journal.begin(0, 0)
+    with rm._span("readback"):
+        rm._device_wait([(jnp.arange(4), np.arange(3)), np.zeros(2),
+                         jnp.ones((2, 2))])
+    rm.journal.end()
+    assert [n for n, _ in rm.journal.entered] == ["readback", "device_wait"]
+    (rec,) = rm.journal.records()
+    assert rec["device_wait_ns"] == STEP_NS
+    assert rec["readback_ns"] == 2 * STEP_NS
+
+
+def _tick(jr, vc, wait_ns, body=None):
+    jr.begin(0, 2)
+    with Span("decode_stretch", {"pc_ns": jr.clock_ns()}, jr=jr):
+        with Span("decode_scan_dispatch", {"n_steps": 32, "rows": 2}, jr=jr):
+            if body is not None:
+                body()
+        with Span("readback", jr=jr):
+            with Span("device_wait", jr=jr):
+                vc.t += wait_ns
+
+
+def test_the_last_tick_of_a_loop_has_no_next_device_wait(caplog):
+    caplog.set_level(logging.WARNING, logger="flexflow_tpu.serve")
+    vc = VirtualClock()
+    jr = TickJournal(clock_ns=vc.ns, chunk_width=CAP)
+    for wait_ms in [300] * 9 + [3_300]:
+        _tick(jr, vc, wait_ms * 1_000_000)
+    jr.end()
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert "split ms: device_wait 3300.0 " in line
+    assert "next device_wait" not in line and "built:" not in line
+    # a stall that outlives its tick: the next one waits long too, and an
+    # idle poll between them is not "the next"
+    caplog.clear()
+    for wait_ms in [300] * 9 + [3_300, -1, 2_100]:
+        if wait_ms < 0:
+            with Span("loop_arrivals", jr=jr):
+                pass
+            continue
+        _tick(jr, vc, wait_ms * 1_000_000)
+    jr.end()
+    lines = [r.getMessage() for r in caplog.records]
+    assert len(lines) == 2 and "; next device_wait 2100.0" in lines[0]
+
+
+TRACE, LOWER, COMPILE = J.BUILD_WHATS
+
+
+def _built(what, secs, fun_name):
+    """An event as jax reports it (the public recorder of its listeners)."""
+    if fun_name is None:
+        jax.monitoring.record_event_duration_secs(what, secs)
+    else:
+        jax.monitoring.record_event_duration_secs(what, secs,
+                                                  fun_name=fun_name)
+
+
+def test_a_build_inside_a_record_is_in_it_and_on_its_line(caplog,
+                                                          monkeypatch):
+    caplog.set_level(logging.WARNING, logger="flexflow_tpu.serve")
+    vc = VirtualClock()
+    jr = TickJournal(clock_ns=vc.ns, chunk_width=CAP)
+    log = J.build_log()
+    # the log stamps arrivals on the virtual clock too (without a read)
+    monkeypatch.setattr(log, "clock_ns", lambda: vc.t)
+    n0 = log.emitted
+    # outside a loop: in the log alone
+    _built(COMPILE, 0.25, "jit(_step_impl)")
+    assert log.emitted == n0 + 1 and jr.emitted == 0
+
+    def recompile():
+        # jax reports a build's start as a scalar under the event's name:
+        # ``multiply`` is traced INSIDE the scan's trace, and is no event
+        jax.monitoring.record_scalar(TRACE, 0.0, fun_name="_decode_scan_impl")
+        jax.monitoring.record_scalar(TRACE, 0.0, fun_name="multiply")
+        _built(TRACE, 0.001, "multiply")
+        _built(TRACE, 0.5, "_decode_scan_impl")
+        _built(LOWER, 0.125, "jit(_decode_scan_impl)")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        _built(COMPILE, 2.0, "jit(_decode_scan_impl)")
+        _built(COMPILE, 0.0625, None)
+        vc.t += 2_700_000_000
+
+    for i in range(10):
+        _tick(jr, vc, 300_000_000, body=recompile if i == 6 else None)
+    jr.end()
+    recs = jr.records()
+    assert [r["builds"] for r in recs] == [0] * 6 + [4] + [0] * 3
+    assert recs[6]["build_ns"] == 2_687_500_000
+    # an overlay: the time lies under the launch span, the record tiles
+    assert recs[6]["decode_scan_dispatch_ns"] == 2_700_000_000 + STEP_NS
+    (line,) = [r.getMessage() for r in caplog.records]
+    assert line.endswith(
+        "; next device_wait 300.0; built: _decode_scan_impl compile 2000.0 "
+        "cached, _decode_scan_impl trace 500.0, _decode_scan_impl lower "
+        "125.0")
+    # the log: one name a program over its three events, ``?`` for an
+    # event without one, ``cached`` for the compile after a cache hit only
+    mine = log.since(n0)
+    assert [(b.what, b.fun_name, b.cached) for b in mine] == [
+        ("compile", "_step_impl", False),
+        ("trace", "_decode_scan_impl", False),
+        ("lower", "_decode_scan_impl", False),
+        ("compile", "_decode_scan_impl", True), ("compile", "?", False)]
+    assert mine[3].dur_ns == 2_000_000_000
+    scan = J.builds(programs=["_decode_scan_impl"])[-3:]
+    assert [b.what for b in scan] == ["trace", "lower", "compile"]
+    t_step = mine[0].t_ns
+    assert J.builds(programs=["_step_impl", "_decode_scan_impl"],
+                    before_ns=t_step)[-1] == mine[0]
+    assert J.builds(after_ns=recs[6]["t0_ns"],
+                    before_ns=recs[6]["t1_ns"]) == mine[1:]
+
+
+def test_one_listener_however_many_journals():
+    vc = VirtualClock()
+    journals = [TickJournal(clock_ns=vc.ns) for _ in range(5)]
+    log = J.build_log()
+    assert log is J.build_log() and all(j in log.journals for j in journals)
+    journals[1].begin(0, 0)
+    journals[3].begin(0, 0)
+    n0 = log.emitted
+    _built(LOWER, 0.5, "jit(_join_impl)")
+    # one event, once: in the log, and in every record that is open
+    assert log.emitted == n0 + 1
+    for j in journals:
+        j.end()
+    assert [[r["builds"] for r in j.records()] for j in journals] == [
+        [], [1], [], [1], []]
+    assert journals[3].records()[0]["build_ns"] == 500_000_000
+    # a journal nobody holds leaves the set
+    n = len(log.journals)
+    del journals, j
+    assert len(log.journals) == n - 5
+
+
+def test_the_build_log_is_bounded():
+    assert J.BUILD_LOG_CAPACITY == 1024
+    assert J.build_log().events.maxlen == J.BUILD_LOG_CAPACITY
+    log = J.BuildLog(capacity=4)    # not the process's: no listener
+    for i in range(6):
+        log._on_duration(COMPILE, i * 1e-9, fun_name=f"jit(f{i})")
+    log._on_duration("/jax/some/other_duration", 1.0, fun_name="g")
+    assert (log.emitted, log.dropped, len(log.events)) == (6, 2, 4)
+    assert [b.fun_name for b in log.events] == ["f2", "f3", "f4", "f5"]
+    assert [b.fun_name for b in log.since(4)] == ["f4", "f5"]
+    assert [b.fun_name for b in log.since(0)] == ["f2", "f3", "f4", "f5"]
+    assert log.since(6) == []
 
 
 @pytest.mark.parametrize("peers,caught", [(9, True), (5, False)])
