@@ -1030,7 +1030,7 @@ def sparse_decode_attention(
 # heads, so the f32 score/softmax working set is [kv_chunk, Bq*gq, Bs] —
 # chunking the heads (heads are independent softmaxes) is what lets the Q
 # tile WIDEN (Bq up to 128 at the 7B shape, where the unchunked
-# [32, 128, 128] plan needs 17.41 MB) without shrinking the seq block below
+# [32, 128, 128] plan counts 18 MiB) without shrinking the seq block below
 # the DMA-efficient size.  This budget bounds the K+V block pipeline alone;
 # the whole plan is then held to the compiler's limit below.
 _VMEM_BUDGET_PREFILL = 4 * 2**20
@@ -1047,10 +1047,14 @@ def _prefill_vmem_bytes(kv_chunk, m_rows, block_s, d, q_itemsize,
     scale) blocks, the m/l/acc scratch, and the f32 score and probability
     tiles the body materializes.  Minor dims pad to the 128-lane tile.
 
-    Checked against the compiler's own totals for a described v5e (the
-    refusal message names them): 17.41 MB at (kv_chunk 32, block 128) and
-    11.54 MB at (16, 256) for m_rows 128, d 128, bf16 — this returns 18 and
-    13 MB; it errs high everywhere probed (the compiler keeps less than two
+    Checked against the compiler's own totals for a described v5e
+    (``used_scoped_memory_configs`` of the compiled call): 14.34 MiB at
+    (kv_chunk 32, block 128) and 9.31 MiB at (16, 256) for m_rows 128, d
+    128, bf16 — this returns 18 and 13 MiB; 6.74 MiB at (1, 256) for m_rows
+    2816 against 12.6.  Those are PR 60's body, which keeps no float32 copy
+    of K and V; with them the compiler counted 17.41 and 11.54 MiB (PR 24:
+    the first is why the plan chunks the heads, and the plan is left as it
+    is).  It errs high everywhere probed (the compiler keeps less than two
     full score tiles live), never low.
     """
     lanes = -(-d // 128) * 128
@@ -1099,6 +1103,30 @@ def _prefill_plan(num_kv, d, q_itemsize, kv_itemsize, kv_quant, m_rows,
         "geometry needs a narrower query tile")
 
 
+def prefill_operand_dtype(q_dtype, kv_dtype):
+    """The type :func:`prefill_attention`'s two contractions take their
+    operands in, from the types it is handed: a floating cache's own where
+    the queries have it too (bf16 x bf16 in a bf16 model: the MXU's native
+    width; a product of two bf16 values is exact in the float32 it
+    accumulates in), the wider of the two where they differ, and the
+    queries' on an int8 cache (|x| <= 127 is exact in bf16)."""
+    if not jnp.issubdtype(kv_dtype, jnp.floating):
+        return jnp.dtype(q_dtype)
+    return jnp.promote_types(q_dtype, kv_dtype)
+
+
+def _lanes_to(x, n):
+    """``x [..., L]`` whose lanes all hold their row's one value, as ``[...,
+    n]``: the first ``n`` lanes, or whole copies side by side — no
+    cross-lane broadcast."""
+    lanes = x.shape[-1]
+    if n <= lanes:
+        return x[..., :n]
+    if n % lanes == 0:
+        return jnp.concatenate([x] * (n // lanes), axis=-1)
+    return jnp.broadcast_to(x[..., 0:1], x.shape[:-1] + (n,))
+
+
 def _prefill_kernel(
     rows_ref,       # scalar prefetch: i32[G] cache row per tile
     pstart_ref,     # scalar prefetch: i32[G] first position in tile
@@ -1116,6 +1144,34 @@ def _prefill_kernel(
     window: int = 0,
     s_len: int = 0,
 ):
+    """One (tile, head chunk, seq block) step of :func:`prefill_attention`:
+    per live block only the arithmetic that block needs.
+
+    The running max and sum stay LANE-REPLICATED — ``m_ref`` / ``l_ref``
+    ``[KC, M, 128]`` hold a row's value in every lane, are read whole, and
+    ``alpha`` is computed on all 128 lanes — so a step does two cross-lane
+    reductions (the block's max and sum) and nothing else across lanes: no
+    one-lane slice of the scratch, no broadcast of ``m``, ``alpha`` or ``l``
+    back over the lanes (``_lanes_to`` takes lanes or lays copies side by
+    side).  With ``M`` = 128-2816 rows those were what a step waited for on
+    the v5e (2.9 of a live step's 4.5 us at the 7B shape, 5 of 8 us on one
+    K/V head: section 6 of PERF.md, PR 60); the values are the same numbers.
+
+    Both contractions take their operands in
+    :func:`prefill_operand_dtype`'s type — q, K and V as they arrive in a
+    bf16 model, ``p`` cast to it for p.v as ``_latent_attend`` does — and
+    accumulate in float32; scores, the running max, ``alpha``, ``l`` (the
+    sum of the float32 ``p``) and the accumulator are float32.  A float32
+    cache therefore runs float32 operands throughout.
+
+    The causal mask is built only where a block needs it: a block that ends
+    at or before the tile's first position (``base + block_s - 1 <=
+    pstart``) is seen whole by every query of the tile and runs without the
+    two iotas and the two ``where``s; the blocks from there to the frontier
+    keep them.  Masking an all-seen block is the identity, so the split
+    changes no bit.  A ring's age mask (``window > 0``) stays on every
+    block.
+    """
     if paged:
         refs = refs[1:]  # page table: index-map-only prefetch operand
     q_ref, k_ref, v_ref, *rest = refs
@@ -1130,6 +1186,7 @@ def _prefill_kernel(
     # a (tile, head-chunk)'s blocks
     s = pl.program_id(2)
     last_s = pl.num_programs(2) - 1
+    d = acc_ref.shape[-1]
 
     @pl.when(s == 0)
     def _init():
@@ -1140,6 +1197,59 @@ def _prefill_kernel(
     fmax = fmax_ref[g]
     pstart = pstart_ref[g]
     base = s * block_s
+    operand = prefill_operand_dtype(q_ref.dtype, k_ref.dtype)
+
+    def attend(masked):
+        sc = jax.lax.dot_general(
+            q_ref[0].astype(operand),                   # [KV, M, D]
+            k_ref[0].astype(operand),                   # [KV, Bs, D]
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        ) * scale                                       # [KV, M, Bs]
+        if kv_quant:  # fused dequant (see _decode_kernel)
+            sc = sc * ks_ref[0][:, None, :]
+
+        if masked:
+            # per-row causal mask, reconstructed from the tile's start
+            # position: query row r (= b*gq + g') sits at absolute position
+            # pstart + b
+            qpos = pstart + jax.lax.broadcasted_iota(
+                jnp.int32, (m_rows, block_s), 0
+            ) // gq
+            key_pos = base + jax.lax.broadcasted_iota(
+                jnp.int32, (m_rows, block_s), 1
+            )
+            if window:
+                # ``key_pos`` is a ring SLOT: it holds the position ``age``
+                # back from the query's, seen if that is one of its
+                # min(qpos + 1, window) newest (a later tile's keys, written
+                # before any tile attends, lie a ring less a chunk back:
+                # never seen)
+                age = qpos % s_len - key_pos
+                age = jnp.where(age < 0, age + s_len, age)
+                seen = age < jnp.minimum(qpos + 1, window)
+            else:
+                seen = key_pos <= qpos
+            live = jnp.broadcast_to(seen[None], sc.shape)
+            sc = jnp.where(live, sc, NEG_INF)
+
+        m_prev = m_ref[...]                             # [KV, M, 128]
+        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(sc - _lanes_to(m_new, block_s))
+        if masked:
+            p = jnp.where(live, p, 0.0)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, -1, keepdims=True)
+        m_ref[...] = m_new
+        if kv_quant:
+            p = p * vs_ref[0][:, None, :]
+        pv = jax.lax.dot_general(
+            p.astype(operand), v_ref[0].astype(operand),  # [KV, Bs, D]
+            (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )                                               # [KV, M, D]
+        acc_ref[...] = acc_ref[...] * _lanes_to(alpha, d) + pv
+
     if window:
         # a ring: worth computing if the block holds a slot that SOME query
         # of the tile sees — the tile's queries together see the
@@ -1147,59 +1257,18 @@ def _prefill_kernel(
         # DMA was skipped by the index map)
         run = _ring_blocks(fmax, block_s, s_len,
                            window + m_rows // gq - 1)[0](s)
+        pl.when(run)(lambda: attend(True))
     else:
-        run = base <= fmax  # blocks past the frontier: DMA already clamped
-
-    @pl.when(run)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32)               # [KV, M, D]
-        k = k_ref[0].astype(jnp.float32)               # [KV, Bs, D]
-        sc = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        ) * scale                                       # [KV, M, Bs]
-        if kv_quant:  # fused dequant (see _decode_kernel)
-            sc = sc * ks_ref[0][:, None, :]
-
-        # per-row causal mask, reconstructed from the tile's start position:
-        # query row r (= b*gq + g') sits at absolute position pstart + b
-        qpos = pstart + jax.lax.broadcasted_iota(
-            jnp.int32, (m_rows, block_s), 0
-        ) // gq
-        key_pos = base + jax.lax.broadcasted_iota(
-            jnp.int32, (m_rows, block_s), 1
-        )
-        if window:
-            # ``key_pos`` is a ring SLOT: it holds the position ``age`` back
-            # from the query's, seen if that is one of its min(qpos + 1,
-            # window) newest (a later tile's keys, written before any tile
-            # attends, lie a ring less a chunk back: never seen)
-            age = qpos % s_len - key_pos
-            age = jnp.where(age < 0, age + s_len, age)
-            seen = age < jnp.minimum(qpos + 1, window)
-        else:
-            seen = key_pos <= qpos
-        live = jnp.broadcast_to(seen[None], sc.shape)
-        sc = jnp.where(live, sc, NEG_INF)
-
-        m_prev = m_ref[:, :, 0:1]                       # [KV, M, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(live, jnp.exp(sc - m_new), 0.0)
-        l_new = alpha * l_ref[:, :, 0:1] + jnp.sum(p, -1, keepdims=True)
-        v = v_ref[0].astype(jnp.float32)                # [KV, Bs, D]
-        pv = jax.lax.dot_general(
-            p * vs_ref[0][:, None, :] if kv_quant else p,
-            v, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )                                               # [KV, M, D]
-        acc_ref[...] = acc_ref[...] * alpha + pv
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+        # blocks past the frontier do nothing (their DMA is already
+        # clamped); one that ends by ``pstart`` lies before it
+        whole = base + block_s - 1 <= pstart
+        pl.when(whole)(lambda: attend(False))
+        pl.when(jnp.logical_not(whole) & (base <= fmax))(
+            lambda: attend(True))
 
     @pl.when(s == last_s)
     def _finalize():
-        denom = jnp.maximum(l_ref[:, :, 0:1], 1e-30)
+        denom = _lanes_to(jnp.maximum(l_ref[...], 1e-30), d)
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
@@ -1241,6 +1310,17 @@ def prefill_attention(
     admits a WIDER Q tile — at the 7B shape tile 128 with kv_chunk 16 and
     256-position seq blocks, vs the old unchunked ceiling of tile 64 with
     128-position blocks: half the grid rows AND 2x the bytes per DMA wait.
+
+    The body (:func:`_prefill_kernel`) adapts to what it is handed: the
+    contractions run on the cache's own operand type
+    (:func:`prefill_operand_dtype`: bf16 x bf16 into float32 in a bf16
+    model, float32 throughout on a float32 cache, an int8 cache's values in
+    the queries' type), the causal mask is built only in the blocks a
+    tile's diagonal crosses, and the running softmax statistics stay
+    lane-replicated.  On the v5e a chunk of four 128-row tiles at the 7B
+    shape (36 live grid steps of 64) takes 133 us against 209 before PR 60,
+    where its copies alone take 106; on one K/V head of 22 query heads
+    (starcoder's: 2816 rows a tile) 110 us against 279 (PERF.md section 6).
 
     ``window > 0``: a sliding-window layer whose cache is a RING, as
     :func:`decode_attention`'s — position ``p`` at slot ``p % S``, ``S`` at

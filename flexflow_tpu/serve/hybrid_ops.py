@@ -68,8 +68,8 @@ from ..core.sharding import TensorSharding
 from ..ops.norm import _rms_norm
 from .batch_config import BatchConfig, PrefillBatchConfig
 from .ops import (DUS_MAX_TOKENS, NEG_INF, _block_chain, _tile_blocks,
-                  apply_rope, note_decode_block, put_blocks, put_rows,
-                  tile_coords, yarn_mscale)
+                  apply_rope, note_decode_block, note_prefill_operands,
+                  put_blocks, put_rows, tile_coords, yarn_mscale)
 from .quant import dequant
 
 LANE = 128  # the kernels' seq-block granule: every cache seq dim is padded to it
@@ -528,6 +528,7 @@ class SlotCacheAttention(_SlotStateOp):
                 q.reshape(g, bq, self.cache_heads * nq, d), kc, vc, rows,
                 pos[:, 0], scale=self.scaling_factor, interpret=interp,
                 window=self.window)
+            note_prefill_operands(ctx.extras, self.path_kind, q, kc)
             return out.reshape(t, self.cache_heads, nq, d), "prefill_attention"
         if pallas:
             # pads stream one block, not a stale row's whole prefix
@@ -1273,6 +1274,7 @@ class EvaAttention(_SlotStateOp):
                 q.reshape(g, bq, *q.shape[1:]), kc, vc,
                 rows.reshape(g, bq)[:, 0], at.reshape(g, bq)[:, 0],
                 scale=self.scaling_factor, interpret=interp)
+            note_prefill_operands(ctx.extras, "eva_attention", q, kc)
             return out.reshape(q.shape), "prefill_attention"
         if ctx.extras.get("pallas_decode"):
             out = decode_attention(q, kc, vc, rows, at,

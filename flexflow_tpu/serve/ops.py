@@ -289,6 +289,20 @@ def note_decode_block(extras, kind, batch, k_cache, **plan):
             k_cache, **plan)
 
 
+def note_prefill_operands(extras, kind, q, k_cache):
+    """Record in ``extras["attention_paths"]`` the type
+    ``prefill_attention``'s contractions take their operands in for op
+    ``kind`` (``prefill_operand_dtype`` of the queries and the cache it is
+    traced with) — the ``attention_path.prefill_operands.<dtype>`` counter:
+    ``bfloat16`` in a bf16 model, ``float32`` only in a float32 one."""
+    paths = extras.get("attention_paths")
+    if paths is not None:
+        from ..ops.pallas.attention import prefill_operand_dtype
+
+        paths[("prefill_operands", kind)] = prefill_operand_dtype(
+            q.dtype, k_cache.dtype).name
+
+
 def alibi_slopes(num_heads: int) -> jax.Array:
     """ALiBi per-head slopes (Press et al.; matches HF's power-of-2 recipe)."""
     import math as _math
@@ -1006,6 +1020,7 @@ class IncMultiHeadSelfAttention(Op):
             kv_l, gq = q_.shape[1], q_.shape[2]
             scales_ = rest[:len(scales)]
             pt_ = rest[len(scales)] if pg else None
+            note_prefill_operands(ctx.extras, self.type_name, q_, kc_)
             return prefill_attention(
                 q_.reshape(t, kv_l * gq, self.head_dim).reshape(
                     g, bq, kv_l * gq, self.head_dim

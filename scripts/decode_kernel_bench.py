@@ -129,7 +129,7 @@ def one_call_s(f, args, lengths=(8, 40), repeats=3):
         def run(q, *rest):
             def body(_, c):
                 out = f(q + c.astype(q.dtype), *rest)
-                return out[0, 0, 0].astype(jnp.float32) * 1e-9
+                return out.ravel()[0].astype(jnp.float32) * 1e-9
             return jax.lax.fori_loop(0, n, body, jnp.float32(0))
         run(*args).block_until_ready()
         best = float("inf")

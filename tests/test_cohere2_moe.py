@@ -306,7 +306,7 @@ def test_the_harness_drive_is_correct(use_pallas):
     kinds = {k for k, _ in im.attention_paths}
     assert kinds - {"kv_block_write"} == {
         "sliding_window_attention", "moe_experts"} | (
-            {"decode_block"} if use_pallas else set())
+            {"decode_block", "prefill_operands"} if use_pallas else set())
 
 
 def test_flat_rows_of_several_requests_go_by_segments():

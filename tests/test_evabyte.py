@@ -268,6 +268,11 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert im.attention_paths.pop(
         ("kv_row_write", "one_row_per_request")) == (
         "pallas" if use_pallas else "dus_chain")
+    # the prompt's tiles went through ``prefill_attention`` on the toy's
+    # float32 cache: float32 operands into its contractions
+    assert im.attention_paths.pop(
+        ("prefill_operands", "eva_attention"), None) == (
+        "float32" if use_pallas else None)
     assert {k for k, _ in im.attention_paths} == {"eva_attention"}
     if use_pallas:
         assert im.attention_paths[

@@ -72,27 +72,46 @@ def test_decode_kernel_fused_dequant_matches_reference(qh, kv, d, s, block):
                                atol=1e-5, rtol=1e-5)
 
 
-def test_prefill_kernel_fused_dequant_matches_reference():
+@pytest.mark.parametrize("q_dtype", ["float32", "bfloat16"])
+def test_prefill_kernel_fused_dequant_matches_reference(q_dtype):
+    """The int8 values reach the contractions in the QUERIES' type (exact for
+    |x| <= 127), the scale planes folded into the scores and into ``p``:
+    float32 queries give the float32 arithmetic of the kernel before PR 60,
+    bf16 queries its bf16-operand form."""
+    import prefill_kernel_forms as forms
+
     rng = np.random.default_rng(1)
     qh, kv, d, s, bq, block = 4, 2, 8, 64, 8, 16
     g = 3
     t = g * bq
-    q = jnp.asarray(rng.normal(size=(g, bq, qh, d)), jnp.float32)
+    q = jnp.asarray(rng.normal(size=(g, bq, qh, d)), q_dtype)
     kc8, ks, kcf = quantize_cache(rng, 4, kv, s, d)
     vc8, vs, vcf = quantize_cache(rng, 4, kv, s, d)
     rows = jnp.asarray([0, 2, 1], jnp.int32)
     pstart = jnp.asarray([8, 0, s - bq], jnp.int32)
-    scale = 1.0 / np.sqrt(d)
-    got = prefill_attention(q, kc8, vc8, rows, pstart, scale, block_s=block,
-                            interpret=True, k_scale=ks, v_scale=vs)
+    kw = dict(scale=1.0 / np.sqrt(d), block_s=block, interpret=True,
+              k_scale=ks, v_scale=vs)
+    got = prefill_attention(q, kc8, vc8, rows, pstart, **kw)
+    assert got.dtype == q.dtype
     flat_rows = jnp.repeat(rows, bq)
     flat_pos = (pstart[:, None] + jnp.arange(bq)[None, :]).reshape(-1)
-    want = ref_attention(q.reshape(t, qh, d), kcf, vcf, flat_rows, flat_pos,
-                         scale)
+    want = ref_attention(q.astype(jnp.float32).reshape(t, qh, d), kcf, vcf,
+                         flat_rows, flat_pos, kw["scale"])
+    tol = 1e-5 if q_dtype == "float32" else 2e-2
     np.testing.assert_allclose(
-        np.asarray(got).reshape(t, qh, d), np.asarray(want),
-        atol=1e-5, rtol=1e-5,
+        np.asarray(got, np.float32).reshape(t, qh, d), np.asarray(want),
+        atol=tol, rtol=tol,
     )
+    # ...and the kernel before PR 60 with these operands (off the chip the
+    # interpreter's fused multiply-adds differ by a float32 ulp where a
+    # block runs without its mask)
+    form = forms.PARENT if q_dtype == "float32" else forms.NATIVE_MASKED
+    ulp = 1e-6 if q_dtype == "float32" else 2 ** -7
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(forms.prefill_attention_with(
+            form, q, kc8, vc8, rows, pstart, **kw), np.float32),
+        atol=ulp, rtol=ulp)
 
 
 def test_tree_kernel_fused_dequant_matches_fp_cache():

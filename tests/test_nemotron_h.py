@@ -292,6 +292,10 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert paths.pop(
         ("kv_row_write", "one_row_per_request")) == (
         "pallas" if use_pallas else "dus_chain")
+    # the GQA layer's prompt tiles, on the toy's float32 cache
+    assert paths.pop(
+        ("prefill_operands", "inc_multihead_self_attention"), None) == (
+        "float32" if use_pallas else None)
     assert {k for k, _ in paths} == {"mamba2_scan", "moe_experts",
                                      "causal_conv1d"}
     # the conv's two forms: the decode scans step the tails in slot order,

@@ -275,7 +275,8 @@ def test_the_harness_drive_is_correct(use_pallas):
     kinds = {k for k, _ in im.attention_paths}
     assert kinds == {"window_attention", "full_attention", "cross_attention",
                      "selective_scan", "causal_conv1d"} | (
-                         {"decode_block"} if use_pallas else set())
+                         {"decode_block", "prefill_operands"}
+                         if use_pallas else set())
     # the conv's two forms: the decode scans step the tails in slot order,
     # the prompt's chunks and the flat steps go by rows
     assert {b: p for (k, b), p in im.attention_paths.items()
@@ -288,6 +289,10 @@ def test_the_harness_drive_is_correct(use_pallas):
             ("full_attention", "PrefillBatchConfig")] == "prefill_attention"
         assert im.attention_paths[
             ("window_attention", "PrefillBatchConfig")] == "xla_tile"
+        # the full and the cross layers' tiles, on the toy's float32 caches
+        assert {b: p for (k, b), p in im.attention_paths.items()
+                if k == "prefill_operands"} == {
+            "full_attention": "float32", "cross_attention": "float32"}
         assert im.attention_paths[
             ("window_attention", "BatchConfig")] == "decode_attention"
         assert scan_path(im, "PrefillBatchConfig") == scan_path(im) == "kernel"

@@ -20,42 +20,159 @@ from flexflow_tpu.serve import (
 )
 from flexflow_tpu.serve.batch_config import BatchConfig, PrefillBatchConfig
 
+import prefill_kernel_forms as forms
 from test_pallas_attention import ref_attention
 from test_serve import TINY, make_im, ref_greedy_decode
 
 
-@pytest.mark.parametrize("qh,kv,d,s,bq,block,kv_chunk", [
-    (4, 2, 8, 64, 8, 16, None),    # GQA, multi-tile
-    (4, 4, 8, 32, 4, 32, None),    # MHA, single seq block
-    (8, 1, 16, 64, 16, 16, None),  # MQA, whole-chunk tile
-    (4, 2, 8, 40, 4, 16, None),    # non-dividing seq len -> gcd'd block
-    (4, 4, 8, 64, 8, 16, 2),       # KV-HEAD-CHUNKED grid (r6 wide-tile axis)
-    (4, 2, 8, 64, 8, 16, 1),       # one head per grid step
+# first positions that put a tile's diagonal AT 0, on a block's last position
+# (the block is seen whole), on a block's first, inside a block, and at the
+# cache's last tile — blocks of 32 in a cache of 128
+_DIAGONALS = (0, 31, 32, 40, 112)
+
+
+@pytest.mark.parametrize("qh,kv,d,s,bq,block,kv_chunk,dtype,pstart", [
+    (4, 2, 8, 64, 8, 16, None, "float32", None),    # GQA, multi-tile
+    (4, 4, 8, 32, 4, 32, None, "float32", None),    # MHA, single seq block
+    (8, 1, 16, 64, 16, 16, None, "float32", None),  # MQA, whole-chunk tile
+    (4, 2, 8, 40, 4, 16, None, "float32", None),    # gcd'd seq block
+    (4, 4, 8, 64, 8, 16, 2, "float32", None),       # KV-HEAD-CHUNKED grid
+    (4, 2, 8, 64, 8, 16, 1, "float32", None),       # one head per grid step
+    # a bf16 cache (bf16 operands into both contractions) against float32
+    # arithmetic on the same values: MHA and MQA, the head chunk planned
+    # and forced
+    (8, 8, 16, 128, 16, 32, None, "bfloat16", _DIAGONALS),
+    (8, 8, 16, 128, 16, 32, 4, "bfloat16", _DIAGONALS),
+    (8, 1, 16, 128, 16, 32, None, "bfloat16", _DIAGONALS),
+    (8, 1, 16, 128, 16, 32, 1, "bfloat16", _DIAGONALS),
+    (4, 2, 8, 128, 16, 32, None, "float32", _DIAGONALS),
 ])
-def test_prefill_kernel_matches_reference(qh, kv, d, s, bq, block, kv_chunk):
+def test_prefill_kernel_matches_reference(qh, kv, d, s, bq, block, kv_chunk,
+                                          dtype, pstart):
     """Per-slot equality vs the gather formulation, pads included: the
     kernel reconstructs every slot's position as pstart + b, so comparing
     against ref_attention at those same positions checks all rows."""
     rng = np.random.default_rng(0)
-    g = 3
+    pstart = jnp.asarray(pstart or (5, 0, s - bq), jnp.int32)
+    g = pstart.shape[0]
     t = g * bq
-    q = jnp.asarray(rng.normal(size=(g, bq, qh, d)), jnp.float32)
-    kc = jnp.asarray(rng.normal(size=(4, kv, s, d)), jnp.float32)
-    vc = jnp.asarray(rng.normal(size=(4, kv, s, d)), jnp.float32)
-    rows = jnp.asarray([0, 2, 1], jnp.int32)
-    pstart = jnp.asarray([5, 0, s - bq], jnp.int32)  # mid / start / end
+    q = jnp.asarray(rng.normal(size=(g, bq, qh, d)), dtype)
+    kc = jnp.asarray(rng.normal(size=(4, kv, s, d)), dtype)
+    vc = jnp.asarray(rng.normal(size=(4, kv, s, d)), dtype)
+    rows = jnp.asarray([0, 2, 1, 3, 0][:g], jnp.int32)
     scale = 1.0 / np.sqrt(d)
     got = prefill_attention(q, kc, vc, rows, pstart, scale,
                             block_s=block, kv_chunk=kv_chunk, interpret=True)
+    assert got.dtype == q.dtype
     flat_rows = jnp.repeat(rows, bq)
     flat_pos = (pstart[:, None] + jnp.arange(bq)[None, :]).reshape(-1)
     flat_pos = jnp.clip(flat_pos, 0, s - 1)
-    want = ref_attention(q.reshape(t, qh, d), kc, vc, flat_rows, flat_pos,
-                         scale)
+    f32 = lambda x: x.astype(jnp.float32)
+    want = ref_attention(f32(q).reshape(t, qh, d), f32(kc), f32(vc),
+                         flat_rows, flat_pos, scale)
+    # bf16: ``p`` and the output round to 8 bits of mantissa
+    tol = 1e-5 if dtype == "float32" else 2e-2
     np.testing.assert_allclose(
-        np.asarray(got).reshape(t, qh, d), np.asarray(want),
-        atol=1e-5, rtol=1e-5,
+        np.asarray(f32(got)).reshape(t, qh, d), np.asarray(want),
+        atol=tol, rtol=tol,
     )
+
+
+@pytest.mark.parametrize("dtype,form,kv,kv_chunk,window", [
+    # a float32 cache: the PARENT's arithmetic (float32 operands, the mask
+    # in every block), to the bit
+    ("float32", forms.PARENT, 8, None, 0),
+    ("float32", forms.PARENT, 8, 2, 0),
+    ("float32", forms.PARENT, 1, None, 0),
+    ("float32", forms.PARENT, 1, None, 48),
+    # a bf16 cache: skipping the mask where a block is seen whole changes
+    # no bit of the bf16-operand arithmetic
+    ("bfloat16", forms.NATIVE_MASKED, 8, None, 0),
+    ("bfloat16", forms.NATIVE_MASKED, 1, None, 0),
+])
+def test_prefill_kernel_equals_the_masked_everywhere_form_to_the_bit(
+        dtype, form, kv, kv_chunk, window):
+    rng = np.random.default_rng(3)
+    qh, d, s, bq, block = 8, 16, 128, 16, 32
+    pstart = jnp.asarray(_DIAGONALS, jnp.int32)
+    g = pstart.shape[0]
+    q = jnp.asarray(rng.normal(size=(g, bq, qh, d)), dtype)
+    kc = jnp.asarray(rng.normal(size=(4, kv, s, d)), dtype)
+    vc = jnp.asarray(rng.normal(size=(4, kv, s, d)), dtype)
+    rows = jnp.asarray([0, 2, 1, 3, 0], jnp.int32)
+    kw = dict(scale=1.0 / np.sqrt(d), block_s=block, kv_chunk=kv_chunk,
+              interpret=True, window=window)
+    got = prefill_attention(q, kc, vc, rows, pstart, **kw)
+    want = forms.prefill_attention_with(form, q, kc, vc, rows, pstart, **kw)
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+
+
+def _dot_generals(jaxpr):
+    """Every ``dot_general`` equation of ``jaxpr``, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _dot_generals(sub)
+
+
+@pytest.mark.parametrize("q_dtype,kv_dtype,operand", [
+    ("bfloat16", "bfloat16", "bfloat16"),   # a bf16 model: no up-cast
+    ("float32", "float32", "float32"),      # a float32 model: as before
+    ("float32", "bfloat16", "float32"),     # mixed: the wider of the two
+    ("bfloat16", "int8", "bfloat16"),       # int8 values are exact in bf16
+])
+def test_prefill_kernel_contracts_in_the_caches_type(q_dtype, kv_dtype,
+                                                     operand):
+    """Both contractions of every branch take their operands in
+    ``prefill_operand_dtype``'s type and accumulate in float32."""
+    from flexflow_tpu.ops.pallas.attention import prefill_operand_dtype
+
+    assert prefill_operand_dtype(jnp.dtype(q_dtype),
+                                 jnp.dtype(kv_dtype)) == operand
+    q = jnp.zeros((2, 16, 8, 16), q_dtype)
+    kc = jnp.zeros((3, 8, 128, 16), kv_dtype)
+    scales = {}
+    if kv_dtype == "int8":
+        scales = dict(k_scale=jnp.ones((3, 8, 128)),
+                      v_scale=jnp.ones((3, 8, 128)))
+    jaxpr = jax.make_jaxpr(lambda *a: prefill_attention(
+        *a, scale=0.25, block_s=32, interpret=True, **scales))(
+            q, kc, kc, jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32))
+    dots = list(_dot_generals(jaxpr.jaxpr))
+    # q.k' and p.v, in the masked and the unmasked branch
+    assert len(dots) == 4
+    for eqn in dots:
+        assert [v.aval.dtype for v in eqn.invars] == [operand, operand]
+        assert eqn.outvars[0].aval.dtype == jnp.float32
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_prefill_kernels_operand_type_is_counted(dtype):
+    """``attention_path.prefill_operands.<dtype>`` once the tiled prefill
+    scan has traced ``prefill_attention``: ``bfloat16`` in a bf16 model,
+    ``float32`` only in a float32 one."""
+    import dataclasses
+
+    from flexflow_tpu.obs import NULL_TELEMETRY, Telemetry
+
+    im = make_im(max_tokens=8, max_requests=2, max_seq=32, use_pallas=True,
+                 cfg=dataclasses.replace(TINY, dtype=dtype))
+    tel = Telemetry()
+    rm = RequestManager(im, GenerationConfig(max_new_tokens=2),
+                        telemetry=tel)
+    try:
+        im._paths_counted = 0
+        rm.generate([[5, 9, 2, 11, 3, 7, 1, 4, 6]])
+        assert im.attention_paths[
+            ("prefill_operands", "inc_multihead_self_attention")] == dtype
+        counters = tel.metrics.snapshot()
+        assert counters[f"attention_path.prefill_operands.{dtype}"] == 1
+        other = {"bfloat16": "float32", "float32": "bfloat16"}[dtype]
+        assert f"attention_path.prefill_operands.{other}" not in counters
+    finally:
+        im.telemetry = NULL_TELEMETRY
 
 
 @pytest.mark.parametrize("chunk", [4, 8, 16])

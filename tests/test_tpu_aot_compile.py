@@ -136,6 +136,8 @@ _CASES = [
     ("prefill", 8, 1, 128, 2048, "bf16"),
     ("decode", 1, 16, 128, 8192, "bf16"),
     ("prefill", 1, 16, 128, 8192, "bf16"),
+    # starcoderbase-3b's own: 22 query heads on the one K/V head
+    ("prefill", 1, 22, 128, 8192, "bf16"),
     # differential attention (phi4flash): 10 K/V pairs cached as heads of
     # 128, four zero-padded query heads each; the one full-length cache,
     # and a 512-window in a ring of 1024 slots
@@ -152,6 +154,7 @@ _CASES = [
     ("prefill", 1, 16, 128, 18432, "bf16"),
     # nemotron_h's one GQA layer in nine: 32 query heads on 2 K/V heads
     ("decode", 2, 16, 128, 8192, "bf16"),
+    ("prefill", 2, 16, 128, 8192, "bf16"),
 ]
 
 
@@ -192,6 +195,39 @@ def test_a_block_grown_by_bytes_compiles_in_the_default_scoped_vmem(
         r'used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
         r'"size":"(\d+)"', call)]
     assert used and 0 < max(used) < 16 * 2**20
+
+
+@pytest.mark.parametrize("kv,gq,s,variant,plan", [
+    (32, 1, 2048, "bf16", (16, 256)),       # opt-6.7b
+    (32, 1, 2048, "int8", (16, 256)),
+    (1, 22, 8192, "bf16", (1, 256)),        # starcoderbase-3b (MQA)
+    (1, 16, 4608, "ring4096", (1, 512)),    # cohere2_moe's sliding layers
+    (10, 4, 8192, "bf16", (2, 512)),        # phi4flash's full layer
+])
+def test_the_prefill_plans_vmem_count_is_never_below_the_compilers(
+        one_chip, kv, gq, s, variant, plan):
+    """``_prefill_plan`` answers what it answered before PR 60 at the cells'
+    geometries, the kernel asks for no more scoped VMEM than the default,
+    and ``_prefill_vmem_bytes`` — which the plan trusts — counts at least
+    what the compiler says the call uses."""
+    import re
+
+    from flexflow_tpu.ops.pallas.attention import (_prefill_plan,
+                                                   _prefill_vmem_bytes)
+
+    quant = variant == "int8"
+    item = 1 if quant else 2
+    assert _prefill_plan(kv, 128, 2, item, quant, TILE * gq, 512,
+                         s) == plan
+    text = _lower("prefill", one_chip, kv, gq, 128, s, variant).compile(
+        ).as_text()
+    call, = [ln for ln in text.splitlines() if "tpu_custom_call" in ln]
+    assert re.search(r'[^_]scoped_memory_configs":\[\]', call)
+    used = [int(n) for n in re.findall(
+        r'used_scoped_memory_configs":\[\{"memory_space":"1","offset":"0",'
+        r'"size":"(\d+)"', call)]
+    assert used and 0 < max(used) <= _prefill_vmem_bytes(
+        *plan[:1], TILE * gq, plan[1], 128, 2, item, quant) < 16 * 2**20
 
 
 @pytest.mark.parametrize("rows", [48, 512], ids=["scan48", "flat512"])
