@@ -68,7 +68,12 @@ _SET = {"scan_tokens": "scan_tokens", "join_tokens": "join_tokens",
         # a routed-expert graph's load, as ``commit`` sets it (0 elsewhere)
         "experts_visited": "experts_visited", "expert_pairs": "expert_pairs",
         "expert_pairs_max": "expert_pairs_max",
-        "expert_steps": "expert_steps"}
+        "expert_steps": "expert_steps",
+        # the same of the prompt-feeding launches (prefill scans, flat steps)
+        "prefill_experts_visited": "prefill_experts_visited",
+        "prefill_expert_pairs": "prefill_expert_pairs",
+        "prefill_expert_pairs_max": "prefill_expert_pairs_max",
+        "prefill_expert_chunks": "prefill_expert_chunks"}
 
 FIELDS = (
     # extent; ``tick_ns`` = the tick span's ``pc_ns`` (0: no tick ran)
@@ -94,6 +99,14 @@ FIELDS = (
     # their seconds: an OVERLAY (the time already lies under the launch
     # span that triggered the build), not part of the split
     "builds", "build_ns",
+    # the prompt-feeding launches' routed-expert load (as ``commit`` sets it:
+    # the decode scans' four above, of the prefill scans' chunks and the flat
+    # steps that fed prompt rows; ``prefill_expert_chunks`` = chunks x routed
+    # layers), and beside ``prompt_tokens`` the positions those prompt rows'
+    # plain RING layers read, sum of min(position + 1, window)
+    "prefill_experts_visited", "prefill_expert_pairs",
+    "prefill_expert_pairs_max", "prefill_expert_chunks",
+    "prompt_ring_ctx_sum",
 )
 _F = {name: i for i, name in enumerate(FIELDS)}
 _SPLIT_AT = {n: _F[f"{n}_ns"] for n in SPLIT}
@@ -121,6 +134,7 @@ _LOG = logging.getLogger("flexflow_tpu.serve")
 def _launch_step(row, args, chunk_width):
     row[_F["step_launches"]] += 1
     row[_F["prompt_tokens"]] += args.get("prompt_tokens", 0)
+    row[_F["prompt_ring_ctx_sum"]] += args.get("prompt_ring_ctx_sum", 0)
     _first_ctx(row, args)
 
 
@@ -145,6 +159,7 @@ def _launch_prefill_scan(row, args, chunk_width):
     row[_F["chunk_rows"]] += n * chunk_width
     row[_F["chunk_tokens"]] += fed
     row[_F["prompt_tokens"]] += fed
+    row[_F["prompt_ring_ctx_sum"]] += args.get("prompt_ring_ctx_sum", 0)
     row[_F["joiners"]] += args.get("joiners", 0)
 
 
@@ -371,7 +386,7 @@ class TickJournal:
     counts them.  4096 holds twenty runs of the benchmark's shortest-tick
     cell (``opt-6.7b-d12.decode-heavy``: 189 records for warm-up,
     rehearsal and the 51 s window, 165 of them the window's; my chip
-    runs, PR 46), as lists of 55 integers.
+    runs, PR 46), as lists of 60 integers.
     ``chunk_width``: rows of one prefill-scan chunk
     (``im.max_tokens``; ``chunk_rows`` = chunks x this).
     ``clock_ns``: the journal's clock, and the tick spans' ``pc_ns``

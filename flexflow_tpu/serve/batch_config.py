@@ -383,3 +383,7 @@ class InferenceResult:
     logits_max: jax.Array  # f32[max_tokens] (argmax logit, diagnostics)
     topk_ids: Optional[jax.Array] = None     # i32[max_tokens, k]
     topk_logprobs: Optional[jax.Array] = None  # f32[max_tokens, k]
+    # a routed graph's load this step, summed over its routed layers: i32[3]
+    # [experts visited, pairs, the fullest expert's pairs] (None, and nothing
+    # in the program, for a graph without such layers)
+    expert_load: Optional[jax.Array] = None

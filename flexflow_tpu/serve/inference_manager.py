@@ -370,6 +370,12 @@ def _pad_chunks(bcs, sample, n: int):
     return pads, sample
 
 
+def _summed_load(load):
+    """A scan's stacked per-step expert load summed over its steps (None for
+    a graph without routed layers: the program then returns nothing more)."""
+    return None if load is None else jnp.sum(load, axis=0)
+
+
 def decode_scan_width(bc) -> int:
     """Rows the decode scan's body runs on for the batch ``bc``: one per
     request slot.  A pure-decode batch holds at most one live row per
@@ -508,14 +514,16 @@ class InferenceManager:
         self.attention_paths: Dict[Tuple[str, Any], str] = {}
         self._paths_counted = 0
         # routed-expert layers (ops that leave a load count: MoEDispatch),
-        # and per decode scan dispatched and not yet collected ``(steps,
+        # and per launch dispatched and not yet collected ``(kind, steps,
         # int32[3] on the device: experts visited, pairs, the fullest
-        # expert's pairs — summed over steps and layers)``; the scheduler
-        # takes them with the stretch's readback (``take_expert_load``)
+        # expert's pairs — summed over steps and layers)``, ``kind`` one of
+        # ``EXPERT_LOAD_KEYS`` (a decode scan's steps; a prefill scan's
+        # chunks or a flat step that fed prompt rows); the scheduler takes
+        # them with its next readback (``take_expert_load``)
         self.expert_layers = sum(
             bool(getattr(n.op, "counts_load", False))
             for n in model.graph.nodes)
-        self.scan_expert_load: List[Tuple[int, Any]] = []
+        self.expert_load_pending: List[Tuple[str, int, Any]] = []
         if outputs is None:
             out_tids = [model.graph.nodes[-1].outputs[-1]]
         else:
@@ -746,8 +754,7 @@ class InferenceManager:
         return sample_tokens(logits, sample)
 
     def _step_impl(self, params, state, bc, sample=None, tree_layout=None,
-                   qkv0=None, pages=None, one_row_per_request=False,
-                   counters=None):
+                   qkv0=None, pages=None, one_row_per_request=False):
         # ``tree_layout`` is passed ONLY by SpecDecodeScan, whose verify
         # batches are guaranteed slot-major [R, P]; host-built tree batches
         # (SpecInferManager) have variable layouts and must not take the
@@ -759,10 +766,11 @@ class InferenceManager:
         # ``one_row_per_request`` (static; the decode scan passes it): every
         # live row is a request of its own, so an op with recurrent state
         # updates all rows at once instead of scanning them in order.
-        # ``counters``: a dict the caller hands in to collect what the ops
-        # count on the device in this step (a routed layer's load:
-        # ``{node: int32[3]}``, traced values of the caller's trace).
+        # A graph with routed layers collects what they count on the device
+        # in this step (``extras["counters"]``: ``{node: int32[3]}``) and
+        # returns the sum as the result's ``expert_load``.
         base = bc if isinstance(bc, BatchConfig) else bc.base
+        counters = {} if self.expert_layers else None
         outs, new_state = self._fwd(
             params,
             {self._token_tid: base.tokens},
@@ -792,8 +800,9 @@ class InferenceManager:
                 lp = jax.nn.log_softmax(logits, axis=-1)
                 topk_lp, topk_ids = jax.lax.top_k(lp, self.topk)
                 topk_ids = topk_ids.astype(jnp.int32)
+        load = sum(counters.values()) if counters else None
         return (
-            InferenceResult(token_ids, logits_max, topk_ids, topk_lp),
+            InferenceResult(token_ids, logits_max, topk_ids, topk_lp, load),
             new_state,
         )
 
@@ -840,6 +849,11 @@ class InferenceManager:
             result, self.state = with_stack_room(
                 self._step, self.params, self.state, bc, sample, None, None,
                 self._page_view())
+        if result.expert_load is not None and (counts or {}).get(
+                "prompt_tokens"):
+            # a flat step that fed prompt rows (decode rows ride along)
+            self.expert_load_pending.append(
+                ("prefill", 1, result.expert_load))
         self._count_attention_paths()
         return result
 
@@ -929,11 +943,9 @@ class InferenceManager:
             # the block table is CONSTANT across the scan: the manager's
             # prepare_write pre-mapped (and COW-resolved) every page the
             # n_steps positions can reach before dispatch
-            load = {} if self.expert_layers else None
             result, state = self._step_impl(params, state, bc, stp,
                                             pages=pages,
-                                            one_row_per_request=True,
-                                            counters=load)
+                                            one_row_per_request=True)
             toks = result.token_ids
             live = alive  # emission validity for THIS step
             with jax.named_scope("advance"):
@@ -953,18 +965,16 @@ class InferenceManager:
                         num_tokens=nxt.num_tokens,
                         seq_lens=nxt.seq_lens,
                     )
-            # the routed layers' load this step, summed over the layers:
-            # [experts visited, pairs, the fullest expert's pairs] (None,
-            # and nothing in the program, for a graph without such layers)
-            load = sum(load.values()) if load else None
-            return (state, nxt, alive, eos_hit), (toks, live, load)
+            # with the routed layers' load this step (None, and nothing in
+            # the program, for a graph without such layers)
+            return (state, nxt, alive, eos_hit), (toks, live,
+                                                  result.expert_load)
 
         eos_hit0 = jnp.zeros_like(alive0)
         (state, bc, alive_end, eos_hit), (tokens, live, load) = jax.lax.scan(
             body, (state, bc, alive0, eos_hit0), jnp.arange(n_steps)
         )
-        if load is not None:
-            load = jnp.sum(load, axis=0)
+        load = _summed_load(load)
         with jax.named_scope("advance"):
             ecode = jnp.where(
                 ~present, EXIT_NOT_IN_BATCH,
@@ -1082,30 +1092,48 @@ class InferenceManager:
             tokens, live, ecode, self.state, bc, load = with_stack_room(
                 self._scan, self.params, self.state, bc, sample,
                 self._page_view(), allowed, n_steps=n_steps, eos=eos)
-        if self.expert_layers:
-            self.scan_expert_load.append((n_steps, load))
+        if load is not None:
+            self.expert_load_pending.append(("scan", n_steps, load))
         if self.telemetry.enabled:
             self.telemetry.metrics.counter("decode_scan_steps").inc(n_steps)
         self._count_attention_paths()
         return tokens, live, ecode, bc
 
+    # ``take_expert_load``'s keys by kind of launch: experts visited, pairs,
+    # the fullest expert's pairs, and steps (chunks) x routed layers
+    EXPERT_LOAD_KEYS = {
+        "scan": ("experts_visited", "expert_pairs", "expert_pairs_max",
+                 "expert_steps"),
+        "prefill": ("prefill_experts_visited", "prefill_expert_pairs",
+                    "prefill_expert_pairs_max", "prefill_expert_chunks"),
+    }
+
     def take_expert_load(self):
-        """The routed layers' load of the decode scans dispatched since the
-        last call, read back: ``{"experts_visited", "expert_pairs",
-        "expert_pairs_max", "expert_steps"}`` (``expert_steps`` = scan steps
-        x routed layers), or None for a graph without routed layers or
-        when no scan ran."""
-        taken, self.scan_expert_load = self.scan_expert_load, []
+        """The routed layers' load of the launches dispatched since the last
+        call, read back — every one was dispatched before the result the
+        caller has just waited for, so nothing here waits: the decode scans'
+        ``{"experts_visited", "expert_pairs", "expert_pairs_max",
+        "expert_steps"}`` (``expert_steps`` = scan steps x routed layers) and
+        the prompt-feeding launches' ``prefill_*`` four
+        (``prefill_expert_chunks`` = prefill-scan chunks and flat steps that
+        fed prompt rows, x routed layers) — each kind's keys only where such
+        a launch ran.  None for a graph without routed layers or when none
+        ran."""
+        taken, self.expert_load_pending = self.expert_load_pending, []
         if not taken:
             return None
         import numpy as np
 
-        total = np.sum([np.asarray(load) for _, load in taken], axis=0)
-        return {"experts_visited": int(total[0]),
-                "expert_pairs": int(total[1]),
-                "expert_pairs_max": int(total[2]),
-                "expert_steps": self.expert_layers * sum(
-                    n for n, _ in taken)}
+        out = {}
+        for kind, keys in self.EXPERT_LOAD_KEYS.items():
+            loads = [(n, np.asarray(load)) for k, n, load in taken
+                     if k == kind]
+            if loads:
+                total = np.sum([load for _, load in loads], axis=0)
+                out.update(zip(keys, (
+                    *(int(v) for v in total),
+                    self.expert_layers * sum(n for n, _ in loads))))
+        return out
 
     def _join_impl(self, bc, tok_src, src_idx, dst, slot, pos, seq_len,
                    num_tokens, eos: Optional[int]):
@@ -1233,13 +1261,13 @@ class InferenceManager:
                 bc, i = xs[0], xs[1]
                 result, state = run_step(state, bc, i,
                                          xs[2] if per_row else None)
-                return state, result.token_ids
+                return state, (result.token_ids, result.expert_load)
 
-            state, tokens = jax.lax.scan(
+            state, (tokens, load) = jax.lax.scan(
                 body, state,
                 (bcs, idx, folds_all) if per_row else (bcs, idx))
             # tokens: i32[n_chunks, T or R]
-            return tokens, self._flat_last(tokens), state
+            return tokens, self._flat_last(tokens), state, _summed_load(load)
 
         # chunk i+1's batch config rides step i's xs; the final step
         # re-projects its own chunk (uniform program; output unused)
@@ -1254,13 +1282,13 @@ class InferenceManager:
             result, state = run_step(state, bc, i,
                                      xs[3] if per_row else None, qkv0=pre)
             pre_next = self._project_chunk0(params, bc_next)
-            return (state, pre_next), result.token_ids
+            return (state, pre_next), (result.token_ids, result.expert_load)
 
-        (state, _), tokens = jax.lax.scan(
+        (state, _), (tokens, load) = jax.lax.scan(
             body, (state, pre0),
             (bcs, bcs_next, idx, folds_all) if per_row
             else (bcs, bcs_next, idx))
-        return tokens, self._flat_last(tokens), state
+        return tokens, self._flat_last(tokens), state, _summed_load(load)
 
     def _flat_last(self, tokens):
         """The last chunk's token ids as ``join_slot``'s ``tok_src``:
@@ -1339,9 +1367,11 @@ class InferenceManager:
                                  kind="prefill_scan",
                                  n_steps=n_chunks, n_chunks=n_chunks,
                                  **(counts or {})):
-            tokens, last, self.state = with_stack_room(
+            tokens, last, self.state, load = with_stack_room(
                 self._pscan, self.params, self.state, bcs, sample,
                 self._page_view(), overlap=self._pscan_overlap)
+        if load is not None and not (counts or {}).get("pad"):
+            self.expert_load_pending.append(("prefill", n_chunks, load))
         self._count_attention_paths()
         return tokens, last
 

@@ -1,5 +1,5 @@
 from . import (cohere2_moe, deepseek_v2, evabyte, falcon,  # noqa: F401
-               kimi_linear, llama, minicpm_sala, mpt, nemotron_h, opt,
+               kimi_linear, llama, mellum, minicpm_sala, mpt, nemotron_h, opt,
                phi4flash, starcoder)
 from .base import MODEL_REGISTRY, ServeModelConfig, build_model
 

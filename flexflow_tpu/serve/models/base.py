@@ -181,6 +181,16 @@ class ServeModelConfig:
     moe_router_activation_func: str = "sigmoid"
     moe_renormalize: bool = True
     num_expert_group: int = 1
+    # mellum (``models/mellum.py``): ``rope_parameters`` NESTED by layer type
+    # — ``{"full_attention": {rope_type, rope_theta, ...}, "sliding_attention":
+    # {...}}``, one rotary parameter set per entry of ``layer_types`` —;
+    # ``mlp_layer_types[i]`` ``sparse`` (the mixture: ``num_experts`` of
+    # ``moe_intermediate_size``, top ``num_experts_per_tok`` by softmax,
+    # ``norm_topk_prob``) or ``dense`` (a gated MLP of ``intermediate_size``);
+    # ``attention_bias`` must be false
+    rope_parameters: Optional[dict] = None
+    mlp_layer_types: Optional[tuple] = None
+    attention_bias: bool = False
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

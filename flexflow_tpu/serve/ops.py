@@ -355,7 +355,10 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     the ``D / 2`` turns by ``position * theta^(-2 i / D)`` — or by YaRN's
     frequencies (``yarn``: :func:`rope_frequencies`) —: the pair is
     ``(x[i], x[i + D / 2])`` (half against half, GPT-NeoX's), or with
-    ``interleaved`` ``(x[2 i], x[2 i + 1])`` (GPT-J's, ``rope_gptj``)."""
+    ``interleaved`` ``(x[2 i], x[2 i + 1])`` (GPT-J's, ``rope_gptj``).
+    Under ``yarn`` cos and sin are scaled by the ``attention_factor`` it
+    STATES, else by ``yarn_mscale(s, mscale) / yarn_mscale(s,
+    mscale_all_dim)``."""
     d = x.shape[-1]
     half = d // 2
     freq = rope_frequencies(half, theta, yarn)
@@ -366,8 +369,12 @@ def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
     sin = jnp.sin(angles).reshape(shape)
     if yarn:
         scale = float(yarn.get("factor", 1.0))
-        amp = (yarn_mscale(scale, float(yarn.get("mscale", 1.0)))
-               / yarn_mscale(scale, float(yarn.get("mscale_all_dim", 0.0))))
+        amp = yarn.get("attention_factor")
+        if amp is None:
+            amp = (yarn_mscale(scale, float(yarn.get("mscale", 1.0)))
+                   / yarn_mscale(scale,
+                                 float(yarn.get("mscale_all_dim", 0.0))))
+        amp = float(amp)
         if amp != 1.0:
             cos, sin = cos * amp, sin * amp
     if interleaved:
@@ -425,7 +432,16 @@ class IncMultiHeadSelfAttention(Op):
         scaling_factor: Optional[float] = None,
         use_alibi: bool = False,
         dtype=jnp.float32,
+        rope_scaling: Optional[dict] = None,
     ):
+        # ``rope_scaling`` (type ``yarn``): the rotary turns by YaRN's
+        # frequencies and amplitude (``apply_rope``'s ``yarn``); None: plain
+        # ``rope_theta``
+        if rope_scaling and rope_scaling.get(
+                "rope_type", rope_scaling.get("type")) != "yarn":
+            raise ValueError("the attention's rotary scaling is YaRN or none "
+                             f"(rope_scaling {rope_scaling!r})")
+        self.rope_scaling = dict(rope_scaling) if rope_scaling else None
         self.embed_dim = int(embed_dim)
         self.num_q_heads = int(num_q_heads)
         self.num_kv_heads = int(num_kv_heads or num_q_heads)
@@ -629,8 +645,8 @@ class IncMultiHeadSelfAttention(Op):
         v = qkv[:, :, self.q_per_kv + 1, :]        # [T, KV, D]
         if self.rotary_embedding:
             pos = base.token_position
-            q = apply_rope(q, pos, self.rope_theta)
-            k = apply_rope(k, pos, self.rope_theta)
+            q = apply_rope(q, pos, self.rope_theta, yarn=self.rope_scaling)
+            k = apply_rope(k, pos, self.rope_theta, yarn=self.rope_scaling)
         return q, k, v
 
     def _rows(self, bc_base: BatchConfig, max_requests: int):

@@ -380,6 +380,7 @@ class RequestManager:
             "prompt_tokens": sum(hi - lo for _, lo, hi in pre),
             "ctx_sum": sum(hi for _, _, hi in dec),
             **self._ring_counts(hi for _, _, hi in dec),
+            **self._prompt_ring_counts((lo, hi) for _, lo, hi in pre),
             "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
                                   for _, lo, hi in pre),
             **self._slot_state_counts([(lo, hi) for _, lo, hi in spans]),
@@ -395,6 +396,19 @@ class RequestManager:
             return {}
         return {"ring_ctx_sum": sum(min(c, self._ring) for c in contexts)}
 
+    def _prompt_ring_counts(self, writes) -> Dict[str, int]:
+        """For the same graphs, of a launch that feeds the prompt positions
+        ``writes`` = ``[(lo, hi)]``: ``prompt_ring_ctx_sum``, sum over those
+        rows of ``min(position + 1, window)`` — the window kernel's least
+        work, beside the full-context sum (``prompt_ctx_sum``)."""
+        if self._ring is None:
+            return {}
+        w = self._ring
+        tri = lambda n: n * (n + 1) // 2    # 1 + 2 + .. + n
+        return {"prompt_ring_ctx_sum": sum(
+            tri(min(hi, w)) - tri(min(lo, w)) + w * (max(hi, w) - max(lo, w))
+            for lo, hi in writes)}
+
     def _slot_state_counts(self, writes) -> Dict[str, int]:
         """Dispatch-span arguments (and counters) of a launch that writes
         the positions ``[(lo, hi)]``, one pair a row, for the kinds of
@@ -402,15 +416,22 @@ class RequestManager:
         return {**self._compact_counts(writes), **self._sparse_counts(writes)}
 
     def _expert_load(self) -> Dict[str, int]:
-        """For a graph with routed-expert layers: what the decode scans of
-        the stretch just read back counted on the device, summed over steps
-        and layers — ``experts_visited`` (held experts that got a row),
-        ``expert_pairs`` (pairs on held experts), ``expert_pairs_max`` (the
-        fullest expert's pairs) and ``expert_steps`` (scan steps x routed
-        layers) — as arguments of the ``commit`` span (and so fields of the
-        tick's journal record) and, per tick, the trace counters
-        ``moe.experts_visited`` / ``moe.pairs`` / ``moe.pairs_max``.
-        Nothing for a graph with none, or a manager that counts none."""
+        """For a graph with routed-expert layers: what the launches read
+        back since the last call counted on the device, summed over steps
+        and layers — of the decode scans ``experts_visited`` (held experts
+        that got a row), ``expert_pairs`` (pairs on held experts),
+        ``expert_pairs_max`` (the fullest expert's pairs) and
+        ``expert_steps`` (scan steps x routed layers); of the prefill scans
+        and the flat steps that fed prompt rows the same four as
+        ``prefill_experts_visited``, ``prefill_expert_pairs``,
+        ``prefill_expert_pairs_max`` and ``prefill_expert_chunks`` — as
+        arguments of the ``commit`` span (and so fields of the tick's
+        journal record) and, per tick, the trace counters
+        ``moe.experts_visited`` / ``moe.pairs`` / ``moe.pairs_max`` and
+        ``moe.prefill_visited`` / ``moe.prefill_pairs``.  Called inside a
+        ``readback`` span, after its wait: every launch it reads was
+        dispatched before the result that wait was for.  Nothing for a graph
+        with none, or a manager that counts none."""
         take = getattr(self.im, "take_expert_load", None)
         load = take() if take is not None else None
         if not load:
@@ -419,9 +440,13 @@ class RequestManager:
         if tel.enabled:
             for name, key in (("moe.experts_visited", "experts_visited"),
                               ("moe.pairs", "expert_pairs"),
-                              ("moe.pairs_max", "expert_pairs_max")):
-                tel.metrics.counter(name).inc(load[key])
-                tel.trace.counter(name, load[key])
+                              ("moe.pairs_max", "expert_pairs_max"),
+                              ("moe.prefill_visited",
+                               "prefill_experts_visited"),
+                              ("moe.prefill_pairs", "prefill_expert_pairs")):
+                if key in load:
+                    tel.metrics.counter(name).inc(load[key])
+                    tel.trace.counter(name, load[key])
         return load
 
     def _sparse_counts(self, writes) -> Dict[str, int]:
@@ -1457,8 +1482,9 @@ class RequestManager:
         with self._span("readback", phase=True):
             self._device_wait(result.token_ids)
             token_ids = np.asarray(result.token_ids)
+            expert_load = self._expert_load()
         self.profiler.host_sync()
-        with self._span("commit") as sp:
+        with self._span("commit", **expert_load) as sp:
             before = self.tokens_decoded
             for flat_idx, rid in sample_points:
                 req = self.requests[rid]
@@ -1848,6 +1874,8 @@ class RequestManager:
             cnt = {"rows": rows, "joiners": len(joiners or ()),
                    "prompt_tokens": fed, "segments": len(parts),
                    "ctx_sum": sum(st for st, _ in parts),
+                   **self._prompt_ring_counts(
+                       (st, st + t) for st, t in parts),
                    **self._slot_state_counts(
                        [(st, st + t) for st, t in parts])}
             res = self._guarded(
@@ -1887,9 +1915,10 @@ class RequestManager:
         with self._span("readback", phase=True):
             self._device_wait([t for _, t, _ in outs])
             toks = {start: np.asarray(t) for start, t, _ in outs}  # one sync
+            expert_load = self._expert_load()
         self.profiler.host_sync(len(outs))
         starts = sorted(toks)
-        with self._span("commit", prefill_tokens=len(points)):
+        with self._span("commit", prefill_tokens=len(points), **expert_load):
             for chunk_idx, flat_idx, rid in points:
                 start = max(s for s in starts if s <= chunk_idx)
                 req = self.requests[rid]

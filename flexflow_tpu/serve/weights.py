@@ -336,6 +336,38 @@ COHERE2_MOE_TENSORS = {
 }
 
 
+# The published tensor names of ``mellum`` (Mellum 2), for an importer to be
+# written when a checkpoint is in the repository (none is: they are the
+# family's convention and stay ASSUMED until one is).  ``{torch tensor: (graph
+# node, parameter, layout)}`` per layer ``model.layers.<l>.``, beside the
+# reference's tables (``benchmark/reference/mellum.py`` ``LAYER`` /
+# ``program_tree``: the same map in kernel form, ``[in, out]``): a projection
+# transposes; q, k and v fuse per K/V head as ``IncMultiHeadSelfAttention``'s
+# do (the ring layers' ``SlidingWindowAttention`` has the same layout); the
+# experts ``mlp.experts.<e>`` stack into ``[E, in, out]``; a ``dense`` layer
+# of ``mlp_layer_types`` (the published list has none) carries
+# ``mlp.{gate,up,down}_proj`` instead of the router and the experts.
+MELLUM_TENSORS = {
+    "model.embed_tokens.weight": ("model.embed_tokens", "weight", "[V, d]"),
+    "model.norm.weight": ("model.norm", "gamma", "[d]"),
+    "lm_head.weight": ("lm_head", "kernel", "[d, V] = .T"),
+    "input_layernorm.weight": ("input_layernorm", "gamma", "[d]"),
+    "post_attention_layernorm.weight":
+        ("post_attention_layernorm", "gamma", "[d]"),
+    "self_attn.q_proj.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.k_proj.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.v_proj.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.o_proj.weight": ("self_attn", "o_proj", "[H hd, d] = .T"),
+    "mlp.gate.weight": ("mlp.gate", "weight", "[d, experts] = .T, float32"),
+    "mlp.experts.<e>.gate_proj.weight": ("mlp.experts", "gate", "[e] = .T"),
+    "mlp.experts.<e>.up_proj.weight": ("mlp.experts", "up", "[e] = .T"),
+    "mlp.experts.<e>.down_proj.weight": ("mlp.experts", "down", "[e] = .T"),
+    "mlp.gate_proj.weight": ("mlp.gate_proj", "kernel", "[d, I] = .T"),
+    "mlp.up_proj.weight": ("mlp.up_proj", "kernel", "[d, I] = .T"),
+    "mlp.down_proj.weight": ("mlp.down_proj", "kernel", "[I, d] = .T"),
+}
+
+
 # The published tensor names of ``deepseek_v2`` (DeepSeek-V2-Lite: no
 # ``q_a_proj`` / ``q_b_proj``, ``q_lora_rank`` null), for an importer to be
 # written when a checkpoint is in the repository (none is: they are the

@@ -145,4 +145,6 @@ def row_write_on_and_off(monkeypatch):
         for a, b in zip(jax.tree.leaves(state_on), jax.tree.leaves(state_off)):
             np.testing.assert_array_equal(a[:-1], b[:-1])
 
+    # for a test that compares two deployments it already holds
+    check.serve = serve
     return check

@@ -23,8 +23,6 @@ the full layer, shared experts summed or an un-normalised top-8 move it by
 4e-3 or more (``test_a_break_is_seen`` holds that).
 """
 
-import functools
-import json
 import os
 import sys
 
@@ -39,24 +37,15 @@ if ROOT not in sys.path:
 
 from benchmark import check, seeded_weights as sw  # noqa: E402
 from benchmark.reference import cohere2_moe as ref  # noqa: E402
-from flexflow_tpu.config import FFConfig  # noqa: E402
 from flexflow_tpu.core.op import OpContext  # noqa: E402
-from flexflow_tpu.model import FFModel  # noqa: E402
 from flexflow_tpu.ops.pallas.attention import (  # noqa: E402
     decode_attention,
     prefill_attention,
 )
-from flexflow_tpu.parallel.mesh import make_mesh  # noqa: E402
-from flexflow_tpu.serve import BatchConfig  # noqa: E402
 from flexflow_tpu.serve import hybrid_ops, ops as serve_ops  # noqa: E402
 from flexflow_tpu.serve.hybrid_ops import (  # noqa: E402
     SlidingWindowAttention,
     SlotCacheAttention,
-)
-from flexflow_tpu.serve.inference_manager import InferenceManager  # noqa: E402
-from flexflow_tpu.serve.models.base import (  # noqa: E402
-    ServeModelConfig,
-    build_model,
 )
 from flexflow_tpu.serve.ops import IncMultiHeadSelfAttention  # noqa: E402
 from flexflow_tpu.serve.ssd_moe_ops import (  # noqa: E402
@@ -66,6 +55,8 @@ from flexflow_tpu.serve.ssd_moe_ops import (  # noqa: E402
     MoERouter,
     SharedExpertLinear,
 )
+
+from reference_rig import Rig  # noqa: E402
 
 WINDOW = 48
 HF = dict(model_type="cohere2_moe", vocab_size=320, hidden_size=64,
@@ -89,112 +80,14 @@ TOL = 2e-4          # nats, see the module docstring
 SEED = 4321
 
 
-def build(cap=CAP, seq=SEQ, use_pallas=False, hf=HF, slots=SLOTS, **kw):
-    mesh = make_mesh({"tp": 1}, jax.devices()[:1])
-    ff = FFModel(FFConfig(), mesh=mesh)
-    build_model(ff, ServeModelConfig.from_hf_config(hf), cap)
-    return InferenceManager(ff, max_requests=slots, max_tokens_per_batch=cap,
-                            max_seq_len=seq, topk=HF["vocab_size"],
-                            use_pallas=use_pallas, **kw)
-
-
-def seeded(im, hf=HF):
-    im.init_operators_inference()
-    like = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding),
-        im.params)
-    im.params = sw.program_params(ref, hf, sw.base_key(SEED), like, "float32")
-    return im
-
-
-@functools.lru_cache(maxsize=None)
-def deployment(use_pallas=False):
-    """One compiled deployment per kernel setting, shared by the tests (each
-    starts its sequences at position 0 of a slot, which is all a slot needs
-    to start clean)."""
-    return seeded(build(use_pallas=use_pallas))
-
-
-@functools.lru_cache(maxsize=None)
-def _ref_layer(padded_len, hf_items):
-    hf = dict(json.loads(hf_items))
-    return jax.jit(lambda key, i, x: ref.layer(
-        hf, sw.draw_table(key, i, ref.LAYER, hf, "float32"), x))
-
-
-def reference_logprobs(ids, hf=HF):
-    """The reference's full forward pass of ``ids``: sorted
-    log-probabilities at every position, and its greedy tokens."""
-    key = sw.base_key(SEED)
-    g = sw.draw_table(key, sw.GLOBAL_ID, ref.GLOBAL, hf, "float32")
-    padded = np.zeros(-(-len(ids) // 64) * 64, np.int32)
-    padded[:len(ids)] = ids
-    x = ref.embed(hf, g, jnp.asarray(padded[None]))
-    layer = _ref_layer(len(padded), json.dumps(sorted(hf.items())))
-    for i in range(ref.num_layers(hf)):
-        x = layer(key, jnp.int32(i), x)
-    logits = ref.head(hf, g, x[:, :len(ids)])[0]
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    return (np.asarray(jnp.sort(lp, axis=-1)[:, ::-1]),
-            np.asarray(jnp.argmax(logits, axis=-1)))
-
-
-def tokens(n, salt=0):
-    rng = np.random.default_rng([SEED, salt])
-    return rng.integers(4, HF["vocab_size"], size=n).tolist()
-
-
-def flat_step(im, pieces, seq_lens):
-    """One flat step holding ``pieces`` = [(slot, ids, start position)];
-    returns the sorted log-probabilities per piece, and the tokens."""
-    toks, slots, pos = [], [], []
-    for slot, ids, start in pieces:
-        toks += list(ids)
-        slots += [slot] * len(ids)
-        pos += list(range(start, start + len(ids)))
-        seq_lens[slot] = start + len(ids)
-    bc = BatchConfig.build(toks, slots, pos, seq_lens,
-                           max_tokens=im.max_tokens,
-                           max_requests=im.max_requests)
-    res = im.step(bc)
-    lp, out, at = np.asarray(res.topk_logprobs), [], 0
-    for _, ids, _ in pieces:
-        out.append(lp[at:at + len(ids)])
-        at += len(ids)
-    return out, np.asarray(res.token_ids)
-
-
-def feed_flat(im, slot, ids, sizes, seq_lens):
-    """``ids`` into ``slot`` from position 0 by flat steps of the given
-    sizes (cycled); the log-probabilities at every position."""
-    rows, at, i = [], 0, 0
-    while at < len(ids):
-        take = min(sizes[i % len(sizes)], len(ids) - at)
-        (lp,), _ = flat_step(im, [(slot, ids[at:at + take], at)], seq_lens)
-        rows.append(lp)
-        at, i = at + take, i + 1
-    return np.concatenate(rows)
-
-
-def decode_scan(im, slot, first, position, steps):
-    """``steps`` decode steps of ``slot`` on the device, in chained scans of
-    at most 32: the tokens produced after ``first`` (fed at ``position``)."""
-    seq = np.zeros(im.max_requests, np.int32)
-    seq[slot] = position + 1
-    bc = BatchConfig.build([first], [slot], [position], seq,
-                           max_tokens=im.max_tokens,
-                           max_requests=im.max_requests)
-    out, done = [], 0
-    while done < steps:
-        n = min(32, steps - done)
-        allowed = np.zeros(im.max_tokens, np.int32)
-        allowed[0] = steps - done
-        toks, live, _, bc = im.decode_scan_async(
-            bc, n, allowed=allowed, max_position=position + done)
-        assert np.asarray(live)[:, 0].all()
-        out += np.asarray(toks)[:, 0].tolist()
-        done += n
-    return out
+# ONE built deployment per kernel setting and process, reset between uses
+# (``tests/reference_rig.py``): the seeded weights, the reference's forward
+# pass and the three ways the tests drive the program
+RIG = Rig(ref, HF, SLOTS, CAP, SEQ, SEED)
+build, seeded, deployment = RIG.build, RIG.seeded, RIG.deployment
+reference_logprobs, tokens = RIG.reference_logprobs, RIG.tokens
+flat_step, feed_flat, decode_scan = (RIG.flat_step, RIG.feed_flat,
+                                     RIG.decode_scan)
 
 
 # 3 windows and a bit: every ring has wrapped, chunk 3 (96..143) wraps it
@@ -951,6 +844,17 @@ def test_a_layers_block_plan_is_noted_by_its_cache(cache, kw, want):
 
 def test_row_write_kernel_on_and_off_serves_the_same(row_write_on_and_off):
     """The decode scan's K/V rows by ``kv_row_write`` and by the chain it
-    replaced — rings and the full cache: the same tokens, the same caches."""
-    row_write_on_and_off(lambda: seeded(build(use_pallas=True)),
-                         [tokens(40, salt=51), tokens(9, salt=52)])
+    replaced — rings and the full cache — on the two deployments this module
+    already built (kernels on: the aliased call; kernels off: the chain):
+    the same tokens, and the same caches off the scratch row to float32's
+    rounding (between two writes stands the attention, a kernel on one side
+    and XLA on the other: the sums' order differs, the values do not)."""
+    prompts = [tokens(40, salt=51), tokens(9, salt=52)]
+    on, took_on, state_on = row_write_on_and_off.serve(
+        deployment(use_pallas=True), prompts, 6)
+    off, took_off, state_off = row_write_on_and_off.serve(
+        deployment(use_pallas=False), prompts, 6)
+    assert took_on == {"pallas"} and took_off == {"dus_chain"}
+    assert on == off and all(len(o) == 6 for o in on)
+    for a, b in zip(jax.tree.leaves(state_on), jax.tree.leaves(state_off)):
+        np.testing.assert_allclose(a[:-1], b[:-1], atol=2e-5, rtol=0)

@@ -155,6 +155,11 @@ _CASES = [
     # nemotron_h's one GQA layer in nine: 32 query heads on 2 K/V heads
     ("decode", 2, 16, 128, 8192, "bf16"),
     ("prefill", 2, 16, 128, 8192, "bf16"),
+    # mellum's sliding layers: 32 query heads on 4 K/V heads, a window of
+    # 1024 in a ring of 2048 slots (the window + a 1024-row prompt chunk):
+    # half the key blocks of a tile lie outside its window
+    ("decode", 4, 8, 128, 2048, "ring1024"),
+    ("prefill", 4, 8, 128, 2048, "ring1024"),
 ]
 
 
@@ -665,6 +670,42 @@ def test_softmax_routed_layer_compiles_for_v5e(one_chip, rows):
                                         "pallas_decode": True})
         ids, w = MoERouter(d, held, k, dtype=x.dtype, bias=False,
                            norm_topk=False, scoring="softmax").lower(
+            ctx(), [x], {"weight": router})
+        xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
+            ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
+        return MoECombine(held, dtype=x.dtype).lower(
+            ctx(), [ys, order, ids, w], {})[0]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((d, held), jnp.float32),
+        sds((held, d, f), jnp.bfloat16), sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+@pytest.mark.parametrize("rows", [16, 1024], ids=["scan16", "chunk1024"])
+def test_mellum_routed_layer_compiles_for_v5e(one_chip, rows):
+    """The routed-expert layer at Mellum 2's published widths (hidden 2304, a
+    softmax router over 64 experts with top-8 renormalised, ALL 64 gated
+    experts of width 896 = 7 x 128 held) on the decode scan's 16 rows and on
+    a prompt chunk's 1024 (8 192 pairs: 128 rows an expert): the tiles
+    ``MoEExperts.out_tile`` plans from the shapes — 896 whole into the hidden
+    width, 2 tiles of 1152 out of it."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.ssd_moe_ops import (MoECombine, MoEDispatch,
+                                                MoEExperts, MoERouter)
+
+    d, f, held, k = 2304, 896, 64, 8
+    assert (MoEExperts.out_tile(d, f, 2), MoEExperts.out_tile(f, d, 2)) == \
+        (896, 1152)
+
+    def layer(x, router, gate, up, down):
+        ctx = lambda: OpContext(extras={"node_name": "n",
+                                        "pallas_decode": True})
+        ids, w = MoERouter(d, held, k, dtype=x.dtype, bias=False,
+                           norm_topk=True, scoring="softmax").lower(
             ctx(), [x], {"weight": router})
         xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
         ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
