@@ -420,11 +420,11 @@ class FFModel:
                          name or "moe_dispatch")
 
     def moe_experts(self, xs, sizes, num_held, width, form="relu2",
-                    name=None):
+                    num_scored=None, name=None):
         from .serve.ssd_moe_ops import MoEExperts
 
         op = MoEExperts(num_held, xs.shape[-1], width, dtype=xs.dtype,
-                        form=form)
+                        form=form, num_scored=num_scored)
         return self._add(op, [xs, sizes], name or "moe_experts")[0]
 
     def shared_expert_dense(self, x, out_dim, name=None):

@@ -102,6 +102,7 @@ def build_deepseek_v2(ff, cfg: ServeModelConfig, max_tokens: int):
             xs, sizes, order = ff.moe_dispatch(n, ids, held, held_lo,
                                                name=f"{p}.mlp.dispatch")
             ys = ff.moe_experts(xs, sizes, held, f, form="swiglu",
+                                num_scored=scored,
                                 name=f"{p}.mlp.experts")
             m = ff.moe_combine(ys, order, ids, w, held, held_lo,
                                dtype=n.dtype, name=f"{p}.mlp.combine")

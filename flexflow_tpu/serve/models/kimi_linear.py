@@ -133,6 +133,7 @@ def build_kimi_linear(ff, cfg: ServeModelConfig, max_tokens: int):
             xs, sizes, order = ff.moe_dispatch(n, ids, held, held_lo,
                                                name=f"{moe}.dispatch")
             ys = ff.moe_experts(xs, sizes, held, f, form="swiglu",
+                                num_scored=scored,
                                 name=f"{moe}.experts")
             m = ff.moe_combine(ys, order, ids, w, held, held_lo,
                                dtype=n.dtype, name=f"{moe}.combine")

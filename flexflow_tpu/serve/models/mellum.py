@@ -121,7 +121,8 @@ def build_mellum(ff, cfg: ServeModelConfig, max_tokens: int):
             xs, sizes, order = ff.moe_dispatch(n, ids, held, held_lo,
                                                name=f"{p}.mlp.dispatch")
             ys = ff.moe_experts(xs, sizes, held, cfg.moe_intermediate_size,
-                                form="swiglu", name=f"{p}.mlp.experts")
+                                form="swiglu", num_scored=scored,
+                                name=f"{p}.mlp.experts")
             m = ff.moe_combine(ys, order, ids, w, held, held_lo,
                                dtype=n.dtype, name=f"{p}.mlp.combine")
         x = ff.add(x, m, name=f"{p}.residual")

@@ -95,6 +95,7 @@ def build_nemotron_h(ff, cfg: ServeModelConfig, max_tokens: int):
             xs, sizes, order = ff.moe_dispatch(
                 a, ids, held, held_lo, name=f"{p}.mixer.dispatch")
             ys = ff.moe_experts(xs, sizes, held, cfg.moe_intermediate_size,
+                                num_scored=scored,
                                 name=f"{p}.mixer.experts")
             h = ff.moe_combine(ys, order, ids, w, held, held_lo,
                                dtype=a.dtype, name=f"{p}.mixer.combine")

@@ -408,13 +408,42 @@ class MoEExperts(_Replicated):
     * ``swiglu``: ``down_e(silu(gate_e x) * up_e x)`` — ``gate`` and ``up``
       ``[E, d, f]``, ``down [E, f, d]``.
 
-    Megablox's Pallas kernel where the kernels are on, which streams a
-    visited expert's matrix once per row tile it touches and no unvisited
-    expert's; ``lax.ragged_dot`` otherwise (the CPU oracle).  Rows past the
-    groups' sum are whatever the kernel left there: ``MoECombine`` reads
-    none of them.
+    Pallas kernels where the kernels are on — sorted groups, dropless, the
+    visited experts only: a grid step is one (group, row tile) pair, and a
+    visited expert's matrix is streamed once per RUN of consecutive steps of
+    one group and output tile (the pipeline skips a block whose index did
+    not change), no unvisited expert's ever —; ``lax.ragged_dot`` otherwise
+    (the CPU oracle).  Rows past the groups' sum are whatever the kernel
+    left there: ``MoECombine`` reads none of them.
 
-    The kernel's tiles (:meth:`out_tile`, from the GEMM's shapes and
+    TWO PLANS, by the rows a held expert can EXPECT in the call — its sorted
+    pairs over the experts the graph's router scores (``num_scored``: the
+    model builder's, out of the configuration; :meth:`group_ahead`):
+
+    * below a row tile (``GMM_ROWS``: every decode scan; a chunk of a graph
+      that holds a share of its experts) nearly every step changes group and
+      the GEMMs are honestly weight-bound: megablox's ``gmm``, three calls
+      (gate, up, down) with the product between them in XLA.
+    * a row tile or more (``mellum``'s chunk: 8192 pairs on 64 of 64) a
+      group is several steps long and the pipeline's fetch of the NEXT
+      group's matrix — asked for one step ahead — has one 128-row step to
+      hide behind: ``ops/pallas/grouped_ffn.py``, two calls.  Gate and up
+      are ONE kernel with the product in its epilogue (the row tile read
+      once, float32 products, one cast, bf16 out; no ``[M, f]`` float32 in
+      HBM), the down projection another; both keep the weights in HBM and
+      copy a visited group's blocks into one of two VMEM slots a GROUP
+      ahead.  Its working set at output tile ``t``, ``w`` matrices a call:
+      two slots a matrix ``2 w c t b``, the pipeline's row tiles ``2 x 128
+      c b`` and output tiles ``2 x 128 t o``, the float32 products ``(w +
+      1) 512 t`` — held to 48 MiB of the v5e's 128, the call's
+      ``vmem_limit_bytes`` set from the sum (the scoped default is 16 MiB).
+      ``swiglu`` at 2304 x 896: gate and up 16.5 + 1.2 + 0.5 + 1.4 =
+      19.5 MB, 896 whole; down 8.3 + 0.5 + 2.4 + 2.4 = 13.4 MB, 2304 whole.
+      At 4096 x 4096: 4 column tiles of 1024 (37.7 MB), down 2 of 2048
+      (39.8 MB).  Widths that are not whole lanes (``nemotron_h``'s 1856)
+      stay on megablox: the plan's copies move whole tiles.
+
+    Megablox's tiles (:meth:`out_tile`, from the GEMM's shapes and
     ``VMEM_BUDGET``; rows ``GMM_ROWS``): the contraction WHOLE — no k loop,
     so a row tile's block index does not change while the grid walks the
     experts that share it and it is fetched once per output tile —, the
@@ -446,7 +475,8 @@ class MoEExperts(_Replicated):
       1024, at most 1948, so 2 tiles of 2304: 1152: rows 0.26 MB, weights
       2.4 MB, output 0.6 MB: 7.0 MB.
 
-    The TPU compiler takes all four (tests/test_tpu_aot_compile.py)."""
+    The TPU compiler takes all four, and the group-ahead plan at both of
+    its shapes (tests/test_tpu_aot_compile.py)."""
 
     type_name = "moe_experts"
     FORMS = ("relu2", "swiglu")
@@ -454,11 +484,14 @@ class MoEExperts(_Replicated):
     VMEM_BUDGET = 11.5e6
 
     def __init__(self, num_held: int, embed_dim: int, width: int,
-                 dtype=jnp.float32, form: str = "relu2"):
+                 dtype=jnp.float32, form: str = "relu2", num_scored=None):
         if form not in self.FORMS:
             raise ValueError(f"an expert's form is one of "
                              f"{sorted(self.FORMS)}, not {form!r}")
         self.num_held = int(num_held)
+        # the experts the graph's router scores (the model builder's, out of
+        # the configuration): a call's pairs spread over ALL of them
+        self.num_scored = int(num_scored or num_held)
         self.embed_dim = int(embed_dim)
         self.width = int(width)
         self.form = form
@@ -495,29 +528,48 @@ class MoEExperts(_Replicated):
         tiles = -(-n // most)
         return -(-n // (tiles * 128)) * 128
 
+    def group_ahead(self, pairs: int) -> bool:
+        """Whether a call of ``pairs`` sorted rows takes the group-ahead
+        plan: a held expert can EXPECT a row tile or more — the pairs over
+        the experts the router scores — and both widths are whole lanes (the
+        plan's own copies move whole tiles of a matrix)."""
+        return pairs // self.num_scored >= GMM_ROWS \
+            and self.embed_dim % 128 == 0 and self.width % 128 == 0
+
     def lower(self, ctx, inputs, params):
         xs, sizes = inputs
-        if ctx.extras.get("pallas_decode"):
-            from jax.experimental.pallas.ops.tpu.megablox import gmm
+        interp = bool(ctx.extras.get("pallas_interpret"))
+        if ctx.extras.get("pallas_decode") and self.group_ahead(xs.shape[0]):
+            from ..ops.pallas.grouped_ffn import grouped_ffn
 
-            interp = bool(ctx.extras.get("pallas_interpret"))
-            grouped = lambda a, w, out_tile: gmm(
-                a, w, sizes, jnp.float32,
-                (GMM_ROWS, w.shape[1], out_tile), interpret=interp)
-            path = "megablox_gmm"
+            into = ("gate", "up") if self.form == "swiglu" else ("up",)
+            h = grouped_ffn(xs, tuple(params[n] for n in into), sizes,
+                            form=self.form, out_dtype=xs.dtype,
+                            interpret=interp)
+            y = grouped_ffn(h, (params["down"],), sizes, form="linear",
+                            out_dtype=jnp.float32, interpret=interp)
+            path = "grouped_ffn"
         else:
-            grouped = lambda a, w, out_tile: jax.lax.ragged_dot(
-                a, w, sizes, preferred_element_type=jnp.float32)
-            path = "ragged_dot"
-        itemsize = jnp.dtype(params["up"].dtype).itemsize
-        hidden_tile = self.out_tile(self.embed_dim, self.width, itemsize)
-        model_tile = self.out_tile(self.width, self.embed_dim, itemsize)
-        h = grouped(xs, params["up"], hidden_tile)
-        if self.form == "swiglu":
-            h = jax.nn.silu(grouped(xs, params["gate"], hidden_tile)) * h
-        else:
-            h = jnp.square(jnp.maximum(h, 0.0))
-        y = grouped(h.astype(xs.dtype), params["down"], model_tile)
+            if ctx.extras.get("pallas_decode"):
+                from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+                grouped = lambda a, w, out_tile: gmm(
+                    a, w, sizes, jnp.float32,
+                    (GMM_ROWS, w.shape[1], out_tile), interpret=interp)
+                path = "megablox_gmm"
+            else:
+                grouped = lambda a, w, out_tile: jax.lax.ragged_dot(
+                    a, w, sizes, preferred_element_type=jnp.float32)
+                path = "ragged_dot"
+            itemsize = jnp.dtype(params["up"].dtype).itemsize
+            hidden_tile = self.out_tile(self.embed_dim, self.width, itemsize)
+            model_tile = self.out_tile(self.width, self.embed_dim, itemsize)
+            h = grouped(xs, params["up"], hidden_tile)
+            if self.form == "swiglu":
+                h = jax.nn.silu(grouped(xs, params["gate"], hidden_tile)) * h
+            else:
+                h = jnp.square(jnp.maximum(h, 0.0))
+            y = grouped(h.astype(xs.dtype), params["down"], model_tile)
         bc = ctx.extras.get("batch_config")
         batch = ("one_row_per_request"
                  if ctx.extras.get("one_row_per_request")

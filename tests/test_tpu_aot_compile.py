@@ -603,7 +603,8 @@ def test_nemotron_routed_layer_compiles_for_v5e(one_chip, rows):
         ids, w = MoERouter(d, scored, k, 2.5, dtype=x.dtype).lower(
             ctx(), [x], {"weight": gate, "e_score_correction_bias": bias})
         xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
-        ys = MoEExperts(held, d, f, dtype=x.dtype).lower(
+        ys = MoEExperts(held, d, f, dtype=x.dtype,
+                        num_scored=scored).lower(
             ctx(), [xs, sizes], {"up": up, "down": down})[0]
         return MoECombine(held, dtype=x.dtype).lower(
             ctx(), [ys, order, ids, w], {})[0]
@@ -636,7 +637,8 @@ def test_gated_routed_layer_compiles_for_v5e(one_chip, rows):
         ids, w = MoERouter(d, scored, k, dtype=x.dtype, bias=False).lower(
             ctx(), [x], {"weight": router})
         xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
-        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu",
+                        num_scored=scored).lower(
             ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
         return MoECombine(held, dtype=x.dtype).lower(
             ctx(), [ys, order, ids, w], {})[0]
@@ -718,7 +720,43 @@ def test_mellum_routed_layer_compiles_for_v5e(one_chip, rows):
         sds((rows, d), jnp.bfloat16), sds((d, held), jnp.float32),
         sds((held, d, f), jnp.bfloat16), sds((held, d, f), jnp.bfloat16),
         sds((held, f, d), jnp.bfloat16)).compile()
-    assert compiled.as_text().count("tpu_custom_call") >= 3
+    # the scan's 2 rows an expert: megablox's three calls; the chunk's 128:
+    # the group-ahead plan's two (gate and up in one, the product inside)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= (3 if rows == 16 else 2)
+    assert ("grouped_ffn_swiglu" in text) == (rows == 1024)
+
+
+@pytest.mark.parametrize("m, d, f, e, tiles", [
+    (8192, 2304, 896, 64, (896, 2304)),
+    (4096, 4096, 4096, 16, (1024, 2048)),
+], ids=["mellum_chunk_2304x896", "swiglu_4096x4096"])
+def test_group_ahead_ffn_compiles_for_v5e(one_chip, m, d, f, e, tiles):
+    """``grouped_ffn`` (ops/pallas/grouped_ffn.py) at the ``mellum`` chunk's
+    shapes — 8192 sorted pairs on 64 experts of 2304 x 896: both matrices
+    whole in each of two slots, 16.5 MB of weights beside the row tile —
+    and at a wider ``swiglu`` whose two slots force column tiles: both
+    kernels compile inside the VMEM limit the plan sets from its working
+    set (the scoped default of 16 MiB would refuse either)."""
+    from flexflow_tpu.ops.pallas import grouped_ffn as gf
+    from flexflow_tpu.ops.pallas.attention import _VMEM_SCOPED_LIMIT
+
+    assert (gf.out_tile(128, d, f, 2, 2, 2),
+            gf.out_tile(128, f, d, 2, 4, 1)) == tiles
+    assert gf.working_set(128, d, tiles[0], 2, 2, 2) > _VMEM_SCOPED_LIMIT
+
+    def layer(xs, sizes, gate, up, down):
+        h = gf.grouped_ffn(xs, (gate, up), sizes, form="swiglu",
+                           out_dtype=xs.dtype)
+        return gf.grouped_ffn(h, (down,), sizes, form="linear",
+                              out_dtype=jnp.float32)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    text = jax.jit(layer).lower(
+        sds((m, d), jnp.bfloat16), sds((e,), jnp.int32),
+        sds((e, d, f), jnp.bfloat16), sds((e, d, f), jnp.bfloat16),
+        sds((e, f, d), jnp.bfloat16)).compile().as_text()
+    assert "grouped_ffn_swiglu" in text and "grouped_ffn_linear" in text
 
 
 def _pallas_calls(jaxpr):
@@ -876,7 +914,8 @@ def test_kimi_routed_layer_compiles_for_v5e(one_chip, rows):
         ids, w = MoERouter(d, scored, k, 2.446, dtype=x.dtype).lower(
             ctx(), [x], {"weight": router, "e_score_correction_bias": bias})
         xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
-        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu").lower(
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu",
+                        num_scored=scored).lower(
             ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
         return MoECombine(held, dtype=x.dtype).lower(
             ctx(), [ys, order, ids, w], {})[0]
