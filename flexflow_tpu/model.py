@@ -318,13 +318,13 @@ class FFModel:
                                      rotary_embedding=True, rope_theta=10000.0,
                                      use_bias=False, scaling_factor=None,
                                      use_alibi=False, rope_scaling=None,
-                                     name=None):
+                                     gate=None, name=None):
         from .serve.ops import IncMultiHeadSelfAttention
 
         op = IncMultiHeadSelfAttention(
             embed_dim, num_q_heads, num_kv_heads, head_dim, rotary_embedding,
             rope_theta, use_bias, scaling_factor, use_alibi, dtype=x.dtype,
-            rope_scaling=rope_scaling)
+            rope_scaling=rope_scaling, gate=gate)
         return self._add(op, [x], name or "inc_mha")[0]
 
     def position_embedding(self, x, num_positions, offset=0, name=None):

@@ -107,6 +107,11 @@ FIELDS = (
     "prefill_experts_visited", "prefill_expert_pairs",
     "prefill_expert_pairs_max", "prefill_expert_chunks",
     "prompt_ring_ctx_sum",
+    # beside ``prompt_tokens``, of the same launches: the PIECES (loop trips)
+    # the chunked delta form runs in ONE delta-rule layer for those prompt
+    # rows — a piece starts with a launch's segment and every ``chunk`` rows
+    # into it (0: a graph without such a layer)
+    "prompt_kda_pieces",
 )
 _F = {name: i for i, name in enumerate(FIELDS)}
 _SPLIT_AT = {n: _F[f"{n}_ns"] for n in SPLIT}
@@ -135,6 +140,7 @@ def _launch_step(row, args, chunk_width):
     row[_F["step_launches"]] += 1
     row[_F["prompt_tokens"]] += args.get("prompt_tokens", 0)
     row[_F["prompt_ring_ctx_sum"]] += args.get("prompt_ring_ctx_sum", 0)
+    row[_F["prompt_kda_pieces"]] += args.get("prompt_kda_pieces", 0)
     _first_ctx(row, args)
 
 
@@ -160,6 +166,7 @@ def _launch_prefill_scan(row, args, chunk_width):
     row[_F["chunk_tokens"]] += fed
     row[_F["prompt_tokens"]] += fed
     row[_F["prompt_ring_ctx_sum"]] += args.get("prompt_ring_ctx_sum", 0)
+    row[_F["prompt_kda_pieces"]] += args.get("prompt_kda_pieces", 0)
     row[_F["joiners"]] += args.get("joiners", 0)
 
 
@@ -386,7 +393,7 @@ class TickJournal:
     counts them.  4096 holds twenty runs of the benchmark's shortest-tick
     cell (``opt-6.7b-d12.decode-heavy``: 189 records for warm-up,
     rehearsal and the 51 s window, 165 of them the window's; my chip
-    runs, PR 46), as lists of 60 integers.
+    runs, PR 46), as lists of 61 integers.
     ``chunk_width``: rows of one prefill-scan chunk
     (``im.max_tokens``; ``chunk_rows`` = chunks x this).
     ``clock_ns``: the journal's clock, and the tick spans' ``pc_ns``

@@ -2047,6 +2047,7 @@ class KimiDeltaAttention(_SlotStateOp):
         q = q / max(|q|, 1e-6) D^-1/2;   k = k / max(|k|, 1e-6)
         g = -exp(A_log_h) softplus((n W_fa W_fb)_h + dt_bias_h)   [D], float32
         beta = sigmoid((n W_beta)_h)                              scalar
+               (``allow_neg_eigval``: 2 sigmoid(..), see below)
         S' = Diag(exp g) S;  S <- S' + beta k (v - S'^T k)^T;  o = S^T q
         y = RMSNorm(o, g_o) * sigmoid((n W_ga W_gb)_h);  out = concat(y) W_o
 
@@ -2071,6 +2072,25 @@ class KimiDeltaAttention(_SlotStateOp):
       U``; ``S = Diag(exp G_C) S0 + (K * exp(G_C - G))^T U``.  The state's
       products at HIGHEST precision (a float32 matmul would otherwise round
       the state to bf16 on the MXU).
+
+    ``allow_neg_eigval`` (``solar_open2``'s ``kda_allow_neg_eigval``; False:
+    the program before the option, jaxpr for jaxpr): ``beta = 2 sigmoid(n
+    W_beta)`` in (0, 2), so a step's transition ``Diag(exp g) (I - beta k
+    k^T)`` has an eigenvalue in (-1, 1) along ``k`` — a state may flip sign
+    along a key, not only shrink.  Both forms take ``beta`` ready-made
+    (``delta_rule_step`` takes ``beta k``): nothing else changes.  The
+    chunked form's solve then meets ``|beta_i A_ij|`` up to 2, not 1.  An
+    ARBITRARY unit-lower matrix with such entries has an inverse that grows
+    as 3^k; this one does not: entry (i, j) of ``(I + Diag(beta) A)^-1`` is
+    ``-beta_i k_i^T (the transitions between j and i) k_j``, a product of
+    contractions for ``beta`` in (0, 2), so it stays under 2 — and
+    :func:`unit_lower_inverse` forms only such sub-inverses and products of
+    three of them, nothing that cancels.  What tests/test_solar_open2.py
+    reads (float32, CPU, against a float64 recurrence, relative to the
+    outputs' size): on keys that REPEAT through a full 32-row piece (``k_i =
+    +-k_j``, the solve's worst case), ``beta`` 1.99 and a decay of 1 or 0.999
+    a step the chunked form is off by 9e-6 at the most (the float32
+    recurrence itself: 8e-7; drawn keys: 7e-7); the test holds it to 5e-5.
     """
 
     type_name = "kimi_delta_attention"
@@ -2080,9 +2100,11 @@ class KimiDeltaAttention(_SlotStateOp):
     NORM_EPS = 1e-6     # the L2 norms' floor
 
     def __init__(self, embed_dim: int, num_heads: int, head_dim: int,
-                 eps: float = 1e-5, chunk: int = 32, dtype=jnp.float32):
+                 eps: float = 1e-5, chunk: int = 32, dtype=jnp.float32,
+                 allow_neg_eigval: bool = False):
         if chunk & (chunk - 1):
             raise ValueError("the chunked form's piece is a power of two")
+        self.allow_neg_eigval = bool(allow_neg_eigval)
         self.embed_dim = int(embed_dim)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
@@ -2255,6 +2277,8 @@ class KimiDeltaAttention(_SlotStateOp):
                 raw + params["dt_bias"]).reshape(t, h, d)
             beta = jax.nn.sigmoid(jnp.dot(x, params["b_proj"],
                                           preferred_element_type=f32))
+            if self.allow_neg_eigval:
+                beta = 2.0 * beta
         with jax.named_scope("attend"):
             if ctx.extras.get("one_row_per_request"):
                 o, kda, path = self._step(q, k, v, g, beta, kda, seg, ctx)
@@ -2265,7 +2289,8 @@ class KimiDeltaAttention(_SlotStateOp):
             ctx.extras["state_out"] = {"kda": kda}
             paths = ctx.extras.get("attention_paths")
             if paths is not None:
-                paths[(self.type_name, batch)] = path
+                paths[(self.type_name, batch)] = path + (
+                    "+neg_eigval" if self.allow_neg_eigval else "")
         with jax.named_scope("o_proj"):
             gate = jnp.dot(
                 jnp.dot(x, weight("g_a"), preferred_element_type=f32
@@ -2275,3 +2300,12 @@ class KimiDeltaAttention(_SlotStateOp):
             y = jnp.dot(o.reshape(t, h * d).astype(x.dtype), weight("o_proj"),
                         preferred_element_type=f32)
             return [y.astype(self.dtype)]
+
+
+def delta_piece(graph):
+    """The piece (``chunk``) of the graph's delta-rule layers' chunked form
+    (:class:`KimiDeltaAttention`), or None for a graph that has none."""
+    for n in graph.nodes:
+        if isinstance(n.op, KimiDeltaAttention):
+            return n.op.chunk
+    return None

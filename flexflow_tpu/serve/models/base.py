@@ -191,6 +191,23 @@ class ServeModelConfig:
     rope_parameters: Optional[dict] = None
     mlp_layer_types: Optional[tuple] = None
     attention_bias: bool = False
+    # solar_open2 (``models/solar_open2.py``): ``gqa_layers`` — a 0-BASED list
+    # — names the layers that are softmax grouped-query attention (every
+    # ``gqa_interval + 1``-th), every other layer is Kimi Delta Attention at
+    # ``linear_attn_config``'s ``num_heads`` / ``head_dim`` /
+    # ``short_conv_kernel_size`` (NO layer lists in it here); ``use_rope``
+    # false: no positional term; ``use_gqa_gate``: an output gate on the
+    # attention layers; ``kda_allow_neg_eigval``: ``beta`` in (0, 2);
+    # ``kda_use_full_proj`` false: the decay's and the gate's projections
+    # are low-rank pairs; the mixture by deepseek's keys (``n_routed_experts``
+    # held of ``router_num_experts``, ``n_shared_experts``,
+    # ``num_experts_per_tok``, ``norm_topk_prob``)
+    gqa_layers: Optional[tuple] = None
+    gqa_interval: Optional[int] = None
+    use_gqa_gate: bool = False
+    use_rope: bool = True
+    kda_allow_neg_eigval: bool = False
+    kda_use_full_proj: bool = False
     # compute/cache dtype for the whole graph: the token embedding is built
     # in this dtype and every downstream op inherits it (x.dtype plumbing),
     # including the attention ops' KV caches.  "bfloat16" is the TPU-native

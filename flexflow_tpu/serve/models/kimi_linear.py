@@ -32,6 +32,15 @@ Sequential pre-norm RMSNorm blocks: ``x <- x + Mix_l(RMS(x))``, then ``x <- x
 
 ``num_experts`` is what THIS graph holds: share ``expert_share_index`` of the
 ``router_num_experts`` the router scores (unset: all), as in ``cohere2_moe``.
+
+``solar_open2`` (``models/solar_open2.py``) builds the same delta-rule
+operator from OTHER keys: there ``linear_attn_config`` holds no layer list
+and a 0-BASED top-level ``gqa_layers`` names the full layers (here two
+1-BASED lists inside ``linear_attn_config`` name every layer); there the
+mixture reads ``n_routed_experts`` / ``n_shared_experts`` /
+``num_experts_per_tok`` / ``norm_topk_prob`` (here ``num_experts`` /
+``num_shared_experts`` / ``num_experts_per_token`` / ``moe_renormalize``),
+and ``kda_allow_neg_eigval`` doubles ``beta`` (here it lies in (0, 1)).
 """
 
 from __future__ import annotations

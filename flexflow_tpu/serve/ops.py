@@ -397,6 +397,18 @@ class IncMultiHeadSelfAttention(Op):
     head_dim]`` (row ``max_requests`` is the pad-token scratch row) and, when
     speculation is enabled, ``sk/sv`` spec-tree buffers
     ``[max_requests+1, max_spec, kv_heads, head_dim]``.
+
+    ``gate`` (``solar_open2``'s ``use_gqa_gate``; None: no gate, the program
+    before the option): an OUTPUT gate between the attention proper and
+    ``W_o`` — ``y = o * sigmoid(x W_g)`` from the op's own input rows ``x``
+    (the normed stream), ``W_g`` a parameter of its own (``g_proj``):
+    ``"elementwise"`` ``[E, QH * D]``, one gate a channel of every head;
+    ``"head"`` ``[E, QH]``, one gate a head, broadcast over its channels.
+    The projection, the float32 sigmoid and the product lie under the stage
+    ``o_proj`` in a nested scope ``gate`` — in every batch kind alike (flat
+    step, tiled prefill, decode scan, tree verify: the gate is a row's own),
+    so the prefill pipelining's carried q/k/v need nothing of it.
+    ``g_proj`` is not among the weights ``quantize_int8`` replaces.
     """
 
     type_name = "inc_multihead_self_attention"
@@ -433,7 +445,12 @@ class IncMultiHeadSelfAttention(Op):
         use_alibi: bool = False,
         dtype=jnp.float32,
         rope_scaling: Optional[dict] = None,
+        gate: Optional[str] = None,
     ):
+        if gate not in (None, "elementwise", "head"):
+            raise ValueError("the attention's output gate is 'elementwise', "
+                             f"'head' or none (gate {gate!r})")
+        self.gate = gate
         # ``rope_scaling`` (type ``yarn``): the rotary turns by YaRN's
         # frequencies and amplitude (``apply_rope``'s ``yarn``); None: plain
         # ``rope_theta``
@@ -460,6 +477,20 @@ class IncMultiHeadSelfAttention(Op):
         )
         self.dtype = jnp.dtype(dtype).name
 
+    @property
+    def gate_width(self) -> int:
+        """Columns of ``g_proj``: a gate a channel, or a gate a head."""
+        return self.num_q_heads * (self.head_dim
+                                   if self.gate == "elementwise" else 1)
+
+    def _gated(self, out, x, params):
+        """``out [T, QH, D] * sigmoid(x W_g)`` (see the class docstring)."""
+        with jax.named_scope("gate"):
+            g = jax.nn.sigmoid(jnp.dot(x, params["g_proj"],
+                                       preferred_element_type=jnp.float32))
+            g = g.reshape(out.shape[0], self.num_q_heads, -1)
+            return (out * g).astype(out.dtype)
+
     # ---- shapes / params ----------------------------------------------
     def infer_shapes(self, in_specs):
         x = in_specs[0]
@@ -485,6 +516,9 @@ class IncMultiHeadSelfAttention(Op):
                 ),
             ),
         ]
+        if self.gate:
+            ps.append(ParamSpec("g_proj", TensorSpec(
+                (self.embed_dim, self.gate_width), jnp.dtype(self.dtype))))
         if self.use_bias:
             ps.append(
                 ParamSpec(
@@ -600,6 +634,14 @@ class IncMultiHeadSelfAttention(Op):
         # [T, QH, D] -> [T, QH*D] -> o_proj (row-parallel under TP)
         t = out.shape[0]
         with jax.named_scope("o_proj"):
+            if self.gate:
+                out = self._gated(out, x, params)
+                paths = ctx.extras.get("attention_paths")
+                if paths is not None:   # beside the kernels' own notes
+                    batch = "one_row_per_request" if ctx.extras.get(
+                        "one_row_per_request") else type(bc).__name__
+                    paths[("attention_gate", (self.type_name, batch))] = \
+                        self.gate
             o_w = params["o_proj"]
             if o_w.dtype == jnp.int8:  # weight-only int8 (serve/quant.py)
                 from .quant import dequant
@@ -1243,6 +1285,9 @@ class IncMultiHeadSelfAttention(Op):
         qkv_sh = TensorSharding.from_axes(4, {1: head} if head else {})
         o_sh = TensorSharding.from_axes(2, {0: head} if head else {})
         params = {"qkv": qkv_sh, "o_proj": o_sh}
+        if self.gate:   # columns in head order, kv-head-major as the queries
+            params["g_proj"] = TensorSharding.from_axes(
+                2, {1: head} if head else {})
         if self.use_bias:
             params["qkv_bias"] = TensorSharding.from_axes(
                 3, {0: head} if head else {}
@@ -1262,6 +1307,8 @@ class IncMultiHeadSelfAttention(Op):
         qh, d = self.num_q_heads, self.head_dim
         s = self.cost_seq_len or 1024
         proj = 2 * t * e * (qh + 2 * self.num_kv_heads) * d + 2 * t * qh * d * e
+        if self.gate:
+            proj += 2 * t * e * self.gate_width
         attn = 2 * t * qh * d * s * 2
         return proj + attn
 

@@ -22,8 +22,8 @@ import numpy as np
 
 from ..obs.telemetry import telemetry_or_null
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .hybrid_ops import (compact_geometry, compact_len, ring_window,
-                         sparse_geometry)
+from .hybrid_ops import (compact_geometry, compact_len, delta_piece,
+                         ring_window, sparse_geometry)
 from .inference_manager import EXIT_NOT_IN_BATCH
 from .resilience import ResilienceConfig, TransientServeError
 
@@ -207,6 +207,9 @@ class RequestManager:
         # the window of a graph's plain ring layers, or None: what
         # ``_ring_counts`` tells the dispatch spans beside ``ctx_sum``
         self._ring = ring_window(im.model.graph)
+        # the piece of a graph's delta-rule layers' chunked form, or None:
+        # what ``_prompt_kda_counts`` tells the prompt launches' spans
+        self._kda_piece = delta_piece(im.model.graph)
         self.scan_runs = 0      # decode stretches run as on-device scans
         # ONE Telemetry handle across the serving stack: syncing it onto the
         # InferenceManager (which forwards to pipeline stages) puts request
@@ -381,6 +384,7 @@ class RequestManager:
             "ctx_sum": sum(hi for _, _, hi in dec),
             **self._ring_counts(hi for _, _, hi in dec),
             **self._prompt_ring_counts((lo, hi) for _, lo, hi in pre),
+            **self._prompt_kda_counts(hi - lo for _, lo, hi in pre),
             "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
                                   for _, lo, hi in pre),
             **self._slot_state_counts([(lo, hi) for _, lo, hi in spans]),
@@ -408,6 +412,20 @@ class RequestManager:
         return {"prompt_ring_ctx_sum": sum(
             tri(min(hi, w)) - tri(min(lo, w)) + w * (max(hi, w) - max(lo, w))
             for lo, hi in writes)}
+
+    def _prompt_kda_counts(self, runs) -> Dict[str, int]:
+        """For a graph with delta-rule layers (``hybrid_ops.
+        KimiDeltaAttention``), of a launch whose prompt rows lie in segments
+        of ``runs`` rows each (one request's consecutive rows of ONE flat
+        batch or scan chunk): ``prompt_kda_pieces``, the loop trips the
+        chunked form runs for them in one such layer — a piece starts with
+        the segment and every ``chunk`` rows into it (``_chunked``'s own
+        rule), so ``ceil(rows / chunk)`` a segment.  Counted here, from what
+        the scheduler holds: no device read."""
+        if self._kda_piece is None:
+            return {}
+        return {"prompt_kda_pieces": sum(-(-n // self._kda_piece)
+                                         for n in runs)}
 
     def _slot_state_counts(self, writes) -> Dict[str, int]:
         """Dispatch-span arguments (and counters) of a launch that writes
@@ -1874,8 +1892,14 @@ class RequestManager:
             cnt = {"rows": rows, "joiners": len(joiners or ()),
                    "prompt_tokens": fed, "segments": len(parts),
                    "ctx_sum": sum(st for st, _ in parts),
+                   # the keys the fed rows see in ONE full-length layer (a
+                   # row at position p sees p + 1), as a flat step's span
+                   # carries it
+                   "prompt_ctx_sum": sum(t * (2 * st + t + 1) // 2
+                                         for st, t in parts),
                    **self._prompt_ring_counts(
                        (st, st + t) for st, t in parts),
+                   **self._prompt_kda_counts(t for _, t in parts),
                    **self._slot_state_counts(
                        [(st, st + t) for st, t in parts])}
             res = self._guarded(

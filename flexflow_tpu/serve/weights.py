@@ -493,6 +493,45 @@ KIMI_LINEAR_TENSORS = {
 }
 
 
+# ``solar_open2`` (Solar-Open2-250B: ``serve/models/solar_open2.py``).
+# ASSUMED like the tables above — the family's convention (``kimi_linear``'s
+# names for the delta rule, deepseek_v3's for the mixture, whose key names the
+# config carries); no checkpoint is on this machine and NO import path is
+# written.  Published ``model.layers.<l>.<name>`` -> (node under
+# ``model.layers.<l>.``, parameter, what to do).  A layer NOT in the 0-BASED
+# ``gqa_layers`` carries the KDA tensors exactly as ``KIMI_LINEAR_TENSORS``
+# maps them; a layer in it carries the attention's under the SAME
+# ``self_attn.{q,k,v,o}_proj`` names with other shapes (keys marked ``@gqa``
+# here, as in benchmark/reference/solar_open2.py) and the output gate's
+# ``self_attn.g_proj``: q, k and v fuse per K/V head as
+# ``IncMultiHeadSelfAttention``'s do.  Every layer from
+# ``first_k_dense_replace`` (0) on has the router ``mlp.gate`` with its
+# ``e_score_correction_bias``, ``mlp.experts.<e>`` stacked into ``[E, in,
+# out]`` and ONE ``mlp.shared_experts`` module.
+SOLAR_OPEN2_TENSORS = {
+    **{k: v for k, v in KIMI_LINEAR_TENSORS.items()
+       if not (k.startswith("block_sparse_moe.") or "@latent" in k
+               or k.startswith("self_attn.kv_"))},
+    "self_attn.q_proj@gqa.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.k_proj@gqa.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.v_proj@gqa.weight": ("self_attn", "qkv", "fuse_qkv"),
+    "self_attn.g_proj@gqa.weight": ("self_attn", "g_proj", "[d, H hd] = .T"),
+    "self_attn.o_proj@gqa.weight": ("self_attn", "o_proj", "[H hd, d] = .T"),
+    "mlp.gate.weight": ("mlp.gate", "weight", "[d, experts] = .T, float32"),
+    "mlp.gate.e_score_correction_bias":
+        ("mlp.gate", "e_score_correction_bias", "[experts] float32"),
+    "mlp.experts.<e>.gate_proj.weight": ("mlp.experts", "gate", "[e] = .T"),
+    "mlp.experts.<e>.up_proj.weight": ("mlp.experts", "up", "[e] = .T"),
+    "mlp.experts.<e>.down_proj.weight": ("mlp.experts", "down", "[e] = .T"),
+    "mlp.shared_experts.gate_proj.weight":
+        ("mlp.shared_experts.gate_proj", "kernel", "[d, n f] = .T"),
+    "mlp.shared_experts.up_proj.weight":
+        ("mlp.shared_experts.up_proj", "kernel", "[d, n f] = .T"),
+    "mlp.shared_experts.down_proj.weight":
+        ("mlp.shared_experts.down_proj", "kernel", "[n f, d] = .T"),
+}
+
+
 def load_hf_model(name_or_path: str):
     """Load a local HF checkpoint (config + weights + tokenizer if present).
 

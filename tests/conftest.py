@@ -118,6 +118,32 @@ def _resource_log(request):
         pass
 
 
+# a process may hold vm.max_map_count (65 530) memory maps; every compiled
+# CPU executable takes a few, a worker of this suite compiles thousands, and
+# past the limit the next compile's mmap fails: the worker dies of a
+# segmentation fault inside ``backend_compile_and_load`` (PR 64: two workers
+# of six at 63k maps, in whatever test happened to compile next).
+MAPS_BEFORE_CLEARING = 40_000
+
+
+@pytest.fixture(autouse=True)
+def _map_guard():
+    """After a test that leaves the process over ``MAPS_BEFORE_CLEARING``
+    maps: drop jax's compiled programs (``jax.clear_caches`` unmaps them; a
+    deployment a later test shares compiles its programs again)."""
+    yield
+    try:
+        with open("/proc/self/maps") as f:
+            nmaps = sum(1 for _ in f)
+    except OSError:
+        return
+    if nmaps > MAPS_BEFORE_CLEARING:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture
 def row_write_on_and_off(monkeypatch):
     """``check(make, prompts, new_tokens)``: serve ``prompts`` through the

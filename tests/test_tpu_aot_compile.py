@@ -997,3 +997,144 @@ def test_latent_decode_kernel_on_32_heads_compiles_for_v5e(one_chip, rows):
     line = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
             and "custom-call(" in ln][0]
     assert line.count(f"bf16[{slots + 1},1,{s},{r}]") == 1, line[:400]
+
+
+def test_solar_open2_chunked_delta_layer_compiles_for_v5e(one_chip):
+    """``KimiDeltaAttention`` at Solar-Open2's widths — 64 heads of 128 x 128
+    float32 (17 state rows of 4 MB) on a 4096 stream, ``beta`` in (0, 2) — on
+    a prompt chunk of 1024 rows in the chunked form: no Mosaic kernel, the
+    71 MB state updated in place, and the piece's ``[32, 32, 64, 128]``
+    float32 tensors (33.5 MB each) a few at a time among the temporaries
+    (174 MB here; 74 MB at 512 rows, 343 MB at 2048: my AOT readings, PR
+    64)."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.batch_config import BatchConfig
+    from flexflow_tpu.serve.hybrid_ops import KimiDeltaAttention
+
+    e, h, d, slots, rows = 4096, 64, 128, 16, 1024
+    op = KimiDeltaAttention(e, h, d, dtype=jnp.bfloat16,
+                            allow_neg_eigval=True)
+    names = [p.name for p in op.params()]
+
+    def mix(qkv, x, kda, request_index, position, *weights):
+        bc = BatchConfig(tokens=position, request_index=request_index,
+                         token_position=position,
+                         num_tokens=jnp.int32(rows),
+                         seq_lens=jnp.zeros((slots,), jnp.int32))
+        ctx = OpContext(extras={
+            "node_name": "n", "batch_config": bc, "state": {"kda": kda},
+            "pallas_decode": True})
+        y = op.lower(ctx, [qkv, x], dict(zip(names, weights)))[0]
+        return y, ctx.extras["state_out"]["kda"]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    weights = [sds(p.spec.shape, p.spec.dtype) for p in op.params()]
+    compiled = jax.jit(mix, donate_argnums=(2,)).lower(
+        sds((rows, 3 * h * d), jnp.bfloat16), sds((rows, e), jnp.bfloat16),
+        sds((slots + 1, h, d, d), jnp.float32), sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), *weights).compile()
+    mem = compiled.memory_analysis()
+    state_bytes = (slots + 1) * h * d * d * 4
+    assert mem.alias_size_in_bytes >= state_bytes      # updated in place
+    assert mem.temp_size_in_bytes < 8 * 32 * 32 * h * d * 4
+    assert compiled.as_text().count("tpu_custom_call") == 0
+
+
+@pytest.mark.parametrize("batch", ["prefill1024", "scan16"])
+def test_solar_open2_gated_attention_compiles_for_v5e(one_chip, batch):
+    """``IncMultiHeadSelfAttention`` at Solar-Open2's widths — 64 query heads
+    of 128 on 8 K/V heads (a query group of 8), no rotation, a cache of
+    24 832 positions a slot, the elementwise output gate (4096 -> 8192)
+    under ``o_proj`` — on a prompt chunk of 1024 rows (the block write and
+    ``prefill_attention``) and on the decode scan's 16 rows (the row write
+    and ``decode_attention``): the caches updated in place, the gate
+    noted among the paths."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.batch_config import (BatchConfig,
+                                                 PrefillBatchConfig)
+    from flexflow_tpu.serve.ops import IncMultiHeadSelfAttention
+
+    e, qh, kv, d, slots, seq = 4096, 64, 8, 128, 16, 24832
+    rows = 1024 if batch == "prefill1024" else slots
+    op = IncMultiHeadSelfAttention(e, qh, kv, d, rotary_embedding=False,
+                                   dtype=jnp.bfloat16, gate="elementwise")
+    names = [p.name for p in op.params()]
+    assert dict(zip(names, (p.spec.shape for p in op.params())))[
+        "g_proj"] == (e, qh * d)
+    paths = {}
+
+    def attend(x, kc, vc, request_index, position, *weights):
+        bc = BatchConfig(tokens=position, request_index=request_index,
+                         token_position=position,
+                         num_tokens=jnp.int32(rows),
+                         seq_lens=jnp.zeros((slots,), jnp.int32))
+        extras = {"node_name": "n", "state": {"k": kc, "v": vc},
+                  "pallas_decode": True, "attention_paths": paths}
+        if batch == "prefill1024":
+            extras["batch_config"] = PrefillBatchConfig(base=bc,
+                                                        tile_size=TILE)
+        else:
+            extras.update(batch_config=bc, one_row_per_request=True)
+        ctx = OpContext(extras=extras)
+        y = op.lower(ctx, [x], dict(zip(names, weights)))[0]
+        out = ctx.extras["state_out"]
+        return y, out["k"], out["v"]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    cache = sds((slots + 1, kv, seq, d), jnp.bfloat16)
+    weights = [sds(p.spec.shape, p.spec.dtype) for p in op.params()]
+    lowered = jax.jit(attend, donate_argnums=(1, 2)).lower(
+        sds((rows, e), jnp.bfloat16), cache, cache, sds((rows,), jnp.int32),
+        sds((rows,), jnp.int32), *weights)
+    compiled = lowered.compile()
+    assert set(paths.values()) >= {"elementwise"}, paths   # the gate lowered
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2       # the write, the kernel
+    mem = compiled.memory_analysis()
+    cache_bytes = (slots + 1) * kv * seq * d * 2
+    assert mem.alias_size_in_bytes >= 2 * cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 4
+
+
+def test_solar_open2_routed_layer_compiles_for_v5e(one_chip):
+    """The routed-expert layer at Solar-Open2's published widths (hidden
+    4096, a sigmoid router with its correction bias over 320 experts with
+    top-8 renormalised, 40 HELD gated experts of width 1280) on a prompt
+    chunk's 1024 rows: 25.6 rows a held expert, under the grouped GEMM's row
+    tile, so the chunk stays on megablox's ``gmm`` (PR 62's rule) with the
+    tiles ``MoEExperts.out_tile`` plans from the shapes, neither of which
+    divides its width: both GEMMs end in a ragged tile."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.ssd_moe_ops import (GMM_ROWS, MoECombine,
+                                                MoEDispatch, MoEExperts,
+                                                MoERouter)
+
+    d, f, held, scored, k, rows = 4096, 1280, 40, 320, 8, 1024
+    assert rows * k / scored < GMM_ROWS
+    # 1280 in tiles of 512 (the last ragged: 256) into the hidden width,
+    # 4096 in three of 1408 (the last ragged: 1280) out of it
+    assert (MoEExperts.out_tile(d, f, 2), MoEExperts.out_tile(f, d, 2)) == \
+        (512, 1408)
+    paths = {}
+
+    def layer(x, router, bias, gate, up, down):
+        ctx = lambda: OpContext(extras={"node_name": "n",
+                                        "pallas_decode": True,
+                                        "attention_paths": paths})
+        ids, w = MoERouter(d, scored, k, 1.0, dtype=x.dtype).lower(
+            ctx(), [x], {"weight": router, "e_score_correction_bias": bias})
+        xs, sizes, order = MoEDispatch(held).lower(ctx(), [x, ids], {})
+        ys = MoEExperts(held, d, f, dtype=x.dtype, form="swiglu",
+                        num_scored=scored).lower(
+            ctx(), [xs, sizes], {"gate": gate, "up": up, "down": down})[0]
+        return MoECombine(held, dtype=x.dtype).lower(
+            ctx(), [ys, order, ids, w], {})[0]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(layer).lower(
+        sds((rows, d), jnp.bfloat16), sds((d, scored), jnp.float32),
+        sds((scored,), jnp.float32), sds((held, d, f), jnp.bfloat16),
+        sds((held, d, f), jnp.bfloat16),
+        sds((held, f, d), jnp.bfloat16)).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    assert set(paths.values()) == {"megablox_gmm"}
