@@ -2062,7 +2062,15 @@ class KimiDeltaAttention(_SlotStateOp):
       off or the head is not whole lanes: the CPU oracle.
     * a prompt chunk, a join's prefill or a flat step: the CHUNKED form over
       PIECES — runs of at most ``chunk`` rows of one request (``Segments``
-      has the boundaries) — one loop trip a piece, never one a row.  Inside
+      has the boundaries; :meth:`_pieces` lists them) —, never a step a row:
+      the Pallas kernel ``delta_rule_chunk`` — ONE call a layer for every
+      piece of the batch, a head's state, the piece's decays and its 32 x 32
+      systems on chip, the state array read at a segment's first piece and
+      written at its last — under the rule of the step kernel (the kernels
+      on, a head of whole lanes); else :meth:`_chunked`, one XLA loop trip
+      a piece: the CPU oracle and the kernels-off path.  The path note
+      names the FORM (``chunked``); ``("delta_pieces", <op>)`` says who ran
+      the pieces (``delta_rule_chunk`` / ``xla_loop``).  Inside
       a piece entered with ``S0``, ``G_i`` the running sum of ``g`` (<= 0):
       ``A_ij = sum_d k_i k_j exp(G_i - G_j)`` (j < i), ``B_ij`` likewise with
       ``q_i`` (j <= i), both by EXPLICIT differences — every exponent is
@@ -2071,7 +2079,9 @@ class KimiDeltaAttention(_SlotStateOp):
       exp G) S0)`` (:func:`unit_lower_inverse`); ``o = (q * exp G) S0 + B
       U``; ``S = Diag(exp G_C) S0 + (K * exp(G_C - G))^T U``.  The state's
       products at HIGHEST precision (a float32 matmul would otherwise round
-      the state to bf16 on the MXU).
+      the state to bf16 on the MXU), in the kernel too — where ``A`` and
+      ``B`` keep the rule by a reference row between the two halves of a
+      block, level by level (``ops/pallas/delta_rule.py``'s header).
 
     ``allow_neg_eigval`` (``solar_open2``'s ``kda_allow_neg_eigval``; False:
     the program before the option, jaxpr for jaxpr): ``beta = 2 sigmoid(n
@@ -2167,13 +2177,18 @@ class KimiDeltaAttention(_SlotStateOp):
             * jax.nn.sigmoid(gate)
 
     # ---- the two forms ----------------------------------------------------
+    def _kernels(self, ctx) -> bool:
+        """Whether both forms go by their Pallas kernels: the flag every
+        kernel obeys, and a head of whole lanes (or interpret mode)."""
+        return bool(ctx.extras.get("pallas_decode")) and (
+            self.head_dim % LANE == 0
+            or bool(ctx.extras.get("pallas_interpret")))
+
     def _step(self, q, k, v, g, beta, kda, seg, ctx):
         """The decode scan's step: one row a request."""
         alpha = jnp.where(seg.fresh[:, None, None], 0.0, jnp.exp(g))
         kb = beta[..., None] * k
-        if ctx.extras.get("pallas_decode") and (
-                self.head_dim % LANE == 0
-                or ctx.extras.get("pallas_interpret")):
+        if self._kernels(ctx):
             from ..ops.pallas.delta_rule import delta_rule_step
 
             o, kda = delta_rule_step(
@@ -2187,6 +2202,41 @@ class KimiDeltaAttention(_SlotStateOp):
         with jax.named_scope("state_write"):
             kda = _set_rows(kda, seg.store, s)
         return jnp.where(seg.live[:, None, None], o, 0.0), kda, "xla_rows"
+
+    def _prompt(self, q, k, v, g, beta, kda, seg, ctx):
+        """A prompt chunk or a flat step: the chunked form, its pieces by
+        the Pallas kernel ``delta_rule_chunk`` or by :meth:`_chunked`'s
+        loop (the kernels off, a head that is not whole lanes: the CPU
+        oracle) — ``(o, kda, which)``."""
+        if not self._kernels(ctx):
+            return (*self._chunked(q, k, v, g, beta, kda, seg), "xla_loop")
+        from ..ops.pallas.delta_rule import delta_rule_chunk
+
+        o, kda = delta_rule_chunk(
+            kda, q, k, v, g, beta, self._pieces(seg), chunk=self.chunk,
+            interpret=bool(ctx.extras.get("pallas_interpret")))
+        # rows of no piece are whatever the output's buffer held
+        return (jnp.where(seg.live[:, None, None], o, 0.0), kda,
+                "delta_rule_chunk")
+
+    def _pieces(self, seg):
+        """The chunked form's pieces in row order, as ``delta_rule_chunk``
+        prefetches them and as :meth:`_chunked`'s loop finds them: how many
+        there are and, a piece, its first row, how many of the ``chunk`` rows
+        from there are its own, its slot's state row, where its entering
+        state is and whether it holds its segment's last row."""
+        from ..ops.pallas.delta_rule import CONTINUE, STORED, ZEROS
+
+        i32, t = jnp.int32, seg.live.shape[0]
+        piece = (seg.start | (seg.offset % self.chunk == 0)) & seg.live
+        first = jnp.argsort(~piece, stable=True).astype(i32)
+        own = jnp.zeros((t + 1,), i32).at[jnp.cumsum(piece.astype(i32))].add(
+            seg.live.astype(i32))[1:]
+        init = jnp.where(seg.start, jnp.where(seg.fresh, ZEROS, STORED),
+                         CONTINUE)[first]
+        last = seg.last[jnp.clip(first + own - 1, 0, t - 1)]
+        return (jnp.sum(piece.astype(i32)), first, own, seg.rows[first],
+                init.astype(i32), last.astype(i32))
 
     def _chunked(self, q, k, v, g, beta, kda, seg):
         """A prompt chunk or a flat step (see the class docstring)."""
@@ -2284,13 +2334,15 @@ class KimiDeltaAttention(_SlotStateOp):
                 o, kda, path = self._step(q, k, v, g, beta, kda, seg, ctx)
                 batch = "one_row_per_request"
             else:
-                o, kda = self._chunked(q, k, v, g, beta, kda, seg)
+                o, kda, ran = self._prompt(q, k, v, g, beta, kda, seg, ctx)
                 path, batch = "chunked", type(bc).__name__
             ctx.extras["state_out"] = {"kda": kda}
             paths = ctx.extras.get("attention_paths")
             if paths is not None:
                 paths[(self.type_name, batch)] = path + (
                     "+neg_eigval" if self.allow_neg_eigval else "")
+                if path == "chunked":   # the FORM; who ran its pieces:
+                    paths[("delta_pieces", self.type_name)] = ran
         with jax.named_scope("o_proj"):
             gate = jnp.dot(
                 jnp.dot(x, weight("g_a"), preferred_element_type=f32

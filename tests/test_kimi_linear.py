@@ -253,10 +253,13 @@ def test_the_harness_drive_is_correct(use_pallas):
     assert paths[("kimi_delta_attention", "one_row_per_request")] == (
         "delta_rule_step" if use_pallas else "xla_rows")
     assert paths[("kimi_delta_attention", "PrefillBatchConfig")] == "chunked"
+    # the FORM above; who ran its pieces under a key of its own
+    assert paths[("delta_pieces", "kimi_delta_attention")] == (
+        "delta_rule_chunk" if use_pallas else "xla_loop")
     kinds = {k for k, _ in paths}
     assert kinds - {"kv_block_write", "kv_row_write"} == {
         "kimi_delta_attention", "latent_attention", "moe_experts",
-        "causal_conv1d"} \
+        "causal_conv1d", "delta_pieces"} \
         | ({"decode_block"} if use_pallas else set())
     # the conv's two forms: the decode scans step the tails in slot order,
     # the prompt's chunks and the flat steps go by rows

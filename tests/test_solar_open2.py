@@ -625,9 +625,13 @@ def test_a_break_is_seen(broken, monkeypatch):
 # ---- what the launches say -----------------------------------------------
 
 def test_the_pieces_a_launch_counts_are_the_loops_trips():
-    """``_prompt_kda_counts`` against ``_chunked``'s own rule on the batch a
-    tiled chunk really is: segments of 37 and 20 rows in tile-padded rows of
-    one chunk are 2 + 1 pieces — the trips ``fori_loop`` runs."""
+    """``_prompt_kda_counts`` against the pieces the device runs, on the
+    batch a tiled chunk really is: segments of 37 and 20 rows in tile-padded
+    rows of one chunk are 2 + 1 pieces — the count ``delta_rule_chunk``
+    takes as its grid's sequential bound (``_pieces``: its scalar-prefetch
+    arrays, a piece's first row, own rows, state row, entering state, last)
+    and the trips ``_chunked``'s ``fori_loop`` runs, by the same rule."""
+    from flexflow_tpu.ops.pallas.delta_rule import CONTINUE, STORED, ZEROS
     from flexflow_tpu.serve import GenerationConfig, RequestManager
 
     rm = RequestManager(RIG.deployment(), GenerationConfig())
@@ -642,6 +646,17 @@ def test_the_pieces_a_launch_counts_are_the_loops_trips():
     seg = Segments(BatchConfig(*(jnp.asarray(f) for f in fields)), SLOTS)
     piece = (seg.start | (seg.offset % PIECE == 0)) & seg.live
     assert int(piece.sum()) == 3
+    (op,) = [n.op for n in RIG.deployment().model.graph.nodes
+             if isinstance(n.op, KimiDeltaAttention)][:1]
+    count, first, own, row, init, last = (
+        np.asarray(a) for a in op._pieces(seg))
+    assert int(count) == 3 == rm._prompt_kda_counts([37, 20])[
+        "prompt_kda_pieces"]
+    assert first[:3].tolist() == [0, 32, 48] and own[:3].tolist() == \
+        [32, 5, 20] and row[:3].tolist() == [0, 0, 1]
+    # request 0 is fed from position 0, request 1 continues at 64
+    assert init[:3].tolist() == [ZEROS, CONTINUE, STORED]
+    assert last[:3].tolist() == [0, 1, 1]
 
 
 def test_the_prompt_launches_count_their_pieces():
@@ -682,6 +697,8 @@ def test_the_prompt_launches_count_their_pieces():
         counters = tel.metrics.snapshot()
         assert counters[
             "attention_path.kimi_delta_attention.chunked+neg_eigval"] >= 1
+        # ... the FORM; who ran its pieces is counted under a key of its own
+        assert counters["attention_path.delta_pieces.delta_rule_chunk"] >= 1
         assert counters["attention_path.attention_gate.elementwise"] >= 1
     finally:
         im.telemetry = type(im).telemetry
