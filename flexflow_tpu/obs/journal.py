@@ -60,20 +60,21 @@ SPLIT = ("host_admit", "host_prepare", "kv_prepare", "sample_for",
          "prefill_scan_dispatch", "join_dispatch", "device_wait", "readback",
          "commit",
          "loop_arrivals", "loop_bookkeep", "loop_idle", "loop_clock")
+# a routed-expert graph's load as ``commit`` sets it (0 elsewhere): of the
+# decode scans, and of the prompt-feeding launches (prefill scans, flat steps)
+_EXPERT_LOAD = ("experts_visited", "expert_pairs", "expert_pairs_max",
+                "expert_steps", "prefill_experts_visited",
+                "prefill_expert_pairs", "prefill_expert_pairs_max",
+                "prefill_expert_chunks")
 # what ``Span.set`` — or an argument a span below the tick is entered
 # with — adds to a record, by argument name
-_SET = {"scan_tokens": "scan_tokens", "join_tokens": "join_tokens",
-        "step_tokens": "step_tokens", "prefill_tokens": "prefill_tokens",
-        "state_reset": "admitted",
-        # a routed-expert graph's load, as ``commit`` sets it (0 elsewhere)
-        "experts_visited": "experts_visited", "expert_pairs": "expert_pairs",
-        "expert_pairs_max": "expert_pairs_max",
-        "expert_steps": "expert_steps",
-        # the same of the prompt-feeding launches (prefill scans, flat steps)
-        "prefill_experts_visited": "prefill_experts_visited",
-        "prefill_expert_pairs": "prefill_expert_pairs",
-        "prefill_expert_pairs_max": "prefill_expert_pairs_max",
-        "prefill_expert_chunks": "prefill_expert_chunks"}
+_SET = {"state_reset": "admitted",
+        **{name: name for name in ("scan_tokens", "join_tokens", "step_tokens",
+                                   "prefill_tokens", *_EXPERT_LOAD)}}
+# what a launch that feeds prompt rows (a flat step, a prefill scan) adds:
+# its argument of the field's name — past the first, as the graph's ops
+# name it (serve/hybrid_ops.py ``launch_counts``; 0: a graph without)
+_PROMPT_LAUNCH = ("prompt_tokens", "prompt_ring_ctx_sum", "prompt_kda_pieces")
 
 FIELDS = (
     # extent; ``tick_ns`` = the tick span's ``pc_ns`` (0: no tick ran)
@@ -138,9 +139,7 @@ _LOG = logging.getLogger("flexflow_tpu.serve")
 
 def _launch_step(row, args, chunk_width):
     row[_F["step_launches"]] += 1
-    row[_F["prompt_tokens"]] += args.get("prompt_tokens", 0)
-    row[_F["prompt_ring_ctx_sum"]] += args.get("prompt_ring_ctx_sum", 0)
-    row[_F["prompt_kda_pieces"]] += args.get("prompt_kda_pieces", 0)
+    _feed_prompt(row, args)
     _first_ctx(row, args)
 
 
@@ -164,14 +163,17 @@ def _launch_prefill_scan(row, args, chunk_width):
     row[_F["chunks"]] += n
     row[_F["chunk_rows"]] += n * chunk_width
     row[_F["chunk_tokens"]] += fed
-    row[_F["prompt_tokens"]] += fed
-    row[_F["prompt_ring_ctx_sum"]] += args.get("prompt_ring_ctx_sum", 0)
-    row[_F["prompt_kda_pieces"]] += args.get("prompt_kda_pieces", 0)
+    _feed_prompt(row, args)
     row[_F["joiners"]] += args.get("joiners", 0)
 
 
 def _launch_join(row, args, chunk_width):
     row[_F["joins"]] += 1
+
+
+def _feed_prompt(row, args):
+    for name in _PROMPT_LAUNCH:
+        row[_F[name]] += args.get(name, 0)
 
 
 def _first_ctx(row, args):

@@ -57,7 +57,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -153,9 +153,56 @@ class _SlotStateOp(Op):
     stateful = True
     slot_state = True   # register_serve_capacities sizes it; refusals key on it
     state_owner: Optional[str] = None
+    # the attributes :meth:`launch_counts` reads: a graph's nodes of one
+    # class agree on them (:func:`launch_counter` holds them to it)
+    launch_reads: Tuple[str, ...] = ()
 
     def parallel_dims(self, in_specs):
         return {}   # replicated: no sharding rule yet (tp > 1 is refused)
+
+    def launch_counts(self, decode, prompt, layers: int, counted: bool):
+        """What ONE launch means to this op's state beyond the contexts
+        every launch's span carries: ``(arguments, counters)``, names to
+        integers — the dispatch span's arguments, of ONE layer, and counters
+        over all ``layers`` nodes of this class (``counted`` False: no one
+        keeps them).  ``decode`` / ``prompt``: the positions ``[(lo, hi)]``
+        the launch writes for its decode rows (``hi - lo`` steps of a decode
+        scan, one of a flat step) and for its prompt segments; ``None`` for
+        a kind of launch that carries no such rows (a prefill scan decodes
+        nothing, a decode scan feeds no prompt): the names that speak of
+        them are then left out.  Host arithmetic, no device read."""
+        return {}, {}
+
+
+def _writes(decode, prompt):
+    """Every row or segment a launch writes, the empty ones dropped."""
+    return [(lo, hi) for lo, hi in (*(decode or ()), *(prompt or ()))
+            if hi > lo]
+
+
+def launch_counter(graph):
+    """``count(decode, prompt, counted) -> (arguments, counters)``: every
+    :meth:`_SlotStateOp.launch_counts` of the graph in one pair of dicts.
+    The first node of a class answers for its siblings."""
+    by_class: Dict[type, List[_SlotStateOp]] = {}
+    for n in graph.nodes:
+        if (isinstance(n.op, _SlotStateOp) and type(n.op).launch_counts
+                is not _SlotStateOp.launch_counts):
+            by_class.setdefault(type(n.op), []).append(n.op)
+    for cls, ops in by_class.items():
+        for name in cls.launch_reads:
+            assert len({getattr(op, name) for op in ops}) == 1, (cls, name)
+    hooks = [(ops[0].launch_counts, len(ops)) for ops in by_class.values()]
+
+    def count(decode, prompt, counted):
+        args, counters = {}, {}
+        for hook, layers in hooks:
+            a, c = hook(decode, prompt, layers, counted)
+            args.update(a)
+            counters.update(c)
+        return args, counters
+
+    return count
 
 
 @register_op
@@ -791,6 +838,24 @@ class SlidingWindowAttention(SlotCacheAttention):
     q_per_cache_head = property(
         lambda self: self.num_q_heads // self.num_kv_heads)
     cache_dim = property(lambda self: self.head_dim)
+    launch_reads = ("window",)
+
+    def launch_counts(self, decode, prompt, layers, counted):
+        """``ring_ctx_sum``: what the decode rows' RING layers read at
+        launch, ``min(context, window)`` a row beside ``ctx_sum``'s whole
+        contexts; ``prompt_ring_ctx_sum``: ``min(position + 1, window)`` a
+        prompt row, the window kernel's least work, beside
+        ``prompt_ctx_sum``."""
+        w, args = self.window, {}
+        if decode is not None:
+            args["ring_ctx_sum"] = sum(min(lo + 1, w)
+                                       for lo, hi in decode if hi > lo)
+        if prompt is not None:
+            tri = lambda n: n * (n + 1) // 2    # 1 + 2 + .. + n
+            args["prompt_ring_ctx_sum"] = sum(
+                tri(min(hi, w)) - tri(min(lo, w))
+                + w * (max(hi, w) - max(lo, w)) for lo, hi in prompt)
+        return args, {}
 
     def infer_shapes(self, in_specs):
         return [TensorSpec(in_specs[0].shape, jnp.dtype(self.dtype))]
@@ -883,6 +948,30 @@ class LatentAttention(_SlotStateOp):
 
     type_name = "latent_attention"
     path_kind = type_name
+    # two planes a position, shared by all heads, that are not K and V
+    # planes: what each deployment option lacks for THEM
+    # (inference_manager.refuse_unsupported_slot_state)
+    refusal_order = 0
+    refusals = {
+        "kv_page_size": (
+            "; for a latent cache, pages, copy-on-write, spill and "
+            "swap_signature over a latent plane and a rotated-key plane of "
+            "another width (kv_paged.py pools K and V planes of one head "
+            "size), and a paged mode of the latent decode kernel"),
+        "kv_dtype": (
+            "; for a latent cache, scale planes beside the latent and the "
+            "rotated key part (one latent is key AND value of every head: a "
+            "per-vector scale folds into neither contraction as the K/V "
+            "kernels' do) and the latent kernel mode that reads them"),
+        "max_spec_tokens": (
+            "; a latent cache has no spec-tree buffers, commit copy or "
+            "tree-mask kernel over latents"),
+        "tp": (
+            "; for latent attention a rule that shards the absorbed heads "
+            "(W_q, the per-head up-projections, W_o's rows) with the latent "
+            "cache replicated"),
+        "pipelined": " (not a latent cache's two planes)",
+    }
     # the projections weight-only int8 replaces (serve/quant.py)
     int8_params = ("q_proj", "kv_a", "kv_b", "o_proj")
 
@@ -1128,24 +1217,6 @@ def compact_len(position, window: int, chunk: int):
     return (window // chunk) * (position // window) + position % window
 
 
-def ring_window(graph):
-    """The window of the graph's PLAIN ring layers
-    (:class:`SlidingWindowAttention`), or None for a graph that has none."""
-    for n in graph.nodes:
-        if isinstance(n.op, SlidingWindowAttention):
-            return n.op.window
-    return None
-
-
-def compact_geometry(graph):
-    """``(window, chunk, layers)`` of the graph's compacting caches, or
-    None for a graph that has none."""
-    ops = [n.op for n in graph.nodes if isinstance(n.op, EvaAttention)]
-    if not ops:
-        return None
-    return ops[0].window, ops[0].chunk, len(ops)
-
-
 @register_op
 class EvaAttention(_SlotStateOp):
     """EVA attention over flat token batches (Zheng et al., "Efficient
@@ -1205,6 +1276,26 @@ class EvaAttention(_SlotStateOp):
     @property
     def per_window(self) -> int:
         return self.window // self.chunk
+
+    launch_reads = ("window", "chunk")
+
+    def launch_counts(self, decode, prompt, layers, counted):
+        """``cache_len_sum``: the entries the rows' caches hold at launch —
+        their ``L(lo)``, where ``ctx_sum`` counts positions; ``compactions``:
+        the windows the launch closes.  Counted: those, and the summary
+        pairs written over all layers."""
+        writes = _writes(decode, prompt)
+        closed = sum(hi // self.window - lo // self.window
+                     for lo, hi in writes)
+        args = {"cache_len_sum": sum(
+                    compact_len(lo, self.window, self.chunk) + 1
+                    for lo, _ in writes),
+                "compactions": closed}
+        if not closed:
+            return args, {}
+        return args, {
+            "eva.windows_closed": closed,
+            "eva.summaries_written": closed * layers * self.per_window}
 
     # ---- shapes / params ----------------------------------------------
     def infer_shapes(self, in_specs):
@@ -1378,19 +1469,6 @@ class EvaAttention(_SlotStateOp):
             return [y.astype(self.dtype)]
 
 
-def sparse_geometry(graph):
-    """What the graph's sparse-attention and linear-attention layers mean to
-    the scheduler's counters: ``(SparseBlockAttention op or None, number of
-    such layers, number of LightningAttention layers)``, or None for a graph
-    that has neither."""
-    sparse = [n.op for n in graph.nodes
-              if isinstance(n.op, SparseBlockAttention)]
-    linear = sum(isinstance(n.op, LightningAttention) for n in graph.nodes)
-    if not sparse and not linear:
-        return None
-    return (sparse[0] if sparse else None), len(sparse), linear
-
-
 @register_op
 class SparseBlockAttention(_SlotStateOp):
     """InfLLM-v2 sparse attention over flat token batches (MiniCPM4 report,
@@ -1539,6 +1617,38 @@ class SparseBlockAttention(_SlotStateOp):
 
         return (below(min(hi, edge)) - below(min(lo, edge))
                 + run * (max(hi, edge) - max(lo, edge)))
+
+    launch_reads = ("block_size", "dense_len", "chosen_blocks", "tail_run",
+                    "kernel_size", "kernel_stride")
+
+    def launch_counts(self, decode, prompt, layers, counted):
+        """``attended_blocks_sum``: the cache blocks the rows read at launch
+        (a sparse layer and K/V head: every block below ``dense_len``, the
+        forced and chosen ones after it); ``window_run_blocks_sum``: those
+        of them fetched as whole runs (the forced window of a row that
+        selects); ``index_len_sum``: the compressed keys they choose by.
+        The ``sparse.*`` counters: the same over every position written."""
+        writes = _writes(decode, prompt)
+        args = {"attended_blocks_sum": sum(self.attended_blocks(lo)
+                                           for lo, _ in writes),
+                "window_run_blocks_sum": sum(self.run_blocks(lo)
+                                             for lo, _ in writes),
+                "index_len_sum": sum(self.index_len(lo) for lo, _ in writes)}
+        if not counted:
+            return args, {}
+        dense = self.dense_len
+        counters = {
+            "sparse.blocks_attended": layers * sum(
+                self.attended_blocks_between(lo, hi) for lo, hi in writes),
+            "sparse.window_run_blocks": layers * sum(
+                self.run_blocks_between(lo, hi) for lo, hi in writes),
+            "sparse.dense_rows": layers * sum(
+                min(hi, dense) - min(lo, dense) for lo, hi in writes)}
+        entries = sum(self.index_len(hi - 1) - self.index_len(lo - 1)
+                      for lo, hi in writes)
+        if entries:
+            counters["sparse.index_entries_written"] = entries * layers
+        return args, counters
 
     # ---- shapes / params ------------------------------------------------
     @property
@@ -1895,6 +2005,12 @@ class LightningAttention(_SlotStateOp):
                  self.head_dim)
         return {"lin": (shape, "float32", TensorSharding.replicated(4))}
 
+    def launch_counts(self, decode, prompt, layers, counted):
+        """Counted: ``linear.state_resets``, the requests whose state starts
+        from zero in this launch, over all layers."""
+        fresh = counted and sum(lo == 0 for lo, _ in _writes(decode, prompt))
+        return {}, ({"linear.state_resets": fresh * layers} if fresh else {})
+
     # ---- the three forms --------------------------------------------------
     def _slot_order(self, q, k, v, lin, seg):
         """The decode scan's step: every slot's matrix decayed, updated and
@@ -2104,6 +2220,29 @@ class KimiDeltaAttention(_SlotStateOp):
     """
 
     type_name = "kimi_delta_attention"
+    # a float32 matrix a head whose update READS the state it changes —
+    # nothing snapshots it or rolls it back (ROADMAP B-I 5): what each
+    # deployment option lacks for it
+    # (inference_manager.refuse_unsupported_slot_state)
+    refusal_order = 1
+    refusals = {
+        "kv_page_size": (
+            "; for a delta state, a snapshot of the float32 matrix a head at "
+            "a shared prefix's end (every token rewrites it whole: no page "
+            "of it outlives a position)"),
+        "kv_dtype": (
+            "; a delta state is float32 by its recurrence (the correction "
+            "subtracts what the state already holds): 'int8' has no reading "
+            "for it"),
+        "max_spec_tokens": (
+            "; a delta state has no rollback at all: its update reads the "
+            "state it changes, so a rejected token leaves nothing to invert"),
+        "tp": (
+            "; for the delta rule a rule that shards its heads (the fused "
+            "projection's columns, the conv's channels, the state's head "
+            "axis, W_o's rows)"),
+        "pipelined": " (nor a delta state's matrices)",
+    }
     # the projections weight-only int8 replaces (serve/quant.py); the
     # decay's pair and ``W_beta`` stay as they are: they are float32 paths
     int8_params = ("g_a", "g_b", "o_proj")
@@ -2218,6 +2357,18 @@ class KimiDeltaAttention(_SlotStateOp):
         # rows of no piece are whatever the output's buffer held
         return (jnp.where(seg.live[:, None, None], o, 0.0), kda,
                 "delta_rule_chunk")
+
+    launch_reads = ("chunk",)
+
+    def launch_counts(self, decode, prompt, layers, counted):
+        """``prompt_kda_pieces``: the pieces the chunked form runs for the
+        launch's prompt segments (one request's consecutive rows of ONE flat
+        batch or scan chunk) — ``ceil(rows / chunk)`` a segment:
+        :meth:`_pieces`' rule, on the host."""
+        if prompt is None:
+            return {}, {}
+        return {"prompt_kda_pieces": sum(-(-(hi - lo) // self.chunk)
+                                         for lo, hi in prompt)}, {}
 
     def _pieces(self, seg):
         """The chunked form's pieces in row order, as ``delta_rule_chunk``
@@ -2352,12 +2503,3 @@ class KimiDeltaAttention(_SlotStateOp):
             y = jnp.dot(o.reshape(t, h * d).astype(x.dtype), weight("o_proj"),
                         preferred_element_type=f32)
             return [y.astype(self.dtype)]
-
-
-def delta_piece(graph):
-    """The piece (``chunk``) of the graph's delta-rule layers' chunked form
-    (:class:`KimiDeltaAttention`), or None for a graph that has none."""
-    for n in graph.nodes:
-        if isinstance(n.op, KimiDeltaAttention):
-            return n.op.chunk
-    return None

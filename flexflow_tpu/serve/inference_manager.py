@@ -170,6 +170,39 @@ def register_serve_capacities(graph, max_requests, max_seq_len,
             node.op.cost_max_tokens = max_tokens or max_seq_len
 
 
+# what each deployment option lacks for ANY per-slot state
+# (:func:`refuse_unsupported_slot_state`); an op's own ``refusals`` add to it
+_SLOT_STATE_LACKS = {
+    "kv_page_size": (
+        "kv_page_size: a page table for a ring that wraps (a differential or "
+        "a plain window layer's: a page would hold positions a ring apart) "
+        "or a cache that compacts (pages assume one entry a position), pages "
+        "for an index of compressed keys beside a cache, and copy-on-write "
+        "of recurrent or matrix state (linear attention's, or a state-space "
+        "scan's per head) or of an open window at a shared prefix's end"),
+    "kv_dtype": (
+        "kv_dtype='int8': quantise-on-write of the window ring (the kernels' "
+        "ring paths take no scale planes), of the cache the cross-attention "
+        "layers read, of a compacting cache's summaries and of a cache whose "
+        "compressed keys choose what is read; a graph that keeps plain K/V "
+        "planes in a few layers beside float32 matrix state in the rest has "
+        "no reading of 'int8' for the state"),
+    "max_spec_tokens": (
+        "speculation: a recurrent or matrix state, a closed window or an "
+        "appended index entry cannot be rolled back over rejected tokens "
+        "without a snapshot per tree node"),
+    "tp": (
+        "tp > 1: a sharding rule for the conv, the scan, the differential "
+        "attention's head pairs, a plain ring's K/V groups (its state is "
+        "replicated), the per-head summaries, a selection per K/V head on "
+        "fewer K/V heads than chips and a matrix state per head"),
+    "pipelined": (
+        "pp > 1 (the pipelined manager, at any number of stages): the "
+        "exported scan output and the shared cache cross stage boundaries, "
+        "and its per-stage state hand-over knows full-length K/V planes only"),
+}
+
+
 def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
                                  max_spec_tokens=0, tp=1,
                                  pipelined=False) -> None:
@@ -178,98 +211,21 @@ def refuse_unsupported_slot_state(graph, *, kv_dtype=None, kv_page_size=None,
     slot-contiguous, in its compute dtype, one token a step, on one chip.
     Each other deployment option needs something that is not written yet; it
     is refused here, at compile, by what is missing — none silently takes
-    another path."""
+    another path.  An op whose state lacks more than every such state does
+    says so itself (``refusals``: the clause it adds, by option;
+    ``refusal_order`` places it among other ops')."""
     kinds = sorted({type(n.op).__name__ for n in graph.nodes
                     if getattr(n.op, "slot_state", False)})
     if not kinds:
         return
-    routed = any(getattr(n.op, "counts_load", False) for n in graph.nodes)
-    # a latent cache (LatentAttention): two planes a position, shared by all
-    # heads, that are not K and V planes — each option says what IT lacks
-    latent = "LatentAttention" in kinds
-    # a delta-rule state (KimiDeltaAttention): a float32 matrix a head whose
-    # update READS the state it changes — nothing snapshots it or rolls it
-    # back (ROADMAP B-I 5)
-    delta = "KimiDeltaAttention" in kinds
-    missing = []
-    if kv_page_size:
-        missing.append("kv_page_size: a page table for a ring that wraps "
-                       "(a differential or a plain window layer's: a page "
-                       "would hold positions a ring apart) "
-                       "or a cache that compacts (pages assume one entry a "
-                       "position), pages for an index of compressed keys "
-                       "beside a cache, and copy-on-write of recurrent or "
-                       "matrix state (linear attention's, or a state-space "
-                       "scan's per head) or of an open window at a shared "
-                       "prefix's end"
-                       + ("; for a latent cache, pages, copy-on-write, spill "
-                          "and swap_signature over a latent plane and a "
-                          "rotated-key plane of another width (kv_paged.py "
-                          "pools K and V planes of one head size), and a "
-                          "paged mode of the latent decode kernel"
-                          if latent else "")
-                       + ("; for a delta state, a snapshot of the float32 "
-                          "matrix a head at a shared prefix's end (every "
-                          "token rewrites it whole: no page of it outlives a "
-                          "position)" if delta else ""))
-    if kv_dtype == "int8":
-        missing.append("kv_dtype='int8': quantise-on-write of the window "
-                       "ring (the kernels' ring paths take no scale planes), "
-                       "of the cache the cross-attention layers read, "
-                       "of a compacting cache's summaries and of a cache "
-                       "whose compressed keys choose what is read; a graph "
-                       "that keeps plain K/V planes in a few layers beside "
-                       "float32 matrix state in the rest has no reading of "
-                       "'int8' for the state"
-                       + ("; for a latent cache, scale planes beside the "
-                          "latent and the rotated key part (one latent is "
-                          "key AND value of every head: a per-vector scale "
-                          "folds into neither contraction as the K/V "
-                          "kernels' do) and the latent kernel mode that "
-                          "reads them" if latent else "")
-                       + ("; a delta state is float32 by its recurrence (the "
-                          "correction subtracts what the state already "
-                          "holds): 'int8' has no reading for it"
-                          if delta else ""))
-    if max_spec_tokens:
-        missing.append("speculation: a recurrent or matrix state, a closed "
-                       "window or an appended index entry cannot be rolled "
-                       "back over rejected tokens without a snapshot per "
-                       "tree node"
-                       + ("; a latent cache has no spec-tree buffers, commit "
-                          "copy or tree-mask kernel over latents"
-                          if latent else "")
-                       + ("; a delta state has no rollback at all: its "
-                          "update reads the state it changes, so a rejected "
-                          "token leaves nothing to invert" if delta else ""))
-    if tp > 1:
-        missing.append("tp > 1: a sharding rule for the conv, the scan, the "
-                       "differential attention's head pairs, a plain ring's "
-                       "K/V groups (its state is replicated), the per-head "
-                       "summaries, a selection per K/V head on fewer K/V "
-                       "heads than chips and a matrix state per head"
-                       + ("; for latent attention a rule that shards the "
-                          "absorbed heads (W_q, the per-head up-projections, "
-                          "W_o's rows) with the latent cache replicated"
-                          if latent else "")
-                       + ("; for the delta rule a rule that shards its heads "
-                          "(the fused projection's columns, the conv's "
-                          "channels, the state's head axis, W_o's rows)"
-                          if delta else "")
-                       + ("; for the routed experts an exchange of rows "
-                          "between the chips that hold them (here each "
-                          "graph computes the experts it holds and nothing "
-                          "brings the rest)" if routed else ""))
-    if pipelined:
-        missing.append("pp > 1 (the pipelined manager, at any number of "
-                       "stages): the exported scan output and the shared "
-                       "cache cross stage boundaries, and its per-stage "
-                       "state hand-over knows full-length K/V planes only"
-                       + (" (not a latent cache's two planes)"
-                          if latent else "")
-                       + (" (nor a delta state's matrices)" if delta else "")
-                       + (" (nor does it carry the routed layers' load "
-                          "counters out of a stage)" if routed else ""))
+    own = sorted({type(n.op) for n in graph.nodes
+                  if getattr(n.op, "refusals", None)},
+                 key=lambda cls: cls.refusal_order)
+    asked = {"kv_page_size": kv_page_size, "kv_dtype": kv_dtype == "int8",
+             "max_spec_tokens": max_spec_tokens, "tp": tp > 1,
+             "pipelined": pipelined}
+    missing = [lacks + "".join(cls.refusals.get(option, "") for cls in own)
+               for option, lacks in _SLOT_STATE_LACKS.items() if asked[option]]
     if missing:
         raise ValueError(
             f"this graph keeps per-slot state in {kinds}, which cannot be "
@@ -513,7 +469,7 @@ class InferenceManager:
         # ``decode_attention`` planned there} (``ops.note_decode_block``)
         self.attention_paths: Dict[Tuple[str, Any], str] = {}
         self._paths_counted = 0
-        # routed-expert layers (ops that leave a load count: MoEDispatch),
+        # routed-expert layers (ops that leave a load count: ``counts_load``),
         # and per launch dispatched and not yet collected ``(kind, steps,
         # int32[3] on the device: experts visited, pairs, the fullest
         # expert's pairs — summed over steps and layers)``, ``kind`` one of

@@ -22,8 +22,7 @@ import numpy as np
 
 from ..obs.telemetry import telemetry_or_null
 from .batch_config import BatchConfig, PrefillBatchConfig
-from .hybrid_ops import (compact_geometry, compact_len, delta_piece,
-                         ring_window, sparse_geometry)
+from .hybrid_ops import launch_counter
 from .inference_manager import EXIT_NOT_IN_BATCH
 from .resilience import ResilienceConfig, TransientServeError
 
@@ -198,18 +197,9 @@ class RequestManager:
         self.tokens_decoded = 0
         # dispatch-span arguments of the batch _build_next_batch made last
         self._step_counts: Optional[Dict[str, int]] = None
-        # (window, chunk, layers) of a cache that compacts itself, or None:
-        # what ``_compact_counts`` tells the dispatch spans of it
-        self._compact = compact_geometry(im.model.graph)
-        # (a sparse-attention op, its layers, the linear-attention layers),
-        # or None: what ``_sparse_counts`` tells them
-        self._sparse = sparse_geometry(im.model.graph)
-        # the window of a graph's plain ring layers, or None: what
-        # ``_ring_counts`` tells the dispatch spans beside ``ctx_sum``
-        self._ring = ring_window(im.model.graph)
-        # the piece of a graph's delta-rule layers' chunked form, or None:
-        # what ``_prompt_kda_counts`` tells the prompt launches' spans
-        self._kda_piece = delta_piece(im.model.graph)
+        # what a launch means to the graph's ops that keep state per slot:
+        # ``_op_counts`` tells the dispatch spans of it beside ``ctx_sum``
+        self._launch_counter = launch_counter(im.model.graph)
         self.scan_runs = 0      # decode stretches run as on-device scans
         # ONE Telemetry handle across the serving stack: syncing it onto the
         # InferenceManager (which forwards to pipeline stages) puts request
@@ -382,56 +372,23 @@ class RequestManager:
             "rows": n_decode,
             "prompt_tokens": sum(hi - lo for _, lo, hi in pre),
             "ctx_sum": sum(hi for _, _, hi in dec),
-            **self._ring_counts(hi for _, _, hi in dec),
-            **self._prompt_ring_counts((lo, hi) for _, lo, hi in pre),
-            **self._prompt_kda_counts(hi - lo for _, lo, hi in pre),
             "prompt_ctx_sum": sum((hi - lo) * (hi + lo + 1) // 2
                                   for _, lo, hi in pre),
-            **self._slot_state_counts([(lo, hi) for _, lo, hi in spans]),
+            **self._op_counts([(lo, hi) for _, lo, hi in dec],
+                              [(lo, hi) for _, lo, hi in pre]),
         }
 
-    def _ring_counts(self, contexts) -> Dict[str, int]:
-        """For a graph with plain sliding-window layers (``hybrid_ops.
-        SlidingWindowAttention``): ``ring_ctx_sum``, the positions the
-        decode rows' RING layers read at launch — ``min(context, window)``
-        a row, where ``ctx_sum`` counts the whole contexts (what the
-        full-length layers read).  Nothing for a graph without them."""
-        if self._ring is None:
-            return {}
-        return {"ring_ctx_sum": sum(min(c, self._ring) for c in contexts)}
-
-    def _prompt_ring_counts(self, writes) -> Dict[str, int]:
-        """For the same graphs, of a launch that feeds the prompt positions
-        ``writes`` = ``[(lo, hi)]``: ``prompt_ring_ctx_sum``, sum over those
-        rows of ``min(position + 1, window)`` — the window kernel's least
-        work, beside the full-context sum (``prompt_ctx_sum``)."""
-        if self._ring is None:
-            return {}
-        w = self._ring
-        tri = lambda n: n * (n + 1) // 2    # 1 + 2 + .. + n
-        return {"prompt_ring_ctx_sum": sum(
-            tri(min(hi, w)) - tri(min(lo, w)) + w * (max(hi, w) - max(lo, w))
-            for lo, hi in writes)}
-
-    def _prompt_kda_counts(self, runs) -> Dict[str, int]:
-        """For a graph with delta-rule layers (``hybrid_ops.
-        KimiDeltaAttention``), of a launch whose prompt rows lie in segments
-        of ``runs`` rows each (one request's consecutive rows of ONE flat
-        batch or scan chunk): ``prompt_kda_pieces``, the loop trips the
-        chunked form runs for them in one such layer — a piece starts with
-        the segment and every ``chunk`` rows into it (``_chunked``'s own
-        rule), so ``ceil(rows / chunk)`` a segment.  Counted here, from what
-        the scheduler holds: no device read."""
-        if self._kda_piece is None:
-            return {}
-        return {"prompt_kda_pieces": sum(-(-n // self._kda_piece)
-                                         for n in runs)}
-
-    def _slot_state_counts(self, writes) -> Dict[str, int]:
-        """Dispatch-span arguments (and counters) of a launch that writes
-        the positions ``[(lo, hi)]``, one pair a row, for the kinds of
-        per-slot state whose work ``ctx_sum`` does not tell."""
-        return {**self._compact_counts(writes), **self._sparse_counts(writes)}
+    def _op_counts(self, decode, prompt) -> Dict[str, int]:
+        """Dispatch-span arguments of a launch for the per-slot state whose
+        work ``ctx_sum`` does not tell, as the graph's ops give them
+        (``hybrid_ops.launch_counter``: its ``decode`` and ``prompt``); the
+        counters they name are counted here."""
+        tel = self.telemetry
+        args, counters = self._launch_counter(decode, prompt, tel.enabled)
+        if tel.enabled:
+            for name, n in counters.items():
+                tel.metrics.counter(name).inc(n)
+        return args
 
     def _expert_load(self) -> Dict[str, int]:
         """For a graph with routed-expert layers: what the launches read
@@ -466,70 +423,6 @@ class RequestManager:
                     tel.metrics.counter(name).inc(load[key])
                     tel.trace.counter(name, load[key])
         return load
-
-    def _sparse_counts(self, writes) -> Dict[str, int]:
-        """For a graph with sparse-attention layers (``hybrid_ops.
-        SparseBlockAttention``): ``attended_blocks_sum``, the cache blocks
-        the rows read at launch (per sparse layer and K/V head — every block
-        below ``dense_len``, the forced and chosen ones after it),
-        ``window_run_blocks_sum``, those of them the kernel fetches as whole
-        runs (one copy of ``window / block`` consecutive blocks: the forced
-        window of a row that selects), and ``index_len_sum``, the compressed
-        keys they choose by.  Counted too: ``sparse.blocks_attended``,
-        ``sparse.window_run_blocks`` and ``sparse.dense_rows`` over every
-        position the launch writes, ``sparse.index_entries_written``, and
-        ``linear.state_resets`` (requests whose linear-attention state
-        starts from zero).  Nothing for a graph with neither."""
-        if self._sparse is None:
-            return {}
-        op, layers, linear = self._sparse
-        writes = [(lo, hi) for lo, hi in writes if hi > lo]
-        tel = self.telemetry
-        if tel.enabled and linear:
-            fresh = sum(lo == 0 for lo, _ in writes)
-            if fresh:
-                tel.metrics.counter("linear.state_resets").inc(fresh * linear)
-        if op is None:
-            return {}
-        if tel.enabled:
-            dense = op.dense_len
-            count = tel.metrics.counter
-            count("sparse.blocks_attended").inc(layers * sum(
-                op.attended_blocks_between(lo, hi) for lo, hi in writes))
-            count("sparse.window_run_blocks").inc(layers * sum(
-                op.run_blocks_between(lo, hi) for lo, hi in writes))
-            count("sparse.dense_rows").inc(layers * sum(
-                min(hi, dense) - min(lo, dense) for lo, hi in writes))
-            entries = sum(op.index_len(hi - 1) - op.index_len(lo - 1)
-                          for lo, hi in writes)
-            if entries:
-                count("sparse.index_entries_written").inc(entries * layers)
-        return {"attended_blocks_sum": sum(op.attended_blocks(lo)
-                                           for lo, _ in writes),
-                "window_run_blocks_sum": sum(op.run_blocks(lo)
-                                             for lo, _ in writes),
-                "index_len_sum": sum(op.index_len(lo) for lo, _ in writes)}
-
-    def _compact_counts(self, writes) -> Dict[str, int]:
-        """What a launch that writes the positions ``[(lo, hi)]`` (one pair
-        a row) means to a cache that compacts itself (``hybrid_ops.
-        EvaAttention``): ``cache_len_sum``, the entries the rows' caches
-        hold at launch — their ``L(lo)``, where ``ctx_sum`` counts
-        positions — and ``compactions``, the windows the launch closes.
-        Counted too (``eva.windows_closed``, and the summary pairs written
-        over all layers); nothing for a graph without such a cache."""
-        if self._compact is None:
-            return {}
-        window, chunk, layers = self._compact
-        closed = sum(hi // window - lo // window for lo, hi in writes)
-        tel = self.telemetry
-        if closed and tel.enabled:
-            tel.metrics.counter("eva.windows_closed").inc(closed)
-            tel.metrics.counter("eva.summaries_written").inc(
-                closed * layers * (window // chunk))
-        return {"cache_len_sum": sum(compact_len(lo, window, chunk) + 1
-                                     for lo, hi in writes if hi > lo),
-                "compactions": closed}
 
     @staticmethod
     def _fold_for(req: Request) -> Tuple[int, int]:
@@ -1897,11 +1790,8 @@ class RequestManager:
                    # carries it
                    "prompt_ctx_sum": sum(t * (2 * st + t + 1) // 2
                                          for st, t in parts),
-                   **self._prompt_ring_counts(
-                       (st, st + t) for st, t in parts),
-                   **self._prompt_kda_counts(t for _, t in parts),
-                   **self._slot_state_counts(
-                       [(st, st + t) for st, t in parts])}
+                   **self._op_counts(
+                       None, [(st, st + t) for st, t in parts])}
             res = self._guarded(
                 "prefill_scan",
                 lambda s=stacked, a=smp, c=cnt, rest=cut[k + 1:]:
@@ -2062,11 +1952,9 @@ class RequestManager:
                     if k > 0:   # a live row, and its KV length at launch
                         cnt["rows"] += 1
                         cnt["ctx_sum"] += dev_seq[req.rid]
-                cnt.update(self._ring_counts(
-                    dev_seq[req.rid] for req, _ in rows if ks[req.rid] > 0))
-                cnt.update(self._slot_state_counts(
+                cnt.update(self._op_counts(
                     [(dev_seq[req.rid] - 1, dev_seq[req.rid] - 1
-                      + ks[req.rid]) for req, _ in rows]))
+                      + ks[req.rid]) for req, _ in rows], None))
                 if prof.enabled:
                     # k_i decode steps per row: each streams the weights
                     # and reads the growing causally-live prefix

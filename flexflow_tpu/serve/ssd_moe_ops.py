@@ -357,9 +357,20 @@ class MoEDispatch(_Replicated):
     it leaves ``[experts visited, pairs, the fullest expert's pairs]``."""
 
     type_name = "moe_dispatch"
-    # one per routed layer: InferenceManager.expert_layers counts these, and
-    # refuse_unsupported_slot_state knows a routed graph by them
+    # one per routed layer: InferenceManager.expert_layers counts these
     counts_load = True
+    # what tp and pp lack for the routed layers beside a per-slot state
+    # (inference_manager.refuse_unsupported_slot_state)
+    refusal_order = 2
+    refusals = {
+        "tp": (
+            "; for the routed experts an exchange of rows between the chips "
+            "that hold them (here each graph computes the experts it holds "
+            "and nothing brings the rest)"),
+        "pipelined": (
+            " (nor does it carry the routed layers' load counters out of a "
+            "stage)"),
+    }
 
     def __init__(self, num_held: int, held_lo: int = 0):
         self.num_held = int(num_held)

@@ -773,8 +773,13 @@ def test_the_decode_scans_launch_says_what_the_rings_read():
         assert spans and all(
             a["ring_ctx_sum"] == WINDOW * a["rows"] < a["ctx_sum"]
             for a in spans)
-        assert rm._ring_counts([3, WINDOW, 500]) == {
-            "ring_ctx_sum": 3 + 2 * WINDOW}
+        (ring,) = [n.op for n in im.model.graph.nodes
+                   if isinstance(n.op, SlidingWindowAttention)][:1]
+        # decode rows at contexts 3, WINDOW and 500 (a scan's rows write
+        # several positions; one that writes none reads nothing)
+        assert ring.launch_counts(
+            [(2, 3), (WINDOW - 1, WINDOW + 7), (499, 500), (40, 40)], None,
+            3, True) == ({"ring_ctx_sum": 3 + 2 * WINDOW}, {})
         records = rm.journal.records()
         assert all(r["ring_ctx_sum"] <= r["ctx_sum"] for r in records)
         assert max(r["ring_ctx_sum"] for r in records) > 0

@@ -46,6 +46,10 @@ from benchmark import check, seeded_weights as sw  # noqa: E402
 from benchmark.reference import mellum as ref  # noqa: E402
 from flexflow_tpu.core.op import OpContext  # noqa: E402
 from flexflow_tpu.serve import ops as serve_ops  # noqa: E402
+from flexflow_tpu.serve.hybrid_ops import (  # noqa: E402
+    SlidingWindowAttention,
+    _SlotStateOp,
+)
 from flexflow_tpu.serve.models import mellum as builder  # noqa: E402
 from flexflow_tpu.serve.models.base import ServeModelConfig  # noqa: E402
 from flexflow_tpu.serve.ssd_moe_ops import MoEExperts, MoERouter  # noqa: E402
@@ -423,9 +427,12 @@ def test_the_prompt_launches_count_their_experts_and_ring_reads():
         assert sum(a["prompt_tokens"] for a in scans) == sum(lens)
         by_hand = sum(min(p + 1, WINDOW) for n in lens for p in range(n))
         assert sum(a["prompt_ring_ctx_sum"] for a in scans) == by_hand
-        assert rm._prompt_ring_counts([(0, 5), (30, 40), (WINDOW, 99)]) == {
-            "prompt_ring_ctx_sum": 15 + (31 + 32 + 8 * WINDOW)
-            + (99 - WINDOW) * WINDOW}
+        (ring,) = [n.op for n in im.model.graph.nodes
+                   if isinstance(n.op, SlidingWindowAttention)][:1]
+        assert ring.launch_counts(
+            None, [(0, 5), (30, 40), (WINDOW, 99)], 3, True) == ({
+                "prompt_ring_ctx_sum": 15 + (31 + 32 + 8 * WINDOW)
+                + (99 - WINDOW) * WINDOW}, {})
         commits = [e["args"] for e in events if e["name"] == "commit"]
         total = lambda k: sum(c.get(k, 0) for c in commits)
         layers, k, held = 2, 4, 16
@@ -451,4 +458,109 @@ def test_the_prompt_launches_count_their_experts_and_ring_reads():
             total("prefill_experts_visited")
         assert im.take_expert_load() is None
     finally:
+        im.telemetry = type(im).telemetry
+
+
+# ---- the seam: what a launch means to an op lives beside the op -----------
+
+class _Tally(_SlotStateOp):
+    """An op no module of the program names: it counts a launch's rows."""
+
+    launch_reads = ("step",)
+
+    def __init__(self, step):
+        self.step = step
+
+    def launch_counts(self, decode, prompt, layers, counted):
+        rows = len(decode or ()) + len(prompt or ())
+        return ({"tally_rows": self.step * rows},
+                {"tally.launches": layers} if rows and counted else {})
+
+
+class _Silent(_SlotStateOp):
+    """A per-slot state that says nothing of a launch (the default)."""
+
+
+def _graph(*ops):
+    node = lambda op: type("Node", (), {"op": op})()
+    return type("Graph", (), {"nodes": [node(op) for op in ops]})()
+
+
+def test_one_walk_of_the_graph_asks_each_class_once():
+    """``launch_counter``: the first node of a class answers for its
+    siblings (``layers`` says how many), the classes' answers merge, an op
+    that keeps the default is not asked, a graph without any gives nothing
+    — and siblings that differ in what the hook reads are refused.  Where
+    no one keeps the counters, none is made."""
+    from flexflow_tpu.serve.hybrid_ops import launch_counter
+
+    ring = SlidingWindowAttention(64, 4, 2, 16, window=8)
+    launch = launch_counter(_graph(_Tally(2), _Silent(), ring, _Tally(2),
+                                   object()))
+    count = lambda decode, prompt: launch(decode, prompt, True)
+    assert count([(3, 4), (20, 21)], [(0, 5)]) == (
+        {"tally_rows": 6, "ring_ctx_sum": 4 + 8,
+         "prompt_ring_ctx_sum": 15}, {"tally.launches": 2})
+    assert launch([(3, 4), (20, 21)], [(0, 5)], False) == (
+        count([(3, 4), (20, 21)], [(0, 5)])[0], {})
+    # a decode scan feeds no prompt, a prefill scan decodes nothing: the
+    # names that speak of the missing rows are left out, not zero
+    assert count([(3, 7)], None) == (
+        {"tally_rows": 2, "ring_ctx_sum": 4}, {"tally.launches": 2})
+    assert count(None, [(0, 5), (8, 9)]) == (
+        {"tally_rows": 4, "prompt_ring_ctx_sum": 15 + 8},
+        {"tally.launches": 2})
+    assert count([], []) == (
+        {"tally_rows": 0, "ring_ctx_sum": 0, "prompt_ring_ctx_sum": 0}, {})
+    assert launch_counter(_graph(_Silent(), object()))(
+        [(3, 4)], [], True) == ({}, {})
+    with pytest.raises(AssertionError, match="step"):
+        launch_counter(_graph(_Tally(2), _Tally(3)))
+
+
+def test_an_op_the_scheduler_never_heard_of_counts_its_launches():
+    """The seam, held: a subclass defined HERE returns a made-up argument
+    and a made-up counter from ``launch_counts`` — both are on the
+    ``prefill_scan_dispatch``, ``decode_scan_dispatch`` and ``step_dispatch``
+    spans and in the metrics registry of served requests, with no edit of
+    ``request_manager.py`` — beside what the class it extends says."""
+    from flexflow_tpu.obs import Telemetry
+    from flexflow_tpu.serve import GenerationConfig, RequestManager
+
+    im = RIG.deployment(use_pallas=True)
+    (ring,) = [n.op for n in im.model.graph.nodes
+               if isinstance(n.op, SlidingWindowAttention)][:1]
+
+    class Tallied(type(ring)):
+        def launch_counts(self, decode, prompt, layers, counted):
+            args, counters = super().launch_counts(decode, prompt, layers,
+                                                   counted)
+            rows = len(decode or ()) + len(prompt or ())
+            return ({**args, "tally_rows": rows},
+                    {**counters, "tally.rows": rows * layers})
+
+    tel = Telemetry()
+    ring.__class__ = Tallied
+    try:
+        rm = RequestManager(im, GenerationConfig(stop_on_eos=False),
+                            telemetry=tel)
+        # 1 token off the prompt, then scans of 4 and 2 steps and a flat
+        # step for the last
+        rm.generate([tokens(70, salt=66), tokens(40, salt=67)], 8)
+        launches = {}
+        for e in tel.trace.trace_events():
+            if e["name"].endswith("_dispatch") and not e["args"].get("pad"):
+                launches.setdefault(e["name"], []).append(e["args"])
+        assert set(launches) == {"prefill_scan_dispatch",
+                                 "decode_scan_dispatch", "step_dispatch"}
+        assert all("tally_rows" in a for v in launches.values() for a in v)
+        assert all(a["tally_rows"] == a["segments"]
+                   and "prompt_ring_ctx_sum" in a
+                   for a in launches["prefill_scan_dispatch"])
+        assert all(a["tally_rows"] == 2 and "ring_ctx_sum" in a
+                   for a in launches["decode_scan_dispatch"])
+        assert tel.metrics.snapshot()["tally.rows"] == sum(
+            a["tally_rows"] for v in launches.values() for a in v)
+    finally:
+        ring.__class__ = SlidingWindowAttention
         im.telemetry = type(im).telemetry

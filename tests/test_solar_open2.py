@@ -52,7 +52,6 @@ from flexflow_tpu.serve.batch_config import PrefillBatchConfig  # noqa: E402
 from flexflow_tpu.serve.hybrid_ops import (  # noqa: E402
     KimiDeltaAttention,
     Segments,
-    delta_piece,
 )
 from flexflow_tpu.serve.models import solar_open2 as builder  # noqa: E402
 from flexflow_tpu.serve.models.base import ServeModelConfig  # noqa: E402
@@ -297,7 +296,7 @@ def test_beta_is_doubled_in_the_graph_and_nowhere_else():
               if isinstance(n.op, KimiDeltaAttention)]
     assert kda.allow_neg_eigval and kda.inner == 2 * HF["hidden_size"]
     assert not KimiDeltaAttention(e, h, d).allow_neg_eigval
-    assert delta_piece(im.model.graph) == PIECE
+    assert kda.chunk == PIECE
 
 
 def test_about_half_the_seeded_rows_have_beta_over_1():
@@ -625,20 +624,26 @@ def test_a_break_is_seen(broken, monkeypatch):
 # ---- what the launches say -----------------------------------------------
 
 def test_the_pieces_a_launch_counts_are_the_loops_trips():
-    """``_prompt_kda_counts`` against the pieces the device runs, on the
+    """The op's ``launch_counts`` against the pieces the device runs, on the
     batch a tiled chunk really is: segments of 37 and 20 rows in tile-padded
     rows of one chunk are 2 + 1 pieces — the count ``delta_rule_chunk``
     takes as its grid's sequential bound (``_pieces``: its scalar-prefetch
     arrays, a piece's first row, own rows, state row, entering state, last)
     and the trips ``_chunked``'s ``fori_loop`` runs, by the same rule."""
     from flexflow_tpu.ops.pallas.delta_rule import CONTINUE, STORED, ZEROS
-    from flexflow_tpu.serve import GenerationConfig, RequestManager
 
-    rm = RequestManager(RIG.deployment(), GenerationConfig())
-    assert rm._prompt_kda_counts([37, 20]) == {"prompt_kda_pieces": 3}
-    assert rm._prompt_kda_counts([5, 32, 33, 64, 65]) == {
-        "prompt_kda_pieces": 1 + 1 + 2 + 2 + 3}
-    assert rm._prompt_kda_counts([]) == {"prompt_kda_pieces": 0}
+    (op,) = [n.op for n in RIG.deployment().model.graph.nodes
+             if isinstance(n.op, KimiDeltaAttention)][:1]
+    # segments of as many rows, wherever they start; no decode row counts
+    pieces = lambda runs: op.launch_counts(
+        [(9, 10)], [(7 * n, 7 * n + rows) for n, rows in enumerate(runs)], 1,
+        True)
+    assert pieces([37, 20]) == ({"prompt_kda_pieces": 3}, {})
+    assert pieces([5, 32, 33, 64, 65]) == (
+        {"prompt_kda_pieces": 1 + 1 + 2 + 2 + 3}, {})
+    assert pieces([]) == ({"prompt_kda_pieces": 0}, {})
+    # a launch that feeds no prompt says nothing of pieces
+    assert op.launch_counts([(9, 12)], None, 1, True) == ({}, {})
     seq = np.zeros(SLOTS, np.int32)
     fields, _ = PrefillBatchConfig.np_fields(
         [(0, list(range(4, 41)), 0), (1, list(range(4, 24)), 64)], seq, 16,
@@ -646,12 +651,9 @@ def test_the_pieces_a_launch_counts_are_the_loops_trips():
     seg = Segments(BatchConfig(*(jnp.asarray(f) for f in fields)), SLOTS)
     piece = (seg.start | (seg.offset % PIECE == 0)) & seg.live
     assert int(piece.sum()) == 3
-    (op,) = [n.op for n in RIG.deployment().model.graph.nodes
-             if isinstance(n.op, KimiDeltaAttention)][:1]
     count, first, own, row, init, last = (
         np.asarray(a) for a in op._pieces(seg))
-    assert int(count) == 3 == rm._prompt_kda_counts([37, 20])[
-        "prompt_kda_pieces"]
+    assert int(count) == 3 == pieces([37, 20])[0]["prompt_kda_pieces"]
     assert first[:3].tolist() == [0, 32, 48] and own[:3].tolist() == \
         [32, 5, 20] and row[:3].tolist() == [0, 0, 1]
     # request 0 is fed from position 0, request 1 continues at 64

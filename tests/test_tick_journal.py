@@ -221,6 +221,39 @@ def test_nested_spans_give_self_time():
     assert jr.emitted == 1
 
 
+def test_a_prompt_launchs_arguments_are_summed_by_name():
+    """Every name of ``_PROMPT_LAUNCH`` is a field that sums the launch's
+    argument of that name — of a flat step and of a prefill scan, whatever
+    the name; a decode scan's adds to none of them, an all-pad scan's
+    neither; and the ``commit`` span's routed-expert load is set by name."""
+    assert set(J._PROMPT_LAUNCH) | set(J._EXPERT_LOAD) <= set(J.FIELDS)
+    vc = VirtualClock()
+    jr = TickJournal(clock_ns=vc.ns, chunk_width=CAP)
+    jr.begin(pending=0, live=1)
+    args = {name: 3 + i for i, name in enumerate(J._PROMPT_LAUNCH)}
+    with Span("serve_step", {"pc_ns": jr.clock_ns()}, jr=jr):
+        with Span("step_dispatch", {"rows": 1, **args}, jr=jr):
+            pass
+        with Span("prefill_scan_dispatch", {"n_steps": 1, **args}, jr=jr):
+            pass
+        with Span("prefill_scan_dispatch",
+                  {"n_steps": 1, "pad": 1, **args}, jr=jr):
+            pass
+        with Span("decode_scan_dispatch",
+                  {"n_steps": 4, "rows": 1, **args}, jr=jr):
+            pass
+        with Span("commit", jr=jr) as sp:
+            sp.set(**{name: 7 for name in J._EXPERT_LOAD})
+            sp.set(**{name: 1 for name in J._EXPERT_LOAD})
+    jr.end()
+    (r,) = jr.records()
+    assert (r["step_launches"], r["prefill_scans"], r["decode_scans"]) == \
+        (1, 2, 1)
+    assert {name: r[name] for name in J._PROMPT_LAUNCH} == {
+        name: 2 * n for name, n in args.items()}
+    assert all(r[name] == 8 for name in J._EXPERT_LOAD)
+
+
 def test_ring_drops_the_oldest_and_counts_them():
     vc = VirtualClock()
     rm = manager(vc, capacity=2)
