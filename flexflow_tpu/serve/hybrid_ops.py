@@ -215,9 +215,11 @@ class CausalConv1d(_SlotStateOp):
     ``[max_tokens, channels]``; state ``conv [max_requests + 1, K - 1,
     channels]``: the last ``K - 1`` inputs of each slot.
 
-    A prompt chunk, a flat step or a spec scan goes by ROWS: each row
-    gathers its slot's tail and picks every tap from it or from the rows
-    before it, and a segment's last row writes the new tail back by index.
+    A prompt chunk or a flat step goes by SEGMENTS (``_rows``): every row
+    takes its taps from the rows before it in the batch, and the stored
+    tails are read and written per SLOT — a slot's tail enters the ``K - 1``
+    rows that open its segment and the segment's last rows leave the new
+    one, so nothing of ``[rows, K - 1, channels]`` is gathered or scattered.
     The decode scan (``one_row_per_request``: every live row a request of
     its own) steps the tails in SLOT ORDER, where they lie.  Both sum the
     same float32 taps in the same order: the tails they leave are equal to
@@ -291,28 +293,55 @@ class CausalConv1d(_SlotStateOp):
         return y[at], tails
 
     def _rows(self, x, tails, seg, w, b):
-        """A prompt chunk, a flat step or a spec scan: each row gathers its
-        slot's tail, and a segment's last row writes the new one back."""
-        k = self.kernel
-        tail = tails[seg.rows]                       # [T, K-1, C]
-        # taps[j] = the input j positions back from the row's own:
-        # a row of this step where the segment reaches that far, else the
-        # slot's stored tail, else (before the request began) zero
-        taps = [x]
-        for back in range(1, k):
-            here = jnp.concatenate(
-                [jnp.zeros_like(x[:back]), x[:-back]], axis=0)
-            at = jnp.clip(k - 1 + seg.offset - back, 0, k - 2)
-            stored = jnp.take_along_axis(tail, at[:, None, None], axis=1)[:, 0]
-            val = jnp.where((seg.offset >= back)[:, None], here, stored)
-            taps.append(jnp.where(
-                ((seg.pos >= back) & seg.live)[:, None], val, 0))
+        """A prompt chunk or a flat step, by SEGMENTS (a batch holds at most
+        one a slot): a first pass takes every tap from the rows before it in
+        the batch; a slot's stored tail reaches only the ``K - 1`` rows that
+        open its segment, which are computed again PER SLOT with the stored
+        entries in place and put over the first pass's, and the new tail is
+        the segment's last rows — after the stored one's newest entries
+        where the segment is shorter.  Every tap is a selection, as in
+        :meth:`_slot_order`; nothing is gathered or written by row."""
+        k, t, nslot = self.kernel, x.shape[0], tails.shape[0]
+        # a tap the segment does not reach (``offset < back``: a stored
+        # entry, or before the request began) reads zero in this pass
+        taps = [x] + [
+            jnp.where((seg.offset >= back)[:, None], jnp.concatenate(
+                [jnp.zeros_like(x[:back]), x[:-back]], axis=0), 0)
+            for back in range(1, k)]
         y = self._taps_out(taps, w, b)
+        # each slot's segment: its first and last row of the batch and the
+        # position it opens at (a comparison a slot and row: no scatter)
+        idx = jnp.arange(t, dtype=jnp.int32)
+        slot = jnp.arange(nslot, dtype=jnp.int32)
+        mine = seg.live & (seg.rows == slot[:, None])
+        first = jnp.min(jnp.where(mine, idx, t), axis=1)
+        last = jnp.max(jnp.where(mine, idx, -1), axis=1)
+        length = last - first + 1                    # <= 0: no segment
+        pos = seg.pos[jnp.minimum(first, t - 1)]
+        # the inputs around the segment's first row, oldest first: the
+        # stored tail (zero before the request began), then its first rows
+        around = [jnp.where((pos + j >= k - 1)[:, None], tails[:, j], 0)
+                  for j in range(k - 1)]
+        around += [x[jnp.minimum(first + j, t - 1)] for j in range(k - 1)]
+        with jax.named_scope("segment_open"):
+            for j in range(k - 1):      # the rows the stored tail reaches
+                fix = self._taps_out([around[k - 1 + j - back]
+                                      for back in range(k)], w, b)
+                at = jnp.where(j < length, first + j, t + slot)
+                y = y.at[at].set(fix, mode="drop", unique_indices=True)
         with jax.named_scope("state_write"):
-            # what the segment leaves behind: the K-1 newest inputs as of
-            # its last row (oldest first, as the tail is read)
-            left = jnp.stack(taps[k - 2::-1], axis=1)
-            tails = _set_rows(tails, seg.store, left)
+            # what the segment leaves behind: its last K-1 rows — fewer,
+            # and the stored tail's newest entries stay in front of them
+            left = []
+            for j in range(k - 1):
+                new = x[jnp.maximum(last - (k - 2) + j, 0)]
+                for short in range(1, k - 1 - j):
+                    new = jnp.where((length == short)[:, None],
+                                    around[short + j], new)
+                left.append(new)
+            tails = jnp.where((length > 0)[:, None, None],
+                              jnp.stack(left, axis=1).astype(tails.dtype),
+                              tails)
         return y, tails
 
     def lower(self, ctx, inputs, params):

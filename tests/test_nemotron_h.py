@@ -768,6 +768,141 @@ def test_the_convs_slot_order_form_equals_its_row_form(layout, kernel, bias,
         assert not gap.any()
 
 
+def _conv_feed(scenario, t):
+    """``[[(slot, rows) ...] ...]``: the batches of ``t`` rows a scenario
+    feeds, in order; slot ``-1`` = pad rows.  ``big`` = a length that grows
+    with the batch (either side of ``DUS_MAX_TOKENS``)."""
+    big = t // 4
+    fill = lambda batch: batch + [(-1, t - sum(n for _, n in batch))]
+    if scenario == "one_segment_fills_the_batch":
+        # ... and the next batch continues it from the tail it left
+        return [[(2, t)], [(2, t)], [(2, t - 5), (-1, 5)]]
+    if scenario == "three_segments_and_pads":
+        return [fill([(-1, 2), (1, big), (-1, 3), (0, big + 1), (-1, 1),
+                      (3, 4)]),
+                fill([(-1, 1), (3, big - 1), (-1, 2), (1, 5), (-1, 4),
+                      (0, big)]),
+                fill([(0, 3), (-1, 1), (1, big), (3, 2)])]
+    if scenario == "segments_shorter_than_the_tail":
+        # 1 and 2 rows on stored tails (the new tail splices old entries and
+        # new rows), again and again; slots 3, 4 open with 2 rows and with 1
+        # (the zeros before position 0 show, and stay in the tail)
+        return [fill([(0, big), (1, 6), (2, 1)]),
+                fill([(0, 1), (1, 2), (-1, 1), (2, 1), (3, 2), (4, 1)]),
+                fill([(4, 1), (3, 1), (2, 2), (-1, 2), (1, 1), (0, 2)]),
+                fill([(0, 1), (1, 1), (2, 1), (3, 1), (4, 2)]),
+                fill([(3, big), (4, 3), (0, 2)])]
+    if scenario == "rows_out_of_slot_order":
+        return [fill([(5, 3), (2, big), (4, 1), (0, 6)]),
+                fill([(4, big), (0, 1), (5, 2), (-1, 3), (2, 2)]),
+                fill([(2, 1), (5, big), (0, 3), (4, 4)])]
+    if scenario == "a_flat_step_of_one_row_segments":
+        # a prompt's end among decode rows, every later step one row each
+        slots = [6, 1, 4, 0, 5, 3]
+        return ([fill([(s, 1 + i) for i, s in enumerate(slots)]
+                      + [(2, big)])]
+                + [fill([(-1, 1)] + [(s, 1) for s in order])
+                   for order in (slots, slots[::-1], slots[2:] + slots[:2])])
+    raise ValueError(scenario)
+
+
+@pytest.mark.parametrize("kernel,bias", [(4, True), (4, False), (3, True),
+                                         (2, False)],
+                         ids=["k4_bias", "k4", "k3_bias", "k2"])
+@pytest.mark.parametrize("t", [32, 1024], ids=["rows32", "rows1024"])
+@pytest.mark.parametrize("scenario", [
+    "one_segment_fills_the_batch", "three_segments_and_pads",
+    "segments_shorter_than_the_tail", "rows_out_of_slot_order",
+    "a_flat_step_of_one_row_segments"])
+def test_the_convs_row_form_equals_a_conv_over_each_whole_request(
+        scenario, t, kernel, bias):
+    """``CausalConv1d``'s row form (by SEGMENTS) against a NumPy conv run
+    request by request over each request's WHOLE input with zeros before
+    position 0 — fed in batches of 32 and of 1024 rows (either side of
+    ``DUS_MAX_TOKENS``), each request continuing where its last batch
+    stopped, on the tail that batch left.  Every slot starts on another
+    request's values, so what comes before position 0 has to be masked, not
+    read.  bf16 operands (the deployments'; their products are exact in
+    float32 and the taps are summed newest first in both): ``y`` of every
+    live row and the tails of every slot a request ran on are equal TO THE
+    BIT; the slots no request ran on and the scratch row (no pad writes it:
+    it is nobody's to read) keep their first values to the bit, after every
+    batch."""
+    rng = np.random.default_rng([SEED, 67, kernel, bias, t])
+    feed = _conv_feed(scenario, t)
+    slots, c = 7, 48
+    op = CausalConv1d(c, kernel, dtype=jnp.float32, bias=bias)
+    draw = lambda *shape: jnp.asarray(
+        rng.normal(size=shape).astype(np.float32), jnp.bfloat16)
+    first_tails = draw(slots + 1, kernel - 1, c)
+    params = {"weight": draw(kernel, c)}
+    if bias:
+        params["bias"] = draw(c)
+    w = np.asarray(params["weight"], np.float32)
+    b = np.asarray(params.get("bias", jnp.zeros((c,))), np.float32)
+
+    @jax.jit
+    def run(x, tails, rows, pos):
+        bc = BatchConfig(tokens=jnp.zeros((t,), jnp.int32),
+                         request_index=rows, token_position=pos,
+                         num_tokens=jnp.sum(rows >= 0).astype(jnp.int32),
+                         seq_lens=jnp.zeros((slots,), jnp.int32))
+        paths = {}
+        ctx = _ctx({"batch_config": bc, "state": {"conv": tails},
+                    "attention_paths": paths})
+        y = op.lower(ctx, [x], params)[0]
+        assert paths == {("causal_conv1d", "BatchConfig"): "rows"}
+        return y, ctx.extras["state_out"]["conv"]
+
+    whole = {}                       # slot -> every input fed to it so far
+    tails = first_tails
+    for batch in feed:
+        assert sum(n for _, n in batch) == t and min(
+            n for _, n in batch) >= 0, batch
+        rows = np.concatenate([np.full(n, s) for s, n in batch])
+        # where each piece starts: its row of the batch, its position
+        starts = [(sum(n for _, n in batch[:i]), len(whole.get(s, ())))
+                  for i, (s, _) in enumerate(batch)]
+        pos = np.concatenate([(done + np.arange(n)) * (s >= 0) for (s, n),
+                              (_, done) in zip(batch, starts)])
+        x = draw(t, c)
+        y, tails = run(x, tails, jnp.asarray(rows, jnp.int32),
+                       jnp.asarray(pos, jnp.int32))
+        y, x32 = np.asarray(y), np.asarray(x, np.float32)
+        assert tails.dtype == first_tails.dtype
+        for (s, n), (at, done) in zip(batch, starts):
+            if s < 0 or not n:
+                continue
+            whole[s] = np.concatenate(
+                [whole.get(s, np.zeros((0, c), np.float32)), x32[at:at + n]])
+            # the definition, over the request's whole input so far
+            padded = np.concatenate(
+                [np.zeros((kernel - 1, c), np.float32), whole[s]])
+            acc = np.broadcast_to(b, (n, c)).astype(np.float32)
+            for back in range(kernel):
+                lo = kernel - 1 + done - back
+                acc = acc + padded[lo:lo + n] * w[kernel - 1 - back]
+            want = np.asarray(jax.nn.silu(jnp.asarray(acc)))
+            np.testing.assert_array_equal(
+                y[at:at + n].view(np.uint32), want.view(np.uint32),
+                err_msg=f"slot {s} from position {done}")
+        left = np.asarray(tails.astype(jnp.float32))
+        for s in range(slots + 1):
+            if s in whole:
+                want = np.concatenate(
+                    [np.zeros((kernel - 1, c), np.float32),
+                     whole[s]])[-(kernel - 1):]
+                np.testing.assert_array_equal(left[s], want,
+                                              err_msg=f"tail of slot {s}")
+                assert not np.signbit(left[s][want == 0]).any()
+            else:
+                np.testing.assert_array_equal(
+                    np.asarray(tails[s]).view(np.uint16),
+                    np.asarray(first_tails[s]).view(np.uint16),
+                    err_msg=f"slot {s} was nobody's to write")
+    assert len(whole) >= 1 and slots not in whole
+
+
 # ---- what the manager, the allocator and the planner make of it -------------
 def test_bytes_per_slot_and_the_plan_against_the_hand_formula():
     """Two M layers: 8 heads x 8 x 16 float32 of state and a conv tail of

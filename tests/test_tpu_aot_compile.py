@@ -461,16 +461,9 @@ _SAMBAY = dict(model_type="phi4flash", hidden_size=64, intermediate_size=96,
                torch_dtype="float32")
 
 
-@pytest.mark.parametrize("use_pallas", [True, False],
-                         ids=["kernel", "row_scan"])
-def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
-    """The 512-row prefill-scan program of a toy SambaY, whole, for the
-    described chip.  With the kernels on, every selective scan is ONE Mosaic
-    kernel: no loop over the rows and no update-slice of the ``[C, N]`` state
-    is left under a ``SelectiveScan`` scope.  The row scan (kernels off) is
-    the control: it holds both, so the reading can see them."""
-    import numpy as np
-
+def _sambay(cap, slots, use_pallas):
+    """The toy SambaY's manager, and a prefill scan's batch of ONE prompt
+    that fills the chunk of ``cap`` rows."""
     from flexflow_tpu.config import FFConfig
     from flexflow_tpu.model import FFModel
     from flexflow_tpu.parallel.mesh import make_mesh
@@ -480,28 +473,44 @@ def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
     from flexflow_tpu.serve.models.base import (ServeModelConfig,
                                                 build_model)
 
-    cap, slots = 512, 4
     ff = FFModel(FFConfig(), mesh=make_mesh({"tp": 1}, jax.devices()[:1]))
     build_model(ff, ServeModelConfig.from_hf_config(_SAMBAY), cap)
     im = InferenceManager(ff, max_requests=slots, max_tokens_per_batch=cap,
                           max_seq_len=1024, use_pallas=use_pallas)
     im.init_operators_inference()
     # the manager sees this process's CPU and would ask for interpret mode:
-    # steered here, as the chip's compiler is what the case is about
+    # steered here, as the chip's compiler is what the cases are about
     im.pallas_interpret = False
     tile = im.prefill_tile
     fields, last_flat = PrefillBatchConfig.np_fields(
-        [(1, list(range(3, 3 + cap)), 0)], [0, cap, 0, 0], tile,
-        max_tokens=cap, max_requests=slots)
+        [(1, list(range(3, 3 + cap)), 0)], [0, cap] + [0] * (slots - 2),
+        tile, max_tokens=cap, max_requests=slots)
     bcs = PrefillBatchConfig(
         base=BatchConfig(*(jnp.asarray(f[None]) for f in fields[:5])),
         tile_size=tile,
         logit_slots=jnp.asarray(PrefillBatchConfig.np_logit_slots(
             [1], last_flat, slots)[None]) if im.gate_lm_head else None)
-    args = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                       sharding=one_chip),
-        (im.params, im.state, bcs))
+    return im, bcs
+
+
+def _described(tree, one_chip):
+    """The shapes of ``tree``'s arrays, on the described chip."""
+    import numpy as np
+
+    return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+        np.shape(x), x.dtype, sharding=one_chip), tree)
+
+
+@pytest.mark.parametrize("use_pallas", [True, False],
+                         ids=["kernel", "row_scan"])
+def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
+    """The 512-row prefill-scan program of a toy SambaY, whole, for the
+    described chip.  With the kernels on, every selective scan is ONE Mosaic
+    kernel: no loop over the rows and no update-slice of the ``[C, N]`` state
+    is left under a ``SelectiveScan`` scope.  The row scan (kernels off) is
+    the control: it holds both, so the reading can see them."""
+    im, bcs = _sambay(512, 4, use_pallas)
+    args = _described((im.params, im.state, bcs), one_chip)
     hlo = jax.jit(im._prefill_scan_impl).lower(*args).compile().as_text()
     scan = [ln for ln in hlo.splitlines() if "/SelectiveScan." in ln]
     kernels = [ln for ln in scan if "tpu_custom_call" in ln]
@@ -517,71 +526,151 @@ def test_sambay_prefill_scan_program_for_v5e(one_chip, use_pallas):
         assert not kernels and loops and writes
 
 
+def _conv_by_index(text, rows, k, c):
+    """What a compiled program does by index under a ``CausalConv1d`` scope:
+    (the scope's lines, the rows of channels each ``scatter`` /
+    ``dynamic-update-slice`` under it writes, the gathers of tails under it
+    — results of ``[..., K - 1, C]`` —, every array of ``[rows, K - 1, C]``
+    in the program)."""
+    import math
+    import re
+
+    dims = {m[1]: [int(d) for d in m[2].split(",") if d] for m in
+            re.finditer(r"%([\w.\-]+) = \(?\w+\[([\d,]*)\]", text)}
+    conv = [ln for ln in text.splitlines() if "/CausalConv1d." in ln]
+    written, gathered = [], []
+    for ln in conv:
+        made = ln.partition(" = ")[2]
+        m = re.search(r" (scatter|dynamic-update-slice)\(([^)]*)\)", ln)
+        if m:
+            operands = re.findall(r"%([\w.\-]+)", m[2])
+            update = dims[operands[2 if m[1] == "scatter" else 1]]
+            if update[-1:] == [c]:
+                written.append(math.prod(update[:-1]))
+        if " gather(" in ln and made.partition("]")[0].endswith(
+                ",%d,%d" % (k - 1, c)):
+            gathered.append(made.partition("{")[0])
+    per_row = re.findall(r"\w+\[%d,%d,%d\]" % (rows, k - 1, c), text)
+    return conv, written, gathered, per_row
+
+
 @pytest.mark.parametrize("slots", [32, 160], ids=["scan32", "scan160"])
 def test_sambay_decode_scan_steps_the_conv_tails_where_they_lie(one_chip,
                                                                 slots):
-    """The decode-scan program of the same toy SambaY, whole, for the
-    described chip, on 32 rows and on 160 (either side of
-    ``DUS_MAX_TOKENS``): under a ``CausalConv1d`` scope NO array of
-    channels is written by index — no ``dynamic-update-slice`` at all, no
-    scatter but the two of one ``int32`` a slot (each slot's row and
-    position; the step's rows come to their slots by a gather of
-    ``[slots + 1, C]``, a third of a tail) — and no tail is gathered.  The
-    flat step (the row form) is the control: it holds the gather and the
-    indexed write-back, so the reading can see them."""
-    import numpy as np
+    """The programs of the same toy SambaY, whole, for the described chip,
+    on 32 slots and on 160 (either side of ``DUS_MAX_TOKENS``).  The DECODE
+    SCAN: under a ``CausalConv1d`` scope NO array of channels is written by
+    index — no ``dynamic-update-slice`` at all, no scatter but the two of
+    one ``int32`` a slot (each slot's row and position; the step's rows
+    come to their slots by a gather of ``[slots + 1, C]``, a third of a
+    tail) — and no tail is gathered.  The FLAT STEP and the PREFILL SCAN
+    (the row form, by segments since PR 67) are held to the same absence
+    but for what puts the rows a stored tail reaches over the first pass's:
+    no tail is gathered, no ``[rows, K - 1, C]`` exists, and no scatter or
+    update-slice writes more than ``slots + 1`` rows of channels.  The
+    control is the body before PR 67 (``tests/conv_row_forms.py``), compiled
+    under the same scope name:
+    the reading sees its gather and its write-back of every row."""
+    from conv_row_forms import gather_scatter
+    from delta_rule_forms import segments
 
-    from flexflow_tpu.config import FFConfig
-    from flexflow_tpu.model import FFModel
-    from flexflow_tpu.parallel.mesh import make_mesh
     from flexflow_tpu.serve.batch_config import BatchConfig
-    from flexflow_tpu.serve.inference_manager import InferenceManager
-    from flexflow_tpu.serve.models.base import (ServeModelConfig,
-                                                build_model)
+    from flexflow_tpu.serve.hybrid_ops import CausalConv1d
 
     cap = 256
-    ff = FFModel(FFConfig(), mesh=make_mesh({"tp": 1}, jax.devices()[:1]))
-    build_model(ff, ServeModelConfig.from_hf_config(_SAMBAY), cap)
-    im = InferenceManager(ff, max_requests=slots, max_tokens_per_batch=cap,
-                          max_seq_len=1024, use_pallas=True)
-    im.init_operators_inference()
-    im.pallas_interpret = False     # as in the prefill-scan case above
+    im, bcs = _sambay(cap, slots, True)
     bc = BatchConfig.build([5] * slots, list(range(slots)), [40] * slots,
                            [41] * slots, max_tokens=cap, max_requests=slots)
-    args = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(np.shape(x), x.dtype,
-                                       sharding=one_chip),
-        (im.params, im.state, bc))
+    args = _described((im.params, im.state, bc), one_chip)
     tails = {tuple(bufs["conv"].shape) for bufs in im.state.values()
              if "conv" in bufs}
     assert tails == {(slots + 1, 3, 128)}
-
-    def conv_lines(text):
-        """What the program does by index under a conv node's scope:
-        (update-slices, scatters of anything but one ``int32`` a slot,
-        gathers of tails)."""
-        conv = [ln for ln in text.splitlines() if "/CausalConv1d." in ln]
-        made = lambda ln: ln.partition(" = ")[2].partition("(")[0]
-        dus = [ln for ln in conv if " dynamic-update-slice(" in ln]
-        scatters = [ln for ln in conv if " scatter(" in ln
-                    and not made(ln).startswith("s32[%d]" % (slots + 1))]
-        gathers = [ln for ln in conv if " gather(" in ln
-                   and ",3,128]" in made(ln)]
-        return conv, dus, scatters, gathers
+    by_index = functools.partial(_conv_by_index, rows=cap, k=4, c=128)
 
     scan = jax.jit(im._decode_scan_impl, static_argnames=("n_steps", "eos"),
                    donate_argnums=(1,)).lower(
         *args, None, None, None, n_steps=4, eos=None).compile().as_text()
-    conv, dus, scatters, gathers = conv_lines(scan)
-    assert conv and not dus and not scatters and not gathers, \
-        (dus[:1], scatters[:1], gathers[:1])
+    conv, written, gathered, per_row = by_index(scan)
+    assert conv and not written and not gathered and not per_row, \
+        (written, gathered[:1], per_row[:1])
     assert im.attention_paths[
         ("causal_conv1d", "one_row_per_request")] == "slot_order"
+
     step = jax.jit(im._step_impl, donate_argnums=(1,)).lower(
         *args).compile().as_text()
-    conv, dus, scatters, gathers = conv_lines(step)
-    assert gathers and (dus or scatters)
+    prefill = jax.jit(im._prefill_scan_impl).lower(*_described(
+        (im.params, im.state, bcs), one_chip)).compile().as_text()
+    for text in (step, prefill):
+        conv, written, gathered, per_row = by_index(text)
+        assert conv and written and max(written) <= slots + 1, written
+        assert not gathered and not per_row, (gathered[:1], per_row[:1])
     assert im.attention_paths[("causal_conv1d", "BatchConfig")] == "rows"
+    assert im.attention_paths[
+        ("causal_conv1d", "PrefillBatchConfig")] == "rows"
+
+    def before(x, tails, req, pos, w):
+        with jax.named_scope("CausalConv1d.control"):
+            return gather_scatter(CausalConv1d(128, 4, bias=False), x, tails,
+                                  segments(req, pos, slots), w, None)
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    control = jax.jit(before, donate_argnums=(1,)).lower(
+        sds((cap, 128), jnp.float32), sds((slots + 1, 3, 128), jnp.float32),
+        sds((cap,), jnp.int32), sds((cap,), jnp.int32),
+        sds((4, 128), jnp.float32)).compile().as_text()
+    conv, written, gathered, per_row = by_index(control)
+    assert gathered and per_row and max(written) >= cap, \
+        (written, gathered[:1], per_row[:1])
+
+
+@pytest.mark.parametrize("widths", ["solar", "kimi", "kimi_flat"])
+def test_the_convs_row_form_compiles_by_segments_for_v5e(one_chip, widths):
+    """``CausalConv1d``'s row form at the cells' widths in bf16, K = 4, for
+    the described chip — ``solar``: a 1024-row chunk of 24 576 channels
+    (q | k | v of 64 heads x 128) over 17 state rows; ``kimi``: 512 rows of
+    12 288 over 257; ``kimi_flat``: a flat step of 128 rows there —: NO
+    array of ``[rows, K - 1, C]`` (each row's gathered tail, or the stack
+    of what each row would leave), no gather of a tail, no scatter or
+    update-slice of more than ``slots + 1`` rows of channels under the
+    op's scope, the tails updated in place, and — where the chunk is
+    larger than the tails — temporaries of at most four times the chunk."""
+    from flexflow_tpu.core.op import OpContext
+    from flexflow_tpu.serve.batch_config import BatchConfig
+    from flexflow_tpu.serve.hybrid_ops import CausalConv1d
+
+    rows, c, slots = {"solar": (1024, 24576, 16), "kimi": (512, 12288, 256),
+                      "kimi_flat": (128, 12288, 256)}[widths]
+    k = 4
+    op = CausalConv1d(c, k, dtype=jnp.bfloat16, bias=False)
+
+    def conv(x, tails, request_index, position, weight):
+        bc = BatchConfig(tokens=position, request_index=request_index,
+                         token_position=position,
+                         num_tokens=jnp.int32(rows),
+                         seq_lens=jnp.zeros((slots,), jnp.int32))
+        paths = {}
+        ctx = OpContext(extras={
+            "node_name": "n", "batch_config": bc, "state": {"conv": tails},
+            "pallas_decode": True, "attention_paths": paths})
+        with jax.named_scope("CausalConv1d.n"):
+            y = op.lower(ctx, [x], {"weight": weight})[0]
+        assert paths == {("causal_conv1d", "BatchConfig"): "rows"}
+        return y, ctx.extras["state_out"]["conv"]
+
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(conv, donate_argnums=(1,)).lower(
+        sds((rows, c), jnp.bfloat16), sds((slots + 1, k - 1, c),
+                                          jnp.bfloat16),
+        sds((rows,), jnp.int32), sds((rows,), jnp.int32),
+        sds((k, c), jnp.bfloat16)).compile()
+    found, written, gathered, per_row = _conv_by_index(
+        compiled.as_text(), rows, k, c)
+    assert found and written and max(written) <= slots + 1, written
+    assert not gathered and not per_row, (gathered[:1], per_row[:1])
+    mem = compiled.memory_analysis()
+    chunk, state = rows * c * 2, (slots + 1) * (k - 1) * c * 2
+    assert mem.alias_size_in_bytes >= state            # updated in place
+    assert mem.temp_size_in_bytes <= 4 * max(chunk, state)
 
 
 @pytest.mark.parametrize("rows", [256, 512], ids=["scan256", "chunk512"])
